@@ -79,6 +79,7 @@ def main() -> None:
     from horovod_tpu.models import transformer as T
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    hvd.place_compile_cache()
     hvd.init()
 
     cfg = T.TransformerConfig(

@@ -1,0 +1,665 @@
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+One process, one model, both hot paths, through the entry points a user
+calls, at the full width of a model the repo documents (the
+``d2048 L8 seq2048 b4, Pallas flash + remat(dots)`` row of
+``docs/benchmarks.md``, with 4 KV heads so head_dim is 128 and both kernel
+families sit on their supported tiling):
+
+1. **kernels** — flash forward and gradients against
+   ``attention.reference_attention`` (plain and under ``remat``);
+   ``paged_attend`` against ``paged_attend_reference`` on the same pool,
+   table and limits (bf16 pages of 16, int8 pages of 32); one fused decode
+   tick against the unfused one at LOGIT level on the full-width model.
+2. **trainer** — ``hvd.init()`` → ``hvd.DistributedOptimizer(optax.adamw)``
+   → ``jax.jit(spmd.shard(step), donate_argnums=...)`` exactly as
+   ``benchmarks/transformer.py`` builds it, a few steps on a fixed batch:
+   loss finite and lower at the end.
+3. **server** — ``serving.InferenceEngine`` with its defaults left alone
+   (paged, overlapped, ``paged_kernel=None``, pages of 16, bf16 KV) →
+   ``warmup`` → ``serving.ServingServer(port=0)`` → concurrent
+   ``POST /generate`` of mixed prompt lengths → ``GET /stats``.
+4. on more than one chip — the data-parallel step spread over all of
+   them and agreeing with one device, the eager allreduce, and ``tp=n``
+   serving answering like ``tp=1``.
+
+Every phase raises on failure; nothing is caught and logged.  Tokens are
+judged at logit level: an engine token must equal the oracle's argmax only
+where the oracle's top-1/top-2 margin exceeds the stated tolerance (a
+random-init bf16 model has near-uniform logits, and an argmax may flip on
+any reordering of a bf16 sum).
+
+It needs a TPU: there is no flag or variable that lets it pass without
+one.  Off-chip it prints what JAX found and exits non-zero with no result
+line.  The last line of a passing run's standard output is one JSON object,
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": n}}``.
+
+The phases are plain functions of a :class:`SmokeConfig`, so
+``tests/test_chip_smoke.py`` runs them at toy size on CPU (where the
+library interprets the same kernel bodies); this script has no such mode.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import gc
+import importlib.metadata
+import json
+import os
+import shutil
+import sys
+import threading
+import time
+import urllib.request
+from typing import Dict, List, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+
+
+@dataclasses.dataclass(frozen=True)
+class SmokeConfig:
+    """One model and how hard to drive it.  The defaults ARE the smoke;
+    the tier-1 test shrinks them."""
+
+    vocab_size: int = 32000
+    d_model: int = 2048
+    n_heads: int = 16
+    n_kv_heads: int = 4
+    n_layers: int = 8
+    d_ff: int = 4096
+    seq: int = 2048
+    dtype: str = "bfloat16"
+    # trainer
+    batch_per_chip: int = 4
+    train_steps: int = 4
+    learning_rate: float = 3e-4
+    # server: prompt lengths span several prefill buckets and leave
+    # partial last pages (page_size stays the engine default, 16)
+    n_slots: int = 4
+    serve_max_len: int = 256
+    prompt_lens: Tuple[int, ...] = (5, 23, 40, 100, 9, 61)
+    max_new_tokens: int = 8
+    # Logit-level tolerance, in units of the spread of the logits it is
+    # applied to (sigma = max(1, std over the vocabulary)): rms
+    # |dlogits| <= logit_tol * sigma, and no single logit off by more
+    # than 5x that.  docs/serving.md gives |dlogits| ~ 3e-3 for
+    # fused-vs-unfused in bf16 on a toy model; eight full-width bf16
+    # layers on the v5e measured rms 1.2e-2 sigma and a max of 5e-2 to
+    # 6e-2 sigma over the 96k logits of one tick (PR 21).  Tokens are
+    # compared only where the oracle's top-1/top-2 margin is clear of
+    # twice the per-logit bound.
+    logit_tol: float = 2e-2
+    seed: int = 0
+    # True on the chip: every kernel must have been COMPILED (Mosaic
+    # custom call in the executable, interpret off, engine kernel
+    # engaged by its own default).  The CPU test passes False and asks
+    # for the (interpreted) paged kernel explicitly.
+    expect_compiled: bool = True
+
+    def model_cfg(self):
+        from horovod_tpu.models import transformer as T
+
+        return T.TransformerConfig(
+            vocab_size=self.vocab_size, d_model=self.d_model,
+            n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
+            n_layers=self.n_layers, d_ff=self.d_ff, max_seq=self.seq,
+            dtype=jnp.dtype(self.dtype), attention_impl="flash",
+            remat=True, remat_policy="dots")
+
+
+class SmokeFailure(AssertionError):
+    """A phase's check did not hold."""
+
+
+def _require(ok: bool, msg: str) -> None:
+    if not ok:
+        raise SmokeFailure(msg)
+
+
+def _say(msg: str) -> None:
+    print(f"[chip_smoke] {msg}", flush=True)
+
+
+def _require_compiled(smoke: SmokeConfig, text: str, at_least: int,
+                      what: str) -> None:
+    """The executable must contain the Mosaic custom call(s) — i.e. the
+    Pallas kernel went through the TPU compiler, not the interpreter and
+    not an XLA substitute."""
+    if not smoke.expect_compiled:
+        return
+    from horovod_tpu.ops import _pallas_util
+
+    _require(not _pallas_util.use_interpret(),
+             f"{what}: Pallas kernels would be interpreted")
+    n = text.count("tpu_custom_call")
+    _require(n >= at_least,
+             f"{what}: {n} Mosaic custom call(s) in the compiled "
+             f"executable, expected at least {at_least}")
+
+
+# --- phase 0: what machine is this -------------------------------------------
+
+
+def report_environment() -> Dict:
+    """Print versions and devices first; return the device record."""
+    import jaxlib
+
+    try:
+        libtpu = importlib.metadata.version("libtpu")
+    except importlib.metadata.PackageNotFoundError:
+        libtpu = "not installed"
+    _say(f"jax {jax.__version__} jaxlib {jaxlib.__version__} "
+         f"libtpu {libtpu} python {sys.version.split()[0]}")
+    devs = jax.devices()
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+              "count": len(devs)}
+    _say(f"devices: platform={device['platform']} "
+         f"device_kind={device['kind']} count={device['count']}")
+    return device
+
+
+def phase_native() -> None:
+    """The C++ control plane must have been built HERE from the tracked
+    sources (the .so is git-ignored; ``horovod_tpu.native`` runs ``make``
+    on first use)."""
+    from horovod_tpu import native
+
+    prebuilt = os.path.exists(native._LIB_PATH)
+    cxx = shutil.which(os.environ.get("CXX", "g++"))
+    if not native.native_built():
+        raise SmokeFailure(
+            "native control plane unavailable: "
+            + ("this machine has no C++ compiler (g++), so "
+               "libhvd_native.so cannot be built from horovod_tpu/native/src"
+               if cxx is None else f"build failed: {native.build_error()}"))
+    _say("native control plane: "
+         + ("loaded an existing libhvd_native.so (sources not newer)"
+            if prebuilt else f"built from tracked sources with {cxx}"))
+
+
+# --- phase 1: kernels against their references -------------------------------
+
+
+def phase_kernels(smoke: SmokeConfig) -> Dict:
+    from horovod_tpu.ops import attention as attn
+    from horovod_tpu.ops import paged_attention as pa
+
+    dt = jnp.dtype(smoke.dtype)
+    low = dt == jnp.bfloat16
+    out_tol = 2e-2 if low else 2e-4
+    Dh = smoke.d_model // smoke.n_heads
+    S = smoke.seq
+    key = jax.random.PRNGKey(smoke.seed)
+    kq, kk, kv, kd = jax.random.split(key, 4)
+    shape = (1, smoke.n_kv_heads, S, Dh)
+    q = jax.random.normal(kq, shape, jnp.float32).astype(dt)
+    k = jax.random.normal(kk, shape, jnp.float32).astype(dt)
+    v = jax.random.normal(kv, shape, jnp.float32).astype(dt)
+    do = jax.random.normal(kd, shape, jnp.float32).astype(dt)
+    report = {}
+
+    def flash(q, k, v):
+        return attn.flash_attention(q, k, v, True)
+
+    def ref(q, k, v):
+        return attn.reference_attention(q, k, v, causal=True)
+
+    fwd = jax.jit(flash).lower(q, k, v).compile()
+    _require_compiled(smoke, fwd.as_text(), 1, "flash forward")
+    o = fwd(q, k, v)
+    o_ref = jax.jit(ref)(q, k, v)
+    err = float(jnp.max(jnp.abs(o.astype(jnp.float32)
+                                - o_ref.astype(jnp.float32))))
+    _require(bool(jnp.isfinite(o.astype(jnp.float32)).all())
+             and err <= out_tol,
+             f"flash forward vs reference: max|d|={err} > {out_tol}")
+    report["flash_fwd_max_abs_err"] = err
+
+    def vjp_of(f):
+        def g(q, k, v):
+            return jax.vjp(f, q, k, v)[1](do)
+        return g
+
+    ref_grads = jax.jit(vjp_of(ref))(q, k, v)
+    for name, f in (("plain", flash), ("remat", jax.checkpoint(flash))):
+        bwd = jax.jit(vjp_of(f)).lower(q, k, v).compile()
+        # forward + dk/dv + dq (remat re-runs the forward: still >= 3)
+        _require_compiled(smoke, bwd.as_text(), 3,
+                          f"flash backward ({name})")
+        worst = 0.0
+        for g, g_ref in zip(bwd(q, k, v), ref_grads):
+            g32, r32 = g.astype(jnp.float32), g_ref.astype(jnp.float32)
+            _require(bool(jnp.isfinite(g32).all()),
+                     f"flash grads ({name}) not finite")
+            rel = float(jnp.linalg.norm(g32 - r32)
+                        / jnp.maximum(jnp.linalg.norm(r32), 1e-6))
+            worst = max(worst, rel)
+        _require(worst <= out_tol,
+                 f"flash grads ({name}) vs reference: rel err {worst} "
+                 f"> {out_tol}")
+        report[f"flash_bwd_{name}_rel_err"] = worst
+
+    # paged decode attention: same pool, table and limits on both sides
+    from horovod_tpu.models import transformer as T
+
+    n_slots, max_pages, n_pages = 4, 6, 32
+    G = smoke.n_heads // smoke.n_kv_heads
+    rng = np.random.RandomState(smoke.seed)
+    for store, ps in (("bf16", 16), ("int8", 32)):
+        table = jnp.asarray(rng.randint(1, n_pages, (n_slots, max_pages)),
+                            jnp.int32)
+        # full table, partial last page, inactive slot, one-past-a-page
+        limit = jnp.asarray([ps * max_pages, ps * 2 + 5, 0, ps + 1],
+                            jnp.int32)
+        qg = jnp.asarray(rng.randn(n_slots, smoke.n_kv_heads, G, Dh), dt)
+        kf = jnp.asarray(rng.randn(n_pages, smoke.n_kv_heads, ps, Dh),
+                         jnp.float32)
+        vf = jnp.asarray(rng.randn(n_pages, smoke.n_kv_heads, ps, Dh),
+                         jnp.float32)
+        if store == "int8":
+            (kp, ks), (vp, vs) = T.kv_quantize(kf), T.kv_quantize(vf)
+            stored = jnp.int8
+        else:
+            stored = jnp.bfloat16 if low else dt
+            kp, vp, ks, vs = kf.astype(stored), vf.astype(stored), None, None
+        if smoke.expect_compiled:
+            _require(pa.kernel_supported(stored, ps, Dh),
+                     f"kernel_supported rejects the smoke's own "
+                     f"{store}/page {ps}/Dh {Dh} layout")
+
+        def fused(qg, kp, vp, ks, vs, table, limit):
+            return pa.paged_attend(qg, kp, vp, ks, vs, table, limit,
+                                   compute_dtype=dt)
+
+        def unfused(qg, kp, vp, ks, vs, table, limit):
+            return pa.paged_attend_reference(qg, kp, vp, ks, vs, table,
+                                             limit, compute_dtype=dt)
+
+        args = (qg, kp, vp, ks, vs, table, limit)
+        run = jax.jit(fused).lower(*args).compile()
+        _require_compiled(smoke, run.as_text(), 1,
+                          f"paged_attend ({store})")
+        o_k, lse_k = run(*args)
+        o_r, lse_r = jax.jit(unfused)(*args)
+        live = np.asarray(limit) > 0
+        e_o = float(jnp.max(jnp.abs(o_k - o_r)))
+        e_l = float(np.max(np.abs(np.asarray(lse_k)[live]
+                                  - np.asarray(lse_r)[live])))
+        _require(e_o <= out_tol and e_l <= out_tol,
+                 f"paged_attend ({store}, page {ps}) vs reference: "
+                 f"max|do|={e_o} max|dlse|={e_l} > {out_tol}")
+        _require(not np.asarray(o_k)[~live].any(),
+                 f"paged_attend ({store}): masked slot produced output")
+        report[f"paged_{store}_max_abs_err"] = max(e_o, e_l)
+    _say("kernels: " + json.dumps(report))
+    return report
+
+
+def phase_decode_logits(smoke: SmokeConfig, params) -> Dict:
+    """One decode tick at LOGIT level on the full-width model: the fused
+    kernel tick against the unfused XLA tick on the same pool."""
+    from horovod_tpu import serving
+    from horovod_tpu.models import transformer as T
+
+    cfg = smoke.model_cfg()
+    n_slots, ps, max_pages = smoke.n_slots, 16, 4
+    n_pages = n_slots * max_pages + 1
+    rng = np.random.RandomState(smoke.seed + 1)
+    pool = serving.init_page_pool(cfg, n_slots, n_pages, ps)
+    # random committed context so attention has something to read
+    for name in ("k", "v"):
+        pool[name] = jnp.asarray(
+            rng.randn(*pool[name].shape), pool[name].dtype)
+    pos = np.asarray([ps * 2 + 3, 1, ps - 1, ps * 3][:n_slots]
+                     + [2] * max(n_slots - 4, 0), np.int32)
+    pool["pos"] = jnp.asarray(pos)
+    table = jnp.asarray(
+        1 + np.arange(n_slots * max_pages).reshape(n_slots, max_pages),
+        jnp.int32)
+    active = jnp.asarray([True] * n_slots).at[1].set(False)
+    tokens = jnp.asarray(rng.randint(0, cfg.vocab_size, (n_slots,)),
+                         jnp.int32)
+
+    def tick(kernel):
+        return jax.jit(lambda p, t, pl_, tb, a: T.decode_step_paged(
+            p, t, pl_, tb, cfg, a, kernel=kernel)[0])
+
+    fused = tick(True).lower(params, tokens, pool, table, active).compile()
+    _require_compiled(smoke, fused.as_text(), 1, "fused decode tick")
+    lk = fused(params, tokens, pool, table, active)
+    lu = tick(False)(params, tokens, pool, table, active)
+    a = np.asarray(active)
+    lk, lu = np.asarray(lk)[a], np.asarray(lu)[a]
+    sigma = max(1.0, float(np.std(lu)))
+    rms = float(np.sqrt(np.mean(np.square(lk - lu)))) / sigma
+    worst = float(np.max(np.abs(lk - lu))) / sigma
+    _require(np.isfinite(lk).all() and rms <= smoke.logit_tol
+             and worst <= 5 * smoke.logit_tol,
+             f"fused vs unfused decode tick: rms|dlogits|={rms:.2e} "
+             f"max={worst:.2e} (in logit std {sigma:.2f}) vs tolerance "
+             f"{smoke.logit_tol} rms / {5 * smoke.logit_tol} max")
+    _say(f"decode tick logits: fused vs unfused rms={rms:.2e} "
+         f"max={worst:.2e} of logit std {sigma:.2f} (tolerance "
+         f"{smoke.logit_tol} rms, {5 * smoke.logit_tol} max)")
+    return {"rms": rms, "max": worst, "logit_std": sigma}
+
+
+# --- phase 2: the trainer -----------------------------------------------------
+
+
+def phase_train(smoke: SmokeConfig):
+    """A few data-parallel steps built exactly as
+    ``benchmarks/transformer.py`` builds them.  Returns
+    ``(host params after training, report)``."""
+    import horovod_tpu as hvd
+    from horovod_tpu import spmd
+    from horovod_tpu.models import transformer as T
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    hvd.init()
+    n = hvd.size()
+    _require(n == len(jax.devices()),
+             f"hvd.size()={n} but JAX sees {len(jax.devices())} devices")
+    cfg = smoke.model_cfg()
+    mesh = hvd.mesh()
+    params = spmd.init_replicated(
+        T.init_params(jax.random.PRNGKey(smoke.seed), cfg))
+    opt = hvd.DistributedOptimizer(optax.adamw(smoke.learning_rate))
+    opt_state = opt.init(params)
+
+    def _step(params, opt_state, batch):
+        loss, grads = jax.value_and_grad(
+            lambda p: T.loss_fn(p, batch, cfg))(params)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return (optax.apply_updates(params, updates), opt_state,
+                jax.lax.pmean(loss, hvd.AXIS))
+
+    step = jax.jit(spmd.shard(
+        _step, in_specs=(P(), P(), P(hvd.AXIS)),
+        out_specs=(P(), P(), P()), mesh=mesh), donate_argnums=(0, 1))
+
+    rows = smoke.batch_per_chip * n
+    tok_host = np.random.RandomState(smoke.seed).randint(
+        0, smoke.vocab_size, (rows, smoke.seq))
+    host_batch = {"tokens": tok_host.astype(np.int32),
+                  "targets": np.roll(tok_host, -1, axis=1).astype(np.int32)}
+    batch = jax.device_put(host_batch, NamedSharding(mesh, P(hvd.AXIS)))
+
+    report = {"chips": n, "global_batch_rows": rows}
+    _require(len(batch["tokens"].sharding.device_set) == n,
+             "batch is not spread over every chip")
+    leaf = jax.tree_util.tree_leaves(params)[0]
+    _require(len(leaf.sharding.device_set) == n
+             and leaf.sharding.is_fully_replicated,
+             "parameters are not replicated on every chip")
+
+    if n > 1:
+        # The same rows on ONE device, chunk by chunk, before training
+        # touches the parameters: the data-parallel loss must agree.
+        report["one_device_loss"] = _one_device_loss(
+            smoke, cfg, params, host_batch, n)
+
+    t0 = time.perf_counter()
+    compiled = step.lower(params, opt_state, batch).compile()
+    report["compile_s"] = round(time.perf_counter() - t0, 1)
+    # forward + dk/dv + dq kernels in the scanned layer (+ remat's
+    # forward again)
+    _require_compiled(smoke, compiled.as_text(), 3, "train step")
+
+    losses: List[float] = []
+    for _ in range(smoke.train_steps):
+        params, opt_state, loss = compiled(params, opt_state, batch)
+        losses.append(float(np.asarray(jax.device_get(loss))))
+    report["losses"] = [round(x, 4) for x in losses]
+    _require(all(np.isfinite(losses)), f"loss not finite: {losses}")
+    _require(losses[-1] < losses[0],
+             f"loss did not fall over {smoke.train_steps} steps: {losses}")
+    if n > 1:
+        ref = report["one_device_loss"]
+        tol = 1e-2 if jnp.dtype(smoke.dtype) == jnp.bfloat16 else 1e-4
+        _require(abs(losses[0] - ref) <= tol * max(abs(ref), 1.0),
+                 f"data-parallel loss {losses[0]} vs one-device loss "
+                 f"{ref} on the same {rows} rows (tol {tol})")
+        out = hvd.allreduce(np.ones(4, np.float32), hvd.Sum)
+        _require(np.allclose(np.asarray(out), float(n)),
+                 f"eager allreduce(ones, Sum) = {np.asarray(out)} != {n}")
+        report["eager_allreduce_sum"] = float(np.asarray(out)[0])
+    leaf = jax.tree_util.tree_leaves(params)[0]
+    _require(len(leaf.sharding.device_set) == n,
+             "updated parameters left some chip")
+    host_params = jax.device_get(params)
+    _say("trainer: " + json.dumps(report))
+    return host_params, report
+
+
+def _one_device_loss(smoke, cfg, params, host_batch, n) -> float:
+    from horovod_tpu.models import transformer as T
+
+    dev0 = jax.devices()[0]
+    p0 = jax.device_put(jax.device_get(params), dev0)
+    loss_of = jax.jit(lambda p, b: T.loss_fn(p, b, cfg))
+    b = smoke.batch_per_chip
+    parts = []
+    for i in range(n):  # equal chunks: the mean of means is the mean
+        chunk = {k: jax.device_put(v[i * b:(i + 1) * b], dev0)
+                 for k, v in host_batch.items()}
+        parts.append(float(np.asarray(jax.device_get(loss_of(p0, chunk)))))
+    return float(np.mean(parts))
+
+
+# --- phase 3: the server --------------------------------------------------------
+
+
+def _prompts(smoke: SmokeConfig) -> List[List[int]]:
+    rng = np.random.RandomState(smoke.seed + 2)
+    return [[int(t) for t in rng.randint(0, smoke.vocab_size, n)]
+            for n in smoke.prompt_lens]
+
+
+def phase_serve(smoke: SmokeConfig, host_params, *, tp: int = 1) -> Dict:
+    """The path of ``examples/serve.py``: engine -> warmup -> HTTP server
+    -> concurrent /generate -> /stats.  Returns the report, including
+    every request's tokens."""
+    from horovod_tpu import serving
+
+    cfg = smoke.model_cfg()
+    if tp == 1:
+        params = jax.device_put(host_params, jax.devices()[0])
+    else:
+        params = host_params  # the engine shards them over its tp mesh
+    engine = serving.InferenceEngine(
+        params, cfg,
+        serving.EngineConfig(
+            n_slots=smoke.n_slots, max_len=smoke.serve_max_len, tp=tp,
+            # the chip takes the engine's own default (auto); the CPU
+            # test asks for the interpreted kernel explicitly
+            paged_kernel=None if smoke.expect_compiled else True))
+    # Record the shapes the engine calls its decode tick with, so the
+    # SAME executable can be inspected afterwards.
+    tick, seen = engine._tick_fn, {}
+
+    def recording_tick(*args):
+        if not seen:
+            seen["avals"] = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(
+                    np.shape(a), a.dtype,
+                    sharding=getattr(a, "sharding", None)), args)
+        return tick(*args)
+
+    engine._tick_fn = recording_tick
+    t0 = time.perf_counter()
+    engine.warmup(sorted(set(smoke.prompt_lens)))
+    warm_s = round(time.perf_counter() - t0, 1)
+    warm = engine.stats()
+    prompts = _prompts(smoke)
+    out: Dict[int, Dict] = {}
+    errors: List[str] = []
+    with serving.ServingServer(engine, port=0) as srv:
+        host, port = srv.address
+        base = f"http://{host}:{port}"
+
+        def client(i: int) -> None:
+            try:
+                req = urllib.request.Request(
+                    base + "/generate",
+                    data=json.dumps({
+                        "tokens": prompts[i],
+                        "max_new_tokens": smoke.max_new_tokens}).encode(),
+                    headers={"Content-Type": "application/json"})
+                with urllib.request.urlopen(req, timeout=600) as r:
+                    out[i] = json.loads(r.read())
+            except Exception as e:  # re-raised below, on the main thread
+                errors.append(f"request {i}: {e!r}")
+
+        threads = [threading.Thread(target=client, args=(i,))
+                   for i in range(len(prompts))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(900)
+        with urllib.request.urlopen(base + "/stats", timeout=60) as r:
+            stats = json.loads(r.read())
+    _require(not errors and len(out) == len(prompts),
+             f"/generate failed: {errors or 'missing replies'}")
+
+    _require(stats["engine_restarts"] == 0 and stats["requests_resumed"] == 0,
+             f"the supervised tick loop swallowed a failure: "
+             f"engine_restarts={stats['engine_restarts']} "
+             f"requests_resumed={stats['requests_resumed']}")
+    _require(stats["decode_compilations"] == warm["decode_compilations"],
+             f"decode recompiled after warmup: "
+             f"{warm['decode_compilations']} -> "
+             f"{stats['decode_compilations']}")
+    _require(stats["paged"] and stats["overlap"]
+             and stats["page_size"] == 16,
+             "engine defaults changed under the smoke")
+    _require(stats["paged_kernel_engaged"] is True,
+             "/stats says the fused paged kernel is NOT in the tick")
+    _require(stats["requests_completed"] >= len(prompts),
+             f"{stats['requests_completed']} requests completed")
+    # The decode tick's own executable, at the shapes it was served at.
+    text = tick.lower(*seen["avals"]).compile().as_text()
+    _require_compiled(smoke, text, 1, f"decode tick (tp={tp})")
+
+    tokens = {i: out[i]["tokens"] for i in sorted(out)}
+    for i, toks in tokens.items():
+        _require(len(toks) == smoke.max_new_tokens
+                 and all(0 <= t < smoke.vocab_size for t in toks),
+                 f"request {i}: bad tokens {toks}")
+    checked = _check_against_oracle(smoke, host_params, prompts, tokens)
+    report = {
+        "tp": tp, "mesh": stats["mesh"], "warmup_s": warm_s,
+        "requests": len(prompts), "prompt_lens": list(smoke.prompt_lens),
+        "prefill_buckets": stats["prefill_buckets"],
+        "decode_compilations": stats["decode_compilations"],
+        "paged_kernel_engaged": stats["paged_kernel_engaged"],
+        "kv_dtype": stats["kv_dtype"],
+        "oracle_positions_checked": checked,
+        "oracle_positions_total": len(prompts) * smoke.max_new_tokens,
+        "tokens": tokens,
+    }
+    _say("server: " + json.dumps({k: v for k, v in report.items()
+                                  if k != "tokens"}))
+    del engine, params
+    return report
+
+
+def _check_against_oracle(smoke, host_params, prompts, tokens):
+    """Teacher-forced logit-level check: run the plain XLA forward
+    (reference attention, no kernel of ours) over prompt + the engine's
+    own tokens; wherever the oracle's top-1/top-2 margin exceeds the
+    tolerance the engine's token must BE the oracle's argmax."""
+    from horovod_tpu.models import transformer as T
+
+    cfg = dataclasses.replace(smoke.model_cfg(), attention_impl="reference",
+                              remat=False)
+    params = jax.device_put(host_params, jax.devices()[0])
+    # One padded batch, one compile: the forward is causal, so right
+    # padding cannot reach the positions that are read.
+    width = max(map(len, prompts)) + smoke.max_new_tokens
+    batch = np.zeros((len(prompts), width), np.int32)
+    for i, prompt in enumerate(prompts):
+        batch[i, :len(prompt) + smoke.max_new_tokens] = prompt + tokens[i]
+    logits = np.asarray(jax.jit(lambda p, t: T.forward(p, t, cfg))(
+        params, jnp.asarray(batch)))                   # (n, width, V) f32
+    checked = 0
+    for i, prompt in enumerate(prompts):
+        for j, tok in enumerate(tokens[i]):
+            row = logits[i, len(prompt) - 1 + j]
+            top2 = np.partition(row, -2)[-2:]
+            # per-logit bound (5x the rms tolerance), on either side
+            tol = 5 * smoke.logit_tol * max(1.0, float(np.std(row)))
+            if top2[1] - top2[0] <= 2 * tol:
+                continue  # too close to call at this precision
+            checked += 1
+            _require(int(np.argmax(row)) == tok,
+                     f"request {i} token {j}: engine said {tok}, oracle "
+                     f"argmax {int(np.argmax(row))} with margin "
+                     f"{top2[1] - top2[0]:.3f} > {2 * tol:.3f}")
+    _require(checked > 0, "no position had a margin wide enough to check")
+    return checked
+
+
+def phase_tp(smoke: SmokeConfig, host_params, tp: int, single: Dict) -> Dict:
+    """``EngineConfig(tp=n)`` answers the same requests as ``tp=1``."""
+    report = phase_serve(smoke, host_params, tp=tp)
+    _require(f"tp={tp}" in report["mesh"],
+             f"/stats mesh {report['mesh']!r} does not name tp={tp}")
+    # Both were held to the oracle where its margin is wide; what is
+    # left is how often they agree with EACH OTHER overall.
+    same = sum(a == b for i in single["tokens"]
+               for a, b in zip(single["tokens"][i], report["tokens"][i]))
+    report["tokens_equal_to_tp1"] = same
+    _say(f"tp={tp}: {same}/{report['oracle_positions_total']} tokens equal "
+         f"to tp=1 (both oracle-checked where the margin allows)")
+    return report
+
+
+# --- the script ----------------------------------------------------------------
+
+
+def run(smoke: SmokeConfig, *, tp: Optional[int] = None) -> Dict:
+    """Every phase, in order; raises on the first failure.  ``tp``: the
+    tensor-parallel degree of the extra serving pass (None = skip)."""
+    phase_native()
+    report = {"kernels": phase_kernels(smoke)}
+    host_params, report["train"] = phase_train(smoke)
+    params0 = jax.device_put(host_params, jax.devices()[0])
+    report["decode_logits"] = phase_decode_logits(smoke, params0)
+    del params0
+    gc.collect()
+    report["serve"] = phase_serve(smoke, host_params)
+    gc.collect()  # the engine is a reference cycle holding device buffers
+    if tp:
+        report["serve_tp"] = phase_tp(smoke, host_params, tp,
+                                      report["serve"])
+    return report
+
+
+def main() -> int:
+    device = report_environment()
+    if device["platform"] != "tpu":
+        print(f"chip_smoke.py needs a TPU; JAX found platform "
+              f"{device['platform']!r}.  Nothing was run.", file=sys.stderr)
+        return 2
+    import horovod_tpu as hvd
+
+    _say(f"compile cache: {hvd.place_compile_cache()}"
+         + (" (from JAX_COMPILATION_CACHE_DIR)"
+            if os.environ.get("JAX_COMPILATION_CACHE_DIR") else ""))
+    t0 = time.perf_counter()
+    n = device["count"]
+    report = run(SmokeConfig(), tp=n if n > 1 else None)
+    hvd.shutdown()
+    _say(f"all phases passed in {time.perf_counter() - t0:.0f}s "
+         f"(train compile {report['train']['compile_s']}s, "
+         f"server warmup {report['serve']['warmup_s']}s)")
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

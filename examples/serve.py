@@ -458,6 +458,16 @@ def main() -> None:
     import horovod_tpu as hvd
     from horovod_tpu import obs, serving
 
+    hvd.place_compile_cache()
+    if args.replicas > 1 or args.rollout:
+        # A chip belongs to one process, and here the REPLICAS own the
+        # chips (the supervisor hands each its own).  This process only
+        # trains the toy LM, routes and supervises: pinned to CPU before
+        # any backend starts, or on a TPU host it would hold the chips
+        # its children need.
+        jax.config.update("jax_platforms", "cpu")
+        print("front-tier parent pinned to CPU; replicas own the "
+              "accelerators")
     hvd.init()
     if args.trace:
         obs.tracing.start(args.trace, jsonl_path=args.trace + ".jsonl")

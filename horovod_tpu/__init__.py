@@ -11,7 +11,6 @@ Typical use (the Horovod "minimal code change" contract, README.rst:37):
     params = hvd.broadcast_parameters(params, root_rank=0)
 """
 
-from horovod_tpu import _compat  # noqa: F401  (installs JAX version shims)
 from horovod_tpu.basics import (
     AXIS,
     CROSS_AXIS,
@@ -84,6 +83,7 @@ from horovod_tpu.state import (
 )
 from horovod_tpu.join import join, masked_average
 from horovod_tpu import callbacks, data, elastic, obs, spmd, parallel, timeline
+from horovod_tpu.compile_cache import place_compile_cache
 from horovod_tpu.data import DataLoader
 from horovod_tpu.timeline import start_timeline, stop_timeline
 

@@ -1,77 +1,15 @@
-"""JAX version compatibility shims.
+"""The one JAX-installation helper the package still needs.
 
-The codebase targets current JAX public APIs; deployment environments
-often pin older runtimes (this repo's CI images ship 0.4.x).  Rather
-than sprinkling per-call-site fallbacks, the few APIs we rely on that
-older JAX lacks are installed here, once, at ``import horovod_tpu``
-time.  Every shim is gated on ``hasattr`` — on a current JAX this
-module is a no-op.
-
-Shimmed:
-
-* ``jax.lax.axis_size(name)`` — older JAX spells the size of a bound
-  mesh axis ``lax.psum(1, name)``, which constant-folds to a static int
-  and raises the same ``NameError`` on an unbound name.
-* ``jax.shard_map`` — re-export of ``jax.experimental.shard_map`` on
-  versions where it has not been promoted to the top level.
+The codebase targets the single installation the sandbox and the chip
+machine share (jax 0.9.0); there are no version shims.
 """
 
 from __future__ import annotations
 
-import os
-
 import jax
-from jax import lax
 
 
 def set_cpu_device_count(n: int) -> None:
     """Request ``n`` virtual CPU devices (tests/benchmarks simulating a
-    multi-chip slice).  Must run before the CPU backend initializes.
-    Newer JAX has a config option; older JAX only honors the XLA flag."""
-    try:
-        jax.config.update("jax_num_cpu_devices", n)
-    except AttributeError:
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={n}"
-        )
-
-
-def _install() -> None:
-    if not hasattr(lax, "axis_size"):
-
-        def axis_size(axis_name):
-            """Size of the bound mesh axis (or product over a tuple)."""
-            return lax.psum(1, axis_name)
-
-        lax.axis_size = axis_size
-
-    if not hasattr(jax, "shard_map"):
-        try:
-            import inspect
-
-            from jax.experimental.shard_map import shard_map
-
-            if "check_vma" not in inspect.signature(shard_map).parameters:
-                # Newer JAX renamed check_rep -> check_vma when
-                # shard_map was promoted to the top level; translate so
-                # callers can use the modern spelling on either.
-                import functools
-
-                _shard_map = shard_map
-
-                @functools.wraps(_shard_map)
-                def shard_map(*args, **kwargs):
-                    # wraps copies __wrapped__, so signature-based
-                    # capability sniffing (inspect.signature) still
-                    # sees the REAL parameter list, not (*args, **kw).
-                    if "check_vma" in kwargs:
-                        kwargs["check_rep"] = kwargs.pop("check_vma")
-                    return _shard_map(*args, **kwargs)
-
-            jax.shard_map = shard_map
-        except ImportError:  # pragma: no cover - shard_map predates 0.4
-            pass
-
-
-_install()
+    multi-chip slice).  Must run before the CPU backend initializes."""
+    jax.config.update("jax_num_cpu_devices", n)

@@ -135,14 +135,10 @@ def _bootstrap_distributed() -> None:
     # Must not touch the XLA backend before jax.distributed.initialize
     # (jax.process_count() would initialize it); inspect the coordination
     # client state directly.
-    try:
-        from jax._src import distributed as _jd
+    from jax._src import distributed as _jd
 
-        if _jd.global_state.client is not None:
-            return  # already initialized (e.g. by the TPU runtime itself)
-    except Exception:
-        if jax.process_count() >= nproc:
-            return
+    if _jd.global_state.client is not None:
+        return  # already initialized (e.g. by the TPU runtime itself)
     # The JAX coordination service needs its own port: the launcher's
     # HOROVOD_COORDINATOR_PORT is the rendezvous KV server, so rank 0 binds
     # KV+2 for the gRPC service unless HOROVOD_JAX_PORT says otherwise.
@@ -154,15 +150,10 @@ def _bootstrap_distributed() -> None:
         addr = f"127.0.0.1:{jax_port}"
     elif ":" not in addr:
         addr = f"{addr}:{jax_port}"
-    # Older JAX gates cross-process CPU collectives behind a config
-    # option (newer builds enable them by default; the option is gone).
-    # Without it a multi-process CPU job fails at the first collective
-    # with "Multiprocess computations aren't implemented on the CPU
-    # backend" — enable gloo before the backend initializes.
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):
-        pass
+    # Cross-process CPU collectives (multi-process CPU jobs: the tests,
+    # the launcher on a chipless host) go over gloo; set before the
+    # backend initializes.
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=addr, num_processes=nproc, process_id=rank
     )
@@ -221,20 +212,10 @@ def init(
     _configure_logging()
     _bootstrap_distributed()
     if devices is None:
-        try:
-            devices = jax.devices()
-        except RuntimeError as e:
-            # A configured platform whose plugin is absent in THIS
-            # process (e.g. an accelerator plugin selected by the parent
-            # environment but not registered in launcher-spawned ranks)
-            # should degrade to CPU with a warning, not kill the job.
-            if "Unable to initialize backend" not in str(e):
-                raise
-            logger.warning(
-                "configured JAX platform unavailable (%s); falling back "
-                "to CPU", e)
-            jax.config.update("jax_platforms", "cpu")
-            devices = jax.devices()
+        # No fallback: a configured platform that cannot initialize
+        # (JAX_PLATFORMS=tpu with no chip, a chip another process holds)
+        # raises here rather than continuing on CPU.
+        devices = jax.devices()
     mesh, hier = _build_meshes(devices, axis_name)
     local = [d for d in devices if d.process_index == jax.process_index()]
     _context = _Context(

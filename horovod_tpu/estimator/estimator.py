@@ -46,11 +46,11 @@ class EstimatorParams:
     storage_format: str = "npz"
     # JAX platform pinned in worker ranks.  "auto" (default) trains on
     # TPU when a single worker process can own the visible chips
-    # (num_proc == 1) and falls back to CPU otherwise — the launcher does
-    # not yet partition chips per process (TPU_VISIBLE_* env plumbing),
-    # so several local workers would contend for libtpu's exclusive host
-    # lock; "cpu"/"tpu" pin explicitly; None leaves the runtime default
-    # untouched.
+    # (num_proc == 1) and pins CPU otherwise; "cpu"/"tpu" pin
+    # explicitly; None leaves the runtime default untouched.  The
+    # launcher now gives local ranks one chip each
+    # (runner/chips.py), so the num_proc > 1 -> CPU default is a
+    # device-hiding leftover, not a necessity (ROADMAP D10).
     jax_platform: Optional[str] = "auto"
 
 
@@ -58,10 +58,9 @@ def resolve_platform(params: "EstimatorParams") -> str:
     """Resolve ``jax_platform="auto"``: TPU by default when the single
     worker process can own the chips, CPU fallback otherwise (VERDICT r1
     weak #7 — the estimator should touch the TPU without the user
-    overriding, but never oversubscribe).  Multi-process runs resolve to
-    CPU: nothing in the launcher partitions chips per process yet, so N
-    local workers opening the full TPU backend would fight over libtpu's
-    exclusive host lock.
+    overriding, but never oversubscribe).  Multi-process runs still
+    resolve to CPU; the launcher can now give each local rank its own
+    chip (runner/chips.py), so that default is due to go (ROADMAP D10).
 
     The probe runs in a THROWAWAY subprocess: enumerating TPUs in this
     process would initialize the backend here and hold the exclusive chip

@@ -1345,13 +1345,19 @@ def prefill_with_prefix(params: Dict, suffix, prefix_k, prefix_v,
     return logits[:, 0], {"k": k_all, "v": v_all, "pos": p0 + true_len}
 
 
-def _attention_prefill(x, p, cfg: TransformerConfig):
+def _attention_prefill(x, p, cfg: TransformerConfig, mesh=None):
     """Full-sequence attention that ALSO returns the (unexpanded,
     post-RoPE) per-layer K/V for cache filling.  Shares the projection
     math with :func:`_attention` via ``_qkv_proj``/``_out_proj`` and
     honors ``attention_impl='reference'``; the sequence-parallel impls
     need a bound mesh axis, so they prefill through the flash kernel
-    (which falls back to fused XLA for untileable prompts)."""
+    (which takes the XLA form for untileable prompts).
+
+    ``mesh``: a tp serving mesh.  GSPMD cannot partition a Mosaic
+    kernel ("wrap the call in a shard_map"), so under tp the flash
+    kernel runs per head shard through ``shard_map`` — attention is
+    per-head, and a contiguous tp split keeps every query head on the
+    device that holds its KV head, so the GQA expansion is local."""
     from horovod_tpu.ops import attention as attn
 
     qh, kh, vh = _qkv_proj(x, p, cfg, 0)  # kh/vh: (B, H_kv, S0, Dh)
@@ -1359,14 +1365,23 @@ def _attention_prefill(x, p, cfg: TransformerConfig):
         oh = attn.reference_attention(
             qh, attn.expand_kv(kh, cfg.n_heads),
             attn.expand_kv(vh, cfg.n_heads), causal=True)
-    else:
-        oh = attn.flash_attention(qh, attn.expand_kv(kh, cfg.n_heads),
-                                  attn.expand_kv(vh, cfg.n_heads), True)
-    return _out_proj(oh, p, cfg), kh, vh
+        return _out_proj(oh, p, cfg), kh, vh
+
+    def flash(q, k, v):
+        return attn.flash_attention(q, attn.expand_kv(k, q.shape[1]),
+                                    attn.expand_kv(v, q.shape[1]), True)
+
+    if mesh is not None:
+        from horovod_tpu import spmd
+
+        head = P(None, "tp", None, None)
+        flash = spmd.shard(flash, in_specs=(head, head, head),
+                           out_specs=head, mesh=mesh)
+    return _out_proj(flash(qh, kh, vh), p, cfg), kh, vh
 
 
 def prefill(params: Dict, prompt, cache: Dict, cfg: TransformerConfig,
-            *, moe_impl: str = "dropless", true_len=None):
+            *, moe_impl: str = "dropless", true_len=None, mesh=None):
     """Fill a FRESH cache with a (B, S0) prompt in ONE forward pass
     (the serving-shape prefill: batched MXU work instead of S0 serial
     decode steps) and return ``(last-position logits (B, V), cache)``
@@ -1390,7 +1405,10 @@ def prefill(params: Dict, prompt, cache: Dict, cfg: TransformerConfig,
     Causality makes the padding inert for the logits (position
     ``true_len - 1`` never attends past itself), and the junk K/V it
     leaves at positions ``>= true_len`` is never read: decode writes
-    position ``p`` in the same step that first attends it."""
+    position ``p`` in the same step that first attends it.
+
+    ``mesh``: the tp serving mesh when params are head-sharded under
+    GSPMD (see :func:`_attention_prefill`)."""
     pos = cache["pos"]
     if not isinstance(pos, jax.core.Tracer) and int(pos) != 0:
         raise ValueError("prefill requires a fresh cache (pos == 0)")
@@ -1403,7 +1421,7 @@ def prefill(params: Dict, prompt, cache: Dict, cfg: TransformerConfig,
     x = params["embed"].astype(cfg.dtype)[prompt]
 
     def layer(x, p):
-        h, kh, vh = _attention_prefill(_rmsnorm(x, p["ln1"]), p, cfg)
+        h, kh, vh = _attention_prefill(_rmsnorm(x, p["ln1"]), p, cfg, mesh)
         # Prefill ingests whole prompts: DROPLESS grouped-matmul dispatch
         # by default — exact like dense but 1/E of its FFN FLOPs
         # (ops/moe.py dropless_moe).  Per-step decode keeps dense (a

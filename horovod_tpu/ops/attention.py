@@ -192,12 +192,11 @@ def _flash_fwd_kernel(shift_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0] = jnp.broadcast_to(lse[None, :], lse_ref.shape[1:])
 
 
-# Shared Pallas plumbing (ops/_pallas_util.py): the guarded import,
-# interpreter fallback, vma-inheriting out shapes, and the SMEM scalar
-# spec are shared with the fused paged-attention decode kernel
-# (ops/paged_attention.py) so the conventions cannot fork.
+# Shared Pallas plumbing (ops/_pallas_util.py): the interpret rule,
+# vma-inheriting out shapes, and the SMEM scalar spec are shared with
+# the fused paged-attention decode kernel (ops/paged_attention.py) so
+# the conventions cannot fork.
 from horovod_tpu.ops._pallas_util import (  # noqa: E402
-    PALLAS_AVAILABLE as _PALLAS,
     out_sds as _out_sds,
     pl,
     pltpu,
@@ -207,6 +206,13 @@ from horovod_tpu.ops._pallas_util import (  # noqa: E402
 )
 
 
+def _tileable(S: int, T: int, D: int, block_q: int, block_k: int) -> bool:
+    """THE shape rule of the flash kernels (forward and backward share
+    it): whole blocks along both sequence dims and a head dim in whole
+    sublanes.  Anything else takes the same math in XLA."""
+    return S % block_q == 0 and T % block_k == 0 and D % 8 == 0
+
+
 def _flash_fwd(q, k, v, shift, sm_scale, block_q: int, block_k: int):
     """shift: None (no mask) or int scalar (traced ok) — shifted causal."""
     B, H, S, D = q.shape
@@ -214,8 +220,7 @@ def _flash_fwd(q, k, v, shift, sm_scale, block_q: int, block_k: int):
     block_q = min(block_q, S)
     block_k = min(block_k, T)
     scale = _sm_scale(q, sm_scale)
-    if (not _PALLAS or S % block_q or T % block_k
-            or D % 8):  # fall back for shapes the kernel can't tile
+    if not _tileable(S, T, D, block_q, block_k):
         return _reference_attention_lse(q, k, v, shift, scale)
     nq, nk = S // block_q, T // block_k
     kernel = functools.partial(
@@ -451,7 +456,7 @@ def _flash_bwd(shift, sm_scale, block_q, block_k, res, do, dlse=None):
     scale = _sm_scale(q, sm_scale)
     bq = min(block_q, S)
     bk = min(block_k, T)
-    if _PALLAS and S % bq == 0 and T % bk == 0 and D % 8 == 0:
+    if _tileable(S, T, D, bq, bk):
         return _flash_bwd_pallas(shift, scale, bq, bk, q, k, v, o, lse, do,
                                  dlse=dlse)
     if T % bk:  # analytic fallback: widen to one K block

@@ -980,10 +980,7 @@ def poll(handle: int) -> bool:
     done = True
     for leaf in jax.tree_util.tree_leaves(val):
         if isinstance(leaf, jax.Array):
-            try:
-                done = done and leaf.is_ready()
-            except AttributeError:  # older jax
-                pass
+            done = done and leaf.is_ready()
     return done
 
 

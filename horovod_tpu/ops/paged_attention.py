@@ -34,10 +34,13 @@ program per ``(slot, kv-head)``:
   combines committed-page attention with in-window attention by LSE).
 
 Conventions shared with :mod:`~horovod_tpu.ops.attention` via
-:mod:`~horovod_tpu.ops._pallas_util`: non-fatal Pallas import, CPU
-interpreter fallback (tier-1 CPU tests exercise the REAL kernel body),
-and a pure-JAX reference path (:func:`paged_attend_reference`) for
-shapes the TPU tiling cannot serve.
+:mod:`~horovod_tpu.ops._pallas_util`: compiled on TPU, interpreted on
+CPU (tier-1 CPU tests exercise the REAL kernel body).  The pure-JAX
+:func:`paged_attend_reference` is the test oracle only —
+:func:`paged_attend` never substitutes it; a caller asks
+:func:`kernel_supported` first (the engine does, once, at
+construction) and takes the unfused XLA tick for layouts the compiler
+cannot tile.
 """
 
 from __future__ import annotations
@@ -50,14 +53,13 @@ import numpy as np
 
 from horovod_tpu.ops._pallas_util import (
     NEG_INF,
-    PALLAS_AVAILABLE,
     pl,
     pltpu,
     use_interpret,
 )
 
-__all__ = ["DEQUANT_COMPUTE", "paged_attend", "paged_attend_reference",
-           "kernel_supported"]
+__all__ = ["DEQUANT_COMPUTE", "UnsupportedPagedLayoutError", "paged_attend",
+           "paged_attend_reference", "kernel_supported"]
 
 
 # The pinned dequant compute dtype.  ``kv_dequantize`` promotes int8
@@ -69,33 +71,43 @@ __all__ = ["DEQUANT_COMPUTE", "paged_attend", "paged_attend_reference",
 DEQUANT_COMPUTE = jnp.float32
 
 
-def _dequant(q, scale, dtype):
-    """The in-kernel mirror of ``kv_dequantize``: f32 multiply, then a
-    single cast to ``dtype`` (see :data:`DEQUANT_COMPUTE`)."""
+def _dequant_col(q, scale_col, dtype):
+    """The mirror of ``kv_dequantize``: f32 multiply, then a single cast
+    to ``dtype`` (see :data:`DEQUANT_COMPUTE`).  ``scale_col`` already
+    carries the trailing unit dim (the kernel reads it as a column)."""
     return (q.astype(DEQUANT_COMPUTE)
-            * scale[..., None].astype(DEQUANT_COMPUTE)).astype(dtype)
+            * scale_col.astype(DEQUANT_COMPUTE)).astype(dtype)
 
 
-# Minimum sublane tile (second-to-last dim) per dtype on TPU.  The
-# interpreter is layout-agnostic, so this gates only the real-TPU path.
+def _dequant(q, scale, dtype):
+    """:func:`_dequant_col` for a scale lacking the trailing dim."""
+    return _dequant_col(q, scale[..., None], dtype)
+
+
+# Minimum sublane tile (second-to-last dim) per STORED dtype on TPU: a
+# page is one ``(page_size, head_dim)`` VMEM block, and Mosaic tiles the
+# last two dims in (sublane, 128-lane) units.
 _MIN_SUBLANE = {"float32": 8, "bfloat16": 16, "int8": 32}
 
 
-def kernel_supported(k_pool, page_size: int, head_dim: int) -> bool:
-    """Whether the Pallas kernel can serve this pool's layout.
+class UnsupportedPagedLayoutError(ValueError):
+    """The fused kernel was demanded for a pool layout the TPU compiler
+    cannot tile (see :func:`kernel_supported`)."""
 
-    Under the interpreter (any non-TPU backend) every shape works; on a
-    real TPU the page must fill whole dtype tiles — ``head_dim`` a lane
-    multiple (128) and ``page_size`` a sublane multiple of the STORED
-    dtype (8 f32 / 16 bf16 / 32 int8).  Otherwise the caller gets the
-    pure-JAX :func:`paged_attend_reference` with identical semantics.
-    """
-    if not PALLAS_AVAILABLE:
-        return False
-    if use_interpret():
-        return True
-    sub = _MIN_SUBLANE.get(jnp.dtype(k_pool.dtype).name, 8)
-    return head_dim % 128 == 0 and page_size % sub == 0
+
+def kernel_supported(storage_dtype, page_size: int, head_dim: int) -> bool:
+    """Whether the COMPILED kernel can serve a pool of this layout.
+
+    This is the TPU compiler's rule, whatever backend asks: the page
+    must fill whole dtype tiles — ``head_dim`` a lane multiple (128)
+    and ``page_size`` a sublane multiple of the stored dtype (8 f32 /
+    16 bf16 / 32 int8).  On CPU the interpreter runs any shape, so
+    :func:`paged_attend` itself does not consult this; the serving
+    engine does, at construction, when it decides whether its ticks use
+    the kernel (``/stats`` ``paged_kernel_engaged``)."""
+    sub = _MIN_SUBLANE.get(jnp.dtype(storage_dtype).name)
+    return (sub is not None and head_dim % 128 == 0
+            and page_size % sub == 0)
 
 
 def _kernel_body(table_ref, limit_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -109,7 +121,7 @@ def _kernel_body(table_ref, limit_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     order".  Scratch (``acc``/``m``/``l``) persists across the innermost
     grid dim, carrying the online softmax over the slot's pages.
     """
-    s, b = pl.program_id(0), pl.program_id(2)
+    s, h, b = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     limit = limit_ref[s]
 
     @pl.when(b == 0)
@@ -123,8 +135,14 @@ def _kernel_body(table_ref, limit_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         k = k_ref[0, 0]                                   # (page, Dh)
         v = v_ref[0, 0]
         if quantized:  # fused dequant: int8 payload * f32 scale, in-reg
-            k = _dequant(k, ks_ref[0, 0], compute_dtype)
-            v = _dequant(v, vs_ref[0, 0], compute_dtype)
+            # The scale block holds ALL of the page's heads (see the
+            # sc_spec note below); take head h's row as a column.
+            k = _dequant_col(
+                k, ks_ref[0, pl.ds(h, 1), :].reshape(page_size, 1),
+                compute_dtype)
+            v = _dequant_col(
+                v, vs_ref[0, pl.ds(h, 1), :].reshape(page_size, 1),
+                compute_dtype)
         q = q_ref[0, 0].astype(k.dtype)                   # (R, Dh)
         Dh = q.shape[-1]
         s_blk = jax.lax.dot_general(
@@ -196,8 +214,13 @@ def _pallas_paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table,
     in_specs = [q_spec, kv_spec, kv_spec]
     operands = [qg, k_pool, v_pool]
     if quantized:
-        sc_spec = pl.BlockSpec((1, 1, ps),
-                               lambda s, h, b, t, lim: (t[s, b], h, 0))
+        # Mosaic wants a block's last two dims in whole (8, 128) tiles
+        # or equal to the array's: a per-head (1, 1, ps) block over
+        # (P, H_kv, ps) is neither, so the block carries every head of
+        # the page (H_kv * ps f32 — a few hundred bytes) and the kernel
+        # picks its row.
+        sc_spec = pl.BlockSpec((1, Hkv, ps),
+                               lambda s, h, b, t, lim: (t[s, b], 0, 0))
         in_specs += [sc_spec, sc_spec]
         operands += [k_scale, v_scale]
 
@@ -235,8 +258,8 @@ def paged_attend_reference(qg, k_pool, v_pool, k_scale, v_scale, table,
     masked softmax — mirroring the unfused decode path's op-for-op
     rounding (``kv_dequantize``'s f32 contract, ``_cache_attend``'s
     stored-dtype dots with f32 accumulation, normalize-then-cast
-    weights).  Used for shapes the TPU tiling cannot serve and as the
-    oracle in tests."""
+    weights).  The oracle in tests and in ``chip_smoke.py``; never a
+    silent substitute for the kernel."""
     S, Hkv, R, Dh = qg.shape
     max_pages = table.shape[1]
     ps = k_pool.shape[2]
@@ -302,12 +325,7 @@ def paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table, limit, *,
       row logsumexp of the masked scores (``NEG_INF`` when fully
       masked) for cross-source combining.
     """
-    ps, Dh = k_pool.shape[2], k_pool.shape[3]
     if compute_dtype is None:
         compute_dtype = k_pool.dtype
-    if not kernel_supported(k_pool, ps, Dh):
-        return paged_attend_reference(
-            qg, k_pool, v_pool, k_scale, v_scale, table, limit,
-            compute_dtype=compute_dtype)
     return _pallas_paged_attend(qg, k_pool, v_pool, k_scale, v_scale,
                                 table, limit, compute_dtype)

@@ -16,7 +16,7 @@ import sys
 import threading
 from typing import Dict, List, Optional, Tuple
 
-from horovod_tpu.runner import safe_shell_exec
+from horovod_tpu.runner import chips, safe_shell_exec
 from horovod_tpu.runner.hosts import HostSpec, SlotInfo, allocate
 from horovod_tpu.runner.rendezvous import RendezvousServer
 
@@ -40,12 +40,14 @@ def build_command(
     env: Dict[str, str],
     coordinator_addr: str,
     coordinator_port: int,
+    chip_env: Optional[Dict[str, str]] = None,
 ) -> (List[str], Dict[str, str], Optional[bytes]):
     """The env contract every rank receives (reference
     ``gloo_run.py:262-288``).  Returns (argv, env, stdin_bytes): for
     remote slots the per-job HMAC secret travels over the ssh channel's
     stdin, never on the command line where any local user could read it
-    from /proc/<pid>/cmdline."""
+    from /proc/<pid>/cmdline.  ``chip_env`` is a LOCAL rank's share of
+    this host's TPU chips (:func:`chips.local_rank_envs`)."""
     slot_env = dict(env)
     slot_env.update(slot.to_env())
     slot_env["HOROVOD_COORDINATOR_ADDR"] = coordinator_addr
@@ -54,6 +56,7 @@ def build_command(
     slot_env["HOROVOD_GLOO_RENDEZVOUS_PORT"] = str(coordinator_port)
     if _is_local(slot.hostname):
         # Local spawn: env travels through Popen(env=...), not argv — safe.
+        slot_env.update(chip_env or {})
         return command, slot_env, None
     secret_val = slot_env.get("HOROVOD_SECRET_KEY")
     exports = " ".join(
@@ -101,6 +104,10 @@ def spawn_ranks(
     terminated (TERM → grace → KILL); ``on_rank_exit(index, slot, rc)``
     fires as each rank exits, from that rank's watcher thread."""
     exit_codes: List[Optional[int]] = [None] * len(slots)
+    # Ranks that share THIS host each get a chip of their own — or the
+    # launch is refused here, before any rank exists.
+    local = [i for i, s in enumerate(slots) if _is_local(s.hostname)]
+    chip_envs = dict(zip(local, chips.local_rank_envs(len(local), env)))
 
     def _run(i: int, slot: SlotInfo) -> None:
         # EVERY exit path must record an exit code: a None left behind
@@ -110,7 +117,8 @@ def spawn_ranks(
         try:
             try:
                 cmd, slot_env, stdin_data = build_command(
-                    slot, command, env, coordinator_addr, coordinator_port)
+                    slot, command, env, coordinator_addr, coordinator_port,
+                    chip_envs.get(i))
                 if output_filename:
                     os.makedirs(output_filename, exist_ok=True)
                     out = open(os.path.join(

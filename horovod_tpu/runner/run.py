@@ -19,6 +19,7 @@ import sys
 from typing import List, Optional
 
 from horovod_tpu.runner import config_parser
+from horovod_tpu.runner.chips import ChipPartitionError
 from horovod_tpu.runner.hosts import parse_hosts
 from horovod_tpu.runner.launch import launch_job
 
@@ -335,13 +336,16 @@ def _run(args: argparse.Namespace) -> int:
             raise SystemExit(f"horovodrun: {e}")
     if args.verbose:
         print(f"horovodrun: launching on {len(host_specs)} host(s)")
-    return launch_job(
-        args.command,
-        host_specs,
-        env=env,
-        output_filename=args.output_filename,
-        coordinator_port=args.start_port,
-    )
+    try:
+        return launch_job(
+            args.command,
+            host_specs,
+            env=env,
+            output_filename=args.output_filename,
+            coordinator_port=args.start_port,
+        )
+    except ChipPartitionError as e:
+        raise SystemExit(f"horovodrun: {e}")
 
 
 def run_commandline(argv: Optional[List[str]] = None) -> None:

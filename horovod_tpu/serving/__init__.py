@@ -62,6 +62,7 @@ from horovod_tpu.serving.sampling import (
     SamplingParams,
     SlotSampling,
 )
+from horovod_tpu.ops.paged_attention import UnsupportedPagedLayoutError
 from horovod_tpu.serving.sharding import (
     ServingSharding,
     ShardingConfigError,
@@ -100,6 +101,7 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "ServingMetrics",
     "SamplingParams", "SlotSampling", "SSEParser", "event_bytes",
     "ServingSharding", "ShardingConfigError",
+    "UnsupportedPagedLayoutError",
     "CacheOutOfPagesError", "DeadlineExceededError", "DrainingError",
     "EngineFailedError", "EngineStalledError", "QueueFullError",
     "Request", "RequestTooLongError", "Scheduler", "ServingError",

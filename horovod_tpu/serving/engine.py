@@ -383,14 +383,19 @@ class EngineConfig:
     # attention through the Pallas flash-decoding kernel
     # (horovod_tpu/ops/paged_attention.py) — pages stream through VMEM
     # with int8 dequant fused into the load, nothing materialized at
-    # logical shape.  None = auto (engage on a real TPU backend, stay
-    # on the unfused XLA path elsewhere — the CPU interpreter runs the
-    # kernel faithfully but slowly); True forces it anywhere Pallas
-    # imports (tests/benchmarks); False pins the unfused path.  Greedy
-    # output is token-identical either way (tests/test_paged.py), and
-    # the flag is a CONSTRUCTOR-level knob: it is baked into the tick
-    # executables at trace time, so flipping it means a rebuild —
-    # tuning/replay.py explores it offline like kv_dtype/page_size.
+    # logical shape.  None = auto: on a TPU backend engage iff the pool
+    # layout passes the compiler's tiling gate
+    # (ops.paged_attention.kernel_supported — e.g. int8 storage needs
+    # page_size % 32 == 0), else run the unfused XLA tick; on CPU stay
+    # unfused (the interpreter runs the kernel faithfully but slowly).
+    # True demands it — a typed UnsupportedPagedLayoutError at
+    # construction if the compiler cannot tile the layout; False pins
+    # the unfused path.  /stats paged_kernel_engaged says what the
+    # ticks were built on.  Greedy output is token-identical either
+    # way (tests/test_paged.py), and the flag is a CONSTRUCTOR-level
+    # knob: it is baked into the tick executables at trace time, so
+    # flipping it means a rebuild — tuning/replay.py explores it
+    # offline like kv_dtype/page_size.
     paged_kernel: Optional[bool] = None
     # Tensor parallelism (docs/serving.md "Tensor-parallel replicas"):
     # tp > 1 runs EVERY compiled tick body under GSPMD over a tp mesh
@@ -717,22 +722,42 @@ class InferenceEngine:
         # hit the same compiled program — so the zero-decode-recompile
         # guard holds under tp unchanged.
         # Fused paged-attention kernel engagement (paged_kernel knob):
-        # resolved HERE, once, to a Python bool — it is closed over by
-        # the tick bodies below at trace time, so engagement can never
-        # cause a steady-state recompile (flipping it is a rebuild, the
-        # same contract as kv_dtype/page_size).  None = auto: engage on
-        # a real TPU backend only — the CPU interpreter runs the kernel
-        # body faithfully but far slower than the unfused XLA path, so
-        # auto keeps CPU ticks (and the tier-1 suite) on the fallback
-        # while tests opt in explicitly with paged_kernel=True.
+        # decided HERE, once, to a Python bool the tick bodies below
+        # close over at trace time — so engagement can never cause a
+        # steady-state recompile, and /stats reports what the ticks
+        # were actually built on.  Where the kernel would be COMPILED
+        # (a TPU backend) the real pool layouts must pass the
+        # compiler's tiling gate (ops.paged_attention.kernel_supported):
+        # auto (None) then engages iff they do; an explicit True on a
+        # layout the gate rejects is a typed error, never a quiet
+        # reference path.  Under the CPU interpreter any layout runs,
+        # and auto stays on the unfused XLA tick (the interpreter is
+        # faithful but slow) while tests opt in with paged_kernel=True.
+        self._paged_kernel = False
         if engine_cfg.paged:
-            from horovod_tpu.ops._pallas_util import PALLAS_AVAILABLE
-            _want = (engine_cfg.paged_kernel
-                     if engine_cfg.paged_kernel is not None
-                     else jax.default_backend() == "tpu")
-            self._paged_kernel = bool(_want) and PALLAS_AVAILABLE
-        else:
-            self._paged_kernel = False
+            from horovod_tpu.ops import _pallas_util
+            from horovod_tpu.ops import paged_attention as _pa
+
+            layouts = [(self.slots._storage_dtype, engine_cfg.page_size,
+                        cfg.head_dim)]
+            if self._spec_model:
+                layouts.append((draft_cfg.dtype, engine_cfg.page_size,
+                                draft_cfg.head_dim))
+            compiled = not _pallas_util.use_interpret()
+            rejected = [lay for lay in layouts
+                        if not _pa.kernel_supported(*lay)] if compiled else []
+            want = engine_cfg.paged_kernel
+            if want and rejected:
+                dt, ps, dh = rejected[0]
+                raise _pa.UnsupportedPagedLayoutError(
+                    f"paged_kernel=True, but the TPU compiler cannot tile "
+                    f"a {jnp.dtype(dt).name} pool with page_size={ps}, "
+                    f"head_dim={dh} (needs head_dim % 128 == 0 and "
+                    f"page_size % 8/16/32 == 0 for f32/bf16/int8 "
+                    f"storage); use paged_kernel=None for the unfused "
+                    f"tick or change page_size")
+            self._paged_kernel = (compiled and not rejected
+                                  if want is None else bool(want))
         _pk = self._paged_kernel
         _pk_mesh = self.mesh if (_pk and engine_cfg.tp > 1) else None
 
@@ -1786,7 +1811,7 @@ class InferenceEngine:
                 obs_tracing.record_compile("serving_draft_prefill")
                 cache = T.init_cache(dcfg, k, bucket)
                 return T.prefill(params, padded, cache, dcfg,
-                                 true_len=true_lens)
+                                 true_len=true_lens, mesh=self.mesh)
 
             fn = self._jit(
                 _prefill,
@@ -2295,7 +2320,7 @@ class InferenceEngine:
                 obs_tracing.record_compile("serving_prefill")
                 cache = T.init_cache(self.cfg, k, bucket)
                 return T.prefill(params, padded, cache, self.cfg,
-                                 true_len=true_lens)
+                                 true_len=true_lens, mesh=self.mesh)
 
             fn = self._jit(
                 _prefill,
@@ -3728,8 +3753,9 @@ class InferenceEngine:
                 "kv_pages_high_water": self.slots.pages_high_water,
                 "prefixes_registered": len(self._prefixes),
                 # Whether the decode/draft/verify ticks were built on
-                # the fused Pallas paged-attention kernel (resolved at
-                # construction from EngineConfig.paged_kernel; see
+                # the fused Pallas paged-attention kernel — what RAN,
+                # not the flag: resolved at construction from
+                # EngineConfig.paged_kernel AND the pool layout (see
                 # docs/serving.md "Paged decode kernel").
                 "paged_kernel_engaged": self._paged_kernel,
             } if self.engine_cfg.paged else {}),

@@ -212,10 +212,14 @@ def main(argv=None) -> int:
 
         ensure_devices(args.tp)
 
-    from horovod_tpu import serving
+    from horovod_tpu import place_compile_cache, serving
     from horovod_tpu.serving.router.supervisor import (
         EXIT_CODE_REPLICA_FAILED,
     )
+
+    # A respawned generation (or a sibling replica) finds the first
+    # one's warmup compiles instead of paying them again.
+    place_compile_cache()
 
     if args.spans:
         from horovod_tpu.obs import tracing as obs_tracing

@@ -41,6 +41,7 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional, Sequence
 
+from horovod_tpu.runner import chips
 from horovod_tpu.runner.run_func import _free_port
 from horovod_tpu.serving.router.registry import (
     ReplicaEndpoint,
@@ -85,11 +86,9 @@ class ReplicaSpec:
     #: tensor-parallel degree per replica (docs/serving.md
     #: "Tensor-parallel replicas"): each replica process owns a tp-
     #: device GSPMD mesh.  The supervisor hands every SLOT a DISJOINT
-    #: device set — accelerator hosts via the visible-devices envs
-    #: (CUDA_VISIBLE_DEVICES / TPU_VISIBLE_DEVICES: slot s gets
-    #: ordinals [s*tp, (s+1)*tp), filled only when the operator has
-    #: not pinned them; multi-host TPU topologies additionally need
-    #: operator-set TPU_PROCESS_BOUNDS — out of scope here), CPU
+    #: device set — TPU hosts through runner/chips.py (slot s owns
+    #: chips [s*tp, (s+1)*tp) as a one-process slice of its own;
+    #: tp=1 replicas get one chip each the same way), CPU
     #: hosts via forced host-device partitioning (each process's
     #: virtual devices are private to it by construction) — so N tp-K
     #: replicas coexist behind the same router with failover/resume/
@@ -447,25 +446,29 @@ class ReplicaSupervisor:
             os.path.dirname(os.path.abspath(__file__)))))
         env["PYTHONPATH"] = (pkg_root + os.pathsep + env["PYTHONPATH"]
                              if env.get("PYTHONPATH") else pkg_root)
-        # Tensor-parallel replicas get a DISJOINT device set per SLOT
-        # (stable across respawns — a respawned generation inherits
-        # its slot's devices, never a survivor's): accelerator hosts
-        # via the visible-devices env, CPU hosts via the forced-host-
-        # device flag (each process's virtual devices are private to
-        # it, so disjointness is by construction).  An operator who
-        # already pinned the env wins — the supervisor only fills
-        # blanks.
+        # Every replica owns a DISJOINT chip set per SLOT (stable across
+        # respawns — a respawned generation inherits its slot's chips,
+        # never a survivor's): on a TPU host tp chips each through the
+        # one chip-partition helper (runner/chips.py) — tp=1 replicas
+        # included, or N of them would all claim every chip; on a CPU
+        # host tp > 1 gets the forced-host-device flag (each process's
+        # virtual devices are private to it).
         spec = self.slot_spec(slot)
         tp = getattr(spec, "tp", 1) if not callable(spec) else 1
-        if tp > 1:
+        n_chips = chips.usable_chips(env)
+        if n_chips:
+            if (slot + 1) * tp > n_chips:
+                raise chips.ChipPartitionError(
+                    f"replica slot {slot} (tp={tp}) needs chips up to "
+                    f"{(slot + 1) * tp - 1}, but this host has {n_chips}; "
+                    f"a chip belongs to one replica")
+            env.update(chips.chip_env(slot, self.n_replicas,
+                                      chips_per_proc=tp, one_job=False))
+        elif tp > 1:
             flag = "--xla_force_host_platform_device_count"
             if flag not in env.get("XLA_FLAGS", ""):
                 env["XLA_FLAGS"] = (
                     f"{env.get('XLA_FLAGS', '')} {flag}={tp}".strip())
-            ordinals = ",".join(str(slot * tp + i) for i in range(tp))
-            for var in ("CUDA_VISIBLE_DEVICES", "TPU_VISIBLE_DEVICES"):
-                if var not in env:
-                    env[var] = ordinals
         prev = self._handles.get(slot)
         restarts = prev.restarts + 1 if prev is not None else 0
         journal_path = self._arm_gen_file(

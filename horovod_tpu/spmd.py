@@ -22,36 +22,14 @@ from jax.sharding import PartitionSpec as P
 
 from horovod_tpu import basics
 
-try:  # jax >= 0.8 stable API (or the _compat re-export on older jax)
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# The replication-check kwarg was renamed check_rep -> check_vma when
-# shard_map was promoted to the stable namespace; sniff the signature
-# rather than the attribute location (horovod_tpu._compat re-exports the
-# experimental one as jax.shard_map on older runtimes).
-import inspect as _inspect
-
-try:
-    _SHARD_MAP_KW = "check_vma" in _inspect.signature(_shard_map).parameters
-except (TypeError, ValueError):  # pragma: no cover - exotic wrappers
-    _SHARD_MAP_KW = True
-
-
 def shard(fn, *, in_specs, out_specs, mesh=None, check_replication: bool = False):
-    """``shard_map`` over the horovod mesh with version-portable kwargs."""
-    mesh = mesh or basics.mesh()
-    if _SHARD_MAP_KW:
-        return _shard_map(
-            fn,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_vma=check_replication,
-        )
-    return _shard_map(  # pragma: no cover - older jax
-        fn, mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_replication
+    """``jax.shard_map`` over the horovod mesh."""
+    return jax.shard_map(
+        fn,
+        mesh=mesh or basics.mesh(),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        check_vma=check_replication,
     )
 
 

@@ -1,0 +1,249 @@
+"""PR 21 bring-up guards: the chip smoke's phases at toy size, and the
+rules that keep a run from quietly leaving the device.
+
+Everything here runs on CPU in seconds.  ``chip_smoke.py`` itself only
+passes on a TPU; its phases are plain functions of a ``SmokeConfig``, so
+the same code is exercised here with the library interpreting the same
+kernel bodies.
+"""
+
+import os
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+import horovod_tpu
+from horovod_tpu import serving
+from horovod_tpu.models import transformer as T
+from horovod_tpu.ops import _pallas_util
+from horovod_tpu.ops import paged_attention as PA
+from horovod_tpu.runner import chips
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+
+
+def _spawn(code_or_script, env_overrides, *, script=False):
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_PLATFORMS", "JAX_COMPILATION_CACHE_DIR",
+                        "XLA_FLAGS")}
+    env["PYTHONPATH"] = REPO
+    env.update(env_overrides)
+    argv = ([sys.executable, code_or_script] if script
+            else [sys.executable, "-c", code_or_script])
+    return subprocess.Popen(argv, cwd=REPO, env=env, text=True,
+                            stdout=subprocess.PIPE, stderr=subprocess.PIPE)
+
+
+def test_smoke_phases_toy_size_on_cpu():
+    """(a) every phase of the smoke — kernels vs references, the DP
+    trainer over the virtual devices, the HTTP server, tp serving — at
+    toy size, kernels interpreted (``expect_compiled=False`` is the only
+    difference from the chip run; the script has no such mode)."""
+    smoke = chip_smoke.SmokeConfig(
+        vocab_size=64, d_model=64, n_heads=4, n_kv_heads=2, n_layers=2,
+        d_ff=128, seq=64, dtype="float32", batch_per_chip=1,
+        train_steps=3, learning_rate=1e-2, n_slots=2, serve_max_len=48,
+        prompt_lens=(3, 17), max_new_tokens=4, logit_tol=1e-3,
+        expect_compiled=False)
+    report = chip_smoke.run(smoke, tp=2)
+    assert report["train"]["losses"][-1] < report["train"]["losses"][0]
+    assert report["train"]["chips"] == horovod_tpu.size() > 1
+    for key in ("serve", "serve_tp"):
+        assert report[key]["paged_kernel_engaged"] is True
+        assert report[key]["oracle_positions_checked"] > 0
+    assert "tp=2" in report["serve_tp"]["mesh"]
+
+
+def test_smoke_compiled_proof_rejects_an_interpreted_run():
+    """The chip run's proof is not vacuous: on this CPU backend the same
+    check (expect_compiled=True) refuses an executable without the
+    Mosaic custom call."""
+    smoke = chip_smoke.SmokeConfig()
+    with pytest.raises(chip_smoke.SmokeFailure, match="interpreted"):
+        chip_smoke._require_compiled(smoke, "HloModule m", 1, "probe")
+
+
+def test_no_chip_means_nonzero_exit_and_no_result():
+    """(b) with the platform pinned to a TPU this sandbox does not have,
+    ``hvd.init()``, ``chip_smoke.py`` and ``bench.py`` each exit non-zero
+    within seconds, print no result line, and never continue on CPU."""
+    tpu = {"JAX_PLATFORMS": "tpu"}
+    procs = {
+        "init": _spawn("import horovod_tpu as hvd; hvd.init(); "
+                       "print('RESULT', hvd.size())", tpu),
+        "smoke": _spawn("chip_smoke.py", tpu, script=True),
+        "bench": _spawn("bench.py", tpu, script=True),
+        # no platform pinned: JAX finds only the CPU — still no result
+        "smoke_cpu": _spawn("chip_smoke.py", {"JAX_PLATFORMS": "cpu"},
+                            script=True),
+        "bench_cpu": _spawn("bench.py", {"JAX_PLATFORMS": "cpu"},
+                            script=True),
+    }
+    for name, proc in procs.items():
+        out, err = proc.communicate(timeout=120)
+        assert proc.returncode != 0, (name, out, err)
+        assert "falling back" not in (out + err).lower(), (name, err)
+        assert "RESULT" not in out and '"ok"' not in out \
+            and '"metric"' not in out, (name, out)
+
+
+def test_compile_cache_placed_from_outside_or_at_one_fixed_path(tmp_path):
+    """(c) with JAX_COMPILATION_CACHE_DIR set the program sets no
+    directory in code; without it, two fresh processes agree on one
+    path inside the checkout."""
+    code = ("import jax, horovod_tpu as hvd; "
+            "before = jax.config.jax_compilation_cache_dir; "
+            "print(hvd.place_compile_cache()); "
+            "print(before); print(jax.config.jax_compilation_cache_dir)")
+    outside = str(tmp_path / "placed")
+    procs = [_spawn(code, {"JAX_PLATFORMS": "cpu"}),
+             _spawn(code, {"JAX_PLATFORMS": "cpu"}),
+             _spawn(code, {"JAX_PLATFORMS": "cpu",
+                           "JAX_COMPILATION_CACHE_DIR": outside})]
+    outs = []
+    for proc in procs:
+        out, err = proc.communicate(timeout=120)
+        assert proc.returncode == 0, err
+        outs.append(out.split())
+    inside = os.path.join(REPO, ".jax_cache")
+    assert outs[0] == outs[1] == [inside, "None", inside]
+    # placed from outside: JAX read the variable itself, and the call
+    # changed nothing
+    assert outs[2] == [outside, outside, outside]
+
+
+@pytest.fixture()
+def toy_model():
+    cfg = T.TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=4, n_layers=1, d_ff=64,
+        max_seq=48, dtype="float32", n_kv_heads=2)
+    import jax
+
+    return T.init_params(jax.random.PRNGKey(0), cfg), cfg
+
+
+def test_kernel_gate_is_the_compilers_rule():
+    assert PA.kernel_supported("bfloat16", 16, 128)
+    assert PA.kernel_supported("int8", 32, 128)
+    assert PA.kernel_supported("float32", 8, 256)
+    # the engine default page_size=16 with int8 storage: int8 tiles are
+    # 32 sublanes deep
+    assert not PA.kernel_supported("int8", 16, 128)
+    assert not PA.kernel_supported("bfloat16", 16, 64)
+    assert not PA.kernel_supported("bfloat16", 8, 128)
+
+
+def test_engine_kernel_engagement_where_it_would_be_compiled(
+        toy_model, monkeypatch):
+    """(d) where the kernel would be COMPILED, engagement follows the
+    compiler's gate on the real pool layout: explicit True on a rejected
+    layout is a typed error at construction, auto takes the unfused tick
+    and /stats says what ran."""
+    params, cfg = toy_model  # head_dim 8: nothing the TPU can tile
+    monkeypatch.setattr(_pallas_util, "use_interpret", lambda: False)
+    with pytest.raises(serving.UnsupportedPagedLayoutError,
+                       match="head_dim=8"):
+        serving.InferenceEngine(
+            params, cfg, serving.EngineConfig(n_slots=2, paged_kernel=True))
+    engine = serving.InferenceEngine(
+        params, cfg, serving.EngineConfig(n_slots=2))  # auto
+    assert engine.stats()["paged_kernel_engaged"] is False
+    # ... and the unfused tick it resolved to serves requests
+    fut = engine.submit([1, 2, 3], max_new_tokens=3)
+    while not fut.done():
+        engine.step()
+    want = np.asarray(T.greedy_decode(
+        params, np.asarray([[1, 2, 3]], np.int32), 3, cfg))[0].tolist()
+    assert fut.result() == want
+    assert engine.stats()["paged_kernel_engaged"] is False
+
+
+def test_pallas_interprets_on_cpu_only(monkeypatch):
+    import jax
+
+    assert _pallas_util.use_interpret() is True  # this suite runs on CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert _pallas_util.use_interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="not supported"):
+        _pallas_util.use_interpret()
+
+
+class TestChipEnv:
+    """(e) which chips a child owns, as a pure function."""
+
+    def test_ranks_of_one_job_get_one_chip_each(self):
+        envs = [chips.chip_env(i, 4, one_job=True) for i in range(4)]
+        assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+        assert [e["CLOUD_TPU_TASK_ID"] for e in envs] == ["0", "1", "2", "3"]
+        assert {e["TPU_PROCESS_BOUNDS"] for e in envs} == {"2,2,1"}
+        assert {e["TPU_CHIPS_PER_PROCESS_BOUNDS"] for e in envs} == {"1,1,1"}
+        # every rank names the same four addresses, and listens on its own
+        addrs = {e["TPU_PROCESS_ADDRESSES"] for e in envs}
+        assert len(addrs) == 1 and len(addrs.pop().split(",")) == 4
+        assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 4
+        assert envs == [chips.chip_env(i, 4, one_job=True)
+                        for i in range(4)]  # pure
+
+    def test_replicas_are_slices_of_their_own(self):
+        a = chips.chip_env(0, 2, chips_per_proc=2, one_job=False)
+        b = chips.chip_env(1, 2, chips_per_proc=2, one_job=False)
+        assert (a["TPU_VISIBLE_CHIPS"], b["TPU_VISIBLE_CHIPS"]) == \
+            ("0,1", "2,3")
+        for e in (a, b):
+            assert e["TPU_PROCESS_BOUNDS"] == "1,1,1"
+            assert e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,2,1"
+            assert e["CLOUD_TPU_TASK_ID"] == "0"
+            assert e["TPU_PROCESS_ADDRESSES"] == \
+                f"localhost:{e['TPU_PROCESS_PORT']}"
+        assert a["TPU_PROCESS_PORT"] != b["TPU_PROCESS_PORT"]
+        one = chips.chip_env(3, 4, one_job=False)  # tp=1 replicas too
+        assert one["TPU_VISIBLE_CHIPS"] == "3"
+
+    def test_refuses_what_it_cannot_partition(self, monkeypatch):
+        with pytest.raises(chips.ChipPartitionError):
+            chips.chip_env(0, 3, one_job=True)
+        with pytest.raises(chips.ChipPartitionError):
+            chips.chip_env(0, 2, chips_per_proc=2, one_job=True)
+        # a four-chip host: -np 8 is refused before anything is spawned,
+        # naming the single-process shape
+        monkeypatch.setattr(chips, "local_tpu_chips", lambda: 4)
+        with pytest.raises(chips.ChipPartitionError, match="-np 1"):
+            chips.local_rank_envs(8, {})
+        assert [e["TPU_VISIBLE_CHIPS"]
+                for e in chips.local_rank_envs(4, {})] == ["0", "1", "2", "3"]
+        # nothing to partition: one rank owns every chip; a CPU-pinned
+        # job opens none
+        assert chips.local_rank_envs(1, {}) == [{}]
+        assert chips.local_rank_envs(4, {"JAX_PLATFORMS": "cpu"}) == [{}] * 4
+        assert chips.usable_chips({"JAX_PLATFORMS": "tpu,cpu"}) == 4
+
+    def test_launcher_hands_each_local_rank_its_chip(self, monkeypatch):
+        """The launcher's spawn path: four local ranks on a (pretend)
+        four-chip host each start with a different chip."""
+        from horovod_tpu.runner import launch
+        from horovod_tpu.runner.hosts import HostSpec, allocate
+
+        monkeypatch.setattr(chips, "local_tpu_chips", lambda: 4)
+        seen = {}
+
+        def fake_exec(cmd, env, **kw):
+            seen[env["HOROVOD_RANK"]] = env.get("TPU_VISIBLE_CHIPS")
+            return 0
+
+        threads, codes = launch.spawn_ranks(
+            ["true"], allocate([HostSpec("localhost", 0)] * 4), {},
+            "127.0.0.1", 1, _executor=fake_exec)
+        for t in threads:
+            t.join(10)
+        assert codes == [0, 0, 0, 0]
+        assert seen == {"0": "0", "1": "1", "2": "2", "3": "3"}
+        with pytest.raises(chips.ChipPartitionError):
+            launch.spawn_ranks(
+                ["true"], allocate([HostSpec("localhost", 0)] * 8), {},
+                "127.0.0.1", 1, _executor=fake_exec)
