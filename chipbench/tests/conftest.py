@@ -1,0 +1,10 @@
+"""CPU only: these tests never need (or load) the TPU library."""
+
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+TOY = os.path.join(os.path.dirname(os.path.abspath(__file__)), "toy")

@@ -1,0 +1,70 @@
+"""The trace reduction on hand-made intervals and on a recorded trace."""
+
+import os
+
+import pytest
+
+from chipbench import xplane
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+
+def test_union_and_subtract():
+    assert xplane.union([(3, 4), (0, 1), (0.5, 2)]) == [(0, 2), (3, 4)]
+    assert xplane.total(xplane.union([(0, 1), (0.5, 2)])) == 2
+    assert xplane.subtract([(0, 10)], [(1, 2), (4, 6)]) \
+        == [(0, 1), (2, 4), (6, 10)]
+    assert xplane.subtract([(0, 2), (5, 6)], [(1, 5.5)]) \
+        == [(0, 1), (5.5, 6)]
+
+
+def _planes():
+    ops0 = [("fusion.1", 0.0, 1.0), ("all-reduce.3", 1.0, 1.5),
+            ("custom-call.7", 2.0, 3.0)]
+    # chip 1: the collective is half hidden behind a fusion
+    ops1 = [("fusion.1", 0.0, 1.25), ("all-reduce.3", 1.0, 1.5),
+            ("custom-call.7", 2.0, 3.0)]
+    host = [("chipbench:loss_fetch", 1.4, 2.1), ("other", 0.0, 3.0)]
+    return {"/device:TPU:0": {"XLA Ops": ops0, "Steps": [("s", 0, 3)]},
+            "/device:TPU:1": {"XLA Ops": ops1},
+            "/host:CPU": {"main": host}}
+
+
+def test_summarise_by_hand():
+    s = xplane.summarise(_planes())
+    assert s["chips"] == 2 and s["window_s"] == 3.0
+    assert s["busy_s"] == pytest.approx((2.5 + 2.5) / 2)
+    assert s["exposed_collective_s"] == pytest.approx((0.5 + 0.25) / 2)
+    assert xplane.op_seconds(s, r"custom-call") == pytest.approx(1.0)
+    assert s["device_ops"][0][0] in ("fusion.1", "custom-call.7")
+    # the one idle gap (1.5 -> 2.0) falls under the benchmark's own span
+    assert s["idle_gaps"] == [["loss_fetch", pytest.approx(0.5)]]
+    idle_pct = 100 * (1 - s["busy_s"] / s["window_s"])
+    assert idle_pct == pytest.approx(100 / 6)
+
+
+def test_nested_operations_count_once():
+    evs = [("while.1", 0.0, 4.0), ("fusion.2", 0.5, 1.5),
+           ("all-reduce.9", 1.5, 2.5), ("fusion.2", 3.0, 4.0)]
+    own = dict()
+    for n, sec in xplane.self_seconds(evs):
+        own[n] = own.get(n, 0.0) + sec
+    assert own == {"while.1": 1.0, "fusion.2": 2.0, "all-reduce.9": 1.0}
+    s = xplane.summarise({"/device:TPU:0": {"XLA Ops": evs}})
+    assert s["busy_s"] == 4.0
+    # the while does not hide the collective inside it
+    assert s["exposed_collective_s"] == pytest.approx(1.0)
+
+
+def test_no_device_operation_gives_nothing():
+    assert xplane.summarise({"/host:CPU": {"main": [("x", 0, 1)]}}) is None
+
+
+def test_recorded_trace():
+    path = os.path.join(HERE, "data", "tiny_v5e.xplane.pb")
+    if not os.path.exists(path):
+        pytest.skip("no recorded trace in this checkout")
+    s = xplane.summarise(xplane.load(path))
+    assert s is not None and s["chips"] >= 1
+    assert 0 < s["busy_s"] <= s["window_s"]
+    assert s["device_ops"] and len(s["device_ops"]) <= 10
