@@ -198,6 +198,35 @@ def batch_specs() -> Dict:
 # --- forward -----------------------------------------------------------------
 
 
+#: The flat vocabulary of device scopes (``jax.named_scope``) the compiled
+#: bodies here, in ``serving/cache.py`` and in ``optim.py`` are cut into.
+#: A scope is trace-time metadata: it becomes a component of every
+#: operation's ``op_name`` (backward operations carry it inside
+#: ``transpose(jvp(<scope>))``), which a profiler trace reports per
+#: device operation — so device time is attributed to a phase of the
+#: program by name.  The Pallas kernels carry names of their own
+#: (``pl.pallas_call(name=)``: ``hvd_paged_attend``, ``hvd_flash_fwd``,
+#: ``hvd_flash_bwd_dq``, ``hvd_flash_bwd_dkv``).
+DEVICE_SCOPES = (
+    "embed",          # token-embedding lookup
+    "layer_scan",     # the scan over layers' own slicing and stacking
+    "attn_qkv",       # pre-attention norm, q/k/v projections, RoPE
+    "kv_write",       # a decode tick's K/V into the cache or page pool
+    "paged_attend",   # decode attention over pages (kernel or gather)
+    "attn",           # whole-sequence attention (training, prefill)
+    "landed_gather",  # a slot's landed pages read back as a prefix block
+    "chunk_attn",     # a prompt chunk attending prefix + itself
+    "kv_land",        # prefilled K/V landing in the cache or page pool
+    "attn_out",       # attention output projection
+    "mlp",            # pre-MLP norm, the MLP (or MoE), its residual
+    "head",           # final norm + vocabulary projection
+    "sample",         # next-token pick from the logits
+    "loss",           # cross-entropy
+    "grad_allreduce",  # optim.py: the gradient collectives
+    "opt_update",     # optim.py: the inner optimizer update
+)
+
+
 def _rmsnorm(x, scale):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
     out = x.astype(jnp.float32) * jax.lax.rsqrt(var + 1e-6)
@@ -232,21 +261,44 @@ def _rope(q, k, theta: float, pos_offset=0, positions=None):
     return rot(q), rot(k)
 
 
+def _scan_layers(layer, init, xs):
+    """``lax.scan`` over the stacked layers, under the ``layer_scan``
+    scope: the scan's OWN operations — slicing each layer's parameters
+    and cache out of the stacked arrays, stacking the results back —
+    carry no other scope, so a profile tells them from the layer's."""
+    with jax.named_scope("layer_scan"):
+        return lax.scan(layer, init, xs)
+
+
+def _embed(params, tokens, cfg: TransformerConfig):
+    with jax.named_scope("embed"):
+        return params["embed"].astype(cfg.dtype)[tokens]
+
+
+def _attn_norm(x, p):
+    """The pre-attention norm, under the scope of the projections it
+    feeds."""
+    with jax.named_scope("attn_qkv"):
+        return _rmsnorm(x, p["ln1"])
+
+
 def _qkv_proj(x, p, cfg: TransformerConfig, pos_offset=0, positions=None):
     """Project to per-head Q/K/V with RoPE applied -> head-major
     ``(B, H, S, Dh)`` / ``(B, H_kv, S, Dh)`` (shared by the training
     attention, prefill, and decode paths so the math cannot drift)."""
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(cfg.dtype))
-    k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(cfg.dtype))
-    v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(cfg.dtype))
-    q, k = _rope(q, k, cfg.rope_theta, pos_offset, positions=positions)
-    return (jnp.moveaxis(q, 2, 1), jnp.moveaxis(k, 2, 1),
-            jnp.moveaxis(v, 2, 1))
+    with jax.named_scope("attn_qkv"):
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(cfg.dtype))
+        k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(cfg.dtype))
+        v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(cfg.dtype))
+        q, k = _rope(q, k, cfg.rope_theta, pos_offset, positions=positions)
+        return (jnp.moveaxis(q, 2, 1), jnp.moveaxis(k, 2, 1),
+                jnp.moveaxis(v, 2, 1))
 
 
 def _out_proj(oh, p, cfg: TransformerConfig):
-    o = jnp.moveaxis(oh, 1, 2).astype(cfg.dtype)  # (B, S, H, Dh)
-    return jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(cfg.dtype))
+    with jax.named_scope("attn_out"):
+        o = jnp.moveaxis(oh, 1, 2).astype(cfg.dtype)  # (B, S, H, Dh)
+        return jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(cfg.dtype))
 
 
 def _attention(x, p, cfg: TransformerConfig):
@@ -265,28 +317,35 @@ def _attention(x, p, cfg: TransformerConfig):
         positions = attn.zigzag_positions(S, "sp")
 
     qh, kh, vh = _qkv_proj(x, p, cfg, pos_offset, positions=positions)
+    with jax.named_scope("attn"):
+        oh = _attention_core(qh, kh, vh, cfg, attn)
+    return _out_proj(oh, p, cfg)
+
+
+def _attention_core(qh, kh, vh, cfg: TransformerConfig, attn):
+    """The whole-sequence attention ``cfg.attention_impl`` names, on
+    projected heads."""
     if cfg.attention_impl == "ring":
         # GQA shards stay small through the ring; expansion is per-chunk.
-        oh = attn.ring_attention(qh, kh, vh, axis_name="sp", causal=True)
-    elif cfg.attention_impl == "ring_zigzag":
-        oh = attn.zigzag_ring_attention(qh, kh, vh, axis_name="sp")
-    elif cfg.attention_impl == "ring_reference":
-        oh = attn.ring_attention(qh, kh, vh, axis_name="sp", causal=True,
-                                 impl="reference")
-    elif cfg.attention_impl == "ulysses":
-        oh = attn.ulysses_attention(qh, kh, vh, axis_name="sp", causal=True)
-    elif cfg.attention_impl == "flash":
-        oh = attn.flash_attention(qh, attn.expand_kv(kh, cfg.n_heads),
-                                  attn.expand_kv(vh, cfg.n_heads), True)
-    elif cfg.attention_impl == "reference":
-        oh = attn.reference_attention(qh, attn.expand_kv(kh, cfg.n_heads),
-                                      attn.expand_kv(vh, cfg.n_heads),
+        return attn.ring_attention(qh, kh, vh, axis_name="sp", causal=True)
+    if cfg.attention_impl == "ring_zigzag":
+        return attn.zigzag_ring_attention(qh, kh, vh, axis_name="sp")
+    if cfg.attention_impl == "ring_reference":
+        return attn.ring_attention(qh, kh, vh, axis_name="sp", causal=True,
+                                   impl="reference")
+    if cfg.attention_impl == "ulysses":
+        return attn.ulysses_attention(qh, kh, vh, axis_name="sp",
                                       causal=True)
-    else:
-        raise ValueError(
-            f"unknown attention_impl {cfg.attention_impl!r}; expected "
-            "'reference', 'flash', 'ring', 'ring_reference' or 'ulysses'")
-    return _out_proj(oh, p, cfg)
+    if cfg.attention_impl == "flash":
+        return attn.flash_attention(qh, attn.expand_kv(kh, cfg.n_heads),
+                                    attn.expand_kv(vh, cfg.n_heads), True)
+    if cfg.attention_impl == "reference":
+        return attn.reference_attention(
+            qh, attn.expand_kv(kh, cfg.n_heads),
+            attn.expand_kv(vh, cfg.n_heads), causal=True)
+    raise ValueError(
+        f"unknown attention_impl {cfg.attention_impl!r}; expected "
+        "'reference', 'flash', 'ring', 'ring_reference' or 'ulysses'")
 
 
 def _dense_mlp(x, p, cfg: TransformerConfig):
@@ -360,19 +419,20 @@ def _mlp_block(x, p, cfg: TransformerConfig, moe_impl: Optional[str] = None,
     behavior, not part of the serving contract.  ``return_aux`` threads
     the MoE balance loss out (0 for dense MLPs so callers can accumulate
     unconditionally)."""
-    m = _rmsnorm(x, p["ln2"])
-    if cfg.n_experts > 1:
-        out = _moe_mlp(m, p, cfg, impl=moe_impl, return_aux=return_aux)
-        if return_aux:
-            y, aux = out
-            return x + y, aux
-        return x + out
-    y = x + _dense_mlp(m, p, cfg)
-    return (y, jnp.float32(0.0)) if return_aux else y
+    with jax.named_scope("mlp"):
+        m = _rmsnorm(x, p["ln2"])
+        if cfg.n_experts > 1:
+            out = _moe_mlp(m, p, cfg, impl=moe_impl, return_aux=return_aux)
+            if return_aux:
+                y, aux = out
+                return x + y, aux
+            return x + out
+        y = x + _dense_mlp(m, p, cfg)
+        return (y, jnp.float32(0.0)) if return_aux else y
 
 
 def _layer_body(x, p, cfg: TransformerConfig, return_aux: bool = False):
-    x = x + _attention(_rmsnorm(x, p["ln1"]), p, cfg)
+    x = x + _attention(_attn_norm(x, p), p, cfg)
     return _mlp_block(x, p, cfg, return_aux=return_aux)
 
 
@@ -389,18 +449,20 @@ def _remat(layer, cfg: TransformerConfig):
 def _lm_head(y, ln_f, head, cfg: TransformerConfig):
     """Final RMSNorm + vocabulary projection (f32 logits) — the ONE copy
     shared by forward, decode/prefill, and both pipeline schedules."""
-    h = _rmsnorm(y, ln_f)
-    return jnp.einsum("bsd,dv->bsv", h, head.astype(cfg.dtype)).astype(
-        jnp.float32)
+    with jax.named_scope("head"):
+        h = _rmsnorm(y, ln_f)
+        return jnp.einsum("bsd,dv->bsv", h, head.astype(cfg.dtype)).astype(
+            jnp.float32)
 
 
 def _xent_sum(logits, targets):
     """SUM of next-token cross-entropy over all positions (divide by the
     token count for a mean) — shared by loss_fn and the pipelines."""
-    logz = jax.scipy.special.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(
-        logits, targets[..., None], axis=-1).squeeze(-1)
-    return jnp.sum(logz - gold)
+    with jax.named_scope("loss"):
+        logz = jax.scipy.special.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(
+            logits, targets[..., None], axis=-1).squeeze(-1)
+        return jnp.sum(logz - gold)
 
 
 def forward(params: Dict, tokens, cfg: TransformerConfig,
@@ -410,7 +472,7 @@ def forward(params: Dict, tokens, cfg: TransformerConfig,
     ``return_aux`` additionally returns the SUM over layers of the MoE
     load-balancing auxiliary loss (0.0 for dense models) — accumulated
     in the layer-scan carry."""
-    x = params["embed"].astype(cfg.dtype)[tokens]
+    x = _embed(params, tokens, cfg)
 
     if return_aux:
         def layer(carry, p):
@@ -424,9 +486,10 @@ def forward(params: Dict, tokens, cfg: TransformerConfig,
     if cfg.remat:
         layer = _remat(layer, cfg)
     if return_aux:
-        (x, aux), _ = lax.scan(layer, (x, jnp.float32(0.0)), params["layers"])
+        (x, aux), _ = _scan_layers(layer, (x, jnp.float32(0.0)),
+                                   params["layers"])
         return _lm_head(x, params["ln_f"], params["head"], cfg), aux
-    x, _ = lax.scan(layer, x, params["layers"])
+    x, _ = _scan_layers(layer, x, params["layers"])
     return _lm_head(x, params["ln_f"], params["head"], cfg)
 
 
@@ -454,10 +517,10 @@ def expert_load(params: Dict, tokens, cfg: TransformerConfig):
     flat during training."""
     if cfg.n_experts <= 1:
         raise ValueError("expert_load needs an MoE config (n_experts > 1)")
-    x = params["embed"].astype(cfg.dtype)[tokens]
+    x = _embed(params, tokens, cfg)
 
     def layer(x, p):
-        att = x + _attention(_rmsnorm(x, p["ln1"]), p, cfg)
+        att = x + _attention(_attn_norm(x, p), p, cfg)
         m = _rmsnorm(att, p["ln2"])
         logits = (m.astype(jnp.float32).reshape(-1, cfg.d_model)
                   @ p["router"].astype(jnp.float32))
@@ -466,7 +529,7 @@ def expert_load(params: Dict, tokens, cfg: TransformerConfig):
             dtype=jnp.float32).mean(0)
         return _mlp_block(att, p, cfg), frac
 
-    _, fracs = lax.scan(layer, x, params["layers"])
+    _, fracs = _scan_layers(layer, x, params["layers"])
     return fracs
 
 
@@ -624,14 +687,15 @@ def _cache_attend(qh, k_cache, v_cache, mask):
     B, H, _, Dh = qh.shape
     Hkv = k_cache.shape[1]
     G = H // Hkv
-    qg = qh.reshape(B, Hkv, G, Dh)                  # one token: drop q dim
-    s = jnp.einsum("bkgd,bktd->bkgt", qg.astype(k_cache.dtype), k_cache,
-                   preferred_element_type=jnp.float32) / np.sqrt(Dh)
-    s = jnp.where(mask, s, -1e30)
-    w = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgt,bktd->bkgd", w.astype(v_cache.dtype), v_cache,
-                   preferred_element_type=jnp.float32)
-    return o.reshape(B, H, 1, Dh)
+    with jax.named_scope("attn"):
+        qg = qh.reshape(B, Hkv, G, Dh)              # one token: drop q dim
+        s = jnp.einsum("bkgd,bktd->bkgt", qg.astype(k_cache.dtype), k_cache,
+                       preferred_element_type=jnp.float32) / np.sqrt(Dh)
+        s = jnp.where(mask, s, -1e30)
+        w = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("bkgt,bktd->bkgd", w.astype(v_cache.dtype), v_cache,
+                       preferred_element_type=jnp.float32)
+        return o.reshape(B, H, 1, Dh)
 
 
 def _attention_decode(x, p, cfg: TransformerConfig, k_cache, v_cache, pos):
@@ -639,10 +703,11 @@ def _attention_decode(x, p, cfg: TransformerConfig, k_cache, v_cache, pos):
     at ``pos``, attend q over positions <= pos (static-shape mask; the
     attention math itself lives in :func:`_cache_attend`)."""
     qh, k_t, v_t = _qkv_proj(x, p, cfg, pos)        # qh: (B, H, 1, Dh)
-    k_cache = lax.dynamic_update_slice_in_dim(
-        k_cache, k_t.astype(k_cache.dtype), pos, axis=2)
-    v_cache = lax.dynamic_update_slice_in_dim(
-        v_cache, v_t.astype(v_cache.dtype), pos, axis=2)
+    with jax.named_scope("kv_write"):
+        k_cache = lax.dynamic_update_slice_in_dim(
+            k_cache, k_t.astype(k_cache.dtype), pos, axis=2)
+        v_cache = lax.dynamic_update_slice_in_dim(
+            v_cache, v_t.astype(v_cache.dtype), pos, axis=2)
     T = k_cache.shape[2]
     mask = (lax.broadcasted_iota(jnp.int32, (T,), 0) <= pos)
     o = _cache_attend(qh, k_cache, v_cache, mask[None, None, None, :])
@@ -670,15 +735,15 @@ def decode_step(params: Dict, tokens_t, cache: Dict, cfg: TransformerConfig):
         raise ValueError(
             f"decode_step past cache capacity (pos {int(pos)} >= "
             f"{T_cache}); init_cache with a larger max_len")
-    x = params["embed"].astype(cfg.dtype)[tokens_t][:, None]  # (B, 1, D)
+    x = _embed(params, tokens_t, cfg)[:, None]  # (B, 1, D)
 
     def layer(x, inp):
         p, k_c, v_c = inp
         h, k_new, v_new = _attention_decode(
-            _rmsnorm(x, p["ln1"]), p, cfg, k_c, v_c, pos)
+            _attn_norm(x, p), p, cfg, k_c, v_c, pos)
         return _mlp_block(x + h, p, cfg, moe_impl="dense"), (k_new, v_new)
 
-    x, (k_all, v_all) = lax.scan(
+    x, (k_all, v_all) = _scan_layers(
         layer, x, (params["layers"], cache["k"], cache["v"]))
     logits = _lm_head(x, params["ln_f"], params["head"], cfg)
     return logits[:, 0], {"k": k_all, "v": v_all, "pos": pos + 1}
@@ -696,8 +761,9 @@ def _attention_decode_slots(x, p, cfg: TransformerConfig, k_cache, v_cache,
     qh, k_t, v_t = _qkv_proj(x, p, cfg, positions=pos[:, None])
     upd = jax.vmap(
         lambda c, t, p_: lax.dynamic_update_slice_in_dim(c, t, p_, axis=1))
-    k_cache = upd(k_cache, k_t.astype(k_cache.dtype), pos)
-    v_cache = upd(v_cache, v_t.astype(v_cache.dtype), pos)
+    with jax.named_scope("kv_write"):
+        k_cache = upd(k_cache, k_t.astype(k_cache.dtype), pos)
+        v_cache = upd(v_cache, v_t.astype(v_cache.dtype), pos)
     T = k_cache.shape[2]
     mask = lax.broadcasted_iota(jnp.int32, (T,), 0)[None, :] <= pos[:, None]
     o = _cache_attend(qh, k_cache, v_cache, mask[:, None, None, :])
@@ -738,16 +804,16 @@ def decode_step_slots(params: Dict, tokens_t, cache: Dict,
                 f"decode_step_slots past cache capacity (slots "
                 f"{np.nonzero(over)[0].tolist()} at pos >= {T_cache}); "
                 "init_slot_cache with a larger max_len")
-    x = params["embed"].astype(cfg.dtype)[tokens_t][:, None]  # (S, 1, D)
+    x = _embed(params, tokens_t, cfg)[:, None]  # (S, 1, D)
     x = jnp.where(active[:, None, None], x, jnp.zeros_like(x))
 
     def layer(x, inp):
         p, k_c, v_c = inp
         h, k_new, v_new = _attention_decode_slots(
-            _rmsnorm(x, p["ln1"]), p, cfg, k_c, v_c, pos)
+            _attn_norm(x, p), p, cfg, k_c, v_c, pos)
         return _mlp_block(x + h, p, cfg, moe_impl="dense"), (k_new, v_new)
 
-    x, (k_all, v_all) = lax.scan(
+    x, (k_all, v_all) = _scan_layers(
         layer, x, (params["layers"], cache["k"], cache["v"]))
     logits = _lm_head(x, params["ln_f"], params["head"], cfg)
     return logits[:, 0], {
@@ -884,21 +950,38 @@ def _attention_decode_paged(x, p, cfg: TransformerConfig, k_pool, v_pool,
     ps = k_pool.shape[2]
     quantized = k_scale is not None
     qh, k_t, v_t = _qkv_proj(x, p, cfg, positions=pos[:, None])
-    k_t1 = k_t[:, :, 0, :]                      # (S, H_kv, Dh)
-    v_t1 = v_t[:, :, 0, :]
-    idx = jnp.clip(pos // ps, 0, max_pages - 1)
-    phys = jnp.where(active, table[jnp.arange(S), idx], 0)
-    off = pos % ps
-    if quantized:
-        qk, sk = kv_quantize(k_t1)
-        qv, sv = kv_quantize(v_t1)
-        k_pool = k_pool.at[phys, :, off, :].set(qk)
-        v_pool = v_pool.at[phys, :, off, :].set(qv)
-        k_scale = k_scale.at[phys, :, off].set(sk)
-        v_scale = v_scale.at[phys, :, off].set(sv)
-    else:
-        k_pool = k_pool.at[phys, :, off, :].set(k_t1.astype(k_pool.dtype))
-        v_pool = v_pool.at[phys, :, off, :].set(v_t1.astype(v_pool.dtype))
+    with jax.named_scope("kv_write"):
+        k_t1 = k_t[:, :, 0, :]                      # (S, H_kv, Dh)
+        v_t1 = v_t[:, :, 0, :]
+        idx = jnp.clip(pos // ps, 0, max_pages - 1)
+        phys = jnp.where(active, table[jnp.arange(S), idx], 0)
+        off = pos % ps
+        if quantized:
+            qk, sk = kv_quantize(k_t1)
+            qv, sv = kv_quantize(v_t1)
+            k_pool = k_pool.at[phys, :, off, :].set(qk)
+            v_pool = v_pool.at[phys, :, off, :].set(qv)
+            k_scale = k_scale.at[phys, :, off].set(sk)
+            v_scale = v_scale.at[phys, :, off].set(sv)
+        else:
+            k_pool = k_pool.at[phys, :, off, :].set(
+                k_t1.astype(k_pool.dtype))
+            v_pool = v_pool.at[phys, :, off, :].set(
+                v_t1.astype(v_pool.dtype))
+    with jax.named_scope("paged_attend"):
+        o = _paged_decode_attend(qh, k_pool, v_pool, k_scale, v_scale,
+                                 table, pos, active, cfg, kernel, mesh)
+    return (_out_proj(o.astype(cfg.dtype), p, cfg),
+            k_pool, v_pool, k_scale, v_scale)
+
+
+def _paged_decode_attend(qh, k_pool, v_pool, k_scale, v_scale, table, pos,
+                         active, cfg: TransformerConfig, kernel, mesh):
+    """The attend tail of :func:`_attention_decode_paged` (after the
+    write): the fused kernel, or gather -> dequant -> ``_cache_attend``."""
+    max_pages = table.shape[1]
+    ps = k_pool.shape[2]
+    quantized = k_scale is not None
     B, H, _, Dh = qh.shape
     if kernel:
         # Fused path: attend positions <= pos ⇔ logical < pos + 1,
@@ -923,8 +1006,7 @@ def _attention_decode_paged(x, p, cfg: TransformerConfig, k_pool, v_pool,
         mask = (lax.broadcasted_iota(jnp.int32, (T,), 0)[None, :]
                 <= pos[:, None])
         o = _cache_attend(qh, kg, vg, mask[:, None, None, :])
-    return (_out_proj(o.astype(cfg.dtype), p, cfg),
-            k_pool, v_pool, k_scale, v_scale)
+    return o
 
 
 def decode_step_paged(params: Dict, tokens_t, pool: Dict, table,
@@ -964,7 +1046,7 @@ def decode_step_paged(params: Dict, tokens_t, pool: Dict, table,
                 f"decode_step_paged past table capacity (slots "
                 f"{np.nonzero(over)[0].tolist()} at pos >= {T_cap}); "
                 "init_page_pool with more pages per slot")
-    x = params["embed"].astype(cfg.dtype)[tokens_t][:, None]  # (S, 1, D)
+    x = _embed(params, tokens_t, cfg)[:, None]  # (S, 1, D)
     x = jnp.where(active[:, None, None], x, jnp.zeros_like(x))
     quantized = "k_scale" in pool
 
@@ -974,7 +1056,7 @@ def decode_step_paged(params: Dict, tokens_t, pool: Dict, table,
         else:
             (p, k_c, v_c), ks_c, vs_c = inp, None, None
         h, k_new, v_new, ks_new, vs_new = _attention_decode_paged(
-            _rmsnorm(x, p["ln1"]), p, cfg, k_c, v_c, ks_c, vs_c,
+            _attn_norm(x, p), p, cfg, k_c, v_c, ks_c, vs_c,
             table, pos, active, kernel=kernel, mesh=mesh)
         out = (k_new, v_new) + ((ks_new, vs_new) if quantized else ())
         return _mlp_block(x + h, p, cfg, moe_impl="dense"), out
@@ -982,7 +1064,7 @@ def decode_step_paged(params: Dict, tokens_t, pool: Dict, table,
     xs = (params["layers"], pool["k"], pool["v"])
     if quantized:
         xs = xs + (pool["k_scale"], pool["v_scale"])
-    x, new = lax.scan(layer, x, xs)
+    x, new = _scan_layers(layer, x, xs)
     logits = _lm_head(x, params["ln_f"], params["head"], cfg)
     out = {"k": new[0], "v": new[1],
            "pos": pos + active.astype(jnp.int32)}
@@ -1126,7 +1208,7 @@ def decode_verify_paged(params: Dict, window, pool: Dict, table,
     H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     G = H // Hkv
 
-    x = params["embed"].astype(cfg.dtype)[window]  # (S, W, D)
+    x = _embed(params, window, cfg)  # (S, W, D)
     x = jnp.where(active[:, None, None], x, jnp.zeros_like(x))
     positions = pos[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
     # (S, 1, 1, W, T + W) mask: committed cache strictly below pos[s]
@@ -1149,7 +1231,7 @@ def decode_verify_paged(params: Dict, window, pool: Dict, table,
             p, k_c, v_c, ks_c, vs_c = inp
         else:
             (p, k_c, v_c), ks_c, vs_c = inp, None, None
-        h = _rmsnorm(x, p["ln1"])
+        h = _attn_norm(x, p)
         qh, kh, vh = _qkv_proj(h, p, cfg, positions=positions)
         if quantized:
             qk, sk = kv_quantize(kh)
@@ -1162,62 +1244,63 @@ def decode_verify_paged(params: Dict, window, pool: Dict, table,
             vh_a = vh.astype(storage)
             ys = (kh_a, vh_a)
         qg = qh.reshape(S, Hkv, G, W, Dh)
-        if kernel:
-            # Fused kernel over the committed pages: W*G query rows per
-            # (slot, kv-head) in one pass, pre-scatter pool (same state
-            # the unfused gather reads), int8 dequant in the load.
-            o_c, lse_c = _paged_kernel_attend(
-                qg.reshape(S, Hkv, G * W, Dh), k_c, v_c, ks_c, vs_c,
-                table, climit, cfg, mesh)
-            o_c = o_c.reshape(S, Hkv, G, W, Dh)
-            lse_c = lse_c.reshape(S, Hkv, G, W)
-            # Dense causal attention within the window (post round-trip
-            # K/V), kept unnormalized alongside its own logsumexp.
-            sw = jnp.einsum("bkgsd,bktd->bkgst", qg.astype(kh_a.dtype),
-                            kh_a, preferred_element_type=jnp.float32
-                            ) / np.sqrt(Dh)
-            sw = jnp.where(wmask, sw, -1e30)
-            mw = jnp.max(sw, axis=-1)           # (S, Hkv, G, W)
-            pw = jnp.exp(sw - mw[..., None])
-            lw = jnp.sum(pw, axis=-1)           # >= 1: diagonal visible
-            o_w = jnp.einsum("bkgst,bktd->bkgsd", pw.astype(vh_a.dtype),
-                             vh_a, preferred_element_type=jnp.float32
-                             ) / lw[..., None]
-            lse_w = mw + jnp.log(lw)
-            # Cross-source LSE combine; a_c underflows to exactly 0 for
-            # rows with no committed context (lse_c == NEG_INF).
-            m = jnp.maximum(lse_c, lse_w)
-            a_c = jnp.exp(lse_c - m)
-            a_w = jnp.exp(lse_w - m)
-            o = ((a_c[..., None] * o_c + a_w[..., None] * o_w)
-                 / (a_c + a_w)[..., None])
-        else:
-            if quantized:
-                kg = kv_dequantize(_gather_pages(k_c, table),
-                                   _gather_scales(ks_c, table), cfg.dtype)
-                vg = kv_dequantize(_gather_pages(v_c, table),
-                                   _gather_scales(vs_c, table), cfg.dtype)
+        with jax.named_scope("paged_attend"):
+            if kernel:
+                # Fused kernel over the committed pages: W*G query rows per
+                # (slot, kv-head) in one pass, pre-scatter pool (same state
+                # the unfused gather reads), int8 dequant in the load.
+                o_c, lse_c = _paged_kernel_attend(
+                    qg.reshape(S, Hkv, G * W, Dh), k_c, v_c, ks_c, vs_c,
+                    table, climit, cfg, mesh)
+                o_c = o_c.reshape(S, Hkv, G, W, Dh)
+                lse_c = lse_c.reshape(S, Hkv, G, W)
+                # Dense causal attention within the window (post round-trip
+                # K/V), kept unnormalized alongside its own logsumexp.
+                sw = jnp.einsum("bkgsd,bktd->bkgst", qg.astype(kh_a.dtype),
+                                kh_a, preferred_element_type=jnp.float32
+                                ) / np.sqrt(Dh)
+                sw = jnp.where(wmask, sw, -1e30)
+                mw = jnp.max(sw, axis=-1)           # (S, Hkv, G, W)
+                pw = jnp.exp(sw - mw[..., None])
+                lw = jnp.sum(pw, axis=-1)           # >= 1: diagonal visible
+                o_w = jnp.einsum("bkgst,bktd->bkgsd", pw.astype(vh_a.dtype),
+                                 vh_a, preferred_element_type=jnp.float32
+                                 ) / lw[..., None]
+                lse_w = mw + jnp.log(lw)
+                # Cross-source LSE combine; a_c underflows to exactly 0 for
+                # rows with no committed context (lse_c == NEG_INF).
+                m = jnp.maximum(lse_c, lse_w)
+                a_c = jnp.exp(lse_c - m)
+                a_w = jnp.exp(lse_w - m)
+                o = ((a_c[..., None] * o_c + a_w[..., None] * o_w)
+                     / (a_c + a_w)[..., None])
             else:
-                kg = _gather_pages(k_c, table)
-                vg = _gather_pages(v_c, table)
-            k_full = jnp.concatenate([kg, kh_a], axis=2)  # (S,Hkv,T+W,Dh)
-            v_full = jnp.concatenate([vg, vh_a], axis=2)
-            # Grouped-query attention, W queries wide — _cache_attend's
-            # bandwidth discipline (stored dtype, f32 MXU accumulation).
-            sc = jnp.einsum("bkgsd,bktd->bkgst", qg.astype(k_full.dtype),
-                            k_full, preferred_element_type=jnp.float32
-                            ) / np.sqrt(Dh)
-            sc = jnp.where(mask, sc, -1e30)
-            w = jax.nn.softmax(sc, axis=-1)
-            o = jnp.einsum("bkgst,bktd->bkgsd", w.astype(v_full.dtype),
-                           v_full, preferred_element_type=jnp.float32)
+                if quantized:
+                    kg = kv_dequantize(_gather_pages(k_c, table),
+                                       _gather_scales(ks_c, table), cfg.dtype)
+                    vg = kv_dequantize(_gather_pages(v_c, table),
+                                       _gather_scales(vs_c, table), cfg.dtype)
+                else:
+                    kg = _gather_pages(k_c, table)
+                    vg = _gather_pages(v_c, table)
+                k_full = jnp.concatenate([kg, kh_a], axis=2)  # (S,Hkv,T+W,Dh)
+                v_full = jnp.concatenate([vg, vh_a], axis=2)
+                # Grouped-query attention, W queries wide — _cache_attend's
+                # bandwidth discipline (stored dtype, f32 MXU accumulation).
+                sc = jnp.einsum("bkgsd,bktd->bkgst", qg.astype(k_full.dtype),
+                                k_full, preferred_element_type=jnp.float32
+                                ) / np.sqrt(Dh)
+                sc = jnp.where(mask, sc, -1e30)
+                w = jax.nn.softmax(sc, axis=-1)
+                o = jnp.einsum("bkgst,bktd->bkgsd", w.astype(v_full.dtype),
+                               v_full, preferred_element_type=jnp.float32)
         out = _out_proj(o.reshape(S, H, W, Dh).astype(cfg.dtype), p, cfg)
         return _mlp_block(x + out, p, cfg, moe_impl="dense"), ys
 
     xs = (params["layers"], pool["k"], pool["v"])
     if quantized:
         xs = xs + (pool["k_scale"], pool["v_scale"])
-    x, ys = lax.scan(layer, x, xs)
+    x, ys = _scan_layers(layer, x, xs)
     logits = _lm_head(x, params["ln_f"], params["head"], cfg)  # (S,W,V)
     t = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     mx = jnp.max(logits, axis=-1)
@@ -1241,33 +1324,34 @@ def decode_verify_paged(params: Dict, window, pool: Dict, table,
     # pos[s] + j through the table iff accepted (j <= acc) and within
     # capacity; everything else — rejected drafts, inactive rows,
     # out-of-capacity positions — routes to the NULL page (physical 0).
-    j = jnp.arange(W, dtype=jnp.int32)[None, :]
-    wpos = pos[:, None] + j
-    ok = active[:, None] & (j <= acc[:, None]) & (wpos < T_cap)
-    idxp = jnp.clip(wpos // ps, 0, max_pages - 1)
-    phys = jnp.where(ok, jnp.take_along_axis(table, idxp, axis=1), 0)
-    off = wpos % ps
+    with jax.named_scope("kv_write"):
+        j = jnp.arange(W, dtype=jnp.int32)[None, :]
+        wpos = pos[:, None] + j
+        ok = active[:, None] & (j <= acc[:, None]) & (wpos < T_cap)
+        idxp = jnp.clip(wpos // ps, 0, max_pages - 1)
+        phys = jnp.where(ok, jnp.take_along_axis(table, idxp, axis=1), 0)
+        off = wpos % ps
 
-    def scatter(pool_l, vals_l):
-        # pool_l (P, Hkv, ps, Dh); vals_l (S, Hkv, W, Dh) -> indexed
-        # result dims (S, W) lead, giving (S, W, Hkv, Dh) values.
-        return pool_l.at[phys, :, off, :].set(jnp.moveaxis(vals_l, 2, 1))
+        def scatter(pool_l, vals_l):
+            # pool_l (P, Hkv, ps, Dh); vals_l (S, Hkv, W, Dh) -> indexed
+            # result dims (S, W) lead, giving (S, W, Hkv, Dh) values.
+            return pool_l.at[phys, :, off, :].set(jnp.moveaxis(vals_l, 2, 1))
 
-    def scatter_scale(scale_l, vals_l):
-        return scale_l.at[phys, :, off].set(jnp.moveaxis(vals_l, 2, 1))
+        def scatter_scale(scale_l, vals_l):
+            return scale_l.at[phys, :, off].set(jnp.moveaxis(vals_l, 2, 1))
 
-    if quantized:
-        qk, sk, qv, sv = ys
-        out = {
-            "k": jax.vmap(scatter)(pool["k"], qk),
-            "v": jax.vmap(scatter)(pool["v"], qv),
-            "k_scale": jax.vmap(scatter_scale)(pool["k_scale"], sk),
-            "v_scale": jax.vmap(scatter_scale)(pool["v_scale"], sv),
-        }
-    else:
-        kh_all, vh_all = ys
-        out = {"k": jax.vmap(scatter)(pool["k"], kh_all),
-               "v": jax.vmap(scatter)(pool["v"], vh_all)}
+        if quantized:
+            qk, sk, qv, sv = ys
+            out = {
+                "k": jax.vmap(scatter)(pool["k"], qk),
+                "v": jax.vmap(scatter)(pool["v"], qv),
+                "k_scale": jax.vmap(scatter_scale)(pool["k_scale"], sk),
+                "v_scale": jax.vmap(scatter_scale)(pool["v_scale"], sv),
+            }
+        else:
+            kh_all, vh_all = ys
+            out = {"k": jax.vmap(scatter)(pool["k"], kh_all),
+                   "v": jax.vmap(scatter)(pool["v"], vh_all)}
     out["pos"] = pos + jnp.where(active, acc + 1, 0)
     return t, mx, acc, out
 
@@ -1303,7 +1387,7 @@ def prefill_with_prefix(params: Dict, suffix, prefix_k, prefix_v,
     p0 = jnp.asarray(prefix_len, jnp.int32)
     true_len = jnp.asarray(true_len, jnp.int32)
     positions = p0 + jnp.arange(S0, dtype=jnp.int32)
-    x = params["embed"].astype(cfg.dtype)[suffix]
+    x = _embed(params, suffix, cfg)
     H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     G = H // Hkv
     # (S0, P0 + S0) mask: the real prefix is fully visible, page-tail
@@ -1316,29 +1400,30 @@ def prefill_with_prefix(params: Dict, suffix, prefix_k, prefix_v,
 
     def layer(x, inp):
         p, pk, pv = inp
-        h = _rmsnorm(x, p["ln1"])
+        h = _attn_norm(x, p)
         qh, kh, vh = _qkv_proj(h, p, cfg, positions=positions)
-        k_full = jnp.concatenate(
-            [jnp.broadcast_to(pk[None].astype(kh.dtype), (K, Hkv, P0, Dh)),
-             kh], axis=2)
-        v_full = jnp.concatenate(
-            [jnp.broadcast_to(pv[None].astype(vh.dtype), (K, Hkv, P0, Dh)),
-             vh], axis=2)
-        # Grouped-query attention with the prefix mask — the same
-        # bandwidth discipline as _cache_attend, S0 queries wide.
-        qg = qh.reshape(K, Hkv, G, S0, Dh)
-        s = jnp.einsum("bkgsd,bktd->bkgst", qg.astype(k_full.dtype),
-                       k_full, preferred_element_type=jnp.float32
-                       ) / np.sqrt(Dh)
-        s = jnp.where(mask, s, -1e30)
-        w = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bkgst,bktd->bkgsd", w.astype(v_full.dtype),
-                       v_full, preferred_element_type=jnp.float32)
-        oh = o.reshape(K, H, S0, Dh)
+        with jax.named_scope("chunk_attn"):
+            k_full = jnp.concatenate(
+                [jnp.broadcast_to(pk[None].astype(kh.dtype),
+                                  (K, Hkv, P0, Dh)), kh], axis=2)
+            v_full = jnp.concatenate(
+                [jnp.broadcast_to(pv[None].astype(vh.dtype),
+                                  (K, Hkv, P0, Dh)), vh], axis=2)
+            # Grouped-query attention with the prefix mask — the same
+            # bandwidth discipline as _cache_attend, S0 queries wide.
+            qg = qh.reshape(K, Hkv, G, S0, Dh)
+            s = jnp.einsum("bkgsd,bktd->bkgst", qg.astype(k_full.dtype),
+                           k_full, preferred_element_type=jnp.float32
+                           ) / np.sqrt(Dh)
+            s = jnp.where(mask, s, -1e30)
+            w = jax.nn.softmax(s, axis=-1)
+            o = jnp.einsum("bkgst,bktd->bkgsd", w.astype(v_full.dtype),
+                           v_full, preferred_element_type=jnp.float32)
+            oh = o.reshape(K, H, S0, Dh)
         out = _out_proj(oh.astype(cfg.dtype), p, cfg)
         return _mlp_block(x + out, p, cfg, moe_impl=moe_impl), (kh, vh)
 
-    x, (k_all, v_all) = lax.scan(
+    x, (k_all, v_all) = _scan_layers(
         layer, x, (params["layers"], prefix_k, prefix_v))
     last = jnp.take_along_axis(x, (true_len - 1)[:, None, None], axis=1)
     logits = _lm_head(last, params["ln_f"], params["head"], cfg)
@@ -1362,9 +1447,10 @@ def _attention_prefill(x, p, cfg: TransformerConfig, mesh=None):
 
     qh, kh, vh = _qkv_proj(x, p, cfg, 0)  # kh/vh: (B, H_kv, S0, Dh)
     if cfg.attention_impl == "reference":
-        oh = attn.reference_attention(
-            qh, attn.expand_kv(kh, cfg.n_heads),
-            attn.expand_kv(vh, cfg.n_heads), causal=True)
+        with jax.named_scope("attn"):
+            oh = attn.reference_attention(
+                qh, attn.expand_kv(kh, cfg.n_heads),
+                attn.expand_kv(vh, cfg.n_heads), causal=True)
         return _out_proj(oh, p, cfg), kh, vh
 
     def flash(q, k, v):
@@ -1377,7 +1463,9 @@ def _attention_prefill(x, p, cfg: TransformerConfig, mesh=None):
         head = P(None, "tp", None, None)
         flash = spmd.shard(flash, in_specs=(head, head, head),
                            out_specs=head, mesh=mesh)
-    return _out_proj(flash(qh, kh, vh), p, cfg), kh, vh
+    with jax.named_scope("attn"):
+        oh = flash(qh, kh, vh)
+    return _out_proj(oh, p, cfg), kh, vh
 
 
 def prefill(params: Dict, prompt, cache: Dict, cfg: TransformerConfig,
@@ -1418,17 +1506,17 @@ def prefill(params: Dict, prompt, cache: Dict, cfg: TransformerConfig,
         raise ValueError(
             f"prompt ({S0} tokens) exceeds cache capacity ({T_cache}); "
             "init_cache with a larger max_len")
-    x = params["embed"].astype(cfg.dtype)[prompt]
+    x = _embed(params, prompt, cfg)
 
     def layer(x, p):
-        h, kh, vh = _attention_prefill(_rmsnorm(x, p["ln1"]), p, cfg, mesh)
+        h, kh, vh = _attention_prefill(_attn_norm(x, p), p, cfg, mesh)
         # Prefill ingests whole prompts: DROPLESS grouped-matmul dispatch
         # by default — exact like dense but 1/E of its FFN FLOPs
         # (ops/moe.py dropless_moe).  Per-step decode keeps dense (a
         # handful of tokens; ragged grouping buys nothing there).
         return _mlp_block(x + h, p, cfg, moe_impl=moe_impl), (kh, vh)
 
-    x, (k_all, v_all) = lax.scan(layer, x, params["layers"])
+    x, (k_all, v_all) = _scan_layers(layer, x, params["layers"])
     # Only one position's logits are needed: slice BEFORE the (B, S0, V)
     # head projection.
     if true_len is None:
@@ -1447,13 +1535,14 @@ def prefill(params: Dict, prompt, cache: Dict, cfg: TransformerConfig,
                                    axis=1)
         new_pos = pos + true_len
     logits = _lm_head(last, params["ln_f"], params["head"], cfg)
-    cache = {
-        "k": lax.dynamic_update_slice_in_dim(
-            cache["k"], k_all.astype(cache["k"].dtype), 0, axis=3),
-        "v": lax.dynamic_update_slice_in_dim(
-            cache["v"], v_all.astype(cache["v"].dtype), 0, axis=3),
-        "pos": new_pos,
-    }
+    with jax.named_scope("kv_land"):
+        cache = {
+            "k": lax.dynamic_update_slice_in_dim(
+                cache["k"], k_all.astype(cache["k"].dtype), 0, axis=3),
+            "v": lax.dynamic_update_slice_in_dim(
+                cache["v"], v_all.astype(cache["v"].dtype), 0, axis=3),
+            "pos": new_pos,
+        }
     return logits[:, 0], cache
 
 
@@ -1486,35 +1575,36 @@ def sample_token_rows(logits, temperature, top_k, top_p, rng, positions,
     construction.  ``rng``: (R, 2) uint32 base keys; ``positions``:
     (R,) int32; ``rows``: (R,) int32 (the engine passes zeros — each
     slot is row 0 of its own per-request oracle call)."""
-    V = logits.shape[-1]
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    # Greedy rows divide by 1.0 (their sampled value is discarded by
-    # the final where, but NaN/Inf from a 0-division must never enter
-    # the softmax); sampled rows divide by their exact temperature.
-    scaled = logits / jnp.where(temperature > 0.0, temperature,
-                                1.0)[:, None]
-    srt = jnp.sort(scaled, axis=-1)[:, ::-1]            # descending
-    kth = jnp.take_along_axis(srt, (jnp.clip(top_k, 1, V) - 1)[:, None],
-                              axis=1)
-    scaled = jnp.where((top_k > 0)[:, None] & (scaled < kth),
-                       -jnp.inf, scaled)
-    probs = jax.nn.softmax(scaled, axis=-1)
-    ps = jnp.sort(probs, axis=-1)[:, ::-1]
-    csum = jnp.cumsum(ps, axis=-1)
-    # Sorted index i is in the nucleus iff the mass BEFORE it is still
-    # under top_p (index 0 always is); the smallest kept probability
-    # becomes the threshold, so threshold ties stay in.
-    keep = (csum - ps) < top_p[:, None]
-    thr = jnp.min(jnp.where(keep, ps, jnp.inf), axis=-1, keepdims=True)
-    p_on = (top_p > 0.0) & (top_p < 1.0)
-    scaled = jnp.where(p_on[:, None] & (probs < thr), -jnp.inf, scaled)
+    with jax.named_scope("sample"):
+        V = logits.shape[-1]
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        # Greedy rows divide by 1.0 (their sampled value is discarded by
+        # the final where, but NaN/Inf from a 0-division must never enter
+        # the softmax); sampled rows divide by their exact temperature.
+        scaled = logits / jnp.where(temperature > 0.0, temperature,
+                                    1.0)[:, None]
+        srt = jnp.sort(scaled, axis=-1)[:, ::-1]            # descending
+        kth = jnp.take_along_axis(srt, (jnp.clip(top_k, 1, V) - 1)[:, None],
+                                  axis=1)
+        scaled = jnp.where((top_k > 0)[:, None] & (scaled < kth),
+                           -jnp.inf, scaled)
+        probs = jax.nn.softmax(scaled, axis=-1)
+        ps = jnp.sort(probs, axis=-1)[:, ::-1]
+        csum = jnp.cumsum(ps, axis=-1)
+        # Sorted index i is in the nucleus iff the mass BEFORE it is still
+        # under top_p (index 0 always is); the smallest kept probability
+        # becomes the threshold, so threshold ties stay in.
+        keep = (csum - ps) < top_p[:, None]
+        thr = jnp.min(jnp.where(keep, ps, jnp.inf), axis=-1, keepdims=True)
+        p_on = (top_p > 0.0) & (top_p < 1.0)
+        scaled = jnp.where(p_on[:, None] & (probs < thr), -jnp.inf, scaled)
 
-    def pick(key, pos, row, lrow):
-        key = jax.random.fold_in(jax.random.fold_in(key, pos), row)
-        return jax.random.categorical(key, lrow)
+        def pick(key, pos, row, lrow):
+            key = jax.random.fold_in(jax.random.fold_in(key, pos), row)
+            return jax.random.categorical(key, lrow)
 
-    sampled = jax.vmap(pick)(rng, positions, rows, scaled)
-    return jnp.where(temperature > 0.0, sampled.astype(jnp.int32), greedy)
+        sampled = jax.vmap(pick)(rng, positions, rows, scaled)
+        return jnp.where(temperature > 0.0, sampled.astype(jnp.int32), greedy)
 
 
 def sample_decode(params: Dict, prompt, steps: int, cfg: TransformerConfig,
@@ -1607,7 +1697,7 @@ def pipelined_forward(params: Dict, tokens, cfg: TransformerConfig, *,
     B = tokens.shape[0]
     M, my_layers, stage_fn = _pipeline_stage_setup(
         params, cfg, axis_name, B, n_microbatches, return_aux=return_aux)
-    x = params["embed"].astype(cfg.dtype)[tokens]
+    x = _embed(params, tokens, cfg)
     mb = x.reshape(M, B // M, *x.shape[1:])
     out = _pl.pipeline_apply(stage_fn, my_layers, mb, axis_name=axis_name,
                              stage_aux=return_aux)
@@ -1652,7 +1742,7 @@ def _pipeline_stage_setup(params: Dict, cfg: TransformerConfig,
             # from the varying activations), so the scan carry init must
             # be too (shard_map VMA typing).
             aux0 = jnp.float32(0.0) + (s * 0).astype(jnp.float32)
-            (out, aux), _ = lax.scan(layer, (xb, aux0), lp_stack)
+            (out, aux), _ = _scan_layers(layer, (xb, aux0), lp_stack)
             return out, aux
 
         return M, my_layers, stage_fn
@@ -1664,7 +1754,7 @@ def _pipeline_stage_setup(params: Dict, cfg: TransformerConfig,
         layer = _remat(layer, cfg)
 
     def stage_fn(lp_stack, xb):
-        out, _ = lax.scan(layer, xb, lp_stack)
+        out, _ = _scan_layers(layer, xb, lp_stack)
         return out
 
     return M, my_layers, stage_fn
@@ -1809,7 +1899,7 @@ def pipelined_value_and_grad(params: Dict, batch: Dict,
     per_stage = cfg.n_layers // P_
     n_tok = B * S
 
-    x = params["embed"].astype(cfg.dtype)[tokens]
+    x = _embed(params, tokens, cfg)
     xs = x.reshape(M, B // M, S, cfg.d_model)
     ts = targets.reshape(M, B // M, S)
 

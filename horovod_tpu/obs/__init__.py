@@ -31,6 +31,8 @@ from __future__ import annotations
 import contextlib
 import time
 
+from jax.profiler import StepTraceAnnotation
+
 from horovod_tpu.obs import (  # noqa: F401
     aggregate,
     fleet,
@@ -84,7 +86,9 @@ def training_step(name: str = "train_step"):
     live ``training_mfu`` gauge when
     :func:`horovod_tpu.obs.xprof.set_training_cost` armed it, and, when
     a timeline is recording, nests a ``train_step`` span onto the same
-    time axis as the serving request spans."""
+    time axis as the serving request spans.  The step is also a
+    ``jax.profiler.StepTraceAnnotation("hvd:<name>")``: a profiler
+    trace taken over the loop groups its device operations by step."""
     m = training_metrics()
     from horovod_tpu import timeline as TL
 
@@ -93,7 +97,9 @@ def training_step(name: str = "train_step"):
     if tl is not None:
         tl.begin(name, "training")
     try:
-        yield
+        with StepTraceAnnotation(tracing.PHASE_PREFIX + name,
+                                 step_num=int(m.steps.value)):
+            yield
     finally:
         dt = time.monotonic() - t0
         if tl is not None:

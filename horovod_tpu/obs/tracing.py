@@ -25,8 +25,17 @@ module-global read per hot-path site plus a 16-hex-char id mint at
 submit; timestamps for the breakdown are stamped regardless (a handful
 of ``time.monotonic()`` calls per request — the breakdown is part of
 the ``/generate`` response contract, tracing or not).  When on, each
-tick adds three queue puts (bounded, drop-on-full — the timeline's
-writer decoupling) and each request retirement one JSONL line.
+engine phase adds one buffered tuple (a queue put per ``TICK_BATCH``,
+bounded, drop-on-full — the timeline's writer decoupling) and each
+request retirement one JSONL line.
+
+:class:`phase` is the ONE span primitive of the engine loop: it enters a
+``jax.profiler.TraceAnnotation("hvd:<name>")`` — so that a profiler
+trace (``jax.profiler.start_trace`` / ``Timeline.profile``) shows the
+program's own phase names on the host plane of the SAME ``.xplane.pb``
+as the device operations, on the profiler's clock — observes a
+histogram with the duration, and hands the span to the active
+:class:`Tracer`'s tick row.
 
 All timestamps are ``time.monotonic()`` seconds — the same clock the
 timeline uses (``monotonic_ns / 1e3`` microseconds), so serving spans
@@ -45,6 +54,8 @@ import time
 import uuid
 from typing import Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "TRACE_ID_HEADER", "PARENT_SPAN_HEADER", "SAMPLED_HEADER",
     "SPAN_EVENT_TYPES", "RETAIN_EVENT_TYPES", "RequestTrace", "Tracer",
@@ -54,7 +65,7 @@ __all__ = [
     "start", "stop", "get", "activate", "deactivate",
     "start_spans", "stop_spans", "spans", "activate_spans",
     "deactivate_spans",
-    "instant", "record_compile",
+    "instant", "record_compile", "phase", "PHASE_PREFIX",
 ]
 
 TRACE_ID_HEADER = "X-Trace-Id"
@@ -270,7 +281,8 @@ class Tracer:
         self.jsonl_path = jsonl_path
         self._named_tids = set()
         self._tid_lock = threading.Lock()
-        # Tick-phase events are the hot emitter (3 per decode tick):
+        # Tick-phase events are the hot emitter (one per engine phase,
+        # seven per steady decode tick):
         # buffer them locally and hand the timeline ONE batch per
         # TICK_BATCH events — a per-event queue put wakes the writer
         # thread every time, and those context switches (not the dict
@@ -293,8 +305,8 @@ class Tracer:
         self._tl.instant(name, args)
 
     def tick_phase(self, name: str, start_s: float, dur_s: float) -> None:
-        """One engine tick phase (dispatch / device wait / host) as a
-        complete span on the tick row.  Hot path: append one TUPLE —
+        """One engine phase (:class:`phase`: dispatch / device wait /
+        host / admit / ...) as a complete span on the tick row.  Hot path: append one TUPLE —
         event dicts are built (and the writer woken) only once per
         TICK_BATCH at flush, so the steady-state decode loop pays
         nanoseconds, not queue wakeups."""
@@ -745,6 +757,51 @@ def deactivate() -> Optional[Tracer]:
     """Detach the active tracer (returned) leaving its files open;
     re-attach with :func:`activate`."""
     return activate(None)
+
+
+# -- the span primitive -------------------------------------------------------
+
+#: Prefix of every span the program writes onto the profiler's trace;
+#: readers of an ``.xplane.pb`` find the program's phases by it.
+PHASE_PREFIX = "hvd:"
+
+
+class phase:
+    """One timed phase of a host loop, as a context manager.
+
+    ``with phase("admit", hist, k=2): ...`` (a) enters
+    ``jax.profiler.TraceAnnotation("hvd:admit", k=2)`` — about half a
+    microsecond when no profiler session is active; when one is, the
+    span lands beside the device operations it caused; (b) observes
+    ``hist`` (anything with ``observe(seconds)``) with the duration;
+    (c) hands ``(name, start, dur)`` to the active :class:`Tracer`'s
+    ``tick_phase`` if there is one.  ``start`` and ``dur`` (seconds on
+    ``time.monotonic()``) stay readable on the object after exit, so a
+    caller that needs a phase boundary's timestamp reads it from the
+    phase instead of taking its own."""
+
+    __slots__ = ("name", "hist", "start", "dur", "_ann")
+
+    def __init__(self, name: str, hist=None, **attrs):
+        self.name = name
+        self.hist = hist
+        self.start = self.dur = 0.0
+        self._ann = TraceAnnotation(PHASE_PREFIX + name, **attrs)
+
+    def __enter__(self) -> "phase":
+        self.start = time.monotonic()
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.dur = time.monotonic() - self.start
+        self._ann.__exit__(*exc)
+        if self.hist is not None:
+            self.hist.observe(self.dur)
+        tp = _tracer
+        if tp is not None:
+            tp.tick_phase(self.name, self.start, self.dur)
+        return False
 
 
 # -- cross-cutting event helpers ---------------------------------------------

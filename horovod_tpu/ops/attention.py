@@ -252,6 +252,7 @@ def _flash_fwd(q, k, v, shift, sm_scale, block_q: int, block_k: int):
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         interpret=_use_interpret(),
+        name="hvd_flash_fwd",
     )(_shift_operand(shift, q), qr, kr, vr)
     return o.reshape(B, H, S, D), lse[:, 0, :].reshape(B, H, S)
 
@@ -417,6 +418,7 @@ def _flash_bwd_pallas(shift, scale, block_q, block_k, q, k, v, o, lse, do,
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, D), jnp.float32)],
         interpret=_use_interpret(),
+        name="hvd_flash_bwd_dkv",
     )(sh, qr, dor, lse_t, delta, kr, vr)
 
     dq = pl.pallas_call(
@@ -430,6 +432,7 @@ def _flash_bwd_pallas(shift, scale, block_q, block_k, q, k, v, o, lse, do,
         out_shape=_out_sds((B * H, S, D), q.dtype, q),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=_use_interpret(),
+        name="hvd_flash_bwd_dq",
     )(sh, qr, dor, lse_t, delta, kr, vr)
 
     return (dq.reshape(B, H, S, D), dk.reshape(B, H, T, D),

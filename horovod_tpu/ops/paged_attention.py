@@ -181,6 +181,29 @@ def _kernel_body(table_ref, limit_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                                          lse_ref.shape[2:])
 
 
+#: The kernel's name on a device trace (``pl.pallas_call(name=)``):
+#: readers of a profile find the call by it, not by operand shapes.
+KERNEL_NAME = "hvd_paged_attend"
+
+
+def grid_extent(n_slots: int, n_kv_heads: int, max_pages: int):
+    """The kernel's grid for one call: one step per (slot, KV head,
+    page block of the slot's table) — EVERY block of the table, whatever
+    the slot's ``limit`` (blocks past it are masked, not skipped).  The
+    ONE statement of the extent: :func:`_pallas_paged_attend` builds its
+    ``grid_spec`` from it and :func:`grid_tokens` counts from it, so a
+    change that shrinks the grid moves the engine's
+    ``paged_walked_tokens`` counter with it."""
+    return (n_slots, n_kv_heads, max_pages)
+
+
+def grid_tokens(n_slots: int, max_pages: int, page_size: int) -> int:
+    """Logical positions one call's grid visits (per KV head and
+    layer): the denominator of "live over walked"."""
+    slots, _, blocks = grid_extent(n_slots, 1, max_pages)
+    return slots * blocks * page_size
+
+
 def _pallas_paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table,
                          limit, compute_dtype):
     S, Hkv, R, Dh = qg.shape
@@ -234,7 +257,7 @@ def _pallas_paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table,
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(S, Hkv, max_pages),
+        grid=grid_extent(S, Hkv, max_pages),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[
@@ -248,6 +271,7 @@ def _pallas_paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table,
         grid_spec=grid_spec,
         out_shape=[o_shape, lse_shape],
         interpret=use_interpret(),
+        name=KERNEL_NAME,
     )(table.astype(jnp.int32), limit.astype(jnp.int32), *operands)
     return o[:, :, :R, :], lse[:, :, 0, :R]
 

@@ -71,14 +71,17 @@ def distributed_gradients(
                 for i, _key, leaf in sparse
             ]
             return SP.merge_sparse_leaves(treedef, out, red_sparse)
-    grads, ctx = compression.compress(grads)
-    if fuse and op in (C.Average, C.Sum):
-        out = F.fused_allreduce_tree(
-            grads, op, axis_name=axis_name, threshold=fusion_threshold
-        )
-    else:
-        out = C.allreduce(grads, op, axis_name=axis_name)
-    return compression.decompress(out, ctx)
+    # "grad_allreduce": the device scope a profile attributes the
+    # compiled collectives (and their casts and packing) to.
+    with jax.named_scope("grad_allreduce"):
+        grads, ctx = compression.compress(grads)
+        if fuse and op in (C.Average, C.Sum):
+            out = F.fused_allreduce_tree(
+                grads, op, axis_name=axis_name, threshold=fusion_threshold
+            )
+        else:
+            out = C.allreduce(grads, op, axis_name=axis_name)
+        return compression.decompress(out, ctx)
 
 
 class _AccumState(NamedTuple):
@@ -134,7 +137,9 @@ def DistributedOptimizer(
             return optimizer.init(params)
 
         def update_fn(grads, state, params=None, **extra):
-            return optimizer.update(_reduce(grads), state, params, **extra)
+            reduced = _reduce(grads)
+            with jax.named_scope("opt_update"):
+                return optimizer.update(reduced, state, params, **extra)
 
         return optax.GradientTransformation(init_fn, update_fn)
 
@@ -159,7 +164,9 @@ def DistributedOptimizer(
                 lambda a: a * jnp.asarray(scale, a.dtype), acc
             )
             reduced = _reduce(scaled)
-            updates, inner2 = optimizer.update(reduced, inner, params, **extra)
+            with jax.named_scope("opt_update"):
+                updates, inner2 = optimizer.update(
+                    reduced, inner, params, **extra)
             zeroed = jax.tree_util.tree_map(jnp.zeros_like, acc)
             return updates, inner2, zeroed
 
@@ -218,9 +225,14 @@ def DistributedAdasumOptimizer(
     from horovod_tpu.ops import adasum as AD
 
     def _adasum(tree):
-        tree, ctx = compression.compress(tree)
-        out = AD.adasum_allreduce(tree, axis_name=axis_name)
-        return compression.decompress(out, ctx)
+        with jax.named_scope("grad_allreduce"):
+            tree, ctx = compression.compress(tree)
+            out = AD.adasum_allreduce(tree, axis_name=axis_name)
+            return compression.decompress(out, ctx)
+
+    def _inner_update(grads, state, params, extra):
+        with jax.named_scope("opt_update"):
+            return optimizer.update(grads, state, params, **extra)
 
     if backward_passes_per_step == 1:
 
@@ -228,7 +240,7 @@ def DistributedAdasumOptimizer(
             return optimizer.init(params)
 
         def update_fn(grads, state, params=None, **extra):
-            updates, inner = optimizer.update(grads, state, params, **extra)
+            updates, inner = _inner_update(grads, state, params, extra)
             return _adasum(updates), inner
 
         return optax.GradientTransformation(init_fn, update_fn)
@@ -247,8 +259,8 @@ def DistributedAdasumOptimizer(
             raise ValueError(
                 "DistributedAdasumOptimizer with backward_passes_per_step "
                 "> 1 needs params passed to update()")
-        local_updates, inner = optimizer.update(
-            grads, state.inner, params, **extra)
+        local_updates, inner = _inner_update(
+            grads, state.inner, params, extra)
         count = state.counter + 1
         boundary = count >= k
 

@@ -44,6 +44,7 @@ def init_slot_cache(cfg: "T.TransformerConfig", n_slots: int,
             "pos": jnp.zeros((n_slots,), jnp.int32)}
 
 
+@jax.named_scope("kv_land")  # T.DEVICE_SCOPES
 def insert_prefill(cache: Dict, slot, prefilled: Dict) -> Dict:
     """Land a batch-1 prefilled cache in slot ``slot`` of a slot cache.
 
@@ -66,6 +67,7 @@ def insert_prefill(cache: Dict, slot, prefilled: Dict) -> Dict:
     return {"k": k, "v": v, "pos": pos}
 
 
+@jax.named_scope("kv_land")  # T.DEVICE_SCOPES
 def insert_prefill_batch(cache: Dict, slots, prefilled: Dict) -> Dict:
     """Land a batch-K prefilled cache in K slots of a slot cache.
 
@@ -237,6 +239,7 @@ def init_page_pool(cfg: "T.TransformerConfig", n_slots: int, n_pages: int,
     return pool
 
 
+@jax.named_scope("kv_land")  # T.DEVICE_SCOPES
 def paged_insert(pool: Dict, slots, new_pos, phys, off,
                  prefilled_k, prefilled_v) -> Dict:
     """Land a prefilled K/V block into pages: position ``t`` of row
@@ -272,6 +275,7 @@ def paged_insert(pool: Dict, slots, new_pos, phys, off,
     return out
 
 
+@jax.named_scope("kv_write")  # T.DEVICE_SCOPES
 def copy_page(pool: Dict, src, dst) -> Dict:
     """Copy one physical page (all layers, payload + scales) — the
     copy-on-write primitive.  ``src``/``dst`` are traced scalars, so
@@ -283,6 +287,7 @@ def copy_page(pool: Dict, src, dst) -> Dict:
     return out
 
 
+@jax.named_scope("landed_gather")  # T.DEVICE_SCOPES
 def gather_prefix_pages(pool: Dict, pages):
     """Materialize ``pages`` (a ``(n,)`` id vector) as contiguous
     ``(k, v)`` of shape ``(L, H_kv, n * page, Dh)`` — the shared-prefix

@@ -874,9 +874,9 @@ class InferenceEngine:
                 logits, pool = T.decode_step_paged(
                     params, tokens, pool, table, self.cfg, active,
                     kernel=_pk, mesh=_pk_mesh)
-                nxt = self._pick(logits, pos, s_t, s_k, s_p, s_key)
-                mx = jnp.max(logits, axis=-1)
-                return jnp.where(active, nxt, 0), mx, pool
+                nxt, mx = self._pick(logits, pos, active, s_t, s_k, s_p,
+                                     s_key)
+                return nxt, mx, pool
 
             self._plain_tick_fn = self._jit(
                 _ptick, donate=(4,),
@@ -898,9 +898,9 @@ class InferenceEngine:
                 # draw with the position-folded key, and no parameter
                 # mix ever retraces this body (the zero-recompile
                 # guard covers sampling now too).
-                nxt = self._pick(logits, pos, s_t, s_k, s_p, s_key)
-                mx = jnp.max(logits, axis=-1)
-                return jnp.where(active, nxt, 0), mx, pool
+                nxt, mx = self._pick(logits, pos, active, s_t, s_k, s_p,
+                                     s_key)
+                return nxt, mx, pool
 
             donate = 4
         else:
@@ -913,13 +913,9 @@ class InferenceEngine:
                 pos = cache["pos"]
                 logits, cache = T.decode_step_slots(
                     params, tokens, cache, self.cfg, active)
-                nxt = self._pick(logits, pos, s_t, s_k, s_p, s_key)
-                # Per-slot max logit rides along for the host-side
-                # finiteness check: NaN/Inf logits (bad params, flaky
-                # hardware) must become a typed engine failure, not
-                # silently-greedy garbage tokens.
-                mx = jnp.max(logits, axis=-1)
-                return jnp.where(active, nxt, 0), mx, cache
+                nxt, mx = self._pick(logits, pos, active, s_t, s_k, s_p,
+                                     s_key)
+                return nxt, mx, cache
 
             donate = 3
 
@@ -949,6 +945,17 @@ class InferenceEngine:
         self._page_pos = np.zeros(engine_cfg.n_slots, np.int64)
         self._dev_table = None
         self._table_uploaded = -1
+        # What else the CURRENT step ran beside its decode tick
+        # (_observe_step_kind), and the positions one paged tick's
+        # attention grid visits (_count_paged_walk).
+        self._step_tick = self._step_prefill = self._step_chunk = False
+        self._walk_tokens = 0
+        if engine_cfg.paged:
+            from horovod_tpu.ops import paged_attention as _pa
+
+            self._walk_tokens = _pa.grid_tokens(
+                engine_cfg.n_slots, self.slots.max_pages,
+                self.slots.page_size)
         # Registered shared prefixes (token tuple -> entry); epoch
         # stamps which cache lifetime the pinned pages belong to.
         self._prefixes: Dict[tuple, _PrefixEntry] = {}
@@ -2026,16 +2033,22 @@ class InferenceEngine:
         return True
 
     @staticmethod
-    def _pick(logits, pos, s_t, s_k, s_p, s_key):
+    def _pick(logits, pos, active, s_t, s_k, s_p, s_key):
         """The ONE in-tick next-token pick, shared by every tick body:
         the token being chosen sits at logical position ``pos + 1``
         (``pos`` = the pool position at tick ENTRY — the input token's
         slot), so its PRNG key is ``fold_in(fold_in(key, pos + 1), 0)``
         — exactly the per-request ``sample_decode`` oracle's schedule
         for row 0 (tests/test_sampling.py).  Greedy rows short to
-        argmax inside the kernel."""
-        return T.sample_token_rows(logits, s_t, s_k, s_p, s_key,
-                                   pos + 1, jnp.zeros_like(pos))
+        argmax inside the kernel.  Returns ``(next tokens, zeroed for
+        inactive rows; per-slot max logit)`` — the max rides along for
+        the host-side finiteness check: NaN/Inf logits (bad params,
+        flaky hardware) must become a typed engine failure, not
+        silently-greedy garbage tokens."""
+        with jax.named_scope("sample"):
+            nxt = T.sample_token_rows(logits, s_t, s_k, s_p, s_key,
+                                      pos + 1, jnp.zeros_like(pos))
+            return jnp.where(active, nxt, 0), jnp.max(logits, axis=-1)
 
     def _run_tick(self, tokens_dev, active_dev):
         """Dispatch ONE compiled decode tick.  Returns ``(next-token
@@ -2110,23 +2123,30 @@ class InferenceEngine:
         terminally ``failed`` when the budget is exhausted."""
         if self._health == FAILED:
             return False
+        t_step = time.monotonic()
         with self._hb_lock:
-            self._tick_started = time.monotonic()
+            self._tick_started = t_step
         try:
             faults = self.engine_cfg.faults
             if faults is not None:
                 faults.probe("watchdog")  # a "hang" here stalls the tick
             with self._lock:
-                worked = self._reclaim_cancelled()
+                # The phases below (and those inside the calls) PARTITION
+                # the step: one after another, none inside another.
+                with self._phase("reclaim"):
+                    worked = self._reclaim_cancelled()
                 worked = self._admit_pending() or worked
                 if self.engine_cfg.overlap:
                     worked = self._decode_tick_overlapped() or worked
                 else:
                     worked = self._decode_tick() or worked
-                self.metrics.queue_depth.set(self.scheduler.depth)
-                self.metrics.slot_occupancy.set(self.slots.occupancy)
-                self._update_page_gauges()
+                with self._phase("bookkeeping"):
+                    self.metrics.queue_depth.set(self.scheduler.depth)
+                    self.metrics.slot_occupancy.set(self.slots.occupancy)
+                    self._update_page_gauges()
+            self._observe_step_kind(t_step)
         except Exception as exc:  # supervised: ANY tick failure recovers
+            self._observe_step_kind(t_step)
             with self._hb_lock:
                 self._tick_started = None
                 stalled = self._stalled
@@ -2159,10 +2179,54 @@ class InferenceEngine:
         # recovering tick's window would score restart noise.
         if self._tuner is not None:
             try:
-                self._tuner.on_tick(self, worked)
+                with self._phase("bookkeeping"):
+                    self._tuner.on_tick(self, worked)
             except Exception:  # tuning must never take serving down
                 self._tuner = None
         return worked
+
+    def _phase(self, name: str, **attrs) -> obs_tracing.phase:
+        """One phase of the engine loop: an ``hvd:<name>`` span on a
+        profiler trace, its histogram in the CURRENT metrics object
+        (benchmarks swap in a fresh one after warm-up), and the active
+        tracer's tick row."""
+        return obs_tracing.phase(name, self.metrics.phases[name], **attrs)
+
+    def _observe_step_kind(self, t_step: float) -> None:
+        """Close a step that dispatched a decode tick: its wall time goes
+        to ``engine_step{kind=}`` by what else the same step ran — an
+        admission prefill (``prefill``, also when a chunk rode along),
+        an ingest chunk (``chunk``), or neither (``plain``)."""
+        if self._step_tick:
+            kind = ("prefill" if self._step_prefill
+                    else "chunk" if self._step_chunk else "plain")
+            self.metrics.engine_step[kind].observe(
+                time.monotonic() - t_step)
+        self._step_tick = self._step_prefill = self._step_chunk = False
+
+    def _count_prefill(self, tokens: int, rows: int, bucket: int,
+                       chunk: bool = False) -> None:
+        """One prefill forward pass of the target model: ``tokens`` real
+        prompt tokens in ``rows`` rows padded to ``bucket``.  It marks
+        the step as one that carried an admission prefill (or an ingest
+        ``chunk``) here, where an executable ran: an admission that only
+        attached shared pages, or whose group emptied, leaves it plain."""
+        if chunk:
+            self._step_chunk = True
+        else:
+            self._step_prefill = True
+        self._prefill_calls += 1
+        self.metrics.prefill_tokens.inc(tokens)
+        self.metrics.prefill_padded_tokens.inc(rows * bucket)
+
+    def _count_paged_walk(self, active: np.ndarray) -> None:
+        """One dispatched paged tick: the positions its active slots may
+        attend (everything up to and including the token being written —
+        read BEFORE the dispatch-time advance of ``_page_pos``) beside
+        the positions the attention's grid visits."""
+        self.metrics.paged_live_tokens.inc(
+            int(self._page_pos[active].sum()) + int(active.sum()))
+        self.metrics.paged_walked_tokens.inc(self._walk_tokens)
 
     def _reclaim_cancelled(self) -> bool:
         """Free slots whose requests were cancelled caller-side — their
@@ -2209,103 +2273,104 @@ class InferenceEngine:
         return True
 
     def _admit_pending(self) -> bool:
-        # Tick-boundary deadline sweep: resolve EVERY dead queued
-        # request (lapsed deadline, cancel, raced drain) wherever it
-        # sits — a doomed request's 504 must not wait behind a long
-        # admission stall for take() to reach it.
-        swept = self.scheduler.sweep()
-        self._tick_prefill_spent = 0
-        self._tick_ingested = set()
-        # Slot-pressure preemption BEFORE the take: a strictly
-        # better-class arrival claims a slot from the worst occupant
-        # (suspended, never lost) instead of waiting out its decode.
-        preempted = self._preempt_for_slots()
-        pages_fn = None
-        if self.engine_cfg.paged:
-            # Page back-pressure: the take stops (requests WAIT,
-            # scheduling order intact) when the next admission's
-            # private pages would overdraw the free heap — typed
-            # starvation-free admission control instead of silent
-            # over-allocation.
-            budget = self.slots.free_pages
-            # Clamp the plan to the deepest the free heap can ever get
-            # (pool minus registry-pinned prefix pages): the plan's
-            # growth-margin page is a heuristic, and an unclamped
-            # demand above that depth would park a request the
-            # submit-time fit check accepted at the FCFS head FOREVER
-            # — admit it when the pool is as free as it gets and let
-            # on-demand grant/preemption resolve the tail instead.
-            pinned = sum(
-                len(e.pages) for e in self._prefixes.values()
-                if e.pages is not None and e.epoch == self._cache_epoch)
-            attainable = max(self.slots.n_pages - pinned, 1)
-            reserved = 0
+        with self._phase("admit"):
+            # Tick-boundary deadline sweep: resolve EVERY dead queued
+            # request (lapsed deadline, cancel, raced drain) wherever it
+            # sits — a doomed request's 504 must not wait behind a long
+            # admission stall for take() to reach it.
+            swept = self.scheduler.sweep()
+            self._tick_prefill_spent = 0
+            self._tick_ingested = set()
+            # Slot-pressure preemption BEFORE the take: a strictly
+            # better-class arrival claims a slot from the worst occupant
+            # (suspended, never lost) instead of waiting out its decode.
+            preempted = self._preempt_for_slots()
+            pages_fn = None
+            if self.engine_cfg.paged:
+                # Page back-pressure: the take stops (requests WAIT,
+                # scheduling order intact) when the next admission's
+                # private pages would overdraw the free heap — typed
+                # starvation-free admission control instead of silent
+                # over-allocation.
+                budget = self.slots.free_pages
+                # Clamp the plan to the deepest the free heap can ever get
+                # (pool minus registry-pinned prefix pages): the plan's
+                # growth-margin page is a heuristic, and an unclamped
+                # demand above that depth would park a request the
+                # submit-time fit check accepted at the FCFS head FOREVER
+                # — admit it when the pool is as free as it gets and let
+                # on-demand grant/preemption resolve the tail instead.
+                pinned = sum(
+                    len(e.pages) for e in self._prefixes.values()
+                    if e.pages is not None and e.epoch == self._cache_epoch)
+                attainable = max(self.slots.n_pages - pinned, 1)
+                reserved = 0
 
-            def pages_fn(req):
-                nonlocal reserved
-                need = min(self._plan_pages(req), attainable)
-                if reserved + need > budget:
+                def pages_fn(req):
+                    nonlocal reserved
+                    need = min(self._plan_pages(req), attainable)
+                    if reserved + need > budget:
+                        return False
+                    reserved += need
+                    return True
+
+            # Per-tick prefill TOKEN budget (chunked prefill): admissions
+            # past the first stop once the tick's ingestion budget is
+            # spent — they wait one tick, bounding how long the decode
+            # batch stalls on prompt ingestion.  The FIRST admission is
+            # always allowed (liveness: a chunked one costs <= one chunk
+            # by construction, and a short over-budget prompt must not
+            # park forever).
+            tok_budget = self.engine_cfg.prefill_chunk_tokens
+            n_admit = 0
+
+            def admit_fn(req):
+                nonlocal n_admit
+                if pages_fn is not None and not pages_fn(req):
                     return False
-                reserved += need
+                if tok_budget:
+                    cost = self._prefill_cost(req)
+                    if n_admit and self._tick_prefill_spent + cost \
+                            > tok_budget:
+                        return False
+                    if not self._chunked(req):
+                        # A chunked admission's spend is counted by its
+                        # _ingest_step — counting it here too would
+                        # double-charge the tick.
+                        self._tick_prefill_spent += cost
+                n_admit += 1
                 return True
 
-        # Per-tick prefill TOKEN budget (chunked prefill): admissions
-        # past the first stop once the tick's ingestion budget is
-        # spent — they wait one tick, bounding how long the decode
-        # batch stalls on prompt ingestion.  The FIRST admission is
-        # always allowed (liveness: a chunked one costs <= one chunk
-        # by construction, and a short over-budget prompt must not
-        # park forever).
-        tok_budget = self.engine_cfg.prefill_chunk_tokens
-        n_admit = 0
-
-        def admit_fn(req):
-            nonlocal n_admit
-            if pages_fn is not None and not pages_fn(req):
-                return False
-            if tok_budget:
-                cost = self._prefill_cost(req)
-                if n_admit and self._tick_prefill_spent + cost \
-                        > tok_budget:
-                    return False
-                if not self._chunked(req):
-                    # A chunked admission's spend is counted by its
-                    # _ingest_step — counting it here too would
-                    # double-charge the tick.
-                    self._tick_prefill_spent += cost
-            n_admit += 1
-            return True
-
-        reqs = self.scheduler.take(
-            self.slots.free_count, bucket_fn=self._group_key,
-            admit_fn=admit_fn if (pages_fn or tok_budget) else None)
-        if not reqs and self.scheduler.depth \
-                and self.engine_cfg.resume and self.journal is not None:
-            # PAGE-pressure preemption: an empty take with a non-empty
-            # queue means the scheduling-order head was blocked — by
-            # the page budget (slot pressure already ran pre-take; the
-            # token budget and bucket truncation never block the FIRST
-            # candidate).  If the head outranks the worst occupant,
-            # suspend that occupant so its pages free the head next
-            # tick; within a class the head keeps waiting, as ever.
-            best = self.scheduler.peek_best_rank()
-            occ = self._occupants()
-            if best is not None and occ:
-                worst = max(occ)
-                if worst[0] > best:
-                    self._preempt(worst[2], "page_pressure")
-        self._taken = list(reqs)
-        live: List[Request] = []
-        for req in reqs:
-            if req.future.done():  # resolved while taken (raced drain)
-                self._taken.remove(req)
-                continue
-            if req.future.cancel_requested:
-                req.future._finish("cancelled")
-                self.metrics.cancelled.inc()
-                self._taken.remove(req)
-                continue
-            live.append(req)
+            reqs = self.scheduler.take(
+                self.slots.free_count, bucket_fn=self._group_key,
+                admit_fn=admit_fn if (pages_fn or tok_budget) else None)
+            if not reqs and self.scheduler.depth \
+                    and self.engine_cfg.resume and self.journal is not None:
+                # PAGE-pressure preemption: an empty take with a non-empty
+                # queue means the scheduling-order head was blocked — by
+                # the page budget (slot pressure already ran pre-take; the
+                # token budget and bucket truncation never block the FIRST
+                # candidate).  If the head outranks the worst occupant,
+                # suspend that occupant so its pages free the head next
+                # tick; within a class the head keeps waiting, as ever.
+                best = self.scheduler.peek_best_rank()
+                occ = self._occupants()
+                if best is not None and occ:
+                    worst = max(occ)
+                    if worst[0] > best:
+                        self._preempt(worst[2], "page_pressure")
+            self._taken = list(reqs)
+            live: List[Request] = []
+            for req in reqs:
+                if req.future.done():  # resolved while taken (raced drain)
+                    self._taken.remove(req)
+                    continue
+                if req.future.cancel_requested:
+                    req.future._finish("cancelled")
+                    self.metrics.cancelled.inc()
+                    self._taken.remove(req)
+                    continue
+                live.append(req)
         if live:
             self._admit_batch(live)
         self._taken = []
@@ -2371,6 +2436,17 @@ class InferenceEngine:
             # does not stall it.
             self._admit_chunked(reqs[0])
             return
+        costs = [self._prefill_cost(r) for r in reqs]
+        with self._phase(
+                "prefill", k=len(reqs), bucket=self._bucket(max(costs)),
+                tokens=sum(costs),
+                trace_ids=",".join(r.trace.trace_id for r in reqs
+                                   if r.trace is not None)):
+            self._prefill_group(reqs)
+
+    def _prefill_group(self, reqs: List[Request]) -> None:
+        """The body of a whole-prompt admission (the ``prefill`` phase):
+        dispatch, landing, and the first-token fetch."""
         faults = self.engine_cfg.faults
         if faults is not None:
             faults.probe("prefill")
@@ -2445,7 +2521,7 @@ class InferenceEngine:
             lens[i] = len(req.prompt)
         logits, pre_cache = self._prefill_fn(bucket, k)(
             self.params, jnp.asarray(padded), jnp.asarray(lens))
-        self._prefill_calls += 1
+        self._count_prefill(int(lens.sum()), k, bucket)
         slots: List[int] = []
         for _ in reqs:
             slot = self.slots.alloc()
@@ -2543,7 +2619,7 @@ class InferenceEngine:
                 logits, suf = self._suffix_prefill(
                     self.params, jnp.asarray(padded),
                     jnp.asarray(suf_lens), pk, pv, jnp.int32(p0))
-                self._prefill_calls += 1
+                self._count_prefill(int(suf_lens.sum()), k, bucket)
                 self.slots.land(slots, suf, suf_lens, start=p0)
                 firsts = self._first_tokens(live, logits)
         else:
@@ -2555,7 +2631,7 @@ class InferenceEngine:
                 lens[i] = len(r.prompt)
             logits, pre = self._prefill_fn(bucket, k)(
                 self.params, jnp.asarray(padded), jnp.asarray(lens))
-            self._prefill_calls += 1
+            self._count_prefill(int(lens.sum()), k, bucket)
             self.slots.land(slots, pre, lens, start=0)
             firsts = self._first_tokens(live, logits)
         for slot, req in zip(slots, live):
@@ -2584,28 +2660,29 @@ class InferenceEngine:
         this tick's budget.  The slot decodes nothing until the last
         chunk's logits yield the first token
         (:meth:`_finish_ingest`)."""
-        t_adm = time.monotonic()
-        if req.trace is not None and req.trace.admitted_at is None:
-            req.trace.admitted_at = t_adm
-            self.metrics.observe_queue_wait(
-                req.priority, t_adm - req.submitted_at)
-        entry = self._matched_prefix(req)
-        if entry is not None:
-            try:
-                self._ensure_prefix(entry)
-            except CacheOutOfPagesError:
-                entry = None  # degrade: chunk the whole prompt
-        slot = self.slots.alloc()
-        assert slot is not None  # take() is bounded by free_count
-        p0 = 0
-        if entry is not None:
-            self.slots.attach(slot, entry.pages)
-            p0 = len(entry.tokens)
-        self._ingest[slot] = _IngestState(request=req, landed=p0,
-                                          started=p0)
-        self._page_pos[slot] = p0
-        self.metrics.admitted.inc()
-        self._taken.remove(req)  # the ingest state owns it now
+        with self._phase("admit"):
+            t_adm = time.monotonic()
+            if req.trace is not None and req.trace.admitted_at is None:
+                req.trace.admitted_at = t_adm
+                self.metrics.observe_queue_wait(
+                    req.priority, t_adm - req.submitted_at)
+            entry = self._matched_prefix(req)
+            if entry is not None:
+                try:
+                    self._ensure_prefix(entry)
+                except CacheOutOfPagesError:
+                    entry = None  # degrade: chunk the whole prompt
+            slot = self.slots.alloc()
+            assert slot is not None  # take() is bounded by free_count
+            p0 = 0
+            if entry is not None:
+                self.slots.attach(slot, entry.pages)
+                p0 = len(entry.tokens)
+            self._ingest[slot] = _IngestState(request=req, landed=p0,
+                                              started=p0)
+            self._page_pos[slot] = p0
+            self.metrics.admitted.inc()
+            self._taken.remove(req)  # the ingest state owns it now
         self._ingest_step(slot)
 
     def _ensure_ingest_pages(self, slot: int, lo: int, hi: int) -> bool:
@@ -2648,6 +2725,17 @@ class InferenceEngine:
         ing = self._ingest.get(slot)
         if ing is None:
             return False
+        lo = ing.landed
+        hi = lo + min(len(ing.request.prompt) - lo,
+                      self.engine_cfg.prefill_chunk_tokens)
+        with self._phase("ingest_chunk", slot=slot, lo=lo, hi=hi,
+                         trace_id=ing.request.trace.trace_id
+                         if ing.request.trace is not None else ""):
+            return self._ingest_chunk(slot, ing)
+
+    def _ingest_chunk(self, slot: int, ing: _IngestState) -> bool:
+        """The body of :meth:`_ingest_step` (the ``ingest_chunk``
+        phase)."""
         if self._reap_ingest(slot):
             return True
         req = ing.request
@@ -2680,14 +2768,14 @@ class InferenceEngine:
         if lo == 0:
             logits, pre = self._prefill_fn(bucket, 1)(
                 self.params, jnp.asarray(padded), lens)
-            self._prefill_calls += 1
+            self._count_prefill(n, 1, bucket, chunk=True)
             self.slots.land([slot], pre, np.asarray([n]), start=0)
         else:
             pk, pv = self._gather_landed(slot, lo)
             logits, suf = self._suffix_prefill(
                 self.params, jnp.asarray(padded), lens, pk, pv,
                 jnp.int32(lo))
-            self._prefill_calls += 1
+            self._count_prefill(n, 1, bucket, chunk=True)
             self.slots.land([slot], suf, np.asarray([n]), start=lo)
         self._tick_prefill_spent += n
         self._tick_ingested.add(slot)
@@ -2836,11 +2924,7 @@ class InferenceEngine:
         baseline): upload tokens + mask, dispatch, fetch, and apply the
         bookkeeping all in the same step — the device idles through the
         host half, which is exactly what the pipeline hides."""
-        if self.engine_cfg.paged and self.slots.active_count:
-            if self._spec:
-                self._prepare_spec_tick()  # window grants; may preempt
-            else:
-                self._prepare_paged_tick()  # grants/COWs; may preempt
+        self._prepare_tick_pages()  # grants/COWs; may preempt
         active = self._decode_mask()
         if not active.any():
             return False
@@ -2850,27 +2934,43 @@ class InferenceEngine:
         for s, st in enumerate(self._states):
             if st is not None:
                 tokens[s] = st.last_token
-        t0 = time.monotonic()
-        nxt, extra = self._run_tick(
-            jnp.asarray(tokens), jnp.asarray(active))
-        if not self._spec:
-            # Speculative ticks advance the mirror at FETCH (the
-            # accepted length is data the host learns there).
-            self._page_pos += active
-        self.metrics.decode_ticks.inc()
-        dt = time.monotonic() - t0
-        self.metrics.tick_dispatch.observe(dt)
-        tp = obs_tracing.get()
-        if tp is not None:
-            tp.tick_phase("tick_dispatch", t0, dt)
+        with self._phase("tick_dispatch") as dispatch:
+            nxt, extra = self._dispatch_tick(
+                jnp.asarray(tokens), jnp.asarray(active), active)
         # Same fetch-and-apply tail as the pipeline, just not deferred.
         self._retire_pending({
             **extra, "active": active,
             "reqs": [st.request if st is not None else None
                      for st in self._states],
-            "kind": kind, "dispatched_at": t0,
+            "kind": kind, "dispatched_at": dispatch.start,
         })
         return True
+
+    def _prepare_tick_pages(self) -> None:
+        """Tick-boundary page maintenance of a paged engine with live
+        slots (the ``page_prep`` phase): grants, COWs and table uploads
+        — host bookkeeping plus async uploads, nothing blocks on the
+        device; a preemption here is never dispatched."""
+        if self.engine_cfg.paged and self.slots.active_count:
+            with self._phase("page_prep"):
+                if self._spec:
+                    self._prepare_spec_tick()   # window grants
+                else:
+                    self._prepare_paged_tick()
+
+    def _dispatch_tick(self, tokens_dev, active_dev, active: np.ndarray):
+        """What BOTH loops do inside ``tick_dispatch``: count the walk,
+        dispatch, advance the dispatch-time position mirror."""
+        if self.engine_cfg.paged:
+            self._count_paged_walk(active)
+        nxt, extra = self._run_tick(tokens_dev, active_dev)
+        if not self._spec:
+            # Speculative ticks advance the mirror at FETCH (the
+            # accepted length is data the host learns there).
+            self._page_pos += active
+        self.metrics.decode_ticks.inc()
+        self._step_tick = True
+        return nxt, extra
 
     def _decode_tick_overlapped(self) -> bool:
         """One PIPELINED decode step (``overlap=True``): dispatch tick
@@ -2882,49 +2982,21 @@ class InferenceEngine:
         (:meth:`_retire_pending`)."""
         worked = False
         faults = self.engine_cfg.faults
-        if self.engine_cfg.paged and self.slots.active_count:
-            # Page maintenance BEFORE the mask snapshot: a preemption
-            # here must not be dispatched, and a grant/COW is host
-            # bookkeeping + async uploads — nothing blocks on device.
-            if self._spec:
-                self._prepare_spec_tick()
-            else:
-                self._prepare_paged_tick()
+        # Page maintenance BEFORE the mask snapshot: a preemption here
+        # must not be dispatched.
+        self._prepare_tick_pages()
         active = self._decode_mask()
         new_pending: Optional[Dict] = None
         if active.any():
             kind = (faults.probe("decode_tick")
                     if faults is not None else None)
-            t0 = time.monotonic()
-            if self._dev_tokens is None:
-                # Pipeline (re)start: seed the device token vector from
-                # host slot state.  After this the ONLY recurring
-                # upload is the active mask, and only when it changes.
-                tokens = np.zeros(self.engine_cfg.n_slots, np.int32)
-                for s, st in enumerate(self._states):
-                    if st is not None:
-                        tokens[s] = st.last_token
-                self._dev_tokens = jnp.asarray(tokens)
-            if (self._dev_active_host is None
-                    or not np.array_equal(active, self._dev_active_host)):
-                self._dev_active = jnp.asarray(active)
-                self._dev_active_host = active
-            nxt, extra = self._run_tick(self._dev_tokens,
-                                        self._dev_active)
-            if not self._spec:
-                self._page_pos += active  # spec: advanced at fetch
-            self._dev_tokens = nxt  # tick N+2's input — never fetched
-            self.metrics.decode_ticks.inc()
-            dt = time.monotonic() - t0
-            self.metrics.tick_dispatch.observe(dt)
-            tp = obs_tracing.get()
-            if tp is not None:
-                tp.tick_phase("tick_dispatch", t0, dt)
+            with self._phase("tick_dispatch") as dispatch:
+                nxt, extra = self._dispatch_overlapped(active)
             new_pending = {
                 **extra, "active": active,
                 "reqs": [st.request if st is not None else None
                          for st in self._states],
-                "kind": kind, "dispatched_at": t0,
+                "kind": kind, "dispatched_at": dispatch.start,
             }
             worked = True
         prev, self._pending = self._pending, new_pending
@@ -2932,6 +3004,28 @@ class InferenceEngine:
             self._retire_pending(prev)
             worked = True
         return worked
+
+    def _dispatch_overlapped(self, active: np.ndarray):
+        """The pipelined loop's ``tick_dispatch`` body: (re)seed the
+        device-resident inputs, dispatch, keep the output as the next
+        tick's input."""
+        if self._dev_tokens is None:
+            # Pipeline (re)start: seed the device token vector from
+            # host slot state.  After this the ONLY recurring
+            # upload is the active mask, and only when it changes.
+            tokens = np.zeros(self.engine_cfg.n_slots, np.int32)
+            for s, st in enumerate(self._states):
+                if st is not None:
+                    tokens[s] = st.last_token
+            self._dev_tokens = jnp.asarray(tokens)
+        if (self._dev_active_host is None
+                or not np.array_equal(active, self._dev_active_host)):
+            self._dev_active = jnp.asarray(active)
+            self._dev_active_host = active
+        nxt, extra = self._dispatch_tick(
+            self._dev_tokens, self._dev_active, active)
+        self._dev_tokens = nxt  # tick N+2's input — never fetched
+        return nxt, extra
 
     def _retire_pending(self, p: Dict) -> None:
         """Fetch a dispatched tick's results — THE one host sync of a
@@ -2956,13 +3050,18 @@ class InferenceEngine:
         faults = self.engine_cfg.faults
         if faults is not None:
             faults.probe("decode_fetch")
-        t0 = time.monotonic()
-        nxt = np.asarray(p["nxt"])           # (S,) — or (S, W) spec
-        mx = np.asarray(p["mx"])
-        acc = np.asarray(p["acc"]) if "acc" in p else None
-        self.metrics.host_syncs.inc()
-        t1 = time.monotonic()
-        self.metrics.tick_device_wait.observe(t1 - t0)
+        with self._phase("tick_device_wait") as wait:
+            nxt = np.asarray(p["nxt"])           # (S,) — or (S, W) spec
+            mx = np.asarray(p["mx"])
+            acc = np.asarray(p["acc"]) if "acc" in p else None
+            self.metrics.host_syncs.inc()
+        with self._phase("tick_host"):
+            self._apply_tick(p, nxt, mx, acc, wait.start + wait.dur)
+
+    def _apply_tick(self, p: Dict, nxt, mx, acc, t1: float) -> None:
+        """The host half of :meth:`_retire_pending` (the ``tick_host``
+        phase): the nonfinite check and the emission rules over results
+        fetched at ``t1``."""
         active = p["active"]
         if p["kind"] == "nonfinite":  # injected: NaN logits
             mx = np.where(active if mx.ndim == 1 else active[:, None],
@@ -3054,12 +3153,6 @@ class InferenceEngine:
                 self._emit(s, int(nxt[s, jt]))
                 emitted += 1
             self.metrics.tokens_per_tick.observe(emitted)
-        t2 = time.monotonic()
-        self.metrics.tick_host.observe(t2 - t1)
-        tp = obs_tracing.get()
-        if tp is not None:
-            tp.tick_phase("tick_device_wait", t0, t1 - t0)
-            tp.tick_phase("tick_host", t1, t2 - t1)
 
     # -- failure recovery --------------------------------------------------
 
@@ -3450,9 +3543,17 @@ class InferenceEngine:
             return
 
         def loop():
+            # engine_loop observes every iteration end to end, so the
+            # phases' sum over it is the share of this thread's time
+            # that lies inside a phase.
+            t_prev = time.monotonic()
             while not self._stop.is_set():
                 if not self.step():
-                    time.sleep(idle_sleep)
+                    with self._phase("idle"):
+                        time.sleep(idle_sleep)
+                now = time.monotonic()
+                self.metrics.engine_loop.observe(now - t_prev)
+                t_prev = now
 
         self._stop.clear()
         self._thread = threading.Thread(target=loop,

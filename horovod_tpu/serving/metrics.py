@@ -79,6 +79,28 @@ class ServingMetrics:
       is the overlap-efficiency number ``benchmarks/serving.py``
       reports — 1.0 means every host cycle was hidden behind device
       compute.
+    * ``phases`` — the engine loop's partition
+      (docs/observability.md "Engine phases"): every moment of the
+      engine thread lies in exactly one of ``reclaim``, ``admit``,
+      ``prefill``, ``ingest_chunk``, ``page_prep``, ``tick_dispatch``,
+      ``tick_device_wait``, ``tick_host``, ``bookkeeping``, ``idle``;
+      ``engine_loop`` observes the loop's own wall time per iteration,
+      so ``sum(phases) / engine_loop`` is the share of the loop the
+      phases cover.  The three ``tick_*`` keep their own families; the
+      other seven are the ``{phase=}`` children of one family.
+    * ``engine_step`` — wall time of the ``step()`` calls that
+      dispatched a decode tick, by what else the same step ran:
+      ``{kind="prefill"}`` an admission prefill, ``{kind="chunk"}`` an
+      ingest chunk (and no admission), ``{kind="plain"}`` neither.  The
+      counts are ``decode_ticks_plain`` / ``_prefill`` / ``_chunk`` in
+      ``/stats`` and sum to ``decode_ticks``.
+    * ``prefill_tokens`` / ``prefill_padded_tokens`` — prompt tokens
+      the prefill executables were asked to ingest, and the
+      bucket-/chunk-padded tokens they actually ran.
+    * ``paged_live_tokens`` / ``paged_walked_tokens`` — per dispatched
+      paged tick, the positions the active slots may attend, and the
+      positions the attention's grid visits
+      (``ops.paged_attention.grid_tokens``).
     * ``kv_pages_total`` / ``kv_pages_free`` / ``kv_pages_shared`` /
       ``kv_bytes_per_token`` — page-pool pressure gauges for the paged
       KV cache (docs/serving.md "Paged KV cache"): pool size, free
@@ -158,6 +180,51 @@ class ServingMetrics:
             "serving_tick_host_seconds",
             "Host bookkeeping per tick (emit/retire/admission)",
             buckets=TICK_PHASE_BUCKETS)
+        phase_family = r.histogram(
+            "serving_engine_phase_seconds",
+            "Time in one phase of the engine loop (reclaim, admit, "
+            "prefill, ingest_chunk, page_prep, bookkeeping, idle); "
+            "with the three serving_tick_* families the phases "
+            "partition the engine thread's time",
+            buckets=TICK_PHASE_BUCKETS, labels=("phase",))
+        self.phases: Dict[str, Histogram] = {
+            "tick_dispatch": self.tick_dispatch,
+            "tick_device_wait": self.tick_device_wait,
+            "tick_host": self.tick_host,
+            **{name: phase_family.labels(phase=name)
+               for name in ("reclaim", "admit", "prefill", "ingest_chunk",
+                            "page_prep", "bookkeeping", "idle")}}
+        self.engine_loop = r.histogram(
+            "serving_engine_loop_seconds",
+            "Wall time of one iteration of the engine thread's loop "
+            "(a step and its idle sleep): the denominator of the "
+            "phases' coverage",
+            buckets=TICK_PHASE_BUCKETS)
+        step_family = r.histogram(
+            "serving_engine_step_seconds",
+            "Wall time of a step() that dispatched a decode tick, by "
+            "what else it ran: kind=prefill (an admission prefill), "
+            "chunk (an ingest chunk), plain (neither)",
+            buckets=TICK_PHASE_BUCKETS, labels=("kind",))
+        self.engine_step: Dict[str, Histogram] = {
+            kind: step_family.labels(kind=kind)
+            for kind in ("plain", "prefill", "chunk")}
+        self.prefill_tokens = r.counter(
+            "serving_prefill_tokens_total",
+            "Prompt tokens run through a prefill executable "
+            "(admission groups and ingest chunks)")
+        self.prefill_padded_tokens = r.counter(
+            "serving_prefill_padded_tokens_total",
+            "Tokens the prefill executables ran, bucket and chunk "
+            "padding included (rows x bucket per call)")
+        self.paged_live_tokens = r.counter(
+            "serving_paged_live_tokens_total",
+            "Per dispatched paged tick, the positions its active "
+            "slots may attend")
+        self.paged_walked_tokens = r.counter(
+            "serving_paged_walked_tokens_total",
+            "Per dispatched paged tick, the positions the paged "
+            "attention's grid visits (every page block of every slot)")
         self.decode_ticks = r.counter(
             "serving_decode_ticks_total", "Decode ticks dispatched")
         self.host_syncs = r.counter(
@@ -306,6 +373,19 @@ class ServingMetrics:
             "tick_device_wait_seconds": self.tick_device_wait.snapshot(),
             "tick_host_seconds": self.tick_host.snapshot(),
             "decode_ticks": ticks,
+            **{f"phase_{name}_seconds": h.snapshot()
+               for name, h in self.phases.items()
+               if not name.startswith("tick_")},
+            "engine_loop_seconds": self.engine_loop.snapshot(),
+            **{f"engine_step_seconds_{kind}": h.snapshot()
+               for kind, h in self.engine_step.items()},
+            **{f"decode_ticks_{kind}": h.count
+               for kind, h in self.engine_step.items()},
+            "prefill_tokens_total": self.prefill_tokens.value,
+            "prefill_padded_tokens_total":
+                self.prefill_padded_tokens.value,
+            "paged_live_tokens_total": self.paged_live_tokens.value,
+            "paged_walked_tokens_total": self.paged_walked_tokens.value,
             "kv_pages_total": self.kv_pages_total.value,
             "kv_pages_free": self.kv_pages_free.value,
             "kv_pages_shared": self.kv_pages_shared.value,

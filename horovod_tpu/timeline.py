@@ -206,11 +206,14 @@ class Timeline:
             # A list is a pre-batched group (Tracer.tick_phase): one
             # queue wakeup carries many events, so a hot emitter costs
             # one writer context switch per BATCH instead of per event.
-            for e in (ev if isinstance(ev, list) else (ev,)):
-                if not self._first:
-                    self._file.write(",\n")
-                self._first = False
-                json.dump(e, self._file)
+            # json.dumps is the C encoder in one call (json.dump streams
+            # chunks through Python), and a batch is one write: what the
+            # writer holds the interpreter lock for, it takes from the
+            # engine thread when tracing is on.
+            text = ",\n".join(map(
+                json.dumps, ev if isinstance(ev, list) else (ev,)))
+            self._file.write(text if self._first else ",\n" + text)
+            self._first = False
 
     def close(self) -> None:
         if self._closed:

@@ -7,9 +7,9 @@ raises — the CI self-check); the tracer threads a Dapper-style trace
 id submit -> prefill -> decode -> retirement and renders request spans,
 tick-phase spans, and lifecycle instants through the existing timeline
 writer so one Perfetto file carries training and serving on one time
-axis.  The perf-marked test bounds the tracing overhead on the decode
-hot path (disabled is two pointer checks per tick; enabled <= 5% at
-the per-tick p25)."""
+axis.  The perf-marked tests bound the span work of a steady decode
+tick (seven ``tracing.phase`` entries: <= 20us with no tracer, <= 50us
+with one — <= 2% and <= 5% of a 1 ms tick)."""
 
 import json
 import queue
@@ -55,6 +55,12 @@ def _engine(model, **kw):
     defaults.update(kw)
     return serving.InferenceEngine(
         params, cfg, serving.EngineConfig(**defaults))
+
+
+#: The phases one steady-state step of the paged, overlapped engine
+#: passes through, in order (docs/observability.md "Engine phases").
+STEADY_TICK_PHASES = ("reclaim", "admit", "page_prep", "tick_dispatch",
+                      "tick_device_wait", "tick_host", "bookkeeping")
 
 
 def _run_until_done(engine, futs, max_ticks=300):
@@ -413,30 +419,33 @@ class TestServerObservability:
 @pytest.mark.perf
 class TestTracingOverhead:
     def test_enabled_per_tick_work_bounded(self, tmp_path):
-        """PERF GUARD (enabled <=5%): the tracer work one steady-state
-        decode tick performs — three buffered tick_phase records plus
-        the amortized batch flush through the live writer thread — must
-        cost <= 50us per tick at the p25.  A serving-shaped decode tick
+        """PERF GUARD (enabled <=5%): the span work one steady-state
+        decode tick performs — one ``phase`` per engine phase of a
+        steady tick (an inactive TraceAnnotation, a histogram
+        observation, a buffered tick_phase record) plus the amortized
+        batch flush through the live writer thread — must cost <= 50us
+        per tick at the p25.  A serving-shaped decode tick
         is >= 1ms (the CPU smoke config's is several ms, TPU ticks
         similar), so 50us caps the enabled overhead at the issue's 5%
-        budget; in practice this measures ~2-5us.  A deterministic
+        budget; in practice this measures ~17us.  A deterministic
         micro-bound instead of an engine wall-clock A/B: this sandbox's
         host noise swings per-tick times tens of percent (the same
         reason _ab_decode compares p25s and only the BENCHMARK reports
         the measured ratio — see tracing_overhead_ratio in
         benchmarks/serving.py)."""
         path = str(tmp_path / "perf_trace.json")
-        tracer = TR.start(path)
+        TR.start(path)
+        hists = serving.ServingMetrics().phases
         try:
             n, reps = 400, 30
             samples = []
             for _ in range(reps):
                 t0 = time.perf_counter()
                 for _ in range(n):
-                    # exactly what the engine emits per steady tick
-                    tracer.tick_phase("tick_dispatch", 1.0, 1e-4)
-                    tracer.tick_phase("tick_device_wait", 1.0, 1e-3)
-                    tracer.tick_phase("tick_host", 1.0, 1e-4)
+                    # exactly what the engine enters per steady tick
+                    for name in STEADY_TICK_PHASES:
+                        with TR.phase(name, hists[name]):
+                            pass
                 samples.append((time.perf_counter() - t0) / n)
             per_tick = float(np.percentile(samples, 25))
             assert per_tick <= 50e-6, f"{per_tick * 1e6:.1f}us per tick"
@@ -445,14 +454,19 @@ class TestTracingOverhead:
 
     def test_enabled_tick_emissions_bounded(self, model, tmp_path):
         """Structural half of the enabled bound: a steady-state decode
-        tick makes EXACTLY three tracer calls (the tick phases) — no
-        per-token, per-slot, or per-future emission creep on the hot
-        path.  Counted with a stub tracer so the assertion is exact."""
+        tick of the paged, overlapped engine makes EXACTLY one tracer
+        call per engine phase it passes through — reclaim, admit,
+        page_prep, tick_dispatch, tick_device_wait, tick_host,
+        bookkeeping — and no per-token, per-slot, or per-future
+        emission creeps onto the hot path.  Counted with a stub tracer
+        so the assertion is exact."""
         calls = {"tick_phase": 0, "other": 0}
+        names = []
 
         class StubTracer:
-            def tick_phase(self, *a, **k):
+            def tick_phase(self, name, *a, **k):
                 calls["tick_phase"] += 1
+                names.append(name)
 
             def __getattr__(self, name):
                 def record(*a, **k):
@@ -472,29 +486,33 @@ class TestTracingOverhead:
         finally:
             TR.activate(prev)
         assert not fut.done()  # still steady-state
-        assert calls["tick_phase"] == 3 * n, calls
+        assert calls["tick_phase"] == len(STEADY_TICK_PHASES) * n, calls
+        assert names == list(STEADY_TICK_PHASES) * n
         assert calls["other"] == 0, calls
         _run_until_done(engine, [fut])
 
     def test_disabled_per_tick_work_bounded(self):
-        """PERF GUARD (disabled <=2%): with no tracer attached the hot
-        path's entire tracing cost is the per-site `tracing.get() is
-        None` check (two per tick).  Bound it at 2us per tick — three
-        orders of magnitude under 2% of a 1ms tick; in practice
-        ~0.1us."""
+        """PERF GUARD (disabled <=2%): with no tracer attached and no
+        profiler session, the span work of a steady tick is seven
+        ``phase`` entries — an inactive TraceAnnotation (~0.4us), two
+        clock reads, one histogram observation and one module-global
+        read each.  Bound it at 20us per tick — 2% of a 1ms tick, 0.01%
+        of a 180 ms tick on the chip; in practice ~10us (the three
+        hand-rolled sites it replaced cost ~2us for three phases), at
+        the p25 as above."""
         assert TR.get() is None
+        hists = serving.ServingMetrics().phases
         n, reps = 2000, 30
         samples = []
         for _ in range(reps):
             t0 = time.perf_counter()
             for _ in range(n):
-                if TR.get() is not None:  # the dispatch-site check
-                    raise AssertionError
-                if TR.get() is not None:  # the retire-site check
-                    raise AssertionError
+                for name in STEADY_TICK_PHASES:
+                    with TR.phase(name, hists[name]):
+                        pass
             samples.append((time.perf_counter() - t0) / n)
         per_tick = float(np.percentile(samples, 25))
-        assert per_tick <= 2e-6, f"{per_tick * 1e6:.2f}us per tick"
+        assert per_tick <= 20e-6, f"{per_tick * 1e6:.2f}us per tick"
 
     def test_disabled_tracing_adds_no_host_syncs(self, model):
         """Structural half of the <=2%-disabled bound: with no tracer,

@@ -1,0 +1,539 @@
+"""The program's own names on the profiler's trace (ISSUE 24).
+
+* ``obs.tracing.phase`` — the one span primitive: an ``hvd:<name>``
+  ``TraceAnnotation`` on the profiler's clock, a histogram observation,
+  and the active tracer's tick row.
+* The engine loop's phases partition its time (their sums add up to
+  ``engine_loop_seconds``; none nests in another), and the tick-kind,
+  prefill-padding and paged-walk counters count where the work happens.
+* ``jax.named_scope`` cuts every compiled body into one flat vocabulary
+  (``T.DEVICE_SCOPES``) and every Pallas call carries a ``name=`` — as
+  trace-time metadata only: the optimised HLO is unchanged.
+"""
+
+import contextlib
+import glob
+import json
+import re
+import time
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+from jax.sharding import PartitionSpec as P
+
+from horovod_tpu import obs, serving, spmd
+from horovod_tpu.models import transformer as T
+from horovod_tpu.obs import tracing as TR
+from horovod_tpu.obs.registry import Histogram
+from horovod_tpu.ops import attention as A
+from horovod_tpu.ops import paged_attention as PA
+from horovod_tpu.serving import cache as C
+
+pytestmark = pytest.mark.serving
+
+PHASES = ("reclaim", "admit", "prefill", "ingest_chunk", "page_prep",
+          "tick_dispatch", "tick_device_wait", "tick_host",
+          "bookkeeping", "idle")
+KERNEL_NAMES = ("hvd_paged_attend", "hvd_flash_fwd", "hvd_flash_bwd_dq",
+                "hvd_flash_bwd_dkv")
+
+
+def _cfg(**kw):
+    base = dict(vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64,
+                max_seq=64, dtype=jnp.float32, attention_impl="reference",
+                n_kv_heads=2)
+    base.update(kw)
+    return T.TransformerConfig(**base)
+
+
+@pytest.fixture(scope="module")
+def model():
+    cfg = _cfg()
+    return T.init_params(jax.random.PRNGKey(0), cfg), cfg
+
+
+def _engine(model, **kw):
+    params, cfg = model
+    defaults = dict(n_slots=3, max_len=56, min_prefill_bucket=4,
+                    page_size=4, prefill_chunk_tokens=8,
+                    restart_backoff=0.01, restart_backoff_max=0.05)
+    defaults.update(kw)
+    return serving.InferenceEngine(
+        params, cfg, serving.EngineConfig(**defaults))
+
+
+def _host_events(trace_dir, prefix):
+    """``[(name, {stat: value})]`` of the host-plane events of a
+    profiler trace whose name starts with ``prefix``."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
+    return [(e.name, dict(e.stats))
+            for plane in ProfileData.from_file(path).planes
+            for line in plane.lines for e in line.events
+            if e.name.startswith(prefix)]
+
+
+class _Recorder:
+    """Stub tracer: keeps every ``(name, start, dur)`` handed to the tick
+    row; any other tracer call is a no-op."""
+
+    def __init__(self):
+        self.spans = []
+
+    def tick_phase(self, name, start, dur):
+        self.spans.append((name, start, dur))
+
+    def __getattr__(self, name):
+        return lambda *a, **k: None
+
+
+@contextlib.contextmanager
+def _recording():
+    rec = _Recorder()
+    prev = TR.activate(rec)
+    try:
+        yield rec
+    finally:
+        TR.activate(prev)
+
+
+# -- (1) the primitive ---------------------------------------------------------
+
+
+class TestPhasePrimitive:
+    def test_span_lands_on_the_profilers_trace_and_in_the_histogram(
+            self, tmp_path):
+        hist = Histogram()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with TR.phase("demo", hist, k=3, bucket=16) as ph:
+                time.sleep(0.01)
+        finally:
+            jax.profiler.stop_trace()
+        assert _host_events(str(tmp_path), "hvd:") == [
+            ("hvd:demo", {"k": 3, "bucket": 16})]
+        assert hist.count == 1
+        assert hist.sum == ph.dur >= 0.01
+        assert ph.start > 0
+
+    def test_without_a_trace_or_tracer_it_only_times(self):
+        assert TR.get() is None
+        hist = Histogram()
+        with TR.phase("quiet", hist):
+            pass
+        with TR.phase("no_histogram"):
+            pass
+        assert hist.count == 1
+
+    def test_exception_closes_the_span_and_propagates(self):
+        hist = Histogram()
+        with _recording() as rec, pytest.raises(KeyError):
+            with TR.phase("boom", hist):
+                raise KeyError("x")
+        assert hist.count == 1
+        assert [n for n, _, _ in rec.spans] == ["boom"]
+
+    def test_tracer_row_still_gets_the_three_tick_phases(self, model,
+                                                         tmp_path):
+        path = str(tmp_path / "trace.json")
+        TR.start(path)
+        try:
+            engine = _engine(model)
+            fut = engine.submit([2, 3, 4], max_new_tokens=6)
+            for _ in range(40):
+                if fut.done():
+                    break
+                engine.step()
+            assert fut.done()
+        finally:
+            TR.stop()
+        events = json.load(open(path))
+        row = {e["name"] for e in events if e.get("cat") == "serving.tick"}
+        assert {"tick_dispatch", "tick_device_wait", "tick_host"} <= row
+        assert row <= set(PHASES)
+        assert {"admit", "prefill"} <= row
+
+    def test_training_step_is_a_step_annotation(self, tmp_path):
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with obs.training_step():
+                pass
+        finally:
+            jax.profiler.stop_trace()
+        ((name, stats),) = _host_events(str(tmp_path), "hvd:")
+        assert name == "hvd:train_step"
+        assert "step_num" in stats
+
+
+# -- (2) the phases partition the loop ----------------------------------------
+
+
+class TestPhasesPartitionTheLoop:
+    def test_sums_add_up_to_the_loop_and_nothing_nests(self):
+        # ticks of tens of ms and idle sleeps of 5 ms, so that the bound
+        # tests the partition and not the interpreter's overhead
+        cfg = _cfg(d_model=1024, d_ff=4096, n_layers=4, vocab_size=2048)
+        model = (T.init_params(jax.random.PRNGKey(1), cfg), cfg)
+        engine = _engine(model, n_slots=4)
+        engine.warmup((3, 20))
+        engine.metrics = serving.ServingMetrics()
+        with _recording() as rec:
+            engine.start(idle_sleep=0.005)
+            idle = engine.metrics.phases["idle"]
+
+            def idle_steps(n):
+                seen, end = idle.count, time.monotonic() + 30
+                while idle.count < seen + n and time.monotonic() < end:
+                    time.sleep(0.005)
+
+            try:
+                idle_steps(5)
+                futs = [engine.submit(list(range(2, 5)), max_new_tokens=12),
+                        engine.submit(list(range(1, 21)), max_new_tokens=6),
+                        engine.submit(list(range(3, 8)), max_new_tokens=9)]
+                for f in futs:
+                    f.result(timeout=60)
+                idle_steps(5)
+            finally:
+                engine.stop()
+        stats = engine.stats()
+        sums = {n: stats[("" if n.startswith("tick_") else "phase_")
+                         + n + "_seconds"]["sum"] for n in PHASES}
+        assert all(v > 0 for v in sums.values()), sums
+        loop = stats["engine_loop_seconds"]["sum"]
+        assert loop > 0
+        assert sum(sums.values()) <= loop
+        assert sum(sums.values()) >= 0.98 * loop, (sums, loop)
+        # no phase inside another: in the order they began, each ends
+        # before the next begins
+        spans = sorted(rec.spans, key=lambda s: s[1])
+        assert {n for n, _, _ in spans} == set(PHASES)
+        for (n0, s0, d0), (n1, s1, _) in zip(spans, spans[1:]):
+            assert s0 + d0 <= s1 + 1e-9, (n0, n1)
+
+    def test_prefill_span_carries_its_attributes(self, model, tmp_path):
+        engine = _engine(model)
+        engine.warmup((3, 20))
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            a = engine.submit([2, 3, 4], max_new_tokens=2)
+            b = engine.submit(list(range(1, 21)), max_new_tokens=2)
+            for _ in range(40):
+                if a.done() and b.done():
+                    break
+                engine.step()
+        finally:
+            jax.profiler.stop_trace()
+        events = _host_events(str(tmp_path), "hvd:")
+        (pre,) = [st for n, st in events if n == "hvd:prefill"]
+        assert (pre["k"], pre["bucket"], pre["tokens"]) == (1, 4, 3)
+        assert pre["trace_ids"] == a.trace_id
+        chunks = [st for n, st in events if n == "hvd:ingest_chunk"]
+        assert [(c["lo"], c["hi"]) for c in chunks] == [
+            (0, 8), (8, 16), (16, 20)]
+        assert {c["trace_id"] for c in chunks} == {b.trace_id}
+        assert len({c["slot"] for c in chunks}) == 1
+
+
+# -- (3) tick kinds, (4) padding and walk counters ----------------------------
+
+
+class TestCountersWhereTheWorkHappens:
+    def test_tick_kinds_classify_a_scripted_sequence(self, model):
+        engine = _engine(model, overlap=False)
+        kinds = ("plain", "prefill", "chunk")
+
+        def step_kind():
+            before = engine.stats()
+            engine.step()
+            after = engine.stats()
+            grown = [k for k in kinds if after[f"decode_ticks_{k}"]
+                     > before[f"decode_ticks_{k}"]]
+            assert len(grown) <= 1
+            assert (after["decode_ticks"] - before["decode_ticks"]
+                    == len(grown))
+            return grown[0] if grown else None
+
+        assert step_kind() is None                      # idle: no tick
+        a = engine.submit([2, 3, 4], max_new_tokens=40)
+        assert step_kind() == "prefill"                 # admission + tick
+        assert step_kind() == "plain"
+        b = engine.submit(list(range(1, 21)), max_new_tokens=4)
+        assert [step_kind() for _ in range(3)] == ["chunk"] * 3
+        assert step_kind() == "plain"
+        c = engine.submit(list(range(1, 21)), max_new_tokens=4)
+        d = engine.submit([5, 6], max_new_tokens=4)
+        # the long prompt is taken alone (its own group), the short one
+        # a step later beside the second chunk: both -> "prefill"
+        assert [step_kind() for _ in range(4)] == [
+            "chunk", "prefill", "chunk", "plain"]
+        stats = engine.stats()
+        assert (sum(stats[f"decode_ticks_{k}"] for k in kinds)
+                == stats["decode_ticks"])
+        for k in kinds:
+            assert (stats[f"engine_step_seconds_{k}"]["count"]
+                    == stats[f"decode_ticks_{k}"])
+            assert stats[f"engine_step_seconds_{k}"]["sum"] > 0
+        for f in (a, b, c, d):
+            f.cancel()
+
+    def test_attach_only_admission_is_a_plain_step(self, model):
+        """A prompt that IS a registered prefix is admitted by attaching
+        its pages: no prefill executable runs, so the step that admits
+        it counts as plain (the flag is set where the work happens)."""
+        engine = _engine(model, overlap=False)
+        prefix = [2, 3, 4, 5, 6, 7, 8, 9]
+        engine.register_prefix(prefix)
+        before = engine.stats()
+        fut = engine.submit(prefix, max_new_tokens=6)
+        engine.step()
+        after = engine.stats()
+        assert after["prefill_calls"] == before["prefill_calls"]
+        assert after["decode_ticks"] == before["decode_ticks"] + 1
+        assert after["decode_ticks_plain"] == before["decode_ticks_plain"] + 1
+        assert after["decode_ticks_prefill"] == before["decode_ticks_prefill"]
+        fut.cancel()
+
+    def test_prefill_tokens_real_and_padded(self, model):
+        engine = _engine(model)
+        futs = [engine.submit([2, 3, 4, 5, 6], max_new_tokens=2),
+                engine.submit(list(range(1, 21)), max_new_tokens=2)]
+        for _ in range(40):
+            if all(f.done() for f in futs):
+                break
+            engine.step()
+        stats = engine.stats()
+        # 5 tokens in a bucket of 8; 20 tokens in three chunks of 8
+        assert stats["prefill_tokens_total"] == 5 + 20
+        assert stats["prefill_padded_tokens_total"] == 8 + 3 * 8
+        assert stats["prefill_calls"] == 4
+
+    def test_paged_live_and_walked_tokens(self, model):
+        engine = _engine(model, n_slots=3, prefill_chunk_tokens=0)
+        la, lb = 5, 7           # one bucket: admitted by one prefill
+        futs = [engine.submit(list(range(1, 1 + la)), max_new_tokens=30),
+                engine.submit(list(range(1, 1 + lb)), max_new_tokens=30)]
+        n = 6
+        for _ in range(n):
+            engine.step()
+        stats = engine.stats()
+        assert stats["decode_ticks"] == n
+        assert not any(f.done() for f in futs)
+        # tick i (from 0) attends positions <= len(prompt) + i per slot
+        assert stats["paged_live_tokens_total"] == sum(
+            (la + i + 1) + (lb + i + 1) for i in range(n))
+        max_pages = engine.slots.max_pages
+        assert max_pages == 56 // 4
+        assert stats["paged_walked_tokens_total"] == n * 3 * max_pages * 4
+        for f in futs:
+            f.cancel()
+
+    def test_grid_extent_is_the_kernels_grid(self):
+        S, Hkv, R, Dh, ps, max_pages, n_pages = 2, 2, 2, 8, 4, 3, 7
+        pool = jnp.zeros((n_pages, Hkv, ps, Dh))
+        jaxpr = jax.make_jaxpr(
+            lambda q, k, v, t, lim: PA.paged_attend(q, k, v, None, None,
+                                                    t, lim))(
+            jnp.zeros((S, Hkv, R, Dh)), pool, pool,
+            jnp.zeros((S, max_pages), jnp.int32), jnp.zeros((S,), jnp.int32))
+        (call,) = _pallas_calls(jaxpr.jaxpr)
+        assert call.params["grid_mapping"].grid == PA.grid_extent(
+            S, Hkv, max_pages) == (S, Hkv, max_pages)
+        assert call.params["name"] == PA.KERNEL_NAME == "hvd_paged_attend"
+        assert PA.grid_tokens(S, max_pages, ps) == S * max_pages * ps
+
+
+def _pallas_calls(jaxpr):
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn)
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                out.extend(_pallas_calls(inner))
+    return out
+
+
+# -- (5) scopes and kernel names in the lowered text --------------------------
+
+
+def _scopes_in(lowered) -> set:
+    """The components of every operation's ``op_name`` in a lowering."""
+    text = lowered.as_text(debug_info=True)
+    names = re.findall(r'loc\("([^"]+)"', text)
+    return {part for n in names for part in re.split(r"[/()]", n) if part}
+
+
+def _decode_tick_lowering(model, kernel=True):
+    params, cfg = model
+    S, ps, max_pages, n_pages = 2, 4, 3, 7
+    pool = C.init_page_pool(cfg, S, n_pages, ps)
+    table = jnp.zeros((S, max_pages), jnp.int32)
+    active = jnp.ones((S,), bool)
+    samp = (jnp.zeros((S,)), jnp.zeros((S,), jnp.int32), jnp.zeros((S,)),
+            jnp.zeros((S, 2), jnp.uint32))
+
+    def tick(params, tokens, pool):
+        pos = pool["pos"]
+        logits, pool = T.decode_step_paged(params, tokens, pool, table, cfg,
+                                           active, kernel=kernel)
+        return serving.InferenceEngine._pick(logits, pos, active, *samp), pool
+
+    return jax.jit(tick).lower(params, jnp.zeros((S,), jnp.int32), pool)
+
+
+def _chunk_lowering(model):
+    params, cfg = model
+    ps, n_pages = 4, 7
+    pool = C.init_page_pool(cfg, 2, n_pages, ps)
+
+    def chunk(params, pool, suffix, pages, phys, off):
+        pk, pv = C.gather_prefix_pages(pool, pages)
+        logits, suf = T.prefill_with_prefix(
+            params, suffix, pk, pv, jnp.int32(6), cfg,
+            true_len=jnp.asarray([8]))
+        return logits, C.paged_insert(
+            pool, jnp.asarray([0]), suf["pos"], phys, off, suf["k"],
+            suf["v"])
+
+    return jax.jit(chunk).lower(
+        params, pool, jnp.zeros((1, 8), jnp.int32),
+        jnp.zeros((2,), jnp.int32), jnp.zeros((1, 8), jnp.int32),
+        jnp.zeros((1, 8), jnp.int32))
+
+
+def _train_step_lowering(hvd, cfg, params, batch):
+    opt = hvd.DistributedOptimizer(optax.sgd(0.1))
+    state = opt.init(params)
+
+    def step(params, state, batch):
+        loss, grads = jax.value_and_grad(
+            lambda p: T.loss_fn(p, batch, cfg))(params)
+        updates, state = opt.update(grads, state, params)
+        return (optax.apply_updates(params, updates), state,
+                jax.lax.pmean(loss, hvd.AXIS))
+
+    fn = jax.jit(spmd.shard(step, in_specs=(P(), P(), P(hvd.AXIS)),
+                            out_specs=(P(), P(), P())))
+    return fn.lower(params, state, batch)
+
+
+class TestDeviceScopes:
+    def test_every_scope_and_kernel_name_is_in_a_lowering(self, model, hvd):
+        params, cfg = model
+        seen = _scopes_in(_decode_tick_lowering(model))
+        assert {"embed", "attn_qkv", "kv_write", "paged_attend",
+                "hvd_paged_attend", "attn_out", "mlp", "head",
+                "sample"} <= seen
+        chunk = _scopes_in(_chunk_lowering(model))
+        assert {"embed", "attn_qkv", "landed_gather", "chunk_attn",
+                "attn_out", "mlp", "head", "kv_land"} <= chunk
+        fcfg = _cfg(attention_impl="flash", max_seq=128)
+        fparams = T.init_params(jax.random.PRNGKey(2), fcfg)
+        whole = _scopes_in(jax.jit(
+            lambda p, x: T.prefill(p, x, T.init_cache(fcfg, 1, 128), fcfg)
+        ).lower(fparams, jnp.zeros((1, 128), jnp.int32)))
+        assert {"attn", "hvd_flash_fwd", "kv_land"} <= whole
+        n = hvd.size()
+        batch = {"tokens": jnp.zeros((n, 128), jnp.int32),
+                 "targets": jnp.zeros((n, 128), jnp.int32)}
+        train = _scopes_in(_train_step_lowering(hvd, fcfg, fparams, batch))
+        assert {"embed", "attn_qkv", "attn", "attn_out", "mlp", "head",
+                "loss", "grad_allreduce", "opt_update", "hvd_flash_fwd",
+                "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv"} <= train
+        assert "transpose" in train and "jvp" in train   # the backward
+        everything = seen | chunk | whole | train
+        assert set(T.DEVICE_SCOPES) <= everything
+        assert set(KERNEL_NAMES) <= everything
+
+    def test_every_pallas_call_in_ops_is_named(self):
+        import inspect
+
+        for mod in (A, PA):
+            src = inspect.getsource(mod)
+            calls = len(re.findall(r"= pl\.pallas_call\(", src))
+            assert calls and len(re.findall(r'\bname=("hvd_\w+"|KERNEL_NAME)',
+                                            src)) == calls, mod.__name__
+
+
+# -- (6) scopes are metadata only ---------------------------------------------
+
+
+def _instruction_count(lowered) -> int:
+    text = lowered.compile().as_text()
+    return sum(1 for ln in text.splitlines() if re.match(r"\s+(ROOT )?%?\S+ = ",
+                                                         ln))
+
+
+def _scoped_probe(x):
+    with jax.named_scope("mlp"):
+        return jnp.tanh(x @ x)
+
+
+class TestScopesAreMetadataOnly:
+    def test_optimised_hlo_has_the_same_instructions_without_them(
+            self, model, hvd, monkeypatch):
+        params, cfg = model
+        n = hvd.size()
+        batch = {"tokens": jnp.zeros((n, 16), jnp.int32),
+                 "targets": jnp.zeros((n, 16), jnp.int32)}
+
+        def counts():
+            return (_instruction_count(_decode_tick_lowering(model, False)),
+                    _instruction_count(_chunk_lowering(model)),
+                    _instruction_count(
+                        _train_step_lowering(hvd, cfg, params, batch)))
+
+        with_scopes = counts()
+        scoped = _scopes_in(_decode_tick_lowering(model, False))
+        monkeypatch.setattr(jax, "named_scope",
+                            lambda name: contextlib.nullcontext())
+        assert not scoped & _scopes_in(_decode_tick_lowering(model, False)) \
+            & set(T.DEVICE_SCOPES)
+        assert counts() == with_scopes
+        assert min(with_scopes) > 20
+
+    def test_cached_executables_are_keyed_by_their_metadata(self, model):
+        """Because scopes change no instruction, JAX's persistent cache
+        (which by default leaves metadata out of its key) would hand a
+        scoped program the executable of an unscoped one, and a trace
+        would show the old names (seen on the chip, PERF.md PR 24):
+        ``place_compile_cache`` (conftest calls it, as every entry point
+        does) puts the metadata in the key — the scopes and the one line
+        that emits each operation, relative to the checkout, so that
+        neither the checkout's place nor an edit to a caller moves a
+        key."""
+        import horovod_tpu as hvd_pkg
+        from horovod_tpu import compile_cache
+
+        hvd_pkg.place_compile_cache()
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+        text = _decode_tick_lowering(model, False).as_text(debug_info=True)
+        assert '"horovod_tpu/models/transformer.py"' in text
+        assert compile_cache.CHECKOUT not in text
+        # no frame of a caller (this test lowers the tick)
+        assert "test_phases.py" not in text
+        # ... and what the COMPILER keeps as op_name still holds the
+        # scope path (with full tracebacks off it is the primitive alone)
+        hlo = jax.jit(_scoped_probe).lower(jnp.ones((4, 4))).compile()
+        assert 'op_name="jit(_scoped_probe)/mlp/' in hlo.as_text()
+
+    def test_engine_compile_counts_unchanged_by_phases(self, model):
+        engine = _engine(model)
+        engine.warmup((3, 20))
+        base = engine.stats()
+        futs = [engine.submit([2, 3, 4], max_new_tokens=5),
+                engine.submit(list(range(1, 21)), max_new_tokens=5)]
+        with _recording():
+            for _ in range(60):
+                if all(f.done() for f in futs):
+                    break
+                engine.step()
+        after = engine.stats()
+        assert all(f.done() for f in futs)
+        assert after["decode_compilations"] == base["decode_compilations"] == 1
+        assert after["prefill_compilations"] == base["prefill_compilations"]
