@@ -245,14 +245,11 @@ def phase_kernels(smoke: SmokeConfig) -> Dict:
     # paged decode attention: same pool, table and limits on both sides
     from horovod_tpu.models import transformer as T
 
-    n_slots, max_pages, n_pages = 4, 6, 32
+    n_slots, max_pages, n_pages = 5, 24, 64
     G = smoke.n_heads // smoke.n_kv_heads
     rng = np.random.RandomState(smoke.seed)
     for store, ps in (("bf16", 16), ("int8", 32)):
         table = jnp.asarray(rng.randint(1, n_pages, (n_slots, max_pages)),
-                            jnp.int32)
-        # full table, partial last page, inactive slot, one-past-a-page
-        limit = jnp.asarray([ps * max_pages, ps * 2 + 5, 0, ps + 1],
                             jnp.int32)
         qg = jnp.asarray(rng.randn(n_slots, smoke.n_kv_heads, G, Dh), dt)
         kf = jnp.asarray(rng.randn(n_pages, smoke.n_kv_heads, ps, Dh),
@@ -265,6 +262,15 @@ def phase_kernels(smoke: SmokeConfig) -> Dict:
         else:
             stored = jnp.bfloat16 if low else dt
             kp, vp, ks, vs = kf.astype(stored), vf.astype(stored), None, None
+        # the kernel walks the table in blocks of pages (two blocks and
+        # three at the smoke's widths): a slot at capacity, one whose
+        # limit ends inside the second block, partial last page,
+        # inactive slot, one-past-a-page
+        block = ps * pa.block_pages(ps, smoke.n_kv_heads, Dh, stored,
+                                    max_pages)
+        limit = jnp.asarray(
+            [ps * max_pages, min(block + ps + 5, ps * max_pages - 3),
+             ps * 2 + 5, 0, ps + 1], jnp.int32)
         if smoke.expect_compiled:
             _require(pa.kernel_supported(stored, ps, Dh),
                      f"kernel_supported rejects the smoke's own "

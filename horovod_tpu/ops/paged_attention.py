@@ -10,28 +10,38 @@ pure overhead — the paper's fusion-buffer insight applied to serving:
 collapse the many small memory-bound steps into one resident pass.
 
 This kernel performs the whole resolve-dequant-attend in one Pallas
-program per ``(slot, kv-head)``:
+program per SLOT, walking only the pages the slot can attend:
 
-* the grid walks ``(slot, kv_head, page_block)`` with the PAGE BLOCK
-  innermost, so the online-softmax scratch carries across a slot's
-  pages;
-* the page table row lives in SMEM via scalar prefetch
-  (``PrefetchScalarGridSpec``) — each K/V BlockSpec's index_map reads
-  ``table[s, b]`` to stream the REFERENCED physical page straight from
-  the pool, so the gather never materializes;
-* int8 dequant is fused into the load: the page's int8 payload and its
-  per-vector scales are combined in-register (f32 compute, then cast to
-  the compute dtype — the exact :func:`~horovod_tpu.models.transformer.
-  kv_dequantize` contract, see :data:`DEQUANT_COMPUTE`);
-* masking is by LOGICAL position against a per-slot ``limit``
-  (positions ``< limit[s]`` attend) — partial last pages, page-tail
-  junk, NULL-page trash, and inactive slots (``limit == 0``) all fall
-  out of the same comparison;
+* the grid is ``(slot,)``; the pools (and the int8 scale pools) stay in
+  HBM (``memory_space=pl.ANY``) and the page table row and the per-slot
+  ``limit`` live in SMEM via scalar prefetch
+  (``PrefetchScalarGridSpec``);
+* per slot a ``fori_loop`` runs over ``ceil(limit / block_tokens)``
+  blocks of the table (:func:`walk` — the trip count is DATA, read
+  from SMEM): a slot with ``limit == 0`` walks nothing, and table
+  entries past the live pages are never read, let alone fetched;
+* a block is :func:`block_pages` pages (a function of the pool's
+  layout alone: 128 tokens for bf16 pages of 16 x 8 heads x 128).  One
+  layer's pool is ``(P, H_kv, page, Dh)``, so ``pool.at[page]`` is ONE
+  contiguous region holding every KV head of the page: the kernel
+  issues one ``make_async_copy`` per live page for K and one for V,
+  into one of two VMEM buffers, and attends the other meanwhile;
+* the block is attended for all KV heads at once (dots batched over
+  the head): int8 dequant is fused into the load — the pages' int8
+  payload and their per-vector scales are combined in-register (f32
+  compute, then cast to the compute dtype — the exact
+  :func:`~horovod_tpu.models.transformer.kv_dequantize` contract, see
+  :data:`DEQUANT_COMPUTE`);
+* masking is by LOGICAL position against ``limit`` (positions
+  ``< limit[s]`` attend) — a partial last page, page-tail junk, the
+  places of a block's dead pages and inactive slots (``limit == 0``)
+  all fall out of the same comparison;
 * cross-block combination is the standard flash-decoding online
-  softmax (running max / sum / accumulator with rescale), and the
-  kernel emits per-row ``logsumexp`` so a caller can merge the result
-  with attention over OTHER sources (the speculative VERIFY path
-  combines committed-page attention with in-window attention by LSE).
+  softmax (running max / sum / accumulator with rescale, carried by
+  the loop), and the kernel emits per-row ``logsumexp`` so a caller
+  can merge the result with attention over OTHER sources (the
+  speculative VERIFY path combines committed-page attention with
+  in-window attention by LSE).
 
 Conventions shared with :mod:`~horovod_tpu.ops.attention` via
 :mod:`~horovod_tpu.ops._pallas_util`: compiled on TPU, interpreted on
@@ -85,8 +95,9 @@ def _dequant(q, scale, dtype):
 
 
 # Minimum sublane tile (second-to-last dim) per STORED dtype on TPU: a
-# page is one ``(page_size, head_dim)`` VMEM block, and Mosaic tiles the
-# last two dims in (sublane, 128-lane) units.
+# head's share of a page is a ``(page_size, head_dim)`` slab of a VMEM
+# buffer, and Mosaic tiles the last two dims in (sublane, 128-lane)
+# units.
 _MIN_SUBLANE = {"float32": 8, "bfloat16": 16, "int8": 32}
 
 
@@ -110,106 +121,163 @@ def kernel_supported(storage_dtype, page_size: int, head_dim: int) -> bool:
             and page_size % sub == 0)
 
 
-def _kernel_body(table_ref, limit_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                 acc_ref, m_ref, l_ref, *, page_size, num_blocks,
-                 compute_dtype, quantized, ks_ref=None, vs_ref=None):
-    """One grid step: slot ``s``, kv-head ``h``, page block ``b``.
-
-    The BlockSpec index_maps already routed ``k_ref``/``v_ref`` (and the
-    scale refs) at PHYSICAL page ``table[s, b]`` — in here the block is
-    simply "this slot's pages ``b*page .. (b+1)*page`` in logical
-    order".  Scratch (``acc``/``m``/``l``) persists across the innermost
-    grid dim, carrying the online softmax over the slot's pages.
-    """
-    s, h, b = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    limit = limit_ref[s]
-
-    @pl.when(b == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when(b * page_size < limit)
-    def _step():
-        k = k_ref[0, 0]                                   # (page, Dh)
-        v = v_ref[0, 0]
-        if quantized:  # fused dequant: int8 payload * f32 scale, in-reg
-            # The scale block holds ALL of the page's heads (see the
-            # sc_spec note below); take head h's row as a column.
-            k = _dequant_col(
-                k, ks_ref[0, pl.ds(h, 1), :].reshape(page_size, 1),
-                compute_dtype)
-            v = _dequant_col(
-                v, vs_ref[0, pl.ds(h, 1), :].reshape(page_size, 1),
-                compute_dtype)
-        q = q_ref[0, 0].astype(k.dtype)                   # (R, Dh)
-        Dh = q.shape[-1]
-        s_blk = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) / np.sqrt(Dh)  # (R, page)
-        # Logical-position mask: page-tail junk / NULL-page trash /
-        # partial last page all sit at positions >= limit.
-        col = b * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s_blk.shape, 1)
-        s_blk = jnp.where(col < limit, s_blk, NEG_INF)
-
-        m_prev = m_ref[:, :1]                             # (R, 1)
-        l_prev = l_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s_blk, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s_blk - m_new)                        # (R, page) f32
-        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        # _cache_attend discipline: weights cast to V's dtype before the
-        # dot, f32 MXU accumulation.
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # (R, Dh)
-        acc_ref[...] = acc_ref[...] * alpha + pv
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-
-    @pl.when(b == num_blocks - 1)
-    def _finalize():
-        m = m_ref[:, :1]
-        l = l_ref[:, :1]
-        empty = l <= 0.0          # fully-masked row (limit == 0)
-        l_safe = jnp.where(empty, 1.0, l)
-        o_ref[0, 0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
-        lse = jnp.where(empty, NEG_INF, m + jnp.log(l_safe))  # (R, 1)
-        lse_ref[0, 0] = jnp.broadcast_to(lse.reshape(1, -1),
-                                         lse_ref.shape[2:])
-
-
 #: The kernel's name on a device trace (``pl.pallas_call(name=)``):
 #: readers of a profile find the call by it, not by operand shapes.
 KERNEL_NAME = "hvd_paged_attend"
 
-
-def grid_extent(n_slots: int, n_kv_heads: int, max_pages: int):
-    """The kernel's grid for one call: one step per (slot, KV head,
-    page block of the slot's table) — EVERY block of the table, whatever
-    the slot's ``limit`` (blocks past it are masked, not skipped).  The
-    ONE statement of the extent: :func:`_pallas_paged_attend` builds its
-    ``grid_spec`` from it and :func:`grid_tokens` counts from it, so a
-    change that shrinks the grid moves the engine's
-    ``paged_walked_tokens`` counter with it."""
-    return (n_slots, n_kv_heads, max_pages)
+# What one step of a slot's walk holds of K (and as much of V) in VMEM,
+# counted at the width it is computed at: an int8 page is widened to the
+# compute dtype on arrival, so it is budgeted at two bytes an element.
+# Two buffers each of K and V make four of these.  On a v5e the kernel
+# alone moves 250-280 GB/s at 64 KB, 360-430 at 128, 490-620 at 256,
+# 430-665 at 512 (PERF.md, PR 25): past 256 KB a longer step gains
+# long contexts what its rounding costs short ones.
+_BLOCK_BYTES = 256 * 1024
 
 
-def grid_tokens(n_slots: int, max_pages: int, page_size: int) -> int:
-    """Logical positions one call's grid visits (per KV head and
-    layer): the denominator of "live over walked"."""
-    slots, _, blocks = grid_extent(n_slots, 1, max_pages)
-    return slots * blocks * page_size
+def block_pages(page_size: int, n_kv_heads: int, head_dim: int,
+                storage_dtype, max_pages: int) -> int:
+    """Pages one step of the walk fetches and attends: as many whole
+    pages (every KV head of each) as fit :data:`_BLOCK_BYTES`, never
+    more than a slot's table holds.  A function of the pool's layout
+    alone — 8 pages (128 tokens) for bf16 pages of 16 x 8 heads x 128,
+    4 pages for int8 pages of 32, 32 for a tp shard's 2 heads."""
+    width = max(jnp.dtype(storage_dtype).itemsize, 2)
+    page_bytes = n_kv_heads * page_size * head_dim * width
+    return max(1, min(_BLOCK_BYTES // page_bytes, max_pages))
+
+
+def walk(limit, block_tokens: int):
+    """``(blocks, tokens)`` the kernel walks for a slot that attends
+    positions ``< limit``: whole blocks up to the last live position,
+    none for ``limit == 0``.  The ONE statement of the bound: it is the
+    kernel's trip count (a traced scalar read from SMEM) and, on the
+    host's ``_page_pos + 1`` (a numpy array), the engine's
+    ``paged_walked_tokens`` counter."""
+    blocks = (limit + block_tokens - 1) // block_tokens
+    return blocks, blocks * block_tokens
+
+
+def _kernel_body(table_ref, limit_ref, q_ref, k_hbm, v_hbm, *refs,
+                 page_size, n_pages, compute_dtype, quantized):
+    """One grid step: slot ``s``, every KV head, the slot's live pages.
+
+    ``k_hbm``/``v_hbm`` (and the scale pools) stay in HBM; the loop
+    fetches block ``b`` of the slot's table — ``n_pages`` pages, each
+    ONE contiguous ``(H_kv, page, Dh)`` transfer — into one of two VMEM
+    buffers while the other is attended.  The online softmax rides the
+    loop's carry.  Only pages holding a position ``< limit`` are
+    fetched: table entries past them are never read.
+    """
+    if quantized:
+        ks_hbm, vs_hbm, o_ref, lse_ref, k_buf, v_buf, ks_buf, vs_buf, \
+            sems = refs
+    else:
+        o_ref, lse_ref, k_buf, v_buf, sems = refs
+    s = pl.program_id(0)
+    Hkv, R, Dh = q_ref.shape[1:]
+    block_tokens = n_pages * page_size
+    # A limit past the table's capacity would index the table out of
+    # bounds; the reference attends nothing there either.
+    limit = jnp.minimum(limit_ref[s], table_ref.shape[1] * page_size)
+    n_blocks, _ = walk(limit, block_tokens)
+
+    @pl.when(s == 0)
+    def _clear():
+        # A dead page's place in a buffer keeps what was there before:
+        # an earlier live page (finite), or, before the first fetch,
+        # whatever VMEM held.  Its weights are exactly 0, and 0 * junk
+        # must stay 0 in the PV product.
+        v_buf[...] = jnp.zeros_like(v_buf)
+        if quantized:
+            vs_buf[...] = jnp.zeros_like(vs_buf)
+
+    def fetch(b, buf, wait):
+        """Start (or wait for) the transfers of block ``b``'s live
+        pages into buffer ``buf``."""
+        for i in range(n_pages):
+            idx = b * n_pages + i
+
+            @pl.when(idx * page_size < limit)
+            def _page():
+                # a wait needs the descriptor's shape, not its source
+                page = 0 if wait else table_ref[s, idx]
+                pairs = [(k_hbm, k_buf.at[buf, :, i]),
+                         (v_hbm, v_buf.at[buf, :, i])]
+                if quantized:
+                    pairs += [(ks_hbm, ks_buf.at[buf, i]),
+                              (vs_hbm, vs_buf.at[buf, i])]
+                for pool, dst in pairs:
+                    dma = pltpu.make_async_copy(pool.at[page], dst,
+                                                sems.at[buf])
+                    dma.wait() if wait else dma.start()
+
+    def scale_col(sc_buf, buf):
+        """The block's ``(n_pages, H_kv, page)`` scales as the column
+        ``(H_kv, block_tokens, 1)`` the payload is multiplied by."""
+        sc = sc_buf[buf][:, :, :page_size]
+        return jnp.concatenate(
+            [sc[i].reshape(Hkv, page_size, 1) for i in range(n_pages)],
+            axis=1)
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        fetch(0, 0, wait=False)
+
+    q = q_ref[0].astype(compute_dtype if quantized else k_buf.dtype)
+
+    def block(b, carry):
+        m_prev, l_prev, acc = carry
+        buf = b % 2
+
+        @pl.when(b + 1 < n_blocks)
+        def _next():
+            fetch(b + 1, 1 - buf, wait=False)
+
+        fetch(b, buf, wait=True)
+        k = k_buf[buf].reshape(Hkv, block_tokens, Dh)
+        v = v_buf[buf].reshape(Hkv, block_tokens, Dh)
+        if quantized:  # fused dequant: int8 payload * f32 scale, in-reg
+            k = _dequant_col(k, scale_col(ks_buf, buf), compute_dtype)
+            v = _dequant_col(v, scale_col(vs_buf, buf), compute_dtype)
+        s_blk = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) / np.sqrt(Dh)
+        # Logical-position mask: page-tail junk, the block's dead pages
+        # and a partial last page all sit at positions >= limit.
+        col = b * block_tokens + jax.lax.broadcasted_iota(
+            jnp.int32, s_blk.shape, 2)
+        s_blk = jnp.where(col < limit, s_blk, NEG_INF)    # (Hkv, R, T)
+
+        m_new = jnp.maximum(m_prev, jnp.max(s_blk, axis=2, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s_blk - m_new)                        # f32
+        l_new = alpha * l_prev + jnp.sum(p, axis=2, keepdims=True)
+        # _cache_attend discipline: weights cast to V's dtype before the
+        # dot, f32 MXU accumulation.
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)           # (Hkv, R, Dh)
+        return m_new, l_new, acc * alpha + pv
+
+    m, l, acc = jax.lax.fori_loop(
+        0, n_blocks, block,
+        (jnp.full((Hkv, R, 1), NEG_INF, jnp.float32),
+         jnp.zeros((Hkv, R, 1), jnp.float32),
+         jnp.zeros((Hkv, R, Dh), jnp.float32)))
+    empty = l <= 0.0              # fully-masked row (limit == 0)
+    l_safe = jnp.where(empty, 1.0, l)
+    o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
+    lse = jnp.where(empty, NEG_INF, m + jnp.log(l_safe))  # (Hkv, R, 1)
+    lse_ref[0] = jnp.broadcast_to(lse.reshape(Hkv, 1, R), lse_ref.shape[1:])
 
 
 def _pallas_paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table,
                          limit, compute_dtype):
     S, Hkv, R, Dh = qg.shape
     _, _, ps, _ = k_pool.shape
-    max_pages = table.shape[1]
     quantized = k_scale is not None
+    n_pages = block_pages(ps, Hkv, Dh, k_pool.dtype, table.shape[1])
 
     # Pad query rows up to a sublane tile so tiny G (or G*W) widths
     # still compile on real hardware; padded rows cost only VPU lanes
@@ -218,58 +286,55 @@ def _pallas_paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table,
     if R_pad != R:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, R_pad - R), (0, 0)))
 
-    kernel = functools.partial(
-        _kernel_body, page_size=ps, num_blocks=max_pages,
-        compute_dtype=compute_dtype, quantized=quantized)
-    if quantized:
-        def kernel(t, lim, q, k, v, ks, vs, o, lse, acc, m, l):  # noqa: F811
-            return _kernel_body(
-                t, lim, q, k, v, o, lse, acc, m, l, page_size=ps,
-                num_blocks=max_pages, compute_dtype=compute_dtype,
-                quantized=True, ks_ref=ks, vs_ref=vs)
+    # The table and the limits are scalar-prefetched into SMEM: the
+    # kernel reads the slot's live entries and issues the page fetches
+    # itself, so the pools never get a BlockSpec's pipeline.
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
 
-    # Scalar-prefetch args (table, limit) arrive as trailing index_map
-    # operands: the K/V specs use the TABLE ROW to stream the referenced
-    # physical page — the "gather" is just block routing.
-    q_spec = pl.BlockSpec((1, 1, R_pad, Dh), lambda s, h, b, t, lim: (s, h, 0, 0))
-    kv_spec = pl.BlockSpec((1, 1, ps, Dh),
-                           lambda s, h, b, t, lim: (t[s, b], h, 0, 0))
-    in_specs = [q_spec, kv_spec, kv_spec]
+    def of_slot(s, table, limit):
+        return (s, 0, 0, 0)
+
+    in_specs = [pl.BlockSpec((1, Hkv, R_pad, Dh), of_slot), hbm, hbm]
     operands = [qg, k_pool, v_pool]
+    # Buffers are head-major so a block reads as (H_kv, tokens, Dh)
+    # with no relayout: page i of a block lands at [:, i].
+    buf = (2, Hkv, n_pages, ps, Dh)
+    scratch = [pltpu.VMEM(buf, k_pool.dtype), pltpu.VMEM(buf, v_pool.dtype)]
     if quantized:
-        # Mosaic wants a block's last two dims in whole (8, 128) tiles
-        # or equal to the array's: a per-head (1, 1, ps) block over
-        # (P, H_kv, ps) is neither, so the block carries every head of
-        # the page (H_kv * ps f32 — a few hundred bytes) and the kernel
-        # picks its row.
-        sc_spec = pl.BlockSpec((1, Hkv, ps),
-                               lambda s, h, b, t, lim: (t[s, b], 0, 0))
-        in_specs += [sc_spec, sc_spec]
-        operands += [k_scale, v_scale]
+        # Mosaic slices an HBM ref in whole 128-lane rows only, so a
+        # page's (H_kv, page) scales travel padded to the lane width.
+        lanes = -(-ps // 128) * 128
+        pad = ((0, 0), (0, 0), (0, lanes - ps))
+        in_specs += [hbm, hbm]
+        operands += [jnp.pad(k_scale, pad), jnp.pad(v_scale, pad)]
+        sc_buf = (2, n_pages, Hkv, lanes)
+        scratch += [pltpu.VMEM(sc_buf, k_scale.dtype),
+                    pltpu.VMEM(sc_buf, v_scale.dtype)]
+    scratch.append(pltpu.SemaphoreType.DMA((2,)))         # one a buffer
 
     o_shape = jax.ShapeDtypeStruct((S, Hkv, R_pad, Dh), jnp.float32)
     # lse rides a sublane-replicated (…, 8, R) layout, like the flash
     # kernel's — callers read row 0.
     lse_shape = jax.ShapeDtypeStruct((S, Hkv, 8, R_pad), jnp.float32)
     out_specs = [
-        pl.BlockSpec((1, 1, R_pad, Dh), lambda s, h, b, t, lim: (s, h, 0, 0)),
-        pl.BlockSpec((1, 1, 8, R_pad), lambda s, h, b, t, lim: (s, h, 0, 0)),
+        pl.BlockSpec((1, Hkv, R_pad, Dh), of_slot),
+        pl.BlockSpec((1, Hkv, 8, R_pad), of_slot),
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=grid_extent(S, Hkv, max_pages),
+        grid=(S,),
         in_specs=in_specs,
         out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((R_pad, Dh), jnp.float32),    # acc
-            pltpu.VMEM((R_pad, 128), jnp.float32),   # running max
-            pltpu.VMEM((R_pad, 128), jnp.float32),   # running sum
-        ],
+        scratch_shapes=scratch,
     )
     o, lse = pl.pallas_call(
-        kernel,
+        functools.partial(_kernel_body, page_size=ps, n_pages=n_pages,
+                          compute_dtype=compute_dtype, quantized=quantized),
         grid_spec=grid_spec,
         out_shape=[o_shape, lse_shape],
+        # the buffers' zeroing at slot 0 must come first
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=use_interpret(),
         name=KERNEL_NAME,
     )(table.astype(jnp.int32), limit.astype(jnp.int32), *operands)

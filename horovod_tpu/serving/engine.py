@@ -102,6 +102,7 @@ import numpy as np
 
 from horovod_tpu.models import transformer as T
 from horovod_tpu.obs import tracing as obs_tracing
+from horovod_tpu.ops import paged_attention as _pa
 from horovod_tpu.serving.cache import (  # noqa: F401
     NULL_PAGE,
     PagedSlotCache,
@@ -736,7 +737,6 @@ class InferenceEngine:
         self._paged_kernel = False
         if engine_cfg.paged:
             from horovod_tpu.ops import _pallas_util
-            from horovod_tpu.ops import paged_attention as _pa
 
             layouts = [(self.slots._storage_dtype, engine_cfg.page_size,
                         cfg.head_dim)]
@@ -946,16 +946,16 @@ class InferenceEngine:
         self._dev_table = None
         self._table_uploaded = -1
         # What else the CURRENT step ran beside its decode tick
-        # (_observe_step_kind), and the positions one paged tick's
-        # attention grid visits (_count_paged_walk).
+        # (_observe_step_kind), and the tokens one step of the paged
+        # kernel's walk covers for this pool (_count_paged_walk; the
+        # kernel sees a tp shard's heads).
         self._step_tick = self._step_prefill = self._step_chunk = False
-        self._walk_tokens = 0
+        self._walk_block_tokens = 0
         if engine_cfg.paged:
-            from horovod_tpu.ops import paged_attention as _pa
-
-            self._walk_tokens = _pa.grid_tokens(
-                engine_cfg.n_slots, self.slots.max_pages,
-                self.slots.page_size)
+            self._walk_block_tokens = self.slots.page_size * _pa.block_pages(
+                self.slots.page_size, cfg.kv_heads // engine_cfg.tp,
+                cfg.head_dim, self.slots._storage_dtype,
+                self.slots.max_pages)
         # Registered shared prefixes (token tuple -> entry); epoch
         # stamps which cache lifetime the pinned pages belong to.
         self._prefixes: Dict[tuple, _PrefixEntry] = {}
@@ -2223,10 +2223,12 @@ class InferenceEngine:
         """One dispatched paged tick: the positions its active slots may
         attend (everything up to and including the token being written —
         read BEFORE the dispatch-time advance of ``_page_pos``) beside
-        the positions the attention's grid visits."""
-        self.metrics.paged_live_tokens.inc(
-            int(self._page_pos[active].sum()) + int(active.sum()))
-        self.metrics.paged_walked_tokens.inc(self._walk_tokens)
+        the positions the kernel's walk covers for those limits (the
+        kernel's own trip count, ``ops.paged_attention.walk``)."""
+        limit = self._page_pos[active] + 1
+        _, walked = _pa.walk(limit, self._walk_block_tokens)
+        self.metrics.paged_live_tokens.inc(int(limit.sum()))
+        self.metrics.paged_walked_tokens.inc(int(walked.sum()))
 
     def _reclaim_cancelled(self) -> bool:
         """Free slots whose requests were cancelled caller-side — their

@@ -99,8 +99,8 @@ class ServingMetrics:
       bucket-/chunk-padded tokens they actually ran.
     * ``paged_live_tokens`` / ``paged_walked_tokens`` — per dispatched
       paged tick, the positions the active slots may attend, and the
-      positions the attention's grid visits
-      (``ops.paged_attention.grid_tokens``).
+      positions the paged kernel's walk covers for them: each slot's
+      limit rounded up to a block (``ops.paged_attention.walk``).
     * ``kv_pages_total`` / ``kv_pages_free`` / ``kv_pages_shared`` /
       ``kv_bytes_per_token`` — page-pool pressure gauges for the paged
       KV cache (docs/serving.md "Paged KV cache"): pool size, free
@@ -224,7 +224,8 @@ class ServingMetrics:
         self.paged_walked_tokens = r.counter(
             "serving_paged_walked_tokens_total",
             "Per dispatched paged tick, the positions the paged "
-            "attention's grid visits (every page block of every slot)")
+            "attention's walk covers (each active slot's limit rounded "
+            "up to a block of pages)")
         self.decode_ticks = r.counter(
             "serving_decode_ticks_total", "Decode ticks dispatched")
         self.host_syncs = r.counter(

@@ -763,40 +763,97 @@ class TestFusedPagedKernel:
 
     _KV = [None, "bf16", "int8"]
 
-    @pytest.mark.parametrize("kv", _KV)
-    def test_kernel_matches_reference_edge_tables(self, model, kv):
-        """Unit: Pallas kernel == pure-JAX reference over one layer's
-        pool for the edge-case table set — partial last page, a slot at
-        exactly table capacity, an inactive (fully masked) slot, and a
-        REPEATED page id (the refcount>1 / COW-shared shape: two slots'
-        tables referencing the same physical page)."""
-        from horovod_tpu.ops import paged_attention as PA
+    # The walk's edge cases.  ``block`` is the pages one step of the walk
+    # takes (None: what the shapes give, the whole toy table); a small
+    # one is set on the module, so toy pools walk many blocks.
+    _WALKS = {
+        # partial last page, a slot at exactly table capacity, an
+        # inactive (fully masked) slot, and a REPEATED page id (the
+        # refcount>1 / COW-shared shape: two tables on one page)
+        "edge_tables": dict(S=4, Hkv=2, R=2, MP=3, block=None,
+                            limits=[24, 5, 0, 11], share=True),
+        # block = 2 pages = 16 tokens: limit 0, 1, one block, one block
+        # + 1, and capacity (4 blocks: both buffers are used twice)
+        "block_bounds": dict(S=5, Hkv=2, R=2, MP=8, block=2,
+                             limits=[0, 1, 16, 17, 64]),
+        # an odd table: the last block is cut by the table's end
+        "wrapping_buffers": dict(S=3, Hkv=2, R=2, MP=7, block=2,
+                                 limits=[56, 41, 33]),
+        # R = G x W rows of a speculative VERIFY window (padded to 16)
+        "verify_rows": dict(S=3, Hkv=2, R=2 * 5, MP=6, block=2,
+                            limits=[48, 19, 0]),
+        # one KV head: what a tp shard of a GQA model sees
+        "one_kv_head": dict(S=3, Hkv=1, R=4, MP=6, block=4,
+                            limits=[48, 30, 7]),
+        # every page that holds no live position is inf, and the table's
+        # entries past the live pages point at such pages: a dead page
+        # that reached the softmax would turn the output to nan
+        "poisoned_dead_pages": dict(S=4, Hkv=2, R=2, MP=7, block=2,
+                                    limits=[0, 3, 24, 56], poison=True),
+    }
 
-        _, cfg = model
-        rng = np.random.RandomState(3)
-        S, Hkv, G, Dh, ps, MP = 4, 2, 2, 16, 8, 3
-        Pn = 8
-        qg = jnp.asarray(rng.randn(S, Hkv, G, Dh), jnp.float32)
+    @staticmethod
+    def _walk_case(rng, kv, *, S, Hkv, R, MP, limits, ps=8, Dh=16,
+                   share=False):
+        """One layer's pool (k, v, k_scale, v_scale), a table over it
+        and queries, for the kernel and the reference alike."""
+        Pn = S * MP + 1
+        qg = jnp.asarray(rng.randn(S, Hkv, R, Dh), jnp.float32)
         kf = rng.randn(Pn, Hkv, ps, Dh).astype(np.float32)
         vf = rng.randn(Pn, Hkv, ps, Dh).astype(np.float32)
-        table = np.asarray(rng.randint(1, Pn, (S, MP)), np.int32)
-        table[1] = table[0]           # shared pages, refcount > 1
-        limit = jnp.asarray([ps * MP,  # exactly at table capacity
-                             5,        # partial last page
-                             0,        # inactive: fully masked
-                             ps + 3], jnp.int32)
+        # every slot its own pages, in a shuffled order
+        table = (1 + rng.permutation(S * MP)).reshape(S, MP).astype(np.int32)
+        if share:
+            table[1] = table[0]       # shared pages, refcount > 1
         if kv == "int8":
             kq, ks = T.kv_quantize(jnp.asarray(kf))
             vq, vs = T.kv_quantize(jnp.asarray(vf))
-            args = (qg, kq, vq, ks, vs)
+            pool = [kq, vq, ks, vs]
         else:
             dt = jnp.bfloat16 if kv == "bf16" else jnp.float32
-            args = (qg, jnp.asarray(kf, dt), jnp.asarray(vf, dt),
-                    None, None)
+            pool = [jnp.asarray(kf, dt), jnp.asarray(vf, dt), None, None]
+        return qg, pool, table, jnp.asarray(limits, jnp.int32)
+
+    @staticmethod
+    def _poisoned(pool, table, limits, ps):
+        """``pool`` with inf in every page no live position references
+        (an int8 page is poisoned through its scales)."""
+        dead = np.ones(pool[0].shape[0], bool)
+        for row, lim in zip(table, limits):
+            dead[row[:-(-lim // ps)]] = False
+        k, v, ks, vs = pool
+        if ks is None:
+            return [jnp.where(dead[:, None, None, None], jnp.inf, x)
+                    for x in (k, v)] + [None, None]
+        return [k, v] + [jnp.where(dead[:, None, None], jnp.inf, x)
+                         for x in (ks, vs)]
+
+    @pytest.mark.parametrize("walk", list(_WALKS))
+    @pytest.mark.parametrize("kv", _KV)
+    def test_kernel_matches_reference_edge_tables(self, model, kv, walk,
+                                                  monkeypatch):
+        """Unit: Pallas kernel == pure-JAX reference over one layer's
+        pool, for each of :attr:`_WALKS`."""
+        from horovod_tpu.ops import paged_attention as PA
+
+        _, cfg = model
+        case = dict(self._WALKS[walk])
+        block, poison = case.pop("block"), case.pop("poison", False)
+        qg, pool, table, limit = self._walk_case(
+            np.random.RandomState(3), kv, **case)
+        _, Hkv, ps, Dh = pool[0].shape
+        if block is not None:
+            width = max(pool[0].dtype.itemsize, 2)
+            monkeypatch.setattr(PA, "_BLOCK_BYTES",
+                                block * Hkv * ps * Dh * width)
+            assert PA.block_pages(ps, Hkv, Dh, pool[0].dtype,
+                                  case["MP"]) == block
         tab = jnp.asarray(table)
-        o_r, l_r = PA.paged_attend_reference(*args, tab, limit,
+        o_r, l_r = PA.paged_attend_reference(qg, *pool, tab, limit,
                                              compute_dtype=cfg.dtype)
-        o_k, l_k = PA._pallas_paged_attend(*args, tab, limit, cfg.dtype)
+        if poison:
+            pool = self._poisoned(pool, table, case["limits"], ps)
+        o_k, l_k = PA._pallas_paged_attend(qg, *pool, tab, limit, cfg.dtype)
         tol = 2e-2 if kv == "bf16" else 1e-4
         np.testing.assert_allclose(np.asarray(o_k), np.asarray(o_r),
                                    atol=tol, rtol=tol)

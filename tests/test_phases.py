@@ -19,6 +19,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
 import pytest
 from jax.sharding import PartitionSpec as P
@@ -311,8 +312,14 @@ class TestCountersWhereTheWorkHappens:
         assert stats["prefill_padded_tokens_total"] == 8 + 3 * 8
         assert stats["prefill_calls"] == 4
 
-    def test_paged_live_and_walked_tokens(self, model):
+    def test_paged_live_and_walked_tokens(self, model, monkeypatch):
+        _, cfg = model
+        ps, pages = 4, 2        # a walk of 2 pages = 8 tokens a step
+        monkeypatch.setattr(
+            PA, "_BLOCK_BYTES", pages * cfg.kv_heads * ps * cfg.head_dim * 4)
         engine = _engine(model, n_slots=3, prefill_chunk_tokens=0)
+        assert engine.slots.page_size == ps
+        assert engine.slots.max_pages == 56 // ps
         la, lb = 5, 7           # one bucket: admitted by one prefill
         futs = [engine.submit(list(range(1, 1 + la)), max_new_tokens=30),
                 engine.submit(list(range(1, 1 + lb)), max_new_tokens=30)]
@@ -324,15 +331,26 @@ class TestCountersWhereTheWorkHappens:
         assert not any(f.done() for f in futs)
         # tick i (from 0) attends positions <= len(prompt) + i per slot
         assert stats["paged_live_tokens_total"] == sum(
-            (la + i + 1) + (lb + i + 1) for i in range(n))
-        max_pages = engine.slots.max_pages
-        assert max_pages == 56 // 4
-        assert stats["paged_walked_tokens_total"] == n * 3 * max_pages * 4
+            (la + i + 1) + (lb + i + 1) for i in range(n)) == 114
+        # ... and walks each slot's limit rounded up to 8: limits 6..11
+        # and 8..13; the third slot is idle and walks nothing
+        assert stats["paged_walked_tokens_total"] == (
+            3 * 8 + 3 * 16) + (8 + 5 * 16)
         for f in futs:
             f.cancel()
 
-    def test_grid_extent_is_the_kernels_grid(self):
-        S, Hkv, R, Dh, ps, max_pages, n_pages = 2, 2, 2, 8, 4, 3, 7
+    def test_walk_is_the_kernels_bound(self, monkeypatch):
+        """One Pallas call, found the way the benchmark finds it (its
+        name; the ``(S, max_pages)`` int32 table its first operand),
+        one grid step a slot, and a trip count from ``PA.walk`` — the
+        function the engine's counter calls."""
+        S, Hkv, R, Dh, ps, max_pages, n_pages = 2, 2, 2, 8, 4, 6, 13
+        monkeypatch.setattr(PA, "_BLOCK_BYTES", 2 * Hkv * ps * Dh * 4)
+        assert PA.block_pages(ps, Hkv, Dh, jnp.float32, max_pages) == 2
+        asked = []
+        walk = PA.walk
+        monkeypatch.setattr(
+            PA, "walk", lambda limit, bt: asked.append(bt) or walk(limit, bt))
         pool = jnp.zeros((n_pages, Hkv, ps, Dh))
         jaxpr = jax.make_jaxpr(
             lambda q, k, v, t, lim: PA.paged_attend(q, k, v, None, None,
@@ -340,10 +358,17 @@ class TestCountersWhereTheWorkHappens:
             jnp.zeros((S, Hkv, R, Dh)), pool, pool,
             jnp.zeros((S, max_pages), jnp.int32), jnp.zeros((S,), jnp.int32))
         (call,) = _pallas_calls(jaxpr.jaxpr)
-        assert call.params["grid_mapping"].grid == PA.grid_extent(
-            S, Hkv, max_pages) == (S, Hkv, max_pages)
         assert call.params["name"] == PA.KERNEL_NAME == "hvd_paged_attend"
-        assert PA.grid_tokens(S, max_pages, ps) == S * max_pages * ps
+        assert call.params["grid_mapping"].grid == (S,)
+        table = call.invars[0].aval
+        assert (table.shape, table.dtype) == ((S, max_pages), jnp.int32)
+        assert asked == [2 * ps]
+        # without the cap of the budget a step takes the whole table
+        monkeypatch.undo()
+        assert PA.block_pages(ps, Hkv, Dh, jnp.float32, max_pages) == max_pages
+        blocks, tokens = PA.walk(np.array([0, 1, 8, 9, 24]), 8)
+        assert blocks.tolist() == [0, 1, 1, 2, 3]
+        assert tokens.tolist() == [0, 8, 8, 16, 24]
 
 
 def _pallas_calls(jaxpr):
