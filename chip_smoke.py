@@ -9,8 +9,11 @@ families sit on their supported tiling):
 1. **kernels** — flash forward and gradients against
    ``attention.reference_attention`` (plain and under ``remat``);
    ``paged_attend`` against ``paged_attend_reference`` on the same pool,
-   table and limits (bf16 pages of 16, int8 pages of 32); one fused decode
-   tick against the unfused one at LOGIT level on the full-width model.
+   table and limits (bf16 pages of 16, int8 pages of 32), without and with
+   a window layer's lower bound; the top-k grouped expert products (the
+   Pallas kernel and ``lax.ragged_dot``) against every expert under a
+   mask; one fused decode tick against the unfused one at LOGIT level on
+   the full-width model.
 2. **trainer** — ``hvd.init()`` → ``hvd.DistributedOptimizer(optax.adamw)``
    → ``jax.jit(spmd.shard(step), donate_argnums=...)`` exactly as
    ``benchmarks/transformer.py`` builds it, a few steps on a fixed batch:
@@ -300,6 +303,75 @@ def phase_kernels(smoke: SmokeConfig) -> Dict:
         _require(not np.asarray(o_k)[~live].any(),
                  f"paged_attend ({store}): masked slot produced output")
         report[f"paged_{store}_max_abs_err"] = max(e_o, e_l)
+
+        # the same pool through a WINDOW layer's call: a lower bound
+        # that starts mid-block, one inside the first block, a window
+        # longer than the context, an idle slot, a window of one page
+        lower = jnp.maximum(limit - jnp.asarray(
+            [block + 3, ps + 5, 10 * ps * max_pages, 0, ps], jnp.int32), 0)
+        wargs = args + (lower,)
+        run_w = jax.jit(lambda *a: pa.paged_attend(
+            *a[:7], compute_dtype=dt, lower=a[7])).lower(*wargs).compile()
+        _require_compiled(smoke, run_w.as_text(), 1,
+                          f"paged_attend with a lower bound ({store})")
+        o_k, lse_k = run_w(*wargs)
+        o_r, lse_r = jax.jit(lambda *a: pa.paged_attend_reference(
+            *a[:7], compute_dtype=dt, lower=a[7]))(*wargs)
+        e_w = max(float(jnp.max(jnp.abs(o_k - o_r))),
+                  float(np.max(np.abs(np.asarray(lse_k)[live]
+                                      - np.asarray(lse_r)[live]))))
+        _require(e_w <= out_tol,
+                 f"paged_attend with a lower bound ({store}, page {ps}) "
+                 f"vs reference: max err {e_w} > {out_tol}")
+        report[f"paged_{store}_window_max_abs_err"] = e_w
+
+    # top-k dropless experts: the grouped products of sorted rows (the
+    # Pallas kernel over the stacked layers' experts, and lax.ragged_dot
+    # over one layer's) against every expert under a mask
+    from horovod_tpu.ops import moe
+
+    L, E, F, topk, rows = 2, 8, 4 * Dh, 2, 4 * smoke.n_heads
+    ke = jax.random.split(kd, 5)
+    x = jax.random.normal(ke[0], (rows, smoke.d_model), jnp.float32)
+    router = jax.random.normal(ke[1], (smoke.d_model, E), jnp.float32)
+    s_d, s_f = smoke.d_model ** -0.5, F ** -0.5
+    wg = jax.random.normal(ke[2], (L, E, smoke.d_model, F)) * s_d
+    wu = jax.random.normal(ke[3], (L, E, smoke.d_model, F)) * s_d
+    wd = jax.random.normal(ke[4], (L, E, F, smoke.d_model)) * s_f
+    x, wg, wu, wd = (a.astype(dt) for a in (x, wg, wu, wd))
+    mask = jnp.arange(rows) % 5 != 0
+    layer = jnp.int32(L - 1)
+
+    def stacked(x, wg, wu, wd, layer):
+        return moe.dropless_moe(x, router, wg, wu, wd, k=topk,
+                                norm_topk=True, token_mask=mask,
+                                layer=layer)
+
+    def ragged(x, wg, wu, wd, layer):
+        return moe.dropless_moe(x, router, wg[L - 1], wu[L - 1], wd[L - 1],
+                                k=topk, norm_topk=True, token_mask=mask)
+
+    def oracle(x, wg, wu, wd, layer):
+        top, gate = moe.route_topk(x, router, topk, True)
+        w = jnp.zeros((rows, E)).at[jnp.arange(rows)[:, None], top].set(gate)
+        xf, g, u, d = (a.astype(jnp.float32)
+                       for a in (x, wg[L - 1], wu[L - 1], wd[L - 1]))
+        y = jnp.einsum("esf,efd->esd",
+                       jax.nn.silu(jnp.einsum("sd,edf->esf", xf, g))
+                       * jnp.einsum("sd,edf->esf", xf, u), d)
+        return jnp.where(mask[:, None], jnp.einsum("esd,se->sd", y, w), 0.0)
+
+    eargs = (x, wg, wu, wd, layer)
+    run_e = jax.jit(stacked).lower(*eargs).compile()
+    _require_compiled(smoke, run_e.as_text(), 3, "grouped expert products")
+    want = jax.jit(oracle)(*eargs)
+    scale = float(jnp.max(jnp.abs(want)))
+    for name, got in (("kernel", run_e(*eargs)),
+                      ("ragged_dot", jax.jit(ragged)(*eargs))):
+        err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want))) / scale
+        _require(err <= out_tol, f"top-{topk} dropless experts ({name}) vs "
+                 f"every expert under a mask: rel err {err} > {out_tol}")
+        report[f"moe_top{topk}_{name}_rel_err"] = err
     _say("kernels: " + json.dumps(report))
     return report
 

@@ -18,11 +18,17 @@ Design notes (TPU):
 * Optional mixture-of-experts MLP (``n_experts > 1``): experts stacked on
   an axis sharded over ``ep``; top-1 routing computed densely (exact, and
   compiles to einsums the MXU likes at benchmark scales).
+* Serving only: a ``layer_pattern`` of full and sliding-window layers
+  (rope by kind, two kinds of KV state) and top-k dropless experts, in
+  ``prefill`` / ``prefill_with_prefix`` / ``decode_step_paged``
+  (:func:`_scan_layer_kinds`); the other bodies refuse such a
+  configuration (:class:`UnsupportedModelConfigError`).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Dict, Optional
 
 import jax
@@ -30,6 +36,17 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
+
+
+#: The kinds of layer a ``layer_pattern`` may name.
+LAYER_KINDS = ("full", "sliding")
+
+
+class UnsupportedModelConfigError(ValueError):
+    """An entry point was handed a configuration it does not compute
+    (a layer pattern, a window or more than one expert a token where
+    only the uniform top-1 model is written): refused, never run as
+    another model."""
 
 
 @dataclasses.dataclass(frozen=True)
@@ -95,11 +112,62 @@ class TransformerConfig:
     # the cheap elementwise work (jax checkpoint_dots policy) — much less
     # recompute when HBM still fits the dot outputs.
     remat_policy: str = "full"
+    # Head size when it is a key of its own (0 = d_model / n_heads): the
+    # q projection is then d_model -> n_heads * d_head.
+    d_head: int = 0
+    # RMSNorm epsilon, every norm of the model.
+    norm_eps: float = 1e-6
+    # RMSNorm with a learned scale over each head's q and k, before RoPE.
+    qk_norm: bool = False
+    # One PERIOD of layer kinds, each "full" or "sliding", repeated
+    # n_layers / len times (() = every layer full).  A sliding layer
+    # attends the last ``window`` positions only and takes the plain
+    # rope; a full layer takes ``rope_yarn`` when it is set.
+    layer_pattern: tuple = ()
+    window: int = 0
+    # YaRN on the full layers: (factor, original_max_position,
+    # beta_fast, beta_slow, attention_factor), () = plain rope.
+    rope_yarn: tuple = ()
+    rope_theta_sliding: float = 0.0  # 0 = rope_theta
+    # Experts a token (top-k routing; serving dispatches only), the
+    # experts' width (0 = d_ff) and whether the k weights are
+    # renormalised to sum to one.
+    n_experts_per_tok: int = 1
+    d_expert: int = 0
+    norm_topk_prob: bool = False
+
+    def __post_init__(self):
+        bad = [k for k in self.layer_pattern if k not in LAYER_KINDS]
+        if bad:
+            raise ValueError(f"unknown layer kind(s) {bad}; expected "
+                             f"{LAYER_KINDS}")
+        if self.layer_pattern and self.n_layers % len(self.layer_pattern):
+            raise ValueError(
+                f"n_layers={self.n_layers} is not a whole number of "
+                f"periods of {self.layer_pattern}")
+        if self.has_window and self.window < 1:
+            raise ValueError("a 'sliding' layer needs window >= 1")
 
     @property
     def head_dim(self) -> int:
+        if self.d_head:
+            return self.d_head
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
+
+    @property
+    def expert_width(self) -> int:
+        return self.d_expert or self.d_ff
+
+    @property
+    def has_window(self) -> bool:
+        """Does any layer attend a window (two kinds of KV state)?"""
+        return "sliding" in self.layer_pattern
+
+    def kind_count(self, kind: str) -> int:
+        """How many of the layers are of one kind."""
+        period = self.layer_pattern or ("full",)
+        return period.count(kind) * (self.n_layers // len(period))
 
     @property
     def kv_heads(self) -> int:
@@ -122,6 +190,8 @@ def init_params(rng, cfg: TransformerConfig) -> Dict:
         cfg.vocab_size,
     )
     E = max(cfg.n_experts, 0)
+    if E > 1:
+        F = cfg.expert_width
 
     def norm_init(k, shape, scale):
         return (jax.random.normal(k, shape) * scale).astype(jnp.float32)
@@ -134,8 +204,11 @@ def init_params(rng, cfg: TransformerConfig) -> Dict:
         "wq": norm_init(keys[0], (L, D, H, Dh), s_d),
         "wk": norm_init(keys[1], (L, D, cfg.kv_heads, Dh), s_d),
         "wv": norm_init(keys[2], (L, D, cfg.kv_heads, Dh), s_d),
-        "wo": norm_init(keys[3], (L, H, Dh, D), s_d),
+        "wo": norm_init(keys[3], (L, H, Dh, D), 1.0 / np.sqrt(H * Dh)),
     }
+    if cfg.qk_norm:
+        layers.update(q_norm=jnp.ones((L, Dh), jnp.float32),
+                      k_norm=jnp.ones((L, Dh), jnp.float32))
     if E > 1:
         layers.update(
             router=norm_init(keys[4], (L, D, E), s_d),
@@ -169,6 +242,8 @@ def param_specs(cfg: TransformerConfig) -> Dict:
         "wv": P("pp", "fsdp", "tp", None),
         "wo": P("pp", "tp", None, "fsdp"),
     }
+    if cfg.qk_norm:
+        layers.update(q_norm=P("pp", None), k_norm=P("pp", None))
     if cfg.n_experts > 1:
         layers.update(
             router=P("pp", None, None),
@@ -227,23 +302,51 @@ DEVICE_SCOPES = (
 )
 
 
-def _rmsnorm(x, scale):
+def _rmsnorm(x, scale, eps: float = 1e-6):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    out = x.astype(jnp.float32) * jax.lax.rsqrt(var + 1e-6)
+    out = x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)
     return (out * scale).astype(x.dtype)
 
 
-def _rope(q, k, theta: float, pos_offset=0, positions=None):
+def yarn_inv_freq(head_dim: int, theta: float, yarn: tuple):
+    """YaRN's per-pair inverse frequencies ``(head_dim / 2,)`` float32
+    for ``yarn = (factor, original_max_position, beta_fast, beta_slow,
+    attention_factor)``: pair ``i`` keeps the plain ``theta**(-2i/d)``
+    below the ramp (``i <= low``: wavelengths the original context
+    turned ``beta_fast`` times or more), is divided by ``factor`` above
+    it (``i >= high``), and is blended linearly between."""
+    factor, orig, beta_fast, beta_slow = yarn[:4]
+    d = head_dim
+
+    def c(r):  # the pair whose wavelength turns r times in ``orig``
+        return d * math.log(orig / (2 * math.pi * r)) / (2 * math.log(theta))
+
+    low = max(math.floor(c(beta_fast)), 0)
+    high = min(math.ceil(c(beta_slow)), d - 1)
+    if high == low:
+        high += 0.001  # the published code's guard against 0 / 0
+    i = jnp.arange(0, d // 2, dtype=jnp.float32)
+    ramp = jnp.clip((i - low) / (high - low), 0.0, 1.0)
+    return (ramp / factor + (1.0 - ramp)) / (theta ** (i / (d // 2)))
+
+
+def _rope(q, k, theta: float, pos_offset=0, positions=None, yarn=()):
     """Rotary position embedding over the head dim (applied to q and k).
     Shapes: (B, S, H, Dh).  ``pos_offset`` shifts positions when the
     sequence axis is sharded (ring attention: shard r starts at
     r*S_local); ``positions`` overrides with EXPLICIT global positions —
     ``(S,)`` per sequence row (zigzag layout: this shard's rows are
     non-contiguous) or ``(B, S)`` per BATCH row (continuous-batching
-    decode: every cache slot sits at a different depth)."""
+    decode: every cache slot sits at a different depth).  ``yarn``
+    (:func:`yarn_inv_freq`) rescales the frequencies and multiplies cos
+    and sin by its attention factor."""
     B, S, H, Dh = q.shape
     half = Dh // 2
-    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if yarn:
+        freqs = yarn_inv_freq(Dh, theta, yarn)
+    else:
+        freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32)
+                                 / half))
     pos = (positions.astype(jnp.float32) if positions is not None
            else pos_offset + jnp.arange(S, dtype=jnp.float32))
     ang = pos[..., None] * freqs  # (S, half) or (B, S, half)
@@ -251,6 +354,8 @@ def _rope(q, k, theta: float, pos_offset=0, positions=None):
         ang = ang[None]
     cos = jnp.cos(ang)[:, :, None, :]  # (1 | B, S, 1, half)
     sin = jnp.sin(ang)[:, :, None, :]
+    if yarn:
+        cos, sin = cos * yarn[4], sin * yarn[4]
 
     def rot(x):
         x1, x2 = x[..., :half], x[..., half:]
@@ -270,27 +375,118 @@ def _scan_layers(layer, init, xs):
         return lax.scan(layer, init, xs)
 
 
+def _require_uniform(cfg: TransformerConfig, what: str) -> None:
+    """Refuse a configuration with more than one kind of layer where
+    only the uniform block is written (training, the contiguous and
+    speculative decode bodies, the pipeline schedules)."""
+    if cfg.has_window:
+        raise UnsupportedModelConfigError(
+            f"{what} computes one kind of layer; this configuration's "
+            f"pattern {cfg.layer_pattern} (window {cfg.window}) is served "
+            "by prefill, prefill_with_prefix and decode_step_paged only")
+
+
+_EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+
+
+def _scan_layer_kinds(cfg: TransformerConfig, layer, init, layers, xs=None):
+    """:func:`_scan_layers` for a stack with more than one kind of
+    layer.  ``layer(carry, p, kind, xs_l) -> (carry, ys_l)`` is told its
+    layer's kind as a Python string; ``xs`` maps a kind to a pytree
+    stacked over THAT kind's layers (a pool of its own shape), and the
+    result maps each kind to its layers' stacked ``ys``.
+
+    A uniform stack is one plain scan over the layers.  A patterned one
+    scans over PERIODS: the body applies the period's layers in order,
+    each with its static kind, so the two kinds may differ in mask,
+    rope and the shape of what they carry, and the program still holds
+    one copy of each kind's layer whatever the depth.  A layer's
+    parameters are cut out of the stack one layer at a time (as a plain
+    scan cuts them), never a period at a time.
+
+    An expert model's three expert matrices are NOT cut out at all:
+    ``p["expert_stack"]`` hands the layer every layer's, stacked, with
+    the layer's (traced) index — the grouped product reads its experts
+    in place (:func:`~horovod_tpu.ops.moe.grouped_matmul`)."""
+    xs = xs or {}
+    stack = None
+    if cfg.n_experts > 1:
+        stack = {k: layers[k] for k in _EXPERT_LEAVES}
+        layers = {k: v for k, v in layers.items() if k not in stack}
+
+    def with_stack(p, l):
+        return p if stack is None else {**p, "expert_stack": (stack, l)}
+
+    period = cfg.layer_pattern
+    if len(set(period)) <= 1:
+        kind = period[0] if period else "full"
+        if stack is None:
+            carry, ys = _scan_layers(
+                lambda c, inp: layer(c, inp[0], kind, inp[1]), init,
+                (layers, xs.get(kind)))
+        else:
+            carry, ys = _scan_layers(
+                lambda c, inp: layer(c, with_stack(inp[0], inp[2]), kind,
+                                     inp[1]), init,
+                (layers, xs.get(kind),
+                 jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+        return carry, {kind: ys}
+    n = cfg.n_layers // len(period)
+    count = {k: period.count(k) for k in set(period)}
+
+    def at(tree, l):
+        return jax.tree_util.tree_map(
+            lambda a: lax.dynamic_index_in_dim(a, l, 0, keepdims=False),
+            tree)
+
+    def body(carry, q):                    # q: the period's index
+        ys = {k: [] for k in count}
+        for i, kind in enumerate(period):
+            l = q * len(period) + i
+            xs_l = at(xs.get(kind), q * count[kind] + len(ys[kind]))
+            carry, y = layer(carry, with_stack(at(layers, l), l), kind, xs_l)
+            ys[kind].append(y)
+        return carry, {k: jax.tree_util.tree_map(
+            lambda *a: jnp.stack(a), *v) for k, v in ys.items()}
+
+    carry, ys = _scan_layers(body, init, jnp.arange(n, dtype=jnp.int32))
+    return carry, {k: jax.tree_util.tree_map(
+        lambda a: a.reshape(n * count[k], *a.shape[2:]), v)
+        for k, v in ys.items()}
+
+
 def _embed(params, tokens, cfg: TransformerConfig):
     with jax.named_scope("embed"):
         return params["embed"].astype(cfg.dtype)[tokens]
 
 
-def _attn_norm(x, p):
+def _attn_norm(x, p, cfg: TransformerConfig):
     """The pre-attention norm, under the scope of the projections it
     feeds."""
     with jax.named_scope("attn_qkv"):
-        return _rmsnorm(x, p["ln1"])
+        return _rmsnorm(x, p["ln1"], cfg.norm_eps)
 
 
-def _qkv_proj(x, p, cfg: TransformerConfig, pos_offset=0, positions=None):
+def _qkv_proj(x, p, cfg: TransformerConfig, pos_offset=0, positions=None,
+              kind: str = "full"):
     """Project to per-head Q/K/V with RoPE applied -> head-major
     ``(B, H, S, Dh)`` / ``(B, H_kv, S, Dh)`` (shared by the training
-    attention, prefill, and decode paths so the math cannot drift)."""
+    attention, prefill, and decode paths so the math cannot drift).
+    ``kind`` is the layer's: a full layer takes ``cfg.rope_yarn``, a
+    sliding one the plain rope (at ``rope_theta_sliding`` if set)."""
     with jax.named_scope("attn_qkv"):
         q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(cfg.dtype))
         k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(cfg.dtype))
         v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(cfg.dtype))
-        q, k = _rope(q, k, cfg.rope_theta, pos_offset, positions=positions)
+        if cfg.qk_norm:
+            q = _rmsnorm(q, p["q_norm"], cfg.norm_eps)
+            k = _rmsnorm(k, p["k_norm"], cfg.norm_eps)
+        if kind == "sliding":
+            q, k = _rope(q, k, cfg.rope_theta_sliding or cfg.rope_theta,
+                         pos_offset, positions=positions)
+        else:
+            q, k = _rope(q, k, cfg.rope_theta, pos_offset,
+                         positions=positions, yarn=cfg.rope_yarn)
         return (jnp.moveaxis(q, 2, 1), jnp.moveaxis(k, 2, 1),
                 jnp.moveaxis(v, 2, 1))
 
@@ -359,6 +555,8 @@ def _moe_mlp_dense(x, p, cfg: TransformerConfig, return_aux: bool = False):
     expert, combine with the routing one-hot.  Exact and dropless — the
     oracle for the sparse path, and the right choice for decoding (a
     handful of tokens) and tiny E."""
+    if cfg.n_experts_per_tok > 1:
+        return _moe_mlp_dense_topk(x, p, cfg, return_aux)
     logits = jnp.einsum("bsd,de->bse", x, p["router"].astype(cfg.dtype))
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     top = jnp.argmax(probs, axis=-1)  # (B, S)
@@ -376,16 +574,48 @@ def _moe_mlp_dense(x, p, cfg: TransformerConfig, return_aux: bool = False):
     return y, cfg.n_experts * jnp.sum(frac * pbar)
 
 
+def _moe_mlp_dense_topk(x, p, cfg: TransformerConfig, return_aux: bool):
+    """Top-k MoE by every expert and a mask: the oracle of the dropless
+    dispatch for more than one expert a token (router in float32, the
+    k weights renormalised when ``cfg.norm_topk_prob``)."""
+    if return_aux:
+        raise UnsupportedModelConfigError(
+            "the balance loss is written for one expert a token; "
+            f"n_experts_per_tok={cfg.n_experts_per_tok} serves only")
+    from horovod_tpu.ops import moe
+
+    B, S, D = x.shape
+    top, gate = moe.route_topk(x.reshape(-1, D), p["router"],
+                               cfg.n_experts_per_tok, cfg.norm_topk_prob)
+    comb = jnp.einsum("tke,tk->te",
+                      jax.nn.one_hot(top, cfg.n_experts, dtype=jnp.float32),
+                      gate).reshape(B, S, cfg.n_experts)
+    g = jnp.einsum("bsd,edf->besf", x, p["w_gate"].astype(cfg.dtype))
+    u = jnp.einsum("bsd,edf->besf", x, p["w_up"].astype(cfg.dtype))
+    y = jnp.einsum("besf,efd->besd", jax.nn.silu(g) * u,
+                   p["w_down"].astype(cfg.dtype))
+    return jnp.einsum("besd,bse->bsd", y.astype(jnp.float32),
+                      comb).astype(cfg.dtype)
+
+
 def _moe_mlp(x, p, cfg: TransformerConfig, impl: Optional[str] = None,
-             return_aux: bool = False):
+             return_aux: bool = False, token_mask=None,
+             return_counts: bool = False):
     """Mixture-of-experts FFN; ``impl`` overrides ``cfg.moe_impl``:
     "switch" (capacity-factor sparse dispatch — training), "dense"
-    (every-expert oracle — per-step decode, tiny E), "dropless"
-    (grouped ragged matmuls, exact at 1/E dense FLOPs — prefill/serving).
+    (every-expert oracle — tiny E), "dropless" (grouped ragged matmuls,
+    exact at k/E dense FLOPs — prefill chunks and paged decode ticks).
     With ``return_aux`` also returns the layer's Switch load-balancing
     loss (ops/moe.py switch_moe(return_aux=True); same formula for
-    dense)."""
+    dense).  ``token_mask`` / ``return_counts`` are the dropless
+    dispatch's (:func:`~horovod_tpu.ops.moe.dropless_moe`)."""
     impl = impl or cfg.moe_impl
+    if impl != "dropless" and "expert_stack" in p:
+        # only the grouped product reads a stack in place: cut this
+        # layer's experts out for the other dispatches
+        w, l = p["expert_stack"]
+        p = {**p, **{k: lax.dynamic_index_in_dim(w[k], l, 0, keepdims=False)
+                     for k in _EXPERT_LEAVES}}
     if impl == "dense":
         return _moe_mlp_dense(x, p, cfg, return_aux=return_aux)
     from horovod_tpu.ops import moe
@@ -395,12 +625,23 @@ def _moe_mlp(x, p, cfg: TransformerConfig, impl: Optional[str] = None,
             raise ValueError(
                 "moe_impl='dropless' is the serving dispatch — train with "
                 "'switch' (+ moe_aux_coeff) for the balance loss")
+        # every layer's experts and this layer's index (a serving
+        # scan: _scan_layer_kinds), or this layer's own
+        w, layer = p.get("expert_stack") or (p, None)
         return moe.dropless_moe(
-            x, p["router"], p["w_gate"].astype(cfg.dtype),
-            p["w_up"].astype(cfg.dtype), p["w_down"].astype(cfg.dtype))
+            x, p["router"], *(w[k].astype(cfg.dtype)
+                              for k in _EXPERT_LEAVES),
+            k=cfg.n_experts_per_tok, norm_topk=cfg.norm_topk_prob,
+            token_mask=token_mask, return_counts=return_counts,
+            layer=layer)
     if impl != "switch":
         raise ValueError(f"unknown moe_impl {impl!r}; "
                          "expected 'switch', 'dense', or 'dropless'")
+    if cfg.n_experts_per_tok > 1:
+        raise UnsupportedModelConfigError(
+            "switch dispatch routes one expert a token; "
+            f"n_experts_per_tok={cfg.n_experts_per_tok} needs "
+            "moe_impl='dropless' (serving) or 'dense' (the oracle)")
     return moe.switch_moe(
         x, p["router"], p["w_gate"].astype(cfg.dtype),
         p["w_up"].astype(cfg.dtype), p["w_down"].astype(cfg.dtype),
@@ -409,30 +650,37 @@ def _moe_mlp(x, p, cfg: TransformerConfig, impl: Optional[str] = None,
 
 
 def _mlp_block(x, p, cfg: TransformerConfig, moe_impl: Optional[str] = None,
-               return_aux: bool = False):
+               return_aux: bool = False, token_mask=None,
+               return_counts: bool = False):
     """Residual MLP half of a layer, shared by forward, the pipeline, and
     the decode step.  Dense MLPs are bit-identical across all three; MoE
-    decode/prefill force dense dispatch, so forward-vs-decode equivalence
-    holds exactly when switch dispatch drops no tokens (capacity_factor
+    decode/prefill take a dropless dispatch (the grouped products, or
+    the every-expert oracle), so forward-vs-decode equivalence holds
+    exactly when switch dispatch drops no tokens (capacity_factor
     >= n_experts guarantees that) and diverges by the dropped tokens'
     contributions otherwise — capacity drops are a training-time
     behavior, not part of the serving contract.  ``return_aux`` threads
     the MoE balance loss out (0 for dense MLPs so callers can accumulate
-    unconditionally)."""
+    unconditionally); ``return_counts`` the rows each expert was handed
+    (dropless dispatch only; an empty vector for dense MLPs)."""
     with jax.named_scope("mlp"):
-        m = _rmsnorm(x, p["ln2"])
+        m = _rmsnorm(x, p["ln2"], cfg.norm_eps)
         if cfg.n_experts > 1:
-            out = _moe_mlp(m, p, cfg, impl=moe_impl, return_aux=return_aux)
-            if return_aux:
-                y, aux = out
-                return x + y, aux
+            out = _moe_mlp(m, p, cfg, impl=moe_impl, return_aux=return_aux,
+                           token_mask=token_mask,
+                           return_counts=return_counts)
+            if return_aux or return_counts:
+                y, extra = out
+                return x + y, extra
             return x + out
+        if return_counts:
+            return x + _dense_mlp(m, p, cfg), jnp.zeros((0,), jnp.int32)
         y = x + _dense_mlp(m, p, cfg)
         return (y, jnp.float32(0.0)) if return_aux else y
 
 
 def _layer_body(x, p, cfg: TransformerConfig, return_aux: bool = False):
-    x = x + _attention(_attn_norm(x, p), p, cfg)
+    x = x + _attention(_attn_norm(x, p, cfg), p, cfg)
     return _mlp_block(x, p, cfg, return_aux=return_aux)
 
 
@@ -450,7 +698,7 @@ def _lm_head(y, ln_f, head, cfg: TransformerConfig):
     """Final RMSNorm + vocabulary projection (f32 logits) — the ONE copy
     shared by forward, decode/prefill, and both pipeline schedules."""
     with jax.named_scope("head"):
-        h = _rmsnorm(y, ln_f)
+        h = _rmsnorm(y, ln_f, cfg.norm_eps)
         return jnp.einsum("bsd,dv->bsv", h, head.astype(cfg.dtype)).astype(
             jnp.float32)
 
@@ -472,6 +720,7 @@ def forward(params: Dict, tokens, cfg: TransformerConfig,
     ``return_aux`` additionally returns the SUM over layers of the MoE
     load-balancing auxiliary loss (0.0 for dense models) — accumulated
     in the layer-scan carry."""
+    _require_uniform(cfg, "forward")
     x = _embed(params, tokens, cfg)
 
     if return_aux:
@@ -517,11 +766,12 @@ def expert_load(params: Dict, tokens, cfg: TransformerConfig):
     flat during training."""
     if cfg.n_experts <= 1:
         raise ValueError("expert_load needs an MoE config (n_experts > 1)")
+    _require_uniform(cfg, "expert_load")
     x = _embed(params, tokens, cfg)
 
     def layer(x, p):
-        att = x + _attention(_attn_norm(x, p), p, cfg)
-        m = _rmsnorm(att, p["ln2"])
+        att = x + _attention(_attn_norm(x, p, cfg), p, cfg)
+        m = _rmsnorm(att, p["ln2"], cfg.norm_eps)
         logits = (m.astype(jnp.float32).reshape(-1, cfg.d_model)
                   @ p["router"].astype(jnp.float32))
         frac = jax.nn.one_hot(
@@ -729,6 +979,7 @@ def decode_step(params: Dict, tokens_t, cache: Dict, cfg: TransformerConfig):
     the last slot and output silently degrades.  Eager misuse raises;
     under jit the position is traced, so callers must size the cache
     (``init_cache(max_len=prompt + steps)``, as greedy_decode does)."""
+    _require_uniform(cfg, "decode_step")
     pos = cache["pos"]
     T_cache = cache["k"].shape[3]
     if not isinstance(pos, jax.core.Tracer) and int(pos) >= T_cache:
@@ -740,7 +991,7 @@ def decode_step(params: Dict, tokens_t, cache: Dict, cfg: TransformerConfig):
     def layer(x, inp):
         p, k_c, v_c = inp
         h, k_new, v_new = _attention_decode(
-            _attn_norm(x, p), p, cfg, k_c, v_c, pos)
+            _attn_norm(x, p, cfg), p, cfg, k_c, v_c, pos)
         return _mlp_block(x + h, p, cfg, moe_impl="dense"), (k_new, v_new)
 
     x, (k_all, v_all) = _scan_layers(
@@ -794,6 +1045,7 @@ def decode_step_slots(params: Dict, tokens_t, cache: Dict,
     slot left behind is overwritten before the next tenant can see it
     (the same argument that makes right-padded bucketed prefill safe;
     see :func:`prefill`)."""
+    _require_uniform(cfg, "decode_step_slots")
     pos = cache["pos"]
     T_cache = cache["k"].shape[3]
     if not isinstance(pos, jax.core.Tracer) and not isinstance(
@@ -810,7 +1062,7 @@ def decode_step_slots(params: Dict, tokens_t, cache: Dict,
     def layer(x, inp):
         p, k_c, v_c = inp
         h, k_new, v_new = _attention_decode_slots(
-            _attn_norm(x, p), p, cfg, k_c, v_c, pos)
+            _attn_norm(x, p, cfg), p, cfg, k_c, v_c, pos)
         return _mlp_block(x + h, p, cfg, moe_impl="dense"), (k_new, v_new)
 
     x, (k_all, v_all) = _scan_layers(
@@ -880,7 +1132,8 @@ def _gather_scales(scale_l, table):
 
 
 def _paged_kernel_attend(qg, k_pool, v_pool, k_scale, v_scale, table,
-                         limit, cfg: TransformerConfig, mesh=None):
+                         limit, cfg: TransformerConfig, mesh=None,
+                         lower=None):
     """Call the fused paged-attention kernel for one layer, under
     ``shard_map`` when a tp mesh is given.
 
@@ -895,8 +1148,15 @@ def _paged_kernel_attend(qg, k_pool, v_pool, k_scale, v_scale, table,
 
     quantized = k_scale is not None
     if mesh is None:
+        if lower is not None:   # a window layer: a call of its own shape
+            return _pa.paged_attend(qg, k_pool, v_pool, k_scale, v_scale,
+                                    table, limit, compute_dtype=cfg.dtype,
+                                    lower=lower)
         return _pa.paged_attend(qg, k_pool, v_pool, k_scale, v_scale,
                                 table, limit, compute_dtype=cfg.dtype)
+    if lower is not None:
+        raise UnsupportedModelConfigError(
+            "a window layer's paged kernel is not written for a tp mesh")
 
     from horovod_tpu import spmd
 
@@ -916,7 +1176,7 @@ def _paged_kernel_attend(qg, k_pool, v_pool, k_scale, v_scale, table,
 
 def _attention_decode_paged(x, p, cfg: TransformerConfig, k_pool, v_pool,
                             k_scale, v_scale, table, pos, active,
-                            kernel=False, mesh=None):
+                            kernel=False, mesh=None, kind: str = "full"):
     """Per-slot one-token attention against a PAGED cache: row ``s``
     writes its K/V at logical position ``pos[s]`` — resolved through
     the page table to ``(page table[s, pos//page], offset pos%page)`` —
@@ -944,12 +1204,18 @@ def _attention_decode_paged(x, p, cfg: TransformerConfig, k_pool, v_pool,
     in the load and NOTHING materialized at logical shape.  The scatter
     (write-before-attend) is identical under both paths, so the fused
     tick attends exactly the same pool state; ``mesh`` routes the
-    kernel through ``shard_map`` for tp head-sharded pools."""
+    kernel through ``shard_map`` for tp head-sharded pools.
+
+    ``kind="sliding"``: the pool and table are the window layers' own,
+    and row ``s`` attends positions ``pos[s] - window < t <= pos[s]``
+    only — the table's entries behind that may already be released."""
     S = x.shape[0]
     max_pages = table.shape[1]
     ps = k_pool.shape[2]
     quantized = k_scale is not None
-    qh, k_t, v_t = _qkv_proj(x, p, cfg, positions=pos[:, None])
+    qh, k_t, v_t = _qkv_proj(x, p, cfg, positions=pos[:, None], kind=kind)
+    lower = (jnp.maximum(pos - cfg.window + 1, 0) if kind == "sliding"
+             else None)
     with jax.named_scope("kv_write"):
         k_t1 = k_t[:, :, 0, :]                      # (S, H_kv, Dh)
         v_t1 = v_t[:, :, 0, :]
@@ -970,15 +1236,18 @@ def _attention_decode_paged(x, p, cfg: TransformerConfig, k_pool, v_pool,
                 v_t1.astype(v_pool.dtype))
     with jax.named_scope("paged_attend"):
         o = _paged_decode_attend(qh, k_pool, v_pool, k_scale, v_scale,
-                                 table, pos, active, cfg, kernel, mesh)
+                                 table, pos, active, cfg, kernel, mesh,
+                                 lower)
     return (_out_proj(o.astype(cfg.dtype), p, cfg),
             k_pool, v_pool, k_scale, v_scale)
 
 
 def _paged_decode_attend(qh, k_pool, v_pool, k_scale, v_scale, table, pos,
-                         active, cfg: TransformerConfig, kernel, mesh):
+                         active, cfg: TransformerConfig, kernel, mesh,
+                         lower=None):
     """The attend tail of :func:`_attention_decode_paged` (after the
-    write): the fused kernel, or gather -> dequant -> ``_cache_attend``."""
+    write): the fused kernel, or gather -> dequant -> ``_cache_attend``;
+    ``lower`` is a window layer's first visible position."""
     max_pages = table.shape[1]
     ps = k_pool.shape[2]
     quantized = k_scale is not None
@@ -991,7 +1260,7 @@ def _paged_decode_attend(qh, k_pool, v_pool, k_scale, v_scale, table, pos,
         Hkv = k_pool.shape[1]
         qg = qh.reshape(B, Hkv, H // Hkv, Dh)
         o, _ = _paged_kernel_attend(qg, k_pool, v_pool, k_scale, v_scale,
-                                    table, limit, cfg, mesh)
+                                    table, limit, cfg, mesh, lower)
         o = o.reshape(B, H, 1, Dh)
     else:
         if quantized:
@@ -1003,15 +1272,43 @@ def _paged_decode_attend(qh, k_pool, v_pool, k_scale, v_scale, table, pos,
             kg = _gather_pages(k_pool, table)
             vg = _gather_pages(v_pool, table)
         T = max_pages * ps
-        mask = (lax.broadcasted_iota(jnp.int32, (T,), 0)[None, :]
-                <= pos[:, None])
+        col = lax.broadcasted_iota(jnp.int32, (T,), 0)[None, :]
+        mask = col <= pos[:, None]
+        if lower is not None:
+            mask &= col >= lower[:, None]
         o = _cache_attend(qh, kg, vg, mask[:, None, None, :])
     return o
 
 
+def _kind_pools(pool: Dict, cfg: TransformerConfig, quantized: bool):
+    """A paged pool's per-layer arrays by layer kind, as
+    :func:`_scan_layer_kinds` takes them: the full layers' ``k``/``v``
+    (with their scales when quantized) and, for a configuration with
+    window layers, those layers' own ``wk``/``wv``."""
+    xs = {"full": (pool["k"], pool["v"])}
+    if quantized:
+        xs["full"] += (pool["k_scale"], pool["v_scale"])
+    if cfg.has_window:
+        if quantized or "wk" not in pool:
+            raise UnsupportedModelConfigError(
+                "window layers keep their own unquantized pool "
+                "('wk'/'wv' beside 'k'/'v')")
+        xs["sliding"] = (pool["wk"], pool["wv"])
+    if not cfg.kind_count("full"):
+        del xs["full"]
+    return xs
+
+
+def moe_load(counts):
+    """``[rows, experts touched, largest expert's rows]`` summed over
+    the layers, from the ``(L, E)`` rows each expert was handed."""
+    return jnp.stack([jnp.sum(counts), jnp.sum(counts > 0),
+                      jnp.sum(jnp.max(counts, axis=-1))]).astype(jnp.int32)
+
+
 def decode_step_paged(params: Dict, tokens_t, pool: Dict, table,
                       cfg: TransformerConfig, active, *, kernel=False,
-                      mesh=None):
+                      mesh=None, wtable=None, return_moe_load=False):
     """One continuous-batching decode tick over a PAGED KV cache.
 
     ``pool``: the page pool (:func:`horovod_tpu.serving.cache.
@@ -1035,7 +1332,17 @@ def decode_step_paged(params: Dict, tokens_t, pool: Dict, table,
     pass — :mod:`horovod_tpu.ops.paged_attention`); logits stay greedy-
     token-identical to the unfused path.  ``kernel``/``mesh`` are
     trace-time Python values, so flipping them selects a DIFFERENT
-    executable rather than recompiling an existing one."""
+    executable rather than recompiling an existing one.
+
+    A configuration with window layers (``cfg.has_window``) holds two
+    kinds of KV state: ``pool["k"]``/``["v"]`` and ``table`` are the
+    FULL layers' (stacked over those layers alone), ``pool["wk"]``/
+    ``["wv"]`` and ``wtable`` the window layers', whose pages behind
+    ``pos - window`` may be released.  An expert model routes each
+    active row to its ``n_experts_per_tok`` experts through the
+    dropless grouped products — ``S * k`` expert rows a tick, idle
+    slots none; ``return_moe_load`` adds :func:`moe_load` of the tick
+    as a third result."""
     pos = pool["pos"]
     T_cap = table.shape[1] * pool["k"].shape[3]
     if not isinstance(pos, jax.core.Tracer) and not isinstance(
@@ -1049,28 +1356,39 @@ def decode_step_paged(params: Dict, tokens_t, pool: Dict, table,
     x = _embed(params, tokens_t, cfg)[:, None]  # (S, 1, D)
     x = jnp.where(active[:, None, None], x, jnp.zeros_like(x))
     quantized = "k_scale" in pool
+    moe = cfg.n_experts > 1
 
-    def layer(x, inp):
-        if quantized:
-            p, k_c, v_c, ks_c, vs_c = inp
-        else:
-            (p, k_c, v_c), ks_c, vs_c = inp, None, None
+    def layer(x, p, kind, kv):
+        k_c, v_c, ks_c, vs_c = kv + (() if quantized else (None, None))
         h, k_new, v_new, ks_new, vs_new = _attention_decode_paged(
-            _attn_norm(x, p), p, cfg, k_c, v_c, ks_c, vs_c,
-            table, pos, active, kernel=kernel, mesh=mesh)
+            _attn_norm(x, p, cfg), p, cfg, k_c, v_c, ks_c, vs_c,
+            wtable if kind == "sliding" else table, pos, active,
+            kernel=kernel, mesh=mesh, kind=kind)
         out = (k_new, v_new) + ((ks_new, vs_new) if quantized else ())
-        return _mlp_block(x + h, p, cfg, moe_impl="dense"), out
+        if not moe:
+            return _mlp_block(x + h, p, cfg), (out, None)
+        # only the active rows' k picks are computed: S * k expert rows
+        y, counts = _mlp_block(x + h, p, cfg, moe_impl="dropless",
+                               token_mask=active, return_counts=True)
+        return y, (out, counts)
 
-    xs = (params["layers"], pool["k"], pool["v"])
-    if quantized:
-        xs = xs + (pool["k_scale"], pool["v_scale"])
-    x, new = _scan_layers(layer, x, xs)
+    x, ys = _scan_layer_kinds(cfg, layer, x, params["layers"],
+                              _kind_pools(pool, cfg, quantized))
     logits = _lm_head(x, params["ln_f"], params["head"], cfg)
-    out = {"k": new[0], "v": new[1],
-           "pos": pos + active.astype(jnp.int32)}
-    if quantized:
-        out["k_scale"], out["v_scale"] = new[2], new[3]
-    return logits[:, 0], out
+    out = {"pos": pos + active.astype(jnp.int32)}
+    if "full" in ys:
+        new = ys["full"][0]
+        out["k"], out["v"] = new[0], new[1]
+        if quantized:
+            out["k_scale"], out["v_scale"] = new[2], new[3]
+    if "sliding" in ys:
+        out["wk"], out["wv"] = ys["sliding"][0]
+    if not return_moe_load:
+        return logits[:, 0], out
+    counts = [ys[k][1] for k in sorted(ys)] if moe else []
+    load = (moe_load(jnp.concatenate(counts)) if counts
+            else jnp.zeros((3,), jnp.int32))
+    return logits[:, 0], out, load
 
 
 # --- speculative decoding (draft / verify multi-token ticks) ------------------
@@ -1198,6 +1516,7 @@ def decode_verify_paged(params: Dict, window, pool: Dict, table,
     flash-decoding cross-source combine.  The in-window K/V still takes
     its storage-dtype round trip first, so verify logits keep their
     bit-identity to the sequential one-token path."""
+    _require_uniform(cfg, "decode_verify_paged")
     pos = pool["pos"]
     S, W = window.shape
     max_pages = table.shape[1]
@@ -1231,7 +1550,7 @@ def decode_verify_paged(params: Dict, window, pool: Dict, table,
             p, k_c, v_c, ks_c, vs_c = inp
         else:
             (p, k_c, v_c), ks_c, vs_c = inp, None, None
-        h = _attn_norm(x, p)
+        h = _attn_norm(x, p, cfg)
         qh, kh, vh = _qkv_proj(h, p, cfg, positions=positions)
         if quantized:
             qk, sk = kv_quantize(kh)
@@ -1356,9 +1675,22 @@ def decode_verify_paged(params: Dict, window, pool: Dict, table,
     return t, mx, acc, out
 
 
+def _by_kind(ys: Dict, pos) -> Dict:
+    """A prefill's per-layer K/V as the cache block it returns:
+    ``k``/``v`` stacked over the full layers and, where the
+    configuration has window layers, ``wk``/``wv`` over those."""
+    out = {"pos": pos}
+    if "full" in ys:
+        out["k"], out["v"] = ys["full"]
+    if "sliding" in ys:
+        out["wk"], out["wv"] = ys["sliding"]
+    return out
+
+
 def prefill_with_prefix(params: Dict, suffix, prefix_k, prefix_v,
                         prefix_len, cfg: TransformerConfig, *,
-                        true_len, moe_impl: str = "dropless"):
+                        true_len, moe_impl: str = "dropless",
+                        win_k=None, win_v=None, win_start=0):
     """Prefill a (K, S0) SUFFIX whose first ``prefix_len`` logical
     positions already exist as cached K/V — the prefix-sharing prefill:
     a registered system prompt is prefilled ONCE, and every request
@@ -1381,7 +1713,15 @@ def prefill_with_prefix(params: Dict, suffix, prefix_k, prefix_v,
     :func:`prefill` bit-for-bit at f32: K/V at a position depend only
     on the tokens at and before it, and the shared math
     (``_qkv_proj`` / ``_cache_attend``-style grouped attention /
-    ``_mlp_block`` / ``_lm_head``) is the same code."""
+    ``_mlp_block`` / ``_lm_head``) is the same code.
+
+    With window layers (``cfg.has_window``) ``prefix_k``/``prefix_v``
+    hold the FULL layers alone and ``win_k``/``win_v`` ``(L_win, H_kv,
+    P0w, Dh)`` the window layers' landed K/V from logical position
+    ``win_start`` on (a traced scalar: whatever lies behind the first
+    query's window was never gathered).  A window layer's query at
+    ``i`` sees key ``j`` iff ``j <= i`` and ``i - j < cfg.window``.
+    The returned block then carries ``wk``/``wv`` beside ``k``/``v``."""
     K, S0 = suffix.shape
     P0 = prefix_k.shape[2]
     p0 = jnp.asarray(prefix_len, jnp.int32)
@@ -1396,12 +1736,29 @@ def prefill_with_prefix(params: Dict, suffix, prefix_k, prefix_v,
     pre_vis = jnp.broadcast_to(pre_vis, (S0, P0))
     suf_vis = (lax.broadcasted_iota(jnp.int32, (S0, S0), 1)
                <= lax.broadcasted_iota(jnp.int32, (S0, S0), 0))
-    mask = jnp.concatenate([pre_vis, suf_vis], axis=1)[None, None, None]
+    masks = {"full": jnp.concatenate([pre_vis, suf_vis],
+                                     axis=1)[None, None, None]}
+    xs = {"full": (prefix_k, prefix_v)}
+    if cfg.has_window:
+        # The window block's column j is logical position win_start + j:
+        # landed (< p0; the gather's padding lies past it) and within
+        # the window of the row; the suffix is causal within the window.
+        W = cfg.window
+        kpos = (jnp.asarray(win_start, jnp.int32)
+                + lax.broadcasted_iota(jnp.int32, (win_k.shape[2],), 0))
+        wpre = ((kpos[None, :] < p0)
+                & (positions[:, None] - kpos[None, :] < W))
+        rows = lax.broadcasted_iota(jnp.int32, (S0, S0), 0)
+        cols = lax.broadcasted_iota(jnp.int32, (S0, S0), 1)
+        masks["sliding"] = jnp.concatenate(
+            [wpre, suf_vis & (rows - cols < W)], axis=1)[None, None, None]
+        xs["sliding"] = (win_k, win_v)
 
-    def layer(x, inp):
-        p, pk, pv = inp
-        h = _attn_norm(x, p)
-        qh, kh, vh = _qkv_proj(h, p, cfg, positions=positions)
+    def layer(x, p, kind, kv):
+        pk, pv = kv
+        P0, mask = pk.shape[1], masks[kind]
+        h = _attn_norm(x, p, cfg)
+        qh, kh, vh = _qkv_proj(h, p, cfg, positions=positions, kind=kind)
         with jax.named_scope("chunk_attn"):
             k_full = jnp.concatenate(
                 [jnp.broadcast_to(pk[None].astype(kh.dtype),
@@ -1423,14 +1780,14 @@ def prefill_with_prefix(params: Dict, suffix, prefix_k, prefix_v,
         out = _out_proj(oh.astype(cfg.dtype), p, cfg)
         return _mlp_block(x + out, p, cfg, moe_impl=moe_impl), (kh, vh)
 
-    x, (k_all, v_all) = _scan_layers(
-        layer, x, (params["layers"], prefix_k, prefix_v))
+    x, ys = _scan_layer_kinds(cfg, layer, x, params["layers"], xs)
     last = jnp.take_along_axis(x, (true_len - 1)[:, None, None], axis=1)
     logits = _lm_head(last, params["ln_f"], params["head"], cfg)
-    return logits[:, 0], {"k": k_all, "v": v_all, "pos": p0 + true_len}
+    return logits[:, 0], _by_kind(ys, p0 + true_len)
 
 
-def _attention_prefill(x, p, cfg: TransformerConfig, mesh=None):
+def _attention_prefill(x, p, cfg: TransformerConfig, mesh=None,
+                       kind: str = "full"):
     """Full-sequence attention that ALSO returns the (unexpanded,
     post-RoPE) per-layer K/V for cache filling.  Shares the projection
     math with :func:`_attention` via ``_qkv_proj``/``_out_proj`` and
@@ -1442,20 +1799,27 @@ def _attention_prefill(x, p, cfg: TransformerConfig, mesh=None):
     kernel ("wrap the call in a shard_map"), so under tp the flash
     kernel runs per head shard through ``shard_map`` — attention is
     per-head, and a contiguous tp split keeps every query head on the
-    device that holds its KV head, so the GQA expansion is local."""
+    device that holds its KV head, so the GQA expansion is local.
+
+    ``kind="sliding"``: the same kernel with the window's lower bound
+    (blocks wholly behind it skipped)."""
     from horovod_tpu.ops import attention as attn
 
-    qh, kh, vh = _qkv_proj(x, p, cfg, 0)  # kh/vh: (B, H_kv, S0, Dh)
+    window = cfg.window if kind == "sliding" else 0
+    qh, kh, vh = _qkv_proj(x, p, cfg, 0, kind=kind)  # kh/vh: (B,H_kv,S0,Dh)
     if cfg.attention_impl == "reference":
         with jax.named_scope("attn"):
             oh = attn.reference_attention(
                 qh, attn.expand_kv(kh, cfg.n_heads),
-                attn.expand_kv(vh, cfg.n_heads), causal=True)
+                attn.expand_kv(vh, cfg.n_heads), causal=True,
+                window=window)
         return _out_proj(oh, p, cfg), kh, vh
 
     def flash(q, k, v):
-        return attn.flash_attention(q, attn.expand_kv(k, q.shape[1]),
-                                    attn.expand_kv(v, q.shape[1]), True)
+        k, v = attn.expand_kv(k, q.shape[1]), attn.expand_kv(v, q.shape[1])
+        if window:
+            return attn.flash_attention_windowed(q, k, v, window)
+        return attn.flash_attention(q, k, v, True)
 
     if mesh is not None:
         from horovod_tpu import spmd
@@ -1496,7 +1860,12 @@ def prefill(params: Dict, prompt, cache: Dict, cfg: TransformerConfig,
     position ``p`` in the same step that first attends it.
 
     ``mesh``: the tp serving mesh when params are head-sharded under
-    GSPMD (see :func:`_attention_prefill`)."""
+    GSPMD (see :func:`_attention_prefill`).
+
+    With window layers (``cfg.has_window``) the K/V come back BY KIND —
+    ``k``/``v`` stacked over the full layers, ``wk``/``wv`` over the
+    window layers, each ``(L_kind, B, H_kv, S0, Dh)`` — for a paged
+    engine's two pools; ``cache`` then only gives ``pos``."""
     pos = cache["pos"]
     if not isinstance(pos, jax.core.Tracer) and int(pos) != 0:
         raise ValueError("prefill requires a fresh cache (pos == 0)")
@@ -1508,15 +1877,15 @@ def prefill(params: Dict, prompt, cache: Dict, cfg: TransformerConfig,
             "init_cache with a larger max_len")
     x = _embed(params, prompt, cfg)
 
-    def layer(x, p):
-        h, kh, vh = _attention_prefill(_attn_norm(x, p), p, cfg, mesh)
+    def layer(x, p, kind, _):
+        h, kh, vh = _attention_prefill(_attn_norm(x, p, cfg), p, cfg, mesh,
+                                       kind)
         # Prefill ingests whole prompts: DROPLESS grouped-matmul dispatch
-        # by default — exact like dense but 1/E of its FFN FLOPs
-        # (ops/moe.py dropless_moe).  Per-step decode keeps dense (a
-        # handful of tokens; ragged grouping buys nothing there).
+        # by default — exact like dense but k/E of its FFN FLOPs
+        # (ops/moe.py dropless_moe).
         return _mlp_block(x + h, p, cfg, moe_impl=moe_impl), (kh, vh)
 
-    x, (k_all, v_all) = _scan_layers(layer, x, params["layers"])
+    x, ys = _scan_layer_kinds(cfg, layer, x, params["layers"])
     # Only one position's logits are needed: slice BEFORE the (B, S0, V)
     # head projection.
     if true_len is None:
@@ -1535,6 +1904,11 @@ def prefill(params: Dict, prompt, cache: Dict, cfg: TransformerConfig,
                                    axis=1)
         new_pos = pos + true_len
     logits = _lm_head(last, params["ln_f"], params["head"], cfg)
+    if cfg.has_window:
+        # two kinds of KV state: handed back by kind for the caller's
+        # two pools, not landed in a cache of one shape
+        return logits[:, 0], _by_kind(ys, new_pos)
+    k_all, v_all = ys[next(iter(ys))]
     with jax.named_scope("kv_land"):
         cache = {
             "k": lax.dynamic_update_slice_in_dim(
@@ -1715,6 +2089,7 @@ def _pipeline_stage_setup(params: Dict, cfg: TransformerConfig,
     """Shared pipeline plumbing (both schedules): divisibility checks,
     this stage's layer slice, and the scanned stage function (aux-carrying
     when ``return_aux`` — the per-stage MoE balance sum)."""
+    _require_uniform(cfg, "the pipeline schedules")
     P_ = lax.axis_size(axis_name)
     s = lax.axis_index(axis_name)
     if cfg.n_layers % P_:

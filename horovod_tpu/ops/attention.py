@@ -87,18 +87,22 @@ def _float0_like(x):
 # --- reference (oracle) -------------------------------------------------------
 
 
-def _reference_attention_lse(q, k, v, shift, scale):
+def _reference_attention_lse(q, k, v, shift, scale, window: int = 0):
     """One O(S^2) score computation -> (output, logsumexp).
 
     ``shift``: None for unmasked, else a (traced or static) int scalar —
     position (row, col) is attended iff ``col + shift <= row``.  shift=0
-    is standard causal."""
+    is standard causal.  ``window`` > 0 also asks ``row - col <
+    window``."""
     scores = jnp.einsum("bhsd,bhtd->bhst", q, k).astype(jnp.float32) * scale
     if shift is not None:
         S, T = scores.shape[-2], scores.shape[-1]
         rows = lax.broadcasted_iota(jnp.int32, (S, T), 0)
         cols = lax.broadcasted_iota(jnp.int32, (S, T), 1)
-        scores = jnp.where(cols + shift <= rows, scores, NEG_INF)
+        vis = cols + shift <= rows
+        if window:
+            vis &= rows - cols < window
+        scores = jnp.where(vis, scores, NEG_INF)
     m = jnp.max(scores, axis=-1, keepdims=True)
     m = jnp.maximum(m, NEG_INF)  # fully-masked rows: stay finite
     p = jnp.where(scores > NEG_INF * 0.5, jnp.exp(scores - m), 0.0)
@@ -111,10 +115,11 @@ def _reference_attention_lse(q, k, v, shift, scale):
 
 
 def reference_attention(q, k, v, *, causal: bool = False,
-                        sm_scale: Optional[float] = None):
-    """O(S^2)-memory oracle used by tests and as the small-shape fallback."""
+                        sm_scale: Optional[float] = None, window: int = 0):
+    """O(S^2)-memory oracle used by tests and as the small-shape fallback
+    (``window`` > 0 with ``causal``: a sliding-window layer)."""
     o, _ = _reference_attention_lse(q, k, v, 0 if causal else None,
-                                    _sm_scale(q, sm_scale))
+                                    _sm_scale(q, sm_scale), window)
     return o
 
 
@@ -124,12 +129,15 @@ def reference_attention(q, k, v, *, causal: bool = False,
 def _flash_fwd_kernel(shift_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                       acc_ref, m_ref, l_ref,
                       *, block_q: int, block_k: int, masked: bool,
-                      scale: float, num_k: int):
+                      scale: float, num_k: int, window: int = 0):
     """Grid: (batch*heads, num_q_blocks, num_k_blocks); K innermost, so the
     (acc, m, l) scratch carries the online softmax across K steps.
 
     ``shift_ref`` is a (1,) int32 in SMEM: position (row, col) attends iff
-    ``col + shift <= row`` (only read when ``masked``)."""
+    ``col + shift <= row`` (only read when ``masked``).  ``window`` > 0
+    (static) also asks ``row - col < window``: K blocks wholly behind
+    the window of the block's first row are skipped like those above
+    the diagonal."""
     iq = pl.program_id(1)
     ik = pl.program_id(2)
 
@@ -143,6 +151,8 @@ def _flash_fwd_kernel(shift_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     run = True
     if masked:
         run = ik * block_k + shift_ref[0] <= iq * block_q + block_q - 1
+        if window:
+            run &= iq * block_q - (ik * block_k + block_k - 1) < window
 
     @pl.when(run)
     def _step():
@@ -159,7 +169,10 @@ def _flash_fwd_kernel(shift_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 jnp.int32, (block_q, block_k), 0)
             cols = ik * block_k + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(cols + shift_ref[0] <= rows, s, NEG_INF)
+            vis = cols + shift_ref[0] <= rows
+            if window:
+                vis &= rows - cols < window
+            s = jnp.where(vis, s, NEG_INF)
         m_prev = m_ref[:, :1]                               # (block_q, 1)
         m_cur = jnp.max(s, axis=-1, keepdims=True)          # (block_q, 1)
         m_new = jnp.maximum(m_prev, m_cur)
@@ -213,19 +226,21 @@ def _tileable(S: int, T: int, D: int, block_q: int, block_k: int) -> bool:
     return S % block_q == 0 and T % block_k == 0 and D % 8 == 0
 
 
-def _flash_fwd(q, k, v, shift, sm_scale, block_q: int, block_k: int):
-    """shift: None (no mask) or int scalar (traced ok) — shifted causal."""
+def _flash_fwd(q, k, v, shift, sm_scale, block_q: int, block_k: int,
+               window: int = 0):
+    """shift: None (no mask) or int scalar (traced ok) — shifted causal;
+    window: 0, or the static span a row may look back over."""
     B, H, S, D = q.shape
     T = k.shape[2]
     block_q = min(block_q, S)
     block_k = min(block_k, T)
     scale = _sm_scale(q, sm_scale)
     if not _tileable(S, T, D, block_q, block_k):
-        return _reference_attention_lse(q, k, v, shift, scale)
+        return _reference_attention_lse(q, k, v, shift, scale, window)
     nq, nk = S // block_q, T // block_k
     kernel = functools.partial(
         _flash_fwd_kernel, block_q=block_q, block_k=block_k,
-        masked=shift is not None, scale=scale, num_k=nk)
+        masked=shift is not None, scale=scale, num_k=nk, window=window)
     qr = q.reshape(B * H, S, D)
     kr = k.reshape(B * H, T, D)
     vr = v.reshape(B * H, T, D)
@@ -527,6 +542,19 @@ def _fa_bwd(causal, sm_scale, block_q, block_k, res, do):
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
+
+
+def flash_attention_windowed(q, k, v, window: int,
+                             sm_scale: Optional[float] = None,
+                             block_q: int = 1024, block_k: int = 512):
+    """Causal :func:`flash_attention` in which row ``i`` sees column
+    ``j`` iff ``j <= i`` and ``i - j < window`` — a sliding-window
+    layer's prefill.  Forward only (serving): the kernel carries no
+    gradient rule for the window."""
+    if window < 1:
+        raise ValueError(f"window must be >= 1, got {window}")
+    o, _ = _flash_fwd(q, k, v, 0, sm_scale, block_q, block_k, window)
+    return o
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))

@@ -34,12 +34,15 @@ against the dense-dispatch oracle in ``tests/test_moe.py``.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+
+from horovod_tpu.ops._pallas_util import pl, pltpu, use_interpret
 
 
 def capacity(T: int, n_experts: int, capacity_factor: float) -> int:
@@ -193,43 +196,188 @@ def switch_moe(
     return y, aux
 
 
-def dropless_moe(x, router, w_gate, w_up, w_down):
-    """Top-1 MoE FFN, DROPLESS, via grouped (ragged) matmuls: sort tokens
-    by expert, run the three FFN matmuls as ``lax.ragged_dot`` with the
-    per-expert group sizes, unsort, scale by the gate.
+#: The grouped expert product's name on a device trace
+#: (``pl.pallas_call(name=)``), and the scope its ``lax.ragged_dot``
+#: form is put under: readers of a profile find either by it.
+EXPERTS_NAME = "hvd_moe_experts"
+
+
+def _work_items(counts, m_tiles: int, tm: int):
+    """The grouped product's walk over ``(expert, row tile)`` pairs, from
+    the rows each expert was handed (rows sorted by expert): ``offsets``
+    ``(E + 1,)`` row bounds, and for each of the ``m_tiles + E - 1`` work
+    items its expert and its row tile, plus how many are real.  An
+    expert with no row gets no item; a row tile shared by several
+    experts is visited once for each.  Items past the real ones repeat
+    the last real one (no new transfer) and are skipped."""
+    E = counts.shape[0]
+    ends = jnp.cumsum(counts)
+    starts = ends - counts
+    first = starts // tm
+    tiles = jnp.where(counts > 0, (ends - 1) // tm - first + 1, 0)
+    item_end = jnp.cumsum(tiles)
+    num = item_end[-1]
+    t = jnp.arange(m_tiles + E - 1, dtype=jnp.int32)
+    t = jnp.minimum(t, jnp.maximum(num - 1, 0))
+    gid = jnp.minimum(jnp.searchsorted(item_end, t, side="right"),
+                      E - 1).astype(jnp.int32)
+    mid = (first[gid] + t - (item_end - tiles)[gid]).astype(jnp.int32)
+    offsets = jnp.concatenate([starts[:1], ends]).astype(jnp.int32)
+    return offsets, gid, mid, num.astype(jnp.int32).reshape(1)
+
+
+def _grouped_kernel(layer_ref, offs_ref, gid_ref, mid_ref, num_ref,
+                    x_ref, w_ref, o_ref, *, tm: int):
+    """One work item: the rows of tile ``mid[t]`` that belong to expert
+    ``gid[t]``, times that expert's matrix (the block the index map
+    fetched from ``w[layer, gid[t]]``).  The output tile stays resident
+    while consecutive items share it; its first visit clears the rows
+    no expert owns."""
+    del layer_ref  # read by the weights' index map
+    t = pl.program_id(0)
+
+    @pl.when(t < num_ref[0])
+    def _item():
+        e, m = gid_ref[t], mid_ref[t]
+        rows = m * tm + lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+        mine = (rows >= offs_ref[e]) & (rows < offs_ref[e + 1])
+        acc = lax.dot_general(x_ref[...], w_ref[...],
+                              (((1,), (0,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+        fresh = (t == 0) | (mid_ref[jnp.maximum(t - 1, 0)] != m)
+        old = jnp.where(fresh, 0.0, o_ref[...].astype(jnp.float32))
+        o_ref[...] = jnp.where(mine, acc, old).astype(o_ref.dtype)
+
+
+def grouped_matmul(xs, w, layer, counts):
+    """``xs[rows of expert e] @ w[layer, e]`` for every expert, as one
+    Pallas kernel (:data:`EXPERTS_NAME`): ``xs`` ``(M, K)`` rows sorted
+    by expert, ``counts`` ``(E,)`` the rows of each, ``w`` ``(L, E, K,
+    N)`` EVERY layer's experts as the checkpoint stacks them, ``layer``
+    a (traced) index into it.  The weights stay where they are: each
+    expert that owns a row has its ``(K, N)`` matrix fetched straight
+    from ``w[layer, e]`` — one contiguous transfer, overlapped with the
+    previous expert's product — so a layer scan hands the kernel the
+    whole stack and no slice of it is ever copied (``lax.ragged_dot``
+    is a custom call whose operand a scan must first cut out: a copy of
+    every expert, every tick).  Rows past ``sum(counts)`` come back
+    zero where their tile was visited and undefined where not."""
+    M, K = xs.shape
+    L, E, _, N = w.shape
+    tm = 128 if M >= 1024 else 32 if M >= 128 else 16
+    m_tiles = -(-M // tm)
+    if m_tiles * tm != M:
+        xs = jnp.pad(xs, ((0, m_tiles * tm - M), (0, 0)))
+    offsets, gid, mid, num = _work_items(counts, m_tiles, tm)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(m_tiles + E - 1,),
+        in_specs=[
+            pl.BlockSpec((tm, K), lambda t, l, o, g, m, n: (m[t], 0)),
+            pl.BlockSpec((None, None, K, N),
+                         lambda t, l, o, g, m, n: (l[0], g[t], 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((tm, N), lambda t, l, o, g, m, n: (m[t], 0)),
+    )
+    out = pl.pallas_call(
+        functools.partial(_grouped_kernel, tm=tm),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m_tiles * tm, N), xs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            # two buffers of a whole (K, N) expert matrix, 4 MB each at
+            # 2304 x 896 in bf16
+            vmem_limit_bytes=48 * 1024 * 1024),
+        interpret=use_interpret(),
+        name=EXPERTS_NAME,
+    )(layer, offsets, gid, mid, num, xs, w)
+    return out[:M]
+
+
+def route_topk(xt, router, k: int = 1, norm_topk: bool = False):
+    """Softmax routing in float32: ``(experts (T, k) int32, weights
+    (T, k) float32)`` — the ``k`` largest of ``softmax(x @ router)``,
+    renormalised to sum to one when ``norm_topk``."""
+    logits = xt.astype(jnp.float32) @ router.astype(jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    if k == 1:
+        return (jnp.argmax(probs, axis=-1).astype(jnp.int32)[:, None],
+                jnp.max(probs, axis=-1)[:, None])
+    gate, e = lax.top_k(probs, k)
+    if norm_topk:
+        gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    return e.astype(jnp.int32), gate
+
+
+def dropless_moe(x, router, w_gate, w_up, w_down, *, k: int = 1,
+                 norm_topk: bool = False, token_mask=None,
+                 return_counts: bool = False, layer=None):
+    """Top-k MoE FFN, DROPLESS, via grouped (ragged) matmuls: each
+    token's ``k`` rows sorted by expert, the three FFN matmuls as
+    ``lax.ragged_dot`` with the per-expert group sizes, unsorted, and
+    the weighted sum back.
 
     Exact (== the dense dispatch oracle — no capacity, nothing dropped)
-    at 1/E of dense FLOPs: each token touches only its own expert's
+    at k/E of dense FLOPs: each row touches only its own expert's
     weights, and the grouped matmuls stay MXU-shaped.  This is the
-    SERVING dispatch: prefill uses it so an E-expert model ingests a
-    prompt at 1× FFN cost instead of dense's E× (training keeps
-    capacity-factor :func:`switch_moe` — fixed shapes and the one
-    all_to_all each way under ``ep``; per-step decode keeps dense — a
-    handful of tokens).  Single-device or tp-sharded; no ep axis
-    (ragged group sizes are data-dependent, which an all_to_all cannot
-    carry statically)."""
+    SERVING dispatch, for prefill chunks and decode ticks alike: a tick
+    of S slots computes ``S * k`` expert rows, not ``S * E`` (training
+    keeps capacity-factor :func:`switch_moe` — fixed shapes and the one
+    all_to_all each way under ``ep``).  Single-device or tp-sharded; no
+    ep axis (ragged group sizes are data-dependent, which an all_to_all
+    cannot carry statically).
+
+    ``token_mask`` ``(T,)`` bool leaves tokens out of every group (a
+    decode tick's idle slots): they cost no expert row and come back as
+    zeros.  ``return_counts`` adds the ``(E,)`` int32 rows each expert
+    was handed.  ``layer``: the three weights are EVERY layer's, stacked
+    ``(L, E, ...)`` as a checkpoint holds them, and this is the (traced)
+    index of the layer at hand — the products then run as
+    :func:`grouped_matmul`, which reads ``w[layer, e]`` in place (what a
+    layer scan needs: see there); without it they are one layer's
+    ``(E, ...)`` and run as ``lax.ragged_dot``.  On a device trace the
+    routing (scores, top-k, sort, weighted sum) reads ``hvd_moe_route``
+    and the grouped products ``hvd_moe_experts``, in either form."""
     lead, D = x.shape[:-1], x.shape[-1]
     xt = x.reshape(-1, D)
     T = xt.shape[0]
     E = router.shape[1]
     dt = x.dtype
 
-    logits = xt.astype(jnp.float32) @ router.astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    e_star = jnp.argmax(probs, axis=-1).astype(jnp.int32)
-    gate = jnp.max(probs, axis=-1)
+    with jax.named_scope("hvd_moe_route"):
+        e_top, gate = route_topk(xt, router, k, norm_topk)
+        e_rows = e_top.reshape(-1)            # row r belongs to token r // k
+        if token_mask is not None:
+            # expert E is no expert: its rows sort past every group
+            e_rows = jnp.where(jnp.repeat(token_mask.reshape(-1), k),
+                               e_rows, E)
+        order = jnp.argsort(e_rows, stable=True)
+        xs = xt[order if k == 1 else order // k]
+        es = e_rows[order]
+        eye = jnp.arange(E, dtype=jnp.int32)
+        counts = (jnp.searchsorted(es, eye, side="right")
+                  - jnp.searchsorted(es, eye)).astype(jnp.int32)
 
-    order = jnp.argsort(e_star, stable=True)
-    xs = xt[order]
-    es = e_star[order]
-    eye = jnp.arange(E, dtype=jnp.int32)
-    counts = (jnp.searchsorted(es, eye, side="right")
-              - jnp.searchsorted(es, eye)).astype(jnp.int32)
+    with jax.named_scope(EXPERTS_NAME):
+        if layer is None:
+            def mm(rows, w):
+                return lax.ragged_dot(rows, w.astype(dt), counts)
+        else:
+            def mm(rows, w):
+                return grouped_matmul(rows, w.astype(dt), layer, counts)
+        y_s = mm(jax.nn.silu(mm(xs, w_gate)) * mm(xs, w_up), w_down)
 
-    g = lax.ragged_dot(xs, w_gate.astype(dt), counts)
-    u = lax.ragged_dot(xs, w_up.astype(dt), counts)
-    y_s = lax.ragged_dot(jax.nn.silu(g) * u, w_down.astype(dt), counts)
-
-    inv = jnp.argsort(order)  # unsort permutation
-    y = y_s[inv] * gate[:, None].astype(dt)
-    return y.reshape(*lead, D)
+    with jax.named_scope("hvd_moe_route"):
+        inv = jnp.argsort(order)  # unsort permutation
+        if k == 1:
+            y = y_s[inv] * gate.astype(dt)
+        else:
+            y = jnp.sum((y_s[inv].astype(jnp.float32)
+                         * gate.reshape(-1, 1)).reshape(T, k, D),
+                        axis=1).astype(dt)
+        if token_mask is not None:
+            # rows past the groups are whatever the grouped product left
+            y = jnp.where(token_mask.reshape(-1, 1), y, jnp.zeros_like(y))
+        y = y.reshape(*lead, D)
+    return (y, counts) if return_counts else y

@@ -35,7 +35,10 @@ program per SLOT, walking only the pages the slot can attend:
 * masking is by LOGICAL position against ``limit`` (positions
   ``< limit[s]`` attend) — a partial last page, page-tail junk, the
   places of a block's dead pages and inactive slots (``limit == 0``)
-  all fall out of the same comparison;
+  all fall out of the same comparison; a window layer also gives
+  ``lower`` (positions ``>= lower[s]``), and its walk starts at the
+  block holding ``lower[s]``: pages wholly behind the window are never
+  fetched, so their table entries may be released;
 * cross-block combination is the standard flash-decoding online
   softmax (running max / sum / accumulator with rescale, carried by
   the loop), and the kernel emits per-row ``logsumexp`` so a caller
@@ -69,7 +72,8 @@ from horovod_tpu.ops._pallas_util import (
 )
 
 __all__ = ["DEQUANT_COMPUTE", "UnsupportedPagedLayoutError", "paged_attend",
-           "paged_attend_reference", "kernel_supported"]
+           "paged_attend_reference", "kernel_supported", "walk",
+           "first_block"]
 
 
 # The pinned dequant compute dtype.  ``kv_dequantize`` promotes int8
@@ -147,19 +151,31 @@ def block_pages(page_size: int, n_kv_heads: int, head_dim: int,
     return max(1, min(_BLOCK_BYTES // page_bytes, max_pages))
 
 
-def walk(limit, block_tokens: int):
+def walk(limit, block_tokens: int, lower=None):
     """``(blocks, tokens)`` the kernel walks for a slot that attends
     positions ``< limit``: whole blocks up to the last live position,
-    none for ``limit == 0``.  The ONE statement of the bound: it is the
-    kernel's trip count (a traced scalar read from SMEM) and, on the
-    host's ``_page_pos + 1`` (a numpy array), the engine's
-    ``paged_walked_tokens`` counter."""
+    none for ``limit == 0``.  With ``lower`` (a window layer: positions
+    ``>= lower`` only) the walk starts at the block that holds
+    ``lower`` — :func:`first_block` — so blocks wholly behind the
+    window are neither fetched nor counted.  The ONE statement of the
+    bound: it is the kernel's trip count (a traced scalar read from
+    SMEM) and, on the host's ``_page_pos + 1`` (a numpy array), the
+    engine's ``paged_walked_tokens`` counter."""
     blocks = (limit + block_tokens - 1) // block_tokens
+    if lower is not None:
+        # an empty window (lower >= limit) walks nothing: every block
+        # that IS walked holds a visible position, as without a bound
+        blocks = (blocks - first_block(lower, block_tokens)) * (lower < limit)
     return blocks, blocks * block_tokens
 
 
-def _kernel_body(table_ref, limit_ref, q_ref, k_hbm, v_hbm, *refs,
-                 page_size, n_pages, compute_dtype, quantized):
+def first_block(lower, block_tokens: int):
+    """The block a walk bounded below by ``lower`` starts at."""
+    return lower // block_tokens
+
+
+def _kernel_body(table_ref, limit_ref, *refs, page_size, n_pages,
+                 compute_dtype, quantized, windowed):
     """One grid step: slot ``s``, every KV head, the slot's live pages.
 
     ``k_hbm``/``v_hbm`` (and the scale pools) stay in HBM; the loop
@@ -169,18 +185,28 @@ def _kernel_body(table_ref, limit_ref, q_ref, k_hbm, v_hbm, *refs,
     loop's carry.  Only pages holding a position ``< limit`` are
     fetched: table entries past them are never read.
     """
+    lower_ref = None
+    if windowed:                  # a third scalar-prefetch operand
+        lower_ref, refs = refs[0], refs[1:]
+    q_ref, k_hbm, v_hbm = refs[:3]
     if quantized:
         ks_hbm, vs_hbm, o_ref, lse_ref, k_buf, v_buf, ks_buf, vs_buf, \
-            sems = refs
+            sems = refs[3:]
     else:
-        o_ref, lse_ref, k_buf, v_buf, sems = refs
+        o_ref, lse_ref, k_buf, v_buf, sems = refs[3:]
     s = pl.program_id(0)
     Hkv, R, Dh = q_ref.shape[1:]
     block_tokens = n_pages * page_size
     # A limit past the table's capacity would index the table out of
     # bounds; the reference attends nothing there either.
     limit = jnp.minimum(limit_ref[s], table_ref.shape[1] * page_size)
-    n_blocks, _ = walk(limit, block_tokens)
+    if windowed:
+        lower = jnp.clip(lower_ref[s], 0, limit)
+        b0 = first_block(lower, block_tokens)
+        n_blocks = b0 + walk(limit, block_tokens, lower)[0]
+    else:
+        b0 = 0
+        n_blocks, _ = walk(limit, block_tokens)
 
     @pl.when(s == 0)
     def _clear():
@@ -198,7 +224,11 @@ def _kernel_body(table_ref, limit_ref, q_ref, k_hbm, v_hbm, *refs,
         for i in range(n_pages):
             idx = b * n_pages + i
 
-            @pl.when(idx * page_size < limit)
+            live = idx * page_size < limit
+            if windowed:          # the page's last position is in reach
+                live &= (idx + 1) * page_size > lower
+
+            @pl.when(live)
             def _page():
                 # a wait needs the descriptor's shape, not its source
                 page = 0 if wait else table_ref[s, idx]
@@ -220,15 +250,15 @@ def _kernel_body(table_ref, limit_ref, q_ref, k_hbm, v_hbm, *refs,
             [sc[i].reshape(Hkv, page_size, 1) for i in range(n_pages)],
             axis=1)
 
-    @pl.when(n_blocks > 0)
+    @pl.when(n_blocks > b0)
     def _first():
-        fetch(0, 0, wait=False)
+        fetch(b0, 0, wait=False)
 
     q = q_ref[0].astype(compute_dtype if quantized else k_buf.dtype)
 
     def block(b, carry):
         m_prev, l_prev, acc = carry
-        buf = b % 2
+        buf = (b - b0) % 2
 
         @pl.when(b + 1 < n_blocks)
         def _next():
@@ -247,7 +277,10 @@ def _kernel_body(table_ref, limit_ref, q_ref, k_hbm, v_hbm, *refs,
         # and a partial last page all sit at positions >= limit.
         col = b * block_tokens + jax.lax.broadcasted_iota(
             jnp.int32, s_blk.shape, 2)
-        s_blk = jnp.where(col < limit, s_blk, NEG_INF)    # (Hkv, R, T)
+        vis = col < limit
+        if windowed:
+            vis &= col >= lower
+        s_blk = jnp.where(vis, s_blk, NEG_INF)            # (Hkv, R, T)
 
         m_new = jnp.maximum(m_prev, jnp.max(s_blk, axis=2, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -261,7 +294,7 @@ def _kernel_body(table_ref, limit_ref, q_ref, k_hbm, v_hbm, *refs,
         return m_new, l_new, acc * alpha + pv
 
     m, l, acc = jax.lax.fori_loop(
-        0, n_blocks, block,
+        b0, n_blocks, block,
         (jnp.full((Hkv, R, 1), NEG_INF, jnp.float32),
          jnp.zeros((Hkv, R, 1), jnp.float32),
          jnp.zeros((Hkv, R, Dh), jnp.float32)))
@@ -273,7 +306,7 @@ def _kernel_body(table_ref, limit_ref, q_ref, k_hbm, v_hbm, *refs,
 
 
 def _pallas_paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table,
-                         limit, compute_dtype):
+                         limit, compute_dtype, lower=None):
     S, Hkv, R, Dh = qg.shape
     _, _, ps, _ = k_pool.shape
     quantized = k_scale is not None
@@ -291,7 +324,12 @@ def _pallas_paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table,
     # itself, so the pools never get a BlockSpec's pipeline.
     hbm = pl.BlockSpec(memory_space=pl.ANY)
 
-    def of_slot(s, table, limit):
+    windowed = lower is not None
+    scalars = [table.astype(jnp.int32), limit.astype(jnp.int32)]
+    if windowed:
+        scalars.append(lower.astype(jnp.int32))
+
+    def of_slot(s, *scalars):
         return (s, 0, 0, 0)
 
     in_specs = [pl.BlockSpec((1, Hkv, R_pad, Dh), of_slot), hbm, hbm]
@@ -321,7 +359,7 @@ def _pallas_paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table,
         pl.BlockSpec((1, Hkv, 8, R_pad), of_slot),
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(scalars),
         grid=(S,),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -329,7 +367,8 @@ def _pallas_paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table,
     )
     o, lse = pl.pallas_call(
         functools.partial(_kernel_body, page_size=ps, n_pages=n_pages,
-                          compute_dtype=compute_dtype, quantized=quantized),
+                          compute_dtype=compute_dtype, quantized=quantized,
+                          windowed=windowed),
         grid_spec=grid_spec,
         out_shape=[o_shape, lse_shape],
         # the buffers' zeroing at slot 0 must come first
@@ -337,12 +376,12 @@ def _pallas_paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table,
             dimension_semantics=("arbitrary",)),
         interpret=use_interpret(),
         name=KERNEL_NAME,
-    )(table.astype(jnp.int32), limit.astype(jnp.int32), *operands)
+    )(*scalars, *operands)
     return o[:, :, :R, :], lse[:, :, 0, :R]
 
 
 def paged_attend_reference(qg, k_pool, v_pool, k_scale, v_scale, table,
-                           limit, *, compute_dtype=None):
+                           limit, *, compute_dtype=None, lower=None):
     """Pure-JAX reference for :func:`paged_attend` — gather, dequant,
     masked softmax — mirroring the unfused decode path's op-for-op
     rounding (``kv_dequantize``'s f32 contract, ``_cache_attend``'s
@@ -372,11 +411,13 @@ def paged_attend_reference(qg, k_pool, v_pool, k_scale, v_scale, table,
     s = jnp.einsum("skrd,sktd->skrt", qg.astype(kg.dtype), kg,
                    preferred_element_type=jnp.float32) / np.sqrt(Dh)
     T = max_pages * ps
-    vis = (jax.lax.broadcasted_iota(jnp.int32, (T,), 0)[None, :]
-           < limit[:, None])                  # (S, T)
+    col = jax.lax.broadcasted_iota(jnp.int32, (T,), 0)[None, :]
+    vis = col < limit[:, None]                # (S, T)
+    if lower is not None:
+        vis &= col >= lower[:, None]
     s = jnp.where(vis[:, None, None, :], s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
-    any_vis = (limit > 0)[:, None, None, None]
+    any_vis = jnp.any(vis, axis=-1)[:, None, None, None]
     p = jnp.exp(s - jnp.where(any_vis, m, 0.0))
     l = jnp.sum(p, axis=-1, keepdims=True)
     w = jnp.where(any_vis, p / l, 0.0)
@@ -388,7 +429,7 @@ def paged_attend_reference(qg, k_pool, v_pool, k_scale, v_scale, table,
 
 
 def paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table, limit, *,
-                 compute_dtype=None):
+                 compute_dtype=None, lower=None):
     """Fused decode attention directly against a paged KV pool.
 
     Args:
@@ -407,6 +448,10 @@ def paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table, limit, *,
       compute_dtype: dtype int8 pages are dequantized TO (the model's
         ``cfg.dtype``); ignored for unquantized pools, which are dotted
         in their stored dtype per ``_cache_attend``.
+      lower: optional ``(S,)`` int32 — a window layer's lower bound:
+        attend positions ``lower[s] <= t < limit[s]`` only, and start
+        the walk at the block that holds ``lower[s]``.  ``None`` (a
+        full layer) compiles the kernel with no such operand.
 
     Returns:
       ``(o, lse)``: ``o`` ``(S, H_kv, R, Dh)`` f32 attention output
@@ -417,4 +462,4 @@ def paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table, limit, *,
     if compute_dtype is None:
         compute_dtype = k_pool.dtype
     return _pallas_paged_attend(qg, k_pool, v_pool, k_scale, v_scale,
-                                table, limit, compute_dtype)
+                                table, limit, compute_dtype, lower)

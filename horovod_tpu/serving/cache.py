@@ -217,15 +217,17 @@ def resolve_kv_dtype(cfg: "T.TransformerConfig", kv_dtype):
 
 
 def init_page_pool(cfg: "T.TransformerConfig", n_slots: int, n_pages: int,
-                   page_size: int, kv_dtype=None) -> Dict:
+                   page_size: int, kv_dtype=None, n_layers=None) -> Dict:
     """The paged device cache: ``k``/``v`` are ``(L, P, H_kv, page,
     Dh)`` page pools (``P`` counts the NULL page), ``pos`` is the
     per-slot ``(S,)`` logical write position, and int8 storage adds
     ``k_scale``/``v_scale`` ``(L, P, H_kv, page)`` per-vector f32
     scales.  The page table itself is HOST state
-    (:attr:`PagedSlotCache.table`), uploaded as data each tick."""
+    (:attr:`PagedSlotCache.table`), uploaded as data each tick.
+    ``n_layers`` overrides the depth: the pool of ONE kind of layer."""
     dt, quant = resolve_kv_dtype(cfg, kv_dtype)
-    L, Hkv, Dh = cfg.n_layers, cfg.kv_heads, cfg.head_dim
+    L = cfg.n_layers if n_layers is None else n_layers
+    Hkv, Dh = cfg.kv_heads, cfg.head_dim
     pool = {
         "k": jnp.zeros((L, n_pages, Hkv, page_size, Dh), dt),
         "v": jnp.zeros((L, n_pages, Hkv, page_size, Dh), dt),
@@ -325,11 +327,20 @@ class PagedSlotCache:
     prompt span; decode writes position ``p`` the same tick it first
     attends ``p``) — the slot-contiguous write-before-attend argument,
     re-proven per page by the no-contamination test in
-    ``tests/test_paged.py``."""
+    ``tests/test_paged.py``.
+
+    A configuration with window layers holds TWO instances side by side
+    (``serving.engine``): the full layers' (``n_layers`` = their count)
+    and, told its ``window``, the window layers' — the same allocator,
+    whose slot gives a page back once every position in it lies behind
+    the next query's window (:meth:`release_behind`), so a slot never
+    holds more than :attr:`window_pages_bound` of them whatever its
+    context."""
 
     def __init__(self, cfg: "T.TransformerConfig", n_slots: int,
                  max_len: int = 0, *, page_size: int = 16,
-                 n_pages: int = 0, kv_dtype=None, mesh=None):
+                 n_pages: int = 0, kv_dtype=None, mesh=None,
+                 n_layers=None, window: int = 0):
         if n_slots < 1:
             raise ValueError(f"need at least one slot, got {n_slots}")
         if page_size < 1:
@@ -339,11 +350,15 @@ class PagedSlotCache:
         self.max_len = max_len or cfg.max_seq
         self.page_size = page_size
         self.max_pages = -(-self.max_len // page_size)
+        self.n_layers = cfg.n_layers if n_layers is None else n_layers
+        self.window = window
         # 0 = capacity parity with the slot-contiguous layout (every
-        # slot can grow to max_len); a smaller pool is the whole point
-        # — mixed-length traffic rarely needs worst case, and the
-        # admission back-pressure handles the tail.
-        self.n_pages = n_pages or n_slots * self.max_pages
+        # slot can grow to max_len, or to its window's bound); a
+        # smaller pool is the whole point — mixed-length traffic rarely
+        # needs worst case, and the admission back-pressure handles the
+        # tail.
+        self.n_pages = n_pages or n_slots * (
+            self.window_pages_bound if window else self.max_pages)
         self.kv_dtype = kv_dtype
         # Tensor-parallel serving (docs/serving.md "Tensor-parallel
         # replicas"): with a mesh, the pool is allocated with an
@@ -356,7 +371,8 @@ class PagedSlotCache:
         self._storage_dtype, self.quantized = resolve_kv_dtype(
             cfg, kv_dtype)
         self.cache = init_page_pool(cfg, n_slots, self.n_pages + 1,
-                                    page_size, kv_dtype)
+                                    page_size, kv_dtype, self.n_layers)
+        self.slot_pages_max = 0  # most pages one slot ever held at once
         if mesh is not None:
             self.cache = T.shard_kv_pool(self.cache, mesh)
         self.table = np.zeros((n_slots, self.max_pages), np.int32)
@@ -465,7 +481,7 @@ class PagedSlotCache:
         lever made legible): payload for k+v across layers, plus the
         per-vector scales for int8."""
         elem = jnp.dtype(self._storage_dtype).itemsize
-        n = self.cfg.n_layers * self.cfg.kv_heads
+        n = self.n_layers * self.cfg.kv_heads
         b = 2 * n * self.cfg.head_dim * elem
         if self.quantized:
             b += 2 * n * 4  # f32 scale per (layer, head, token) vector
@@ -508,7 +524,40 @@ class PagedSlotCache:
         self._ref[pg] = 1
         self.table[slot, idx] = pg
         self.table_version += 1
+        self.slot_pages_max = max(self.slot_pages_max,
+                                  int(np.count_nonzero(self.table[slot])))
         return pg
+
+    # -- a window layer's pages --------------------------------------------
+
+    @property
+    def window_pages_bound(self) -> int:
+        """The most pages a slot of a windowed cache holds at once: the
+        window's span plus one page of rounding."""
+        return min(self.max_pages, -(-self.window // self.page_size) + 1)
+
+    def first_live(self, pos: int) -> int:
+        """The first table index a query at position ``pos`` (or later)
+        can still read: it sees positions ``> pos - window``.  0 for a
+        cache with no window."""
+        if not self.window:
+            return 0
+        return max(0, pos - self.window + 1) // self.page_size
+
+    def release_behind(self, slot: int, pos: int) -> None:
+        """Give back every page of ``slot`` that lies wholly behind the
+        window of a query at ``pos``: the next position the slot writes
+        and attends (decode), or the first query of the chunk after the
+        one just planned (ingestion).  The entries become NULL pages —
+        the kernel's walk starts past them, and a landing routes what
+        falls there to the trash page."""
+        row = self.table[slot, :self.first_live(pos)]
+        held = np.nonzero(row)[0]
+        if held.size:
+            for idx in held:
+                self._decref(int(row[idx]))
+            row[held] = NULL_PAGE
+            self.table_version += 1
 
     def grant_raw(self, n: int) -> List[int]:
         """``n`` pages owned by the CALLER (the prefix registry's pin),

@@ -108,6 +108,7 @@ from horovod_tpu.serving.cache import (  # noqa: F401
     PagedSlotCache,
     SlotCache,
     init_slot_cache,
+    resolve_kv_dtype,
 )
 from horovod_tpu.serving.faults import FaultInjector
 from horovod_tpu.serving.journal import RequestJournal
@@ -470,6 +471,10 @@ class EngineConfig:
     # _ensure_write_range caps the span at the request's last real
     # write, so look-ahead never buys a page nobody keeps.
     page_grant_ahead: int = 0
+    # The WINDOW layers' page pool of a configuration with sliding-
+    # window layers (0 = every slot's bound, ceil(window / page_size)
+    # + 1 pages each: the pool then never runs dry).
+    window_n_pages: int = 0
     # Online autotuning (docs/serving.md "Autotuning"): after warmup()
     # the engine installs a tuning.OnlineTuner over the compile-safe
     # knob space derived from its warmed state and perturbs/scores/
@@ -607,6 +612,24 @@ class InferenceEngine:
                 raise ValueError(
                     f"prefill_chunk_tokens must be >= 1 (or 0 to "
                     f"disable), got {engine_cfg.prefill_chunk_tokens}")
+        if cfg.has_window:
+            # Two kinds of KV state live side by side only where they
+            # are written: the paged single-chip tick.  Every other
+            # mode refuses the configuration here, typed — none may
+            # run it as another model.
+            refused = [why for on, why in (
+                (not engine_cfg.paged, "paged=False (the slot-contiguous "
+                 "cache has one kind of state)"),
+                (engine_cfg.tp > 1, "tp > 1"),
+                (self._spec, "speculative=True"),
+                (engine_cfg.paged and resolve_kv_dtype(
+                    cfg, engine_cfg.kv_dtype)[1], "kv_dtype='int8'"),
+            ) if on]
+            if refused:
+                raise T.UnsupportedModelConfigError(
+                    f"a configuration with window layers (pattern "
+                    f"{cfg.layer_pattern}) is not served with "
+                    + ", ".join(refused))
         # Tensor-parallel mesh (EngineConfig.tp): the engine OWNS the
         # mesh — built once here, params and the page pool placed on
         # it, and every executable below jitted with in/out shardings
@@ -634,6 +657,7 @@ class InferenceEngine:
                 self.draft_params = self._shard.shard_params(
                     self.draft_params, self.draft_cfg)
         self.slots = self._make_slots()
+        self.wslots = self._make_window_slots()
         self.metrics = ServingMetrics()
         self.scheduler = Scheduler(
             max_queue_depth=engine_cfg.max_queue_depth,
@@ -884,14 +908,22 @@ class InferenceEngine:
                 out_s=shd and (_R, _R, _poolsh))
             donate = None
         elif engine_cfg.paged:
+            # An expert model's tick also hands back its experts' load
+            # (three numbers, fetched with the tokens); a model with
+            # window layers takes the two kinds' tables as a pair.
+            _moe = cfg.n_experts > 1
+
             def _tick(params, tokens, active, table, pool, s_t, s_k,
                       s_p, s_key):
                 self._decode_traces += 1
                 obs_tracing.record_compile("serving_decode")
                 pos = pool["pos"]
-                logits, pool = T.decode_step_paged(
+                table, wtable = (table if cfg.has_window
+                                 else (table, None))
+                logits, pool, *load = T.decode_step_paged(
                     params, tokens, pool, table, self.cfg, active,
-                    kernel=_pk, mesh=_pk_mesh)
+                    kernel=_pk, mesh=_pk_mesh, wtable=wtable,
+                    return_moe_load=_moe)
                 # The sampled pick — per-slot temperature/top-k/top-p
                 # COLUMNS and PRNG key ROWS, all data: greedy rows
                 # (temperature 0) are the argmax of old, sampled rows
@@ -900,7 +932,7 @@ class InferenceEngine:
                 # guard covers sampling now too).
                 nxt, mx = self._pick(logits, pos, active, s_t, s_k, s_p,
                                      s_key)
-                return nxt, mx, pool
+                return (nxt, mx, pool, *load)
 
             donate = 4
         else:
@@ -930,7 +962,9 @@ class InferenceEngine:
                 _tick, donate=(donate,),
                 in_s=shd and (_psh, _R, _R, _R, _poolsh,
                               _R, _R, _R, _R),
-                out_s=shd and (_R, _R, _poolsh))
+                out_s=shd and ((_R, _R, _poolsh)
+                               + ((_R,) if cfg.n_experts > 1
+                                  and engine_cfg.paged else ())))
         self._prefill_fns: Dict[tuple, Callable] = {}
         self._prefill_traces = 0
         self._prefill_calls = 0  # prefill FORWARD PASSES (sharing hook)
@@ -962,22 +996,23 @@ class InferenceEngine:
         self._prefix_version = 0  # bumps on (un)register: match cache
         self._cache_epoch = 0
         if engine_cfg.paged:
-            def _suffix_prefill(params, padded, lens, pk, pv, p0):
+            def _suffix_prefill(params, padded, lens, prefix, p0):
                 self._prefill_traces += 1
                 obs_tracing.record_compile("serving_prefill")
+                pk, pv, *win = prefix
+                kw = dict(zip(("win_k", "win_v", "win_start"), win))
                 return T.prefill_with_prefix(
-                    params, padded, pk, pv, p0, self.cfg, true_len=lens)
+                    params, padded, pk, pv, p0, self.cfg, true_len=lens,
+                    **kw)
 
             # jax.jit caches per (n_prefix_pages, bucket, k) shape; the
             # prefix length p0 is a traced scalar, so prefixes of any
             # length share the page-granular compile set.
             self._suffix_prefill = self._jit(
                 _suffix_prefill,
-                in_s=shd and (_psh, _R, _R, _presh, _presh, _R),
+                in_s=shd and (_psh, _R, _R, (_presh, _presh), _R),
                 out_s=shd and (_R, _kvsh))
-            self.metrics.kv_pages_total.set(self.slots.n_pages)
-            self.metrics.kv_pages_free.set(self.slots.free_pages)
-            self.metrics.kv_bytes_per_token.set(self.slots.bytes_per_token)
+            self._update_page_gauges()
 
         # Speculative host state: the per-slot enablement mask (the
         # per-request opt-out, uploaded as DATA like the active mask),
@@ -1317,11 +1352,26 @@ class InferenceEngine:
     def _make_slots(self):
         ec = self.engine_cfg
         if ec.paged:
-            return PagedSlotCache(self.cfg, ec.n_slots, ec.max_len,
-                                  page_size=ec.page_size,
-                                  n_pages=ec.n_pages, kv_dtype=ec.kv_dtype,
-                                  mesh=self.mesh)
+            return PagedSlotCache(
+                self.cfg, ec.n_slots, ec.max_len, page_size=ec.page_size,
+                n_pages=ec.n_pages, kv_dtype=ec.kv_dtype, mesh=self.mesh,
+                n_layers=self.cfg.kind_count("full"))
         return SlotCache(self.cfg, ec.n_slots, ec.max_len)
+
+    def _make_window_slots(self) -> Optional[PagedSlotCache]:
+        """The WINDOW layers' page pool of a configuration that has
+        them: the same allocator class as the full layers', slot-
+        aligned with it (same slot ids, same retirement, like the
+        draft pool), told the window — a slot gives a page back once
+        it lies wholly behind the next query's window."""
+        if not self.cfg.has_window:
+            return None
+        ec = self.engine_cfg
+        return PagedSlotCache(
+            self.cfg, ec.n_slots, ec.max_len, page_size=ec.page_size,
+            n_pages=ec.window_n_pages, kv_dtype=ec.kv_dtype,
+            n_layers=self.cfg.kind_count("sliding"),
+            window=self.cfg.window)
 
     def _make_draft_slots(self) -> Optional[PagedSlotCache]:
         """The draft model's page pool: slot-aligned with the target
@@ -1344,6 +1394,8 @@ class InferenceEngine:
         the draft free heap) and the opt-out mask (reset to the engine
         default for the next tenant)."""
         self.slots.free(slot)
+        if self.wslots is not None and self.wslots._active[slot]:
+            self.wslots.free(slot)
         self._samp.clear(slot)  # greedy/zero row for the next tenant
         self._spec_host[slot] = True
         # The adaptive live/idle state deliberately SURVIVES the
@@ -1368,6 +1420,10 @@ class InferenceEngine:
         paged engine."""
         if not self.engine_cfg.paged:
             raise ValueError("prefix sharing requires EngineConfig.paged")
+        if self.wslots is not None:
+            raise T.UnsupportedModelConfigError(
+                "prefix sharing is not written for window layers' pages "
+                "(a sharer's window would release a page its peers read)")
         tokens = tuple(int(t) for t in tokens)
         if not tokens:
             raise ServingError("empty prefix")
@@ -1694,10 +1750,17 @@ class InferenceEngine:
         for s in range(self.engine_cfg.n_slots):
             if self._states[s] is not None:
                 self._ensure_write_page(s)
-        if (self._dev_table is None
-                or self._table_uploaded != self.slots.table_version):
+        version = (self.slots.table_version,
+                   self.wslots and self.wslots.table_version)
+        if self._dev_table is None or self._table_uploaded != version:
             self._dev_table = jnp.asarray(self.slots.table)
-            self._table_uploaded = self.slots.table_version
+            if self.wslots is not None:
+                # (a COPY: a window's entries go back to NULL while the
+                # tick in flight still reads them, and on a CPU backend
+                # jnp.asarray may alias the host array)
+                self._dev_table = (self._dev_table,
+                                   jnp.asarray(self.wslots.table.copy()))
+            self._table_uploaded = version
 
     def _ensure_write_range(self, s: int, lo: int, hi: int) -> bool:
         """Grant/COW PRIVATE pages under every write position in
@@ -1716,13 +1779,33 @@ class InferenceEngine:
         if hi < lo:
             return True
         ps = self.slots.page_size
+        mine = lambda: self._states[s] is not None  # noqa: E731
         for idx in range(max(lo, 0) // ps, hi // ps + 1):
-            if not self._claim_page(
-                    s, idx, lambda: self._states[s] is not None):
+            if not self._claim_page(s, idx, mine):
                 return False  # s itself was the victim — it paid
+        # (look-ahead is the full pool's knob: the window's bound holds)
+        return self._ensure_window_pages(s, max(lo, 0), max(lo, 0), mine)
+
+    def _ensure_window_pages(self, slot: int, nxt: int, hi: int,
+                             still_mine) -> bool:
+        """The window layers' side of a page plan (nothing without
+        them): ``nxt`` is the next position the slot queries from, so
+        pages wholly behind ITS window go back first, then every page
+        from the window's first up to position ``hi`` is claimed —
+        in that order, so a slot never holds more than the window's
+        bound.  Returns False if ``slot`` itself was evicted."""
+        w = self.wslots
+        if w is None:
+            return True
+        w.release_behind(slot, nxt)
+        for idx in range(w.first_live(nxt), hi // w.page_size + 1):
+            if (w.table[slot, idx] == NULL_PAGE
+                    and not self._claim_page(slot, idx, still_mine, w)):
+                return False
         return True
 
-    def _claim_page(self, slot: int, idx: int, still_mine) -> bool:
+    def _claim_page(self, slot: int, idx: int, still_mine,
+                    cache: Optional[PagedSlotCache] = None) -> bool:
         """THE grant/COW/evict protocol, in one copy (decode growth,
         speculative windows, and chunk ingestion all route here):
         ensure ``slot`` owns a PRIVATE page at table index ``idx`` —
@@ -1730,13 +1813,16 @@ class InferenceEngine:
         (no-op when already private) — preempting victims on
         exhaustion.  ``still_mine()`` is the caller's occupancy check;
         returns False when the caller itself was evicted paying for
-        its page."""
+        its page.  ``cache``: the pool to claim in (the full layers'
+        by default; the window layers' from
+        :meth:`_ensure_window_pages`)."""
+        cache = cache or self.slots
         while True:
             try:
-                if self.slots.table[slot, idx] == NULL_PAGE:
-                    self.slots.grant(slot, idx)
+                if cache.table[slot, idx] == NULL_PAGE:
+                    cache.grant(slot, idx)
                 else:
-                    self.slots.cow(slot, idx)
+                    cache.cow(slot, idx)
                 return True
             except CacheOutOfPagesError:
                 self._evict_for_pages()
@@ -2089,13 +2175,23 @@ class InferenceEngine:
             return nxt, {"nxt": t, "mx": mx, "acc": acc,
                          "spec": self._dev_spec_host.copy()}
         if self.engine_cfg.paged:
-            nxt, mx, cache = self._tick_fn(
+            pool = self.slots.cache
+            if self.wslots is not None:
+                pool = {**pool, "wk": self.wslots.cache["k"],
+                        "wv": self.wslots.cache["v"]}
+            nxt, mx, cache, *load = self._tick_fn(
                 self.params, tokens_dev, active_dev, self._dev_table,
-                self.slots.cache, s_t, s_k, s_p, s_key)
-        else:
-            nxt, mx, cache = self._tick_fn(
-                self.params, tokens_dev, active_dev, self.slots.cache,
-                s_t, s_k, s_p, s_key)
+                pool, s_t, s_k, s_p, s_key)
+            if self.wslots is not None:
+                self.wslots.cache = {**self.wslots.cache,
+                                     "k": cache.pop("wk"),
+                                     "v": cache.pop("wv")}
+            self.slots.cache = cache
+            return nxt, {"nxt": nxt, "mx": mx,
+                         **({"moe": load[0]} if load else {})}
+        nxt, mx, cache = self._tick_fn(
+            self.params, tokens_dev, active_dev, self.slots.cache,
+            s_t, s_k, s_p, s_key)
         self.slots.cache = cache
         return nxt, {"nxt": nxt, "mx": mx}
 
@@ -2108,6 +2204,11 @@ class InferenceEngine:
         self.metrics.kv_bytes_per_token.set(self.slots.bytes_per_token)
         self.metrics.kv_pages_free.set(self.slots.free_pages)
         self.metrics.kv_pages_shared.set(self.slots.pages_shared)
+        if self.wslots is not None:
+            self.metrics.kv_window_pages_total.set(self.wslots.n_pages)
+            self.metrics.kv_window_pages_free.set(self.wslots.free_pages)
+            self.metrics.kv_window_pages_per_slot_max.set(
+                self.wslots.slot_pages_max)
 
     # -- the tick ----------------------------------------------------------
 
@@ -2229,6 +2330,12 @@ class InferenceEngine:
         _, walked = _pa.walk(limit, self._walk_block_tokens)
         self.metrics.paged_live_tokens.inc(int(limit.sum()))
         self.metrics.paged_walked_tokens.inc(int(walked.sum()))
+        if self.wslots is not None:
+            # a window layer's walk: the same statement, told the bound
+            lower = np.maximum(limit - self.cfg.window, 0)
+            _, walked = _pa.walk(limit, self._walk_block_tokens, lower)
+            self.metrics.window_live_tokens.inc(int((limit - lower).sum()))
+            self.metrics.window_walked_tokens.inc(int(walked.sum()))
 
     def _reclaim_cancelled(self) -> bool:
         """Free slots whose requests were cancelled caller-side — their
@@ -2533,6 +2640,23 @@ class InferenceEngine:
         firsts = self._first_tokens(reqs, logits)  # one sync for K
         return slots, reqs, firsts
 
+    def _alloc_slot(self) -> int:
+        """A free slot (``take()`` is bounded by ``free_count``), taken
+        in the window layers' paired pool too."""
+        slot = self.slots.alloc()
+        assert slot is not None
+        if self.wslots is not None:
+            self.wslots.acquire(slot)
+        return slot
+
+    def _land(self, slots, pre: Dict, lens, start: int) -> None:
+        """Land a prefilled block in the slots' pages — each kind of
+        layer's K/V in its own pool."""
+        self.slots.land(slots, pre, lens, start=start)
+        if self.wslots is not None:
+            self.wslots.land(slots, {"k": pre["wk"], "v": pre["wv"],
+                                     "pos": pre["pos"]}, lens, start=start)
+
     def _map_pages(self, slot: int, req: Request,
                    entry: Optional[_PrefixEntry]) -> None:
         """Build one slot's page table for admission: attach the shared
@@ -2544,6 +2668,12 @@ class InferenceEngine:
         if entry is None:
             for idx in range(n_idx):
                 self.slots.grant(slot, idx)
+            if self.wslots is not None:
+                # only what the first decoded token's window reaches:
+                # the landing routes the rest to the trash page
+                for idx in range(self.wslots.first_live(len(req.prompt)),
+                                 n_idx):
+                    self.wslots.grant(slot, idx)
             return
         p0 = len(entry.tokens)
         self.slots.attach(slot, entry.pages)
@@ -2579,8 +2709,7 @@ class InferenceEngine:
         slots: List[int] = []
         live: List[Request] = []
         for req in reqs:
-            slot = self.slots.alloc()
-            assert slot is not None  # take() is bounded by free_count
+            slot = self._alloc_slot()
             try:
                 self._map_pages(slot, req, entry)
             except CacheOutOfPagesError as e:
@@ -2617,10 +2746,10 @@ class InferenceEngine:
                 padded = np.zeros((k, bucket), np.int32)
                 for i, r in enumerate(live):
                     padded[i, :len(r.prompt) - p0] = r.prompt[p0:]
-                pk, pv = self.slots.gather_prefix(entry.pages)
                 logits, suf = self._suffix_prefill(
                     self.params, jnp.asarray(padded),
-                    jnp.asarray(suf_lens), pk, pv, jnp.int32(p0))
+                    jnp.asarray(suf_lens),
+                    self.slots.gather_prefix(entry.pages), jnp.int32(p0))
                 self._count_prefill(int(suf_lens.sum()), k, bucket)
                 self.slots.land(slots, suf, suf_lens, start=p0)
                 firsts = self._first_tokens(live, logits)
@@ -2634,7 +2763,7 @@ class InferenceEngine:
             logits, pre = self._prefill_fn(bucket, k)(
                 self.params, jnp.asarray(padded), jnp.asarray(lens))
             self._count_prefill(int(lens.sum()), k, bucket)
-            self.slots.land(slots, pre, lens, start=0)
+            self._land(slots, pre, lens, start=0)
             firsts = self._first_tokens(live, logits)
         for slot, req in zip(slots, live):
             self._page_pos[slot] = len(req.prompt)
@@ -2674,8 +2803,7 @@ class InferenceEngine:
                     self._ensure_prefix(entry)
                 except CacheOutOfPagesError:
                     entry = None  # degrade: chunk the whole prompt
-            slot = self.slots.alloc()
-            assert slot is not None  # take() is bounded by free_count
+            slot = self._alloc_slot()
             p0 = 0
             if entry is not None:
                 self.slots.attach(slot, entry.pages)
@@ -2709,12 +2837,23 @@ class InferenceEngine:
         + suffix-prefill compile set is bounded by page-count buckets
         — chunk boundaries stay pure data."""
         n_pg = self.slots.pages_for(lo)
-        pages = [int(self.slots.table[slot, i]) for i in range(n_pg)]
-        padded = 1
-        while padded < n_pg:
-            padded *= 2
-        pages += [NULL_PAGE] * (padded - n_pg)
-        return self.slots.gather_prefix(pages)
+
+        def gather(cache, first):
+            pages = [int(cache.table[slot, i]) for i in range(first, n_pg)]
+            padded = 1
+            while padded < len(pages):
+                padded *= 2
+            return cache.gather_prefix(
+                pages + [NULL_PAGE] * (padded - len(pages)))
+
+        prefix = gather(self.slots, 0)
+        if self.wslots is not None:
+            # the window layers' block starts at the first page the
+            # chunk's first query (position lo) still sees
+            first = self.wslots.first_live(lo)
+            prefix += gather(self.wslots, first) + (
+                jnp.int32(first * self.wslots.page_size),)
+        return prefix
 
     def _ingest_step(self, slot: int) -> bool:
         """Land ONE chunk of ``slot``'s prompt: grant/COW the chunk's
@@ -2759,6 +2898,14 @@ class InferenceEngine:
                 self.engine_cfg.prefill_chunk_tokens)
         if not self._ensure_ingest_pages(slot, lo, lo + n - 1):
             return True  # preempted paying for its own chunk
+        # The landed prefix is read through the tables as they stand
+        # (its gather is dispatched now); only then do the window
+        # layers give back what the NEXT chunk's window no longer
+        # reaches and claim the pages this chunk's tail lands in.
+        prefix = self._gather_landed(slot, lo) if lo else None
+        if not self._ensure_window_pages(
+                slot, lo + n, lo + n - 1, lambda: slot in self._ingest):
+            return True
         # ONE bucket for every chunk — the full chunk width, with the
         # tail chunk right-padded and its real length as data
         # (true_len): a partial last chunk must not mint its own
@@ -2771,14 +2918,13 @@ class InferenceEngine:
             logits, pre = self._prefill_fn(bucket, 1)(
                 self.params, jnp.asarray(padded), lens)
             self._count_prefill(n, 1, bucket, chunk=True)
-            self.slots.land([slot], pre, np.asarray([n]), start=0)
+            self._land([slot], pre, np.asarray([n]), start=0)
         else:
-            pk, pv = self._gather_landed(slot, lo)
             logits, suf = self._suffix_prefill(
-                self.params, jnp.asarray(padded), lens, pk, pv,
+                self.params, jnp.asarray(padded), lens, prefix,
                 jnp.int32(lo))
             self._count_prefill(n, 1, bucket, chunk=True)
-            self.slots.land([slot], suf, np.asarray([n]), start=lo)
+            self._land([slot], suf, np.asarray([n]), start=lo)
         self._tick_prefill_spent += n
         self._tick_ingested.add(slot)
         ing.landed = lo + n
@@ -3056,6 +3202,13 @@ class InferenceEngine:
             nxt = np.asarray(p["nxt"])           # (S,) — or (S, W) spec
             mx = np.asarray(p["mx"])
             acc = np.asarray(p["acc"]) if "acc" in p else None
+            if "moe" in p:   # the experts' load rides the same fetch
+                rows, touched, load_max = np.asarray(p["moe"]).tolist()
+                self.metrics.moe_rows.inc(rows)
+                self.metrics.moe_experts_touched.inc(touched)
+                self.metrics.moe_load_max_rows.inc(load_max)
+                self.metrics.moe_load_mean_rows.inc(
+                    rows / self.cfg.n_experts)
             self.metrics.host_syncs.inc()
         with self._phase("tick_host"):
             self._apply_tick(p, nxt, mx, acc, wait.start + wait.dur)
@@ -3258,8 +3411,9 @@ class InferenceEngine:
         self._states = [None] * self.engine_cfg.n_slots
         self._ingest = {}
         self.slots.release_all()
-        if self.draft_slots is not None:
-            self.draft_slots.release_all()
+        for paired in (self.wslots, self.draft_slots):
+            if paired is not None:
+                paired.release_all()
         self._reset_spec_state()
         # release_all zeroed every page refcount, including the prefix
         # registry's pins: bump the epoch HERE (not just in _restart)
@@ -3418,6 +3572,7 @@ class InferenceEngine:
         DRAINING (still rejecting new work), everything else restarts
         DEGRADED."""
         self.slots = self._make_slots()
+        self.wslots = self._make_window_slots()
         self.draft_slots = self._make_draft_slots()
         self._reset_spec_state()
         self._states = [None] * self.engine_cfg.n_slots
@@ -3854,6 +4009,9 @@ class InferenceEngine:
                 "page_size": self.slots.page_size,
                 "kv_dtype": str(jnp.dtype(self.slots._storage_dtype).name),
                 "kv_pages_high_water": self.slots.pages_high_water,
+                "kv_window_pages_per_slot_bound":
+                    self.wslots.window_pages_bound
+                    if self.wslots is not None else 0,
                 "prefixes_registered": len(self._prefixes),
                 # Whether the decode/draft/verify ticks were built on
                 # the fused Pallas paged-attention kernel — what RAN,

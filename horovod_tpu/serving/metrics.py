@@ -101,12 +101,25 @@ class ServingMetrics:
       paged tick, the positions the active slots may attend, and the
       positions the paged kernel's walk covers for them: each slot's
       limit rounded up to a block (``ops.paged_attention.walk``).
+      With window layers these are the FULL layers'; ``window_live_
+      tokens`` / ``window_walked_tokens`` are the window layers' (live:
+      the window's span; walked: whole blocks from the one holding the
+      window's first position).
+    * ``moe_rows`` / ``moe_experts_touched`` / ``moe_load_max_rows`` /
+      ``moe_load_mean_rows`` — an expert model's decode ticks, summed
+      over layers: expert rows computed (active slots x experts a
+      token), experts handed at least one row, the largest expert's
+      rows, and rows / experts (``max / mean`` is the imbalance).  They
+      ride out with the tick's tokens: no extra host sync.
     * ``kv_pages_total`` / ``kv_pages_free`` / ``kv_pages_shared`` /
       ``kv_bytes_per_token`` — page-pool pressure gauges for the paged
       KV cache (docs/serving.md "Paged KV cache"): pool size, free
       heap depth (admission headroom), pages referenced by >1 owner
       (prefix sharing in effect), and the per-token cache cost the
       ``kv_dtype`` lever moves.  All 0 on a slot-contiguous engine.
+      ``kv_window_pages_total`` / ``_free`` / ``_per_slot_max`` are
+      the window layers' pool (0 without window layers): size, free
+      heap, and the most pages one slot ever held at once.
     * ``decode_ticks`` / ``host_syncs`` — dispatched decode ticks and
       host sync points (value fetches that block on device work) on
       the decode hot path.  Steady-state overlapped decode performs
@@ -226,6 +239,29 @@ class ServingMetrics:
             "Per dispatched paged tick, the positions the paged "
             "attention's walk covers (each active slot's limit rounded "
             "up to a block of pages)")
+        self.window_live_tokens = r.counter(
+            "serving_window_live_tokens_total",
+            "Per dispatched paged tick, the positions its active slots "
+            "may attend in a window layer (the window's span)")
+        self.window_walked_tokens = r.counter(
+            "serving_window_walked_tokens_total",
+            "Per dispatched paged tick, the positions a window layer's "
+            "walk covers (whole blocks from the window's first)")
+        self.moe_rows = r.counter(
+            "serving_moe_rows_total",
+            "Expert rows computed by decode ticks, summed over layers "
+            "(active slots x experts a token)")
+        self.moe_experts_touched = r.counter(
+            "serving_moe_experts_touched_total",
+            "Experts handed at least one row by a decode tick, summed "
+            "over layers")
+        self.moe_load_max_rows = r.counter(
+            "serving_moe_load_max_rows_total",
+            "The largest expert's rows in a decode tick, summed over "
+            "layers")
+        self.moe_load_mean_rows = r.counter(
+            "serving_moe_load_mean_rows_total",
+            "Rows over experts in a decode tick, summed over layers")
         self.decode_ticks = r.counter(
             "serving_decode_ticks_total", "Decode ticks dispatched")
         self.host_syncs = r.counter(
@@ -241,6 +277,15 @@ class ServingMetrics:
             "serving_kv_pages_shared",
             "KV pages referenced by more than one owner "
             "(prefix sharing in effect)")
+        self.kv_window_pages_total = r.gauge(
+            "serving_kv_window_pages_total",
+            "Window layers' KV page pool size (0 = no window layers)")
+        self.kv_window_pages_free = r.gauge(
+            "serving_kv_window_pages_free",
+            "Window layers' KV pages on the free heap")
+        self.kv_window_pages_per_slot_max = r.gauge(
+            "serving_kv_window_pages_per_slot_max",
+            "Most window-layer pages one slot ever held at once")
         self.kv_bytes_per_token = r.gauge(
             "serving_kv_bytes_per_token",
             "KV cache bytes per stored token (k+v across layers, "
@@ -387,8 +432,23 @@ class ServingMetrics:
                 self.prefill_padded_tokens.value,
             "paged_live_tokens_total": self.paged_live_tokens.value,
             "paged_walked_tokens_total": self.paged_walked_tokens.value,
+            "window_live_tokens_total": self.window_live_tokens.value,
+            "window_walked_tokens_total": self.window_walked_tokens.value,
+            "moe_rows_total": self.moe_rows.value,
+            "moe_experts_touched_total": self.moe_experts_touched.value,
+            "moe_load_max_rows_total": self.moe_load_max_rows.value,
+            "moe_load_mean_rows_total": self.moe_load_mean_rows.value,
             "kv_pages_total": self.kv_pages_total.value,
             "kv_pages_free": self.kv_pages_free.value,
+            "kv_pages_in_use":
+                self.kv_pages_total.value - self.kv_pages_free.value,
+            "kv_window_pages_total": self.kv_window_pages_total.value,
+            "kv_window_pages_free": self.kv_window_pages_free.value,
+            "kv_window_pages_in_use":
+                self.kv_window_pages_total.value
+                - self.kv_window_pages_free.value,
+            "kv_window_pages_per_slot_max":
+                self.kv_window_pages_per_slot_max.value,
             "kv_pages_shared": self.kv_pages_shared.value,
             "kv_bytes_per_token": self.kv_bytes_per_token.value,
             "tokens_per_tick": self.tokens_per_tick.snapshot(),
