@@ -21,7 +21,10 @@ families sit on their supported tiling):
 3. **server** — ``serving.InferenceEngine`` with its defaults left alone
    (paged, overlapped, ``paged_kernel=None``, pages of 16, bf16 KV) →
    ``warmup`` → ``serving.ServingServer(port=0)`` → concurrent
-   ``POST /generate`` of mixed prompt lengths → ``GET /stats``.
+   ``POST /generate`` of mixed prompt lengths → ``GET /stats``.  The
+   compiled decode tick and the compiled landing are then read: the KV
+   pool is written in place, so no instruction of either may have a
+   result the size of one layer of it (:func:`pool_sized_results`).
 4. on more than one chip — the data-parallel step spread over all of
    them and agreeing with one device, the eager allreduce, and ``tp=n``
    serving answering like ``tp=1``.
@@ -49,6 +52,7 @@ import gc
 import importlib.metadata
 import json
 import os
+import re
 import shutil
 import sys
 import threading
@@ -83,6 +87,12 @@ class SmokeConfig:
     # partial last pages (page_size stays the engine default, 16)
     n_slots: int = 4
     serve_max_len: int = 256
+    # The KV pool is sized as a deployment's (16 384 pages: 134 M
+    # elements a layer, 4.3 GB of bf16 K and V over 8 layers), not as
+    # these six requests need: the compiled tick and landing are read
+    # for results the size of one layer of it, and at that size nothing
+    # else in either program is as large (the embedding is 65.5 M).
+    serve_n_pages: int = 16384
     prompt_lens: Tuple[int, ...] = (5, 23, 40, 100, 9, 61)
     max_new_tokens: int = 8
     # Logit-level tolerance, in units of the spread of the logits it is
@@ -141,6 +151,75 @@ def _require_compiled(smoke: SmokeConfig, text: str, at_least: int,
     _require(n >= at_least,
              f"{what}: {n} Mosaic custom call(s) in the compiled "
              f"executable, expected at least {at_least}")
+
+
+# What may have a result the size of the KV pool in a compiled program
+# that writes it in place: the pool's own pass-through.
+_POOL_PASS_THROUGH = ("parameter", "while", "tuple", "get-tuple-element",
+                      "bitcast")
+_HLO_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(")
+_HLO_ARRAY = re.compile(r"\b[a-z]+\d+\[([\d,]*)\]")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
+
+
+def pool_sized_results(text: str, floor: int):
+    """Read a compiled executable's HLO text: ``(offenders, largest)``.
+
+    ``largest`` are the five instructions with the largest results
+    (elements of the largest array in the result's type) outside the
+    pass-through opcodes; ``offenders`` those of at least ``floor``
+    elements that are neither pass-through nor an IN-PLACE write — an
+    instruction whose result the compiler aliased to an operand
+    (``aliasing_operands`` of a TPU scatter fusion, a custom call's
+    ``output_to_operand_aliasing``): the donated pool updated where it
+    lies.  Instructions inside a fused computation have no buffer of
+    their own and are skipped.  Each entry is ``(elements, opcode,
+    name, op_name)``."""
+    lines = text.splitlines()
+    fused = set()
+    for line in lines:
+        if " fusion(" in line:
+            fused.update(re.findall(r"calls=%?([\w.\-]+)", line))
+    found, inside = [], None
+    for line in lines:
+        comp = _HLO_COMPUTATION.match(line)
+        if comp:
+            inside = comp.group(1)
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if not m or inside in fused:
+            continue
+        name, result, opcode = m.groups()
+        if opcode in _POOL_PASS_THROUGH:
+            continue
+        size = max((int(np.prod([int(d) for d in dims.split(",") if d]))
+                    for dims in _HLO_ARRAY.findall(result)), default=0)
+        in_place = ('"aliasing_operands":{"lists":[{' in line
+                    or "output_to_operand_aliasing={" in line)
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        found.append((size, opcode, name, op_name.group(1) if op_name else "",
+                      in_place))
+    found.sort(reverse=True)
+    return ([f[:4] for f in found if f[0] >= floor and not f[4]],
+            [f[:4] for f in found[:5]])
+
+
+def _require_pool_in_place(smoke: SmokeConfig, text: str, floor: int,
+                           what: str) -> None:
+    """No instruction of ``text`` may produce ``floor`` elements — one
+    layer of a KV pool — other than the pool's pass-through and its
+    in-place writes.  The largest results are printed either way; the
+    requirement is the TPU compiler's to meet (layout assignment is
+    its), so off the chip they are only printed."""
+    offenders, largest = pool_sized_results(text, floor)
+    _say(f"{what}: largest results (a layer of the pool is {floor} "
+         f"elements): " + "; ".join(
+             f"{n} {op} {name} [{scope}]" for n, op, name, scope in largest))
+    if smoke.expect_compiled:
+        _require(not offenders,
+                 f"{what}: {len(offenders)} instruction(s) with a result "
+                 f"the size of a layer of the KV pool: {offenders[:5]}")
 
 
 # --- phase 0: what machine is this -------------------------------------------
@@ -552,22 +631,26 @@ def phase_serve(smoke: SmokeConfig, host_params, *, tp: int = 1) -> Dict:
         params, cfg,
         serving.EngineConfig(
             n_slots=smoke.n_slots, max_len=smoke.serve_max_len, tp=tp,
+            n_pages=smoke.serve_n_pages,
             # the chip takes the engine's own default (auto); the CPU
             # test asks for the interpreted kernel explicitly
             paged_kernel=None if smoke.expect_compiled else True))
-    # Record the shapes the engine calls its decode tick with, so the
-    # SAME executable can be inspected afterwards.
-    tick, seen = engine._tick_fn, {}
+    # Record the shapes the engine calls its decode tick and its landing
+    # with, so the SAME executables can be inspected afterwards.
+    seen = {}
 
-    def recording_tick(*args):
-        if not seen:
-            seen["avals"] = jax.tree_util.tree_map(
+    def recording(name, fn):
+        def call(*args):
+            seen.setdefault(name, jax.tree_util.tree_map(
                 lambda a: jax.ShapeDtypeStruct(
                     np.shape(a), a.dtype,
-                    sharding=getattr(a, "sharding", None)), args)
-        return tick(*args)
+                    sharding=getattr(a, "sharding", None)), args))
+            return fn(*args)
+        return call
 
-    engine._tick_fn = recording_tick
+    tick, land = engine._tick_fn, engine.slots._insert
+    engine._tick_fn = recording("tick", tick)
+    engine.slots._insert = recording("land", land)
     t0 = time.perf_counter()
     engine.warmup(sorted(set(smoke.prompt_lens)))
     warm_s = round(time.perf_counter() - t0, 1)
@@ -619,8 +702,16 @@ def phase_serve(smoke: SmokeConfig, host_params, *, tp: int = 1) -> Dict:
     _require(stats["requests_completed"] >= len(prompts),
              f"{stats['requests_completed']} requests completed")
     # The decode tick's own executable, at the shapes it was served at.
-    text = tick.lower(*seen["avals"]).compile().as_text()
+    text = tick.lower(*seen["tick"]).compile().as_text()
     _require_compiled(smoke, text, 1, f"decode tick (tp={tp})")
+    # ... and the pool it serves from is written where it lies: in the
+    # tick, and in the landing of a prefill.
+    pool = engine.slots.cache["k"]
+    layer = int(np.prod(pool.shape[1:])) // tp
+    _require_pool_in_place(smoke, text, layer, f"decode tick (tp={tp})")
+    _require_pool_in_place(
+        smoke, land.lower(*seen["land"]).compile().as_text(), layer,
+        f"landing (tp={tp})")
 
     tokens = {i: out[i]["tokens"] for i in sorted(out)}
     for i, toks in tokens.items():

@@ -369,8 +369,10 @@ def _rope(q, k, theta: float, pos_offset=0, positions=None, yarn=()):
 def _scan_layers(layer, init, xs):
     """``lax.scan`` over the stacked layers, under the ``layer_scan``
     scope: the scan's OWN operations — slicing each layer's parameters
-    and cache out of the stacked arrays, stacking the results back —
-    carry no other scope, so a profile tells them from the layer's."""
+    out of the stacked arrays, stacking its results — carry no other
+    scope, so a profile tells them from the layer's.  A paged KV pool is
+    never among ``xs`` or the results: it rides the carry and is written
+    in place (:func:`decode_step_paged`)."""
     with jax.named_scope("layer_scan"):
         return lax.scan(layer, init, xs)
 
@@ -393,8 +395,10 @@ def _scan_layer_kinds(cfg: TransformerConfig, layer, init, layers, xs=None):
     """:func:`_scan_layers` for a stack with more than one kind of
     layer.  ``layer(carry, p, kind, xs_l) -> (carry, ys_l)`` is told its
     layer's kind as a Python string; ``xs`` maps a kind to a pytree
-    stacked over THAT kind's layers (a pool of its own shape), and the
-    result maps each kind to its layers' stacked ``ys``.
+    stacked over THAT kind's layers (a landed prefix of its own length;
+    for what rides the carry, such as a paged pool, the layer's index
+    among its kind: ``jnp.arange``), and the result maps each kind to
+    its layers' stacked ``ys``.
 
     A uniform stack is one plain scan over the layers.  A patterned one
     scans over PERIODS: the body applies the period's layers in order,
@@ -857,18 +861,20 @@ def paged_kernel_specs(quantized: bool = False):
     :meth:`~horovod_tpu.serving.sharding.ServingSharding.
     paged_kernel_shardings` both read.  The kernel's grid is
     per-(slot, kv-head) with no cross-head communication, so grouped
-    queries, the per-layer pool, and int8 scales all split at the
-    kv-head dim over ``tp`` while the page table and per-slot limits
-    stay replicated host data; outputs come back head-sharded, matching
-    the out-projection that consumes them.  Returns ``(in_specs,
-    out_specs)`` ordered as ``(q, k_pool, v_pool[, k_scale, v_scale],
-    table, limit)`` / ``(o, lse)``."""
+    queries, the STACKED pool ``(L, P, H_kv, page, Dh)`` and int8 scales
+    all split at the kv-head dim over ``tp`` while the page table, the
+    per-slot limits and the layer's index stay replicated host data;
+    outputs come back head-sharded, matching the out-projection that
+    consumes them.  Returns ``(in_specs, out_specs)`` ordered as ``(q,
+    k_pool, v_pool[, k_scale, v_scale], table, limit, layer)`` / ``(o,
+    lse)``."""
     head = P(None, "tp", None, None)
-    scale = P(None, "tp", None)
-    in_specs = (head, head, head)
+    pool = P(None, None, "tp", None, None)
+    scale = P(None, None, "tp", None)
+    in_specs = (head, pool, pool)
     if quantized:
         in_specs = in_specs + (scale, scale)
-    return in_specs + (P(), P()), (head, P(None, "tp", None))
+    return in_specs + (P(), P(), P()), (head, P(None, "tp", None))
 
 
 def prefix_kv_specs():
@@ -1084,8 +1090,8 @@ def kv_quantize(x):
     """Symmetric per-vector int8 quantization over the trailing head
     dim (the KIVI/KVQuant-style per-token granularity): each ``(..., Dh)``
     vector gets its own f32 scale, so a later write never has to
-    re-quantize earlier positions — the scale is written once, in the
-    same scatter as the int8 payload, and write-before-attend carries
+    re-quantize earlier positions — the scale is written once, by the
+    same page write as the int8 payload, and write-before-attend carries
     over to quantized pages unchanged.  Returns ``(q int8, scale f32)``
     with ``scale`` lacking the trailing dim."""
     xf = x.astype(jnp.float32)
@@ -1109,51 +1115,55 @@ def kv_dequantize(q, scale, dtype):
             * scale[..., None].astype(jnp.float32)).astype(dtype)
 
 
-def _gather_pages(pool_l, table):
-    """Resolve one layer's page pool through a page table: ``pool_l``
-    ``(P, H_kv, page, Dh)`` gathered by ``table`` ``(S, max_pages)`` ->
-    the per-slot LOGICAL cache ``(S, H_kv, max_pages * page, Dh)``.
-    The table is DATA (int32 indices), so the gather is one executable
-    for every allocation pattern — pages can come, go, grow, and be
-    shared without recompiling the tick."""
+def _gather_pages(pool, layer, table):
+    """Resolve one layer of a page pool through a page table: the
+    stacked ``pool`` ``(L, P, H_kv, page, Dh)`` gathered at ``[layer,
+    table]`` (``table`` ``(S, max_pages)``) -> the per-slot LOGICAL
+    cache ``(S, H_kv, max_pages * page, Dh)``.  The layer is never cut
+    out of the stack first: the gather's indices are the two leading
+    dims.  The table is DATA (int32 indices), so the gather is one
+    executable for every allocation pattern — pages can come, go, grow,
+    and be shared without recompiling the tick."""
     S, max_pages = table.shape
-    _, Hkv, ps, Dh = pool_l.shape
-    g = pool_l[table]                      # (S, max_pages, H_kv, ps, Dh)
+    _, _, Hkv, ps, Dh = pool.shape
+    g = pool[layer, table]                 # (S, max_pages, H_kv, ps, Dh)
     return jnp.moveaxis(g, 1, 2).reshape(S, Hkv, max_pages * ps, Dh)
 
 
-def _gather_scales(scale_l, table):
-    """Scale companion of :func:`_gather_pages`: ``(P, H_kv, page)`` ->
-    ``(S, H_kv, max_pages * page)``."""
+def _gather_scales(scale, layer, table):
+    """Scale companion of :func:`_gather_pages`: ``(L, P, H_kv, page)``
+    -> ``(S, H_kv, max_pages * page)``."""
     S, max_pages = table.shape
-    _, Hkv, ps = scale_l.shape
-    g = scale_l[table]                     # (S, max_pages, H_kv, ps)
+    _, _, Hkv, ps = scale.shape
+    g = scale[layer, table]                # (S, max_pages, H_kv, ps)
     return jnp.moveaxis(g, 1, 2).reshape(S, Hkv, max_pages * ps)
 
 
-def _paged_kernel_attend(qg, k_pool, v_pool, k_scale, v_scale, table,
-                         limit, cfg: TransformerConfig, mesh=None,
+def _paged_kernel_attend(qg, k_pool, v_pool, k_scale, v_scale, layer,
+                         table, limit, cfg: TransformerConfig, mesh=None,
                          lower=None):
-    """Call the fused paged-attention kernel for one layer, under
-    ``shard_map`` when a tp mesh is given.
+    """Call the fused paged-attention kernel for layer ``layer`` of the
+    STACKED pools, under ``shard_map`` when a tp mesh is given.
+
+    The kernel takes the whole stack and the layer's index (a traced
+    scalar): a custom call would otherwise make the layer scan cut its
+    operand — a whole layer of the pool — out of the stack first.
 
     The kernel's grid is per-(slot, kv-head) with NO cross-head
     communication, so the tp=N head-sharded pool (``paged_pool_specs``)
     maps onto it shard-locally: each device runs the kernel over its
-    own ``H_kv / tp`` heads against its own pool shard, with the table
-    and per-slot limits replicated (host tick data).  Outputs come back
-    head-sharded, matching the projection that consumes them.  Without
-    a mesh the kernel is called directly (single-device serving)."""
+    own ``H_kv / tp`` heads against its own pool shard, with the table,
+    the per-slot limits and the layer index replicated (host tick data).
+    Outputs come back head-sharded, matching the projection that
+    consumes them.  Without a mesh the kernel is called directly
+    (single-device serving)."""
     from horovod_tpu.ops import paged_attention as _pa
 
     quantized = k_scale is not None
     if mesh is None:
-        if lower is not None:   # a window layer: a call of its own shape
-            return _pa.paged_attend(qg, k_pool, v_pool, k_scale, v_scale,
-                                    table, limit, compute_dtype=cfg.dtype,
-                                    lower=lower)
         return _pa.paged_attend(qg, k_pool, v_pool, k_scale, v_scale,
-                                table, limit, compute_dtype=cfg.dtype)
+                                table, limit, compute_dtype=cfg.dtype,
+                                lower=lower, layer=layer)
     if lower is not None:
         raise UnsupportedModelConfigError(
             "a window layer's paged kernel is not written for a tp mesh")
@@ -1163,25 +1173,36 @@ def _paged_kernel_attend(qg, k_pool, v_pool, k_scale, v_scale, table,
     in_specs, out_specs = paged_kernel_specs(quantized)
     if quantized:
         fn = spmd.shard(
-            lambda q_, k_, v_, ks_, vs_, t_, l_: _pa.paged_attend(
-                q_, k_, v_, ks_, vs_, t_, l_, compute_dtype=cfg.dtype),
+            lambda q_, k_, v_, ks_, vs_, t_, l_, i_: _pa.paged_attend(
+                q_, k_, v_, ks_, vs_, t_, l_, compute_dtype=cfg.dtype,
+                layer=i_),
             in_specs=in_specs, out_specs=out_specs, mesh=mesh)
-        return fn(qg, k_pool, v_pool, k_scale, v_scale, table, limit)
+        return fn(qg, k_pool, v_pool, k_scale, v_scale, table, limit, layer)
     fn = spmd.shard(
-        lambda q_, k_, v_, t_, l_: _pa.paged_attend(
-            q_, k_, v_, None, None, t_, l_, compute_dtype=cfg.dtype),
+        lambda q_, k_, v_, t_, l_, i_: _pa.paged_attend(
+            q_, k_, v_, None, None, t_, l_, compute_dtype=cfg.dtype,
+            layer=i_),
         in_specs=in_specs, out_specs=out_specs, mesh=mesh)
-    return fn(qg, k_pool, v_pool, table, limit)
+    return fn(qg, k_pool, v_pool, table, limit, layer)
 
 
-def _attention_decode_paged(x, p, cfg: TransformerConfig, k_pool, v_pool,
-                            k_scale, v_scale, table, pos, active,
-                            kernel=False, mesh=None, kind: str = "full"):
+def _attention_decode_paged(x, p, cfg: TransformerConfig, kv, layer, table,
+                            pos, active, kernel=False, mesh=None,
+                            kind: str = "full"):
     """Per-slot one-token attention against a PAGED cache: row ``s``
     writes its K/V at logical position ``pos[s]`` — resolved through
     the page table to ``(page table[s, pos//page], offset pos%page)`` —
     then gathers its pages back into logical order and attends
     positions ``<= pos[s]`` (the shared :func:`_cache_attend` math).
+
+    ``kv`` holds the STACKED pools of this layer's kind, ``(k, v)`` each
+    ``(L, P, H_kv, page, Dh)`` (``(k, v, k_scale, v_scale)`` for int8
+    storage), and comes back written; ``layer`` is this layer's
+    (traced) index into them: the write is
+    :func:`~horovod_tpu.serving.cache.write_pages` at ``[layer, page]``
+    and the attend reads ``[layer, table]``, so no operation cuts a
+    layer out of the stack or has a result of its size, and the caller
+    carries the stacks through its layer scan as loop state.
 
     Inactive rows are routed to physical page 0, the reserved NULL/
     trash page no live slot's table ever maps below its own position:
@@ -1193,15 +1214,15 @@ def _attention_decode_paged(x, p, cfg: TransformerConfig, k_pool, v_pool,
     PRIVATE (refcount 1; copy-on-write splits a shared page before any
     write targets it).
 
-    ``k_scale``/``v_scale`` are the per-(head, position) f32 scales of
-    int8 pools (None for bf16/f32 storage): the payload is dequantized
+    The scales are the per-(head, position) f32 scales of int8 pools
+    (absent for bf16/f32 storage): the payload is dequantized
     AFTER the gather, so only the logical view — not the whole pool —
     is ever materialized at compute dtype.
 
     ``kernel=True`` replaces the gather -> dequant -> attend tail with
     the fused Pallas flash-decoding kernel (:mod:`horovod_tpu.ops.
     paged_attention`): the pages stream through VMEM with int8 dequant
-    in the load and NOTHING materialized at logical shape.  The scatter
+    in the load and NOTHING materialized at logical shape.  The write
     (write-before-attend) is identical under both paths, so the fused
     tick attends exactly the same pool state; ``mesh`` routes the
     kernel through ``shard_map`` for tp head-sharded pools.
@@ -1209,47 +1230,39 @@ def _attention_decode_paged(x, p, cfg: TransformerConfig, k_pool, v_pool,
     ``kind="sliding"``: the pool and table are the window layers' own,
     and row ``s`` attends positions ``pos[s] - window < t <= pos[s]``
     only — the table's entries behind that may already be released."""
+    from horovod_tpu.serving.cache import write_pages
+
     S = x.shape[0]
     max_pages = table.shape[1]
-    ps = k_pool.shape[2]
-    quantized = k_scale is not None
+    ps = kv[0].shape[3]
     qh, k_t, v_t = _qkv_proj(x, p, cfg, positions=pos[:, None], kind=kind)
     lower = (jnp.maximum(pos - cfg.window + 1, 0) if kind == "sliding"
              else None)
     with jax.named_scope("kv_write"):
-        k_t1 = k_t[:, :, 0, :]                      # (S, H_kv, Dh)
-        v_t1 = v_t[:, :, 0, :]
         idx = jnp.clip(pos // ps, 0, max_pages - 1)
         phys = jnp.where(active, table[jnp.arange(S), idx], 0)
-        off = pos % ps
-        if quantized:
-            qk, sk = kv_quantize(k_t1)
-            qv, sv = kv_quantize(v_t1)
-            k_pool = k_pool.at[phys, :, off, :].set(qk)
-            v_pool = v_pool.at[phys, :, off, :].set(qv)
-            k_scale = k_scale.at[phys, :, off].set(sk)
-            v_scale = v_scale.at[phys, :, off].set(sv)
-        else:
-            k_pool = k_pool.at[phys, :, off, :].set(
-                k_t1.astype(k_pool.dtype))
-            v_pool = v_pool.at[phys, :, off, :].set(
-                v_t1.astype(v_pool.dtype))
+        take = jnp.arange(ps, dtype=jnp.int32) == (pos % ps)[:, None]
+        rows = (k_t, v_t)                  # each (S, H_kv, 1, Dh)
+        if len(kv) == 4:                   # ... and the (S, H_kv, 1) scales
+            (qk, sk), (qv, sv) = kv_quantize(k_t), kv_quantize(v_t)
+            rows = (qk, qv, sk, sv)
+        kv = tuple(write_pages(stack, layer, phys, row, take)
+                   for stack, row in zip(kv, rows))
     with jax.named_scope("paged_attend"):
-        o = _paged_decode_attend(qh, k_pool, v_pool, k_scale, v_scale,
+        o = _paged_decode_attend(qh, *kv, *(None,) * (4 - len(kv)), layer,
                                  table, pos, active, cfg, kernel, mesh,
                                  lower)
-    return (_out_proj(o.astype(cfg.dtype), p, cfg),
-            k_pool, v_pool, k_scale, v_scale)
+    return _out_proj(o.astype(cfg.dtype), p, cfg), kv
 
 
-def _paged_decode_attend(qh, k_pool, v_pool, k_scale, v_scale, table, pos,
-                         active, cfg: TransformerConfig, kernel, mesh,
+def _paged_decode_attend(qh, k_pool, v_pool, k_scale, v_scale, layer, table,
+                         pos, active, cfg: TransformerConfig, kernel, mesh,
                          lower=None):
     """The attend tail of :func:`_attention_decode_paged` (after the
     write): the fused kernel, or gather -> dequant -> ``_cache_attend``;
     ``lower`` is a window layer's first visible position."""
     max_pages = table.shape[1]
-    ps = k_pool.shape[2]
+    ps = k_pool.shape[3]
     quantized = k_scale is not None
     B, H, _, Dh = qh.shape
     if kernel:
@@ -1257,20 +1270,14 @@ def _paged_decode_attend(qh, k_pool, v_pool, k_scale, v_scale, table, pos,
         # zeroed for inactive rows so their (NULL-page-routed) writes
         # are never attended.
         limit = jnp.where(active, pos + 1, 0)
-        Hkv = k_pool.shape[1]
+        Hkv = k_pool.shape[2]
         qg = qh.reshape(B, Hkv, H // Hkv, Dh)
         o, _ = _paged_kernel_attend(qg, k_pool, v_pool, k_scale, v_scale,
-                                    table, limit, cfg, mesh, lower)
+                                    layer, table, limit, cfg, mesh, lower)
         o = o.reshape(B, H, 1, Dh)
     else:
-        if quantized:
-            kg = kv_dequantize(_gather_pages(k_pool, table),
-                               _gather_scales(k_scale, table), cfg.dtype)
-            vg = kv_dequantize(_gather_pages(v_pool, table),
-                               _gather_scales(v_scale, table), cfg.dtype)
-        else:
-            kg = _gather_pages(k_pool, table)
-            vg = _gather_pages(v_pool, table)
+        kg, vg = _gather_kv(k_pool, v_pool, k_scale, v_scale, layer, table,
+                            cfg)
         T = max_pages * ps
         col = lax.broadcasted_iota(jnp.int32, (T,), 0)[None, :]
         mask = col <= pos[:, None]
@@ -1280,23 +1287,37 @@ def _paged_decode_attend(qh, k_pool, v_pool, k_scale, v_scale, table, pos,
     return o
 
 
-def _kind_pools(pool: Dict, cfg: TransformerConfig, quantized: bool):
-    """A paged pool's per-layer arrays by layer kind, as
-    :func:`_scan_layer_kinds` takes them: the full layers' ``k``/``v``
-    (with their scales when quantized) and, for a configuration with
-    window layers, those layers' own ``wk``/``wv``."""
-    xs = {"full": (pool["k"], pool["v"])}
-    if quantized:
-        xs["full"] += (pool["k_scale"], pool["v_scale"])
-    if cfg.has_window:
-        if quantized or "wk" not in pool:
-            raise UnsupportedModelConfigError(
-                "window layers keep their own unquantized pool "
-                "('wk'/'wv' beside 'k'/'v')")
-        xs["sliding"] = (pool["wk"], pool["wv"])
-    if not cfg.kind_count("full"):
-        del xs["full"]
-    return xs
+def _gather_kv(k_pool, v_pool, k_scale, v_scale, layer, table,
+               cfg: TransformerConfig):
+    """The unfused attend's logical K/V of layer ``layer``, int8 pages
+    dequantized after the gather."""
+    kg = _gather_pages(k_pool, layer, table)
+    vg = _gather_pages(v_pool, layer, table)
+    if k_scale is not None:
+        kg = kv_dequantize(kg, _gather_scales(k_scale, layer, table),
+                           cfg.dtype)
+        vg = kv_dequantize(vg, _gather_scales(v_scale, layer, table),
+                           cfg.dtype)
+    return kg, vg
+
+
+_POOL_ARRAYS = {"full": ("k", "v", "k_scale", "v_scale"),
+                "sliding": ("wk", "wv")}
+
+
+def _kind_pools(pool: Dict, cfg: TransformerConfig):
+    """The names of a paged pool's stacked arrays by layer kind — the
+    full layers' ``k``/``v`` (with their scales when quantized) and, for
+    a configuration with window layers, those layers' own ``wk``/``wv``
+    — for the kinds this configuration HAS: a uniform model carries no
+    second stack, a model of window layers alone no first."""
+    quantized = "k_scale" in pool
+    if cfg.has_window and (quantized or "wk" not in pool):
+        raise UnsupportedModelConfigError(
+            "window layers keep their own unquantized pool "
+            "('wk'/'wv' beside 'k'/'v')")
+    return {kind: tuple(n for n in names if n in pool)
+            for kind, names in _POOL_ARRAYS.items() if cfg.kind_count(kind)}
 
 
 def moe_load(counts):
@@ -1355,38 +1376,37 @@ def decode_step_paged(params: Dict, tokens_t, pool: Dict, table,
                 "init_page_pool with more pages per slot")
     x = _embed(params, tokens_t, cfg)[:, None]  # (S, 1, D)
     x = jnp.where(active[:, None, None], x, jnp.zeros_like(x))
-    quantized = "k_scale" in pool
     moe = cfg.n_experts > 1
+    names = _kind_pools(pool, cfg)
 
-    def layer(x, p, kind, kv):
-        k_c, v_c, ks_c, vs_c = kv + (() if quantized else (None, None))
-        h, k_new, v_new, ks_new, vs_new = _attention_decode_paged(
-            _attn_norm(x, p, cfg), p, cfg, k_c, v_c, ks_c, vs_c,
+    # The stacked pools are the scan's CARRY, beside x: loop state that
+    # each layer writes in place at its own index.  As xs -> ys the scan
+    # would cut every layer out of the stack and stack it back.
+    def layer(carry, p, kind, i):
+        x, pools = carry
+        h, kv = _attention_decode_paged(
+            _attn_norm(x, p, cfg), p, cfg,
+            tuple(pools[n] for n in names[kind]), i,
             wtable if kind == "sliding" else table, pos, active,
             kernel=kernel, mesh=mesh, kind=kind)
-        out = (k_new, v_new) + ((ks_new, vs_new) if quantized else ())
+        pools = {**pools, **dict(zip(names[kind], kv))}
         if not moe:
-            return _mlp_block(x + h, p, cfg), (out, None)
+            return (_mlp_block(x + h, p, cfg), pools), None
         # only the active rows' k picks are computed: S * k expert rows
         y, counts = _mlp_block(x + h, p, cfg, moe_impl="dropless",
                                token_mask=active, return_counts=True)
-        return y, (out, counts)
+        return (y, pools), counts
 
-    x, ys = _scan_layer_kinds(cfg, layer, x, params["layers"],
-                              _kind_pools(pool, cfg, quantized))
+    (x, pools), ys = _scan_layer_kinds(
+        cfg, layer, (x, {n: pool[n] for ns in names.values() for n in ns}),
+        params["layers"],
+        {kind: jnp.arange(cfg.kind_count(kind), dtype=jnp.int32)
+         for kind in names})
     logits = _lm_head(x, params["ln_f"], params["head"], cfg)
-    out = {"pos": pos + active.astype(jnp.int32)}
-    if "full" in ys:
-        new = ys["full"][0]
-        out["k"], out["v"] = new[0], new[1]
-        if quantized:
-            out["k_scale"], out["v_scale"] = new[2], new[3]
-    if "sliding" in ys:
-        out["wk"], out["wv"] = ys["sliding"][0]
+    out = {**pools, "pos": pos + active.astype(jnp.int32)}
     if not return_moe_load:
         return logits[:, 0], out
-    counts = [ys[k][1] for k in sorted(ys)] if moe else []
-    load = (moe_load(jnp.concatenate(counts)) if counts
+    load = (moe_load(jnp.concatenate([ys[k] for k in sorted(ys)])) if moe
             else jnp.zeros((3,), jnp.int32))
     return logits[:, 0], out, load
 
@@ -1545,11 +1565,14 @@ def decode_verify_paged(params: Dict, window, pool: Dict, table,
     climit = jnp.where(active, pos, 0)
     wmask = win_vis[:, None, None]              # (S, 1, 1, W, W)
 
+    k_c, v_c = pool["k"], pool["v"]
+    ks_c, vs_c = pool.get("k_scale"), pool.get("v_scale")
+
+    # The scan only READS the pool (the window's K/V is written after
+    # it, once acceptance is known): the stacks stay outside the scan's
+    # xs and each layer attends ``[l, table]`` of them.
     def layer(x, inp):
-        if quantized:
-            p, k_c, v_c, ks_c, vs_c = inp
-        else:
-            (p, k_c, v_c), ks_c, vs_c = inp, None, None
+        p, l = inp
         h = _attn_norm(x, p, cfg)
         qh, kh, vh = _qkv_proj(h, p, cfg, positions=positions)
         if quantized:
@@ -1570,7 +1593,7 @@ def decode_verify_paged(params: Dict, window, pool: Dict, table,
                 # the unfused gather reads), int8 dequant in the load.
                 o_c, lse_c = _paged_kernel_attend(
                     qg.reshape(S, Hkv, G * W, Dh), k_c, v_c, ks_c, vs_c,
-                    table, climit, cfg, mesh)
+                    l, table, climit, cfg, mesh)
                 o_c = o_c.reshape(S, Hkv, G, W, Dh)
                 lse_c = lse_c.reshape(S, Hkv, G, W)
                 # Dense causal attention within the window (post round-trip
@@ -1594,14 +1617,7 @@ def decode_verify_paged(params: Dict, window, pool: Dict, table,
                 o = ((a_c[..., None] * o_c + a_w[..., None] * o_w)
                      / (a_c + a_w)[..., None])
             else:
-                if quantized:
-                    kg = kv_dequantize(_gather_pages(k_c, table),
-                                       _gather_scales(ks_c, table), cfg.dtype)
-                    vg = kv_dequantize(_gather_pages(v_c, table),
-                                       _gather_scales(vs_c, table), cfg.dtype)
-                else:
-                    kg = _gather_pages(k_c, table)
-                    vg = _gather_pages(v_c, table)
+                kg, vg = _gather_kv(k_c, v_c, ks_c, vs_c, l, table, cfg)
                 k_full = jnp.concatenate([kg, kh_a], axis=2)  # (S,Hkv,T+W,Dh)
                 v_full = jnp.concatenate([vg, vh_a], axis=2)
                 # Grouped-query attention, W queries wide — _cache_attend's
@@ -1616,10 +1632,8 @@ def decode_verify_paged(params: Dict, window, pool: Dict, table,
         out = _out_proj(o.reshape(S, H, W, Dh).astype(cfg.dtype), p, cfg)
         return _mlp_block(x + out, p, cfg, moe_impl="dense"), ys
 
-    xs = (params["layers"], pool["k"], pool["v"])
-    if quantized:
-        xs = xs + (pool["k_scale"], pool["v_scale"])
-    x, ys = _scan_layers(layer, x, xs)
+    x, ys = _scan_layers(
+        layer, x, (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
     logits = _lm_head(x, params["ln_f"], params["head"], cfg)  # (S,W,V)
     t = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     mx = jnp.max(logits, axis=-1)
@@ -1639,38 +1653,38 @@ def decode_verify_paged(params: Dict, window, pool: Dict, table,
         acc = jnp.where(temp > 0.0, 0, acc)
     acc = jnp.where(active, acc, 0)
 
-    # Accepted-only scatter: window offset j lands at logical position
+    # Accepted-only write: window offset j lands at logical position
     # pos[s] + j through the table iff accepted (j <= acc) and within
     # capacity; everything else — rejected drafts, inactive rows,
-    # out-of-capacity positions — routes to the NULL page (physical 0).
+    # out-of-capacity positions — is written nowhere.  The W positions
+    # of a slot span at most ``C`` pages: they are merged into whole
+    # pages first (a page is written once), and a page that takes none
+    # of them is the NULL page (physical 0).
+    from horovod_tpu.serving.cache import write_pages
+
     with jax.named_scope("kv_write"):
-        j = jnp.arange(W, dtype=jnp.int32)[None, :]
-        wpos = pos[:, None] + j
-        ok = active[:, None] & (j <= acc[:, None]) & (wpos < T_cap)
-        idxp = jnp.clip(wpos // ps, 0, max_pages - 1)
-        phys = jnp.where(ok, jnp.take_along_axis(table, idxp, axis=1), 0)
-        off = wpos % ps
+        C = -(-(W - 1) // ps) + 1
+        first = pos // ps                                   # (S,)
+        tpos = ((first[:, None] + jnp.arange(C, dtype=jnp.int32)) * ps
+                )[:, :, None] + jnp.arange(ps, dtype=jnp.int32)  # (S, C, ps)
+        j = tpos - pos[:, None, None]
+        take = ((j >= 0) & (j <= acc[:, None, None]) & (j < W)
+                & active[:, None, None] & (tpos < T_cap))
+        idxp = jnp.clip(first[:, None] + jnp.arange(C), 0, max_pages - 1)
+        phys = jnp.where(jnp.any(take, axis=-1),
+                         jnp.take_along_axis(table, idxp, axis=1), 0)
+        jc = jnp.clip(j, 0, W - 1).reshape(S, C * ps)
+        layers = jnp.arange(cfg.n_layers, dtype=jnp.int32)[:, None, None]
 
-        def scatter(pool_l, vals_l):
-            # pool_l (P, Hkv, ps, Dh); vals_l (S, Hkv, W, Dh) -> indexed
-            # result dims (S, W) lead, giving (S, W, Hkv, Dh) values.
-            return pool_l.at[phys, :, off, :].set(jnp.moveaxis(vals_l, 2, 1))
+        def write(stack, vals):
+            # vals (L, S, Hkv, W[, Dh]) -> the pages' (L, S, C, Hkv, ps[, Dh])
+            g = vals[:, jnp.arange(S)[:, None], :, jc]  # (S, C*ps, L, Hkv..)
+            g = g.reshape((S, C, ps) + g.shape[2:])
+            g = jnp.moveaxis(g, (3, 0, 1, 4, 2), (0, 1, 2, 3, 4))
+            return write_pages(stack, layers, phys[None], g, take[None])
 
-        def scatter_scale(scale_l, vals_l):
-            return scale_l.at[phys, :, off].set(jnp.moveaxis(vals_l, 2, 1))
-
-        if quantized:
-            qk, sk, qv, sv = ys
-            out = {
-                "k": jax.vmap(scatter)(pool["k"], qk),
-                "v": jax.vmap(scatter)(pool["v"], qv),
-                "k_scale": jax.vmap(scatter_scale)(pool["k_scale"], sk),
-                "v_scale": jax.vmap(scatter_scale)(pool["v_scale"], sv),
-            }
-        else:
-            kh_all, vh_all = ys
-            out = {"k": jax.vmap(scatter)(pool["k"], kh_all),
-                   "v": jax.vmap(scatter)(pool["v"], vh_all)}
+        names = ("k", "k_scale", "v", "v_scale") if quantized else ("k", "v")
+        out = {n: write(pool[n], y) for n, y in zip(names, ys)}
     out["pos"] = pos + jnp.where(active, acc + 1, 0)
     return t, mx, acc, out
 

@@ -21,11 +21,14 @@ program per SLOT, walking only the pages the slot can attend:
   from SMEM): a slot with ``limit == 0`` walks nothing, and table
   entries past the live pages are never read, let alone fetched;
 * a block is :func:`block_pages` pages (a function of the pool's
-  layout alone: 128 tokens for bf16 pages of 16 x 8 heads x 128).  One
-  layer's pool is ``(P, H_kv, page, Dh)``, so ``pool.at[page]`` is ONE
-  contiguous region holding every KV head of the page: the kernel
-  issues one ``make_async_copy`` per live page for K and one for V,
-  into one of two VMEM buffers, and attends the other meanwhile;
+  layout alone: 128 tokens for bf16 pages of 16 x 8 heads x 128).  The
+  pool is the STACK of every layer's, ``(L, P, H_kv, page, Dh)``, and
+  the layer's index one more scalar in SMEM — a custom call's operand
+  is cut out of a layer scan's stack whole, so one layer's pool is
+  never the operand — and ``pool.at[layer, page]`` is ONE contiguous
+  region holding every KV head of the page: the kernel issues one
+  ``make_async_copy`` per live page for K and one for V, into one of
+  two VMEM buffers, and attends the other meanwhile;
 * the block is attended for all KV heads at once (dots batched over
   the head): int8 dequant is fused into the load — the pages' int8
   payload and their per-vector scales are combined in-register (f32
@@ -178,7 +181,8 @@ def _kernel_body(table_ref, limit_ref, *refs, page_size, n_pages,
                  compute_dtype, quantized, windowed):
     """One grid step: slot ``s``, every KV head, the slot's live pages.
 
-    ``k_hbm``/``v_hbm`` (and the scale pools) stay in HBM; the loop
+    ``k_hbm``/``v_hbm`` (every layer's pool, read at ``layer_ref[0]``;
+    and this layer's scale pools) stay in HBM; the loop
     fetches block ``b`` of the slot's table — ``n_pages`` pages, each
     ONE contiguous ``(H_kv, page, Dh)`` transfer — into one of two VMEM
     buffers while the other is attended.  The online softmax rides the
@@ -188,6 +192,7 @@ def _kernel_body(table_ref, limit_ref, *refs, page_size, n_pages,
     lower_ref = None
     if windowed:                  # a third scalar-prefetch operand
         lower_ref, refs = refs[0], refs[1:]
+    layer_ref, refs = refs[0], refs[1:]   # the last scalar-prefetch one
     q_ref, k_hbm, v_hbm = refs[:3]
     if quantized:
         ks_hbm, vs_hbm, o_ref, lse_ref, k_buf, v_buf, ks_buf, vs_buf, \
@@ -195,6 +200,7 @@ def _kernel_body(table_ref, limit_ref, *refs, page_size, n_pages,
     else:
         o_ref, lse_ref, k_buf, v_buf, sems = refs[3:]
     s = pl.program_id(0)
+    layer = layer_ref[0]
     Hkv, R, Dh = q_ref.shape[1:]
     block_tokens = n_pages * page_size
     # A limit past the table's capacity would index the table out of
@@ -231,15 +237,14 @@ def _kernel_body(table_ref, limit_ref, *refs, page_size, n_pages,
             @pl.when(live)
             def _page():
                 # a wait needs the descriptor's shape, not its source
-                page = 0 if wait else table_ref[s, idx]
-                pairs = [(k_hbm, k_buf.at[buf, :, i]),
-                         (v_hbm, v_buf.at[buf, :, i])]
-                if quantized:
-                    pairs += [(ks_hbm, ks_buf.at[buf, i]),
-                              (vs_hbm, vs_buf.at[buf, i])]
-                for pool, dst in pairs:
-                    dma = pltpu.make_async_copy(pool.at[page], dst,
-                                                sems.at[buf])
+                at = (0, 0) if wait else (layer, table_ref[s, idx])
+                pairs = [(k_hbm.at[at], k_buf.at[buf, :, i]),
+                         (v_hbm.at[at], v_buf.at[buf, :, i])]
+                if quantized:         # one layer's scales: (P, H_kv, lanes)
+                    pairs += [(ks_hbm.at[at[1]], ks_buf.at[buf, i]),
+                              (vs_hbm.at[at[1]], vs_buf.at[buf, i])]
+                for src, dst in pairs:
+                    dma = pltpu.make_async_copy(src, dst, sems.at[buf])
                     dma.wait() if wait else dma.start()
 
     def scale_col(sc_buf, buf):
@@ -306,9 +311,9 @@ def _kernel_body(table_ref, limit_ref, *refs, page_size, n_pages,
 
 
 def _pallas_paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table,
-                         limit, compute_dtype, lower=None):
+                         limit, compute_dtype, lower, layer):
     S, Hkv, R, Dh = qg.shape
-    _, _, ps, _ = k_pool.shape
+    _, _, _, ps, _ = k_pool.shape
     quantized = k_scale is not None
     n_pages = block_pages(ps, Hkv, Dh, k_pool.dtype, table.shape[1])
 
@@ -324,10 +329,14 @@ def _pallas_paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table,
     # itself, so the pools never get a BlockSpec's pipeline.
     hbm = pl.BlockSpec(memory_space=pl.ANY)
 
+    # The table stays the call's FIRST operand (readers of a device
+    # trace find the kernel's events by it); the layer's index goes last.
     windowed = lower is not None
     scalars = [table.astype(jnp.int32), limit.astype(jnp.int32)]
     if windowed:
         scalars.append(lower.astype(jnp.int32))
+    layer = jnp.asarray(layer, jnp.int32)
+    scalars.append(layer.reshape(1))
 
     def of_slot(s, *scalars):
         return (s, 0, 0, 0)
@@ -341,10 +350,13 @@ def _pallas_paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table,
     if quantized:
         # Mosaic slices an HBM ref in whole 128-lane rows only, so a
         # page's (H_kv, page) scales travel padded to the lane width.
+        # Only this layer's are padded: 1 / Dh of the layer's payload.
         lanes = -(-ps // 128) * 128
         pad = ((0, 0), (0, 0), (0, lanes - ps))
         in_specs += [hbm, hbm]
-        operands += [jnp.pad(k_scale, pad), jnp.pad(v_scale, pad)]
+        operands += [jnp.pad(jax.lax.dynamic_index_in_dim(
+            sc, layer, 0, keepdims=False), pad)
+            for sc in (k_scale, v_scale)]
         sc_buf = (2, n_pages, Hkv, lanes)
         scratch += [pltpu.VMEM(sc_buf, k_scale.dtype),
                     pltpu.VMEM(sc_buf, v_scale.dtype)]
@@ -429,17 +441,19 @@ def paged_attend_reference(qg, k_pool, v_pool, k_scale, v_scale, table,
 
 
 def paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table, limit, *,
-                 compute_dtype=None, lower=None):
+                 compute_dtype=None, lower=None, layer=None):
     """Fused decode attention directly against a paged KV pool.
 
     Args:
       qg: ``(S, H_kv, R, Dh)`` grouped queries — ``R = G`` (GQA group)
         for a one-token decode tick, ``R = G * W`` for a W-wide VERIFY
         window (rows ``g * W + j``).
-      k_pool / v_pool: ONE layer's pool, ``(P, H_kv, page, Dh)`` in the
-        stored dtype (f32 / bf16 / int8).
-      k_scale / v_scale: ``(P, H_kv, page)`` f32 per-vector scales for
-        int8 pools, else ``None``.
+      k_pool / v_pool: the pool in the stored dtype (f32 / bf16 / int8):
+        every layer's, ``(L, P, H_kv, page, Dh)``, with ``layer``; or
+        ONE layer's, ``(P, H_kv, page, Dh)``, with ``layer=None`` (a
+        stack of one: a reshape, no copy).
+      k_scale / v_scale: f32 per-vector scales for int8 pools, shaped as
+        the pool less its last dim, else ``None``.
       table: ``(S, max_pages)`` int32 physical page ids (host data —
         any allocation pattern, one executable).
       limit: ``(S,)`` int32 — attend logical positions ``< limit[s]``
@@ -452,6 +466,9 @@ def paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table, limit, *,
         attend positions ``lower[s] <= t < limit[s]`` only, and start
         the walk at the block that holds ``lower[s]``.  ``None`` (a
         full layer) compiles the kernel with no such operand.
+      layer: int32 scalar (traced in a layer scan) — which layer of the
+        stacked pool to attend.  The kernel reads ``pool[layer, page]``
+        in place; the caller never slices the layer out.
 
     Returns:
       ``(o, lse)``: ``o`` ``(S, H_kv, R, Dh)`` f32 attention output
@@ -461,5 +478,10 @@ def paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table, limit, *,
     """
     if compute_dtype is None:
         compute_dtype = k_pool.dtype
+    if layer is None:
+        layer = 0
+        k_pool, v_pool = k_pool[None], v_pool[None]
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[None], v_scale[None]
     return _pallas_paged_attend(qg, k_pool, v_pool, k_scale, v_scale,
-                                table, limit, compute_dtype, lower)
+                                table, limit, compute_dtype, lower, layer)

@@ -241,38 +241,90 @@ def init_page_pool(cfg: "T.TransformerConfig", n_slots: int, n_pages: int,
     return pool
 
 
+def write_pages(stack, layer, phys, new, take):
+    """THE write into a page pool: whole pages, addressed by the pool's
+    two leading dims and nothing else.
+
+    ``stack`` is one pool array, ``(L, P, H_kv, page, ...)`` (payload
+    with its trailing ``Dh``, or an int8 pool's scales without one);
+    ``layer`` and ``phys`` are int32 arrays that broadcast to one batch
+    shape ``B``; ``new`` broadcasts to ``B + (H_kv, page, ...)`` and
+    ``take`` ``B + (page,)`` says which offsets of each page take it.
+    The ``B`` target pages are read, the taken offsets replaced, and the
+    pages written back at ``[layer, phys]``: the scatter's indices are
+    the leading dims and its window the whole page, which is the pool's
+    own layout, so the compiler updates a donated (or loop-carried) pool
+    in place and no operation has a result the size of a layer of it.
+    What is not taken keeps its contents — the positions before a
+    suffix's ``start``, a page's tail.
+
+    A page may appear ONCE among the targets: of two whole-page updates
+    of one page the later would undo the earlier, so callers merge the
+    rows that share a page first.  The NULL page alone is exempt:
+    inactive rows, padding and rejected drafts all go there, and what
+    it holds is never attended."""
+    idx = (jnp.asarray(layer, jnp.int32), jnp.asarray(phys, jnp.int32))
+    take = take.reshape(take.shape[:-1] + (1, take.shape[-1])
+                        + (1,) * (stack.ndim - 4))
+    pages = jnp.where(take, new.astype(stack.dtype), stack[idx])
+    return stack.at[idx].set(pages)
+
+
+def _bucket_pages(x, first, n_pg: int, ps: int):
+    """A prefilled block ``(L, K, H_kv, Tb, ...)`` as the pages it lands
+    in, ``(L, K, n_pg, H_kv, page, ...)``: column ``t`` sits at offset
+    ``(first + t) % page`` of page ``(first + t) // page`` — ``first``
+    (traced) is where column 0 falls in its page, 0 unless a suffix
+    starts mid-page.  Offsets no column reaches hold padding."""
+    tb = x.shape[3]
+    pad = [(0, 0)] * x.ndim
+    pad[3] = (ps, n_pg * ps - tb)
+    x = lax.dynamic_slice_in_dim(jnp.pad(x, pad), ps - first, n_pg * ps, 3)
+    x = x.reshape(x.shape[:3] + (n_pg, ps) + x.shape[4:])
+    return jnp.moveaxis(x, 3, 2)
+
+
+def landing_pages(bucket: int, page_size: int) -> int:
+    """Pages a landed block of ``bucket`` columns can touch in one row,
+    wherever in a page its first column falls."""
+    return -(-(bucket + page_size - 1) // page_size)
+
+
 @jax.named_scope("kv_land")  # T.DEVICE_SCOPES
-def paged_insert(pool: Dict, slots, new_pos, phys, off,
+def paged_insert(pool: Dict, slots, new_pos, pages, first, lens,
                  prefilled_k, prefilled_v) -> Dict:
-    """Land a prefilled K/V block into pages: position ``t`` of row
-    ``i`` scatters to ``(page phys[i, t], offset off[i, t])`` — the
-    index arrays are host-built DATA, so one executable per
-    ``(K, bucket)`` shape serves every page assignment, every bucket
-    alignment (suffix landings start mid-page after a COW), and junk
-    routing (padding positions point at the NULL page).  ``slots`` /
+    """Land a prefilled K/V block ``(L, K, H_kv, Tb, Dh)`` into pages.
+    Column ``t`` of row ``i`` is logical position ``start + t``; with
+    ``first = start % page`` it goes to offset ``(first + t) % page``
+    of ``pages[i, (first + t) // page]`` if ``t < lens[i]``, and
+    nowhere otherwise (bucket padding).  ``pages`` ``(K,
+    landing_pages(Tb, page))``, ``first`` and ``lens`` are host-built
+    DATA, so one executable per ``(K, bucket)`` shape serves every page
+    assignment and every bucket alignment (suffix landings start
+    mid-page after a COW: the positions before ``start`` stay), and a
+    page that takes no column is the NULL page.  ``slots`` /
     ``new_pos`` adopt the per-row positions (empty for slotless
     landings — prefix registration).  int8 pools quantize per vector
-    on the way in, writing payload and scale in the same scatter."""
-    k, v = prefilled_k, prefilled_v  # (L, K, H_kv, Tb, Dh)
-    quant = "k_scale" in pool
+    on the way in; payload and scale go through the same
+    :func:`write_pages`."""
+    ps = pool["k"].shape[3]
+    L, n_pg = pool["k"].shape[0], pages.shape[1]
+    first = jnp.asarray(first, jnp.int32)
+    col = (jnp.arange(n_pg * ps, dtype=jnp.int32) - first).reshape(n_pg, ps)
+    take = (col >= 0) & (col < jnp.asarray(lens, jnp.int32)[:, None, None])
+    layer = jnp.arange(L, dtype=jnp.int32)[:, None, None]
+
+    def land(name, x):
+        return write_pages(pool[name], layer, pages[None],
+                           _bucket_pages(x, first, n_pg, ps), take[None])
+
     out = dict(pool)
-    if quant:
-        qk, sk = T.kv_quantize(k)
-        qv, sv = T.kv_quantize(v)
-        out["k"] = pool["k"].at[:, phys, :, off, :].set(
-            jnp.transpose(qk, (1, 3, 0, 2, 4)))
-        out["v"] = pool["v"].at[:, phys, :, off, :].set(
-            jnp.transpose(qv, (1, 3, 0, 2, 4)))
-        out["k_scale"] = pool["k_scale"].at[:, phys, :, off].set(
-            jnp.transpose(sk, (1, 3, 0, 2)))
-        out["v_scale"] = pool["v_scale"].at[:, phys, :, off].set(
-            jnp.transpose(sv, (1, 3, 0, 2)))
-    else:
-        dt = pool["k"].dtype
-        out["k"] = pool["k"].at[:, phys, :, off, :].set(
-            jnp.transpose(k.astype(dt), (1, 3, 0, 2, 4)))
-        out["v"] = pool["v"].at[:, phys, :, off, :].set(
-            jnp.transpose(v.astype(dt), (1, 3, 0, 2, 4)))
+    k, v = prefilled_k, prefilled_v
+    if "k_scale" in pool:
+        k, sk = T.kv_quantize(k)
+        v, sv = T.kv_quantize(v)
+        out["k_scale"], out["v_scale"] = land("k_scale", sk), land("v_scale", sv)
+    out["k"], out["v"] = land("k", k), land("v", v)
     out["pos"] = pool["pos"].at[slots].set(new_pos)
     return out
 
@@ -285,7 +337,10 @@ def copy_page(pool: Dict, src, dst) -> Dict:
     out = dict(pool)
     for name in ("k", "v", "k_scale", "v_scale"):
         if name in pool:
-            out[name] = pool[name].at[:, dst].set(pool[name][:, src])
+            a = pool[name]
+            layer = jnp.arange(a.shape[0], dtype=jnp.int32)
+            out[name] = write_pages(a, layer, dst, a[layer, src],
+                                    jnp.ones((1, a.shape[3]), bool))
     return out
 
 
@@ -611,54 +666,57 @@ class PagedSlotCache:
 
     # -- device ops ---------------------------------------------------------
 
-    def _phys_off(self, rows: Sequence[Sequence[int]], start: int,
-                  true_lens, bucket: int):
-        """Host-built landing indices: row ``i``'s position ``start +
-        t`` maps to its page table unless past ``true_lens[i]`` (bucket
-        padding), which routes to the NULL page."""
+    def _land_pages(self, rows: Sequence[Sequence[int]], start: int,
+                    true_lens, bucket: int) -> np.ndarray:
+        """Host-built landing targets: for each row, the physical pages
+        its ``bucket`` columns from logical position ``start`` fall in,
+        in order — ``landing_pages`` of them, whatever ``start %
+        page_size`` is, so the executable's shape depends on the bucket
+        alone.  A page that takes no column (past ``true_lens[i]``:
+        bucket padding; past the table) is the NULL page."""
         ps = self.page_size
-        logical = start + np.arange(bucket)
-        idxs = np.clip(logical // ps, 0, self.max_pages - 1)
-        phys = np.zeros((len(rows), bucket), np.int32)
+        c = np.arange(landing_pages(bucket, ps))
+        idx = start // ps + c
+        pages = np.zeros((len(rows), c.size), np.int32)
         for i, row in enumerate(rows):
-            p = np.asarray(row, np.int32)[idxs]
-            phys[i] = np.where(logical < start + int(true_lens[i]), p,
-                               NULL_PAGE)
-        return phys, np.asarray(logical % ps, np.int32)
+            row = np.asarray(row, np.int32)
+            live = (c * ps - start % ps < int(true_lens[i])) & (
+                idx < row.size)
+            pages[i, live] = row[idx[live]]
+        return pages
+
+    def _land(self, slots, new_pos, rows, prefilled: Dict, true_lens,
+              start: int) -> None:
+        bucket = prefilled["k"].shape[3]
+        self.cache = self._insert(
+            self.cache, np.asarray(slots, np.int32), new_pos,
+            self._land_pages(rows, start, true_lens, bucket),
+            np.int32(start % self.page_size),
+            np.asarray(true_lens, np.int32), prefilled["k"],
+            prefilled["v"])
 
     def land(self, slots: Sequence[int], prefilled: Dict,
              true_lens, start: int = 0) -> None:
         """Land a prefilled (or suffix-prefilled) K/V block into the
-        slots' granted pages with ONE scatter, and adopt the per-row
-        positions from ``prefilled["pos"]``.  ``start`` is the logical
-        position of bucket column 0 (0 for full prompts, the shared
-        prefix length for suffix landings)."""
+        slots' granted pages with ONE page-granular write
+        (:func:`paged_insert`), and adopt the per-row positions from
+        ``prefilled["pos"]``.  ``start`` is the logical position of
+        bucket column 0 (0 for full prompts, the shared prefix length
+        for suffix landings)."""
         for s in slots:
             if not self._active[s]:
                 raise ValueError(f"slot {s} is not allocated")
-        bucket = prefilled["k"].shape[3]
-        phys, off = self._phys_off([self.table[s] for s in slots], start,
-                                   true_lens, bucket)
-        self.cache = self._insert(
-            self.cache, np.asarray(slots, np.int32),
-            prefilled["pos"].astype(jnp.int32), phys,
-            np.broadcast_to(off, phys.shape), prefilled["k"],
-            prefilled["v"])
+        self._land(slots, prefilled["pos"].astype(jnp.int32),
+                   [self.table[s] for s in slots], prefilled, true_lens,
+                   start)
 
     def land_raw(self, pages: Sequence[int], prefilled: Dict,
                  true_len: int) -> None:
         """Slotless landing into raw pages (prefix registration): the
         prefix block fills ``pages`` in order; no slot position is
         touched."""
-        bucket = prefilled["k"].shape[3]
-        row = list(pages) + [NULL_PAGE] * max(
-            0, self.max_pages - len(pages))
-        phys, off = self._phys_off([row], 0, [true_len], bucket)
-        empty = np.zeros((0,), np.int32)
-        self.cache = self._insert(
-            self.cache, empty, jnp.zeros((0,), jnp.int32), phys,
-            np.broadcast_to(off, phys.shape), prefilled["k"],
-            prefilled["v"])
+        self._land((), jnp.zeros((0,), jnp.int32), [pages], prefilled,
+                   [true_len], 0)
 
     def set_pos(self, slots: Sequence[int], vals: Sequence[int]) -> None:
         """Adopt positions without landing (attach-only admission — the

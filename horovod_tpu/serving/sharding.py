@@ -182,7 +182,8 @@ class ServingSharding:
         """NamedShardings for the fused paged-attention kernel's
         operands/results (:func:`~horovod_tpu.models.transformer.
         paged_kernel_specs` order: ``(q, k_pool, v_pool[, k_scale,
-        v_scale], table, limit)`` / ``(o, lse)``).  The kernel runs
+        v_scale], table, limit, layer)`` / ``(o, lse)``, the pools the
+        stacked ``(L, P, H_kv, page, Dh)``).  The kernel runs
         per-(slot, kv-head) with no cross-head traffic, so the
         head-dim-sharded pool passes straight through: the tick's
         ``shard_map`` uses the raw specs, and these placements exist so

@@ -35,6 +35,8 @@ from horovod_tpu.compile_cache import place_compile_cache  # noqa: E402
 place_compile_cache()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 
@@ -174,3 +176,175 @@ def parse_prometheus_text(text):
             assert buckets[-1][0] == float("inf"), (fam, key)
             assert buckets[-1][1] == count.get(key), (fam, key)
     return families
+
+
+# --- the paged KV pool's write discipline (serving/cache.py write_pages) ------
+#
+# Shared by tests/test_paged.py, test_window_layers.py, test_speculative.py
+# and test_tp_serving.py: what a jaxpr must not do to a pool (structure), and
+# a plain numpy writer of (page, offset) to hold a pool's bytes to (contents).
+
+
+def _sub_jaxprs(eqn):
+    """The jaxprs an equation carries (scan / while / cond / pjit /
+    closed_call / shard_map bodies), a Pallas kernel's own body apart:
+    it addresses the pool by reference, and is tested where it is."""
+    if eqn.primitive.name == "pallas_call":
+        return
+    for v in eqn.params.values():
+        for j in (v if isinstance(v, (list, tuple)) else (v,)):
+            j = getattr(j, "jaxpr", j)
+            if hasattr(j, "eqns"):
+                yield j
+
+
+def pool_structure_faults(jaxpr, pool_shapes):
+    """What a traced program does to a paged pool that the write
+    discipline forbids, as a list of sentences (empty: sound).
+    ``pool_shapes`` are the stacked pool arrays' shapes, ``(L, P, H_kv,
+    page[, Dh])``.
+
+    * no ``scan`` takes an array of a pool's shape among its ``xs`` or
+      yields one among its ``ys`` (the pool is the loop's carry);
+    * no ``dynamic_slice`` / ``gather`` / ``squeeze`` yields a whole
+      layer of a pool (``(P, H_kv, page[, Dh])``, unit dims aside);
+    * every ``scatter`` INTO an array of a pool's shape indexes leading
+      dims only: the scattered dims are ``0..k-1``, the window every
+      dim behind them."""
+    pools = {tuple(s) for s in pool_shapes}
+    layers = {tuple(d for d in s[1:] if d != 1) for s in pools}
+    faults = []
+
+    def walk(j):
+        for eqn in j.eqns:
+            name = eqn.primitive.name
+            if name == "scan":
+                nc, nk = eqn.params["num_consts"], eqn.params["num_carry"]
+                for kind, vs in (("xs", eqn.invars[nc + nk:]),
+                                 ("ys", eqn.outvars[nk:])):
+                    faults.extend(
+                        f"a scan has a pool {v.aval.shape} among its {kind}"
+                        for v in vs if tuple(v.aval.shape) in pools)
+            elif name in ("dynamic_slice", "gather", "squeeze"):
+                shape = tuple(d for d in eqn.outvars[0].aval.shape if d != 1)
+                if shape in layers:
+                    faults.append(f"{name} yields a whole layer of a pool "
+                                  f"{eqn.outvars[0].aval.shape}")
+            elif name.startswith("scatter") and tuple(
+                    eqn.invars[0].aval.shape) in pools:
+                dn = eqn.params["dimension_numbers"]
+                lead = tuple(range(len(dn.scatter_dims_to_operand_dims)))
+                if (tuple(dn.scatter_dims_to_operand_dims) != lead
+                        or tuple(dn.inserted_window_dims) != lead):
+                    faults.append(
+                        f"a scatter into a pool {eqn.invars[0].aval.shape} "
+                        f"indexes dims {dn.scatter_dims_to_operand_dims} "
+                        f"(inserted {dn.inserted_window_dims})")
+            for sub in _sub_jaxprs(eqn):
+                walk(sub)
+
+    walk(getattr(jaxpr, "jaxpr", jaxpr))
+    return faults
+
+
+class KVSpy:
+    """Records the K/V a decode or verify body computes, layer by layer,
+    without touching what it does with them: ``transformer._qkv_proj``
+    is wrapped (``monkeypatch``) to ship its results to the host
+    (``jax.debug.callback``) before returning them.  ``take()`` hands
+    back and forgets the calls so far, in layer order, each ``(kind,
+    positions, k, v)`` with ``k``/``v`` ``(S, H_kv, W, Dh)`` float
+    arrays — the values the program then stores, bit for bit.
+
+    The calls arrive in program order; a program over several devices
+    cannot promise that (``ordered=False``), so there the layers are
+    told apart by their first ``ln1`` weight, which the test makes rise
+    with the layer."""
+
+    def __init__(self, monkeypatch, ordered=True):
+        from horovod_tpu.models import transformer as T
+
+        self.calls = []
+        self.ordered = ordered
+        real = T._qkv_proj
+
+        def spied(x, p, cfg, pos_offset=0, positions=None, kind="full"):
+            q, k, v = real(x, p, cfg, pos_offset=pos_offset,
+                           positions=positions, kind=kind)
+            jax.debug.callback(
+                lambda tag, pos, k_, v_: self.calls.append(
+                    (float(tag), kind, np.asarray(pos), np.asarray(k_),
+                     np.asarray(v_))),
+                p["ln1"][0], positions, k, v, ordered=ordered)
+            return q, k, v
+
+        monkeypatch.setattr(T, "_qkv_proj", spied)
+
+    def take(self):
+        jax.effects_barrier()
+        calls, self.calls = self.calls, []
+        if not self.ordered:
+            calls.sort(key=lambda c: c[0])
+        return [c[1:] for c in calls]
+
+
+class PoolMirror:
+    """A plain numpy writer of ``(page, offset)``: the bytes a paged
+    pool must hold after a sequence of landings and ticks, computed
+    position by position from the host's page tables.  ``names`` maps a
+    layer kind to the ``(k, v)`` array names of its pool (``("k",
+    "v")``, a window kind's ``("wk", "wv")``); int8 pools' scales ride
+    beside (``k_scale``, ``v_scale``), from
+    ``transformer.kv_quantize``."""
+
+    def __init__(self, pool, page_size):
+        self.a = {n: np.array(a) for n, a in pool.items() if n != "pos"}
+        self.ps = page_size
+
+    def _stored(self, name, x):
+        """``x`` ``(..., Dh)`` float as the pool stores it: ``[(array
+        name, values)]`` — the payload cast, or int8 with its scales
+        (quantized under ``jit``, as the program's are)."""
+        from horovod_tpu.models import transformer as T
+
+        scale = f"{name[-1]}_scale"
+        if scale in self.a:
+            q, s = jax.jit(T.kv_quantize)(jnp.asarray(x))
+            return [(name, np.asarray(q)), (scale, np.asarray(s))]
+        return [(name, np.asarray(jnp.asarray(x).astype(
+            self.a[name].dtype)))]
+
+    def land(self, names, rows, start, true_lens, k, v):
+        """A landed block ``(L, K, H_kv, Tb, Dh)``: column ``t`` of row
+        ``i`` is logical position ``start + t`` of table row
+        ``rows[i]``, written iff ``t < true_lens[i]``."""
+        for name, x in zip(names, (k, v)):
+            for arr, val in self._stored(name, x):
+                for i, row in enumerate(rows):
+                    for t in range(int(true_lens[i])):
+                        page, off = divmod(start + t, self.ps)
+                        # a page released behind a window: nowhere
+                        if page < len(row) and row[page] != 0:
+                            self.a[arr][:, row[page], :, off] = val[:, i, :, t]
+
+    def write(self, names, layer, table, pos, ok, k, v):
+        """One layer's K/V ``(S, H_kv, W, Dh)`` of a tick (``W = 1``)
+        or a verify: offset ``j`` of slot ``s`` is logical position
+        ``pos[s] + j``, written iff ``ok[s, j]``."""
+        for name, x in zip(names, (k, v)):
+            for arr, val in self._stored(name, x):
+                for s, j in zip(*np.nonzero(ok)):
+                    page, off = divmod(int(pos[s]) + j, self.ps)
+                    if page < table.shape[1]:
+                        self.a[arr][layer, table[s, page], :, off] = (
+                            val[s, :, j])
+
+    def assert_holds(self, pool):
+        """Every array byte for byte, the NULL page (page 0) excepted."""
+        for n, want in self.a.items():
+            got = np.asarray(pool[n])
+            bad = np.argwhere(got[:, 1:] != want[:, 1:])
+            assert bad.size == 0, (
+                f"pool[{n!r}] differs from the (page, offset) writer at "
+                f"{len(bad)} places, first (layer, page-1, ...) = "
+                f"{bad[0].tolist()}")

@@ -48,6 +48,7 @@ def test_smoke_phases_toy_size_on_cpu():
         vocab_size=64, d_model=64, n_heads=4, n_kv_heads=2, n_layers=2,
         d_ff=128, seq=64, dtype="float32", batch_per_chip=1,
         train_steps=3, learning_rate=1e-2, n_slots=2, serve_max_len=48,
+        serve_n_pages=6,
         prompt_lens=(3, 17), max_new_tokens=4, logit_tol=1e-3,
         expect_compiled=False)
     report = chip_smoke.run(smoke, tp=2)
@@ -247,3 +248,49 @@ class TestChipEnv:
             launch.spawn_ranks(
                 ["true"], allocate([HostSpec("localhost", 0)] * 8), {},
                 "127.0.0.1", 1, _executor=fake_exec)
+
+
+_HLO = """HloModule jit__tick, is_scheduled=true
+
+%fused_computation.5 (param_0.1: bf16[2,65,4,16,128], param_1.2: s32[4,2]) -> bf16[2,65,4,16,128] {
+  %param_0.1 = bf16[2,65,4,16,128]{4,3,2,1,0:T(8,128)(2,1)} parameter(0)
+  ROOT %scatter.1 = bf16[2,65,4,16,128]{4,3,2,1,0:T(8,128)(2,1)} scatter(%param_0.1, %param_1.2), to_apply=%region
+}
+
+%body (arg: (s32[], bf16[2,65,4,16,128], /*index=2*/f32[4,8])) -> (s32[], bf16[2,65,4,16,128], f32[4,8]) {
+  %gte = bf16[2,65,4,16,128]{4,3,2,1,0:T(8,128)(2,1)} get-tuple-element(%arg), index=1
+  %fusion.9 = bf16[2,65,4,16,128]{4,3,2,1,0:T(8,128)(2,1)} fusion(%gte, %idx), kind=kCustom, calls=%fused_computation.5, metadata={op_name="jit(_tick)/kv_write/scatter"}, backend_config={"aliasing_operands":{"lists":[{"indices":["0","2"]}]}}
+  %copy.110 = bf16[65,4,16,128]{3,1,2,0:T(8,128)(2,1)} copy(%slice), metadata={op_name="jit(_tick)/layer_scan/while/body/kv_write/scatter"}
+  %small = f32[4,8]{1,0:T(4,128)} fusion(%x), kind=kLoop, calls=%fused_computation.6, backend_config={"aliasing_operands":{"lists":[]}}
+  ROOT %tuple.1 = (s32[], bf16[2,65,4,16,128]{4,3,2,1,0:T(8,128)(2,1)}, f32[4,8]{1,0}) tuple(%i, %fusion.9, %small)
+}
+
+ENTRY %main (p: bf16[2,65,4,16,128]) -> bf16[2,65,4,16,128] {
+  %p = bf16[2,65,4,16,128]{4,3,2,1,0:T(8,128)(2,1)} parameter(0)
+  %copy.133 = bf16[2,65,4,16,128]{4,3,2,1,0:T(8,128)(2,1)} copy(%p)
+  %while.1 = (s32[], bf16[2,65,4,16,128]{4,3,2,1,0:T(8,128)(2,1)}, f32[4,8]{1,0}) while(%t), condition=%cond, body=%body
+  ROOT %out = bf16[2,65,4,16,128]{4,3,2,1,0:T(8,128)(2,1)} get-tuple-element(%while.1), index=1
+}
+"""
+
+
+def test_pool_sized_results_reads_a_compiled_program():
+    """The compiled-program check of the served tick and landing: the
+    pool passing through (parameter, while, tuple, get-tuple-element)
+    and a write the compiler aliased to its operand are the pool's own;
+    a copy of the pool, or of one layer in another layout, is an
+    offender; what sits inside a fused computation has no buffer."""
+    layer = 65 * 4 * 16 * 128
+    offenders, largest = chip_smoke.pool_sized_results(_HLO, layer)
+    assert [(o[1], o[2]) for o in offenders] == [
+        ("copy", "copy.133"), ("copy", "copy.110")]
+    assert offenders[1][3].endswith("kv_write/scatter")
+    assert [x[2] for x in largest] == ["fusion.9", "copy.133", "copy.110",
+                                       "small"]
+    smoke = chip_smoke.SmokeConfig()
+    with pytest.raises(chip_smoke.SmokeFailure, match="size of a layer"):
+        chip_smoke._require_pool_in_place(smoke, _HLO, layer, "probe")
+    chip_smoke._require_pool_in_place(
+        smoke, _HLO.replace("copy(%p)", "bitcast(%p)").replace(
+            "%copy.110 = bf16[65,4,16,128]", "%copy.110 = bf16[1,4,16,128]"),
+        layer, "probe")

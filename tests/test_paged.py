@@ -853,7 +853,8 @@ class TestFusedPagedKernel:
                                              compute_dtype=cfg.dtype)
         if poison:
             pool = self._poisoned(pool, table, case["limits"], ps)
-        o_k, l_k = PA._pallas_paged_attend(qg, *pool, tab, limit, cfg.dtype)
+        o_k, l_k = PA.paged_attend(qg, *pool, tab, limit,
+                                    compute_dtype=cfg.dtype)
         tol = 2e-2 if kv == "bf16" else 1e-4
         np.testing.assert_allclose(np.asarray(o_k), np.asarray(o_r),
                                    atol=tol, rtol=tol)
@@ -1036,6 +1037,231 @@ class TestFusedPagedKernel:
             assert engine.stats()["paged_kernel_engaged"] is True
         finally:
             engine.stop()
+
+
+def _junk_pool(pc, rng):
+    """Fill a cache's pool arrays with junk: what a sequence does not
+    write must still be there at its end."""
+    def junk(a):
+        if a.dtype == jnp.int8:
+            return jnp.asarray(rng.integers(-127, 128, a.shape), jnp.int8)
+        return jnp.asarray(rng.standard_normal(a.shape), a.dtype)
+    pc.cache = {n: a if n == "pos" else junk(a)
+                for n, a in pc.cache.items()}
+
+
+def _kv_block(rng, cfg, K, bucket, pos, n_layers=None):
+    shape = (n_layers or cfg.n_layers, K, cfg.kv_heads, bucket,
+             cfg.head_dim)
+    return {"k": jnp.asarray(rng.standard_normal(shape), jnp.float32),
+            "v": jnp.asarray(rng.standard_normal(shape), jnp.float32),
+            "pos": jnp.asarray(pos, jnp.int32)}
+
+
+class TestPoolWrittenInPlace:
+    """The pool's write discipline (``serving.cache.write_pages``): the
+    pool is loop state, every write into it is whole pages addressed by
+    the leading ``(layer, page)`` dims, and what lands where is what a
+    position-by-position writer would have put there."""
+
+    S, PS, PAGES, MAX_LEN = 4, 4, 22, 32
+
+    def _pool(self, cfg, kv):
+        return serving.init_page_pool(cfg, self.S, self.PAGES + 1, self.PS,
+                                      kv)
+
+    @pytest.mark.parametrize("what,kv,kernel", [
+        ("tick", None, True), ("tick", None, False),
+        ("tick", "bf16", True), ("tick", "int8", False),
+        ("verify", None, True), ("verify", "int8", False),
+        ("draft", None, True), ("landing", None, None),
+        ("landing", "int8", None), ("cow", "int8", None)])
+    def test_no_operation_the_size_of_a_layer_of_the_pool(self, model, what,
+                                                           kv, kernel):
+        """From the jaxpr: the pool is among no scan's xs or ys, no
+        slice, gather or squeeze yields a layer of it, and every scatter
+        into it indexes ``(layer, page)`` alone."""
+        from conftest import pool_structure_faults
+
+        params, cfg = model
+        pool = self._pool(cfg, kv)
+        table = jnp.zeros((self.S, self.MAX_LEN // self.PS), jnp.int32)
+        active = jnp.ones((self.S,), bool)
+        tok = jnp.zeros((self.S,), jnp.int32)
+        if what == "tick":
+            jaxpr = jax.make_jaxpr(lambda pl: T.decode_step_paged(
+                params, tok, pl, table, cfg, active, kernel=kernel))(pool)
+        elif what == "draft":
+            jaxpr = jax.make_jaxpr(lambda pl: T.draft_propose_paged(
+                params, tok, pl, table, cfg, active, 3, kernel=kernel))(pool)
+        elif what == "cow":
+            jaxpr = jax.make_jaxpr(serving.cache.copy_page)(
+                pool, jnp.int32(3), jnp.int32(5))
+        elif what == "verify":
+            jaxpr = jax.make_jaxpr(lambda pl: T.decode_verify_paged(
+                params, jnp.zeros((self.S, 4), jnp.int32), pl, table, cfg,
+                active, kernel=kernel))(pool)
+        else:
+            blk = _kv_block(np.random.default_rng(0), cfg, 2, 8, [5, 8])
+            jaxpr = jax.make_jaxpr(
+                lambda pl, pages, first, lens: serving.cache.paged_insert(
+                    pl, jnp.asarray([0, 1]), blk["pos"], pages, first, lens,
+                    blk["k"], blk["v"]))(
+                pool, jnp.zeros((2, serving.cache.landing_pages(8, self.PS)),
+                                jnp.int32), jnp.int32(0),
+                jnp.asarray([5, 8], jnp.int32))
+        shapes = [a.shape for n, a in pool.items() if n != "pos"]
+        assert pool_structure_faults(jaxpr, shapes) == []
+
+    def test_the_structure_check_finds_the_old_forms(self, model):
+        """The check is not vacuous: a pool scanned as xs -> ys and
+        written at ``[page, :, offset]`` is found on all three counts."""
+        from conftest import pool_structure_faults
+
+        _, cfg = model
+        pool = self._pool(cfg, None)["k"]
+        phys = jnp.arange(self.S)
+
+        def old(pool):
+            def layer(c, pool_l):
+                return c, pool_l.at[phys, :, phys % self.PS, :].set(1.0)
+            return jax.lax.scan(layer, 0, pool)[1]
+
+        faults = " ".join(pool_structure_faults(jax.make_jaxpr(old)(pool),
+                                                [pool.shape]))
+        assert "among its xs" in faults and "among its ys" in faults
+        # under a scan the old write is a scatter into ONE layer:
+        assert pool_structure_faults(
+            jax.make_jaxpr(lambda p: p.at[:, phys, :, phys % self.PS].set(
+                1.0))(pool), [pool.shape])
+        assert any("whole layer" in f for f in pool_structure_faults(
+            jax.make_jaxpr(lambda p, l: jax.lax.dynamic_index_in_dim(
+                p, l, 0, keepdims=False))(pool, 1), [pool.shape]))
+
+    @pytest.mark.parametrize("kv", [None, "bf16", "int8"])
+    @pytest.mark.parametrize("kernel", [False, True],
+                             ids=["unfused", "kernel"])
+    def test_pool_bytes_match_a_page_offset_writer(self, model, monkeypatch,
+                                                   kv, kernel):
+        """A slotless prefix registration, a suffix that starts mid-page
+        after a COW, two rows of one landing with bucket padding, a
+        second chunk, then ticks with an idle slot among the active ones
+        and slots crossing page boundaries: every pool array, byte for
+        byte, the NULL page excepted."""
+        from conftest import KVSpy, PoolMirror
+
+        params, cfg = model
+        ps, names = self.PS, ("k", "v")
+        rng = np.random.default_rng(7)
+        spy = KVSpy(monkeypatch)
+        pc = serving.PagedSlotCache(cfg, self.S, max_len=self.MAX_LEN,
+                                    page_size=ps, n_pages=self.PAGES,
+                                    kv_dtype=kv)
+        _junk_pool(pc, rng)
+        mirror = PoolMirror(pc.cache, ps)
+
+        def land(slots, lens, start, bucket):
+            for s, n in zip(slots, lens):
+                for idx in range(start // ps, -(-(start + n) // ps)):
+                    if pc.table[s, idx] == NULL_PAGE:
+                        pc.grant(s, idx)
+            blk = _kv_block(rng, cfg, len(slots), bucket,
+                            [start + n for n in lens])
+            pc.land(slots, blk, lens, start=start)
+            mirror.land(names, [pc.table[s].copy() for s in slots], start,
+                        lens, blk["k"], blk["v"])
+
+        # a prefix of 6 tokens registered with no slot, in a bucket of 8
+        pin = pc.grant_raw(2)
+        blk = _kv_block(rng, cfg, 1, 8, [6])
+        pc.land_raw(pin, blk, 6)
+        mirror.land(names, [pin], 0, [6], blk["k"], blk["v"])
+        # slot a shares it, splits its half-filled page, lands 5 more
+        a = pc.alloc()
+        pc.attach(a, pin)
+        split = pc.cow(a, 1)
+        assert split != pin[1]
+        for arr in mirror.a.values():
+            arr[:, split] = arr[:, pin[1]]
+        land([a], [5], 6, 8)
+        # two rows in one landing: 5 of 8 columns and 8 of 8
+        b, c = pc.alloc(), pc.alloc()
+        land([b, c], [5, 8], 0, 8)
+        land([c], [3], 8, 4)            # c's second chunk
+        assert pc.positions().tolist() == [11, 5, 11, 0]
+
+        tick = jax.jit(lambda tok, pool, table, active: T.decode_step_paged(
+            params, tok, pool, table, cfg, active, kernel=kernel)[1])
+        for step in range(5):
+            pos, active = pc.positions(), pc.active_mask()
+            active[c] &= step != 1       # idle for one tick, among active
+            for s in np.nonzero(active)[0]:
+                if pc.table[s, pos[s] // ps] == NULL_PAGE:
+                    pc.grant(s, pos[s] // ps)
+            table = pc.table.copy()
+            pc.cache = tick(jnp.asarray(rng.integers(0, 64, self.S),
+                                        jnp.int32),
+                            pc.cache, jnp.asarray(table), jnp.asarray(active))
+            calls = spy.take()
+            assert len(calls) == cfg.n_layers
+            for l, (_, at, k, v) in enumerate(calls):
+                assert at[:, 0].tolist() == pos.tolist()
+                mirror.write(names, l, table, pos, active[:, None], k, v)
+        assert pc.positions().tolist() == [16, 10, 15, 0]  # b crossed 8
+        mirror.assert_holds(pc.cache)
+
+    @pytest.mark.parametrize("kv", [None, "int8"])
+    @pytest.mark.parametrize("kernel", [False, True],
+                             ids=["unfused", "kernel"])
+    def test_verify_writes_the_accepted_positions_alone(self, model,
+                                                        monkeypatch, kv,
+                                                        kernel):
+        """A W-wide verify whose slots accept 0..W-1 drafts from
+        positions that straddle a page, fill one, end at the table's
+        capacity, or belong to an idle slot: the accepted offsets are in
+        their pages, every other byte of the pool is as it was."""
+        from conftest import KVSpy, PoolMirror
+
+        params, cfg = model
+        ps, W, S = self.PS, 4, self.S
+        rng = np.random.default_rng(11)
+        pc = serving.PagedSlotCache(cfg, S, max_len=16, page_size=ps,
+                                    n_pages=self.PAGES, kv_dtype=kv)
+        _junk_pool(pc, rng)
+        for s in range(S):
+            pc.alloc()
+            for idx in range(pc.max_pages):
+                pc.grant(s, idx)
+        # straddles pages 0|1; fills page 1; runs off the table; idle
+        pos = np.asarray([2, 4, 14, 6], np.int32)
+        active = np.asarray([True, True, True, False])
+        pc.set_pos(range(S), pos)
+        table = pc.table.copy()
+        spy = KVSpy(monkeypatch)
+        verify = jax.jit(lambda win, pool: T.decode_verify_paged(
+            params, win, pool, jnp.asarray(table), cfg, jnp.asarray(active),
+            kernel=kernel))
+        # drafts that agree with the target for 1, 3, 2 positions:
+        # found greedily, one column at a time, on the untouched pool
+        window = np.asarray(rng.integers(0, 64, (S, W)), np.int32)
+        for i in range(W - 1):
+            t = np.asarray(verify(jnp.asarray(window), pc.cache)[0])
+            window[:, i + 1] = t[:, i]
+        want = np.asarray([1, 3, 2, 0])
+        for s in range(S):
+            if want[s] < W - 1:
+                window[s, want[s] + 1] ^= 1
+        spy.take()                   # the search's calls: not these
+        mirror = PoolMirror(pc.cache, ps)
+        _, _, acc, out = verify(jnp.asarray(window), pc.cache)
+        assert np.asarray(acc).tolist() == [1, 3, 2, 0]
+        assert np.asarray(out["pos"]).tolist() == [4, 8, 17, 6]
+        calls = spy.take()
+        assert len(calls) == cfg.n_layers
+        ok = active[:, None] & (np.arange(W) <= np.asarray(acc)[:, None])
+        for l, (_, _, k, v) in enumerate(calls):
+            mirror.write(("k", "v"), l, table, pos, ok, k, v)
+        mirror.assert_holds(out)
 
 
 class TestPagedHTTP:

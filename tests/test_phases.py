@@ -416,19 +416,19 @@ def _chunk_lowering(model):
     ps, n_pages = 4, 7
     pool = C.init_page_pool(cfg, 2, n_pages, ps)
 
-    def chunk(params, pool, suffix, pages, phys, off):
+    def chunk(params, pool, suffix, pages, land, first):
         pk, pv = C.gather_prefix_pages(pool, pages)
         logits, suf = T.prefill_with_prefix(
             params, suffix, pk, pv, jnp.int32(6), cfg,
             true_len=jnp.asarray([8]))
         return logits, C.paged_insert(
-            pool, jnp.asarray([0]), suf["pos"], phys, off, suf["k"],
-            suf["v"])
+            pool, jnp.asarray([0]), suf["pos"], land, first,
+            jnp.asarray([8]), suf["k"], suf["v"])
 
     return jax.jit(chunk).lower(
         params, pool, jnp.zeros((1, 8), jnp.int32),
-        jnp.zeros((2,), jnp.int32), jnp.zeros((1, 8), jnp.int32),
-        jnp.zeros((1, 8), jnp.int32))
+        jnp.zeros((2,), jnp.int32),
+        jnp.zeros((1, C.landing_pages(8, ps)), jnp.int32), jnp.int32(2))
 
 
 def _train_step_lowering(hvd, cfg, params, batch):

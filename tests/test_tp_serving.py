@@ -313,6 +313,79 @@ class TestTpCompose:
         assert eng.stats()["paged_kernel_engaged"] is True
         assert oracle.stats()["paged_kernel_engaged"] is False
 
+    @pytest.mark.parametrize("kv,kernel", [(None, True), ("int8", False)])
+    def test_sharded_pool_bytes_match_a_page_offset_writer(
+            self, model, monkeypatch, kv, kernel):
+        """The pool's write discipline on a head-sharded pool
+        (``write_pages`` under GSPMD, the kernel's stacked operand under
+        ``shard_map``): a landing with bucket padding, a suffix that
+        starts mid-page, then ticks with an idle slot and a page
+        crossed — every pool array, gathered from both shards, is byte
+        for byte what a plain ``(page, offset)`` writer produces."""
+        from conftest import KVSpy, PoolMirror
+
+        params, cfg = model
+        sh = ServingSharding(cfg, 2)
+        params = {**params, "layers": {
+            **params["layers"], "ln1": params["layers"]["ln1"]
+            + 0.01 * jnp.arange(cfg.n_layers)[:, None]}}
+        params_tp = sh.shard_params(params)
+        S, ps = 3, 4
+        rng = np.random.default_rng(5)
+        spy = KVSpy(monkeypatch, ordered=False)   # two devices
+        pc = serving.PagedSlotCache(cfg, S, max_len=24, page_size=ps,
+                                    n_pages=14, kv_dtype=kv, mesh=sh.mesh)
+        poolsh = sh.pool_shardings(pc.quantized)
+        pc.cache = {n: a if n == "pos" else jax.device_put(
+            jnp.asarray(rng.integers(-90, 90, a.shape), a.dtype), poolsh[n])
+            for n, a in pc.cache.items()}
+        mirror = PoolMirror(pc.cache, ps)
+
+        def land(slots, lens, start, bucket):
+            for s, n in zip(slots, lens):
+                for idx in range(start // ps, -(-(start + n) // ps)):
+                    if pc.table[s, idx] == serving.cache.NULL_PAGE:
+                        pc.grant(s, idx)
+            shape = (cfg.n_layers, len(slots), cfg.kv_heads, bucket,
+                     cfg.head_dim)
+            blk = {x: jax.device_put(
+                jnp.asarray(rng.standard_normal(shape), jnp.float32),
+                sh.prefill_cache_shardings()[x]) for x in "kv"}
+            blk["pos"] = jnp.asarray([start + n for n in lens])
+            pc.land(slots, blk, lens, start=start)
+            mirror.land(("k", "v"), [pc.table[s].copy() for s in slots],
+                        start, lens, blk["k"], blk["v"])
+
+        a, b = pc.alloc(), pc.alloc()
+        land([a, b], [6, 3], 0, 8)       # padded rows of one landing
+        land([a], [5], 6, 8)             # a suffix from mid-page
+        R = sh.replicated
+        tick = jax.jit(
+            lambda p, tok, pool, table, active: T.decode_step_paged(
+                p, tok, pool, table, cfg, active, kernel=kernel,
+                mesh=sh.mesh if kernel else None)[1],
+            in_shardings=(sh.param_shardings(), R, poolsh, R, R),
+            out_shardings=poolsh)
+        for step in range(3):
+            pos, active = pc.positions(), pc.active_mask()
+            active[a] &= step != 1
+            for s in np.nonzero(active)[0]:
+                if pc.table[s, pos[s] // ps] == serving.cache.NULL_PAGE:
+                    pc.grant(s, pos[s] // ps)
+            table = pc.table.copy()
+            pc.cache = tick(params_tp,
+                            jnp.asarray(rng.integers(0, 64, S), jnp.int32),
+                            pc.cache, jnp.asarray(table),
+                            jnp.asarray(active))
+            calls = spy.take()
+            assert len(calls) == cfg.n_layers
+            for l, (_, _, k, v) in enumerate(calls):
+                mirror.write(("k", "v"), l, table, pos, active[:, None],
+                             k, v)
+        assert pc.positions().tolist() == [13, 6, 0]    # b crossed 4
+        assert pc.cache["k"].sharding.spec == poolsh["k"].spec
+        mirror.assert_holds(pc.cache)
+
     @pytest.mark.slow
     def test_chunked_prefill_under_tp(self, model):
         # Slow (PR 17 budget pass): oracle + tp engine pair is ~8 s;

@@ -1,0 +1,125 @@
+"""The TPU compiler's own verdict on the serving programs, with no chip:
+``jax.experimental.topologies`` describes a v5e and XLA:TPU / Mosaic
+compile for it here (nothing runs).  What is held: the paged decode tick
+and the landing of a prefill write the KV pool IN PLACE — layout
+assignment is the TPU compiler's, so the CPU tests of
+``tests/test_paged.py`` cannot see it, and ``chip_smoke.py`` sees it
+only on the chip.
+
+Keep every such compile in THIS file (one process may hold libtpu), and
+describe the topology only inside the fixture below.
+"""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+import chip_smoke  # noqa: E402
+from horovod_tpu.models import transformer as T  # noqa: E402
+from horovod_tpu.ops import paged_attention as PA  # noqa: E402
+from horovod_tpu.serving import cache as C  # noqa: E402
+
+pytestmark = [pytest.mark.serving, pytest.mark.paged]
+
+S, PS, PAGES, MAX_LEN = 8, 16, 2048, 512
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # such a compile can be written to the persistent cache but never
+    # read back without a chip: keep it out
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _cfg(**kw):
+    return T.TransformerConfig(
+        vocab_size=512, d_model=256, n_heads=2, n_kv_heads=2, d_ff=512,
+        max_seq=MAX_LEN, dtype=jnp.bfloat16, attention_impl="reference",
+        **kw)
+
+
+CASES = {
+    "uniform": _cfg(n_layers=2),
+    "patterned": _cfg(n_layers=4, window=64,
+                      layer_pattern=("sliding", "full")),
+}
+
+
+@pytest.mark.parametrize("what", ["tick", "landing"])
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_the_tpu_compiler_writes_the_pool_in_place(one_chip, monkeypatch,
+                                                   case, what):
+    """Compiled for the v5e, neither the tick (fused kernel, the pools
+    the layer scan's carry) nor the landing has an instruction with a
+    result the size of one layer of a pool, other than the pool passing
+    through and the writes the compiler aliased to it; and both need
+    next to no temporary memory beside the pool."""
+    monkeypatch.setattr(PA, "use_interpret", lambda: False)
+    cfg = CASES[case]
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    def bf16(a):
+        return a.astype(cfg.dtype) if a.dtype == jnp.float32 else a
+
+    pool = C.init_page_pool(cfg, S, PAGES + 1, PS, None,
+                            cfg.kind_count("full"))
+    if cfg.has_window:
+        w = C.init_page_pool(cfg, S, PAGES // 4 + 1, PS, None,
+                             cfg.kind_count("sliding"))
+        pool = {**pool, "wk": w["k"], "wv": w["v"]}
+    pool = on_chip(jax.eval_shape(lambda: pool))
+    layer = min(a.size // a.shape[0] for n, a in pool.items() if n != "pos")
+    table = on_chip(jax.ShapeDtypeStruct((S, MAX_LEN // PS), jnp.int32))
+    if what == "tick":
+        params = on_chip(jax.eval_shape(lambda: jax.tree_util.tree_map(
+            bf16, T.init_params(jax.random.PRNGKey(0), cfg))))
+        compiled = jax.jit(
+            lambda p, tok, act, t, wt, pl: T.decode_step_paged(
+                p, tok, pl, t, cfg, act, kernel=True,
+                wtable=wt if cfg.has_window else None),
+            donate_argnums=(5,)).lower(
+                params, on_chip(jax.ShapeDtypeStruct((S,), jnp.int32)),
+                on_chip(jax.ShapeDtypeStruct((S,), jnp.bool_)), table,
+                table, pool).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+    else:
+        full = {n: a for n, a in pool.items() if n not in ("wk", "wv")}
+        blk = on_chip(jax.ShapeDtypeStruct(
+            (cfg.kind_count("full"), 2, cfg.kv_heads, 128, cfg.head_dim),
+            cfg.dtype))
+        i32 = lambda *shape: on_chip(  # noqa: E731
+            jax.ShapeDtypeStruct(shape, jnp.int32))
+        compiled = jax.jit(C.paged_insert, donate_argnums=(0,)).lower(
+            full, i32(2), i32(2), i32(2, C.landing_pages(128, PS)), i32(),
+            i32(2), blk, blk).compile()
+    offenders, largest = chip_smoke.pool_sized_results(compiled.as_text(),
+                                                       layer)
+    assert offenders == [], (offenders, largest)
+    # the pool itself is argument and aliased result; beside it the
+    # program holds less than one layer of it
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < layer * 2, mem

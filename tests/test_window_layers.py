@@ -529,6 +529,132 @@ class TestWindowAllocator:
         assert st["kv_pages_in_use"] == st["kv_window_pages_in_use"] == 0
 
 
+class TestTwoKindPoolsWrittenInPlace:
+    """The write discipline of ``serving.cache.write_pages`` for a model
+    with two kinds of layer: both kinds' stacked pools ride the period
+    scan's carry, each layer writes its own kind's stack at its index
+    among that kind, and the bytes are a ``(page, offset)`` writer's."""
+
+    S, PS, MAX_LEN = 3, 4, 32
+
+    def _caches(self, cfg, n_pages=17):
+        fc = PagedSlotCache(cfg, self.S, self.MAX_LEN, page_size=self.PS,
+                            n_pages=n_pages, n_layers=cfg.kind_count("full"))
+        wc = PagedSlotCache(cfg, self.S, self.MAX_LEN, page_size=self.PS,
+                            n_layers=cfg.kind_count("sliding"),
+                            window=WINDOW)
+        return fc, wc
+
+    @staticmethod
+    def _pool(fc, wc):
+        return {**fc.cache, "wk": wc.cache["k"], "wv": wc.cache["v"]}
+
+    @pytest.mark.parametrize("kernel", [False, True],
+                             ids=["unfused", "kernel"])
+    def test_no_operation_the_size_of_a_layer_of_either_pool(self, model,
+                                                             kernel):
+        """The patterned case of ``tests/test_paged.py``'s structure
+        test: neither stack is among the period scan's xs or ys, no
+        layer of either is cut out, every scatter indexes ``(layer,
+        page)``."""
+        from conftest import pool_structure_faults
+
+        params, cfg = model
+        pool = self._pool(*self._caches(cfg))
+        table = jnp.zeros((self.S, self.MAX_LEN // self.PS), jnp.int32)
+        jaxpr = jax.make_jaxpr(lambda pl: T.decode_step_paged(
+            params, jnp.zeros((self.S,), jnp.int32), pl, table, cfg,
+            jnp.ones((self.S,), bool), kernel=kernel, wtable=table,
+            return_moe_load=True))(pool)
+        shapes = {a.shape for n, a in pool.items() if n != "pos"}
+        assert len(shapes) == 2          # two kinds, two pool shapes
+        assert pool_structure_faults(jaxpr, shapes) == []
+
+    @pytest.mark.parametrize("kernel", [False, True],
+                             ids=["unfused", "kernel"])
+    def test_both_pools_bytes_match_a_page_offset_writer(self, model,
+                                                         monkeypatch, kernel):
+        """Two chunks of one prompt (the window cache gives the page
+        behind the window back between them, and what the second chunk
+        holds of it lands nowhere), a second slot's padded landing, then
+        ticks through which the window slot releases a page behind it,
+        the other slot takes it up, and one slot idles: both kinds'
+        arrays, byte for byte, the NULL page excepted."""
+        from conftest import KVSpy, PoolMirror
+
+        params, cfg = model
+        ps, S = self.PS, self.S
+        names = {"full": ("k", "v"), "sliding": ("wk", "wv")}
+        count = {k: cfg.kind_count(k) for k in names}
+        rng = np.random.default_rng(3)
+        spy = KVSpy(monkeypatch)
+        fc, wc = self._caches(cfg)
+        for c in (fc, wc):
+            c.cache = {n: a if n == "pos" else jnp.asarray(
+                rng.standard_normal(a.shape), a.dtype)
+                for n, a in c.cache.items()}
+        mirror = PoolMirror(self._pool(fc, wc), ps)
+
+        def claim(c, slot, lo, hi):
+            for idx in range(max(lo // ps, c.first_live(lo)),
+                             -(-hi // ps)):
+                if c.table[slot, idx] == NULL_PAGE:
+                    c.grant(slot, idx)
+
+        def land(slots, lens, start, bucket, nxt):
+            """``nxt``: the first query after this block, whose window
+            the window cache keeps."""
+            for c, kind in ((fc, "full"), (wc, "sliding")):
+                for s, n in zip(slots, lens):
+                    c.release_behind(s, nxt)
+                    claim(c, s, max(start, nxt - WINDOW + 1)
+                          if c.window else start, start + n)
+                shape = (count[kind], len(slots), cfg.kv_heads, bucket,
+                         cfg.head_dim)
+                blk = {x: jnp.asarray(rng.standard_normal(shape),
+                                      jnp.float32) for x in "kv"}
+                blk["pos"] = jnp.asarray([start + n for n in lens])
+                c.land(slots, blk, lens, start=start)
+                mirror.land(names[kind], [c.table[s].copy() for s in slots],
+                            start, lens, blk["k"], blk["v"])
+
+        a, b = fc.alloc(), fc.alloc()
+        wc.acquire(a), wc.acquire(b)
+        land([a], [8], 0, 8, nxt=8)
+        land([a], [5], 8, 8, nxt=13)     # window page 0 is given back
+        assert wc.table[a, 0] == NULL_PAGE and wc.table[a, 1] != NULL_PAGE
+        land([b], [3], 0, 4, nxt=3)
+
+        tick = jax.jit(lambda tok, pool, t, wt, active: T.decode_step_paged(
+            params, tok, pool, t, cfg, active, kernel=kernel, wtable=wt)[1])
+        for step in range(6):
+            pos, active = fc.positions(), fc.active_mask()
+            active[b] &= step != 2       # idle for one tick
+            for s in np.nonzero(active)[0]:
+                wc.release_behind(s, pos[s])
+                claim(fc, s, pos[s], pos[s] + 1)
+                claim(wc, s, pos[s], pos[s] + 1)
+            tables = {"full": fc.table.copy(), "sliding": wc.table.copy()}
+            out = tick(jnp.asarray(rng.integers(0, 128, S), jnp.int32),
+                       self._pool(fc, wc), jnp.asarray(tables["full"]),
+                       jnp.asarray(tables["sliding"]), jnp.asarray(active))
+            wc.cache = {**wc.cache, "k": out.pop("wk"), "v": out.pop("wv")}
+            fc.cache = out
+            calls = spy.take()
+            assert [c[0] for c in calls] == list(cfg.layer_pattern) * 2
+            seen = dict.fromkeys(names, 0)
+            for kind, at, k, v in calls:
+                assert at[:, 0].tolist() == pos.tolist()
+                mirror.write(names[kind], seen[kind], tables[kind], pos,
+                             active[:, None], k, v)
+                seen[kind] += 1
+        assert fc.positions().tolist() == [19, 8, 0]
+        # a walked past 16: its window pages behind 16 - 8 went back
+        assert not wc.table[a, :2].any() and wc.table[a, 4] != NULL_PAGE
+        assert wc.slot_pages_max <= wc.window_pages_bound
+        mirror.assert_holds(self._pool(fc, wc))
+
+
 # --- what is refused, and what is unchanged ----------------------------------
 
 
