@@ -5,7 +5,7 @@ Two engines over the same params and the same paged pool geometry —
 ``paged_kernel=True`` vs ``paged_kernel=False`` — run the identical
 workload with reps interleaved (chip-state variance dominates
 cross-process comparisons; see moe_dispatch_ab.py), timed at the
-full-pool per-tick p25 like benchmarks/serving.py ``_ab_paged``.  The
+full-pool per-tick p25 like benchmarks/serving.py ``_ab_decode``.  The
 output sequences are compared token-for-token: the fused kernel is only
 a win if it is also EXACT (the A/B oracle contract from
 tests/test_paged.py).

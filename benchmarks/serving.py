@@ -15,10 +15,8 @@ load shape a static-batch number can't see — and reports tok/s,
 p50/p99 TTFT, and mean slot occupancy next to a static-batch decode
 reference at B = n_slots, PLUS the EngineConfig.overlap A/B
 (steady-state decode tok/s, pipelined vs synchronous, identical
-workload), the EngineConfig.paged A/B (decode tok/s and max concurrent
-mixed-length requests at a fixed HBM budget, page pool vs the
-slot-contiguous baseline, with kv_bytes_per_token and the page-pool
-high-water mark in the JSON line) and the pipeline phase metrics
+workload), the page pool's kv_bytes_per_token and high-water mark in
+the JSON line, and the pipeline phase metrics
 (overlap_efficiency = device-wait share of the tick,
 host_syncs_per_tick):
 
@@ -196,165 +194,6 @@ def _ab_decode(args, cfg, params):
         "overlap_decode_speedup": round(q["sync"] / q["overlap"], 3),
         "equal_output_tokens": toks["overlap"] == toks["sync"],
         "ab_steps_sampled": {n: len(d) for n, (_, d) in engines.items()},
-    }
-
-
-def _ab_paged(args, cfg, params):
-    """The EngineConfig.paged A/B (docs/serving.md "Paged KV cache"):
-
-    1. Steady-state decode tok/s, paged pool vs the slot-contiguous
-       baseline on the IDENTICAL workload, reps interleaved and
-       compared at the per-tick p25 exactly like :func:`_ab_decode`.
-       The page-table gather is indirection the contiguous layout does
-       not pay, so a ratio near 1.0 is the goal — the paged win is the
-       byte/concurrency column, not this one.
-    2. Max concurrent requests at a FIXED HBM budget of cache tokens
-       (2 worst-case slots' worth): the slot-contiguous layout admits
-       ``budget // max_len`` requests no matter their actual length —
-       that ceiling is the layout, not a measurement — while the paged
-       engine admits short mixed-length requests page by page until
-       the same bytes are genuinely full.
-    """
-    from horovod_tpu import serving
-
-    S = args.slots
-    prompt = np.random.default_rng(3).integers(
-        0, cfg.vocab_size, max(args.prompt_len // 2, 1)).tolist()
-    engines = {}
-    for name, paged in (("paged", True), ("unpaged", False)):
-        eng = serving.InferenceEngine(
-            params, cfg, serving.EngineConfig(
-                n_slots=S, max_len=cfg.max_seq,
-                max_prefills_per_tick=args.max_prefills_per_tick,
-                max_queue_depth=max(2 * S, 8), paged=paged))
-        eng.warmup([len(prompt)])
-        engines[name] = (eng, [])
-
-    toks = {}
-    steps = max(min(max(args.steps, 24), cfg.max_seq - len(prompt) + 1), 1)
-    for _ in range(max(args.iters, 4)):
-        for name, (eng, dts) in engines.items():
-            futs = [eng.submit(prompt, max_new_tokens=steps)
-                    for _ in range(S)]
-            while not all(f.done() for f in futs):
-                full = eng.slots.active_count == S
-                t0 = time.perf_counter()
-                eng.step()
-                dt = time.perf_counter() - t0
-                if full and eng.slots.active_count == S:
-                    dts.append(dt)
-            # The SEQUENCES, not counts (counts are equal by
-            # construction — every future runs to max_new_tokens):
-            # this is the benchmark's live token-identity check.
-            toks.setdefault(name, []).extend(
-                f.tokens_so_far() for f in futs)
-    q = {name: float(np.percentile(dts, 25))
-         for name, (_, dts) in engines.items()}
-
-    # -- fixed-HBM-budget concurrency ------------------------------------
-    ps = 16
-    max_len = cfg.max_seq
-    budget_tokens = 2 * max_len  # two worst-case slots' worth of bytes
-    unpaged_ceiling = budget_tokens // max_len
-    rng = np.random.default_rng(4)
-    n_req = 2 * S
-    # Short mixed-length requests (~one page each): the traffic shape
-    # the contiguous layout wastes a full max_len reservation on.
-    frag_prompts = [rng.integers(0, cfg.vocab_size,
-                                 int(n)).tolist()
-                    for n in rng.integers(max(ps // 4, 1),
-                                          ps // 2 + 1, n_req)]
-    eng = serving.InferenceEngine(
-        params, cfg, serving.EngineConfig(
-            n_slots=S, max_len=max_len, page_size=ps,
-            n_pages=budget_tokens // ps, max_prefills_per_tick=S,
-            max_queue_depth=n_req))
-    eng.warmup(sorted({eng._bucket(len(p)) for p in frag_prompts}))
-    futs = [eng.submit(p, max_new_tokens=ps // 4) for p in frag_prompts]
-    peak = 0
-    while not all(f.done() for f in futs):
-        eng.step()
-        peak = max(peak, eng.slots.active_count)
-    preempted = 0
-    for f in futs:
-        try:
-            f.result(timeout=0)
-        except serving.CacheOutOfPagesError:
-            preempted += 1
-
-    # -- per-tick attention time SPLIT: gather / dequant / attend vs the
-    #    fused kernel, each leg its own jitted function on one layer's
-    #    full int8 pool (int8 so the dequant leg is live), scaled to a
-    #    per-tick figure by n_layers.  This is the attribution column
-    #    for benchmarks/paged_decode_ab.py's end-to-end A/B: when the
-    #    fused ratio moves, this says WHICH leg the kernel absorbed.
-    from horovod_tpu.models import transformer as T
-    from horovod_tpu.ops import paged_attention as PA
-
-    hkv = cfg.n_kv_heads or cfg.n_heads
-    dh = cfg.d_model // cfg.n_heads
-    mp = -(-max_len // ps)
-    npage = 1 + S * mp  # page 0 = NULL
-    kq, ks = T.kv_quantize(jax.random.normal(
-        jax.random.PRNGKey(11), (npage, hkv, ps, dh), jnp.float32))
-    vq, vs = T.kv_quantize(jax.random.normal(
-        jax.random.PRNGKey(12), (npage, hkv, ps, dh), jnp.float32))
-    table = jnp.asarray(
-        1 + np.arange(S * mp, dtype=np.int32).reshape(S, mp))
-    pos = jnp.full((S,), max_len - 1, jnp.int32)
-    mask = (jax.lax.broadcasted_iota(jnp.int32, (mp * ps,), 0)[None, :]
-            <= pos[:, None])
-    qh = jax.random.normal(jax.random.PRNGKey(13),
-                           (S, cfg.n_heads, 1, dh), cfg.dtype)
-
-    gather = jax.jit(lambda kp, sk, vp, sv, t: (
-        T._gather_pages(kp, t), T._gather_scales(sk, t),
-        T._gather_pages(vp, t), T._gather_scales(sv, t)))
-    dequant = jax.jit(lambda kg, sk, vg, sv: (
-        T.kv_dequantize(kg, sk, cfg.dtype),
-        T.kv_dequantize(vg, sv, cfg.dtype)))
-    attend = jax.jit(lambda q, kd, vd: T._cache_attend(
-        q, kd, vd, mask[:, None, None, :]))
-    fused = jax.jit(lambda q, kp, vp, sk, sv, t, lim: PA.paged_attend(
-        q.reshape(S, hkv, cfg.n_heads // hkv, dh), kp, vp, sk, sv,
-        t, lim, compute_dtype=cfg.dtype)[0])
-
-    def _best(fn, *a):
-        jax.block_until_ready(fn(*a))  # compile + warm
-        best = float("inf")
-        for _ in range(max(args.iters, 4)):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(*a))
-            best = min(best, time.perf_counter() - t0)
-        return best
-
-    t_gather = _best(gather, kq, ks, vq, vs, table)
-    kg, skg, vg, svg = gather(kq, ks, vq, vs, table)
-    t_dequant = _best(dequant, kg, skg, vg, svg)
-    kd, vd = dequant(kg, skg, vg, svg)
-    t_attend = _best(attend, qh, kd, vd)
-    t_fused = _best(fused, qh, kq, vq, ks, vs, table, pos + 1)
-    to_tick_ms = cfg.n_layers * 1e3
-    attn_split = {
-        "gather_ms": round(t_gather * to_tick_ms, 4),
-        "dequant_ms": round(t_dequant * to_tick_ms, 4),
-        "attend_ms": round(t_attend * to_tick_ms, 4),
-        "unfused_total_ms": round(
-            (t_gather + t_dequant + t_attend) * to_tick_ms, 4),
-        "fused_ms": round(t_fused * to_tick_ms, 4),
-    }
-
-    return {
-        "attn_split_per_tick": attn_split,
-        "decode_tok_s_paged": round(S / q["paged"], 2),
-        "decode_tok_s_unpaged": round(S / q["unpaged"], 2),
-        "paged_decode_ratio": round(q["unpaged"] / q["paged"], 3),
-        "paged_equal_output_tokens": toks["paged"] == toks["unpaged"],
-        "fixed_budget_tokens": budget_tokens,
-        "max_concurrent_paged": peak,
-        "max_concurrent_unpaged": unpaged_ceiling,
-        "fixed_budget_preempted": preempted,
-        "fixed_budget_pages_high_water": eng.slots.pages_high_water,
     }
 
 
@@ -1611,7 +1450,6 @@ def _engine_mode(args, T, cfg, params) -> None:
 
         obs_tracing.stop()
     ab = None if args.overlap_only else _ab_decode(args, cfg, params)
-    pab = None if args.overlap_only else _ab_paged(args, cfg, params)
     tab = None if args.overlap_only else _ab_tracing(args, cfg, params)
     sab = None if args.overlap_only else _ab_spec(args, T, cfg)
     smab = None if args.overlap_only else _ab_sampled(args, cfg, params)
@@ -1654,7 +1492,7 @@ def _engine_mode(args, T, cfg, params) -> None:
             engine.metrics.tokens_per_tick.percentile(0.50),
         "tokens_per_tick_p95":
             engine.metrics.tokens_per_tick.percentile(0.95),
-        # Page-pool pressure for the (paged-by-default) open-loop run:
+        # Page-pool pressure for the open-loop run:
         # per-token cache cost, pool size, and the high-water mark that
         # sizes n_pages for this traffic shape.
         "paged": snap["paged"],
@@ -1673,8 +1511,6 @@ def _engine_mode(args, T, cfg, params) -> None:
         result["trace_jsonl"] = args.trace + ".jsonl"
     if ab is not None:
         result.update(ab)
-    if pab is not None:
-        result.update(pab)
     if tab is not None:
         result.update(tab)
     if sab is not None:
@@ -1725,13 +1561,6 @@ def _engine_mode(args, T, cfg, params) -> None:
         print(f"A/B      steady decode {ab['decode_tok_s_overlap']:9.1f} "
               f"tok/s overlapped vs {ab['decode_tok_s_sync']:9.1f} sync "
               f"-> {ab['overlap_decode_speedup']}x")
-    if pab is not None:
-        print(f"paged    steady decode {pab['decode_tok_s_paged']:9.1f} "
-              f"tok/s paged vs {pab['decode_tok_s_unpaged']:9.1f} "
-              f"contiguous -> {pab['paged_decode_ratio']}x | "
-              f"{pab['fixed_budget_tokens']}-token budget holds "
-              f"{pab['max_concurrent_paged']} concurrent paged vs "
-              f"{pab['max_concurrent_unpaged']} slot-contiguous")
     if tab is not None:
         print(f"tracing  {tab['decode_tok_s_tracing']:9.1f} tok/s traced "
               f"vs {tab['decode_tok_s_notracing']:9.1f} untraced -> "

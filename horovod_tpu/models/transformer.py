@@ -379,8 +379,8 @@ def _scan_layers(layer, init, xs):
 
 def _require_uniform(cfg: TransformerConfig, what: str) -> None:
     """Refuse a configuration with more than one kind of layer where
-    only the uniform block is written (training, the contiguous and
-    speculative decode bodies, the pipeline schedules)."""
+    only the uniform block is written (training, the single-request
+    and speculative decode bodies, the pipeline schedules)."""
     if cfg.has_window:
         raise UnsupportedModelConfigError(
             f"{what} computes one kind of layer; this configuration's "
@@ -927,9 +927,10 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int = 0) -> Dict:
 def _cache_attend(qh, k_cache, v_cache, mask):
     """One query token per row against the full cache — the ONE copy of
     the decode attention math, shared by the scalar-position path
-    (:func:`_attention_decode`) and the per-slot path
-    (:func:`_attention_decode_slots`) so the bandwidth discipline cannot
-    fork.  ``mask`` is broadcastable to ``(B, H_kv, G, T)``.
+    (:func:`_attention_decode`) and the unfused paged path
+    (:func:`_attention_decode_paged`, on each slot's gathered pages) so
+    the bandwidth discipline cannot fork.  ``mask`` is broadcastable to
+    ``(B, H_kv, G, T)``.
 
     Bandwidth discipline (decode is cache-bandwidth-bound): the cache is
     dotted IN ITS STORED DTYPE with f32 MXU accumulation
@@ -1004,80 +1005,6 @@ def decode_step(params: Dict, tokens_t, cache: Dict, cfg: TransformerConfig):
         layer, x, (params["layers"], cache["k"], cache["v"]))
     logits = _lm_head(x, params["ln_f"], params["head"], cfg)
     return logits[:, 0], {"k": k_all, "v": v_all, "pos": pos + 1}
-
-
-def _attention_decode_slots(x, p, cfg: TransformerConfig, k_cache, v_cache,
-                            pos):
-    """Per-slot positioned one-token attention: row ``b`` writes its K/V
-    at ``pos[b]`` and attends positions ``<= pos[b]`` — continuous
-    batching, where every batch row is an independent request at its own
-    depth.  The attention math (and its bandwidth discipline) is the
-    shared :func:`_cache_attend`; the per-row write is a vmapped
-    ``dynamic_update_slice`` (a scatter touching one position per row,
-    not a cache-sized ``where``)."""
-    qh, k_t, v_t = _qkv_proj(x, p, cfg, positions=pos[:, None])
-    upd = jax.vmap(
-        lambda c, t, p_: lax.dynamic_update_slice_in_dim(c, t, p_, axis=1))
-    with jax.named_scope("kv_write"):
-        k_cache = upd(k_cache, k_t.astype(k_cache.dtype), pos)
-        v_cache = upd(v_cache, v_t.astype(v_cache.dtype), pos)
-    T = k_cache.shape[2]
-    mask = lax.broadcasted_iota(jnp.int32, (T,), 0)[None, :] <= pos[:, None]
-    o = _cache_attend(qh, k_cache, v_cache, mask[:, None, None, :])
-    return _out_proj(o.astype(cfg.dtype), p, cfg), k_cache, v_cache
-
-
-def decode_step_slots(params: Dict, tokens_t, cache: Dict,
-                      cfg: TransformerConfig, active):
-    """One continuous-batching decode tick over a pool of S cache slots.
-
-    ``tokens_t``: (S,) int32 — each slot's last emitted token;
-    ``cache``: a SLOT cache (:func:`horovod_tpu.serving.cache.
-    init_slot_cache`) whose ``pos`` is a PER-SLOT (S,) int32 vector;
-    ``active``: (S,) bool — which slots hold live requests.  Returns
-    ``(logits (S, V) float32, updated cache)``.
-
-    Inactive rows compute on zeros (the Join-style zero-substitution the
-    eager runtime uses for absent ranks — ``horovod_tpu/join.py``) and
-    their positions do not advance, so ONE compiled executable serves
-    every admit/retire pattern: shapes are static in S and the live set
-    is data, not structure.  Row ``s`` of the logits equals
-    :func:`decode_step`'s for the same request decoded alone at position
-    ``pos[s]`` (token-identity: ``tests/test_serving.py``).
-
-    Inactive rows still scatter their (zero-computed) K/V at their stale
-    position — harmless by construction: decode always writes position
-    ``p`` in the same step that first attends it, so anything a freed
-    slot left behind is overwritten before the next tenant can see it
-    (the same argument that makes right-padded bucketed prefill safe;
-    see :func:`prefill`)."""
-    _require_uniform(cfg, "decode_step_slots")
-    pos = cache["pos"]
-    T_cache = cache["k"].shape[3]
-    if not isinstance(pos, jax.core.Tracer) and not isinstance(
-            active, jax.core.Tracer):
-        over = np.asarray(active) & (np.asarray(pos) >= T_cache)
-        if over.any():
-            raise ValueError(
-                f"decode_step_slots past cache capacity (slots "
-                f"{np.nonzero(over)[0].tolist()} at pos >= {T_cache}); "
-                "init_slot_cache with a larger max_len")
-    x = _embed(params, tokens_t, cfg)[:, None]  # (S, 1, D)
-    x = jnp.where(active[:, None, None], x, jnp.zeros_like(x))
-
-    def layer(x, inp):
-        p, k_c, v_c = inp
-        h, k_new, v_new = _attention_decode_slots(
-            _attn_norm(x, p, cfg), p, cfg, k_c, v_c, pos)
-        return _mlp_block(x + h, p, cfg, moe_impl="dense"), (k_new, v_new)
-
-    x, (k_all, v_all) = _scan_layers(
-        layer, x, (params["layers"], cache["k"], cache["v"]))
-    logits = _lm_head(x, params["ln_f"], params["head"], cfg)
-    return logits[:, 0], {
-        "k": k_all, "v": v_all,
-        "pos": pos + active.astype(jnp.int32),
-    }
 
 
 # --- paged KV cache (block tables resolved inside the tick) -------------------
@@ -1206,10 +1133,9 @@ def _attention_decode_paged(x, p, cfg: TransformerConfig, kv, layer, table,
 
     Inactive rows are routed to physical page 0, the reserved NULL/
     trash page no live slot's table ever maps below its own position:
-    unlike the slot-contiguous layout, a stale write here could land in
-    a page that has since been re-granted or shared, so the inactive
-    scribble is not merely harmless-by-overwrite — it must be (and is)
-    aimed somewhere no one attends.  Active rows never collide: the
+    a stale write could land in a page that has since been re-granted
+    or shared, so the inactive scribble is not merely harmless-by-
+    overwrite — it must be (and is) aimed somewhere no one attends.  Active rows never collide: the
     host allocator guarantees every active slot's write page is
     PRIVATE (refcount 1; copy-on-write splits a shared page before any
     write targets it).
@@ -1341,9 +1267,12 @@ def decode_step_paged(params: Dict, tokens_t, pool: Dict, table,
     in S, P, and max_pages; the table and the live mask are DATA, so
     ONE compiled executable serves every allocation pattern — requests
     coming, going, growing pages, and sharing prefix pages never
-    recompile the tick (the paged analogue of
-    :func:`decode_step_slots`, whose per-row logits it matches exactly
-    for any table that lays the slot's positions out in order).
+    recompile the tick.  Inactive rows compute on zeros (the Join-style
+    zero-substitution of ``horovod_tpu/join.py``) and their positions
+    do not advance.  Row ``s`` of the logits equals
+    :func:`decode_step`'s for the same request decoded alone at
+    position ``pos[s]``, for any table that lays the slot's positions
+    out in order (``tests/test_paged.py``).
 
     Returns ``(logits (S, V) float32, updated pool)`` — the table is
     host-owned and passed back unchanged.
@@ -1867,7 +1796,7 @@ def prefill(params: Dict, prompt, cache: Dict, cfg: TransformerConfig,
     ``(B,)`` VECTOR gives every row its own length — the batch-K
     multi-request prefill the continuous-batching engine admits with —
     and the returned ``pos`` is the ``(B,)`` per-row count (consumed by
-    ``serving.cache.insert_prefill_batch``, one slot per row).
+    ``serving.cache.paged_insert``, one slot per row).
     Causality makes the padding inert for the logits (position
     ``true_len - 1`` never attends past itself), and the junk K/V it
     leaves at positions ``>= true_len`` is never read: decode writes

@@ -1,8 +1,8 @@
 """Continuous-batching inference serving (docs/serving.md).
 
 The paper's fusion-of-pending-work architecture applied to decoding:
-one compiled ``decode_step_slots`` executable hot over a fixed pool of
-cache slots, a bounded FCFS scheduler admitting requests (one batched
+one compiled ``decode_step_paged`` executable hot over a fixed set of
+slots whose K/V lives in a page pool, a bounded FCFS scheduler admitting requests (one batched
 batch-K prefill per tick) into freed slots with zero recompilation,
 and a threaded stdlib-HTTP front — wrapped in a fault-tolerance layer
 (supervised tick restarts, a watchdog against hung ticks, typed
@@ -28,11 +28,7 @@ path (docs/serving.md "Performance").
 
 from horovod_tpu.serving.cache import (
     PagedSlotCache,
-    SlotCache,
     init_page_pool,
-    init_slot_cache,
-    insert_prefill,
-    insert_prefill_batch,
 )
 from horovod_tpu.serving.engine import (
     DEGRADED,
@@ -92,8 +88,7 @@ from horovod_tpu.serving import router  # noqa: E402  (docs/serving.md "Front ti
 
 __all__ = [
     "router",
-    "SlotCache", "PagedSlotCache", "init_slot_cache", "init_page_pool",
-    "insert_prefill", "insert_prefill_batch",
+    "PagedSlotCache", "init_page_pool",
     "EngineConfig", "GenerationFuture", "InferenceEngine",
     "HEALTHY", "DEGRADED", "DRAINING", "FAILED",
     "FaultInjector", "FaultSpec", "InjectedFaultError",

@@ -1,21 +1,26 @@
-"""Slot-based KV-cache manager for continuous batching.
+"""The serving engine's KV cache: a pool of fixed-size pages.
 
-The device side is the transformer's existing STATIC cache layout
-(:func:`horovod_tpu.models.transformer.init_cache` with ``batch = S``)
-with one change: ``pos`` is a PER-SLOT ``(S,)`` vector instead of a
-shared scalar, because every slot holds a different request at a
-different depth.  The host side (:class:`SlotCache`) is plain free-list
-bookkeeping: slots are allocated FCFS-lowest-index, freed on
-retirement, and the active set is exported as a ``(S,)`` bool mask that
-the engine feeds to :func:`~horovod_tpu.models.transformer.
-decode_step_slots` every tick — the live set is DATA, not structure, so
-the decode executable never recompiles as requests come and go.
+The paged layout (PagedAttention, Kwon et al., SOSP 2023) stores K/V as
+a pool of fixed-size pages, ``(L, P, H_kv, page, Dh)``; each of the S
+slots owns an int32 page-table row, resolved INSIDE the compiled decode
+tick (:func:`~horovod_tpu.models.transformer.decode_step_paged`), and a
+per-slot ``(S,)`` write position, because every slot holds a different
+request at a different depth.  Page tables and the active mask are
+DATA, not structure, so requests coming, going, growing and sharing
+prefix pages never recompile anything.  Page 0 is the reserved
+NULL/trash page: never granted, the routing target for inactive rows'
+writes and unpopulated table entries.
 
-A freed slot is NOT scrubbed: decode writes position ``p`` in the same
-step that first attends it, so whatever the previous tenant left behind
-is overwritten before the next one can attend it (the argument is
-spelled out on ``decode_step_slots``; the no-contamination test in
-``tests/test_serving.py`` exercises it).
+The host side (:class:`PagedSlotCache`) is free-list bookkeeping: slots
+are allocated lowest-index-first and freed on retirement, pages are
+granted on demand, refcounted for prefix sharing and copied on write.
+Nothing freed is scrubbed: a page's next owner writes every position
+before first attending it (``tests/test_paged.py`` exercises it).
+Every write into the pool, from the tick, the speculative verify and
+the landing alike, is :func:`write_pages`.
+
+(Until PR 28 a slot-contiguous ``(L, S, H_kv, T, Dh)`` cache stood
+beside this one; no workload ran it.)
 """
 
 from __future__ import annotations
@@ -30,169 +35,6 @@ from jax import lax
 
 from horovod_tpu.models import transformer as T
 from horovod_tpu.serving.scheduler import CacheOutOfPagesError
-
-
-def init_slot_cache(cfg: "T.TransformerConfig", n_slots: int,
-                    max_len: int = 0) -> Dict:
-    """A per-layer KV cache with ``n_slots`` independent request slots:
-    ``k``/``v`` are ``(L, S, H_kv, T, Dh)`` exactly as
-    :func:`~horovod_tpu.models.transformer.init_cache` lays them out for
-    ``batch = S``, and ``pos`` is ``(S,)`` int32 — one write position per
-    slot."""
-    base = T.init_cache(cfg, n_slots, max_len)
-    return {"k": base["k"], "v": base["v"],
-            "pos": jnp.zeros((n_slots,), jnp.int32)}
-
-
-@jax.named_scope("kv_land")  # T.DEVICE_SCOPES
-def insert_prefill(cache: Dict, slot, prefilled: Dict) -> Dict:
-    """Land a batch-1 prefilled cache in slot ``slot`` of a slot cache.
-
-    ``prefilled`` is the cache returned by a single-request
-    :func:`~horovod_tpu.models.transformer.prefill` — ``k``/``v`` shaped
-    ``(L, 1, H_kv, T_pre, Dh)`` with ``T_pre <= T`` and scalar ``pos``.
-    One ``lax.dynamic_update_slice`` per tensor writes the block at
-    ``(layer 0, slot, head 0, position 0, dim 0)``; ``slot`` may be
-    traced, so a jitted wrapper compiles once per prefill bucket shape
-    and serves every slot index."""
-    slot = jnp.asarray(slot, jnp.int32)
-    zero = jnp.int32(0)
-    k = lax.dynamic_update_slice(
-        cache["k"], prefilled["k"].astype(cache["k"].dtype),
-        (zero, slot, zero, zero, zero))
-    v = lax.dynamic_update_slice(
-        cache["v"], prefilled["v"].astype(cache["v"].dtype),
-        (zero, slot, zero, zero, zero))
-    pos = cache["pos"].at[slot].set(prefilled["pos"].astype(jnp.int32))
-    return {"k": k, "v": v, "pos": pos}
-
-
-@jax.named_scope("kv_land")  # T.DEVICE_SCOPES
-def insert_prefill_batch(cache: Dict, slots, prefilled: Dict) -> Dict:
-    """Land a batch-K prefilled cache in K slots of a slot cache.
-
-    ``prefilled`` is the cache returned by a batch-K
-    :func:`~horovod_tpu.models.transformer.prefill` with a PER-ROW
-    ``true_len`` — ``k``/``v`` shaped ``(L, K, H_kv, T_pre, Dh)`` with
-    ``T_pre <= T`` and ``pos`` a ``(K,)`` vector of per-row counts.
-    Row ``i`` lands in slot ``slots[i]`` via one scatter per tensor;
-    ``slots`` may be traced, so a jitted wrapper compiles once per
-    ``(K, T_pre)`` shape and serves every slot assignment."""
-    slots = jnp.asarray(slots, jnp.int32)
-    t_pre = prefilled["k"].shape[3]
-    k = cache["k"].at[:, slots, :, :t_pre, :].set(
-        prefilled["k"].astype(cache["k"].dtype))
-    v = cache["v"].at[:, slots, :, :t_pre, :].set(
-        prefilled["v"].astype(cache["v"].dtype))
-    pos = cache["pos"].at[slots].set(prefilled["pos"].astype(jnp.int32))
-    return {"k": k, "v": v, "pos": pos}
-
-
-class SlotCache:
-    """Host-side slot allocator wrapped around one device slot cache.
-
-    The device cache dict lives at :attr:`cache` and is REPLACED (never
-    mutated) by :meth:`insert` and by the engine's decode tick — JAX
-    functional style with host bookkeeping alongside.
-    """
-
-    def __init__(self, cfg: "T.TransformerConfig", n_slots: int,
-                 max_len: int = 0):
-        if n_slots < 1:
-            raise ValueError(f"need at least one slot, got {n_slots}")
-        self.cfg = cfg
-        self.n_slots = n_slots
-        self.max_len = max_len or cfg.max_seq
-        self.cache = init_slot_cache(cfg, n_slots, self.max_len)
-        self._active = np.zeros(n_slots, bool)
-        self._free: List[int] = list(range(n_slots))
-        # One compiled insert per prefill bucket shape (slot is traced);
-        # the slot cache is donated — insert replaces it in place instead
-        # of holding two full copies live.  The batch variant compiles
-        # per (K, bucket) shape — the engine's batched admission path.
-        self._insert = jax.jit(insert_prefill, donate_argnums=(0,))
-        self._insert_batch = jax.jit(insert_prefill_batch,
-                                     donate_argnums=(0,))
-
-    # -- allocation ---------------------------------------------------------
-
-    def alloc(self) -> Optional[int]:
-        """Lowest free slot index, or ``None`` when the pool is full."""
-        if not self._free:
-            return None
-        # A min-heap keeps FCFS-lowest-index assignment at O(log S) per
-        # op; the old list.pop(0) + sort() was O(S log S) per
-        # retirement on the hot path.
-        slot = heapq.heappop(self._free)
-        self._active[slot] = True
-        return slot
-
-    def free(self, slot: int) -> None:
-        if not self._active[slot]:
-            raise ValueError(f"slot {slot} is not active")
-        self._active[slot] = False
-        heapq.heappush(self._free, slot)
-
-    def release_all(self) -> None:
-        """Host-side reset: every slot freed (device K/V left in place —
-        write-before-attend makes scrubbing unnecessary).  The engine's
-        failure paths use this so a dead engine never reports phantom
-        in-flight work."""
-        self._active[:] = False
-        self._free = list(range(self.n_slots))
-
-    @property
-    def free_count(self) -> int:
-        return len(self._free)
-
-    @property
-    def active_count(self) -> int:
-        return int(self._active.sum())
-
-    @property
-    def occupancy(self) -> float:
-        return self.active_count / self.n_slots
-
-    def active_mask(self) -> np.ndarray:
-        """(S,) bool — a COPY, safe to hand to jit."""
-        return self._active.copy()
-
-    def positions(self) -> np.ndarray:
-        return np.asarray(self.cache["pos"])
-
-    # -- device ops ---------------------------------------------------------
-
-    def insert(self, slot: int, prefilled: Dict) -> None:
-        """Write a batch-1 prefilled cache into ``slot`` (which must be
-        allocated) and adopt its position."""
-        if not self._active[slot]:
-            raise ValueError(f"slot {slot} is not allocated")
-        self.cache = self._insert(self.cache, slot, prefilled)
-
-    def insert_batch(self, slots, prefilled: Dict) -> None:
-        """Write a batch-K prefilled cache (per-row ``true_len``
-        prefill) into K allocated slots — row ``i`` lands in
-        ``slots[i]`` — and adopt the per-row positions.  ONE device
-        scatter for the whole admission group instead of K serial
-        inserts."""
-        for s in slots:
-            if not self._active[s]:
-                raise ValueError(f"slot {s} is not allocated")
-        self.cache = self._insert_batch(
-            self.cache, np.asarray(slots, np.int32), prefilled)
-
-
-# --- paged layout (block allocator + page tables) -----------------------------
-#
-# The slot-contiguous layout above reserves max_len x S positions up
-# front, so occupancy is bounded by the WORST-CASE request and mixed
-# lengths fragment HBM.  The paged layout (PagedAttention, Kwon et al.,
-# SOSP 2023) stores K/V as a pool of fixed-size pages; each slot owns an
-# int32 page-table row, resolved INSIDE the compiled decode tick
-# (models/transformer.py:decode_step_paged) — page tables are DATA, not
-# structure, so allocation patterns never recompile anything.  Page 0 is
-# the reserved NULL/trash page: never granted, the routing target for
-# inactive rows' writes and unpopulated table entries.
 
 NULL_PAGE = 0
 
@@ -368,9 +210,9 @@ def gather_prefix_pages(pool: Dict, pages):
 
 class PagedSlotCache:
     """Host-side page allocator + slot bookkeeping over one device page
-    pool.  API-compatible with :class:`SlotCache` where the engine
-    touches it (alloc/free/active_mask/occupancy/...), plus the paging
-    surface: per-slot page tables (:attr:`table`, uploaded as tick
+    pool.  The slot surface is what the engine's admission and
+    retirement touch (alloc/free/active_mask/occupancy/...); the paging
+    surface is per-slot page tables (:attr:`table`, uploaded as tick
     data; :attr:`table_version` bumps on every change so the engine
     re-uploads only then), a heapq free list of pages, REFCOUNTED pages
     for prefix sharing (:meth:`attach` / :meth:`grant_raw`), and
@@ -380,9 +222,8 @@ class PagedSlotCache:
     Freed pages are NOT scrubbed: a page's next owner writes every
     position before first attending it (prefill landing covers the
     prompt span; decode writes position ``p`` the same tick it first
-    attends ``p``) — the slot-contiguous write-before-attend argument,
-    re-proven per page by the no-contamination test in
-    ``tests/test_paged.py``.
+    attends ``p``) — the write-before-attend argument, proven per page
+    by the no-contamination test in ``tests/test_paged.py``.
 
     A configuration with window layers holds TWO instances side by side
     (``serving.engine``): the full layers' (``n_layers`` = their count)
@@ -407,8 +248,8 @@ class PagedSlotCache:
         self.max_pages = -(-self.max_len // page_size)
         self.n_layers = cfg.n_layers if n_layers is None else n_layers
         self.window = window
-        # 0 = capacity parity with the slot-contiguous layout (every
-        # slot can grow to max_len, or to its window's bound); a
+        # 0 = every slot can grow to max_len (or to its window's
+        # bound) at once; a
         # smaller pool is the whole point — mixed-length traffic rarely
         # needs worst case, and the admission back-pressure handles the
         # tail.
@@ -448,9 +289,10 @@ class PagedSlotCache:
             lambda pool, s, v: {**pool, "pos": pool["pos"].at[s].set(v)},
             donate_argnums=(0,))
 
-    # -- slot allocation (SlotCache-compatible) -----------------------------
+    # -- slot allocation: lowest free index first, O(log S) an op ------------
 
     def alloc(self) -> Optional[int]:
+        """Lowest free slot index, or ``None`` when every slot is held."""
         if not self._free:
             return None
         slot = heapq.heappop(self._free)

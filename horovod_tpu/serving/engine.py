@@ -2,11 +2,13 @@
 
 The paper's core move — a background controller that fuses pending work
 from many independent callers into one efficient device operation —
-applied to decoding: ONE compiled ``decode_step_slots`` executable stays
-hot over a fixed pool of S cache slots, and new requests land in freed
-slots between ticks via a bucketed single-request prefill +
-``insert_prefill``, with zero recompilation of the decode step (the
-live set is data — an ``(S,)`` active mask — not structure).
+applied to decoding: ONE compiled ``decode_step_paged`` executable stays
+hot over a fixed set of S slots whose K/V lives in a pool of fixed-size
+pages (:class:`~horovod_tpu.serving.cache.PagedSlotCache`), and new
+requests land in freed slots between ticks via a bucketed prefill
+scattered into the slot's pages, with zero recompilation of the decode
+step (the live set and the page tables are data — an ``(S,)`` active
+mask and an ``(S, max_pages)`` table — not structure).
 
 Tick loop (:meth:`InferenceEngine.step`):
 
@@ -17,7 +19,7 @@ Tick loop (:meth:`InferenceEngine.step`):
    power-of-two bucket, per-row ``true_len``; compile set bounded by
    buckets x K), whose last-real-position logits yield each request's
    FIRST token immediately.
-2. **Decode**: one masked ``decode_step_slots`` over all S slots;
+2. **Decode**: one masked ``decode_step_paged`` over all S slots;
    inactive slots compute on zeros (Join-style).  Each active slot's
    next greedy token streams to its future; EOS / max-token / capacity
    retirement frees the slot for the next admission.
@@ -48,7 +50,7 @@ supervision):
 
 * **Supervised tick loop with DURABLE requests** — any exception out
   of :meth:`step` triggers a supervised restart: fresh
-  :class:`SlotCache` (the device cache is suspect after a failure),
+  :class:`PagedSlotCache` (the device cache is suspect after a failure),
   bounded consecutive attempts with exponential backoff,
   ``engine_restarts`` counter.  With ``EngineConfig.resume`` (the
   default) in-flight requests SURVIVE the restart: their decode state
@@ -102,12 +104,11 @@ import numpy as np
 
 from horovod_tpu.models import transformer as T
 from horovod_tpu.obs import tracing as obs_tracing
+from horovod_tpu.ops import _pallas_util
 from horovod_tpu.ops import paged_attention as _pa
 from horovod_tpu.serving.cache import (  # noqa: F401
     NULL_PAGE,
     PagedSlotCache,
-    SlotCache,
-    init_slot_cache,
     resolve_kv_dtype,
 )
 from horovod_tpu.serving.faults import FaultInjector
@@ -325,11 +326,11 @@ class EngineConfig:
     synchronous A/B baseline: fetch-and-apply in the same step, same
     tokens, ~the device wait slower per tick.
 
-    Paged KV cache (``paged``, default on — docs/serving.md "Paged KV
-    cache"): K/V live in a pool of ``n_pages`` fixed-size pages
-    (``page_size`` tokens each; ``n_pages=0`` sizes the pool for
-    capacity parity with the slot-contiguous layout, smaller pools
-    trade worst-case capacity for admission headroom), resolved
+    Paged KV cache (docs/serving.md "Paged KV cache"): K/V live in a
+    pool of ``n_pages`` fixed-size pages (``page_size`` tokens each;
+    ``n_pages=0`` gives every slot its ``max_len`` worth of pages,
+    smaller pools trade worst-case capacity for admission headroom),
+    resolved
     through per-slot page tables INSIDE the one compiled tick.  Pages
     are granted on demand at tick boundaries, refcounted for prefix
     sharing (:meth:`InferenceEngine.register_prefix`), and
@@ -337,8 +338,9 @@ class EngineConfig:
     into it.  ``kv_dtype`` selects page storage: None = the model
     dtype, "bf16" halves f32 cache bytes (exact for bf16 models),
     "int8" quarters them (per-vector scales, dequantize-on-attend —
-    lossy).  ``paged=False`` keeps the slot-contiguous
-    :class:`SlotCache` — the A/B oracle baseline.
+    lossy).  ``paged`` accepts only ``True`` (the slot-contiguous
+    cache went in PR 28) and goes when the benchmark's configuration
+    files stop passing it.
 
     Fault tolerance: ``max_restarts`` bounds CONSECUTIVE supervised
     restarts before the engine goes terminally ``failed`` (a clean tick
@@ -410,7 +412,7 @@ class EngineConfig:
     # executables: chunked prefill, speculative verify, sampling
     # columns, journal/resume, and SSE failover compose unchanged, and
     # output is token-identical to the tp=1 oracle.  Requires
-    # paged=True, n_heads % tp == 0 and kv_heads % tp == 0 (typed
+    # n_heads % tp == 0 and kv_heads % tp == 0 (typed
     # ShardingConfigError at construction), and tp visible devices
     # (CPU: XLA_FLAGS=--xla_force_host_platform_device_count=N).
     tp: int = 1
@@ -430,7 +432,7 @@ class EngineConfig:
     # The final chunk's last-position logits are bit-identical to a
     # whole-prompt prefill's, so greedy AND sampled output is
     # token-identical to the un-chunked oracle.  0 disables (whole
-    # prompts, the historical behavior); requires ``paged=True``.
+    # prompts, the historical behavior).
     prefill_chunk_tokens: int = 0
     # Speculative decoding (docs/serving.md "Speculative decoding"):
     # draft spec_k tokens per active slot inside the compiled tick,
@@ -438,7 +440,7 @@ class EngineConfig:
     # prefix plus the target's correction token — 1..spec_k+1 tokens
     # per slot per tick, byte-identical to plain greedy decode (the
     # emitted tokens are always the target's own argmax picks; draft
-    # quality moves only the acceptance rate).  Requires paged=True.
+    # quality moves only the acceptance rate).
     # spec_draft: "model" (a shallower TransformerConfig sharing the
     # tokenizer, passed as InferenceEngine(draft_params=, draft_cfg=),
     # with its own slot-aligned paged KV pool), "ngram" (prompt-lookup
@@ -506,6 +508,13 @@ class EngineConfig:
     # counters into achieved FLOP/s in /stats — the honest utilization
     # number a router/capacity planner balances on.  None disables.
     model_flops_per_token: Optional[float] = None
+
+    def __post_init__(self):
+        if not self.paged:
+            raise ValueError(
+                "EngineConfig(paged=False): the slot-contiguous KV cache "
+                "was removed in PR 28; the engine has one cache, the "
+                "page pool (drop the argument)")
 
 
 @dataclasses.dataclass
@@ -576,11 +585,6 @@ class InferenceEngine:
         self.draft_params = draft_params
         self.draft_cfg = draft_cfg
         if self._spec:
-            if not engine_cfg.paged:
-                raise ValueError(
-                    "EngineConfig.speculative requires paged=True (the "
-                    "verify kernel resolves page tables inside the "
-                    "compiled tick)")
             if engine_cfg.spec_k < 1:
                 raise ValueError(
                     f"spec_k must be >= 1, got {engine_cfg.spec_k}")
@@ -602,28 +606,20 @@ class InferenceEngine:
                         f"draft model must share the tokenizer: vocab "
                         f"{draft_cfg.vocab_size} != {cfg.vocab_size}")
             self._spec_model = mode == "model"
-        if engine_cfg.prefill_chunk_tokens:
-            if not engine_cfg.paged:
-                raise ValueError(
-                    "EngineConfig.prefill_chunk_tokens requires "
-                    "paged=True (chunks attend the already-landed "
-                    "pages through prefill_with_prefix)")
-            if engine_cfg.prefill_chunk_tokens < 1:
-                raise ValueError(
-                    f"prefill_chunk_tokens must be >= 1 (or 0 to "
-                    f"disable), got {engine_cfg.prefill_chunk_tokens}")
+        if engine_cfg.prefill_chunk_tokens < 0:
+            raise ValueError(
+                f"prefill_chunk_tokens must be >= 1 (or 0 to disable), "
+                f"got {engine_cfg.prefill_chunk_tokens}")
         if cfg.has_window:
             # Two kinds of KV state live side by side only where they
             # are written: the paged single-chip tick.  Every other
             # mode refuses the configuration here, typed — none may
             # run it as another model.
             refused = [why for on, why in (
-                (not engine_cfg.paged, "paged=False (the slot-contiguous "
-                 "cache has one kind of state)"),
                 (engine_cfg.tp > 1, "tp > 1"),
                 (self._spec, "speculative=True"),
-                (engine_cfg.paged and resolve_kv_dtype(
-                    cfg, engine_cfg.kv_dtype)[1], "kv_dtype='int8'"),
+                (resolve_kv_dtype(cfg, engine_cfg.kv_dtype)[1],
+                 "kv_dtype='int8'"),
             ) if on]
             if refused:
                 raise T.UnsupportedModelConfigError(
@@ -643,11 +639,6 @@ class InferenceEngine:
             raise ShardingConfigError(
                 f"EngineConfig.tp must be >= 1, got {engine_cfg.tp}")
         if engine_cfg.tp > 1:
-            if not engine_cfg.paged:
-                raise ShardingConfigError(
-                    "EngineConfig.tp > 1 requires paged=True (the tp "
-                    "mesh shards the paged KV pool by head; the "
-                    "slot-contiguous A/B cache stays single-device)")
             self._shard = ServingSharding(
                 cfg, engine_cfg.tp,
                 draft_cfg=draft_cfg if self._spec_model else None)
@@ -737,15 +728,6 @@ class InferenceEngine:
         self._tuner = None
         self._warmed = False
 
-        # Tensor-parallel in/out shardings for every executable below
-        # (all None on a single-device engine).  The placement rule:
-        # params and the page pool carry their head-sharded placements;
-        # EVERYTHING the host uploads or fetches (tokens, masks,
-        # tables, sampling columns, logits, acceptance) is pinned
-        # REPLICATED.  Explicit shardings keep executable signatures
-        # stable — a fed-back committed output and a fresh host upload
-        # hit the same compiled program — so the zero-decode-recompile
-        # guard holds under tp unchanged.
         # Fused paged-attention kernel engagement (paged_kernel knob):
         # decided HERE, once, to a Python bool the tick bodies below
         # close over at trace time — so engagement can never cause a
@@ -758,33 +740,38 @@ class InferenceEngine:
         # reference path.  Under the CPU interpreter any layout runs,
         # and auto stays on the unfused XLA tick (the interpreter is
         # faithful but slow) while tests opt in with paged_kernel=True.
-        self._paged_kernel = False
-        if engine_cfg.paged:
-            from horovod_tpu.ops import _pallas_util
-
-            layouts = [(self.slots._storage_dtype, engine_cfg.page_size,
-                        cfg.head_dim)]
-            if self._spec_model:
-                layouts.append((draft_cfg.dtype, engine_cfg.page_size,
-                                draft_cfg.head_dim))
-            compiled = not _pallas_util.use_interpret()
-            rejected = [lay for lay in layouts
-                        if not _pa.kernel_supported(*lay)] if compiled else []
-            want = engine_cfg.paged_kernel
-            if want and rejected:
-                dt, ps, dh = rejected[0]
-                raise _pa.UnsupportedPagedLayoutError(
-                    f"paged_kernel=True, but the TPU compiler cannot tile "
-                    f"a {jnp.dtype(dt).name} pool with page_size={ps}, "
-                    f"head_dim={dh} (needs head_dim % 128 == 0 and "
-                    f"page_size % 8/16/32 == 0 for f32/bf16/int8 "
-                    f"storage); use paged_kernel=None for the unfused "
-                    f"tick or change page_size")
-            self._paged_kernel = (compiled and not rejected
-                                  if want is None else bool(want))
+        layouts = [(self.slots._storage_dtype, engine_cfg.page_size,
+                    cfg.head_dim)]
+        if self._spec_model:
+            layouts.append((draft_cfg.dtype, engine_cfg.page_size,
+                            draft_cfg.head_dim))
+        compiled = not _pallas_util.use_interpret()
+        rejected = [lay for lay in layouts
+                    if not _pa.kernel_supported(*lay)] if compiled else []
+        want = engine_cfg.paged_kernel
+        if want and rejected:
+            dt, ps, dh = rejected[0]
+            raise _pa.UnsupportedPagedLayoutError(
+                f"paged_kernel=True, but the TPU compiler cannot tile "
+                f"a {jnp.dtype(dt).name} pool with page_size={ps}, "
+                f"head_dim={dh} (needs head_dim % 128 == 0 and "
+                f"page_size % 8/16/32 == 0 for f32/bf16/int8 "
+                f"storage); use paged_kernel=None for the unfused "
+                f"tick or change page_size")
+        self._paged_kernel = (compiled and not rejected
+                              if want is None else bool(want))
         _pk = self._paged_kernel
         _pk_mesh = self.mesh if (_pk and engine_cfg.tp > 1) else None
 
+        # Tensor-parallel in/out shardings for every executable below
+        # (all None on a single-device engine).  The placement rule:
+        # params and the page pool carry their head-sharded placements;
+        # EVERYTHING the host uploads or fetches (tokens, masks,
+        # tables, sampling columns, logits, acceptance) is pinned
+        # REPLICATED.  Explicit shardings keep executable signatures
+        # stable — a fed-back committed output and a fresh host upload
+        # hit the same compiled program — so the zero-decode-recompile
+        # guard holds under tp unchanged.
         shd = self._shard
         self._sh_R = _R = shd.replicated if shd else None
         self._sh_params = _psh = shd.param_shardings() if shd else None
@@ -799,14 +786,56 @@ class InferenceEngine:
         self._sh_prefix = _presh = (shd.prefix_kv_sharding()
                                     if shd else None)
 
-        if engine_cfg.paged and self._spec:
-            # The SPECULATIVE tick: draft -> one batched W-position
-            # verify -> accepted-prefix select, all device-resident.
-            # Shapes are static in S and W = spec_k + 1; the per-slot
-            # accepted length is DATA, so varying acceptance never
-            # recompiles.  The device-side next-token is the bonus/
-            # correction token t[s, acc[s]] — the overlap pipeline's
-            # tick N+1 input, no host round-trip.
+        # The PLAIN one-token tick, built once for every engine.  An
+        # expert model's tick also hands back its experts' load (three
+        # numbers, fetched with the tokens); a model with window layers
+        # takes the two kinds' tables as a pair.
+        _moe = cfg.n_experts > 1
+
+        def _tick(params, tokens, active, table, pool, s_t, s_k,
+                  s_p, s_key):
+            self._decode_traces += 1
+            # Runs once per (re)trace: this IS a compile event — count
+            # it and mark it on the active trace/timeline.
+            obs_tracing.record_compile("serving_decode")
+            pos = pool["pos"]
+            table, wtable = table if cfg.has_window else (table, None)
+            logits, pool, *load = T.decode_step_paged(
+                params, tokens, pool, table, self.cfg, active,
+                kernel=_pk, mesh=_pk_mesh, wtable=wtable,
+                return_moe_load=_moe)
+            # The pick — per-slot temperature/top-k/top-p COLUMNS and
+            # PRNG key ROWS, all data (greedy rows are temperature 0):
+            # no parameter mix ever retraces this body.
+            nxt, mx = self._pick(logits, pos, active, s_t, s_k, s_p,
+                                 s_key)
+            return (nxt, mx, pool, *load)
+
+        # Donate the pool: without it XLA keeps input AND output pools
+        # alive across the tick (2x the KV HBM — half the servable
+        # slots) and copies the whole pool every token.  (The page
+        # TABLE is not donated — it is host-owned tick data, like the
+        # active mask.)
+        self._tick_fn = self._jit(
+            _tick, donate=(4,),
+            in_s=shd and (_psh, _R, _R, _R, _poolsh, _R, _R, _R, _R),
+            out_s=shd and ((_R, _R, _poolsh) + ((_R,) if _moe else ())))
+
+        # A speculative engine's DRAFT/VERIFY tick rides beside it: a
+        # tick where no slot speculates (every request opted out, or
+        # spec_adaptive disabled them all) dispatches the plain tick
+        # above instead — the losing case pays plain-engine cost, not
+        # a W-wide verify for nothing.  Both executables are warmed by
+        # warmup(); per-slot acceptance and the mask are data, so the
+        # compile count stays constant at two.
+        self._spec_tick_fn = None
+        if self._spec:
+            # draft -> one batched W-position verify -> accepted-prefix
+            # select, all device-resident.  Shapes are static in S and
+            # W = spec_k + 1; the per-slot accepted length is DATA, so
+            # varying acceptance never recompiles.  The device-side
+            # next-token is the bonus/correction token t[s, acc[s]] —
+            # the overlap pipeline's tick N+1 input, no host round-trip.
             K = engine_cfg.spec_k
             if self._spec_model:
                 dcfg = draft_cfg
@@ -840,7 +869,7 @@ class InferenceEngine:
                     return (jnp.where(active, nxt, 0), t, mx, acc,
                             pool, dpool)
 
-                self._tick_fn = self._jit(
+                self._spec_tick_fn = self._jit(
                     _tick, donate=(7, 8),
                     in_s=shd and (_psh, _dpsh, _R, _R, _R, _R, _R,
                                   _poolsh, _dpoolsh, _R, _R, _R, _R),
@@ -877,94 +906,11 @@ class InferenceEngine:
                     return (jnp.where(active, nxt, 0), t, mx, acc,
                             pool, hist)
 
-                self._tick_fn = self._jit(
+                self._spec_tick_fn = self._jit(
                     _tick, donate=(5, 6),
                     in_s=shd and (_psh, _R, _R, _R, _R, _poolsh, _R,
                                   _R, _R, _R, _R),
                     out_s=shd and (_R, _R, _R, _R, _poolsh, _R))
-
-            # The PLAIN one-token executable rides alongside: a tick
-            # where no slot speculates (every request opted out, or
-            # spec_adaptive disabled them all) dispatches this instead
-            # — the losing case pays plain-engine cost, not a W-wide
-            # verify for nothing.  Both executables are warmed by
-            # warmup(); per-slot acceptance and the mask are data, so
-            # the compile count stays constant at two.
-            def _ptick(params, tokens, active, table, pool, s_t, s_k,
-                       s_p, s_key):
-                self._decode_traces += 1
-                obs_tracing.record_compile("serving_decode")
-                pos = pool["pos"]
-                logits, pool = T.decode_step_paged(
-                    params, tokens, pool, table, self.cfg, active,
-                    kernel=_pk, mesh=_pk_mesh)
-                nxt, mx = self._pick(logits, pos, active, s_t, s_k, s_p,
-                                     s_key)
-                return nxt, mx, pool
-
-            self._plain_tick_fn = self._jit(
-                _ptick, donate=(4,),
-                in_s=shd and (_psh, _R, _R, _R, _poolsh, _R, _R, _R, _R),
-                out_s=shd and (_R, _R, _poolsh))
-            donate = None
-        elif engine_cfg.paged:
-            # An expert model's tick also hands back its experts' load
-            # (three numbers, fetched with the tokens); a model with
-            # window layers takes the two kinds' tables as a pair.
-            _moe = cfg.n_experts > 1
-
-            def _tick(params, tokens, active, table, pool, s_t, s_k,
-                      s_p, s_key):
-                self._decode_traces += 1
-                obs_tracing.record_compile("serving_decode")
-                pos = pool["pos"]
-                table, wtable = (table if cfg.has_window
-                                 else (table, None))
-                logits, pool, *load = T.decode_step_paged(
-                    params, tokens, pool, table, self.cfg, active,
-                    kernel=_pk, mesh=_pk_mesh, wtable=wtable,
-                    return_moe_load=_moe)
-                # The sampled pick — per-slot temperature/top-k/top-p
-                # COLUMNS and PRNG key ROWS, all data: greedy rows
-                # (temperature 0) are the argmax of old, sampled rows
-                # draw with the position-folded key, and no parameter
-                # mix ever retraces this body (the zero-recompile
-                # guard covers sampling now too).
-                nxt, mx = self._pick(logits, pos, active, s_t, s_k, s_p,
-                                     s_key)
-                return (nxt, mx, pool, *load)
-
-            donate = 4
-        else:
-            def _tick(params, tokens, active, cache, s_t, s_k, s_p,
-                      s_key):
-                self._decode_traces += 1
-                # Runs once per (re)trace: this IS a compile event —
-                # count it and mark it on the active trace/timeline.
-                obs_tracing.record_compile("serving_decode")
-                pos = cache["pos"]
-                logits, cache = T.decode_step_slots(
-                    params, tokens, cache, self.cfg, active)
-                nxt, mx = self._pick(logits, pos, active, s_t, s_k, s_p,
-                                     s_key)
-                return nxt, mx, cache
-
-            donate = 3
-
-        # Donate the cache: without it XLA keeps input AND output caches
-        # alive across the tick (2x the KV HBM — half the servable
-        # slots) and copies the whole cache every token.  (The page
-        # TABLE is not donated — it is host-owned tick data, like the
-        # active mask.)  The speculative variants jit themselves above
-        # (their pool/draft-pool/history argnums differ).
-        if donate is not None:
-            self._tick_fn = self._jit(
-                _tick, donate=(donate,),
-                in_s=shd and (_psh, _R, _R, _R, _poolsh,
-                              _R, _R, _R, _R),
-                out_s=shd and ((_R, _R, _poolsh)
-                               + ((_R,) if cfg.n_experts > 1
-                                  and engine_cfg.paged else ())))
         self._prefill_fns: Dict[tuple, Callable] = {}
         self._prefill_traces = 0
         self._prefill_calls = 0  # prefill FORWARD PASSES (sharing hook)
@@ -984,35 +930,33 @@ class InferenceEngine:
         # kernel's walk covers for this pool (_count_paged_walk; the
         # kernel sees a tp shard's heads).
         self._step_tick = self._step_prefill = self._step_chunk = False
-        self._walk_block_tokens = 0
-        if engine_cfg.paged:
-            self._walk_block_tokens = self.slots.page_size * _pa.block_pages(
-                self.slots.page_size, cfg.kv_heads // engine_cfg.tp,
-                cfg.head_dim, self.slots._storage_dtype,
-                self.slots.max_pages)
+        self._walk_block_tokens = self.slots.page_size * _pa.block_pages(
+            self.slots.page_size, cfg.kv_heads // engine_cfg.tp,
+            cfg.head_dim, self.slots._storage_dtype,
+            self.slots.max_pages)
         # Registered shared prefixes (token tuple -> entry); epoch
         # stamps which cache lifetime the pinned pages belong to.
         self._prefixes: Dict[tuple, _PrefixEntry] = {}
         self._prefix_version = 0  # bumps on (un)register: match cache
         self._cache_epoch = 0
-        if engine_cfg.paged:
-            def _suffix_prefill(params, padded, lens, prefix, p0):
-                self._prefill_traces += 1
-                obs_tracing.record_compile("serving_prefill")
-                pk, pv, *win = prefix
-                kw = dict(zip(("win_k", "win_v", "win_start"), win))
-                return T.prefill_with_prefix(
-                    params, padded, pk, pv, p0, self.cfg, true_len=lens,
-                    **kw)
 
-            # jax.jit caches per (n_prefix_pages, bucket, k) shape; the
-            # prefix length p0 is a traced scalar, so prefixes of any
-            # length share the page-granular compile set.
-            self._suffix_prefill = self._jit(
-                _suffix_prefill,
-                in_s=shd and (_psh, _R, _R, (_presh, _presh), _R),
-                out_s=shd and (_R, _kvsh))
-            self._update_page_gauges()
+        def _suffix_prefill(params, padded, lens, prefix, p0):
+            self._prefill_traces += 1
+            obs_tracing.record_compile("serving_prefill")
+            pk, pv, *win = prefix
+            kw = dict(zip(("win_k", "win_v", "win_start"), win))
+            return T.prefill_with_prefix(
+                params, padded, pk, pv, p0, self.cfg, true_len=lens,
+                **kw)
+
+        # jax.jit caches per (n_prefix_pages, bucket, k) shape; the
+        # prefix length p0 is a traced scalar, so prefixes of any
+        # length share the page-granular compile set.
+        self._suffix_prefill = self._jit(
+            _suffix_prefill,
+            in_s=shd and (_psh, _R, _R, (_presh, _presh), _R),
+            out_s=shd and (_R, _kvsh))
+        self._update_page_gauges()
 
         # Speculative host state: the per-slot enablement mask (the
         # per-request opt-out, uploaded as DATA like the active mask),
@@ -1265,8 +1209,7 @@ class InferenceEngine:
             raise RequestTooLongError(
                 f"prompt ({len(prompt)}) + max_new_tokens ({n_new}) "
                 f"exceeds slot capacity ({cap})")
-        if (self.engine_cfg.paged
-                and self.slots.pages_for(len(prompt) + n_new - 1)
+        if (self.slots.pages_for(len(prompt) + n_new - 1)
                 > self.slots.n_pages):
             # Could NEVER run, even with the whole pool to itself — a
             # typed rejection now, not an admission stall forever.
@@ -1349,14 +1292,12 @@ class InferenceEngine:
         return jax.jit(fn, donate_argnums=donate,
                        in_shardings=in_s, out_shardings=out_s)
 
-    def _make_slots(self):
+    def _make_slots(self) -> PagedSlotCache:
         ec = self.engine_cfg
-        if ec.paged:
-            return PagedSlotCache(
-                self.cfg, ec.n_slots, ec.max_len, page_size=ec.page_size,
-                n_pages=ec.n_pages, kv_dtype=ec.kv_dtype, mesh=self.mesh,
-                n_layers=self.cfg.kind_count("full"))
-        return SlotCache(self.cfg, ec.n_slots, ec.max_len)
+        return PagedSlotCache(
+            self.cfg, ec.n_slots, ec.max_len, page_size=ec.page_size,
+            n_pages=ec.n_pages, kv_dtype=ec.kv_dtype, mesh=self.mesh,
+            n_layers=self.cfg.kind_count("full"))
 
     def _make_window_slots(self) -> Optional[PagedSlotCache]:
         """The WINDOW layers' page pool of a configuration that has
@@ -1416,10 +1357,7 @@ class InferenceEngine:
         prefill.  A request whose prompt IS the prefix admits with no
         prefill at all (the first greedy token is cached here).  Pages
         stay pinned across slot churn; a supervised restart invalidates
-        the entry, which lazily re-prefills on next use.  Requires a
-        paged engine."""
-        if not self.engine_cfg.paged:
-            raise ValueError("prefix sharing requires EngineConfig.paged")
+        the entry, which lazily re-prefills on next use."""
         if self.wslots is not None:
             raise T.UnsupportedModelConfigError(
                 "prefix sharing is not written for window layers' pages "
@@ -1563,13 +1501,11 @@ class InferenceEngine:
     def _group_key(self, req: Request):
         """Admission-group key for :meth:`Scheduler.take`: groups must
         share one prefill executable, so the key is the prompt bucket —
-        and, when paged, the matched prefix (one shared-prefix gather +
-        suffix prefill serves the whole group) with the SUFFIX bucket.
+        and the matched prefix (one shared-prefix gather + suffix
+        prefill serves the whole group) with the SUFFIX bucket.
         A CHUNKED request is taken ALONE (singleton key): its
         ingestion spans many ticks and shares no prefill shape with
         anyone."""
-        if not self.engine_cfg.paged:
-            return self._bucket(len(req.prompt))
         if self._chunked(req):
             return ("chunk", req.id)
         entry = self._matched_prefix(req)
@@ -2145,28 +2081,16 @@ class InferenceEngine:
         per-slot accepted length ``acc`` ``(S,)``, and the dispatch-
         time speculation mask."""
         s_t, s_k, s_p, s_key = self._samp.device()
-        if self._spec:
-            if not self._dev_spec_host.any():
-                # Nobody speculating this tick: the plain one-token
-                # executable earns the same greedy token at plain cost.
-                # Draft state (history / draft cache) goes stale for
-                # the slots it skips — marked for rebuild at re-probe.
-                self._spec_stale |= self.slots.active_mask()
-                nxt, mx, cache = self._plain_tick_fn(
-                    self.params, tokens_dev, active_dev,
-                    self._dev_table, self.slots.cache,
-                    s_t, s_k, s_p, s_key)
-                self.slots.cache = cache
-                return nxt, {"nxt": nxt, "mx": mx}
+        if self._spec and self._dev_spec_host.any():
             if self._spec_model:
-                nxt, t, mx, acc, pool, dpool = self._tick_fn(
+                nxt, t, mx, acc, pool, dpool = self._spec_tick_fn(
                     self.params, self.draft_params, tokens_dev,
                     active_dev, self._dev_spec, self._dev_table,
                     self._dev_dtable, self.slots.cache,
                     self.draft_slots.cache, s_t, s_k, s_p, s_key)
                 self.draft_slots.cache = dpool
             else:
-                nxt, t, mx, acc, pool, hist = self._tick_fn(
+                nxt, t, mx, acc, pool, hist = self._spec_tick_fn(
                     self.params, tokens_dev, active_dev, self._dev_spec,
                     self._dev_table, self.slots.cache,
                     self._history(), s_t, s_k, s_p, s_key)
@@ -2174,30 +2098,28 @@ class InferenceEngine:
             self.slots.cache = pool
             return nxt, {"nxt": t, "mx": mx, "acc": acc,
                          "spec": self._dev_spec_host.copy()}
-        if self.engine_cfg.paged:
-            pool = self.slots.cache
-            if self.wslots is not None:
-                pool = {**pool, "wk": self.wslots.cache["k"],
-                        "wv": self.wslots.cache["v"]}
-            nxt, mx, cache, *load = self._tick_fn(
-                self.params, tokens_dev, active_dev, self._dev_table,
-                pool, s_t, s_k, s_p, s_key)
-            if self.wslots is not None:
-                self.wslots.cache = {**self.wslots.cache,
-                                     "k": cache.pop("wk"),
-                                     "v": cache.pop("wv")}
-            self.slots.cache = cache
-            return nxt, {"nxt": nxt, "mx": mx,
-                         **({"moe": load[0]} if load else {})}
-        nxt, mx, cache = self._tick_fn(
-            self.params, tokens_dev, active_dev, self.slots.cache,
-            s_t, s_k, s_p, s_key)
+        if self._spec:
+            # Nobody speculating this tick: the plain one-token
+            # executable earns the same greedy token at plain cost.
+            # Draft state (history / draft cache) goes stale for the
+            # slots it skips — marked for rebuild at re-probe.
+            self._spec_stale |= self.slots.active_mask()
+        pool = self.slots.cache
+        if self.wslots is not None:
+            pool = {**pool, "wk": self.wslots.cache["k"],
+                    "wv": self.wslots.cache["v"]}
+        nxt, mx, cache, *load = self._tick_fn(
+            self.params, tokens_dev, active_dev, self._dev_table,
+            pool, s_t, s_k, s_p, s_key)
+        if self.wslots is not None:
+            self.wslots.cache = {**self.wslots.cache,
+                                 "k": cache.pop("wk"),
+                                 "v": cache.pop("wv")}
         self.slots.cache = cache
-        return nxt, {"nxt": nxt, "mx": mx}
+        return nxt, {"nxt": nxt, "mx": mx,
+                     **({"moe": load[0]} if load else {})}
 
     def _update_page_gauges(self) -> None:
-        if not self.engine_cfg.paged:
-            return
         # Statics re-asserted too: benchmarks swap in a fresh
         # ServingMetrics after warmup, which would otherwise zero them.
         self.metrics.kv_pages_total.set(self.slots.n_pages)
@@ -2394,34 +2316,24 @@ class InferenceEngine:
             # better-class arrival claims a slot from the worst occupant
             # (suspended, never lost) instead of waiting out its decode.
             preempted = self._preempt_for_slots()
-            pages_fn = None
-            if self.engine_cfg.paged:
-                # Page back-pressure: the take stops (requests WAIT,
-                # scheduling order intact) when the next admission's
-                # private pages would overdraw the free heap — typed
-                # starvation-free admission control instead of silent
-                # over-allocation.
-                budget = self.slots.free_pages
-                # Clamp the plan to the deepest the free heap can ever get
-                # (pool minus registry-pinned prefix pages): the plan's
-                # growth-margin page is a heuristic, and an unclamped
-                # demand above that depth would park a request the
-                # submit-time fit check accepted at the FCFS head FOREVER
-                # — admit it when the pool is as free as it gets and let
-                # on-demand grant/preemption resolve the tail instead.
-                pinned = sum(
-                    len(e.pages) for e in self._prefixes.values()
-                    if e.pages is not None and e.epoch == self._cache_epoch)
-                attainable = max(self.slots.n_pages - pinned, 1)
-                reserved = 0
-
-                def pages_fn(req):
-                    nonlocal reserved
-                    need = min(self._plan_pages(req), attainable)
-                    if reserved + need > budget:
-                        return False
-                    reserved += need
-                    return True
+            # Page back-pressure: the take stops (requests WAIT,
+            # scheduling order intact) when the next admission's
+            # private pages would overdraw the free heap — typed
+            # starvation-free admission control instead of silent
+            # over-allocation.
+            budget = self.slots.free_pages
+            # Clamp the plan to the deepest the free heap can ever get
+            # (pool minus registry-pinned prefix pages): the plan's
+            # growth-margin page is a heuristic, and an unclamped
+            # demand above that depth would park a request the
+            # submit-time fit check accepted at the FCFS head FOREVER
+            # — admit it when the pool is as free as it gets and let
+            # on-demand grant/preemption resolve the tail instead.
+            pinned = sum(
+                len(e.pages) for e in self._prefixes.values()
+                if e.pages is not None and e.epoch == self._cache_epoch)
+            attainable = max(self.slots.n_pages - pinned, 1)
+            reserved = 0
 
             # Per-tick prefill TOKEN budget (chunked prefill): admissions
             # past the first stop once the tick's ingestion budget is
@@ -2434,9 +2346,11 @@ class InferenceEngine:
             n_admit = 0
 
             def admit_fn(req):
-                nonlocal n_admit
-                if pages_fn is not None and not pages_fn(req):
+                nonlocal n_admit, reserved
+                need = min(self._plan_pages(req), attainable)
+                if reserved + need > budget:
                     return False
+                reserved += need
                 if tok_budget:
                     cost = self._prefill_cost(req)
                     if n_admit and self._tick_prefill_spent + cost \
@@ -2452,7 +2366,7 @@ class InferenceEngine:
 
             reqs = self.scheduler.take(
                 self.slots.free_count, bucket_fn=self._group_key,
-                admit_fn=admit_fn if (pages_fn or tok_budget) else None)
+                admit_fn=admit_fn)
             if not reqs and self.scheduler.depth \
                     and self.engine_cfg.resume and self.journal is not None:
                 # PAGE-pressure preemption: an empty take with a non-empty
@@ -2538,8 +2452,7 @@ class InferenceEngine:
         fetch yields the K first tokens (prefill logits ARE the first
         greedy step).  The scheduler's bucket-uniform take keeps the
         group on one bucket, so the compile set is buckets x K."""
-        if (self.engine_cfg.paged and len(reqs) == 1
-                and self._chunked(reqs[0])):
+        if len(reqs) == 1 and self._chunked(reqs[0]):
             # Long prompt: chunked ingestion (singleton group by
             # construction of _group_key) — it rides the tick, it
             # does not stall it.
@@ -2568,15 +2481,11 @@ class InferenceEngine:
                 req.trace.admitted_at = t_adm
                 self.metrics.observe_queue_wait(
                     req.priority, t_adm - req.submitted_at)
-        if self.engine_cfg.paged:
-            slots, reqs, firsts, synced = self._admit_paged(reqs)
-            if not reqs:
-                return
-        else:
-            slots, reqs, firsts = self._admit_contiguous(reqs)
-            synced = True
+        slots, reqs, firsts, synced = self._admit_paged(reqs)
+        if not reqs:
+            return
         if synced:
-            # Attach-only paged admission (prompt == prefix) fetches
+            # Attach-only admission (prompt == prefix) fetches
             # nothing — the counter tracks real blocking syncs only.
             self.metrics.host_syncs.inc()
         now = time.monotonic()
@@ -2616,29 +2525,6 @@ class InferenceEngine:
                 mask[slot] = True
             self._dev_tokens = self._merge_tokens(
                 self._dev_tokens, jnp.asarray(vals), jnp.asarray(mask))
-
-    def _admit_contiguous(self, reqs: List[Request]):
-        """Slot-contiguous admission: one batch-K prefill + one
-        insert scatter (the pre-paging layout, kept as the A/B
-        oracle)."""
-        k = len(reqs)
-        bucket = max(self._bucket(len(r.prompt)) for r in reqs)
-        padded = np.zeros((k, bucket), np.int32)
-        lens = np.zeros((k,), np.int32)
-        for i, req in enumerate(reqs):
-            padded[i, :len(req.prompt)] = req.prompt
-            lens[i] = len(req.prompt)
-        logits, pre_cache = self._prefill_fn(bucket, k)(
-            self.params, jnp.asarray(padded), jnp.asarray(lens))
-        self._count_prefill(int(lens.sum()), k, bucket)
-        slots: List[int] = []
-        for _ in reqs:
-            slot = self.slots.alloc()
-            assert slot is not None  # take() is bounded by free_count
-            slots.append(slot)
-        self.slots.insert_batch(slots, pre_cache)
-        firsts = self._first_tokens(reqs, logits)  # one sync for K
-        return slots, reqs, firsts
 
     def _alloc_slot(self) -> int:
         """A free slot (``take()`` is bounded by ``free_count``), taken
@@ -3095,11 +2981,11 @@ class InferenceEngine:
         return True
 
     def _prepare_tick_pages(self) -> None:
-        """Tick-boundary page maintenance of a paged engine with live
+        """Tick-boundary page maintenance of an engine with live
         slots (the ``page_prep`` phase): grants, COWs and table uploads
         — host bookkeeping plus async uploads, nothing blocks on the
         device; a preemption here is never dispatched."""
-        if self.engine_cfg.paged and self.slots.active_count:
+        if self.slots.active_count:
             with self._phase("page_prep"):
                 if self._spec:
                     self._prepare_spec_tick()   # window grants
@@ -3109,8 +2995,7 @@ class InferenceEngine:
     def _dispatch_tick(self, tokens_dev, active_dev, active: np.ndarray):
         """What BOTH loops do inside ``tick_dispatch``: count the walk,
         dispatch, advance the dispatch-time position mirror."""
-        if self.engine_cfg.paged:
-            self._count_paged_walk(active)
+        self._count_paged_walk(active)
         nxt, extra = self._run_tick(tokens_dev, active_dev)
         if not self._spec:
             # Speculative ticks advance the mirror at FETCH (the
@@ -3192,7 +3077,7 @@ class InferenceEngine:
         EOS, and a freed slot can never leak a token into its next
         tenant.  The stale row's device write is harmless by the same
         write-before-attend argument as bucketed prefill padding
-        (``decode_step_slots``).  (In the synchronous path the snapshot
+        (``decode_step_paged``).  (In the synchronous path the snapshot
         always matches — nothing can retire a slot between dispatch and
         this call within one locked step.)"""
         faults = self.engine_cfg.faults
@@ -3456,7 +3341,7 @@ class InferenceEngine:
         so a crash costs one tick plus one re-prefill instead of the
         request; without it (or at a terminal failure) they fail with
         the typed error, as before.  Either way the engine restarts
-        (fresh SlotCache, exponential backoff) or goes terminally
+        (fresh PagedSlotCache, exponential backoff) or goes terminally
         ``failed`` when ``max_restarts`` consecutive attempts are
         spent."""
         if not isinstance(exc, EngineFailedError):
@@ -3562,7 +3447,7 @@ class InferenceEngine:
                 self.metrics.queue_depth.set(self.scheduler.depth)
 
     def _restart(self) -> None:
-        """Fresh SlotCache + slot bookkeeping (the old device cache is
+        """Fresh PagedSlotCache + slot bookkeeping (the old device cache is
         suspect after a failure); queued requests survive and are
         admitted by the next tick.  Caller holds ``_lock``.
 
@@ -3581,9 +3466,7 @@ class InferenceEngine:
         # died with the old cache — bump the epoch so entries lazily
         # re-prefill (once) on their next use.
         self._cache_epoch += 1
-        if self.engine_cfg.paged:
-            self.metrics.kv_pages_free.set(self.slots.free_pages)
-            self.metrics.kv_pages_shared.set(0)
+        self._update_page_gauges()
         with self._hb_lock:
             self._epoch += 1
             self._stalled = False
@@ -3982,7 +3865,7 @@ class InferenceEngine:
             # (bucket, batch) shape pairs the prefill has compiled for
             # — bounded by buckets x max_prefills_per_tick.
             "prefill_buckets": sorted(self._prefill_fns),
-            "paged": self.engine_cfg.paged,
+            "paged": True,  # the only cache since PR 28; readers exist
             # SLO scheduling (docs/serving.md "Scheduling"): the chunk
             # budget (0 = whole-prompt prefill) and how many slots are
             # mid-ingestion right now; per-class TTFT/queue-wait and
@@ -4005,19 +3888,17 @@ class InferenceEngine:
                     self.draft_slots.free_pages
                     if self.draft_slots is not None else None,
             } if self._spec else {}),
-            **({
-                "page_size": self.slots.page_size,
-                "kv_dtype": str(jnp.dtype(self.slots._storage_dtype).name),
-                "kv_pages_high_water": self.slots.pages_high_water,
-                "kv_window_pages_per_slot_bound":
-                    self.wslots.window_pages_bound
-                    if self.wslots is not None else 0,
-                "prefixes_registered": len(self._prefixes),
-                # Whether the decode/draft/verify ticks were built on
-                # the fused Pallas paged-attention kernel — what RAN,
-                # not the flag: resolved at construction from
-                # EngineConfig.paged_kernel AND the pool layout (see
-                # docs/serving.md "Paged decode kernel").
-                "paged_kernel_engaged": self._paged_kernel,
-            } if self.engine_cfg.paged else {}),
+            "page_size": self.slots.page_size,
+            "kv_dtype": str(jnp.dtype(self.slots._storage_dtype).name),
+            "kv_pages_high_water": self.slots.pages_high_water,
+            "kv_window_pages_per_slot_bound":
+                self.wslots.window_pages_bound
+                if self.wslots is not None else 0,
+            "prefixes_registered": len(self._prefixes),
+            # Whether the decode/draft/verify ticks were built on the
+            # fused Pallas paged-attention kernel — what RAN, not the
+            # flag: resolved at construction from
+            # EngineConfig.paged_kernel AND the pool layout (see
+            # docs/serving.md "Paged decode kernel").
+            "paged_kernel_engaged": self._paged_kernel,
         }

@@ -60,7 +60,7 @@ class ServingMetrics:
       including the server's 504 slot reclamation).
     * ``engine_failures`` / ``engine_restarts`` — fault-tolerance
       counters: every tick failure or watchdog stall, and every
-      successful supervised restart (fresh slot cache).
+      successful supervised restart (fresh page pool).
     * ``resumed`` / ``resume_wasted_tokens`` — durability counters
       (docs/serving.md "Operations"): in-flight requests re-admitted
       across a supervised restart with their futures still live, and
@@ -116,7 +116,7 @@ class ServingMetrics:
       KV cache (docs/serving.md "Paged KV cache"): pool size, free
       heap depth (admission headroom), pages referenced by >1 owner
       (prefix sharing in effect), and the per-token cache cost the
-      ``kv_dtype`` lever moves.  All 0 on a slot-contiguous engine.
+      ``kv_dtype`` lever moves.
       ``kv_window_pages_total`` / ``_free`` / ``_per_slot_max`` are
       the window layers' pool (0 without window layers): size, free
       heap, and the most pages one slot ever held at once.
@@ -180,7 +180,7 @@ class ServingMetrics:
             "Tick failures and watchdog stalls")
         self.engine_restarts = r.counter(
             "serving_engine_restarts_total",
-            "Successful supervised restarts (fresh slot cache)")
+            "Successful supervised restarts (fresh page pool)")
         self.tick_dispatch = r.histogram(
             "serving_tick_dispatch_seconds",
             "Time to build and dispatch one decode tick (async)",
@@ -269,7 +269,7 @@ class ServingMetrics:
             "Host sync points (blocking value fetches) on the decode path")
         self.kv_pages_total = r.gauge(
             "serving_kv_pages_total",
-            "KV page pool size (paged cache; 0 = slot-contiguous)")
+            "KV page pool size")
         self.kv_pages_free = r.gauge(
             "serving_kv_pages_free",
             "KV pages on the free heap (admission headroom)")

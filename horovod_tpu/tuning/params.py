@@ -166,13 +166,12 @@ def online_knob_space(engine) -> KnobSpace:
                 apply_path=f"EngineConfig replace; moves inside the "
                            f"warmed {bucket}-token chunk bucket"))
 
-    if cfg.paged:
-        knobs.append(Knob(
-            name="page_grant_ahead", default=cfg.page_grant_ahead,
-            kind="sweep",
-            candidates=tuple(sorted({cfg.page_grant_ahead, 0, 1, 2})),
-            apply_path="EngineConfig replace; page-table data only "
-                       "(_ensure_write_page grant-ahead span)"))
+    knobs.append(Knob(
+        name="page_grant_ahead", default=cfg.page_grant_ahead,
+        kind="sweep",
+        candidates=tuple(sorted({cfg.page_grant_ahead, 0, 1, 2})),
+        apply_path="EngineConfig replace; page-table data only "
+                   "(_ensure_write_page grant-ahead span)"))
 
     if getattr(engine, "_spec", False):
         knobs.append(Knob(

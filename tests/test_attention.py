@@ -191,11 +191,7 @@ class TestRingAttention:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-4, rtol=2e-4)
 
-    @pytest.mark.slow
     def test_differentiable(self):
-        # Slow (PR 17 budget pass): grad-of-sharded-ring compiles
-        # ~10 s; the forward-match params above stay tier-1, and the
-        # zigzag/ring-GQA gradient drills already run under -m slow.
         q, k, v = _qkv(b=1, h=1, s=N * 8, d=16)
 
         def loss(q, k, v):

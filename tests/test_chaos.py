@@ -139,7 +139,7 @@ class TestSupervisedRestart:
     def test_decode_raise_fails_inflight_and_restarts(self, model):
         """A device exception mid-decode resolves every in-flight
         future with a typed EngineFailedError, restarts the engine
-        (fresh SlotCache), and post-restart output is oracle-exact."""
+        (fresh PagedSlotCache), and post-restart output is oracle-exact."""
         params, cfg = model
         inj = serving.FaultInjector([
             serving.FaultSpec(site="decode_tick", kind="raise", skip=1)])

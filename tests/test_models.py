@@ -303,11 +303,7 @@ class TestDecode:
         with pytest.raises(ValueError, match="larger max_len"):
             T.prefill(params, jnp.zeros((1, 6), jnp.int32), cache, cfg)
 
-    @pytest.mark.slow
     def test_sample_decode_temperature_zero_is_greedy(self):
-        # Slow (PR 17 budget pass): compiles both decode paths, ~7 s;
-        # test_sampling keeps the temperature-0 == greedy property
-        # tier-1 at the engine level.
         cfg = self._cfg()
         params = T.init_params(jax.random.PRNGKey(0), cfg)
         prompt = jax.random.randint(jax.random.PRNGKey(2), (2, 4), 0, 64)

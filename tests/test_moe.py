@@ -427,11 +427,7 @@ class TestModelAuxLoss:
         _, daux = Td.forward(dparams, batch["tokens"], dcfg, return_aux=True)
         assert float(daux) == 0.0
 
-    @pytest.mark.slow
     def test_router_gradient_flows_from_aux(self):
-        # Slow (PR 17 budget pass): grad-of-model compile is ~5 s;
-        # test_loss_fn_adds_exactly_coeff_times_aux keeps the aux-loss
-        # contract tier-1 (the full training loop is already slow).
         """With every token hard-routed to one expert, the plain LM loss
         gives the router no balance pressure; the aux term must produce a
         router gradient pushing load off the overloaded expert."""

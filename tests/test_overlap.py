@@ -147,14 +147,8 @@ class TestOverlapOracle:
         assert outs[True][2] == outs[False][2] == _ref_greedy(
             params, cfg, [5, 6, 7, 8], 6)
 
-    @pytest.mark.slow
     def test_ab_across_restart(self, model):
-        """Slow (PR 17 budget pass): both-modes restart pair is
-        ~10 s; test_chaos's TestRestartResume keeps crash-resume
-        oracle-exactness tier-1 (overlap mode) and the sync-mode
-        restart rides the legacy test below's sibling set.
-
-        A mid-decode device fault in each mode: the in-flight
+        """A mid-decode device fault in each mode: the in-flight
         request RESUMES across the restart (journaled decode state,
         same future) and its output is oracle-exact in both modes —
         the pipeline state (device tokens, in-flight tick) is rebuilt

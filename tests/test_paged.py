@@ -1,13 +1,11 @@
 """Paged KV cache (serving/cache.py PagedSlotCache +
 models/transformer.py decode_step_paged / prefill_with_prefix).
 
-The gold check is the same A/B greedy oracle the slot-contiguous
-engine ships with, re-proven under paging: whatever the allocation
-pattern — page churn, on-demand growth, COW prefix sharing, int8/bf16
-storage — the paged engine's greedy output is token-identical to
-per-request ``greedy_decode`` AND to the unpaged engine at fixed
-config, with the decode executable compiled exactly once.  Page
-tables are data, never structure.
+The gold check is the greedy oracle: whatever the allocation pattern —
+page churn, on-demand growth, COW prefix sharing, int8/bf16 storage —
+the engine's greedy output is token-identical to per-request
+``greedy_decode``, with the decode executable compiled exactly once.
+Page tables are data, never structure.
 """
 
 import dataclasses
@@ -104,40 +102,38 @@ class TestPageAllocator:
         assert pc.n_pages == 3 * 5  # every slot can still grow to max_len
 
     def test_slot_free_list_is_fcfs_lowest(self, model):
-        # the heapq rewrite keeps SlotCache's allocation order contract
+        # lowest free index first — also after a paired pool's acquire
+        # took a slot out of the middle of the heap
         _, cfg = model
-        for cls in (serving.SlotCache, serving.PagedSlotCache):
-            slots = cls(cfg, 3, max_len=16)
-            assert [slots.alloc() for _ in range(3)] == [0, 1, 2]
-            slots.free(1), slots.free(0)
-            assert slots.alloc() == 0
+        slots = serving.PagedSlotCache(cfg, 4, max_len=16)
+        slots.acquire(1)
+        assert [slots.alloc() for _ in range(3)] == [0, 2, 3]
+        slots.free(2), slots.free(0)
+        assert slots.alloc() == 0
+        with pytest.raises(ValueError, match="already active"):
+            slots.acquire(3)
 
 
 class TestPagedOracle:
-    """ACCEPTANCE: paged greedy output == unpaged engine == per-request
-    greedy_decode at fixed config, decode compiled exactly once across
-    churn, growth, and sharing."""
+    """ACCEPTANCE: greedy output == per-request greedy_decode at fixed
+    config, decode compiled exactly once across churn, growth, and
+    sharing."""
 
     @pytest.mark.perf
     @pytest.mark.slow
-    def test_token_identity_vs_unpaged_engine(self, model):
+    def test_token_identity_vs_greedy_decode(self, model):
         params, cfg = model
         rng = np.random.default_rng(7)
         prompts = [rng.integers(0, cfg.vocab_size, n).tolist()
                    for n in (3, 9, 5, 12, 2, 7)]
         steps = 11
-        outs = {}
-        for paged in (False, True):
-            engine = _engine(params, cfg, paged=paged, n_slots=3,
-                             max_prefills_per_tick=2, max_queue_depth=8)
-            futs = [engine.submit(p, max_new_tokens=steps)
-                    for p in prompts]
-            _run_until_done(engine, futs)
-            outs[paged] = [f.result(timeout=0) for f in futs]
-            assert engine.decode_compilations == 1
-        assert outs[True] == outs[False]
-        for p, out in zip(prompts, outs[True]):
-            assert out == _ref_greedy(params, cfg, p, steps)
+        engine = _engine(params, cfg, n_slots=3,
+                         max_prefills_per_tick=2, max_queue_depth=8)
+        futs = [engine.submit(p, max_new_tokens=steps) for p in prompts]
+        _run_until_done(engine, futs)
+        assert engine.decode_compilations == 1
+        for p, f in zip(prompts, futs):
+            assert f.result(timeout=0) == _ref_greedy(params, cfg, p, steps)
 
     def test_growth_crosses_page_boundaries(self, model):
         """A long generation grows page by page (prompt 3 + 30 tokens:
@@ -178,13 +174,14 @@ class TestPagedOracle:
     @pytest.mark.slow
     def test_fragmentation_beats_slot_contiguous_ceiling(self, model):
         """SATELLITE: at a fixed HBM budget of 48 cache tokens
-        (page_size 8 x 6 pages), the slot-contiguous layout fits
+        (page_size 8 x 6 pages), a layout that reserves max_len a slot
+        (the slot-contiguous cache, gone in PR 28) fits
         floor(48 / max_len 40) = ONE worst-case slot; the paged engine
         runs FOUR short requests (each within one page) concurrently
         out of the same bytes."""
         params, cfg = model
         budget_tokens = 48
-        ceiling = budget_tokens // 40  # slot-contiguous: 1 request
+        ceiling = budget_tokens // 40  # max_len a slot: 1 request
         engine = _engine(params, cfg, n_slots=4, n_pages=6,
                          max_prefills_per_tick=4, max_queue_depth=8)
         rng = np.random.default_rng(3)
@@ -470,23 +467,19 @@ class TestQuantizedPages:
     @pytest.mark.slow
     def test_bf16_pages_token_identical_on_bf16_model(self):
         """ACCEPTANCE: with a bf16 model, bf16 page storage is the same
-        rounding the slot-contiguous cache applies — paged+bf16 output
-        is token-identical to the unpaged engine at fixed config."""
+        rounding the single-request cache of ``greedy_decode`` applies
+        — bf16 pages serve the oracle's tokens at fixed config."""
         cfg = _cfg(dtype=jnp.bfloat16)
         params = T.init_params(jax.random.PRNGKey(0), cfg)
         rng = np.random.default_rng(17)
         prompts = [rng.integers(0, cfg.vocab_size, n).tolist()
                    for n in (3, 7, 5)]
-        outs = {}
-        for name, kw in (("unpaged", dict(paged=False)),
-                         ("paged_bf16", dict(paged=True,
-                                             kv_dtype="bf16"))):
-            engine = _engine(params, cfg, n_slots=3,
-                             max_prefills_per_tick=2, **kw)
-            futs = [engine.submit(p, max_new_tokens=8) for p in prompts]
-            _run_until_done(engine, futs)
-            outs[name] = [f.result(timeout=0) for f in futs]
-        assert outs["paged_bf16"] == outs["unpaged"]
+        engine = _engine(params, cfg, n_slots=3, max_prefills_per_tick=2,
+                         kv_dtype="bf16")
+        futs = [engine.submit(p, max_new_tokens=8) for p in prompts]
+        _run_until_done(engine, futs)
+        for p, f in zip(prompts, futs):
+            assert f.result(timeout=0) == _ref_greedy(params, cfg, p, 8)
 
     def test_bf16_pages_halve_cache_bytes_on_f32_model(self, model):
         params, cfg = model
@@ -547,13 +540,8 @@ class TestBackPressure:
         for (p, s), f in zip(cases, futs):
             assert f.result(timeout=0) == _ref_greedy(params, cfg, p, s)
 
-    @pytest.mark.slow
     def test_whole_pool_request_admits_eventually(self, model):
-        """Slow (PR 17 budget pass): drain-the-pool wait is ~6 s;
-        test_decode_growth_exhaustion_preempts_youngest keeps the
-        pool-pressure admission path tier-1.
-
-        REGRESSION: a request whose prompt needs every page the pool
+        """REGRESSION: a request whose prompt needs every page the pool
         has — so the admission plan's margin heuristic (prompt pages
         + 1) exceeds n_pages outright — must still admit once the pool
         drains, not park the FCFS head (and everyone behind it)
@@ -677,26 +665,23 @@ class TestPagedObservability:
 
 
 class TestPagedDecodeKernel:
-    @pytest.mark.slow  # ~9 s eager rowwise A/B (PR 19 budget pass,
-    # DURATIONS.md); tier-1 siblings: test_growth_crosses_page_boundaries
-    # + test_inactive_rows_write_only_the_null_page below
-    def test_matches_slot_decode_rowwise(self, model):
-        """decode_step_paged row s == decode_step_slots row s for an
-        OUT-OF-ORDER page table — the indirection is exact."""
+    def test_matches_decode_step_rowwise(self, model):
+        """Row s of decode_step_paged == the single-request decode_step
+        at that slot's own position, for slots at DIFFERENT depths and
+        an OUT-OF-ORDER page table — the indirection is exact."""
         params, cfg = model
         ps, max_pages, S = 8, 6, 3
         P = 1 + S * max_pages
         pool = serving.init_page_pool(cfg, S, P, ps)
-        slots = serving.SlotCache(cfg, S, max_len=48)
         table = np.zeros((S, max_pages), np.int32)
         table[0, :3] = [5, 2, 9]
         table[1, :3] = [1, 7, 3]
         prompts = [[3, 4, 5, 6], [10, 11]]
+        singles = []
         for s, p in enumerate(prompts):
-            slots.alloc()
             _, pre = T.prefill(params, jnp.asarray([p], jnp.int32),
-                               T.init_cache(cfg, 1, len(p)), cfg)
-            slots.insert(s, pre)
+                               T.init_cache(cfg, 1, 16), cfg)
+            singles.append(pre)
             pool["pos"] = pool["pos"].at[s].set(len(p))
             for t in range(len(p)):
                 pg, off = table[s, t // ps], t % ps
@@ -706,16 +691,17 @@ class TestPagedDecodeKernel:
         active = jnp.asarray([True, True, False])
         tokens = jnp.asarray([7, 12, 0], jnp.int32)
         tab = jnp.asarray(table)
-        for _ in range(4):
-            ls, slots.cache = T.decode_step_slots(
-                params, tokens, slots.cache, cfg, active)
+        for _ in range(6):  # slot 0 crosses into its second page
             lp, pool = T.decode_step_paged(
                 params, tokens, pool, tab, cfg, active)
-            np.testing.assert_allclose(np.asarray(lp[:2]),
-                                       np.asarray(ls[:2]),
-                                       atol=1e-4, rtol=1e-4)
-            tokens = jnp.argmax(ls, -1).astype(jnp.int32)
-        assert np.asarray(pool["pos"]).tolist()[2] == 0  # inactive froze
+            for s in range(2):
+                ref, singles[s] = T.decode_step(
+                    params, tokens[s:s + 1], singles[s], cfg)
+                np.testing.assert_allclose(np.asarray(lp[s]),
+                                           np.asarray(ref[0]),
+                                           atol=1e-4, rtol=1e-4)
+            tokens = jnp.argmax(lp, -1).astype(jnp.int32)
+        assert np.asarray(pool["pos"]).tolist() == [10, 8, 0]
 
     def test_inactive_rows_write_only_the_null_page(self, model):
         """An inactive row's stale scatter must land in page 0 — under

@@ -237,22 +237,19 @@ class TestEngineSampling:
             "sampling parameter mix recompiled the decode tick"
 
     @pytest.mark.slow
-    def test_sync_and_contiguous_modes_match_oracle(self, model):
-        # Slow (PR 17 budget pass): builds two more engine variants,
-        # ~14 s; the default-mode (overlap+paged) oracle tests stay
-        # tier-1 and test_serving covers the sync/contiguous ticks.
+    def test_sync_mode_matches_oracle(self, model):
+        # Slow (PR 17 budget pass): builds one more engine variant; the
+        # default-mode (overlap) oracle tests stay tier-1 and
+        # test_serving covers the sync tick.
         params, cfg = model
-        for ec in (serving.EngineConfig(n_slots=4, max_len=32,
-                                        overlap=False, tick_timeout=0),
-                   serving.EngineConfig(n_slots=4, max_len=32,
-                                        paged=False, tick_timeout=0)):
-            eng = serving.InferenceEngine(params, cfg, ec)
-            eng.warmup([1, 4])
-            futs = [eng.submit(p, max_new_tokens=6, **kw)
-                    for p, kw in MIX[:3]]
-            _run(eng, futs)
-            for (p, kw), f in zip(MIX, futs):
-                assert f.result(1) == _oracle(params, cfg, p, 6, **kw)
+        eng = serving.InferenceEngine(params, cfg, serving.EngineConfig(
+            n_slots=4, max_len=32, overlap=False, tick_timeout=0))
+        eng.warmup([1, 4])
+        futs = [eng.submit(p, max_new_tokens=6, **kw)
+                for p, kw in MIX[:3]]
+        _run(eng, futs)
+        for (p, kw), f in zip(MIX, futs):
+            assert f.result(1) == _oracle(params, cfg, p, 6, **kw)
 
     def test_sampled_prefix_sharers_draw_own_tokens(self, model):
         """Attach-only admission (prompt == registered prefix) must

@@ -290,19 +290,10 @@ class TestDeadlineSweep:
 
 
 class TestChunkedPrefill:
-    def test_chunked_requires_paged(self, model):
-        params, cfg = model
-        with pytest.raises(ValueError):
-            serving.InferenceEngine(params, cfg, serving.EngineConfig(
-                paged=False, prefill_chunk_tokens=8))
-
-    @pytest.mark.slow
     def test_chunked_greedy_oracle_overlap(self, model):
         """Mixed long/short greedy traffic, chunked: token-identical
         to the whole-prompt oracle; ONE decode compile (chunk
-        boundaries are data).  Slow (PR 17 budget pass): the 4-prompt
-        mixed-length A/B is ~13 s; the sync-mode single-prompt oracle
-        below keeps the same property tier-1."""
+        boundaries are data)."""
         params, cfg = model
         engine = _engine(params, cfg, prefill_chunk_tokens=8)
         rng = np.random.default_rng(7)
@@ -326,14 +317,11 @@ class TestChunkedPrefill:
         assert fut.result(timeout=0) == _ref_greedy(params, cfg, p, 6)
         assert engine.decode_compilations == 1
 
-    @pytest.mark.slow
     def test_chunked_sampled_oracle(self, model):
         """A SAMPLED long prompt: the final chunk's logits feed the
         first draw at key index len(prompt), so the stream matches
         sample_decode exactly — chunking never touches the PRNG
-        schedule.  Slow (PR 17 budget pass): the greedy chunked
-        oracles here plus test_sampling's engine-level PRNG oracles
-        keep both halves of the property tier-1."""
+        schedule."""
         params, cfg = model
         engine = _engine(params, cfg, prefill_chunk_tokens=8)
         rng = np.random.default_rng(11)

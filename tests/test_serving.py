@@ -54,10 +54,12 @@ def _run_until_done(engine, futs, max_ticks=200):
     raise AssertionError("engine did not finish within the tick budget")
 
 
-class TestSlotCache:
+class TestSlots:
+    """The slot surface of the one KV cache (``PagedSlotCache``)."""
+
     def test_alloc_free_fcfs_lowest(self, model):
         _, cfg = model
-        slots = serving.SlotCache(cfg, 3, max_len=16)
+        slots = serving.PagedSlotCache(cfg, 3, max_len=16, page_size=8)
         assert [slots.alloc() for _ in range(3)] == [0, 1, 2]
         assert slots.alloc() is None and slots.free_count == 0
         slots.free(1)
@@ -67,74 +69,48 @@ class TestSlotCache:
         with pytest.raises(ValueError):
             slots.free(2), slots.free(2)
 
-    def test_insert_prefill_lands_in_slot(self, model):
+    def test_landed_prefill_reads_back_through_the_table(self, model):
+        """A prefilled block lands in the slot's granted pages — handed
+        out of order — and reads back through its table row as the
+        prefill's own K/V; the slot adopts the prompt's length and no
+        other slot's pages are touched."""
         params, cfg = model
-        prompt = jnp.asarray([[5, 9, 2]], jnp.int32)
-        pre_logits, pre = T.prefill(params, prompt,
-                                    T.init_cache(cfg, 1, 8), cfg)
-        slots = serving.SlotCache(cfg, 3, max_len=16)
+        prompt = jnp.asarray([[5, 9, 2, 7, 1, 3, 8, 4, 6, 2, 9]], jnp.int32)
+        n = prompt.shape[1]
+        _, pre = T.prefill(params, prompt, T.init_cache(cfg, 1, 16), cfg)
+        slots = serving.PagedSlotCache(cfg, 3, max_len=16, page_size=8,
+                                       n_pages=6)
         slots.alloc(), slots.alloc()
-        slots.insert(1, pre)
-        cache = slots.cache
+        held = slots.grant_raw(1)           # so slot 1's pages are 2, 3
+        slots.grant(1, 1), slots.grant(1, 0)
+        assert slots.table[1].tolist() == [3, 2]
+        slots.land([1], pre, [n])
+        k, v = slots.gather_prefix(slots.table[1])
         np.testing.assert_array_equal(
-            np.asarray(cache["k"][:, 1, :, :8]), np.asarray(pre["k"][:, 0]))
+            np.asarray(k[:, :, :n]), np.asarray(pre["k"][:, 0, :, :n]))
         np.testing.assert_array_equal(
-            np.asarray(cache["v"][:, 1, :, :8]), np.asarray(pre["v"][:, 0]))
-        assert slots.positions().tolist() == [0, 3, 0]
-        # untouched slots stay zero
-        assert not np.asarray(cache["k"][:, 0]).any()
+            np.asarray(v[:, :, :n]), np.asarray(pre["v"][:, 0, :, :n]))
+        assert slots.positions().tolist() == [0, n, 0]
+        # pages no slot was granted stay zero
+        assert not np.asarray(slots.cache["k"][:, held + [4, 5, 6]]).any()
 
-    def test_insert_requires_allocated_slot(self, model):
+    def test_land_requires_allocated_slot(self, model):
         params, cfg = model
         _, pre = T.prefill(params, jnp.asarray([[1]], jnp.int32),
                            T.init_cache(cfg, 1, 8), cfg)
-        slots = serving.SlotCache(cfg, 2, max_len=16)
-        with pytest.raises(ValueError):
-            slots.insert(0, pre)
+        slots = serving.PagedSlotCache(cfg, 2, max_len=16, page_size=8)
+        with pytest.raises(ValueError, match="not allocated"):
+            slots.land([0], pre, [1])
 
 
-class TestDecodeStepSlots:
-    @pytest.mark.slow
-    def test_matches_per_request_decode_step(self, model):
-        """Row s of the masked slot decode == batch-1 decode_step at that
-        slot's own position, for slots at DIFFERENT depths."""
-        params, cfg = model
-        prompts = [[3, 4, 5, 6], [10, 11]]
-        slots = serving.SlotCache(cfg, 3, max_len=16)
-        singles = []
-        for s, p in enumerate(prompts):
-            slots.alloc()
-            _, pre = T.prefill(params, jnp.asarray([p], jnp.int32),
-                               T.init_cache(cfg, 1, len(p)), cfg)
-            slots.insert(s, pre)
-            _, single = T.prefill(params, jnp.asarray([p], jnp.int32),
-                                  T.init_cache(cfg, 1, 16), cfg)
-            singles.append(single)
-
-        active = jnp.asarray([True, True, False])
-        tokens = jnp.asarray([7, 12, 0], jnp.int32)
-        for _ in range(3):
-            logits, cache = T.decode_step_slots(
-                params, tokens, slots.cache, cfg, active)
-            slots.cache = cache
-            for s in range(2):
-                ref_logits, singles[s] = T.decode_step(
-                    params, tokens[s:s + 1], singles[s], cfg)
-                np.testing.assert_allclose(
-                    np.asarray(logits[s]), np.asarray(ref_logits[0]),
-                    atol=1e-4, rtol=1e-4)
-            tokens = jnp.argmax(logits, -1).astype(jnp.int32)
-        # inactive slot never advances
-        assert slots.positions().tolist()[2] == 0
-
-    def test_eager_capacity_guard(self, model):
-        params, cfg = model
-        slots = serving.SlotCache(cfg, 2, max_len=4)
-        slots.cache["pos"] = jnp.asarray([4, 0], jnp.int32)
-        with pytest.raises(ValueError, match="capacity"):
-            T.decode_step_slots(params, jnp.zeros(2, jnp.int32),
-                                slots.cache, cfg,
-                                jnp.asarray([True, False]))
+class TestOneCache:
+    def test_paged_false_is_refused_typed(self):
+        """The slot-contiguous cache went in PR 28: the field stays only
+        because the benchmark's configuration files pass ``paged=True``."""
+        with pytest.raises(ValueError, match="PR 28"):
+            serving.EngineConfig(paged=False)
+        assert not hasattr(serving, "SlotCache")
+        assert not hasattr(T, "decode_step_slots")
 
 
 class TestEngineCorrectness:

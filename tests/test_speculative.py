@@ -590,10 +590,6 @@ class TestSpeculativeOracle:
             outs[spec] = [f.result(timeout=0) for f in futs]
         assert outs[True] == outs[False]
 
-    def test_speculative_requires_paged(self, model):
-        with pytest.raises(ValueError, match="paged"):
-            _engine(model, speculative=True, paged=False)
-
     def test_model_draft_requires_shared_vocab(self, model):
         params, cfg = model
         bad = _draft_cfg()

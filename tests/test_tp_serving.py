@@ -112,11 +112,6 @@ class TestTpConfig:
         with pytest.raises(ShardingConfigError, match="devices"):
             _engine(params, cfg, tp=16)
 
-    def test_tp_requires_paged(self, model):
-        params, cfg = model
-        with pytest.raises(ShardingConfigError, match="paged"):
-            _engine(params, cfg, tp=2, paged=False)
-
     def test_tp_zero_is_typed(self, model):
         params, cfg = model
         with pytest.raises(ShardingConfigError, match=">= 1"):

@@ -660,7 +660,6 @@ class TestTwoKindPoolsWrittenInPlace:
 
 class TestRefusals:
     @pytest.mark.parametrize("kw,why", [
-        (dict(paged=False, prefill_chunk_tokens=0), "paged=False"),
         (dict(tp=2), "tp > 1"),
         (dict(speculative=True), "speculative"),
         (dict(kv_dtype="int8"), "int8"),
@@ -688,9 +687,6 @@ class TestRefusals:
             T.forward(params, toks, cfg)
         with refuse:
             T.decode_step(params, toks[:, 0], T.init_cache(cfg, 1, 8), cfg)
-        with refuse:
-            T.decode_step_slots(params, toks[:, 0],
-                                serving.init_slot_cache(cfg, 1, 8), cfg, act)
         with refuse:
             T.decode_verify_paged(params, toks[:, :2], pool, table, cfg, act)
         with refuse:  # one pool for both kinds: no window layers' pages
