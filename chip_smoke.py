@@ -205,6 +205,60 @@ def pool_sized_results(text: str, floor: int):
             [f[:4] for f in found[:5]])
 
 
+_HLO_CALLED = re.compile(
+    r"\b(?:calls|to_apply|body|condition|true_computation|"
+    r"false_computation)=%?([\w.\-]+)")
+_HLO_CALLED_LIST = re.compile(
+    r"\b(?:branch_computations|called_computations)=\{([^}]*)\}")
+
+
+_HLO_COLLECTIVES = tuple(
+    op + tail for op in ("all-reduce", "all-gather", "all-to-all",
+                         "collective-permute", "reduce-scatter")
+    for tail in ("", "-start"))
+
+
+def sorts_by_conditional(text: str, opcodes=("sort",)):
+    """Read a compiled executable's HLO text: ``(conditionals, inside,
+    outside)`` — how many ``conditional`` instructions survived the
+    compiler, and the names of the ``sort`` instructions (or those of
+    ``opcodes``) that lie in a computation some conditional's branch
+    reaches (run only when that branch is taken) and of those that lie
+    elsewhere (run every time).  A conditional the compiler flattened
+    into "run both, select" shows as no conditional and every sort
+    outside."""
+    calls: Dict[str, set] = {}
+    sorts: Dict[str, List[str]] = {}
+    branches, conditionals, inside = set(), 0, None
+    for line in text.splitlines():
+        comp = _HLO_COMPUTATION.match(line)
+        if comp:
+            inside = comp.group(1)
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if not m:
+            continue
+        called = set(_HLO_CALLED.findall(line))
+        for group in _HLO_CALLED_LIST.findall(line):
+            called.update(n.strip().lstrip("%") for n in group.split(","))
+        calls.setdefault(inside, set()).update(called)
+        if m.group(3) == "conditional":
+            conditionals += 1
+            branches.update(called)
+        elif m.group(3) in opcodes:
+            sorts.setdefault(inside, []).append(m.group(1))
+    reached, todo = set(), list(branches)
+    while todo:
+        c = todo.pop()
+        if c not in reached:
+            reached.add(c)
+            todo.extend(calls.get(c, ()))
+    return (conditionals,
+            sorted(n for c, ns in sorts.items() if c in reached for n in ns),
+            sorted(n for c, ns in sorts.items() if c not in reached
+                   for n in ns))
+
+
 def _require_pool_in_place(smoke: SmokeConfig, text: str, floor: int,
                            what: str) -> None:
     """No instruction of ``text`` may produce ``floor`` elements — one
@@ -220,6 +274,31 @@ def _require_pool_in_place(smoke: SmokeConfig, text: str, floor: int,
         _require(not offenders,
                  f"{what}: {len(offenders)} instruction(s) with a result "
                  f"the size of a layer of the KV pool: {offenders[:5]}")
+
+
+def _require_sorts_gated(smoke: SmokeConfig, text: str, what: str) -> None:
+    """The next-token pick's sorts (top-k, nucleus) run only in a tick
+    whose batch asks for them: in the compiled program the conditionals
+    of ``sample_token_rows`` survive, with every sort under a branch.
+    Whether a conditional stays one is the TPU compiler's to decide, so
+    off the chip the reading is only printed.  (The smoke's model is
+    dense, so the pick's are the tick's only sorts; an expert layer's
+    routing sorts too, every tick.)  And on every backend: no branch
+    holds a collective — under tp the pick takes the logits whole, so
+    the devices of one execution never wait for each other inside a
+    branch (XLA:CPU can cross-wait there: ``sample_token_rows``)."""
+    conditionals, inside, outside = sorts_by_conditional(text)
+    collectives = sorts_by_conditional(text, _HLO_COLLECTIVES)[1]
+    _say(f"{what}: {conditionals} conditional(s); sorts under a branch "
+         f"{inside}, outside {outside}; collectives under a branch "
+         f"{collectives}")
+    _require(not collectives,
+             f"{what}: collectives under a conditional: {collectives}")
+    if smoke.expect_compiled:
+        _require(conditionals >= 1 and inside and not outside,
+                 f"{what}: the pick's sorts are not gated: {conditionals} "
+                 f"conditional(s), sorts under a branch {inside}, "
+                 f"outside {outside}")
 
 
 # --- phase 0: what machine is this -------------------------------------------
@@ -662,13 +741,14 @@ def phase_serve(smoke: SmokeConfig, host_params, *, tp: int = 1) -> Dict:
         host, port = srv.address
         base = f"http://{host}:{port}"
 
-        def client(i: int) -> None:
+        def client(i: int, **sampling) -> None:
             try:
                 req = urllib.request.Request(
                     base + "/generate",
                     data=json.dumps({
-                        "tokens": prompts[i],
-                        "max_new_tokens": smoke.max_new_tokens}).encode(),
+                        "tokens": prompts[i % len(prompts)],
+                        "max_new_tokens": smoke.max_new_tokens,
+                        **sampling}).encode(),
                     headers={"Content-Type": "application/json"})
                 with urllib.request.urlopen(req, timeout=600) as r:
                     out[i] = json.loads(r.read())
@@ -682,9 +762,27 @@ def phase_serve(smoke: SmokeConfig, host_params, *, tp: int = 1) -> Dict:
         for t in threads:
             t.join(900)
         with urllib.request.urlopen(base + "/stats", timeout=60) as r:
+            greedy = json.loads(r.read())
+        # ... then ONE sampled request (temperature, top-k, nucleus): the
+        # same executable takes the pick's other branches, as data.
+        client(len(prompts), temperature=0.8, top_k=40, top_p=0.9, seed=7)
+        with urllib.request.urlopen(base + "/stats", timeout=60) as r:
             stats = json.loads(r.read())
-    _require(not errors and len(out) == len(prompts),
+    _require(not errors and len(out) == len(prompts) + 1,
              f"/generate failed: {errors or 'missing replies'}")
+    sampled = out.pop(len(prompts))["tokens"]
+    _require(len(sampled) == smoke.max_new_tokens
+             and all(0 <= t < smoke.vocab_size for t in sampled),
+             f"sampled request: bad tokens {sampled}")
+    # The host's count of the pick's gates: every greedy tick ran no
+    # sort, every tick of the sampled request (alone by then) did.
+    ticks, free = "decode_ticks", "sample_ticks_sortfree_total"
+    _require(greedy[free] == greedy[ticks] > 0
+             and stats[free] == greedy[free]
+             and stats[ticks] > greedy[ticks],
+             f"the pick's gates: {greedy[free]} of {greedy[ticks]} greedy "
+             f"ticks sort-free, then {stats[free] - greedy[free]} of "
+             f"{stats[ticks] - greedy[ticks]} with a top-p request")
 
     _require(stats["engine_restarts"] == 0 and stats["requests_resumed"] == 0,
              f"the supervised tick loop swallowed a failure: "
@@ -709,6 +807,7 @@ def phase_serve(smoke: SmokeConfig, host_params, *, tp: int = 1) -> Dict:
     pool = engine.slots.cache["k"]
     layer = int(np.prod(pool.shape[1:])) // tp
     _require_pool_in_place(smoke, text, layer, f"decode tick (tp={tp})")
+    _require_sorts_gated(smoke, text, f"decode tick (tp={tp})")
     _require_pool_in_place(
         smoke, land.lower(*seen["land"]).compile().as_text(), layer,
         f"landing (tp={tp})")

@@ -1413,7 +1413,8 @@ def ngram_propose(hist, pos, k: int):
 
 def decode_verify_paged(params: Dict, window, pool: Dict, table,
                         cfg: TransformerConfig, active, spec_on=None,
-                        sample=None, *, kernel=False, mesh=None):
+                        sample=None, *, kernel=False, mesh=None,
+                        replicated=None):
     """One batched W-position VERIFY forward over a paged cache — the
     speculative tick's target-model half.
 
@@ -1453,6 +1454,8 @@ def decode_verify_paged(params: Dict, window, pool: Dict, table,
     sampled stream never accepts them — it emits exactly one sampled
     token per tick through this executable, which is what lets mixed
     sampled/greedy-speculating batches share the program.
+    ``replicated`` is :func:`sample_token_rows`'s: the sharding that
+    holds the logits whole on every device of a tp mesh.
 
     Returns ``(target_tokens (S, W) int32, max_logits (S, W) f32,
     accepted (S,) int32, updated pool)`` with ``pos`` advanced by
@@ -1577,7 +1580,8 @@ def decode_verify_paged(params: Dict, window, pool: Dict, table,
         # stream, whatever the host-side mask said.
         temp, s_tk, s_tp, s_rng = sample
         s0 = sample_token_rows(logits[:, 0, :], temp, s_tk, s_tp, s_rng,
-                               pos + 1, jnp.zeros((S,), jnp.int32))
+                               pos + 1, jnp.zeros((S,), jnp.int32),
+                               replicated=replicated)
         t = t.at[:, 0].set(jnp.where(temp > 0.0, s0, t[:, 0]))
         acc = jnp.where(temp > 0.0, 0, acc)
     acc = jnp.where(active, acc, 0)
@@ -1863,8 +1867,19 @@ def prefill(params: Dict, prompt, cache: Dict, cfg: TransformerConfig,
     return logits[:, 0], cache
 
 
+def sample_gates(temperature, top_k, top_p):
+    """What a batch of sampling columns asks of :func:`sample_token_rows`,
+    as three scalars: some row draws (``temperature > 0``), some row has
+    a top-k, some row has a nucleus.  Array methods only, so the device
+    (traced columns, inside the tick) and the host (``SlotSampling``'s
+    numpy mirror, for the ``/stats`` counters) evaluate the SAME
+    statement."""
+    return ((temperature > 0.0).any(), (top_k > 0).any(),
+            ((top_p > 0.0) & (top_p < 1.0)).any())
+
+
 def sample_token_rows(logits, temperature, top_k, top_p, rng, positions,
-                      rows):
+                      rows, *, replicated=None):
     """Pick one token per row with EVERY sampling parameter as DATA —
     the serving engine's per-slot sampling kernel, and the math
     :func:`sample_decode` (the per-request oracle) is defined by.  One
@@ -1882,6 +1897,25 @@ def sample_token_rows(logits, temperature, top_k, top_p, rng, positions,
     ``top_p`` (ties at the threshold are kept; ``0`` or ``>= 1`` =
     off), applied AFTER top-k on the temperature-scaled distribution.
 
+    What a batch pays is what its rows ask for, decided ON THE DEVICE
+    each call from the three columns (:func:`sample_gates`; ``lax.cond``
+    on a scalar, so still one executable): a batch of greedy rows runs
+    the argmax and nothing else; a temperature-only batch adds the
+    categorical draw; any top-k row adds one full-vocabulary sort; any
+    nucleus row adds the softmax and one sort.  Each gate closes only a
+    stage whose result the open path discards or leaves unchanged, so
+    the tokens are those of the ungated body bit for bit.  Do not
+    ``vmap`` over this function: a ``cond`` under ``vmap`` is a select.
+
+    ``replicated``: under a tp serving mesh, the sharding that holds a
+    whole array on every device.  The logits leave the head sharded
+    over the vocabulary; they are pinned to ``replicated`` before the
+    gates, so that each branch runs whole on every device.  Left
+    sharded, GSPMD partitions the sorts and puts their all-to-alls
+    UNDER the conditionals, where XLA:CPU's in-process rendezvous can
+    cross-wait between the devices of one execution (seen under load
+    in the tp tests: a 40 s stall, then an abort).
+
     PRNG schedule (the contract resume/failover identity hangs on):
     the token at logical sequence position ``p`` of batch row ``r``
     draws from ``fold_in(fold_in(rng[r], p), r)``.  Keys are a pure
@@ -1894,34 +1928,51 @@ def sample_token_rows(logits, temperature, top_k, top_p, rng, positions,
     slot is row 0 of its own per-request oracle call)."""
     with jax.named_scope("sample"):
         V = logits.shape[-1]
+        if replicated is not None:
+            logits = lax.with_sharding_constraint(logits, replicated)
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        # Greedy rows divide by 1.0 (their sampled value is discarded by
-        # the final where, but NaN/Inf from a 0-division must never enter
-        # the softmax); sampled rows divide by their exact temperature.
-        scaled = logits / jnp.where(temperature > 0.0, temperature,
-                                    1.0)[:, None]
-        srt = jnp.sort(scaled, axis=-1)[:, ::-1]            # descending
-        kth = jnp.take_along_axis(srt, (jnp.clip(top_k, 1, V) - 1)[:, None],
-                                  axis=1)
-        scaled = jnp.where((top_k > 0)[:, None] & (scaled < kth),
-                           -jnp.inf, scaled)
-        probs = jax.nn.softmax(scaled, axis=-1)
-        ps = jnp.sort(probs, axis=-1)[:, ::-1]
-        csum = jnp.cumsum(ps, axis=-1)
-        # Sorted index i is in the nucleus iff the mass BEFORE it is still
-        # under top_p (index 0 always is); the smallest kept probability
-        # becomes the threshold, so threshold ties stay in.
-        keep = (csum - ps) < top_p[:, None]
-        thr = jnp.min(jnp.where(keep, ps, jnp.inf), axis=-1, keepdims=True)
-        p_on = (top_p > 0.0) & (top_p < 1.0)
-        scaled = jnp.where(p_on[:, None] & (probs < thr), -jnp.inf, scaled)
+        draws, any_k, any_p = sample_gates(temperature, top_k, top_p)
+
+        def mask_top_k(scaled):
+            srt = jnp.sort(scaled, axis=-1)[:, ::-1]        # descending
+            kth = jnp.take_along_axis(
+                srt, (jnp.clip(top_k, 1, V) - 1)[:, None], axis=1)
+            return jnp.where((top_k > 0)[:, None] & (scaled < kth),
+                             -jnp.inf, scaled)
+
+        def mask_top_p(scaled):
+            probs = jax.nn.softmax(scaled, axis=-1)
+            ps = jnp.sort(probs, axis=-1)[:, ::-1]
+            csum = jnp.cumsum(ps, axis=-1)
+            # Sorted index i is in the nucleus iff the mass BEFORE it is
+            # still under top_p (index 0 always is); the smallest kept
+            # probability becomes the threshold, so threshold ties stay in.
+            keep = (csum - ps) < top_p[:, None]
+            thr = jnp.min(jnp.where(keep, ps, jnp.inf), axis=-1,
+                          keepdims=True)
+            p_on = (top_p > 0.0) & (top_p < 1.0)
+            return jnp.where(p_on[:, None] & (probs < thr), -jnp.inf, scaled)
 
         def pick(key, pos, row, lrow):
             key = jax.random.fold_in(jax.random.fold_in(key, pos), row)
             return jax.random.categorical(key, lrow)
 
-        sampled = jax.vmap(pick)(rng, positions, rows, scaled)
-        return jnp.where(temperature > 0.0, sampled.astype(jnp.int32), greedy)
+        def draw(logits):
+            # Greedy rows divide by 1.0 (their sampled value is discarded
+            # by the final where, but NaN/Inf from a 0-division must never
+            # enter the softmax); sampled rows divide by their exact
+            # temperature.
+            scaled = logits / jnp.where(temperature > 0.0, temperature,
+                                        1.0)[:, None]
+            # A closed gate's mask is the identity already: no row has the
+            # parameter, so its where() would keep every element.
+            scaled = lax.cond(any_k, mask_top_k, lambda x: x, scaled)
+            scaled = lax.cond(any_p, mask_top_p, lambda x: x, scaled)
+            sampled = jax.vmap(pick)(rng, positions, rows, scaled)
+            return jnp.where(temperature > 0.0, sampled.astype(jnp.int32),
+                             greedy)
+
+        return lax.cond(draws, draw, lambda _: greedy, logits)
 
 
 def sample_decode(params: Dict, prompt, steps: int, cfg: TransformerConfig,
@@ -1962,6 +2013,11 @@ def sample_decode(params: Dict, prompt, steps: int, cfg: TransformerConfig,
             for k, v in cache.items()
         }
     logits, cache = prefill(params, prompt, cache, cfg)
+    replicated = None
+    if cache_shardings is not None:  # whole logits on every tp device
+        from jax.sharding import NamedSharding
+
+        replicated = NamedSharding(cache_shardings["k"].mesh, P())
     temp_col = jnp.full((B,), temperature, jnp.float32)
     tk_col = jnp.full((B,), top_k, jnp.int32)
     tp_col = jnp.full((B,), top_p, jnp.float32)
@@ -1971,7 +2027,8 @@ def sample_decode(params: Dict, prompt, steps: int, cfg: TransformerConfig,
     def gen(carry, pos):
         cache, logits = carry
         tok = sample_token_rows(logits, temp_col, tk_col, tp_col, keys,
-                                jnp.full((B,), pos, jnp.int32), rows)
+                                jnp.full((B,), pos, jnp.int32), rows,
+                                replicated=replicated)
         logits, cache = decode_step(params, tok, cache, cfg)
         return (cache, logits), tok
 

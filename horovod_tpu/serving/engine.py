@@ -808,7 +808,7 @@ class InferenceEngine:
             # PRNG key ROWS, all data (greedy rows are temperature 0):
             # no parameter mix ever retraces this body.
             nxt, mx = self._pick(logits, pos, active, s_t, s_k, s_p,
-                                 s_key)
+                                 s_key, replicated=_R)
             return (nxt, mx, pool, *load)
 
         # Donate the pool: without it XLA keeps input AND output pools
@@ -859,7 +859,7 @@ class InferenceEngine:
                     t, mx, acc, pool = T.decode_verify_paged(
                         params, window, pool, table, self.cfg, active,
                         spec_on, sample=(s_t, s_k, s_p, s_key),
-                        kernel=_pk, mesh=_pk_mesh)
+                        kernel=_pk, mesh=_pk_mesh, replicated=_R)
                     # Draft rollback on rejection = reset pos to the
                     # committed depth; the rejected tail's stale draft
                     # K/V is overwritten before it is ever attended
@@ -893,7 +893,7 @@ class InferenceEngine:
                     t, mx, acc, pool = T.decode_verify_paged(
                         params, window, pool, table, self.cfg, active,
                         spec_on, sample=(s_t, s_k, s_p, s_key),
-                        kernel=_pk, mesh=_pk_mesh)
+                        kernel=_pk, mesh=_pk_mesh, replicated=_R)
                     # Accepted drafts are now committed history too.
                     j = jnp.arange(1, K + 1, dtype=jnp.int32)[None, :]
                     wp = pos[:, None] + j
@@ -1036,7 +1036,7 @@ class InferenceEngine:
             obs_tracing.record_compile("serving_sample")
             return T.sample_token_rows(
                 logits, s_t, s_k, s_p, s_key, positions,
-                jnp.zeros_like(positions))
+                jnp.zeros_like(positions), replicated=_R)
 
         self._first_sample = self._jit(
             _first_sample,
@@ -2055,21 +2055,26 @@ class InferenceEngine:
         return True
 
     @staticmethod
-    def _pick(logits, pos, active, s_t, s_k, s_p, s_key):
+    def _pick(logits, pos, active, s_t, s_k, s_p, s_key, replicated=None):
         """The ONE in-tick next-token pick, shared by every tick body:
         the token being chosen sits at logical position ``pos + 1``
         (``pos`` = the pool position at tick ENTRY — the input token's
         slot), so its PRNG key is ``fold_in(fold_in(key, pos + 1), 0)``
         — exactly the per-request ``sample_decode`` oracle's schedule
-        for row 0 (tests/test_sampling.py).  Greedy rows short to
-        argmax inside the kernel.  Returns ``(next tokens, zeroed for
-        inactive rows; per-slot max logit)`` — the max rides along for
+        for row 0 (tests/test_sampling.py).  A batch of greedy rows
+        runs the argmax alone: each further stage (draw, top-k sort,
+        nucleus sort) runs only in a tick where some row asks for it,
+        by ``lax.cond`` inside the kernel (``replicated``: under tp, the
+        sharding that holds the logits whole on every device, so that
+        no branch holds a collective).  Returns ``(next tokens, zeroed
+        for inactive rows; per-slot max logit)`` — the max rides along for
         the host-side finiteness check: NaN/Inf logits (bad params,
         flaky hardware) must become a typed engine failure, not
         silently-greedy garbage tokens."""
         with jax.named_scope("sample"):
             nxt = T.sample_token_rows(logits, s_t, s_k, s_p, s_key,
-                                      pos + 1, jnp.zeros_like(pos))
+                                      pos + 1, jnp.zeros_like(pos),
+                                      replicated=replicated)
             return jnp.where(active, nxt, 0), jnp.max(logits, axis=-1)
 
     def _run_tick(self, tokens_dev, active_dev):
@@ -2081,6 +2086,14 @@ class InferenceEngine:
         per-slot accepted length ``acc`` ``(S,)``, and the dispatch-
         time speculation mask."""
         s_t, s_k, s_p, s_key = self._samp.device()
+        # Which stages of the pick this tick's columns open: the device
+        # decides from the same columns (T.sample_gates), the host only
+        # counts.
+        draws, any_k, any_p = self._samp.gates()
+        if not draws:
+            self.metrics.sample_ticks_drawfree.inc()
+        if not (draws and (any_k or any_p)):
+            self.metrics.sample_ticks_sortfree.inc()
         if self._spec and self._dev_spec_host.any():
             if self._spec_model:
                 nxt, t, mx, acc, pool, dpool = self._spec_tick_fn(

@@ -105,6 +105,11 @@ class ServingMetrics:
       tokens`` / ``window_walked_tokens`` are the window layers' (live:
       the window's span; walked: whole blocks from the one holding the
       window's first position).
+    * ``sample_ticks_drawfree`` / ``sample_ticks_sortfree`` — dispatched
+      decode ticks whose next-token pick skipped the draw (every row
+      greedy) / ran no sort (that, or no row with a top-k or a nucleus):
+      the host's reading of the gates ``sample_token_rows`` takes on the
+      device (``SlotSampling.gates``).
     * ``moe_rows`` / ``moe_experts_touched`` / ``moe_load_max_rows`` /
       ``moe_load_mean_rows`` — an expert model's decode ticks, summed
       over layers: expert rows computed (active slots x experts a
@@ -247,6 +252,14 @@ class ServingMetrics:
             "serving_window_walked_tokens_total",
             "Per dispatched paged tick, the positions a window layer's "
             "walk covers (whole blocks from the window's first)")
+        self.sample_ticks_drawfree = r.counter(
+            "serving_sample_ticks_drawfree_total",
+            "Dispatched decode ticks whose batch held no sampled row: "
+            "the next-token pick ran the argmax alone")
+        self.sample_ticks_sortfree = r.counter(
+            "serving_sample_ticks_sortfree_total",
+            "Dispatched decode ticks whose pick ran no full-vocabulary "
+            "sort: no sampled row, or none with a top-k or a nucleus")
         self.moe_rows = r.counter(
             "serving_moe_rows_total",
             "Expert rows computed by decode ticks, summed over layers "
@@ -434,6 +447,10 @@ class ServingMetrics:
             "paged_walked_tokens_total": self.paged_walked_tokens.value,
             "window_live_tokens_total": self.window_live_tokens.value,
             "window_walked_tokens_total": self.window_walked_tokens.value,
+            "sample_ticks_drawfree_total":
+                self.sample_ticks_drawfree.value,
+            "sample_ticks_sortfree_total":
+                self.sample_ticks_sortfree.value,
             "moe_rows_total": self.moe_rows.value,
             "moe_experts_touched_total": self.moe_experts_touched.value,
             "moe_load_max_rows_total": self.moe_load_max_rows.value,

@@ -123,8 +123,7 @@ class SlotSampling:
         self.top_k = np.zeros(n_slots, np.int32)
         self.top_p = np.zeros(n_slots, np.float32)
         self.key = np.zeros((n_slots, 2), np.uint32)
-        self._dev: Optional[tuple] = None
-        self._dirty = True
+        self._dev: Optional[tuple] = None  # dropped by any change
 
     def set(self, slot: int, *, temperature: float, top_k: int,
             top_p: float, seed: int) -> None:
@@ -132,14 +131,14 @@ class SlotSampling:
         self.top_k[slot] = top_k
         self.top_p[slot] = top_p
         self.key[slot] = seed_key(seed)
-        self._dirty = True
+        self._dev = None
 
     def clear(self, slot: int) -> None:
         self.temperature[slot] = 0.0
         self.top_k[slot] = 0
         self.top_p[slot] = 0.0
         self.key[slot] = 0
-        self._dirty = True
+        self._dev = None
 
     def reset(self) -> None:
         """Restart path: zero every column and drop the device copy
@@ -149,21 +148,26 @@ class SlotSampling:
         self.top_p[:] = 0.0
         self.key[:] = 0
         self._dev = None
-        self._dirty = True
 
-    @property
-    def any_sampled(self) -> bool:
-        return bool((self.temperature > 0.0).any())
+    def gates(self) -> Tuple[bool, bool, bool]:
+        """``(draws, any top-k, any nucleus)`` over the host mirror: the
+        predicates ``sample_token_rows`` branches on, by the same
+        function, so the host knows which stages the next tick runs
+        without asking the device (three reductions over ``n_slots``)."""
+        from horovod_tpu.models.transformer import sample_gates
+
+        draws, any_k, any_p = sample_gates(self.temperature, self.top_k,
+                                           self.top_p)
+        return bool(draws), bool(any_k), bool(any_p)
 
     def device(self) -> tuple:
         """The ``(temperature, top_k, top_p, keys)`` device columns the
-        tick consumes — re-uploaded (async) only when dirty."""
-        if self._dev is None or self._dirty:
+        tick consumes — re-uploaded (async) only after a change."""
+        if self._dev is None:
             import jax.numpy as jnp
 
             self._dev = (jnp.asarray(self.temperature),
                          jnp.asarray(self.top_k),
                          jnp.asarray(self.top_p),
                          jnp.asarray(self.key))
-            self._dirty = False
         return self._dev

@@ -294,3 +294,53 @@ def test_pool_sized_results_reads_a_compiled_program():
         smoke, _HLO.replace("copy(%p)", "bitcast(%p)").replace(
             "%copy.110 = bf16[65,4,16,128]", "%copy.110 = bf16[1,4,16,128]"),
         layer, "probe")
+
+
+_HLO_PICK = """HloModule jit__tick, is_scheduled=true
+
+%compare (a: f32[], b: f32[]) -> pred[] {
+  ROOT %lt = pred[] compare(%a, %b), direction=LT
+}
+
+%region_6.8 (div.0: (f32[32,512])) -> (f32[32,512]) {
+  ROOT %div.0 = (f32[32,512]{1,0:T(8,128)}) parameter(0)
+}
+
+%helper (x: f32[32,512]) -> f32[32,512] {
+  %sort.9 = (f32[32,512]{1,0:T(8,128)S(1)}, s32[32,512]{1,0:T(8,128)}) sort(%x, %iota.12), dimensions={1}, is_stable=true, to_apply=%compare
+  ROOT %g = f32[32,512]{1,0:T(8,128)} get-tuple-element(%sort.9), index=0
+}
+
+%region_7.15 (arg_tuple.2: (f32[32,512], s32[32])) -> (f32[32,512]) {
+  %sort.6 = (f32[32,512]{1,0:T(8,128)S(1)}, s32[32,512]{1,0:T(8,128)}) sort(%copy-done, %iota.11), dimensions={1}, is_stable=true, to_apply=%compare
+  %call.1 = f32[32,512]{1,0:T(8,128)} call(%y), to_apply=%helper
+  ROOT %t = (f32[32,512]{1,0:T(8,128)}) tuple(%call.1)
+}
+
+ENTRY %main (p: f32[32,512]) -> s32[32] {
+  %p = f32[32,512]{1,0:T(8,128)} parameter(0)
+  %conditional = (f32[32,512]{1,0:T(8,128)}) conditional(%pred.5, %tuple.14, %tuple.15), branch_computations={%region_6.8, %region_7.15}, metadata={op_name="jit(_tick)/sample/cond"}
+  %sort.2 = (f32[32,64]{1,0:T(8,128)}, s32[32,64]{1,0:T(8,128)}) sort(%scores, %iota.2), dimensions={1}, to_apply=%compare, metadata={op_name="jit(_tick)/hvd_moe_route/sort"}
+  ROOT %out = s32[32]{0:T(128)} fusion(%conditional), kind=kLoop, calls=%fused_computation.45
+}
+"""
+
+
+def test_sorts_by_conditional_reads_a_compiled_program():
+    """The compiled-program check of the pick's gates: a sort in a
+    conditional's branch, or in a computation a branch calls, runs only
+    when the branch is taken; one in the entry computation runs every
+    tick; with the conditional flattened away every sort does."""
+    assert chip_smoke.sorts_by_conditional(_HLO_PICK) == (
+        1, ["sort.6", "sort.9"], ["sort.2"])
+    smoke = chip_smoke.SmokeConfig()
+    with pytest.raises(chip_smoke.SmokeFailure, match="not gated"):
+        chip_smoke._require_sorts_gated(smoke, _HLO_PICK, "probe")
+    gated = "\n".join(line for line in _HLO_PICK.splitlines()
+                      if "%sort.2" not in line)
+    chip_smoke._require_sorts_gated(smoke, gated, "probe")
+    flat = gated.replace(" conditional(", " select(")
+    assert chip_smoke.sorts_by_conditional(flat) == (
+        0, [], ["sort.6", "sort.9"])
+    with pytest.raises(chip_smoke.SmokeFailure, match="not gated"):
+        chip_smoke._require_sorts_gated(smoke, flat, "probe")

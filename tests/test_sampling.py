@@ -156,6 +156,193 @@ class TestSampleTokenRows:
 
 
 # ---------------------------------------------------------------------------
+# the gates: each stage of the pick runs only where some row asks for it
+# ---------------------------------------------------------------------------
+
+
+def _sample_rows_ungated(logits, temperature, top_k, top_p, rng, positions,
+                         rows):
+    """``sample_token_rows`` as it stood before its stages were gated
+    (PR 29): both sorts, the softmax and the draw for every batch.  The
+    reference the gated function must equal bit for bit."""
+    V = logits.shape[-1]
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    scaled = logits / jnp.where(temperature > 0.0, temperature,
+                                1.0)[:, None]
+    srt = jnp.sort(scaled, axis=-1)[:, ::-1]
+    kth = jnp.take_along_axis(srt, (jnp.clip(top_k, 1, V) - 1)[:, None],
+                              axis=1)
+    scaled = jnp.where((top_k > 0)[:, None] & (scaled < kth),
+                       -jnp.inf, scaled)
+    probs = jax.nn.softmax(scaled, axis=-1)
+    ps = jnp.sort(probs, axis=-1)[:, ::-1]
+    csum = jnp.cumsum(ps, axis=-1)
+    keep = (csum - ps) < top_p[:, None]
+    thr = jnp.min(jnp.where(keep, ps, jnp.inf), axis=-1, keepdims=True)
+    p_on = (top_p > 0.0) & (top_p < 1.0)
+    scaled = jnp.where(p_on[:, None] & (probs < thr), -jnp.inf, scaled)
+
+    def pick(key, pos, row, lrow):
+        key = jax.random.fold_in(jax.random.fold_in(key, pos), row)
+        return jax.random.categorical(key, lrow)
+
+    sampled = jax.vmap(pick)(rng, positions, rows, scaled)
+    return jnp.where(temperature > 0.0, sampled.astype(jnp.int32), greedy)
+
+
+def _sorts(jaxpr, in_cond=False):
+    """Every ``sort`` equation of a jaxpr, sub-jaxprs included, as
+    whether it lies inside some ``cond``'s branch."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "sort":
+            yield in_cond
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _sorts(sub, in_cond or eqn.primitive.name == "cond")
+
+
+R_GATED, V_GATED = 32, 203  # a vocabulary that is no multiple of 128
+
+#: name -> (temperature, top_k, top_p) columns of R_GATED rows, and the
+#: gates they open.
+GATE_CASES = {
+    "all_greedy": ([0.0] * 32, [0] * 32, [0.0] * 32,
+                   (False, False, False)),
+    # greedy rows may carry a top-k / top-p: validate() lets them
+    "greedy_with_filters": ([0.0] * 32, [5] * 32, [0.5] * 32,
+                            (False, True, True)),
+    "temperature_only": ([0.7, 1.0, 1.9, 0.0] * 8, [0] * 32,
+                         [0.0, 1.0] * 16, (True, False, False)),
+    "top_k_only": ([1.3] * 32, [1, 7, 0, 300] * 8, [0.0] * 32,
+                   (True, True, False)),
+    "top_p_only": ([0.9] * 32, [0] * 32, [0.8, 0.3, 1.0, 0.0] * 8,
+                   (True, False, True)),
+    "both": ([1.1, 0.6] * 16, [4, 0, 50, 9] * 8, [0.9, 0.5, 0.0, 0.7] * 8,
+             (True, True, True)),
+    "one_sampled_among_31_greedy": (
+        [0.0] * 17 + [1.2] + [0.0] * 14, [0] * 17 + [6] + [0] * 14,
+        [0.0] * 17 + [0.85] + [0.0] * 14, (True, True, True)),
+}
+
+
+class TestGatedSampler:
+    @staticmethod
+    def _args(case, seed):
+        temp, tk, tp, _ = GATE_CASES[case]
+        key = jax.random.PRNGKey(100 + seed)
+        logits = 3.0 * jax.random.normal(key, (R_GATED, V_GATED),
+                                         jnp.float32)
+        # ties at the k-th value and at the nucleus threshold
+        logits = logits.at[:, 5].set(logits[:, 9])
+        keys = jnp.asarray(np.stack(
+            [S.seed_key(1000 * seed + r) for r in range(R_GATED)]))
+        return (logits, jnp.asarray(temp, jnp.float32),
+                jnp.asarray(tk, jnp.int32), jnp.asarray(tp, jnp.float32),
+                keys, jnp.arange(R_GATED, dtype=jnp.int32) + 7 * seed,
+                jnp.zeros((R_GATED,), jnp.int32))
+
+    @pytest.mark.parametrize("seed", [0, 1])
+    @pytest.mark.parametrize("case", sorted(GATE_CASES))
+    def test_gated_tokens_equal_the_ungated_body(self, case, seed):
+        """One executable each, every case through the same two: the
+        gated pick returns the ungated body's token in every row."""
+        args = self._args(case, seed)
+        want = np.asarray(jax.jit(_sample_rows_ungated)(*args))
+        got = np.asarray(jax.jit(T.sample_token_rows)(*args))
+        np.testing.assert_array_equal(got, want)
+        temp = np.asarray(args[1])
+        assert (got[temp <= 0]
+                == np.argmax(np.asarray(args[0]), -1)[temp <= 0]).all()
+        gates = tuple(bool(g) for g in T.sample_gates(*args[1:4]))
+        assert gates == GATE_CASES[case][3]
+
+    def test_every_sort_of_the_pick_sits_in_a_cond_branch(self):
+        jaxpr = jax.make_jaxpr(T.sample_token_rows)(
+            *self._args("both", 0)).jaxpr
+        where = list(_sorts(jaxpr))
+        assert where == [True, True], where
+        # the reader itself: the ungated body's sorts are seen, outside
+        assert list(_sorts(jax.make_jaxpr(_sample_rows_ungated)(
+            *self._args("both", 0)).jaxpr)) == [False, False]
+
+    def test_every_sort_of_the_engine_tick_sits_in_a_cond_branch(
+            self, model):
+        """The tick the engine dispatches, traced at the shapes it was
+        called with: its only sorts are the pick's, each in a branch —
+        and there is ONE tick, whatever the mix."""
+        params, cfg = model
+        eng = serving.InferenceEngine(params, cfg, serving.EngineConfig(
+            n_slots=4, max_len=32, tick_timeout=0))
+        seen, tick = [], eng._tick_fn
+
+        def tap(*args):
+            seen.append(jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype), args))
+            return tick(*args)
+
+        eng._tick_fn = tap
+        _run(eng, [eng.submit([3, 4, 5], max_new_tokens=3)])
+        where = list(_sorts(jax.make_jaxpr(tick)(*seen[0]).jaxpr))
+        assert where == [True, True], where
+
+    def test_host_gates_equal_device_gates(self):
+        """The host counts with the predicates the device branches on:
+        over set, clear and reset they read the same."""
+        cols = serving.SlotSampling(4)
+
+        def agree():
+            dev = tuple(bool(g)
+                        for g in T.sample_gates(*cols.device()[:3]))
+            assert cols.gates() == dev
+            return dev
+
+        assert agree() == (False, False, False)
+        cols.set(2, temperature=0.0, top_k=4, top_p=0.5, seed=1)
+        assert agree() == (False, True, True)  # a greedy row's filters
+        cols.set(1, temperature=0.9, top_k=0, top_p=1.0, seed=2)
+        assert agree() == (True, True, True)
+        cols.clear(2)
+        assert agree() == (True, False, False)  # top_p 1.0 is off
+        cols.set(0, temperature=1.0, top_k=0, top_p=0.3, seed=3)
+        assert agree() == (True, False, True)
+        cols.clear(0)
+        cols.set(3, temperature=1.0, top_k=2, top_p=0.0, seed=4)
+        assert agree() == (True, True, False)
+        cols.reset()
+        assert agree() == (False, False, False)
+
+    def test_stats_count_the_ticks_with_closed_gates(self, model):
+        """``/stats``: one more draw-free and one more sort-free tick
+        for every tick of an all-greedy engine; a temperature-only
+        request stops the first, a top-p request both, for as long as
+        it holds a slot."""
+        params, cfg = model
+        eng = serving.InferenceEngine(params, cfg, serving.EngineConfig(
+            n_slots=4, max_len=32, tick_timeout=0))
+
+        def grown(**kw):
+            before = eng.stats()
+            # the greedy companion leaves first: the other request's
+            # row is in the columns of every tick counted here
+            _run(eng, [eng.submit([3, 4, 5], max_new_tokens=6, **kw),
+                       eng.submit([7, 8], max_new_tokens=3)])
+            after = eng.stats()
+            return tuple(after[k] - before[k] for k in (
+                "decode_ticks", "sample_ticks_drawfree_total",
+                "sample_ticks_sortfree_total"))
+
+        ticks, drawfree, sortfree = grown()
+        assert ticks >= 5 and drawfree == sortfree == ticks
+        ticks, drawfree, sortfree = grown(temperature=0.8, seed=3)
+        assert ticks >= 5 and drawfree == 0 and sortfree == ticks
+        ticks, drawfree, sortfree = grown(temperature=0.8, top_p=0.9,
+                                          seed=3)
+        assert ticks >= 5 and drawfree == 0 and sortfree == 0
+        # released: its row is cleared, the gates close again
+        ticks, drawfree, sortfree = grown()
+        assert ticks >= 5 and drawfree == sortfree == ticks
+
+
+# ---------------------------------------------------------------------------
 # the oracle itself
 # ---------------------------------------------------------------------------
 

@@ -155,6 +155,55 @@ class TestTpCollectives:
         assert "all-reduce" in hlo or "all-gather" in hlo, (
             "tp decode tick must carry tp collectives")
 
+    def test_the_picks_branches_hold_no_collective(self, model):
+        """The tick with the engine's pick, under the engine's shardings:
+        GSPMD leaves the logits sharded over the vocabulary, and a pick
+        that took them so would sort with all-to-alls UNDER its
+        conditionals — where two devices of one execution can wait for
+        each other (XLA:CPU's rendezvous: seen as a 40 s stall and an
+        abort under load).  Told the replicated sharding, every branch
+        runs whole on each device; left untold, the collectives are
+        there (the reader is not blind)."""
+        import sys
+
+        sys.path.insert(0, os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+        import chip_smoke
+        from horovod_tpu.serving.engine import InferenceEngine
+
+        params, cfg = model
+        sh = ServingSharding(cfg, 2)
+        S, ps = 4, 8
+        pool = T.shard_kv_pool(serving.init_page_pool(cfg, S, 9, ps),
+                               sh.mesh)
+        R = sh.replicated
+        poolsh = sh.pool_shardings(False)
+
+        def under_branches(replicated):
+            def tick(p, t, a, tb, pl, s_t, s_k, s_p, s_key):
+                logits, out = T.decode_step_paged(p, t, pl, tb, cfg, a)
+                return InferenceEngine._pick(
+                    logits, pl["pos"], a, s_t, s_k, s_p, s_key,
+                    replicated=replicated), out
+
+            hlo = jax.jit(
+                tick,
+                in_shardings=(sh.param_shardings(), R, R, R, poolsh,
+                              R, R, R, R),
+                out_shardings=((R, R), poolsh)).lower(
+                    sh.shard_params(params), jnp.zeros((S,), jnp.int32),
+                    jnp.zeros((S,), bool), jnp.zeros((S, 6), jnp.int32),
+                    pool, jnp.zeros((S,), jnp.float32),
+                    jnp.zeros((S,), jnp.int32), jnp.zeros((S,), jnp.float32),
+                    jnp.zeros((S, 2), jnp.uint32)).compile().as_text()
+            conditionals, inside, _ = chip_smoke.sorts_by_conditional(
+                hlo, chip_smoke._HLO_COLLECTIVES)
+            assert conditionals >= 1
+            return inside
+
+        assert under_branches(R) == []
+        assert under_branches(None) != []
+
 
 # ---------------------------------------------------------------------------
 # the tp=1 oracle A/Bs

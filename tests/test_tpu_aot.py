@@ -4,7 +4,8 @@ compile for it here (nothing runs).  What is held: the paged decode tick
 and the landing of a prefill write the KV pool IN PLACE — layout
 assignment is the TPU compiler's, so the CPU tests of
 ``tests/test_paged.py`` cannot see it, and ``chip_smoke.py`` sees it
-only on the chip.
+only on the chip; and the tick's next-token pick keeps its conditionals
+(a compiler that ran both branches and selected would sort every tick).
 
 Keep every such compile in THIS file (one process may hold libtpu), and
 describe the topology only inside the fixture below.
@@ -25,6 +26,7 @@ import chip_smoke  # noqa: E402
 from horovod_tpu.models import transformer as T  # noqa: E402
 from horovod_tpu.ops import paged_attention as PA  # noqa: E402
 from horovod_tpu.serving import cache as C  # noqa: E402
+from horovod_tpu.serving.engine import InferenceEngine  # noqa: E402
 
 pytestmark = [pytest.mark.serving, pytest.mark.paged]
 
@@ -58,6 +60,21 @@ def _cfg(**kw):
         **kw)
 
 
+def _on(sharding, tree):
+    """``tree``'s shapes, placed on the described chip."""
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def _params(sharding, cfg):
+    """The model's parameters as shapes on the chip, f32 leaves in the
+    configuration's dtype (as a server holds them)."""
+    return _on(sharding, jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda a: a.astype(cfg.dtype) if a.dtype == jnp.float32 else a,
+        T.init_params(jax.random.PRNGKey(0), cfg))))
+
+
 CASES = {
     "uniform": _cfg(n_layers=2),
     "patterned": _cfg(n_layers=4, window=64,
@@ -78,12 +95,7 @@ def test_the_tpu_compiler_writes_the_pool_in_place(one_chip, monkeypatch,
     cfg = CASES[case]
 
     def on_chip(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                           sharding=one_chip), tree)
-
-    def bf16(a):
-        return a.astype(cfg.dtype) if a.dtype == jnp.float32 else a
+        return _on(one_chip, tree)
 
     pool = C.init_page_pool(cfg, S, PAGES + 1, PS, None,
                             cfg.kind_count("full"))
@@ -95,8 +107,7 @@ def test_the_tpu_compiler_writes_the_pool_in_place(one_chip, monkeypatch,
     layer = min(a.size // a.shape[0] for n, a in pool.items() if n != "pos")
     table = on_chip(jax.ShapeDtypeStruct((S, MAX_LEN // PS), jnp.int32))
     if what == "tick":
-        params = on_chip(jax.eval_shape(lambda: jax.tree_util.tree_map(
-            bf16, T.init_params(jax.random.PRNGKey(0), cfg))))
+        params = _params(one_chip, cfg)
         compiled = jax.jit(
             lambda p, tok, act, t, wt, pl: T.decode_step_paged(
                 p, tok, pl, t, cfg, act, kernel=True,
@@ -123,3 +134,43 @@ def test_the_tpu_compiler_writes_the_pool_in_place(one_chip, monkeypatch,
     # program holds less than one layer of it
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < layer * 2, mem
+
+
+def test_the_tpu_compiler_keeps_the_picks_sorts_under_a_conditional(
+        one_chip, monkeypatch):
+    """The chat-shaped tick (32 slots, the Mistral vocabulary) with the
+    engine's pick, compiled for the v5e: the conditionals of
+    ``sample_token_rows`` survive, both full-vocabulary sorts lie under a
+    branch and none outside, and the branches took nothing of the pool
+    along — it is still written in place."""
+    monkeypatch.setattr(PA, "use_interpret", lambda: False)
+    slots = 32
+    cfg = T.TransformerConfig(
+        vocab_size=32768, d_model=256, n_heads=2, n_kv_heads=2, d_ff=512,
+        n_layers=2, max_seq=MAX_LEN, dtype=jnp.bfloat16,
+        attention_impl="reference")
+
+    def col(dtype, *more):
+        return _on(one_chip, jax.ShapeDtypeStruct((slots,) + more, dtype))
+
+    params = _params(one_chip, cfg)
+    pool = _on(one_chip, jax.eval_shape(lambda: C.init_page_pool(
+        cfg, slots, PAGES + 1, PS, None, cfg.n_layers)))
+    layer = min(a.size // a.shape[0] for n, a in pool.items() if n != "pos")
+
+    def tick(p, tok, act, table, pl, s_t, s_k, s_p, s_key):
+        logits, out = T.decode_step_paged(p, tok, pl, table, cfg, act,
+                                          kernel=True)
+        return InferenceEngine._pick(logits, pl["pos"], act, s_t, s_k,
+                                     s_p, s_key), out
+
+    compiled = jax.jit(tick, donate_argnums=(4,)).lower(
+        params, col(jnp.int32), col(jnp.bool_),
+        col(jnp.int32, MAX_LEN // PS), pool, col(jnp.float32),
+        col(jnp.int32), col(jnp.float32), col(jnp.uint32, 2)).compile()
+    text = compiled.as_text()
+    conditionals, inside, outside = chip_smoke.sorts_by_conditional(text)
+    assert conditionals >= 1 and len(inside) == 2 and outside == [], (
+        conditionals, inside, outside)
+    offenders, largest = chip_smoke.pool_sized_results(text, layer)
+    assert offenders == [], (offenders, largest)
