@@ -695,6 +695,19 @@ def _prompts(smoke: SmokeConfig) -> List[List[int]]:
             for n in smoke.prompt_lens]
 
 
+def _recording(seen: Dict, name: str, fn):
+    """``fn``, noting under ``seen[name]`` the shapes (and shardings) of
+    its first call's arguments, so that the SAME executable can be
+    lowered and read afterwards."""
+    def call(*args):
+        seen.setdefault(name, jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(
+                np.shape(a), a.dtype,
+                sharding=getattr(a, "sharding", None)), args))
+        return fn(*args)
+    return call
+
+
 def phase_serve(smoke: SmokeConfig, host_params, *, tp: int = 1) -> Dict:
     """The path of ``examples/serve.py``: engine -> warmup -> HTTP server
     -> concurrent /generate -> /stats.  Returns the report, including
@@ -717,19 +730,9 @@ def phase_serve(smoke: SmokeConfig, host_params, *, tp: int = 1) -> Dict:
     # Record the shapes the engine calls its decode tick and its landing
     # with, so the SAME executables can be inspected afterwards.
     seen = {}
-
-    def recording(name, fn):
-        def call(*args):
-            seen.setdefault(name, jax.tree_util.tree_map(
-                lambda a: jax.ShapeDtypeStruct(
-                    np.shape(a), a.dtype,
-                    sharding=getattr(a, "sharding", None)), args))
-            return fn(*args)
-        return call
-
     tick, land = engine._tick_fn, engine.slots._insert
-    engine._tick_fn = recording("tick", tick)
-    engine.slots._insert = recording("land", land)
+    engine._tick_fn = _recording(seen, "tick", tick)
+    engine.slots._insert = _recording(seen, "land", land)
     t0 = time.perf_counter()
     engine.warmup(sorted(set(smoke.prompt_lens)))
     warm_s = round(time.perf_counter() - t0, 1)
@@ -835,15 +838,16 @@ def phase_serve(smoke: SmokeConfig, host_params, *, tp: int = 1) -> Dict:
     return report
 
 
-def _check_against_oracle(smoke, host_params, prompts, tokens):
+def _check_against_oracle(smoke, host_params, prompts, tokens, cfg=None):
     """Teacher-forced logit-level check: run the plain XLA forward
     (reference attention, no kernel of ours) over prompt + the engine's
     own tokens; wherever the oracle's top-1/top-2 margin exceeds the
-    tolerance the engine's token must BE the oracle's argmax."""
+    tolerance the engine's token must BE the oracle's argmax.  ``cfg``:
+    another model than the smoke's own."""
     from horovod_tpu.models import transformer as T
 
-    cfg = dataclasses.replace(smoke.model_cfg(), attention_impl="reference",
-                              remat=False)
+    cfg = dataclasses.replace(cfg or smoke.model_cfg(),
+                              attention_impl="reference", remat=False)
     params = jax.device_put(host_params, jax.devices()[0])
     # One padded batch, one compile: the forward is causal, so right
     # padding cannot reach the positions that are read.
@@ -869,6 +873,82 @@ def _check_against_oracle(smoke, host_params, prompts, tokens):
                      f"{top2[1] - top2[0]:.3f} > {2 * tol:.3f}")
     _require(checked > 0, "no position had a margin wide enough to check")
     return checked
+
+
+def latent_cfg(smoke: SmokeConfig):
+    """The smoke's small LATENT-attention model: rows of 128 + 64 in 256
+    lanes (what the compiled ``hvd_mla_decode`` can tile), a leading
+    dense layer, one shared expert beside 4 held of 8 sigmoid-routed."""
+    from horovod_tpu.models import transformer as T
+
+    return T.TransformerConfig(
+        vocab_size=smoke.vocab_size, d_model=256, n_heads=2, n_layers=3,
+        d_ff=512, max_seq=smoke.serve_max_len, dtype=jnp.dtype(smoke.dtype),
+        attention_impl="flash", q_lora_rank=128, kv_lora_rank=128,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        rope_yarn=(4.0, 64.0, 32.0, 1.0, 1.0, 1.0), n_dense_layers=1,
+        n_experts=8, n_experts_held=4, expert_offset=4, n_experts_per_tok=2,
+        d_expert=256, n_shared_experts=1, moe_score="sigmoid", n_group=2,
+        topk_group=1, norm_topk_prob=True, routed_scaling_factor=2.5,
+        moe_impl="dropless")
+
+
+def phase_serve_latent(smoke: SmokeConfig) -> Dict:
+    """The small latent configuration through the engine on one chip:
+    whole and chunked prompts, the absorbed decode kernel engaged, the
+    tokens ``forward``'s where its margin is clear, and the compiled
+    tick and landing writing the ONE latent pool array in place."""
+    from horovod_tpu import serving
+    from horovod_tpu.models import transformer as T
+
+    cfg = latent_cfg(smoke)
+    params = jax.tree_util.tree_map(
+        lambda a: a.astype(cfg.dtype),
+        T.init_params(jax.random.PRNGKey(smoke.seed + 3), cfg))
+    engine = serving.InferenceEngine(params, cfg, serving.EngineConfig(
+        n_slots=smoke.n_slots, max_len=smoke.serve_max_len,
+        n_pages=smoke.serve_n_pages, prefill_chunk_tokens=32,
+        paged_kernel=None if smoke.expect_compiled else True))
+    seen = {}
+    tick, land = engine._tick_fn, engine.slots._insert
+    engine._tick_fn = _recording(seen, "tick", tick)
+    engine.slots._insert = _recording(seen, "land", land)
+    prompts = _prompts(smoke)
+    futs = [engine.submit(p, max_new_tokens=smoke.max_new_tokens)
+            for p in prompts]
+    while not all(f.done() for f in futs):
+        engine.step()
+    stats = engine.stats()
+    _require(stats["paged_kernel_engaged"] is True
+             and stats["decode_compilations"] == 1,
+             f"latent engine: kernel engaged "
+             f"{stats['paged_kernel_engaged']}, decode compilations "
+             f"{stats['decode_compilations']}")
+    _require(stats["kv_latent_bytes_per_token"] == cfg.n_layers
+             * cfg.latent_row * jnp.dtype(cfg.dtype).itemsize
+             and set(engine.slots.cache) == {"k", "pos"},
+             f"the latent pool's layout: {stats['kv_latent_bytes_per_token']}"
+             f" B a token, arrays {sorted(engine.slots.cache)}")
+    _require(stats["moe_rows_routed_away_total"] > 0,
+             "a share of the experts routed nothing away")
+    text = tick.lower(*seen["tick"]).compile().as_text()
+    _require_compiled(smoke, text, 1, "latent decode tick")
+    layer = int(np.prod(engine.slots.cache["k"].shape[1:]))
+    _require_pool_in_place(smoke, text, layer, "latent decode tick")
+    _require_pool_in_place(
+        smoke, land.lower(*seen["land"]).compile().as_text(), layer,
+        "latent landing")
+    # the tokens are forward's own, where its margin is clear
+    tokens = {i: f.result() for i, f in enumerate(futs)}
+    checked = _check_against_oracle(smoke, params, prompts, tokens, cfg)
+    report = {"requests": len(prompts), "oracle_positions_checked": checked,
+              "kv_latent_bytes_per_token":
+                  stats["kv_latent_bytes_per_token"],
+              "moe_rows_here": stats["moe_rows_total"],
+              "moe_rows_routed_away": stats["moe_rows_routed_away_total"]}
+    _say("latent server: " + json.dumps(report))
+    del engine, params
+    return report
 
 
 def phase_tp(smoke: SmokeConfig, host_params, tp: int, single: Dict) -> Dict:
@@ -901,6 +981,8 @@ def run(smoke: SmokeConfig, *, tp: Optional[int] = None) -> Dict:
     gc.collect()
     report["serve"] = phase_serve(smoke, host_params)
     gc.collect()  # the engine is a reference cycle holding device buffers
+    report["serve_latent"] = phase_serve_latent(smoke)
+    gc.collect()
     if tp:
         report["serve_tp"] = phase_tp(smoke, host_params, tp,
                                       report["serve"])

@@ -1,4 +1,9 @@
-"""The plain reference of a patterned expert model: its forward pass in
+"""The plain references of the served models that are more than the
+uniform block: a patterned expert model (below), and a LATENT-ATTENTION
+expert model (``latent_*``, at the end of the file, with its own
+description there).
+
+The plain reference of a patterned expert model: its forward pass in
 straightforward ``jax.numpy``, float32 at
 ``jax.default_matmul_precision("highest")``, with no kernel, no cache
 and no batching — one sequence, every position against every earlier
@@ -134,5 +139,181 @@ def forward(params, tokens, dims: dict):
         for l, kind in enumerate(dims["layer_types"]):
             w = jax.tree_util.tree_map(lambda a: a[l], params["layers"])
             x = layer(x, w, dims, kind)
+        x = rmsnorm(x, params["ln_f"], dims["rms_norm_eps"])
+        return x @ params["head"].astype(F32)
+
+
+# --- a latent-attention expert model (DeepSeek-V3's block) --------------------
+#
+# The forward pass of the block that ``A.X-K1`` publishes (its config
+# keys are DeepSeek-V3's), in the same plain style: float32 at
+# ``default_matmul_precision("highest")``, one sequence, NON-absorbed
+# attention — every head's K and V expanded from the latent, every
+# position against every earlier one — every held expert computed and
+# masked, no cache, no kernel, nothing imported from the program.
+# ``tests/test_latent_attention.py`` holds ``forward``, whole prefill +
+# paged decode and chunked prefill + decode to its LOGITS.
+#
+# The layer (no bias; ``n = RMSNorm(x)``; ``h = x + Attn(n1)``, ``y = h +
+# FFN(n2)``):
+#
+# * attention: ``cq = RMSNorm(n Wqa)``; ``q = cq Wqb`` -> H heads x (nope
+#   + rope); ``[ckv, kr] = n Wkva``; ``ckv = RMSNorm(ckv)``; ``k_rope =
+#   RoPE(kr)``, ONE per token, shared by all heads; ``q_rope =
+#   RoPE(q_rope)``; ``[k_nope_i, v_i] = ckv Wkvb_i``; scores ``(q_nope_i .
+#   k_nope_i + q_rope_i . k_rope) * s``, causal, softmax in float32; ``o =
+#   concat_i(sum p v_i) Wo``.  RoPE is YaRN over the rope dims; ``s =
+#   (nope + rope)^-0.5 * m^2`` with ``m = 0.1 * mscale_all_dim * ln(factor)
+#   + 1``; cos/sin carry ``yarn_mscale(mscale) / yarn_mscale(mscale_all_dim)``.
+# * experts: ``sc = sigmoid(n Wr)`` (or softmax, by ``scoring_func``);
+#   the experts in ``n_group`` groups, a group's score the sum of its two
+#   largest ``sc``, the ``topk_group`` best groups stay, among theirs the
+#   ``num_experts_per_tok`` largest; weights ``g = sc[sel] / sum(sc[sel])
+#   * routed_scaling_factor``; ``FFN(n) = sum_e g_e E_e(n) + S(n)``, every
+#   ``E_e`` and the shared ``S`` a SwiGLU.  The first
+#   ``first_k_dense_replace`` layers' FFN is one dense SwiGLU.
+# * final RMSNorm and an untied head.
+#
+# A CHIP'S SHARE: ``params`` may hold only the experts ``expert_offset <=
+# e < expert_offset + E_held`` (the stack's own size) of the router's
+# outputs.  Routing and the weights' normalisation are over ALL the
+# router's experts; what the absent experts would add is left out — the
+# partial result is what goes on to the next layer, as in the program.
+#
+# Assumptions (the published config leaves these open), each the
+# program's too: ``topk_method: "none"`` is a value DeepSeek's code does
+# not define — read as NO score-correction bias (that is ``noaux_tc``'s),
+# selection on the raw scores, group-limited as ``n_group`` /
+# ``topk_group`` state, a group scored as DeepSeek-V3 scores one (sum of
+# its top 2); experts outside the kept groups are masked with -inf where
+# DeepSeek's code writes 0.0 (the same choice for positive scores);
+# ``seq_aux`` is a training loss, no part of the forward; the rope pairs
+# dims rotate-half (with seeded weights the published interleaved
+# pairing is a permutation of ``Wqb`` / ``Wkva``'s columns).
+#
+# ``params``: ``embed``, ``head``, ``ln_f``, ``dense_layers`` (the leading
+# dense stack) and ``layers`` (the expert stack), each stacked on a
+# leading axis: ``ln1``, ``ln2``, ``wq_a (D, R)``, ``q_a_norm (R)``, ``wq_b
+# (R, H, nope + rope)``, ``wkv_a (D, C + rope)``, ``kv_a_norm (C)``,
+# ``wkv_b (C, H, nope + v)``, ``wo (H, v, D)``; a dense layer's ``w_gate``
+# / ``w_up (D, F)``, ``w_down (F, D)``; an expert layer's ``router (D,
+# E)``, ``w_gate`` / ``w_up (E_held, D, Fe)``, ``w_down (E_held, Fe, D)``
+# and the shared ``ws_gate`` / ``ws_up (D, Fs)``, ``ws_down (Fs, D)``.
+
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def latent_rope(dims: dict) -> dict:
+    """The ``rope_scaling`` group as :func:`rope_tables` takes it."""
+    rs = dims["rope_scaling"]
+    assert rs["type"] == "yarn", rs
+    f = float(rs["factor"])
+    return {"rope_type": "yarn", "rope_theta": dims["rope_theta"],
+            "factor": f, "original_max_position_embeddings":
+                rs["original_max_position_embeddings"],
+            "beta_fast": rs["beta_fast"], "beta_slow": rs["beta_slow"],
+            "attention_factor": _yarn_mscale(f, rs.get("mscale", 1))
+            / _yarn_mscale(f, rs.get("mscale_all_dim", 0))}
+
+
+def latent_softmax_scale(dims: dict) -> float:
+    s = (dims["qk_nope_head_dim"] + dims["qk_rope_head_dim"]) ** -0.5
+    rs = dims["rope_scaling"]
+    if rs.get("mscale_all_dim", 0):
+        s *= _yarn_mscale(float(rs["factor"]), rs["mscale_all_dim"]) ** 2
+    return s
+
+
+def latent_attention(n, w, dims: dict):
+    S = n.shape[0]
+    eps = dims["rms_norm_eps"]
+    nope, c = dims["qk_nope_head_dim"], dims["kv_lora_rank"]
+    cq = rmsnorm(n @ w["wq_a"].astype(F32), w["q_a_norm"], eps)
+    q = jnp.einsum("sr,rhk->shk", cq, w["wq_b"].astype(F32))
+    q_nope, q_rope = q[..., :nope], q[..., nope:]
+    kv = n @ w["wkv_a"].astype(F32)
+    ckv = rmsnorm(kv[:, :c], w["kv_a_norm"], eps)
+    cos, sin = rope_tables(jnp.arange(S), dims["qk_rope_head_dim"],
+                           latent_rope(dims))
+    q_rope = rotate(q_rope, cos, sin)
+    k_rope = rotate(kv[:, None, c:], cos, sin)[:, 0]       # (S, rope)
+    kvb = jnp.einsum("sc,chk->shk", ckv, w["wkv_b"].astype(F32))
+    k_nope, v = kvb[..., :nope], kvb[..., nope:]
+    s = (jnp.einsum("qhd,khd->hqk", q_nope, k_nope)
+         + jnp.einsum("qhd,kd->hqk", q_rope, k_rope)
+         ) * latent_softmax_scale(dims)
+    i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+    p = jax.nn.softmax(jnp.where((j <= i)[None], s, -jnp.inf), axis=-1)
+    o = jnp.einsum("hqk,khd->qhd", p, v)
+    return jnp.einsum("shk,hkd->sd", o, w["wo"].astype(F32))
+
+
+def latent_route(n, router, dims: dict):
+    """``(S, E)`` combination weights over ALL the router's experts."""
+    logits = n @ router.astype(F32)
+    sc = (jax.nn.sigmoid(logits) if dims["scoring_func"] == "sigmoid"
+          else jax.nn.softmax(logits, axis=-1))
+    S, E = sc.shape
+    choice = sc
+    g = dims.get("n_group", 1)
+    if g > 1:
+        grouped = sc.reshape(S, g, E // g)
+        g_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        _, keep = jax.lax.top_k(g_score, dims["topk_group"])
+        kept = jnp.zeros((S, g), bool).at[
+            jnp.arange(S)[:, None], keep].set(True)
+        choice = jnp.where(kept[:, :, None], grouped, -jnp.inf
+                           ).reshape(S, E)
+    _, top_e = jax.lax.top_k(choice, dims["num_experts_per_tok"])
+    top_g = jnp.take_along_axis(sc, top_e, axis=-1)
+    if dims["norm_topk_prob"]:
+        top_g = top_g / jnp.sum(top_g, axis=-1, keepdims=True)
+    top_g = top_g * dims["routed_scaling_factor"]
+    return jnp.zeros_like(sc).at[jnp.arange(S)[:, None], top_e].set(top_g)
+
+
+def _swiglu(n, gate, up, down):
+    return (jax.nn.silu(n @ gate.astype(F32)) * (n @ up.astype(F32))
+            ) @ down.astype(F32)
+
+
+def latent_experts(n, w, dims: dict):
+    """The held experts' part of the routed sum, and the shared expert
+    (every expert held computed on every position, masked by the weight
+    the router gave it: 0 if not picked)."""
+    weight = latent_route(n, w["router"], dims)
+    off = dims.get("expert_offset", 0)
+    weight = weight[:, off:off + w["w_gate"].shape[0]]
+    gate = jnp.einsum("sd,edf->esf", n, w["w_gate"].astype(F32))
+    up = jnp.einsum("sd,edf->esf", n, w["w_up"].astype(F32))
+    out = jnp.einsum("esf,efd->esd", jax.nn.silu(gate) * up,
+                     w["w_down"].astype(F32))
+    y = jnp.einsum("esd,se->sd", out, weight)
+    if dims.get("n_shared_experts", 0):
+        y = y + _swiglu(n, w["ws_gate"], w["ws_up"], w["ws_down"])
+    return y
+
+
+def latent_layer(x, w, dims: dict):
+    eps = dims["rms_norm_eps"]
+    h = x + latent_attention(rmsnorm(x, w["ln1"], eps), w, dims)
+    n = rmsnorm(h, w["ln2"], eps)
+    if "router" in w:
+        return h + latent_experts(n, w, dims)
+    return h + _swiglu(n, w["w_gate"], w["w_up"], w["w_down"])
+
+
+def latent_forward(params, tokens, dims: dict):
+    """Logits ``(S, V)`` float32 of one sequence ``tokens`` ``(S,)``."""
+    with jax.default_matmul_precision("highest"):
+        x = params["embed"].astype(F32)[tokens]
+        kd = dims["first_k_dense_replace"]
+        for l in range(dims["num_hidden_layers"]):
+            stack, i = (("dense_layers", l) if l < kd
+                        else ("layers", l - kd))
+            w = jax.tree_util.tree_map(lambda a: a[i], params[stack])
+            x = latent_layer(x, w, dims)
         x = rmsnorm(x, params["ln_f"], dims["rms_norm_eps"])
         return x @ params["head"].astype(F32)

@@ -23,6 +23,16 @@ Design notes (TPU):
   ``prefill`` / ``prefill_with_prefix`` / ``decode_step_paged``
   (:func:`_scan_layer_kinds`); the other bodies refuse such a
   configuration (:class:`UnsupportedModelConfigError`).
+* Serving only: latent attention (``kv_lora_rank`` and its four sizes):
+  the cache holds one 576-wide latent a token and layer instead of every
+  head's K and V; whole prompts and a chunk's own block EXPAND it and
+  run the flash forward (``hvd_flash_fwd``), a chunk's landed prefix is
+  expanded in blocks (``hvd_mla_expand``), and a decode tick attends it
+  ABSORBED through the ``hvd_mla_decode`` kernel, its projections under
+  ``hvd_mla_q`` / ``hvd_mla_kv`` / ``hvd_mla_out``.  Beside it: leading
+  dense layers in a stack of their own (``n_dense_layers``), a shared
+  expert (``hvd_moe_shared``), sigmoid group-limited routing, and ONE
+  CHIP'S SHARE of the routed experts (``n_experts_held``).
 """
 
 from __future__ import annotations
@@ -135,8 +145,71 @@ class TransformerConfig:
     n_experts_per_tok: int = 1
     d_expert: int = 0
     norm_topk_prob: bool = False
+    # Latent attention (MLA), set = all five > 0 (the published keys'
+    # meanings): q goes down to ``q_lora_rank``, is normed, and up to
+    # n_heads x (qk_nope_head_dim + qk_rope_head_dim); K/V go down to
+    # ONE ``kv_lora_rank`` latent (normed) plus ONE ``qk_rope_head_dim``
+    # rope key a token, shared by every head, and each head's
+    # ``qk_nope_head_dim`` key and ``v_head_dim`` value are read up
+    # from the latent.  The cache holds the latent and the rope key.
+    # With ``rope_yarn`` a sixth entry, YaRN's ``mscale_all_dim``,
+    # scales the softmax (:attr:`mla_scale`).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # The first ``n_dense_layers`` of the ``n_layers`` take a dense MLP
+    # of width ``d_ff`` (a stack of their own, ``params["dense_layers"]``)
+    # where the rest take experts of width ``d_expert``.
+    n_dense_layers: int = 0
+    # Experts every token passes through, beside the routed ones: one
+    # dense SwiGLU of width ``n_shared_experts * d_expert``.
+    n_shared_experts: int = 0
+    # The router's score ("softmax" | "sigmoid"), the factor its
+    # weights are multiplied by, and group-limited selection (the
+    # experts in ``n_group`` runs, ``topk_group`` of them kept:
+    # ops.moe.route_topk).
+    moe_score: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    n_group: int = 0
+    topk_group: int = 0
+    # One chip's share of an expert-parallel layer: the stack holds the
+    # experts ``expert_offset <= e < expert_offset + n_experts_held`` of
+    # the router's ``n_experts`` (0 = every expert is held).
+    n_experts_held: int = 0
+    expert_offset: int = 0
 
     def __post_init__(self):
+        mla = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
+               self.qk_rope_head_dim, self.v_head_dim)
+        if any(mla) and not all(mla):
+            raise ValueError(
+                "latent attention is its five sizes together (q_lora_rank, "
+                "kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim, "
+                f"v_head_dim); got {mla}")
+        if self.moe_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown moe_score {self.moe_score!r}; "
+                             "expected 'softmax' or 'sigmoid'")
+        if not 0 <= self.n_dense_layers <= self.n_layers:
+            raise ValueError(
+                f"n_dense_layers={self.n_dense_layers} of "
+                f"n_layers={self.n_layers}")
+        if self.n_experts_held and not (
+                0 <= self.expert_offset
+                and self.expert_offset + self.n_experts_held
+                <= self.n_experts):
+            raise ValueError(
+                f"experts {self.expert_offset}..+{self.n_experts_held} "
+                f"are not among the router's {self.n_experts}")
+        if self.latent and self.has_window:
+            raise UnsupportedModelConfigError(
+                "latent attention together with window layers is not "
+                "written (one latent pool, every layer full)")
+        if self.n_dense_layers and self.has_window:
+            raise UnsupportedModelConfigError(
+                "leading dense layers together with a layer pattern are "
+                "not written")
         bad = [k for k in self.layer_pattern if k not in LAYER_KINDS]
         if bad:
             raise ValueError(f"unknown layer kind(s) {bad}; expected "
@@ -160,6 +233,65 @@ class TransformerConfig:
         return self.d_expert or self.d_ff
 
     @property
+    def latent(self) -> bool:
+        """Latent attention (MLA)?"""
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_width(self) -> int:
+        """What a token leaves in a latent cache, a layer: the latent
+        and the one rope key (576 values at the published sizes)."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_row(self) -> int:
+        """The cached row's width in storage: :attr:`latent_width`
+        rounded up to whole 128-lane groups, zeros behind the rope key
+        (640 for 576).  The TPU lays a 576-wide bf16 row out in 640
+        lanes whatever the program says, and a kernel can slice HBM in
+        whole lane groups only: a pool declared 576 wide would be
+        copied into the 640-lane layout for every kernel call."""
+        return -(-self.latent_width // 128) * 128
+
+    @property
+    def mla_scale(self) -> float:
+        """Latent attention's softmax scale: ``(nope + rope)**-0.5``,
+        times ``m**2`` with ``m = 0.1 * mscale_all_dim * ln(factor) + 1``
+        where YaRN states an ``mscale_all_dim`` (``rope_yarn[5]``)."""
+        s = (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        if len(self.rope_yarn) > 5 and self.rope_yarn[5] \
+                and self.rope_yarn[0] > 1:
+            m = 0.1 * self.rope_yarn[5] * math.log(self.rope_yarn[0]) + 1.0
+            s *= m * m
+        return s
+
+    @property
+    def experts_held(self) -> int:
+        """The experts whose weights are HERE (all, or a chip's share)."""
+        return self.n_experts_held or self.n_experts
+
+    @property
+    def held_offset(self):
+        """First held expert, or None when every expert is held: the
+        dispatch then is the one written before a share was."""
+        if self.experts_held == self.n_experts:
+            return None
+        return self.expert_offset
+
+    @property
+    def moe_routing(self) -> dict:
+        """:func:`~horovod_tpu.ops.moe.route_topk`'s keywords beyond the
+        softmax top-k; empty for it."""
+        out = {}
+        if self.moe_score != "softmax":
+            out["score"] = self.moe_score
+        if self.n_group > 1:
+            out.update(n_group=self.n_group, topk_group=self.topk_group)
+        if self.routed_scaling_factor != 1.0:
+            out["scale"] = self.routed_scaling_factor
+        return out
+
+    @property
     def has_window(self) -> bool:
         """Does any layer attend a window (two kinds of KV state)?"""
         return "sliding" in self.layer_pattern
@@ -180,89 +312,132 @@ class TransformerConfig:
 
 
 def init_params(rng, cfg: TransformerConfig) -> Dict:
+    """Seeded parameters as a checkpoint lays them out: ``embed``,
+    ``head``, ``ln_f`` and ``layers`` stacked on a leading axis.  With
+    ``cfg.n_dense_layers`` the leading dense layers are a stack of
+    their own, ``dense_layers``, and ``layers`` holds the rest."""
     keys = jax.random.split(rng, 10)
-    D, H, Dh, F, L, V = (
-        cfg.d_model,
-        cfg.n_heads,
-        cfg.head_dim,
-        cfg.d_ff,
-        cfg.n_layers,
-        cfg.vocab_size,
-    )
-    E = max(cfg.n_experts, 0)
-    if E > 1:
-        F = cfg.expert_width
+    D, V = cfg.d_model, cfg.vocab_size
 
     def norm_init(k, shape, scale):
         return (jax.random.normal(k, shape) * scale).astype(jnp.float32)
 
-    s_d = 1.0 / np.sqrt(D)
-    s_f = 1.0 / np.sqrt(F)
-    layers = {
-        "ln1": jnp.ones((L, D), jnp.float32),
-        "ln2": jnp.ones((L, D), jnp.float32),
-        "wq": norm_init(keys[0], (L, D, H, Dh), s_d),
-        "wk": norm_init(keys[1], (L, D, cfg.kv_heads, Dh), s_d),
-        "wv": norm_init(keys[2], (L, D, cfg.kv_heads, Dh), s_d),
-        "wo": norm_init(keys[3], (L, H, Dh, D), 1.0 / np.sqrt(H * Dh)),
-    }
-    if cfg.qk_norm:
-        layers.update(q_norm=jnp.ones((L, Dh), jnp.float32),
-                      k_norm=jnp.ones((L, Dh), jnp.float32))
-    if E > 1:
-        layers.update(
-            router=norm_init(keys[4], (L, D, E), s_d),
-            w_gate=norm_init(keys[5], (L, E, D, F), s_d),
-            w_up=norm_init(keys[6], (L, E, D, F), s_d),
-            w_down=norm_init(keys[7], (L, E, F, D), s_f),
-        )
-    else:
-        layers.update(
-            w_gate=norm_init(keys[5], (L, D, F), s_d),
-            w_up=norm_init(keys[6], (L, D, F), s_d),
-            w_down=norm_init(keys[7], (L, F, D), s_f),
-        )
-    return {
+    def stack(keys, L, experts: bool):
+        H, Dh = cfg.n_heads, cfg.head_dim
+        F = cfg.expert_width if experts else cfg.d_ff
+        s_d, s_f = 1.0 / np.sqrt(D), 1.0 / np.sqrt(F)
+        layers = {"ln1": jnp.ones((L, D), jnp.float32),
+                  "ln2": jnp.ones((L, D), jnp.float32)}
+        if cfg.latent:
+            ak = jax.random.split(keys[0], 4)
+            R, C = cfg.q_lora_rank, cfg.kv_lora_rank
+            N, Rp, Vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                         cfg.v_head_dim)
+            layers.update(
+                wq_a=norm_init(ak[0], (L, D, R), s_d),
+                q_a_norm=jnp.ones((L, R), jnp.float32),
+                wq_b=norm_init(ak[1], (L, R, H, N + Rp), 1.0 / np.sqrt(R)),
+                wkv_a=norm_init(ak[2], (L, D, C + Rp), s_d),
+                kv_a_norm=jnp.ones((L, C), jnp.float32),
+                wkv_b=norm_init(ak[3], (L, C, H, N + Vd), 1.0 / np.sqrt(C)),
+                wo=norm_init(keys[3], (L, H, Vd, D), 1.0 / np.sqrt(H * Vd)))
+        else:
+            layers.update(
+                wq=norm_init(keys[0], (L, D, H, Dh), s_d),
+                wk=norm_init(keys[1], (L, D, cfg.kv_heads, Dh), s_d),
+                wv=norm_init(keys[2], (L, D, cfg.kv_heads, Dh), s_d),
+                wo=norm_init(keys[3], (L, H, Dh, D), 1.0 / np.sqrt(H * Dh)))
+        if cfg.qk_norm:
+            layers.update(q_norm=jnp.ones((L, Dh), jnp.float32),
+                          k_norm=jnp.ones((L, Dh), jnp.float32))
+        if experts:
+            E = cfg.experts_held
+            layers.update(
+                router=norm_init(keys[4], (L, D, cfg.n_experts), s_d),
+                w_gate=norm_init(keys[5], (L, E, D, F), s_d),
+                w_up=norm_init(keys[6], (L, E, D, F), s_d),
+                w_down=norm_init(keys[7], (L, E, F, D), s_f),
+            )
+            if cfg.n_shared_experts:
+                Fs = cfg.n_shared_experts * F
+                sk = jax.random.split(keys[4], 4)[1:]
+                layers.update(
+                    ws_gate=norm_init(sk[0], (L, D, Fs), s_d),
+                    ws_up=norm_init(sk[1], (L, D, Fs), s_d),
+                    ws_down=norm_init(sk[2], (L, Fs, D), 1.0 / np.sqrt(Fs)))
+        else:
+            layers.update(
+                w_gate=norm_init(keys[5], (L, D, F), s_d),
+                w_up=norm_init(keys[6], (L, D, F), s_d),
+                w_down=norm_init(keys[7], (L, F, D), s_f),
+            )
+        return layers
+
+    params = {
         "embed": norm_init(keys[8], (V, D), 1.0),
-        "layers": layers,
+        "layers": stack(keys, cfg.n_layers - cfg.n_dense_layers,
+                        cfg.n_experts > 1),
         "ln_f": jnp.ones((D,), jnp.float32),
-        "head": norm_init(keys[9], (D, V), s_d),
+        "head": norm_init(keys[9], (D, V), 1.0 / np.sqrt(D)),
     }
+    if cfg.n_dense_layers:
+        params["dense_layers"] = stack(
+            jax.random.split(jax.random.fold_in(rng, 1), 10),
+            cfg.n_dense_layers, False)
+    return params
 
 
 def param_specs(cfg: TransformerConfig) -> Dict:
     """GSPMD sharding rules.  Axes: tp shards heads/ffn/vocab, fsdp shards
     the d_model dim of weights (ZeRO-3 style), pp shards the stacked layer
     axis, ep shards experts."""
-    layers = {
-        "ln1": P("pp", None),
-        "ln2": P("pp", None),
-        "wq": P("pp", "fsdp", "tp", None),
-        "wk": P("pp", "fsdp", "tp", None),
-        "wv": P("pp", "fsdp", "tp", None),
-        "wo": P("pp", "tp", None, "fsdp"),
-    }
-    if cfg.qk_norm:
-        layers.update(q_norm=P("pp", None), k_norm=P("pp", None))
-    if cfg.n_experts > 1:
-        layers.update(
-            router=P("pp", None, None),
-            w_gate=P("pp", "ep", "fsdp", "tp"),
-            w_up=P("pp", "ep", "fsdp", "tp"),
-            w_down=P("pp", "ep", "tp", "fsdp"),
-        )
-    else:
-        layers.update(
-            w_gate=P("pp", "fsdp", "tp"),
-            w_up=P("pp", "fsdp", "tp"),
-            w_down=P("pp", "tp", "fsdp"),
-        )
-    return {
+    def stack(experts: bool):
+        layers = {"ln1": P("pp", None), "ln2": P("pp", None)}
+        if cfg.latent:
+            # the down-projections and their norms are every head's:
+            # replicated; the up-projections split by head
+            layers.update(
+                wq_a=P("pp", "fsdp", None), q_a_norm=P("pp", None),
+                wq_b=P("pp", None, "tp", None),
+                wkv_a=P("pp", "fsdp", None), kv_a_norm=P("pp", None),
+                wkv_b=P("pp", None, "tp", None),
+                wo=P("pp", "tp", None, "fsdp"))
+        else:
+            layers.update(
+                wq=P("pp", "fsdp", "tp", None),
+                wk=P("pp", "fsdp", "tp", None),
+                wv=P("pp", "fsdp", "tp", None),
+                wo=P("pp", "tp", None, "fsdp"))
+        if cfg.qk_norm:
+            layers.update(q_norm=P("pp", None), k_norm=P("pp", None))
+        if experts:
+            layers.update(
+                router=P("pp", None, None),
+                w_gate=P("pp", "ep", "fsdp", "tp"),
+                w_up=P("pp", "ep", "fsdp", "tp"),
+                w_down=P("pp", "ep", "tp", "fsdp"),
+            )
+            if cfg.n_shared_experts:
+                layers.update(ws_gate=P("pp", "fsdp", "tp"),
+                              ws_up=P("pp", "fsdp", "tp"),
+                              ws_down=P("pp", "tp", "fsdp"))
+        else:
+            layers.update(
+                w_gate=P("pp", "fsdp", "tp"),
+                w_up=P("pp", "fsdp", "tp"),
+                w_down=P("pp", "tp", "fsdp"),
+            )
+        return layers
+
+    specs = {
         "embed": P("tp", "fsdp"),
-        "layers": layers,
+        "layers": stack(cfg.n_experts > 1),
         "ln_f": P(None),
         "head": P("fsdp", "tp"),
     }
+    if cfg.n_dense_layers:
+        specs["dense_layers"] = stack(False)
+    return specs
 
 
 def batch_specs() -> Dict:
@@ -280,8 +455,16 @@ def batch_specs() -> Dict:
 #: ``transpose(jvp(<scope>))``), which a profiler trace reports per
 #: device operation — so device time is attributed to a phase of the
 #: program by name.  The Pallas kernels carry names of their own
-#: (``pl.pallas_call(name=)``: ``hvd_paged_attend``, ``hvd_flash_fwd``,
-#: ``hvd_flash_bwd_dq``, ``hvd_flash_bwd_dkv``).
+#: (``pl.pallas_call(name=)``: ``hvd_paged_attend``, ``hvd_mla_decode``,
+#: ``hvd_flash_fwd``, ``hvd_flash_bwd_dq``, ``hvd_flash_bwd_dkv``,
+#: ``hvd_moe_experts``), and so do the scopes that cut a mechanism out of
+#: one of these (an operation reads as its INNERMOST name):
+#: ``hvd_moe_route`` / ``hvd_moe_shared`` inside ``mlp``, and latent
+#: attention's ``hvd_mla_q`` (q down, norm, up, rope, and a tick's
+#: absorption of W_k), ``hvd_mla_kv`` (kv down, norm, rope),
+#: ``hvd_mla_expand`` (latents up through W_kv into heads: a prompt's
+#: own, and a chunk's landed prefix) and ``hvd_mla_out`` (a tick's W_v,
+#: and W_o).
 DEVICE_SCOPES = (
     "embed",          # token-embedding lookup
     "layer_scan",     # the scan over layers' own slicing and stacking
@@ -388,10 +571,26 @@ def _require_uniform(cfg: TransformerConfig, what: str) -> None:
             "by prefill, prefill_with_prefix and decode_step_paged only")
 
 
+def _require_no_latent(cfg: TransformerConfig, what: str) -> None:
+    """Refuse latent attention, leading dense layers and a chip's share
+    of the experts where they are not written (the speculative verify,
+    the pipeline schedules, training's backward)."""
+    for on, name in ((cfg.latent, "latent attention"),
+                     (cfg.n_dense_layers, "leading dense layers"),
+                     (cfg.held_offset is not None,
+                      "a share of the experts (n_experts_held)")):
+        if on:
+            raise UnsupportedModelConfigError(
+                f"{what} is not written for {name}: forward, prefill, "
+                "prefill_with_prefix, decode_step and decode_step_paged "
+                "compute it")
+
+
 _EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
 
 
-def _scan_layer_kinds(cfg: TransformerConfig, layer, init, layers, xs=None):
+def _scan_layer_kinds(cfg: TransformerConfig, layer, init, layers, xs=None,
+                      dense=None):
     """:func:`_scan_layers` for a stack with more than one kind of
     layer.  ``layer(carry, p, kind, xs_l) -> (carry, ys_l)`` is told its
     layer's kind as a Python string; ``xs`` maps a kind to a pytree
@@ -411,8 +610,34 @@ def _scan_layer_kinds(cfg: TransformerConfig, layer, init, layers, xs=None):
     An expert model's three expert matrices are NOT cut out at all:
     ``p["expert_stack"]`` hands the layer every layer's, stacked, with
     the layer's (traced) index — the grouped product reads its experts
-    in place (:func:`~horovod_tpu.ops.moe.grouped_matmul`)."""
+    in place (:func:`~horovod_tpu.ops.moe.grouped_matmul`).
+
+    ``dense``: the stack of the configuration's LEADING dense layers
+    (``params["dense_layers"]``), run before ``layers``; ``xs`` is then
+    stacked over both, in order."""
     xs = xs or {}
+    if dense is not None:
+        # the leading dense stack, then the rest, ONE layer index
+        # running through both: what is stacked over the layers (a
+        # landed prefix, the pool's layer indices) is cut where the
+        # stacks meet, and the results are joined there
+        nd = cfg.n_dense_layers
+
+        def cut(tree, a, b):
+            return jax.tree_util.tree_map(lambda x: x[a:b], tree)
+
+        carry, ys_d = _scan_layer_kinds(
+            dataclasses.replace(cfg, n_layers=nd, n_dense_layers=0,
+                                n_experts=0, n_experts_held=0),
+            layer, init, dense, cut(xs, 0, nd))
+        carry, ys_e = _scan_layer_kinds(
+            dataclasses.replace(cfg, n_layers=cfg.n_layers - nd,
+                                n_dense_layers=0),
+            layer, carry, layers, cut(xs, nd, None))
+        return carry, {k: ys_e[k] if ys_d[k] is None else
+                       jax.tree_util.tree_map(
+                           lambda a, b: jnp.concatenate([a, b]),
+                           ys_d[k], ys_e[k]) for k in ys_e}
     stack = None
     if cfg.n_experts > 1:
         stack = {k: layers[k] for k in _EXPERT_LEAVES}
@@ -501,7 +726,197 @@ def _out_proj(oh, p, cfg: TransformerConfig):
         return jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(cfg.dtype))
 
 
+# --- latent attention (MLA) ---------------------------------------------------
+#
+# The cache of a latent layer is ONE key a token, shared by every head:
+# ``[ckv | k_rope]`` (``kv_lora_rank + qk_rope_head_dim`` wide, 576 at
+# the published sizes), the normed latent and the roped key.  Two ways
+# to attend it, the same mathematics:
+#
+# * EXPANDED: each head's ``k_nope`` and ``v`` read up from the latent
+#   through ``wkv_b`` (``_mla_expand``), then ordinary attention with
+#   q/k ``nope + rope`` wide and v ``v_head_dim`` wide.  Whole prompts,
+#   ``forward``, and a chunk (its own block, and its landed prefix in
+#   blocks: ``_mla_chunk_attend``).
+# * ABSORBED: ``q_lat_h = q_nope_h W_k,h^T`` so that the score is
+#   ``[q_lat_h | q_rope_h] . [ckv | k_rope]`` and the output ``(sum p
+#   ckv) W_v,h``: attention with ONE kv head whose key is the cached
+#   row and whose value is that row's first ``kv_lora_rank`` lanes
+#   (``_mla_absorb_q`` / ``_mla_absorbed_out``).  A decode tick: it
+#   reads the cache as it lies, 1 152 bytes a token, where expanding
+#   would cost 16.8 MFLOP a cached token and tick.
+
+
+def _rope_one(x, cfg: TransformerConfig, pos_offset, positions):
+    """:func:`_rope` (YaRN) on ONE array ``(B, S, heads, rope)``: latent
+    attention ropes its queries' and its one key's rope parts apart."""
+    return _rope(x, x, cfg.rope_theta, pos_offset, positions=positions,
+                 yarn=cfg.rope_yarn)[0]
+
+
+def _mla_q(x, p, cfg: TransformerConfig, pos_offset=0, positions=None):
+    """Latent attention's queries: down, norm, up, rope on the rope
+    part -> ``(q_nope (B, S, H, nope), q_rope (B, S, H, rope))``."""
+    with jax.named_scope("hvd_mla_q"):
+        cq = _rmsnorm(jnp.einsum("bsd,dr->bsr", x,
+                                 p["wq_a"].astype(cfg.dtype)),
+                      p["q_a_norm"], cfg.norm_eps)
+        q = jnp.einsum("bsr,rhk->bshk", cq, p["wq_b"].astype(cfg.dtype))
+        q_nope, q_rope = jnp.split(q, [cfg.qk_nope_head_dim], axis=-1)
+        return q_nope, _rope_one(q_rope, cfg, pos_offset, positions)
+
+
+def _mla_kv(x, p, cfg: TransformerConfig, pos_offset=0, positions=None):
+    """What a token leaves in the cache: ``(B, S, latent_row)`` — the
+    latent after its norm, then the rope key after the rope, then
+    zeros up to the row's stored width."""
+    with jax.named_scope("hvd_mla_kv"):
+        kv = jnp.einsum("bsd,dc->bsc", x, p["wkv_a"].astype(cfg.dtype))
+        ckv, kr = jnp.split(kv, [cfg.kv_lora_rank], axis=-1)
+        ckv = _rmsnorm(ckv, p["kv_a_norm"], cfg.norm_eps)
+        kr = _rope_one(kr[:, :, None], cfg, pos_offset, positions)[:, :, 0]
+        pad = jnp.zeros(kr.shape[:2] + (cfg.latent_row - cfg.latent_width,),
+                        kr.dtype)
+        return jnp.concatenate([ckv, kr, pad], axis=-1)
+
+
+def _mla_expand(lat, p, cfg: TransformerConfig):
+    """Cached rows ``(..., T, latent_row)`` as every head's key and
+    value, head-major: ``(k (..., H, T, nope + rope), v (..., H, T,
+    v_head_dim))`` — ``k_nope`` and ``v`` up through ``wkv_b``, the one
+    rope key repeated for every head."""
+    with jax.named_scope("hvd_mla_expand"):
+        ckv, kr, _ = jnp.split(lat.astype(cfg.dtype),
+                               [cfg.kv_lora_rank, cfg.latent_width], axis=-1)
+        kv = jnp.einsum("...tc,chk->...htk", ckv,
+                        p["wkv_b"].astype(cfg.dtype))
+        k_nope, v = jnp.split(kv, [cfg.qk_nope_head_dim], axis=-1)
+        kr = jnp.broadcast_to(kr[..., None, :, :],
+                              k_nope.shape[:-1] + kr.shape[-1:])
+        return jnp.concatenate([k_nope, kr], axis=-1), v
+
+
+def _mla_heads(q_nope, q_rope):
+    """The expanded form's queries, head-major ``(B, H, S, nope +
+    rope)``."""
+    return jnp.moveaxis(jnp.concatenate([q_nope, q_rope], axis=-1), 2, 1)
+
+
+def _mla_absorb_q(q_nope, q_rope, p, cfg: TransformerConfig):
+    """The absorbed form's queries ``(B, S, H, latent_row)``:
+    ``[q_nope W_k^T | q_rope | 0]``, to be dotted with cached rows."""
+    with jax.named_scope("hvd_mla_q"):
+        w_k = p["wkv_b"][..., :cfg.qk_nope_head_dim].astype(cfg.dtype)
+        q_lat = jnp.einsum("bshn,chn->bshc", q_nope, w_k)
+        pad = jnp.zeros(q_lat.shape[:-1]
+                        + (cfg.latent_row - cfg.latent_width,), q_lat.dtype)
+        return jnp.concatenate([q_lat, q_rope, pad], axis=-1)
+
+
+def _mla_out(o, p, cfg: TransformerConfig, absorbed: bool = False):
+    """Output projection from ``o`` ``(B, S, H, v_head_dim)`` — or,
+    ``absorbed``, from ``o_lat`` ``(B, S, H, kv_lora_rank)``, each
+    head's weighted sum of latents, read up through ``W_v`` first."""
+    with jax.named_scope("hvd_mla_out"):
+        o = o.astype(cfg.dtype)
+        if absorbed:
+            w_v = p["wkv_b"][..., cfg.qk_nope_head_dim:].astype(cfg.dtype)
+            o = jnp.einsum("bshc,chv->bshv", o, w_v)
+        return jnp.einsum("bshv,hvd->bsd", o, p["wo"].astype(cfg.dtype))
+
+
+def _mla_attention(x, p, cfg: TransformerConfig, mesh=None):
+    """Whole-sequence latent attention, expanded: ``(out, latent rows
+    (B, 1, S, latent_row))`` — the rows shaped as the cache block of
+    ONE kv head that a prefill hands back."""
+    from horovod_tpu.ops import attention as attn
+
+    if mesh is not None:
+        raise UnsupportedModelConfigError(
+            "latent attention is not written for a tp mesh")
+    q_nope, q_rope = _mla_q(x, p, cfg)
+    lat = _mla_kv(x, p, cfg)
+    k, v = _mla_expand(lat, p, cfg)
+    qh = _mla_heads(q_nope, q_rope)
+    with jax.named_scope("attn"):
+        if cfg.attention_impl == "reference":
+            oh = attn.reference_attention(qh, k, v, causal=True,
+                                          sm_scale=cfg.mla_scale)
+        elif cfg.attention_impl == "flash":
+            oh = attn.flash_attention(qh, k, v, True, cfg.mla_scale)
+        else:
+            raise UnsupportedModelConfigError(
+                f"latent attention runs attention_impl 'flash' or "
+                f"'reference', not {cfg.attention_impl!r}")
+    return _mla_out(jnp.moveaxis(oh, 1, 2), p, cfg), lat[:, None]
+
+
+#: Rows a chunk expands and attends at a time: ``wkv_b``'s output for
+#: 2048 rows is 64 heads x 2048 x 256 x 2 B = 67 MB (a 16 k prefix
+#: expanded whole would be 671 MB a layer).
+_MLA_PREFIX_BLOCK = 2048
+
+
+def _mla_chunk_attend(q_nope, q_rope, lat, prefix_lat, p0, p,
+                      cfg: TransformerConfig):
+    """A chunk's ``(K, S0)`` queries against their own rows ``lat``
+    ``(K, S0, latent_row)`` (causal) and the landed prefix
+    ``prefix_lat`` ``(P0, latent_row)``, positions ``< p0`` of it —
+    EXPANDED, through the flash forward (``hvd_flash_fwd``).
+
+    The chunk's rows are laid behind the landed ones AT ``p0`` (over the
+    gather's padding: ``P0`` is a power of two of pages), so that row
+    ``j`` of the whole is logical position ``j`` and query ``r`` (at
+    ``p0 + r``) sees ``j <= p0 + r``: one shifted-causal mask, ``col +
+    (start - p0) <= row`` for the block that starts at ``start``.  The
+    whole goes in blocks of :data:`_MLA_PREFIX_BLOCK` rows: each is
+    read up through ``wkv_b`` (``hvd_mla_expand``), attended by the
+    flash kernel with that shift, and folded in by its logsumexp — as
+    many blocks as hold a visible position; what lies past ``p0 + S0``
+    is neither expanded nor attended.
+
+    Why expanded: against ``C`` landed tokens a chunk of 512 costs
+    ``C x 16.8`` MFLOP to expand and ``C x 21.0`` to attend; absorbed,
+    ``C x 71.3`` (every query-head pair dots 576 and sums 512 wide).
+    Measured on the chip at ``C`` = 4 k and 16 k (PERF.md, PR 30).
+
+    -> ``o`` ``(K, S0, H, v_head_dim)`` float32."""
+    from horovod_tpu.ops import attention as attn
+
+    K, S0 = lat.shape[:2]
+    P0 = prefix_lat.shape[0]
+    p0 = jnp.asarray(p0, jnp.int32)
+    qh = _mla_heads(q_nope, q_rope)                       # (K, H, S0, 192)
+    blk = min(_MLA_PREFIX_BLOCK, P0)
+    n_rows = -(-(P0 + S0) // blk) * blk
+    with jax.named_scope("chunk_attn"):
+        rows = jnp.zeros((K, n_rows, lat.shape[-1]), lat.dtype)
+        rows = rows.at[:, :P0].set(prefix_lat.astype(lat.dtype)[None])
+        rows = lax.dynamic_update_slice_in_dim(rows, lat, p0, 1)
+
+    def block(b, carry):
+        o, lse = carry
+        k_b, v_b = _mla_expand(
+            lax.dynamic_slice_in_dim(rows, b * blk, blk, 1), p, cfg)
+        with jax.named_scope("chunk_attn"):
+            o_b, lse_b = attn.flash_attention_shifted(
+                qh, k_b, v_b, b * blk - p0, cfg.mla_scale)
+            new = jnp.logaddexp(lse, lse_b)
+            o = (o * jnp.exp(lse - new)[..., None]
+                 + o_b.astype(jnp.float32) * jnp.exp(lse_b - new)[..., None])
+        return o, new
+
+    H = qh.shape[1]
+    o, _ = lax.fori_loop(
+        0, (p0 + S0 + blk - 1) // blk, block,
+        (jnp.zeros((K, H, S0, cfg.v_head_dim), jnp.float32),
+         jnp.full((K, H, S0), -1e30, jnp.float32)))
+    return jnp.moveaxis(o, 1, 2)
+
+
 def _attention(x, p, cfg: TransformerConfig):
+    if cfg.latent:
+        return _mla_attention(x, p, cfg)[0]
     B, S, D = x.shape
     from horovod_tpu.ops import attention as attn
 
@@ -559,7 +974,8 @@ def _moe_mlp_dense(x, p, cfg: TransformerConfig, return_aux: bool = False):
     expert, combine with the routing one-hot.  Exact and dropless — the
     oracle for the sparse path, and the right choice for decoding (a
     handful of tokens) and tiny E."""
-    if cfg.n_experts_per_tok > 1:
+    if (cfg.n_experts_per_tok > 1 or cfg.moe_routing
+            or cfg.held_offset is not None):
         return _moe_mlp_dense_topk(x, p, cfg, return_aux)
     logits = jnp.einsum("bsd,de->bse", x, p["router"].astype(cfg.dtype))
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
@@ -581,19 +997,25 @@ def _moe_mlp_dense(x, p, cfg: TransformerConfig, return_aux: bool = False):
 def _moe_mlp_dense_topk(x, p, cfg: TransformerConfig, return_aux: bool):
     """Top-k MoE by every expert and a mask: the oracle of the dropless
     dispatch for more than one expert a token (router in float32, the
-    k weights renormalised when ``cfg.norm_topk_prob``)."""
+    k weights renormalised when ``cfg.norm_topk_prob``; any routing
+    ``cfg.moe_routing`` states; of a chip's share, the held experts'
+    columns of the combination alone)."""
     if return_aux:
         raise UnsupportedModelConfigError(
-            "the balance loss is written for one expert a token; "
-            f"n_experts_per_tok={cfg.n_experts_per_tok} serves only")
+            "the balance loss is written for one softmax expert a token; "
+            f"n_experts_per_tok={cfg.n_experts_per_tok}, routing "
+            f"{cfg.moe_routing} and a share of the experts serve only")
     from horovod_tpu.ops import moe
 
     B, S, D = x.shape
     top, gate = moe.route_topk(x.reshape(-1, D), p["router"],
-                               cfg.n_experts_per_tok, cfg.norm_topk_prob)
+                               cfg.n_experts_per_tok, cfg.norm_topk_prob,
+                               **cfg.moe_routing)
     comb = jnp.einsum("tke,tk->te",
                       jax.nn.one_hot(top, cfg.n_experts, dtype=jnp.float32),
                       gate).reshape(B, S, cfg.n_experts)
+    if cfg.held_offset is not None:
+        comb = comb[..., cfg.held_offset:cfg.held_offset + cfg.experts_held]
     g = jnp.einsum("bsd,edf->besf", x, p["w_gate"].astype(cfg.dtype))
     u = jnp.einsum("bsd,edf->besf", x, p["w_up"].astype(cfg.dtype))
     y = jnp.einsum("besf,efd->besd", jax.nn.silu(g) * u,
@@ -605,6 +1027,26 @@ def _moe_mlp_dense_topk(x, p, cfg: TransformerConfig, return_aux: bool):
 def _moe_mlp(x, p, cfg: TransformerConfig, impl: Optional[str] = None,
              return_aux: bool = False, token_mask=None,
              return_counts: bool = False):
+    """The expert layer's FFN: the routed experts (:func:`_moe_routed`)
+    and, where the configuration has them, the shared experts beside —
+    one dense SwiGLU every token passes through, under its own scope
+    ``hvd_moe_shared``, added ONCE whatever share of the routed experts
+    is held here."""
+    out = _moe_routed(x, p, cfg, impl, return_aux, token_mask,
+                      return_counts)
+    if not cfg.n_shared_experts:
+        return out
+    with jax.named_scope("hvd_moe_shared"):
+        shared = _dense_mlp(x, {k: p["ws_" + k[2:]] for k in _EXPERT_LEAVES},
+                            cfg)
+    if return_aux or return_counts:
+        return out[0] + shared, out[1]
+    return out + shared
+
+
+def _moe_routed(x, p, cfg: TransformerConfig, impl: Optional[str] = None,
+                return_aux: bool = False, token_mask=None,
+                return_counts: bool = False):
     """Mixture-of-experts FFN; ``impl`` overrides ``cfg.moe_impl``:
     "switch" (capacity-factor sparse dispatch — training), "dense"
     (every-expert oracle — tiny E), "dropless" (grouped ragged matmuls,
@@ -637,14 +1079,17 @@ def _moe_mlp(x, p, cfg: TransformerConfig, impl: Optional[str] = None,
                               for k in _EXPERT_LEAVES),
             k=cfg.n_experts_per_tok, norm_topk=cfg.norm_topk_prob,
             token_mask=token_mask, return_counts=return_counts,
-            layer=layer)
+            layer=layer, routing=cfg.moe_routing or None,
+            held_offset=cfg.held_offset)
     if impl != "switch":
         raise ValueError(f"unknown moe_impl {impl!r}; "
                          "expected 'switch', 'dense', or 'dropless'")
-    if cfg.n_experts_per_tok > 1:
+    if (cfg.n_experts_per_tok > 1 or cfg.moe_routing
+            or cfg.held_offset is not None):
         raise UnsupportedModelConfigError(
-            "switch dispatch routes one expert a token; "
-            f"n_experts_per_tok={cfg.n_experts_per_tok} needs "
+            "switch dispatch routes one softmax expert a token over "
+            f"every expert; n_experts_per_tok={cfg.n_experts_per_tok}, "
+            f"routing {cfg.moe_routing} or a share of the experts need "
             "moe_impl='dropless' (serving) or 'dense' (the oracle)")
     return moe.switch_moe(
         x, p["router"], p["w_gate"].astype(cfg.dtype),
@@ -669,7 +1114,8 @@ def _mlp_block(x, p, cfg: TransformerConfig, moe_impl: Optional[str] = None,
     (dropless dispatch only; an empty vector for dense MLPs)."""
     with jax.named_scope("mlp"):
         m = _rmsnorm(x, p["ln2"], cfg.norm_eps)
-        if cfg.n_experts > 1:
+        # a leading dense layer of an expert model has no router
+        if cfg.n_experts > 1 and "router" in p:
             out = _moe_mlp(m, p, cfg, impl=moe_impl, return_aux=return_aux,
                            token_mask=token_mask,
                            return_counts=return_counts)
@@ -738,12 +1184,14 @@ def forward(params: Dict, tokens, cfg: TransformerConfig,
 
     if cfg.remat:
         layer = _remat(layer, cfg)
+    carry = (x, jnp.float32(0.0)) if return_aux else x
+    for stack in ("dense_layers", "layers"):   # the leading dense first
+        if stack in params:
+            carry, _ = _scan_layers(layer, carry, params[stack])
     if return_aux:
-        (x, aux), _ = _scan_layers(layer, (x, jnp.float32(0.0)),
-                                   params["layers"])
-        return _lm_head(x, params["ln_f"], params["head"], cfg), aux
-    x, _ = _scan_layers(layer, x, params["layers"])
-    return _lm_head(x, params["ln_f"], params["head"], cfg)
+        return _lm_head(carry[0], params["ln_f"], params["head"],
+                        cfg), carry[1]
+    return _lm_head(carry, params["ln_f"], params["head"], cfg)
 
 
 def loss_fn(params: Dict, batch: Dict, cfg: TransformerConfig):
@@ -752,6 +1200,7 @@ def loss_fn(params: Dict, batch: Dict, cfg: TransformerConfig):
     With ``cfg.moe_aux_coeff > 0`` on an MoE config, adds
     ``coeff * sum_over_layers(aux)`` — the Switch balance term that keeps
     the learned router from collapsing onto few experts."""
+    _require_no_latent(cfg, "loss_fn (training: the backward)")
     if cfg.n_experts > 1 and cfg.moe_aux_coeff > 0.0:
         logits, aux = forward(params, batch["tokens"], cfg, return_aux=True)
         xent = _xent_sum(logits, batch["targets"]) / batch["targets"].size
@@ -771,6 +1220,7 @@ def expert_load(params: Dict, tokens, cfg: TransformerConfig):
     if cfg.n_experts <= 1:
         raise ValueError("expert_load needs an MoE config (n_experts > 1)")
     _require_uniform(cfg, "expert_load")
+    _require_no_latent(cfg, "expert_load")
     x = _embed(params, tokens, cfg)
 
     def layer(x, p):
@@ -915,6 +1365,12 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int = 0) -> Dict:
     growing arrays).  GQA (``n_kv_heads``) shrinks the cache by
     ``n_heads / kv_heads`` — the serving-memory lever."""
     T = max_len or cfg.max_seq
+    if cfg.latent:
+        # one kv "head" whose key is the cached row and whose value is
+        # that row's first kv_lora_rank lanes: ``k`` alone
+        return {"k": jnp.zeros((cfg.n_layers, batch, 1, T,
+                                cfg.latent_row), cfg.dtype),
+                "pos": jnp.zeros((), jnp.int32)}
     return {
         "k": jnp.zeros((cfg.n_layers, batch, cfg.kv_heads, T, cfg.head_dim),
                        cfg.dtype),
@@ -924,13 +1380,15 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int = 0) -> Dict:
     }
 
 
-def _cache_attend(qh, k_cache, v_cache, mask):
+def _cache_attend(qh, k_cache, v_cache, mask, scale=None):
     """One query token per row against the full cache — the ONE copy of
     the decode attention math, shared by the scalar-position path
     (:func:`_attention_decode`) and the unfused paged path
     (:func:`_attention_decode_paged`, on each slot's gathered pages) so
     the bandwidth discipline cannot fork.  ``mask`` is broadcastable to
-    ``(B, H_kv, G, T)``.
+    ``(B, H_kv, G, T)``.  ``scale`` replaces ``1 / sqrt(Dh)`` (latent
+    attention's absorbed form: one kv head, its value the key's first
+    lanes, the scale :attr:`TransformerConfig.mla_scale`).
 
     Bandwidth discipline (decode is cache-bandwidth-bound): the cache is
     dotted IN ITS STORED DTYPE with f32 MXU accumulation
@@ -947,18 +1405,34 @@ def _cache_attend(qh, k_cache, v_cache, mask):
     with jax.named_scope("attn"):
         qg = qh.reshape(B, Hkv, G, Dh)              # one token: drop q dim
         s = jnp.einsum("bkgd,bktd->bkgt", qg.astype(k_cache.dtype), k_cache,
-                       preferred_element_type=jnp.float32) / np.sqrt(Dh)
+                       preferred_element_type=jnp.float32)
+        s = s / np.sqrt(Dh) if scale is None else s * scale
         s = jnp.where(mask, s, -1e30)
         w = jax.nn.softmax(s, axis=-1)
         o = jnp.einsum("bkgt,bktd->bkgd", w.astype(v_cache.dtype), v_cache,
                        preferred_element_type=jnp.float32)
-        return o.reshape(B, H, 1, Dh)
+        return o.reshape(B, H, 1, v_cache.shape[-1])
 
 
 def _attention_decode(x, p, cfg: TransformerConfig, k_cache, v_cache, pos):
     """One-token attention against the cache: write this position's K/V
     at ``pos``, attend q over positions <= pos (static-shape mask; the
-    attention math itself lives in :func:`_cache_attend`)."""
+    attention math itself lives in :func:`_cache_attend`).  Latent
+    attention has no ``v_cache``: the row is written to ``k_cache`` and
+    attended absorbed."""
+    if cfg.latent:
+        q = _mla_absorb_q(*_mla_q(x, p, cfg, pos), p, cfg)  # (B, 1, H, 640)
+        with jax.named_scope("kv_write"):
+            k_cache = lax.dynamic_update_slice_in_dim(
+                k_cache, _mla_kv(x, p, cfg, pos)[:, None].astype(
+                    k_cache.dtype), pos, axis=2)
+        T = k_cache.shape[2]
+        mask = (lax.broadcasted_iota(jnp.int32, (T,), 0) <= pos)
+        o = _cache_attend(jnp.moveaxis(q, 1, 2), k_cache,
+                          k_cache[..., :cfg.kv_lora_rank],
+                          mask[None, None, None, :], cfg.mla_scale)
+        return _mla_out(jnp.moveaxis(o, 1, 2), p, cfg,
+                        absorbed=True), k_cache, None
     qh, k_t, v_t = _qkv_proj(x, p, cfg, pos)        # qh: (B, H, 1, Dh)
     with jax.named_scope("kv_write"):
         k_cache = lax.dynamic_update_slice_in_dim(
@@ -995,16 +1469,20 @@ def decode_step(params: Dict, tokens_t, cache: Dict, cfg: TransformerConfig):
             f"{T_cache}); init_cache with a larger max_len")
     x = _embed(params, tokens_t, cfg)[:, None]  # (B, 1, D)
 
-    def layer(x, inp):
-        p, k_c, v_c = inp
+    def layer(x, p, kind, kv):
         h, k_new, v_new = _attention_decode(
-            _attn_norm(x, p, cfg), p, cfg, k_c, v_c, pos)
+            _attn_norm(x, p, cfg), p, cfg, kv[0], kv[1], pos)
         return _mlp_block(x + h, p, cfg, moe_impl="dense"), (k_new, v_new)
 
-    x, (k_all, v_all) = _scan_layers(
-        layer, x, (params["layers"], cache["k"], cache["v"]))
+    x, ys = _scan_layer_kinds(
+        cfg, layer, x, params["layers"],
+        {"full": (cache["k"], cache.get("v"))}, params.get("dense_layers"))
     logits = _lm_head(x, params["ln_f"], params["head"], cfg)
-    return logits[:, 0], {"k": k_all, "v": v_all, "pos": pos + 1}
+    k_all, v_all = ys["full"]
+    out = {"k": k_all, "pos": pos + 1}
+    if v_all is not None:
+        out["v"] = v_all
+    return logits[:, 0], out
 
 
 # --- paged KV cache (block tables resolved inside the tick) -------------------
@@ -1161,19 +1639,35 @@ def _attention_decode_paged(x, p, cfg: TransformerConfig, kv, layer, table,
     S = x.shape[0]
     max_pages = table.shape[1]
     ps = kv[0].shape[3]
-    qh, k_t, v_t = _qkv_proj(x, p, cfg, positions=pos[:, None], kind=kind)
+    if cfg.latent:
+        # ABSORBED: the row written is the row read, as it lies
+        q = _mla_absorb_q(*_mla_q(x, p, cfg, positions=pos[:, None]), p,
+                          cfg)[:, 0]                     # (S, H, 640)
+        rows = (_mla_kv(x, p, cfg, positions=pos[:, None])[:, None],)
+    else:
+        qh, k_t, v_t = _qkv_proj(x, p, cfg, positions=pos[:, None],
+                                 kind=kind)
+        rows = (k_t, v_t)                  # each (S, H_kv, 1, Dh)
     lower = (jnp.maximum(pos - cfg.window + 1, 0) if kind == "sliding"
              else None)
     with jax.named_scope("kv_write"):
         idx = jnp.clip(pos // ps, 0, max_pages - 1)
         phys = jnp.where(active, table[jnp.arange(S), idx], 0)
         take = jnp.arange(ps, dtype=jnp.int32) == (pos % ps)[:, None]
-        rows = (k_t, v_t)                  # each (S, H_kv, 1, Dh)
         if len(kv) == 4:                   # ... and the (S, H_kv, 1) scales
             (qk, sk), (qv, sv) = kv_quantize(k_t), kv_quantize(v_t)
             rows = (qk, qv, sk, sv)
         kv = tuple(write_pages(stack, layer, phys, row, take)
                    for stack, row in zip(kv, rows))
+    if cfg.latent:
+        from horovod_tpu.ops import paged_attention as _pa
+
+        with jax.named_scope("paged_attend"):
+            limit = jnp.where(active, pos + 1, 0)
+            attend = _pa.mla_decode if kernel else _pa.mla_decode_reference
+            o_lat, _ = attend(q, kv[0], table, limit, layer=layer,
+                              v_dim=cfg.kv_lora_rank, sm_scale=cfg.mla_scale)
+        return _mla_out(o_lat[:, None], p, cfg, absorbed=True), kv
     with jax.named_scope("paged_attend"):
         o = _paged_decode_attend(qh, *kv, *(None,) * (4 - len(kv)), layer,
                                  table, pos, active, cfg, kernel, mesh,
@@ -1319,7 +1813,7 @@ def decode_step_paged(params: Dict, tokens_t, pool: Dict, table,
             wtable if kind == "sliding" else table, pos, active,
             kernel=kernel, mesh=mesh, kind=kind)
         pools = {**pools, **dict(zip(names[kind], kv))}
-        if not moe:
+        if not moe or "router" not in p:   # ... or a leading dense layer
             return (_mlp_block(x + h, p, cfg), pools), None
         # only the active rows' k picks are computed: S * k expert rows
         y, counts = _mlp_block(x + h, p, cfg, moe_impl="dropless",
@@ -1330,7 +1824,7 @@ def decode_step_paged(params: Dict, tokens_t, pool: Dict, table,
         cfg, layer, (x, {n: pool[n] for ns in names.values() for n in ns}),
         params["layers"],
         {kind: jnp.arange(cfg.kind_count(kind), dtype=jnp.int32)
-         for kind in names})
+         for kind in names}, params.get("dense_layers"))
     logits = _lm_head(x, params["ln_f"], params["head"], cfg)
     out = {**pools, "pos": pos + active.astype(jnp.int32)}
     if not return_moe_load:
@@ -1469,6 +1963,7 @@ def decode_verify_paged(params: Dict, window, pool: Dict, table,
     its storage-dtype round trip first, so verify logits keep their
     bit-identity to the sequential one-token path."""
     _require_uniform(cfg, "decode_verify_paged")
+    _require_no_latent(cfg, "decode_verify_paged (speculation)")
     pos = pool["pos"]
     S, W = window.shape
     max_pages = table.shape[1]
@@ -1629,6 +2124,8 @@ def _by_kind(ys: Dict, pos) -> Dict:
     out = {"pos": pos}
     if "full" in ys:
         out["k"], out["v"] = ys["full"]
+        if out["v"] is None:       # latent attention: one array, ``k``
+            del out["v"]
     if "sliding" in ys:
         out["wk"], out["wv"] = ys["sliding"]
     return out
@@ -1668,13 +2165,35 @@ def prefill_with_prefix(params: Dict, suffix, prefix_k, prefix_v,
     ``win_start`` on (a traced scalar: whatever lies behind the first
     query's window was never gathered).  A window layer's query at
     ``i`` sees key ``j`` iff ``j <= i`` and ``i - j < cfg.window``.
-    The returned block then carries ``wk``/``wv`` beside ``k``/``v``."""
+    The returned block then carries ``wk``/``wv`` beside ``k``/``v``.
+
+    With latent attention ``prefix_k`` is the landed latent rows ``(L,
+    1, P0, latent_row)`` and ``prefix_v`` None; the chunk attends them
+    EXPANDED, in blocks (:func:`_mla_chunk_attend`), and the returned
+    block is ``k`` alone."""
     K, S0 = suffix.shape
     P0 = prefix_k.shape[2]
     p0 = jnp.asarray(prefix_len, jnp.int32)
     true_len = jnp.asarray(true_len, jnp.int32)
     positions = p0 + jnp.arange(S0, dtype=jnp.int32)
     x = _embed(params, suffix, cfg)
+    if cfg.latent:
+        # the prefix is the landed latent rows (L, 1, P0, latent_row);
+        # it has no V, and the returned block none either
+        def layer(x, p, kind, kv):
+            h = _attn_norm(x, p, cfg)
+            q_nope, q_rope = _mla_q(h, p, cfg, positions=positions)
+            lat = _mla_kv(h, p, cfg, positions=positions)
+            o = _mla_chunk_attend(q_nope, q_rope, lat, kv[0][0], p0, p, cfg)
+            return (_mlp_block(x + _mla_out(o, p, cfg), p, cfg,
+                               moe_impl=moe_impl), (lat[:, None], None))
+
+        x, ys = _scan_layer_kinds(cfg, layer, x, params["layers"],
+                                  {"full": (prefix_k, None)},
+                                  params.get("dense_layers"))
+        last = jnp.take_along_axis(x, (true_len - 1)[:, None, None], axis=1)
+        logits = _lm_head(last, params["ln_f"], params["head"], cfg)
+        return logits[:, 0], _by_kind(ys, p0 + true_len)
     H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     G = H // Hkv
     # (S0, P0 + S0) mask: the real prefix is fully visible, page-tail
@@ -1727,7 +2246,8 @@ def prefill_with_prefix(params: Dict, suffix, prefix_k, prefix_v,
         out = _out_proj(oh.astype(cfg.dtype), p, cfg)
         return _mlp_block(x + out, p, cfg, moe_impl=moe_impl), (kh, vh)
 
-    x, ys = _scan_layer_kinds(cfg, layer, x, params["layers"], xs)
+    x, ys = _scan_layer_kinds(cfg, layer, x, params["layers"], xs,
+                              params.get("dense_layers"))
     last = jnp.take_along_axis(x, (true_len - 1)[:, None, None], axis=1)
     logits = _lm_head(last, params["ln_f"], params["head"], cfg)
     return logits[:, 0], _by_kind(ys, p0 + true_len)
@@ -1752,6 +2272,8 @@ def _attention_prefill(x, p, cfg: TransformerConfig, mesh=None,
     (blocks wholly behind it skipped)."""
     from horovod_tpu.ops import attention as attn
 
+    if cfg.latent:  # the cache block is the latent rows; there is no V
+        return _mla_attention(x, p, cfg, mesh) + (None,)
     window = cfg.window if kind == "sliding" else 0
     qh, kh, vh = _qkv_proj(x, p, cfg, 0, kind=kind)  # kh/vh: (B,H_kv,S0,Dh)
     if cfg.attention_impl == "reference":
@@ -1832,7 +2354,8 @@ def prefill(params: Dict, prompt, cache: Dict, cfg: TransformerConfig,
         # (ops/moe.py dropless_moe).
         return _mlp_block(x + h, p, cfg, moe_impl=moe_impl), (kh, vh)
 
-    x, ys = _scan_layer_kinds(cfg, layer, x, params["layers"])
+    x, ys = _scan_layer_kinds(cfg, layer, x, params["layers"],
+                              dense=params.get("dense_layers"))
     # Only one position's logits are needed: slice BEFORE the (B, S0, V)
     # head projection.
     if true_len is None:
@@ -1855,15 +2378,12 @@ def prefill(params: Dict, prompt, cache: Dict, cfg: TransformerConfig,
         # two kinds of KV state: handed back by kind for the caller's
         # two pools, not landed in a cache of one shape
         return logits[:, 0], _by_kind(ys, new_pos)
-    k_all, v_all = ys[next(iter(ys))]
+    blocks = dict(zip(("k", "v"), ys[next(iter(ys))]))
     with jax.named_scope("kv_land"):
-        cache = {
-            "k": lax.dynamic_update_slice_in_dim(
-                cache["k"], k_all.astype(cache["k"].dtype), 0, axis=3),
-            "v": lax.dynamic_update_slice_in_dim(
-                cache["v"], v_all.astype(cache["v"].dtype), 0, axis=3),
-            "pos": new_pos,
-        }
+        cache = {n: lax.dynamic_update_slice_in_dim(
+            cache[n], b.astype(cache[n].dtype), 0, axis=3)
+            for n, b in blocks.items() if b is not None}
+        cache["pos"] = new_pos
     return logits[:, 0], cache
 
 
@@ -2090,6 +2610,7 @@ def _pipeline_stage_setup(params: Dict, cfg: TransformerConfig,
     this stage's layer slice, and the scanned stage function (aux-carrying
     when ``return_aux`` — the per-stage MoE balance sum)."""
     _require_uniform(cfg, "the pipeline schedules")
+    _require_no_latent(cfg, "the pipeline schedules")
     P_ = lax.axis_size(axis_name)
     s = lax.axis_index(axis_name)
     if cfg.n_layers % P_:

@@ -231,11 +231,11 @@ def _flash_fwd(q, k, v, shift, sm_scale, block_q: int, block_k: int,
     """shift: None (no mask) or int scalar (traced ok) — shifted causal;
     window: 0, or the static span a row may look back over."""
     B, H, S, D = q.shape
-    T = k.shape[2]
+    T, Dv = k.shape[2], v.shape[3]   # a value may be narrower than a key
     block_q = min(block_q, S)
     block_k = min(block_k, T)
     scale = _sm_scale(q, sm_scale)
-    if not _tileable(S, T, D, block_q, block_k):
+    if not (_tileable(S, T, D, block_q, block_k) and Dv % 8 == 0):
         return _reference_attention_lse(q, k, v, shift, scale, window)
     nq, nk = S // block_q, T // block_k
     kernel = functools.partial(
@@ -243,7 +243,7 @@ def _flash_fwd(q, k, v, shift, sm_scale, block_q: int, block_k: int,
         masked=shift is not None, scale=scale, num_k=nk, window=window)
     qr = q.reshape(B * H, S, D)
     kr = k.reshape(B * H, T, D)
-    vr = v.reshape(B * H, T, D)
+    vr = v.reshape(B * H, T, Dv)
     o, lse = pl.pallas_call(
         kernel,
         grid=(B * H, nq, nk),
@@ -251,25 +251,25 @@ def _flash_fwd(q, k, v, shift, sm_scale, block_q: int, block_k: int,
             _smem_spec(),
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda b, i, j: (b, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
-            _out_sds((B * H, S, D), q.dtype, q),
+            _out_sds((B * H, S, Dv), q.dtype, q),
             _out_sds((B * H, 8, S), jnp.float32, q),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         interpret=_use_interpret(),
         name="hvd_flash_fwd",
     )(_shift_operand(shift, q), qr, kr, vr)
-    return o.reshape(B, H, S, D), lse[:, 0, :].reshape(B, H, S)
+    return o.reshape(B, H, S, Dv), lse[:, 0, :].reshape(B, H, S)
 
 
 def _flash_bwd_dkdv_kernel(shift_ref, q_ref, do_ref, lse_ref, delta_ref,
@@ -471,6 +471,10 @@ def _flash_bwd(shift, sm_scale, block_q, block_k, res, do, dlse=None):
     q, k, v, o, lse = res
     B, H, S, D = q.shape
     T = k.shape[2]
+    if v.shape[3] != D:
+        raise NotImplementedError(
+            f"the flash backward is written for one head size; q/k are "
+            f"{D} wide and v {v.shape[3]} (latent attention serves only)")
     scale = _sm_scale(q, sm_scale)
     bq = min(block_q, S)
     bk = min(block_k, T)

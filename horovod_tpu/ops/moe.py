@@ -227,26 +227,66 @@ def _work_items(counts, m_tiles: int, tm: int):
 
 
 def _grouped_kernel(layer_ref, offs_ref, gid_ref, mid_ref, num_ref,
-                    x_ref, w_ref, o_ref, *, tm: int):
+                    x_ref, w_ref, o_ref, *acc, tm: int, k_tiles: int):
     """One work item: the rows of tile ``mid[t]`` that belong to expert
     ``gid[t]``, times that expert's matrix (the block the index map
     fetched from ``w[layer, gid[t]]``).  The output tile stays resident
     while consecutive items share it; its first visit clears the rows
-    no expert owns."""
+    no expert owns.  A matrix too large for one transfer comes in
+    ``k_tiles`` runs of whole rows (the grid's inner axis), summed in
+    the float32 scratch ``acc`` and written with the last."""
     del layer_ref  # read by the weights' index map
     t = pl.program_id(0)
+    k = pl.program_id(1) if k_tiles > 1 else 0
 
-    @pl.when(t < num_ref[0])
-    def _item():
+    def write(acc):
         e, m = gid_ref[t], mid_ref[t]
         rows = m * tm + lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
         mine = (rows >= offs_ref[e]) & (rows < offs_ref[e + 1])
-        acc = lax.dot_general(x_ref[...], w_ref[...],
-                              (((1,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)
         fresh = (t == 0) | (mid_ref[jnp.maximum(t - 1, 0)] != m)
         old = jnp.where(fresh, 0.0, o_ref[...].astype(jnp.float32))
         o_ref[...] = jnp.where(mine, acc, old).astype(o_ref.dtype)
+
+    @pl.when(t < num_ref[0])
+    def _item():
+        part = lax.dot_general(x_ref[...], w_ref[...],
+                               (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+        if k_tiles == 1:
+            write(part)
+            return
+        acc_ref, = acc
+
+        @pl.when(k == 0)
+        def _first():
+            acc_ref[...] = part
+
+        @pl.when(k > 0)
+        def _more():
+            acc_ref[...] += part
+
+        @pl.when(k == k_tiles - 1)
+        def _last():
+            write(acc_ref[...])
+
+
+#: The most of one expert's matrix a step of the grouped product
+#: fetches (of two such buffers): a whole ``2304 x 896`` bf16 matrix
+#: (4.1 MB) is one step; ``7168 x 2048`` (29 MB, more than the kernel's
+#: VMEM holds twice) goes in 7 runs of 1024 whole rows.
+_EXPERT_BLOCK_BYTES = 9 * 512 * 1024
+
+
+def _k_tile(K: int, N: int, itemsize: int) -> int:
+    """Rows of a ``(K, N)`` expert matrix one step fetches: all, if they
+    fit :data:`_EXPERT_BLOCK_BYTES`; else the largest divisor of ``K``
+    in whole 128s that does (whole rows: one contiguous transfer)."""
+    if K * N * itemsize <= _EXPERT_BLOCK_BYTES or K % 128:
+        return K
+    fits = [d * 128 for d in range(1, K // 128 + 1)
+            if (K // 128) % d == 0
+            and d * 128 * N * itemsize <= _EXPERT_BLOCK_BYTES]
+    return max(fits) if fits else 128
 
 
 def grouped_matmul(xs, w, layer, counts):
@@ -256,8 +296,9 @@ def grouped_matmul(xs, w, layer, counts):
     N)`` EVERY layer's experts as the checkpoint stacks them, ``layer``
     a (traced) index into it.  The weights stay where they are: each
     expert that owns a row has its ``(K, N)`` matrix fetched straight
-    from ``w[layer, e]`` — one contiguous transfer, overlapped with the
-    previous expert's product — so a layer scan hands the kernel the
+    from ``w[layer, e]`` — one contiguous transfer (or :func:`_k_tile`
+    rows of it at a time), overlapped with the previous product — so a
+    layer scan hands the kernel the
     whole stack and no slice of it is ever copied (``lax.ragged_dot``
     is a custom call whose operand a scan must first cut out: a copy of
     every expert, every tick).  Rows past ``sum(counts)`` come back
@@ -268,26 +309,30 @@ def grouped_matmul(xs, w, layer, counts):
     m_tiles = -(-M // tm)
     if m_tiles * tm != M:
         xs = jnp.pad(xs, ((0, m_tiles * tm - M), (0, 0)))
+    tk = _k_tile(K, N, jnp.dtype(w.dtype).itemsize)
+    k_tiles = K // tk
     offsets, gid, mid, num = _work_items(counts, m_tiles, tm)
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
-        grid=(m_tiles + E - 1,),
+        grid=(m_tiles + E - 1, k_tiles),
         in_specs=[
-            pl.BlockSpec((tm, K), lambda t, l, o, g, m, n: (m[t], 0)),
-            pl.BlockSpec((None, None, K, N),
-                         lambda t, l, o, g, m, n: (l[0], g[t], 0, 0)),
+            pl.BlockSpec((tm, tk), lambda t, k, l, o, g, m, n: (m[t], k)),
+            pl.BlockSpec((None, None, tk, N),
+                         lambda t, k, l, o, g, m, n: (l[0], g[t], k, 0)),
         ],
-        out_specs=pl.BlockSpec((tm, N), lambda t, l, o, g, m, n: (m[t], 0)),
+        out_specs=pl.BlockSpec((tm, N),
+                               lambda t, k, l, o, g, m, n: (m[t], 0)),
+        scratch_shapes=([pltpu.VMEM((tm, N), jnp.float32)]
+                        if k_tiles > 1 else []),
     )
     out = pl.pallas_call(
-        functools.partial(_grouped_kernel, tm=tm),
+        functools.partial(_grouped_kernel, tm=tm, k_tiles=k_tiles),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m_tiles * tm, N), xs.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            # two buffers of a whole (K, N) expert matrix, 4 MB each at
-            # 2304 x 896 in bf16
+            dimension_semantics=("arbitrary", "arbitrary"),
+            # two buffers of an expert's block, 4-4.5 MB each
             vmem_limit_bytes=48 * 1024 * 1024),
         interpret=use_interpret(),
         name=EXPERTS_NAME,
@@ -295,24 +340,62 @@ def grouped_matmul(xs, w, layer, counts):
     return out[:M]
 
 
-def route_topk(xt, router, k: int = 1, norm_topk: bool = False):
-    """Softmax routing in float32: ``(experts (T, k) int32, weights
-    (T, k) float32)`` — the ``k`` largest of ``softmax(x @ router)``,
-    renormalised to sum to one when ``norm_topk``."""
+def route_topk(xt, router, k: int = 1, norm_topk: bool = False, *,
+               score: str = "softmax", n_group: int = 0, topk_group: int = 0,
+               scale: float = 1.0):
+    """Routing in float32: ``(experts (T, k) int32, weights (T, k)
+    float32)`` — the ``k`` largest scores of ``x @ router``,
+    renormalised to sum to one when ``norm_topk``, times ``scale``
+    (a published ``routed_scaling_factor``).
+
+    ``score``: ``"softmax"`` over the experts, or ``"sigmoid"`` of each
+    expert's logit alone.  ``n_group`` > 1 limits the choice by GROUPS
+    (the experts in ``n_group`` equal runs of the router's outputs): a
+    group's score is the sum of its two largest experts' scores, the
+    ``topk_group`` best groups stay, and the ``k`` experts are the
+    largest among theirs.  The weights are the chosen experts' OWN
+    scores (no correction bias is written).  Ties go to the lower
+    index, at every step (``lax.top_k``'s rule)."""
     logits = xt.astype(jnp.float32) @ router.astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    if k == 1:
-        return (jnp.argmax(probs, axis=-1).astype(jnp.int32)[:, None],
-                jnp.max(probs, axis=-1)[:, None])
-    gate, e = lax.top_k(probs, k)
-    if norm_topk:
-        gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    if score == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+    elif score == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"unknown router score {score!r}; expected "
+                         "'softmax' or 'sigmoid'")
+    choice = probs
+    if n_group > 1:
+        T, E = probs.shape
+        if E % n_group or not 0 < topk_group <= n_group:
+            raise ValueError(
+                f"{E} experts do not split into {n_group} groups of "
+                f"which {topk_group} stay")
+        grouped = probs.reshape(T, n_group, E // n_group)
+        g_score = jnp.sum(lax.top_k(grouped, 2)[0], axis=-1)
+        _, g_keep = lax.top_k(g_score, topk_group)
+        keep = jnp.zeros((T, n_group), bool).at[
+            jnp.arange(T)[:, None], g_keep].set(True)
+        choice = jnp.where(keep[:, :, None], grouped, -jnp.inf
+                           ).reshape(T, E)
+    if k == 1 and choice is probs:
+        e = jnp.argmax(probs, axis=-1)[:, None]
+        gate = jnp.max(probs, axis=-1)[:, None]
+    else:
+        gate, e = lax.top_k(choice, k)
+        if choice is not probs:
+            gate = jnp.take_along_axis(probs, e, axis=-1)
+        if norm_topk:
+            gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    if scale != 1.0:
+        gate = gate * scale
     return e.astype(jnp.int32), gate
 
 
 def dropless_moe(x, router, w_gate, w_up, w_down, *, k: int = 1,
                  norm_topk: bool = False, token_mask=None,
-                 return_counts: bool = False, layer=None):
+                 return_counts: bool = False, layer=None,
+                 routing: Optional[dict] = None, held_offset=None):
     """Top-k MoE FFN, DROPLESS, via grouped (ragged) matmuls: each
     token's ``k`` rows sorted by expert, the three FFN matmuls as
     ``lax.ragged_dot`` with the per-expert group sizes, unsorted, and
@@ -338,20 +421,45 @@ def dropless_moe(x, router, w_gate, w_up, w_down, *, k: int = 1,
     layer scan needs: see there); without it they are one layer's
     ``(E, ...)`` and run as ``lax.ragged_dot``.  On a device trace the
     routing (scores, top-k, sort, weighted sum) reads ``hvd_moe_route``
-    and the grouped products ``hvd_moe_experts``, in either form."""
+    and the grouped products ``hvd_moe_experts``, in either form.
+
+    ``routing``: :func:`route_topk`'s further keywords (``score``,
+    ``n_group``, ``topk_group``, ``scale``).  ``held_offset``: this is
+    ONE CHIP'S SHARE of an expert-parallel layer — the weights hold
+    only the experts ``held_offset <= e < held_offset + E_held`` of the
+    router's ``E`` (``E_held`` is their leading size).  Every token is
+    still routed over all ``E`` and its weights normalised over all
+    ``k`` picks; the picks whose expert lies elsewhere are left out of
+    every group (a token keeps 0 to ``k`` of its rows), so the result
+    is ``sum over the held picks of g_e E_e(x)`` — the part of the
+    layer's output this chip gives.  Nothing stands in for the other
+    chips' parts.  ``counts`` are then the HELD experts' ``(E_held,)``.
+    ``None`` (every expert held) is the one dispatch written before a
+    share was."""
     lead, D = x.shape[:-1], x.shape[-1]
     xt = x.reshape(-1, D)
     T = xt.shape[0]
-    E = router.shape[1]
+    E = w_gate.shape[-3]                      # experts held here
     dt = x.dtype
+    if held_offset is None and E != router.shape[1]:
+        raise ValueError(
+            f"the router scores {router.shape[1]} experts and the stack "
+            f"holds {E}: a share of the experts needs held_offset")
 
     with jax.named_scope("hvd_moe_route"):
-        e_top, gate = route_topk(xt, router, k, norm_topk)
+        e_top, gate = route_topk(xt, router, k, norm_topk,
+                                 **(routing or {}))
         e_rows = e_top.reshape(-1)            # row r belongs to token r // k
+        here = None
+        if held_offset is not None:
+            e_rows = e_rows - held_offset     # the held experts' own index
+            here = (e_rows >= 0) & (e_rows < E)
         if token_mask is not None:
+            live = jnp.repeat(token_mask.reshape(-1), k)
+            here = live if here is None else here & live
+        if here is not None:
             # expert E is no expert: its rows sort past every group
-            e_rows = jnp.where(jnp.repeat(token_mask.reshape(-1), k),
-                               e_rows, E)
+            e_rows = jnp.where(here, e_rows, E)
         order = jnp.argsort(e_rows, stable=True)
         xs = xt[order if k == 1 else order // k]
         es = e_rows[order]
@@ -370,13 +478,18 @@ def dropless_moe(x, router, w_gate, w_up, w_down, *, k: int = 1,
 
     with jax.named_scope("hvd_moe_route"):
         inv = jnp.argsort(order)  # unsort permutation
+        y_r = y_s[inv]
+        if held_offset is not None:
+            # rows past the groups are whatever the grouped product
+            # left: a pick held elsewhere adds nothing HERE
+            y_r = jnp.where(here[:, None], y_r, jnp.zeros_like(y_r))
         if k == 1:
-            y = y_s[inv] * gate.astype(dt)
+            y = y_r * gate.astype(dt)
         else:
-            y = jnp.sum((y_s[inv].astype(jnp.float32)
+            y = jnp.sum((y_r.astype(jnp.float32)
                          * gate.reshape(-1, 1)).reshape(T, k, D),
                         axis=1).astype(dt)
-        if token_mask is not None:
+        if token_mask is not None and held_offset is None:
             # rows past the groups are whatever the grouped product left
             y = jnp.where(token_mask.reshape(-1, 1), y, jnp.zeros_like(y))
         y = y.reshape(*lead, D)

@@ -75,8 +75,8 @@ from horovod_tpu.ops._pallas_util import (
 )
 
 __all__ = ["DEQUANT_COMPUTE", "UnsupportedPagedLayoutError", "paged_attend",
-           "paged_attend_reference", "kernel_supported", "walk",
-           "first_block"]
+           "paged_attend_reference", "mla_decode", "mla_decode_reference",
+           "kernel_supported", "walk", "first_block"]
 
 
 # The pinned dequant compute dtype.  ``kv_dequantize`` promotes int8
@@ -113,7 +113,8 @@ class UnsupportedPagedLayoutError(ValueError):
     cannot tile (see :func:`kernel_supported`)."""
 
 
-def kernel_supported(storage_dtype, page_size: int, head_dim: int) -> bool:
+def kernel_supported(storage_dtype, page_size: int, head_dim: int,
+                     v_dim=None) -> bool:
     """Whether the COMPILED kernel can serve a pool of this layout.
 
     This is the TPU compiler's rule, whatever backend asks: the page
@@ -122,15 +123,21 @@ def kernel_supported(storage_dtype, page_size: int, head_dim: int) -> bool:
     16 bf16 / 32 int8).  On CPU the interpreter runs any shape, so
     :func:`paged_attend` itself does not consult this; the serving
     engine does, at construction, when it decides whether its ticks use
-    the kernel (``/stats`` ``paged_kernel_engaged``)."""
+    the kernel (``/stats`` ``paged_kernel_engaged``).  ``v_dim``: a
+    latent pool (:func:`mla_decode`), ``head_dim`` its row's width."""
     sub = _MIN_SUBLANE.get(jnp.dtype(storage_dtype).name)
+    # a latent pool's VALUE, its row's first v_dim lanes, is cut at a
+    # lane boundary too
     return (sub is not None and head_dim % 128 == 0
-            and page_size % sub == 0)
+            and page_size % sub == 0 and (v_dim or 0) % 128 == 0)
 
 
 #: The kernel's name on a device trace (``pl.pallas_call(name=)``):
 #: readers of a profile find the call by it, not by operand shapes.
 KERNEL_NAME = "hvd_paged_attend"
+#: ... and the name the same walk carries over a LATENT pool
+#: (:func:`mla_decode`).
+MLA_KERNEL_NAME = "hvd_mla_decode"
 
 # What one step of a slot's walk holds of K (and as much of V) in VMEM,
 # counted at the width it is computed at: an int8 page is widened to the
@@ -141,17 +148,36 @@ KERNEL_NAME = "hvd_paged_attend"
 # long contexts what its rounding costs short ones.
 _BLOCK_BYTES = 256 * 1024
 
+# A step of the walk over a LATENT pool (one array, two buffers of
+# this).  Every fetched row is attended by all 64 heads, so a step
+# carries more arithmetic than a dense pool's, and its fixed costs (the
+# accumulator's rescale: 64 x 512 floats) want longer steps: on a v5e
+# the kernel alone, 32 slots at 2-18 k of context, moves 184 GB/s of
+# needed bytes at 96 tokens a step, 219 at 128, 335 at 384, 364 at 512,
+# 388 at 768, 400 at 1152 (PERF.md, PR 30); past 768 a longer step
+# gains less than its rounding costs contexts of a few thousand.
+_LATENT_BLOCK_BYTES = 1024 * 1024
+
 
 def block_pages(page_size: int, n_kv_heads: int, head_dim: int,
-                storage_dtype, max_pages: int) -> int:
+                storage_dtype, max_pages: int, latent: bool = False) -> int:
     """Pages one step of the walk fetches and attends: as many whole
     pages (every KV head of each) as fit :data:`_BLOCK_BYTES`, never
     more than a slot's table holds.  A function of the pool's layout
     alone — 8 pages (128 tokens) for bf16 pages of 16 x 8 heads x 128,
-    4 pages for int8 pages of 32, 32 for a tp shard's 2 heads."""
+    4 pages for int8 pages of 32, 32 for a tp shard's 2 heads.
+
+    ``latent``: a latent pool's one array (:func:`mla_decode`) takes
+    :data:`_LATENT_BLOCK_BYTES` a step, cut to whole 128-token lane
+    groups (the scores' last dim): 48 pages (768 tokens, 983 KB) for
+    bf16 pages of 16 x 640."""
     width = max(jnp.dtype(storage_dtype).itemsize, 2)
     page_bytes = n_kv_heads * page_size * head_dim * width
-    return max(1, min(_BLOCK_BYTES // page_bytes, max_pages))
+    if not latent:
+        return max(1, min(_BLOCK_BYTES // page_bytes, max_pages))
+    n = max(1, min(_LATENT_BLOCK_BYTES // page_bytes, max_pages))
+    group = max(128 // page_size, 1)
+    return n - n % group if n > group else n
 
 
 def walk(limit, block_tokens: int, lower=None):
@@ -178,8 +204,12 @@ def first_block(lower, block_tokens: int):
 
 
 def _kernel_body(table_ref, limit_ref, *refs, page_size, n_pages,
-                 compute_dtype, quantized, windowed):
+                 compute_dtype, quantized, windowed, v_dim=None,
+                 sm_scale=None):
     """One grid step: slot ``s``, every KV head, the slot's live pages.
+
+    ``v_dim`` (a latent pool): there is no V pool — a fetched row is the
+    key, and its first ``v_dim`` lanes the value.
 
     ``k_hbm``/``v_hbm`` (every layer's pool, read at ``layer_ref[0]``;
     and this layer's scale pools) stay in HBM; the loop
@@ -193,15 +223,19 @@ def _kernel_body(table_ref, limit_ref, *refs, page_size, n_pages,
     if windowed:                  # a third scalar-prefetch operand
         lower_ref, refs = refs[0], refs[1:]
     layer_ref, refs = refs[0], refs[1:]   # the last scalar-prefetch one
-    q_ref, k_hbm, v_hbm = refs[:3]
-    if quantized:
-        ks_hbm, vs_hbm, o_ref, lse_ref, k_buf, v_buf, ks_buf, vs_buf, \
-            sems = refs[3:]
+    latent = v_dim is not None
+    if latent:
+        q_ref, k_hbm, o_ref, lse_ref, k_buf, sems = refs
+        v_hbm = v_buf = None
+    elif quantized:
+        q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, lse_ref, k_buf, v_buf, \
+            ks_buf, vs_buf, sems = refs
     else:
-        o_ref, lse_ref, k_buf, v_buf, sems = refs[3:]
+        q_ref, k_hbm, v_hbm, o_ref, lse_ref, k_buf, v_buf, sems = refs
     s = pl.program_id(0)
     layer = layer_ref[0]
     Hkv, R, Dh = q_ref.shape[1:]
+    Dv = v_dim if latent else Dh
     block_tokens = n_pages * page_size
     # A limit past the table's capacity would index the table out of
     # bounds; the reference attends nothing there either.
@@ -220,7 +254,8 @@ def _kernel_body(table_ref, limit_ref, *refs, page_size, n_pages,
         # an earlier live page (finite), or, before the first fetch,
         # whatever VMEM held.  Its weights are exactly 0, and 0 * junk
         # must stay 0 in the PV product.
-        v_buf[...] = jnp.zeros_like(v_buf)
+        values = k_buf if latent else v_buf
+        values[...] = jnp.zeros_like(values)
         if quantized:
             vs_buf[...] = jnp.zeros_like(vs_buf)
 
@@ -238,8 +273,9 @@ def _kernel_body(table_ref, limit_ref, *refs, page_size, n_pages,
             def _page():
                 # a wait needs the descriptor's shape, not its source
                 at = (0, 0) if wait else (layer, table_ref[s, idx])
-                pairs = [(k_hbm.at[at], k_buf.at[buf, :, i]),
-                         (v_hbm.at[at], v_buf.at[buf, :, i])]
+                pairs = [(k_hbm.at[at], k_buf.at[buf, :, i])]
+                if not latent:
+                    pairs.append((v_hbm.at[at], v_buf.at[buf, :, i]))
                 if quantized:         # one layer's scales: (P, H_kv, lanes)
                     pairs += [(ks_hbm.at[at[1]], ks_buf.at[buf, i]),
                               (vs_hbm.at[at[1]], vs_buf.at[buf, i])]
@@ -271,13 +307,16 @@ def _kernel_body(table_ref, limit_ref, *refs, page_size, n_pages,
 
         fetch(b, buf, wait=True)
         k = k_buf[buf].reshape(Hkv, block_tokens, Dh)
-        v = v_buf[buf].reshape(Hkv, block_tokens, Dh)
+        v = (k[:, :, :Dv] if latent
+             else v_buf[buf].reshape(Hkv, block_tokens, Dh))
         if quantized:  # fused dequant: int8 payload * f32 scale, in-reg
             k = _dequant_col(k, scale_col(ks_buf, buf), compute_dtype)
             v = _dequant_col(v, scale_col(vs_buf, buf), compute_dtype)
         s_blk = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) / np.sqrt(Dh)
+            preferred_element_type=jnp.float32)
+        s_blk = (s_blk / np.sqrt(Dh) if sm_scale is None
+                 else s_blk * sm_scale)
         # Logical-position mask: page-tail junk, the block's dead pages
         # and a partial last page all sit at positions >= limit.
         col = b * block_tokens + jax.lax.broadcasted_iota(
@@ -302,7 +341,7 @@ def _kernel_body(table_ref, limit_ref, *refs, page_size, n_pages,
         b0, n_blocks, block,
         (jnp.full((Hkv, R, 1), NEG_INF, jnp.float32),
          jnp.zeros((Hkv, R, 1), jnp.float32),
-         jnp.zeros((Hkv, R, Dh), jnp.float32)))
+         jnp.zeros((Hkv, R, Dv), jnp.float32)))
     empty = l <= 0.0              # fully-masked row (limit == 0)
     l_safe = jnp.where(empty, 1.0, l)
     o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
@@ -311,11 +350,14 @@ def _kernel_body(table_ref, limit_ref, *refs, page_size, n_pages,
 
 
 def _pallas_paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table,
-                         limit, compute_dtype, lower, layer):
+                         limit, compute_dtype, lower, layer, v_dim=None,
+                         sm_scale=None):
     S, Hkv, R, Dh = qg.shape
     _, _, _, ps, _ = k_pool.shape
     quantized = k_scale is not None
-    n_pages = block_pages(ps, Hkv, Dh, k_pool.dtype, table.shape[1])
+    latent = v_dim is not None
+    Dv = v_dim if latent else Dh
+    n_pages = block_pages(ps, Hkv, Dh, k_pool.dtype, table.shape[1], latent)
 
     # Pad query rows up to a sublane tile so tiny G (or G*W) widths
     # still compile on real hardware; padded rows cost only VPU lanes
@@ -341,12 +383,16 @@ def _pallas_paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table,
     def of_slot(s, *scalars):
         return (s, 0, 0, 0)
 
-    in_specs = [pl.BlockSpec((1, Hkv, R_pad, Dh), of_slot), hbm, hbm]
-    operands = [qg, k_pool, v_pool]
+    in_specs = [pl.BlockSpec((1, Hkv, R_pad, Dh), of_slot), hbm]
+    operands = [qg, k_pool]
     # Buffers are head-major so a block reads as (H_kv, tokens, Dh)
     # with no relayout: page i of a block lands at [:, i].
     buf = (2, Hkv, n_pages, ps, Dh)
-    scratch = [pltpu.VMEM(buf, k_pool.dtype), pltpu.VMEM(buf, v_pool.dtype)]
+    scratch = [pltpu.VMEM(buf, k_pool.dtype)]
+    if not latent:
+        in_specs.append(hbm)
+        operands.append(v_pool)
+        scratch.append(pltpu.VMEM(buf, v_pool.dtype))
     if quantized:
         # Mosaic slices an HBM ref in whole 128-lane rows only, so a
         # page's (H_kv, page) scales travel padded to the lane width.
@@ -362,12 +408,12 @@ def _pallas_paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table,
                     pltpu.VMEM(sc_buf, v_scale.dtype)]
     scratch.append(pltpu.SemaphoreType.DMA((2,)))         # one a buffer
 
-    o_shape = jax.ShapeDtypeStruct((S, Hkv, R_pad, Dh), jnp.float32)
+    o_shape = jax.ShapeDtypeStruct((S, Hkv, R_pad, Dv), jnp.float32)
     # lse rides a sublane-replicated (…, 8, R) layout, like the flash
     # kernel's — callers read row 0.
     lse_shape = jax.ShapeDtypeStruct((S, Hkv, 8, R_pad), jnp.float32)
     out_specs = [
-        pl.BlockSpec((1, Hkv, R_pad, Dh), of_slot),
+        pl.BlockSpec((1, Hkv, R_pad, Dv), of_slot),
         pl.BlockSpec((1, Hkv, 8, R_pad), of_slot),
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -380,20 +426,21 @@ def _pallas_paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table,
     o, lse = pl.pallas_call(
         functools.partial(_kernel_body, page_size=ps, n_pages=n_pages,
                           compute_dtype=compute_dtype, quantized=quantized,
-                          windowed=windowed),
+                          windowed=windowed, v_dim=v_dim, sm_scale=sm_scale),
         grid_spec=grid_spec,
         out_shape=[o_shape, lse_shape],
         # the buffers' zeroing at slot 0 must come first
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=use_interpret(),
-        name=KERNEL_NAME,
+        name=KERNEL_NAME if not latent else MLA_KERNEL_NAME,
     )(*scalars, *operands)
     return o[:, :, :R, :], lse[:, :, 0, :R]
 
 
 def paged_attend_reference(qg, k_pool, v_pool, k_scale, v_scale, table,
-                           limit, *, compute_dtype=None, lower=None):
+                           limit, *, compute_dtype=None, lower=None,
+                           v_dim=None, sm_scale=None):
     """Pure-JAX reference for :func:`paged_attend` — gather, dequant,
     masked softmax — mirroring the unfused decode path's op-for-op
     rounding (``kv_dequantize``'s f32 contract, ``_cache_attend``'s
@@ -419,9 +466,11 @@ def paged_attend_reference(qg, k_pool, v_pool, k_scale, v_scale, table,
         vg = _dequant(gather(v_pool), gather_sc(v_scale), compute_dtype)
     else:
         kg = gather(k_pool)
-        vg = gather(v_pool)
+        # a latent pool: the value is the key's first v_dim lanes
+        vg = gather(v_pool) if v_dim is None else kg[..., :v_dim]
     s = jnp.einsum("skrd,sktd->skrt", qg.astype(kg.dtype), kg,
-                   preferred_element_type=jnp.float32) / np.sqrt(Dh)
+                   preferred_element_type=jnp.float32)
+    s = s / np.sqrt(Dh) if sm_scale is None else s * sm_scale
     T = max_pages * ps
     col = jax.lax.broadcasted_iota(jnp.int32, (T,), 0)[None, :]
     vis = col < limit[:, None]                # (S, T)
@@ -485,3 +534,58 @@ def paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table, limit, *,
             k_scale, v_scale = k_scale[None], v_scale[None]
     return _pallas_paged_attend(qg, k_pool, v_pool, k_scale, v_scale,
                                 table, limit, compute_dtype, lower, layer)
+
+
+def mla_decode(q, pool, table, limit, *, v_dim: int, sm_scale: float,
+               layer=None):
+    """Absorbed latent attention of one token a slot, directly against
+    a paged LATENT pool: kernel :data:`MLA_KERNEL_NAME`, which is
+    :func:`paged_attend`'s walk (table, limits, :func:`walk`,
+    :func:`block_pages`, the double-buffered block loop and its online
+    softmax — one body, ``_kernel_body``) over ONE array: a fetched row
+    ``[ckv | k_rope | 0]`` is the key of all ``H`` heads, and its first
+    ``v_dim`` lanes their value.
+
+    Args:
+      q: ``(S, H, W)`` absorbed queries ``[q_nope W_k^T | q_rope | 0]``,
+        ``W`` the STORED row: ``kv_lora_rank + qk_rope_head_dim`` (576)
+        rounded up to whole 128-lane groups (640), zeros behind.
+      pool: every layer's latent rows ``(L, P, 1, page, W)`` with
+        ``layer`` (traced in a layer scan), or one layer's ``(P, 1,
+        page, W)`` with ``layer=None``.
+      table, limit: as :func:`paged_attend`.
+      v_dim: ``kv_lora_rank`` (512).
+      sm_scale: the softmax scale (``TransformerConfig.mla_scale``).
+
+    Returns ``(o_lat (S, H, v_dim) float32, lse (S, H))``: each head's
+    weighted sum of latents — ``W_v`` and ``W_o`` are applied outside.
+
+    Per cached token the mathematics needs 576 x 2 bytes (the walk
+    fetches the stored 640) and ``H x 2 x (576 + v_dim)`` FLOPs: 1 152 B
+    against 139 kFLOP at the published sizes, 121 FLOPs a byte — under a v5e's 240, so bound by bytes with
+    the MXU half busy."""
+    if layer is None:
+        layer, pool = 0, pool[None]
+    o, lse = _pallas_paged_attend(
+        q[:, None], pool, None, None, None, table, limit, pool.dtype, None,
+        layer, v_dim=v_dim, sm_scale=sm_scale)
+    return o[:, 0], lse[:, 0]
+
+
+def mla_decode_reference(q, pool, table, limit, *, v_dim: int,
+                         sm_scale: float, layer=None):
+    """:func:`mla_decode`'s unfused twin (gather, masked softmax, in
+    XLA): the test oracle, and the tick's attend where the kernel is not
+    engaged (the CPU).  ``pool[layer, table]`` is gathered, so no layer
+    is cut out of the stack."""
+    if layer is not None:
+        S, max_pages = table.shape
+        g = pool[layer, table]             # (S, max_pages, 1, page, W)
+        # already the slots' logical rows: a pool of S * max_pages pages
+        pool = g.reshape((S * max_pages,) + g.shape[2:])
+        table = jnp.arange(S * max_pages, dtype=jnp.int32).reshape(
+            S, max_pages)
+    o, lse = paged_attend_reference(
+        q[:, None], pool, None, None, None, table, limit, v_dim=v_dim,
+        sm_scale=sm_scale)
+    return o[:, 0], lse[:, 0]

@@ -1,7 +1,9 @@
 """The serving engine's KV cache: a pool of fixed-size pages.
 
 The paged layout (PagedAttention, Kwon et al., SOSP 2023) stores K/V as
-a pool of fixed-size pages, ``(L, P, H_kv, page, Dh)``; each of the S
+a pool of fixed-size pages, ``(L, P, H_kv, page, Dh)`` (K and V; a
+latent-attention model's ONE array ``(L, P, 1, page, 640)``, the rows
+its heads share: :func:`init_page_pool`); each of the S
 slots owns an int32 page-table row, resolved INSIDE the compiled decode
 tick (:func:`~horovod_tpu.models.transformer.decode_step_paged`), and a
 per-slot ``(S,)`` write position, because every slot holds a different
@@ -69,6 +71,21 @@ def init_page_pool(cfg: "T.TransformerConfig", n_slots: int, n_pages: int,
     ``n_layers`` overrides the depth: the pool of ONE kind of layer."""
     dt, quant = resolve_kv_dtype(cfg, kv_dtype)
     L = cfg.n_layers if n_layers is None else n_layers
+    if cfg.latent:
+        # latent attention: ONE array.  A token leaves one row a layer,
+        # ``[ckv | k_rope | 0]`` (cfg.latent_row: 576 values in 640
+        # lanes) — the key of one kv "head" every query head shares,
+        # whose first kv_lora_rank lanes are the value too — so
+        # the pool is ``k`` with H_kv = 1 and no ``v``; everything that
+        # addresses pages (write_pages, the landing, COW, the gather)
+        # reads its layout from the array, as before.
+        if quant:
+            raise T.UnsupportedModelConfigError(
+                "int8 pages (per-vector scales) are not written for a "
+                "latent pool")
+        return {"k": jnp.zeros((L, n_pages, 1, page_size,
+                                cfg.latent_row), dt),
+                "pos": jnp.zeros((n_slots,), jnp.int32)}
     Hkv, Dh = cfg.kv_heads, cfg.head_dim
     pool = {
         "k": jnp.zeros((L, n_pages, Hkv, page_size, Dh), dt),
@@ -134,7 +151,7 @@ def landing_pages(bucket: int, page_size: int) -> int:
 
 @jax.named_scope("kv_land")  # T.DEVICE_SCOPES
 def paged_insert(pool: Dict, slots, new_pos, pages, first, lens,
-                 prefilled_k, prefilled_v) -> Dict:
+                 prefilled_k, prefilled_v=None) -> Dict:
     """Land a prefilled K/V block ``(L, K, H_kv, Tb, Dh)`` into pages.
     Column ``t`` of row ``i`` is logical position ``start + t``; with
     ``first = start % page`` it goes to offset ``(first + t) % page``
@@ -148,7 +165,8 @@ def paged_insert(pool: Dict, slots, new_pos, pages, first, lens,
     ``new_pos`` adopt the per-row positions (empty for slotless
     landings — prefix registration).  int8 pools quantize per vector
     on the way in; payload and scale go through the same
-    :func:`write_pages`."""
+    :func:`write_pages`.  A latent pool has ``k`` alone
+    (``prefilled_v`` None): the block is the latent rows."""
     ps = pool["k"].shape[3]
     L, n_pg = pool["k"].shape[0], pages.shape[1]
     first = jnp.asarray(first, jnp.int32)
@@ -166,7 +184,9 @@ def paged_insert(pool: Dict, slots, new_pos, pages, first, lens,
         k, sk = T.kv_quantize(k)
         v, sv = T.kv_quantize(v)
         out["k_scale"], out["v_scale"] = land("k_scale", sk), land("v_scale", sv)
-    out["k"], out["v"] = land("k", k), land("v", v)
+    out["k"] = land("k", k)
+    if v is not None:
+        out["v"] = land("v", v)
     out["pos"] = pool["pos"].at[slots].set(new_pos)
     return out
 
@@ -194,10 +214,11 @@ def gather_prefix_pages(pool: Dict, pages):
     prefill_with_prefix`.  int8 pools dequantize here (f32), so the
     suffix prefill attends real values."""
     k = pool["k"][:, pages]                   # (L, n, H_kv, ps, Dh)
-    v = pool["v"][:, pages]
     L, n, Hkv, ps, Dh = k.shape
     k = jnp.moveaxis(k, 1, 2).reshape(L, Hkv, n * ps, Dh)
-    v = jnp.moveaxis(v, 1, 2).reshape(L, Hkv, n * ps, Dh)
+    if "v" not in pool:                       # a latent pool's rows
+        return k, None
+    v = jnp.moveaxis(pool["v"][:, pages], 1, 2).reshape(L, Hkv, n * ps, Dh)
     if "k_scale" in pool:
         ks = jnp.moveaxis(pool["k_scale"][:, pages], 1, 2
                           ).reshape(L, Hkv, n * ps)
@@ -378,6 +399,8 @@ class PagedSlotCache:
         lever made legible): payload for k+v across layers, plus the
         per-vector scales for int8."""
         elem = jnp.dtype(self._storage_dtype).itemsize
+        if self.cfg.latent:    # one stored row a layer (cfg.latent_row)
+            return self.n_layers * self.cfg.latent_row * elem
         n = self.n_layers * self.cfg.kv_heads
         b = 2 * n * self.cfg.head_dim * elem
         if self.quantized:
@@ -535,7 +558,7 @@ class PagedSlotCache:
             self._land_pages(rows, start, true_lens, bucket),
             np.int32(start % self.page_size),
             np.asarray(true_lens, np.int32), prefilled["k"],
-            prefilled["v"])
+            prefilled.get("v"))
 
     def land(self, slots: Sequence[int], prefilled: Dict,
              true_lens, start: int = 0) -> None:

@@ -610,6 +610,21 @@ class InferenceEngine:
             raise ValueError(
                 f"prefill_chunk_tokens must be >= 1 (or 0 to disable), "
                 f"got {engine_cfg.prefill_chunk_tokens}")
+        if cfg.latent or cfg.n_dense_layers or cfg.held_offset is not None:
+            # Latent attention, leading dense layers and a chip's share
+            # of the experts are written for the paged single-chip tick
+            # and the prefills: every other mode refuses them by name.
+            refused = [why for on, why in (
+                (engine_cfg.tp > 1, "tp > 1"),
+                (self._spec, "speculative=True"),
+                (cfg.latent and resolve_kv_dtype(
+                    cfg, engine_cfg.kv_dtype)[1], "kv_dtype='int8'"),
+            ) if on]
+            if refused:
+                raise T.UnsupportedModelConfigError(
+                    "a configuration with latent attention, leading "
+                    "dense layers or a share of the experts is not "
+                    "served with " + ", ".join(refused))
         if cfg.has_window:
             # Two kinds of KV state live side by side only where they
             # are written: the paged single-chip tick.  Every other
@@ -741,6 +756,8 @@ class InferenceEngine:
         # and auto stays on the unfused XLA tick (the interpreter is
         # faithful but slow) while tests opt in with paged_kernel=True.
         layouts = [(self.slots._storage_dtype, engine_cfg.page_size,
+                    cfg.latent_row, cfg.kv_lora_rank) if cfg.latent else
+                   (self.slots._storage_dtype, engine_cfg.page_size,
                     cfg.head_dim)]
         if self._spec_model:
             layouts.append((draft_cfg.dtype, engine_cfg.page_size,
@@ -750,7 +767,7 @@ class InferenceEngine:
                     if not _pa.kernel_supported(*lay)] if compiled else []
         want = engine_cfg.paged_kernel
         if want and rejected:
-            dt, ps, dh = rejected[0]
+            dt, ps, dh = rejected[0][:3]
             raise _pa.UnsupportedPagedLayoutError(
                 f"paged_kernel=True, but the TPU compiler cannot tile "
                 f"a {jnp.dtype(dt).name} pool with page_size={ps}, "
@@ -930,10 +947,14 @@ class InferenceEngine:
         # kernel's walk covers for this pool (_count_paged_walk; the
         # kernel sees a tp shard's heads).
         self._step_tick = self._step_prefill = self._step_chunk = False
-        self._walk_block_tokens = self.slots.page_size * _pa.block_pages(
-            self.slots.page_size, cfg.kv_heads // engine_cfg.tp,
-            cfg.head_dim, self.slots._storage_dtype,
-            self.slots.max_pages)
+        self._walk_block_tokens = self.slots.page_size * (
+            _pa.block_pages(
+                self.slots.page_size, 1, cfg.latent_row,
+                self.slots._storage_dtype, self.slots.max_pages, True)
+            if cfg.latent else _pa.block_pages(
+                self.slots.page_size, cfg.kv_heads // engine_cfg.tp,
+                cfg.head_dim, self.slots._storage_dtype,
+                self.slots.max_pages))
         # Registered shared prefixes (token tuple -> entry); epoch
         # stamps which cache lifetime the pinned pages belong to.
         self._prefixes: Dict[tuple, _PrefixEntry] = {}
@@ -3106,7 +3127,12 @@ class InferenceEngine:
                 self.metrics.moe_experts_touched.inc(touched)
                 self.metrics.moe_load_max_rows.inc(load_max)
                 self.metrics.moe_load_mean_rows.inc(
-                    rows / self.cfg.n_experts)
+                    rows / self.cfg.experts_held)
+                # a chip's share: the picks whose expert lies elsewhere
+                # (every active slot picks k in every expert layer)
+                self.metrics.moe_rows_routed_away.inc(
+                    int(p["active"].sum()) * self.cfg.n_experts_per_tok
+                    * (self.cfg.n_layers - self.cfg.n_dense_layers) - rows)
             self.metrics.host_syncs.inc()
         with self._phase("tick_host"):
             self._apply_tick(p, nxt, mx, acc, wait.start + wait.dur)
@@ -3903,6 +3929,10 @@ class InferenceEngine:
             } if self._spec else {}),
             "page_size": self.slots.page_size,
             "kv_dtype": str(jnp.dtype(self.slots._storage_dtype).name),
+            # what a token leaves in a LATENT pool, every layer's row
+            # (0: the pool holds every head's K and V, kv_bytes_per_token)
+            "kv_latent_bytes_per_token":
+                self.slots.bytes_per_token if self.cfg.latent else 0,
             "kv_pages_high_water": self.slots.pages_high_water,
             "kv_window_pages_per_slot_bound":
                 self.wslots.window_pages_bound

@@ -115,7 +115,10 @@ class ServingMetrics:
       over layers: expert rows computed (active slots x experts a
       token), experts handed at least one row, the largest expert's
       rows, and rows / experts (``max / mean`` is the imbalance).  They
-      ride out with the tick's tokens: no extra host sync.
+      ride out with the tick's tokens: no extra host sync.  Of ONE
+      CHIP'S SHARE of the experts these count what is routed HERE, and
+      ``moe_rows_routed_away`` the picks whose expert another chip
+      holds (0 where every expert is held).
     * ``kv_pages_total`` / ``kv_pages_free`` / ``kv_pages_shared`` /
       ``kv_bytes_per_token`` — page-pool pressure gauges for the paged
       KV cache (docs/serving.md "Paged KV cache"): pool size, free
@@ -264,6 +267,12 @@ class ServingMetrics:
             "serving_moe_rows_total",
             "Expert rows computed by decode ticks, summed over layers "
             "(active slots x experts a token)")
+        self.moe_rows_routed_away = r.counter(
+            "serving_moe_rows_routed_away_total",
+            "Decode ticks' expert picks whose expert another chip holds "
+            "(a chip's share of an expert-parallel layer; 0 where every "
+            "expert is held): rows / (rows + routed away) is the share "
+            "that lands here")
         self.moe_experts_touched = r.counter(
             "serving_moe_experts_touched_total",
             "Experts handed at least one row by a decode tick, summed "
@@ -452,6 +461,7 @@ class ServingMetrics:
             "sample_ticks_sortfree_total":
                 self.sample_ticks_sortfree.value,
             "moe_rows_total": self.moe_rows.value,
+            "moe_rows_routed_away_total": self.moe_rows_routed_away.value,
             "moe_experts_touched_total": self.moe_experts_touched.value,
             "moe_load_max_rows_total": self.moe_load_max_rows.value,
             "moe_load_mean_rows_total": self.moe_load_mean_rows.value,

@@ -24,6 +24,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 import chip_smoke  # noqa: E402
 from horovod_tpu.models import transformer as T  # noqa: E402
+from horovod_tpu.ops import moe as MOE  # noqa: E402
 from horovod_tpu.ops import paged_attention as PA  # noqa: E402
 from horovod_tpu.serving import cache as C  # noqa: E402
 from horovod_tpu.serving.engine import InferenceEngine  # noqa: E402
@@ -79,6 +80,17 @@ CASES = {
     "uniform": _cfg(n_layers=2),
     "patterned": _cfg(n_layers=4, window=64,
                       layer_pattern=("sliding", "full")),
+    # latent attention over ONE pool array (rows of 128 + 64 in 256
+    # lanes), a leading dense layer, a share of sigmoid-routed experts
+    "latent": _cfg(n_layers=3, n_dense_layers=1, q_lora_rank=128,
+                   kv_lora_rank=128, qk_nope_head_dim=128,
+                   qk_rope_head_dim=64, v_head_dim=128,
+                   rope_yarn=(4.0, 64.0, 32.0, 1.0, 1.0, 1.0),
+                   n_experts=8, n_experts_held=4, expert_offset=4,
+                   n_experts_per_tok=2, d_expert=256, n_shared_experts=1,
+                   moe_score="sigmoid", n_group=2, topk_group=1,
+                   norm_topk_prob=True, routed_scaling_factor=2.5,
+                   moe_impl="dropless"),
 }
 
 
@@ -92,6 +104,7 @@ def test_the_tpu_compiler_writes_the_pool_in_place(one_chip, monkeypatch,
     through and the writes the compiler aliased to it; and both need
     next to no temporary memory beside the pool."""
     monkeypatch.setattr(PA, "use_interpret", lambda: False)
+    monkeypatch.setattr(MOE, "use_interpret", lambda: False)
     cfg = CASES[case]
 
     def on_chip(tree):
@@ -117,16 +130,18 @@ def test_the_tpu_compiler_writes_the_pool_in_place(one_chip, monkeypatch,
                 on_chip(jax.ShapeDtypeStruct((S,), jnp.bool_)), table,
                 table, pool).compile()
         assert "tpu_custom_call" in compiled.as_text()
+        if cfg.latent:      # the latent walk is the kernel in the tick
+            assert PA.MLA_KERNEL_NAME in compiled.as_text()
     else:
         full = {n: a for n, a in pool.items() if n not in ("wk", "wv")}
         blk = on_chip(jax.ShapeDtypeStruct(
-            (cfg.kind_count("full"), 2, cfg.kv_heads, 128, cfg.head_dim),
-            cfg.dtype))
+            (cfg.kind_count("full"), 2) + pool["k"].shape[2:3] + (128,)
+            + pool["k"].shape[4:], cfg.dtype))
         i32 = lambda *shape: on_chip(  # noqa: E731
             jax.ShapeDtypeStruct(shape, jnp.int32))
         compiled = jax.jit(C.paged_insert, donate_argnums=(0,)).lower(
             full, i32(2), i32(2), i32(2, C.landing_pages(128, PS)), i32(),
-            i32(2), blk, blk).compile()
+            i32(2), blk, *([blk] if "v" in pool else [])).compile()
     offenders, largest = chip_smoke.pool_sized_results(compiled.as_text(),
                                                        layer)
     assert offenders == [], (offenders, largest)
