@@ -17,7 +17,8 @@ families sit on their supported tiling):
 2. **trainer** — ``hvd.init()`` → ``hvd.DistributedOptimizer(optax.adamw)``
    → ``jax.jit(spmd.shard(step), donate_argnums=...)`` exactly as
    ``benchmarks/transformer.py`` builds it, a few steps on a fixed batch:
-   loss finite and lower at the end.
+   loss finite and lower at the end; the compiled step holds ONE
+   ``hvd_flash_fwd`` call (remat ``dots`` saves what the backward reads).
 3. **server** — ``serving.InferenceEngine`` with its defaults left alone
    (paged, overlapped, ``paged_kernel=None``, pages of 16, bf16 KV) →
    ``warmup`` → ``serving.ServingServer(port=0)`` → concurrent
@@ -151,6 +152,16 @@ def _require_compiled(smoke: SmokeConfig, text: str, at_least: int,
     _require(n >= at_least,
              f"{what}: {n} Mosaic custom call(s) in the compiled "
              f"executable, expected at least {at_least}")
+
+
+def kernel_calls(text: str, name: str) -> int:
+    """How many Mosaic custom calls of a compiled program are the Pallas
+    kernel ``name`` (its ``pl.pallas_call(name=)``, which the call's
+    ``op_name`` carries).  A scanned layer counts once: the loop's body
+    holds the call, whatever the depth."""
+    return sum(1 for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line
+               and f"/{name}/" in line)
 
 
 # What may have a result the size of the KV pool in a compiled program
@@ -641,9 +652,17 @@ def phase_train(smoke: SmokeConfig):
     t0 = time.perf_counter()
     compiled = step.lower(params, opt_state, batch).compile()
     report["compile_s"] = round(time.perf_counter() - t0, 1)
-    # forward + dk/dv + dq kernels in the scanned layer (+ remat's
-    # forward again)
-    _require_compiled(smoke, compiled.as_text(), 3, "train step")
+    # forward + dk/dv + dq kernels in the scanned layer, and NO second
+    # forward in the backward loop: remat "dots" saved its two results
+    text = compiled.as_text()
+    _require_compiled(smoke, text, 3, "train step")
+    if smoke.expect_compiled:
+        n_fwd = kernel_calls(text, "hvd_flash_fwd")
+        report["flash_fwd_calls"] = n_fwd
+        _require(n_fwd == 1,
+                 f"train step: {n_fwd} hvd_flash_fwd calls in the compiled "
+                 "step, expected 1 (the forward loop's; the backward pass "
+                 "reads the saved output and log-sum-exp)")
 
     losses: List[float] = []
     for _ in range(smoke.train_steps):

@@ -117,10 +117,15 @@ class TransformerConfig:
     # FLOPs for O(n_layers) less HBM — the standard long-context /
     # big-batch lever on TPU where HBM, not MXU, binds.
     remat: bool = False
-    # Remat granularity: "full" recomputes everything (max memory
-    # savings); "dots" keeps matmul outputs resident and recomputes only
-    # the cheap elementwise work (jax checkpoint_dots policy) — much less
-    # recompute when HBM still fits the dot outputs.
+    # Remat granularity: "full" saves nothing and recomputes everything
+    # (max memory savings); "dots" keeps what is dear to recompute — the
+    # matmul outputs (jax checkpoint_dots policy) and the flash forward
+    # kernel's output and log-sum-exp — and redoes only the cheap
+    # elementwise work.  The kernel's two arrays cost one activation
+    # (B, S, n_heads, d_head) + an f32 row (B, n_heads, S) a layer (ring
+    # attention: a pair per chunk of the ring), ~10 % over the dot
+    # outputs; without them the backward pass runs the whole forward
+    # kernel a second time.
     remat_policy: str = "full"
     # Head size when it is a key of its own (0 = d_model / n_heads): the
     # q projection is then d_model -> n_heads * d_head.
@@ -1138,8 +1143,12 @@ def _remat(layer, cfg: TransformerConfig):
     if cfg.remat_policy == "full":
         return jax.checkpoint(layer)
     if cfg.remat_policy == "dots":
-        return jax.checkpoint(
-            layer, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+        from horovod_tpu.ops import attention as attn
+        policies = jax.checkpoint_policies
+        return jax.checkpoint(layer, policy=policies.save_from_both_policies(
+            policies.dots_with_no_batch_dims_saveable,
+            policies.save_only_these_names(attn.FLASH_OUT_NAME,
+                                           attn.FLASH_LSE_NAME)))
     raise ValueError(f"unknown remat_policy {cfg.remat_policy!r}; "
                      "expected 'full' or 'dots'")
 

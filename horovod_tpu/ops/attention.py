@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 NEG_INF = -1e30  # finite mask value: exp(NEG_INF - anything_real) == 0
 
@@ -534,9 +535,26 @@ def flash_attention(q, k, v, causal: bool = False,
     return o
 
 
+# The names a checkpoint policy keeps the forward kernel's two results
+# under (models/transformer.py _remat, "dots"): saved, the backward pass
+# reads them; not saved, it runs the whole kernel again to get them back.
+FLASH_OUT_NAME = "hvd_flash_out"
+FLASH_LSE_NAME = "hvd_flash_lse"
+
+
+def _flash_fwd_saved(q, k, v, shift, sm_scale, block_q, block_k):
+    """:func:`_flash_fwd` for the forward RULES below: ``(o, lse)`` named,
+    so the arrays a rule returns AND keeps as residuals are ones a
+    ``save_only_these_names`` policy can hold.  Outside ``jax.checkpoint``
+    the names do nothing; the primal bodies (serving) never get here."""
+    o, lse = _flash_fwd(q, k, v, shift, sm_scale, block_q, block_k)
+    return (checkpoint_name(o, FLASH_OUT_NAME),
+            checkpoint_name(lse, FLASH_LSE_NAME))
+
+
 def _fa_fwd(q, k, v, causal, sm_scale, block_q, block_k):
-    o, lse = _flash_fwd(q, k, v, 0 if causal else None, sm_scale,
-                        block_q, block_k)
+    o, lse = _flash_fwd_saved(q, k, v, 0 if causal else None, sm_scale,
+                              block_q, block_k)
     return o, (q, k, v, o, lse)
 
 
@@ -575,8 +593,8 @@ def flash_attention_with_lse(q, k, v, causal: bool = False,
 
 
 def _fal_fwd(q, k, v, causal, sm_scale, block_q, block_k):
-    o, lse = _flash_fwd(q, k, v, 0 if causal else None, sm_scale,
-                        block_q, block_k)
+    o, lse = _flash_fwd_saved(q, k, v, 0 if causal else None, sm_scale,
+                              block_q, block_k)
     return (o, lse), (q, k, v, o, lse)
 
 
@@ -609,7 +627,7 @@ def flash_attention_shifted(q, k, v, shift,
 
 
 def _fas_fwd(q, k, v, shift, sm_scale, block_q, block_k):
-    o, lse = _flash_fwd(q, k, v, shift, sm_scale, block_q, block_k)
+    o, lse = _flash_fwd_saved(q, k, v, shift, sm_scale, block_q, block_k)
     return (o, lse), (q, k, v, o, lse, shift)
 
 

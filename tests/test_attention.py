@@ -5,6 +5,8 @@ The reference has no attention ops (SURVEY.md §5.7) — these cover the
 TPU-native long-context extensions.  Oracle: O(S^2) reference_attention.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -164,6 +166,88 @@ class TestFlashShifted:
         for a, b, name in zip(g, gr, "qkv"):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=2e-3, rtol=2e-3, err_msg=name)
+
+
+def _pallas_kernels(jaxpr):
+    """Every Pallas call of a traced program, by its ``name=``."""
+    from conftest import _sub_jaxprs
+
+    names = [eqn.params["name"] for eqn in jaxpr.eqns
+             if eqn.primitive.name == "pallas_call"]
+    for eqn in jaxpr.eqns:
+        for sub in _sub_jaxprs(eqn):
+            names.extend(_pallas_kernels(sub))
+    return sorted(names)
+
+
+class TestFlashUnderRemat:
+    """What a checkpointed layer keeps of the forward kernel: under
+    ``"dots"`` its output and log-sum-exp are saved residuals, so the
+    backward pass holds the two backward kernels and NO second forward;
+    ``"full"`` saves nothing and runs it again."""
+
+    B, S, H, D = 1, 128, 2, 32
+
+    def _layer(self, wrapper):
+        """Projections, one of the three custom-vjp wrappers, the output
+        projection: a layer whose matmul outputs ``"dots"`` saves."""
+        B, S, H, D = self.B, self.S, self.H, self.D
+
+        def attend(q, k, v):
+            if wrapper == "plain":
+                return A.flash_attention(q, k, v, True, None, 64, 64)
+            if wrapper == "with_lse":
+                o, lse = A.flash_attention_with_lse(q, k, v, True, None,
+                                                    64, 64)
+            else:
+                o, lse = A.flash_attention_shifted(q, k, v, jnp.int32(-32),
+                                                   None, 64, 64)
+            # the lse reaches the output: its cotangent is not zero
+            return o * jax.nn.sigmoid(lse)[..., None]
+
+        def layer(x, w):
+            q, k, v = (
+                (x @ w[n]).reshape(B, S, H, D).transpose(0, 2, 1, 3)
+                for n in ("wq", "wk", "wv"))
+            o = attend(q, k, v)
+            return x + o.transpose(0, 2, 1, 3).reshape(B, S, H * D) @ w["wo"]
+
+        return layer
+
+    def _inputs(self):
+        d = self.H * self.D
+        ks = jax.random.split(jax.random.PRNGKey(3), 5)
+        w = {n: jax.random.normal(k, (d, d)) * d ** -0.5
+             for n, k in zip(("wq", "wk", "wv", "wo"), ks)}
+        return jax.random.normal(ks[4], (self.B, self.S, d)), w
+
+    @pytest.mark.parametrize("wrapper", ["plain", "with_lse", "shifted"])
+    def test_dots_saves_the_forward_kernels_results(self, wrapper):
+        from horovod_tpu.models import transformer as T
+
+        layer, (x, w) = self._layer(wrapper), self._inputs()
+        cfg = T.TransformerConfig(
+            vocab_size=8, d_model=8, n_heads=1, n_layers=1, d_ff=8)
+
+        def grad_of(policy):
+            f = layer if policy is None else T._remat(
+                layer, dataclasses.replace(cfg, remat_policy=policy))
+            return jax.grad(lambda x, w: jnp.sum(f(x, w) ** 2),
+                            argnums=(0, 1))
+
+        bwd = ["hvd_flash_bwd_dkv", "hvd_flash_bwd_dq"]
+        for policy, forwards in ((None, 1), ("dots", 1), ("full", 2)):
+            kernels = _pallas_kernels(
+                jax.make_jaxpr(grad_of(policy))(x, w).jaxpr)
+            assert kernels == bwd + ["hvd_flash_fwd"] * forwards, (
+                policy, kernels)
+        # the saved arrays ARE the ones a rerun would give: equal, not close
+        plain = jax.jit(grad_of(None))(x, w)
+        dots = jax.jit(grad_of("dots"))(x, w)
+        for a, b in zip(jax.tree_util.tree_leaves(dots),
+                        jax.tree_util.tree_leaves(plain)):
+            assert np.abs(np.asarray(b)).max() > 0
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 class TestRingAttention:

@@ -4,8 +4,10 @@ compile for it here (nothing runs).  What is held: the paged decode tick
 and the landing of a prefill write the KV pool IN PLACE — layout
 assignment is the TPU compiler's, so the CPU tests of
 ``tests/test_paged.py`` cannot see it, and ``chip_smoke.py`` sees it
-only on the chip; and the tick's next-token pick keeps its conditionals
-(a compiler that ran both branches and selected would sort every tick).
+only on the chip; the tick's next-token pick keeps its conditionals
+(a compiler that ran both branches and selected would sort every tick);
+and the training step under remat ``dots`` runs the flash forward kernel
+once, for the two arrays it saves.
 
 Keep every such compile in THIS file (one process may hold libtpu), and
 describe the topology only inside the fixture below.
@@ -16,6 +18,7 @@ import sys
 
 import jax
 import jax.numpy as jnp
+import optax
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -24,6 +27,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 import chip_smoke  # noqa: E402
 from horovod_tpu.models import transformer as T  # noqa: E402
+from horovod_tpu.ops import attention as ATT  # noqa: E402
 from horovod_tpu.ops import moe as MOE  # noqa: E402
 from horovod_tpu.ops import paged_attention as PA  # noqa: E402
 from horovod_tpu.serving import cache as C  # noqa: E402
@@ -189,3 +193,57 @@ def test_the_tpu_compiler_keeps_the_picks_sorts_under_a_conditional(
         conditionals, inside, outside)
     offenders, largest = chip_smoke.pool_sized_results(text, layer)
     assert offenders == [], (offenders, largest)
+
+
+@pytest.mark.parametrize("policy,forwards", [("dots", 1), ("full", 2)])
+def test_the_train_step_runs_the_flash_forward_once_under_dots(
+        one_chip, monkeypatch, policy, forwards):
+    """One layer's AdamW step at a small tileable shape (2 rows of 2048,
+    8 heads of 128), compiled for the v5e: under ``"dots"`` the program
+    holds ONE ``hvd_flash_fwd`` custom call — the backward pass reads the
+    saved output and log-sum-exp — and under ``"full"`` two.  What
+    ``"dots"`` holds for it: no more temporary memory over the form that
+    saved matmul outputs alone (the parent's) than the two arrays, with a
+    tenth of room for the allocator's packing (it read +0.7 % here, and
+    +0.1 % at the benchmark's shape)."""
+    monkeypatch.setattr(ATT, "_use_interpret", lambda: False)
+    rows, seq = 2, 2048
+    cfg = T.TransformerConfig(
+        vocab_size=4096, d_model=1024, n_heads=8, n_kv_heads=8, d_ff=2048,
+        n_layers=1, max_seq=seq, dtype=jnp.bfloat16, attention_impl="flash",
+        remat=True, remat_policy=policy)
+    params = _on(one_chip, jax.eval_shape(
+        lambda: T.init_params(jax.random.PRNGKey(0), cfg)))
+    opt = optax.adamw(3e-4)
+    opt_state = _on(one_chip, jax.eval_shape(opt.init, params))
+    batch = _on(one_chip, {k: jax.ShapeDtypeStruct((rows, seq), jnp.int32)
+                           for k in ("tokens", "targets")})
+
+    def compiled():
+        def step(params, opt_state, batch):   # a new function: traced anew
+            loss, grads = jax.value_and_grad(
+                lambda p: T.loss_fn(p, batch, cfg))(params)
+            updates, opt_state = opt.update(grads, opt_state, params)
+            return optax.apply_updates(params, updates), opt_state, loss
+
+        return jax.jit(step, donate_argnums=(0, 1)).lower(
+            params, opt_state, batch).compile()
+
+    program = compiled()
+    text = program.as_text()
+    calls = {k: chip_smoke.kernel_calls(text, k) for k in (
+        "hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv")}
+    assert calls == {"hvd_flash_fwd": forwards * cfg.n_layers,
+                     "hvd_flash_bwd_dq": 1, "hvd_flash_bwd_dkv": 1}, calls
+    if policy != "dots":
+        return
+    # the parent's form of "dots": matmul outputs alone
+    monkeypatch.setattr(T, "_remat", lambda layer, cfg: jax.checkpoint(
+        layer, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable))
+    before = compiled()
+    assert chip_smoke.kernel_calls(before.as_text(), "hvd_flash_fwd") == 2
+    saved = (rows * seq * cfg.d_model * 2          # o, bf16
+             + rows * cfg.n_heads * seq * 4)       # lse, f32
+    grew = (program.memory_analysis().temp_size_in_bytes
+            - before.memory_analysis().temp_size_in_bytes)
+    assert 0 < grew <= saved * 1.1, (grew, saved)
