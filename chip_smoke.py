@@ -912,18 +912,17 @@ def latent_cfg(smoke: SmokeConfig):
         moe_impl="dropless")
 
 
-def phase_serve_latent(smoke: SmokeConfig) -> Dict:
-    """The small latent configuration through the engine on one chip:
-    whole and chunked prompts, the absorbed decode kernel engaged, the
-    tokens ``forward``'s where its margin is clear, and the compiled
-    tick and landing writing the ONE latent pool array in place."""
+def _serve_small(smoke: SmokeConfig, cfg, seed: int):
+    """A small model of another architecture through the engine: seeded
+    weights, every prompt of the smoke served to its end, the tick's and
+    the landing's first calls recorded for a later lowering ->
+    ``(engine, params, prompts, futures, seen, tick, land)``."""
     from horovod_tpu import serving
     from horovod_tpu.models import transformer as T
 
-    cfg = latent_cfg(smoke)
     params = jax.tree_util.tree_map(
         lambda a: a.astype(cfg.dtype),
-        T.init_params(jax.random.PRNGKey(smoke.seed + 3), cfg))
+        T.init_params(jax.random.PRNGKey(seed), cfg))
     engine = serving.InferenceEngine(params, cfg, serving.EngineConfig(
         n_slots=smoke.n_slots, max_len=smoke.serve_max_len,
         n_pages=smoke.serve_n_pages, prefill_chunk_tokens=32,
@@ -937,6 +936,17 @@ def phase_serve_latent(smoke: SmokeConfig) -> Dict:
             for p in prompts]
     while not all(f.done() for f in futs):
         engine.step()
+    return engine, params, prompts, futs, seen, tick, land
+
+
+def phase_serve_latent(smoke: SmokeConfig) -> Dict:
+    """The small latent configuration through the engine on one chip:
+    whole and chunked prompts, the absorbed decode kernel engaged, the
+    tokens ``forward``'s where its margin is clear, and the compiled
+    tick and landing writing the ONE latent pool array in place."""
+    cfg = latent_cfg(smoke)
+    engine, params, prompts, futs, seen, tick, land = _serve_small(
+        smoke, cfg, smoke.seed + 3)
     stats = engine.stats()
     _require(stats["paged_kernel_engaged"] is True
              and stats["decode_compilations"] == 1,
@@ -966,6 +976,83 @@ def phase_serve_latent(smoke: SmokeConfig) -> Dict:
               "moe_rows_here": stats["moe_rows_total"],
               "moe_rows_routed_away": stats["moe_rows_routed_away_total"]}
     _say("latent server: " + json.dumps(report))
+    del engine, params
+    return report
+
+
+def sparse_cfg(smoke: SmokeConfig):
+    """:func:`latent_cfg` with a lightning indexer (4 index heads of
+    128, ``index_topk`` half the longest prompt: 50 of 100, so the long
+    contexts select and the short ones keep everything) and the router's
+    score-correction bias."""
+    return dataclasses.replace(
+        latent_cfg(smoke), index_n_heads=4, index_head_dim=128,
+        index_topk=max(max(smoke.prompt_lens) // 2, 1), moe_score_bias=True)
+
+
+def phase_serve_sparse(smoke: SmokeConfig) -> Dict:
+    """The small SPARSE configuration through the engine on one chip:
+    contexts past ``index_topk``, the index walk and the selected attend
+    compiled (``hvd_dsa_score``, ``hvd_dsa_attend``), both pool arrays
+    written in place by the compiled tick and landing, the counters as
+    the layout says, the tokens ``forward``'s where its margin is
+    clear."""
+    from horovod_tpu.ops import paged_attention as PA
+
+    cfg = sparse_cfg(smoke)
+    engine, params, prompts, futs, seen, tick, land = _serve_small(
+        smoke, cfg, smoke.seed + 4)
+    stats = engine.stats()
+    item = jnp.dtype(cfg.dtype).itemsize
+    _require(stats["paged_kernel_engaged"] is True
+             and stats["decode_compilations"] == 1,
+             f"sparse engine: kernel engaged "
+             f"{stats['paged_kernel_engaged']}, decode compilations "
+             f"{stats['decode_compilations']}")
+    _require(stats["kv_index_bytes_per_token"] == cfg.n_layers
+             * cfg.index_head_dim * item
+             and stats["kv_latent_bytes_per_token"] == cfg.n_layers
+             * cfg.latent_row * item
+             and set(engine.slots.cache) == {"k", "ik", "pos"},
+             f"the two-array pool's layout: latent "
+             f"{stats['kv_latent_bytes_per_token']} + index "
+             f"{stats['kv_index_bytes_per_token']} B a token, arrays "
+             f"{sorted(engine.slots.cache)}")
+    # every decoded token of a request alone scores its whole context
+    # and selects min(index_topk, context) of it
+    # (the overlapped loop may have dispatched one tick more a request)
+    ctx = [len(p) + j + 1 for p in prompts
+           for j in range(smoke.max_new_tokens - 1)]
+    last = [len(p) + smoke.max_new_tokens for p in prompts]
+    scored, picked = (stats["dsa_scored_tokens_total"],
+                      stats["dsa_selected_tokens_total"])
+    k = cfg.index_topk
+    _require(sum(ctx) <= scored <= sum(ctx) + sum(last)
+             and sum(min(k, c) for c in ctx) <= picked
+             <= sum(min(k, c) for c in ctx + last)
+             and picked < scored and max(ctx) > k,
+             f"sparse counters: scored {stats['dsa_scored_tokens_total']} "
+             f"(contexts sum {sum(ctx)}), selected "
+             f"{stats['dsa_selected_tokens_total']}")
+    text = tick.lower(*seen["tick"]).compile().as_text()
+    _require_compiled(smoke, text, 2, "sparse decode tick")
+    _require(not smoke.expect_compiled or (
+        PA.INDEX_KERNEL_NAME in text and PA.SELECT_ATTEND_NAME in text),
+        "sparse decode tick: hvd_dsa_score / hvd_dsa_attend not compiled")
+    layer = min(int(np.prod(engine.slots.cache[n].shape[1:]))
+                for n in ("k", "ik"))
+    _require_pool_in_place(smoke, text, layer, "sparse decode tick")
+    _require_pool_in_place(
+        smoke, land.lower(*seen["land"]).compile().as_text(), layer,
+        "sparse landing")
+    tokens = {i: f.result() for i, f in enumerate(futs)}
+    checked = _check_against_oracle(smoke, params, prompts, tokens, cfg)
+    report = {"requests": len(prompts), "oracle_positions_checked": checked,
+              "kv_index_bytes_per_token": stats["kv_index_bytes_per_token"],
+              "dsa_scored_tokens": stats["dsa_scored_tokens_total"],
+              "dsa_selected_tokens": stats["dsa_selected_tokens_total"],
+              "dsa_full_rows": stats["dsa_full_rows_total"]}
+    _say("sparse server: " + json.dumps(report))
     del engine, params
     return report
 
@@ -1001,6 +1088,8 @@ def run(smoke: SmokeConfig, *, tp: Optional[int] = None) -> Dict:
     report["serve"] = phase_serve(smoke, host_params)
     gc.collect()  # the engine is a reference cycle holding device buffers
     report["serve_latent"] = phase_serve_latent(smoke)
+    gc.collect()
+    report["serve_sparse"] = phase_serve_sparse(smoke)
     gc.collect()
     if tp:
         report["serve_tp"] = phase_tp(smoke, host_params, tp,

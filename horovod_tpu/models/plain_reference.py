@@ -1,7 +1,8 @@
 """The plain references of the served models that are more than the
-uniform block: a patterned expert model (below), and a LATENT-ATTENTION
-expert model (``latent_*``, at the end of the file, with its own
-description there).
+uniform block: a patterned expert model (below), a LATENT-ATTENTION
+expert model (``latent_*``, with its own description there) and that
+model with a lightning indexer's SPARSE selection and a biased router
+(``sparse_*``, at the end of the file).
 
 The plain reference of a patterned expert model: its forward pass in
 straightforward ``jax.numpy``, float32 at
@@ -315,5 +316,181 @@ def latent_forward(params, tokens, dims: dict):
                         else ("layers", l - kd))
             w = jax.tree_util.tree_map(lambda a: a[i], params[stack])
             x = latent_layer(x, w, dims)
+        x = rmsnorm(x, params["ln_f"], dims["rms_norm_eps"])
+        return x @ params["head"].astype(F32)
+
+
+# --- a sparse latent-attention expert model (DeepSeek-V3.2-Exp's block) -------
+#
+# DeepSeek-V3's block (above) with two additions, as the family's
+# published inference code states them (``inference/model.py``: ``MLA``,
+# ``Indexer``, ``Gate``), in the same plain style; ``h`` is the layer's
+# input after ``attn_norm`` and ``cq`` the normed query latent that
+# latent attention computes anyway:
+#
+# * a LIGHTNING INDEXER a layer: index queries ``q_I[t] = cq[t] W_Iq``
+#   (``index_n_heads`` x ``index_head_dim``); ONE index key a token
+#   ``k_I[s] = LayerNorm(h[s] W_Ik)`` (scale and bias, eps 1e-6); RoPE
+#   (the layer's own YaRN tables) on the first ``qk_rope_head_dim`` dims
+#   of both; head weights ``w[t] = (h[t] W_Iw) x index_n_heads^-0.5 x
+#   index_head_dim^-0.5``; index score ``I[t, s] = sum_j w[t, j]
+#   relu(q_I[t, j] . k_I[s])`` for ``s <= t``
+#   (:func:`sparse_index_scores`).  The query attends the ``min(index_topk,
+#   t + 1)`` positions of largest score, ties to the lower position
+#   (``lax.top_k``'s rule: :func:`sparse_select`), and the softmax of
+#   latent attention runs over that set alone (:func:`sparse_attention`).
+# * the router CHOOSES on ``scores + e_score_correction_bias`` (``router_bias``
+#   ``(E,)``: ``topk_method: "noaux_tc"``) — the groups' scores (sum of a
+#   group's two largest) and the experts among the kept groups — and
+#   WEIGHTS by the chosen experts' raw scores, renormalised, times
+#   ``routed_scaling_factor`` (:func:`sparse_route`).
+#
+# Departures from the published code, each the program's too: (1) the
+# multi-token-prediction module (``num_nextn_predict_layers``) is no part
+# of the layers' forward pass (the family's own loader drops it) and is
+# left out; (2) the published code rotates index queries and keys by a
+# Hadamard matrix and quantises them to fp8 — the rotation is orthogonal
+# and leaves ``q_I . k_I`` as it is, and here nothing is quantised; (3)
+# the rope pairs dims rotate-half for the attention and the indexer alike
+# (the published code pairs the two differently; with seeded weights
+# either is a permutation of columns).
+#
+# ``params`` as for ``latent_*``, each layer with ``wi_q (R, Hi, Di)``,
+# ``wi_k (D, Di)``, ``i_k_norm`` / ``i_k_bias (Di)``, ``wi_w (D, Hi)`` and
+# an expert layer with ``router_bias (E)``; ``dims`` with
+# ``index_n_heads``, ``index_head_dim``, ``index_topk``.
+
+
+def _layernorm(x, w, b, eps: float):
+    mu = jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
+    return (x - mu) * jax.lax.rsqrt(var + eps) * w.astype(F32) \
+        + b.astype(F32)
+
+
+def sparse_index_scores(n, cq, w, dims: dict, rope: bool = True):
+    """``I`` ``(S, S)``: the index score of every pair, unmasked.
+    ``rope=False`` leaves the index vectors un-roped (a control the
+    tests hold the tolerance against)."""
+    S = n.shape[0]
+    r = dims["qk_rope_head_dim"]
+    hi, di = dims["index_n_heads"], dims["index_head_dim"]
+    qi = jnp.einsum("sr,rhk->shk", cq, w["wi_q"].astype(F32))
+    ki = _layernorm(n @ w["wi_k"].astype(F32), w["i_k_norm"],
+                    w["i_k_bias"], 1e-6)
+    if rope:
+        cos, sin = rope_tables(jnp.arange(S), r, latent_rope(dims))
+        qi = jnp.concatenate([rotate(qi[..., :r], cos, sin), qi[..., r:]],
+                             -1)
+        ki = jnp.concatenate(
+            [rotate(ki[:, None, :r], cos, sin)[:, 0], ki[:, r:]], -1)
+    wt = (n @ w["wi_w"].astype(F32)) * (hi ** -0.5 * di ** -0.5)
+    return jnp.einsum("qh,qhk->qk", wt, jax.nn.relu(
+        jnp.einsum("qhd,kd->qhk", qi, ki)))
+
+
+def sparse_select(scores, topk: int):
+    """``(S, S)`` bool: may query ``t`` attend position ``s``?  Its
+    ``min(topk, t + 1)`` best-scored positions ``s <= t``, ties to the
+    lower position (``lax.top_k``)."""
+    S = scores.shape[0]
+    i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+    _, top = jax.lax.top_k(jnp.where(j <= i, scores, -jnp.inf),
+                           min(topk, S))
+    picked = jnp.zeros((S, S), bool).at[i, top].set(True)
+    return picked & (j <= i)
+
+
+def sparse_attention(n, w, dims: dict, select: bool = True,
+                     rope_index: bool = True):
+    """Latent attention (``latent_attention``'s mathematics, non-
+    absorbed) with the softmax over each query's selected set.
+    ``select=False`` is dense attention (the control)."""
+    S = n.shape[0]
+    eps = dims["rms_norm_eps"]
+    nope, c = dims["qk_nope_head_dim"], dims["kv_lora_rank"]
+    cq = rmsnorm(n @ w["wq_a"].astype(F32), w["q_a_norm"], eps)
+    q = jnp.einsum("sr,rhk->shk", cq, w["wq_b"].astype(F32))
+    kv = n @ w["wkv_a"].astype(F32)
+    ckv = rmsnorm(kv[:, :c], w["kv_a_norm"], eps)
+    cos, sin = rope_tables(jnp.arange(S), dims["qk_rope_head_dim"],
+                           latent_rope(dims))
+    q_rope = rotate(q[..., nope:], cos, sin)
+    k_rope = rotate(kv[:, None, c:], cos, sin)[:, 0]
+    kvb = jnp.einsum("sc,chk->shk", ckv, w["wkv_b"].astype(F32))
+    s = (jnp.einsum("qhd,khd->hqk", q[..., :nope], kvb[..., :nope])
+         + jnp.einsum("qhd,kd->hqk", q_rope, k_rope)
+         ) * latent_softmax_scale(dims)
+    i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+    vis = j <= i
+    if select:
+        vis = sparse_select(sparse_index_scores(n, cq, w, dims, rope_index),
+                            dims["index_topk"])
+    p = jax.nn.softmax(jnp.where(vis[None], s, -jnp.inf), axis=-1)
+    o = jnp.einsum("hqk,khd->qhd", p, kvb[..., nope:])
+    return jnp.einsum("shk,hkd->sd", o, w["wo"].astype(F32))
+
+
+def sparse_route(n, router, bias, dims: dict):
+    """``(S, E)`` combination weights over ALL the router's experts:
+    chosen on ``scores + bias``, weighted by the raw scores."""
+    sc = jax.nn.sigmoid(n @ router.astype(F32))
+    S, E = sc.shape
+    choice = sc + bias.astype(F32)
+    g = dims.get("n_group", 1)
+    if g > 1:
+        grouped = choice.reshape(S, g, E // g)
+        g_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        _, keep = jax.lax.top_k(g_score, dims["topk_group"])
+        kept = jnp.zeros((S, g), bool).at[
+            jnp.arange(S)[:, None], keep].set(True)
+        choice = jnp.where(kept[:, :, None], grouped, -jnp.inf
+                           ).reshape(S, E)
+    _, top_e = jax.lax.top_k(choice, dims["num_experts_per_tok"])
+    top_g = jnp.take_along_axis(sc, top_e, axis=-1)
+    if dims["norm_topk_prob"]:
+        top_g = top_g / jnp.sum(top_g, axis=-1, keepdims=True)
+    top_g = top_g * dims["routed_scaling_factor"]
+    return jnp.zeros_like(sc).at[jnp.arange(S)[:, None], top_e].set(top_g)
+
+
+def sparse_experts(n, w, dims: dict):
+    """The held experts' part of the routed sum under the biased
+    choice, and the shared expert (``latent_experts`` with
+    :func:`sparse_route`)."""
+    weight = sparse_route(n, w["router"], w["router_bias"], dims)
+    off = dims.get("expert_offset", 0)
+    weight = weight[:, off:off + w["w_gate"].shape[0]]
+    gate = jnp.einsum("sd,edf->esf", n, w["w_gate"].astype(F32))
+    up = jnp.einsum("sd,edf->esf", n, w["w_up"].astype(F32))
+    out = jnp.einsum("esf,efd->esd", jax.nn.silu(gate) * up,
+                     w["w_down"].astype(F32))
+    y = jnp.einsum("esd,se->sd", out, weight)
+    if dims.get("n_shared_experts", 0):
+        y = y + _swiglu(n, w["ws_gate"], w["ws_up"], w["ws_down"])
+    return y
+
+
+def sparse_layer(x, w, dims: dict, **controls):
+    eps = dims["rms_norm_eps"]
+    h = x + sparse_attention(rmsnorm(x, w["ln1"], eps), w, dims, **controls)
+    n = rmsnorm(h, w["ln2"], eps)
+    if "router" in w:
+        return h + sparse_experts(n, w, dims)
+    return h + _swiglu(n, w["w_gate"], w["w_up"], w["w_down"])
+
+
+def sparse_forward(params, tokens, dims: dict, **controls):
+    """Logits ``(S, V)`` float32 of one sequence ``tokens`` ``(S,)``.
+    ``controls`` (``select=False``, ``rope_index=False``) loosen the
+    layer for the tests that hold the tolerance tight."""
+    with jax.default_matmul_precision("highest"):
+        x = params["embed"].astype(F32)[tokens]
+        kd = dims["first_k_dense_replace"]
+        for l in range(dims["num_hidden_layers"]):
+            stack, i = (("dense_layers", l) if l < kd
+                        else ("layers", l - kd))
+            w = jax.tree_util.tree_map(lambda a: a[i], params[stack])
+            x = sparse_layer(x, w, dims, **controls)
         x = rmsnorm(x, params["ln_f"], dims["rms_norm_eps"])
         return x @ params["head"].astype(F32)

@@ -33,6 +33,14 @@ Design notes (TPU):
   dense layers in a stack of their own (``n_dense_layers``), a shared
   expert (``hvd_moe_shared``), sigmoid group-limited routing, and ONE
   CHIP'S SHARE of the routed experts (``n_experts_held``).
+* Serving only: learned SPARSE attention over that cache
+  (``index_topk`` and its two sizes): an indexer's key cached beside the
+  latent row, every query attending its ``index_topk`` best-scored
+  positions — an index walk (``hvd_dsa_score``), an exact sort-free
+  selection (``hvd_dsa_select``) and an attend over the selected rows
+  (``hvd_dsa_attend``) in the tick, in a chunk and in a whole prompt —
+  and a router that chooses under a score-correction bias
+  (``moe_score_bias``).
 """
 
 from __future__ import annotations
@@ -184,6 +192,21 @@ class TransformerConfig:
     # the router's ``n_experts`` (0 = every expert is held).
     n_experts_held: int = 0
     expert_offset: int = 0
+    # The router CHOOSES on ``scores + router_bias`` (a learned
+    # score-correction bias, one value an expert and layer: the
+    # published ``topk_method: "noaux_tc"``) and weights by the raw
+    # scores.
+    moe_score_bias: bool = False
+    # Learned sparse attention over a latent cache (set = all three > 0;
+    # the published keys' meanings): an INDEXER of ``index_n_heads``
+    # heads of ``index_head_dim`` scores every earlier token for each
+    # query — ``I[t, s] = sum_j w[t, j] relu(q_j[t] . k[s])``, the index
+    # key ``k[s]`` ONE cached vector a token and layer — and the query
+    # attends its ``index_topk`` best-scored positions only (all of
+    # them while it has no more).
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
 
     def __post_init__(self):
         mla = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
@@ -193,6 +216,14 @@ class TransformerConfig:
                 "latent attention is its five sizes together (q_lora_rank, "
                 "kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim, "
                 f"v_head_dim); got {mla}")
+        dsa = (self.index_n_heads, self.index_head_dim, self.index_topk)
+        if any(dsa) and not (all(dsa) and all(mla)
+                             and self.index_head_dim
+                             >= self.qk_rope_head_dim):
+            raise ValueError(
+                "sparse attention is an indexer's three sizes together "
+                "(index_n_heads, index_head_dim >= qk_rope_head_dim, "
+                f"index_topk) over latent attention; got {dsa} with {mla}")
         if self.moe_score not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown moe_score {self.moe_score!r}; "
                              "expected 'softmax' or 'sigmoid'")
@@ -241,6 +272,11 @@ class TransformerConfig:
     def latent(self) -> bool:
         """Latent attention (MLA)?"""
         return self.kv_lora_rank > 0
+
+    @property
+    def sparse(self) -> bool:
+        """Learned sparse attention (an indexer beside the latent)?"""
+        return self.index_topk > 0
 
     @property
     def latent_width(self) -> int:
@@ -297,6 +333,15 @@ class TransformerConfig:
         return out
 
     @property
+    def pool_arrays(self) -> tuple:
+        """What a prefill hands back for the full layers' cache, by the
+        page pool's names: K and V; a latent model's rows alone; with an
+        indexer, its keys beside them."""
+        if not self.latent:
+            return ("k", "v")
+        return ("k", "ik") if self.sparse else ("k", None)
+
+    @property
     def has_window(self) -> bool:
         """Does any layer attend a window (two kinds of KV state)?"""
         return "sliding" in self.layer_pattern
@@ -346,6 +391,15 @@ def init_params(rng, cfg: TransformerConfig) -> Dict:
                 kv_a_norm=jnp.ones((L, C), jnp.float32),
                 wkv_b=norm_init(ak[3], (L, C, H, N + Vd), 1.0 / np.sqrt(C)),
                 wo=norm_init(keys[3], (L, H, Vd, D), 1.0 / np.sqrt(H * Vd)))
+            if cfg.sparse:
+                ik = jax.random.split(keys[1], 3)
+                Hi, Di = cfg.index_n_heads, cfg.index_head_dim
+                layers.update(
+                    wi_q=norm_init(ik[0], (L, R, Hi, Di), 1.0 / np.sqrt(R)),
+                    wi_k=norm_init(ik[1], (L, D, Di), s_d),
+                    i_k_norm=jnp.ones((L, Di), jnp.float32),
+                    i_k_bias=jnp.zeros((L, Di), jnp.float32),
+                    wi_w=norm_init(ik[2], (L, D, Hi), s_d))
         else:
             layers.update(
                 wq=norm_init(keys[0], (L, D, H, Dh), s_d),
@@ -363,6 +417,10 @@ def init_params(rng, cfg: TransformerConfig) -> Dict:
                 w_up=norm_init(keys[6], (L, E, D, F), s_d),
                 w_down=norm_init(keys[7], (L, E, F, D), s_f),
             )
+            if cfg.moe_score_bias:
+                # not zeros: a zero bias would choose as no bias does
+                layers["router_bias"] = norm_init(
+                    jax.random.fold_in(keys[4], 7), (L, cfg.n_experts), 0.05)
             if cfg.n_shared_experts:
                 Fs = cfg.n_shared_experts * F
                 sk = jax.random.split(keys[4], 4)[1:]
@@ -407,6 +465,11 @@ def param_specs(cfg: TransformerConfig) -> Dict:
                 wkv_a=P("pp", "fsdp", None), kv_a_norm=P("pp", None),
                 wkv_b=P("pp", None, "tp", None),
                 wo=P("pp", "tp", None, "fsdp"))
+            if cfg.sparse:     # the indexer is every head's: replicated
+                layers.update(
+                    wi_q=P("pp", None, None, None),
+                    wi_k=P("pp", "fsdp", None), i_k_norm=P("pp", None),
+                    i_k_bias=P("pp", None), wi_w=P("pp", "fsdp", None))
         else:
             layers.update(
                 wq=P("pp", "fsdp", "tp", None),
@@ -422,6 +485,8 @@ def param_specs(cfg: TransformerConfig) -> Dict:
                 w_up=P("pp", "ep", "fsdp", "tp"),
                 w_down=P("pp", "ep", "tp", "fsdp"),
             )
+            if cfg.moe_score_bias:
+                layers["router_bias"] = P("pp", None)
             if cfg.n_shared_experts:
                 layers.update(ws_gate=P("pp", "fsdp", "tp"),
                               ws_up=P("pp", "fsdp", "tp"),
@@ -469,7 +534,10 @@ def batch_specs() -> Dict:
 #: absorption of W_k), ``hvd_mla_kv`` (kv down, norm, rope),
 #: ``hvd_mla_expand`` (latents up through W_kv into heads: a prompt's
 #: own, and a chunk's landed prefix) and ``hvd_mla_out`` (a tick's W_v,
-#: and W_o).
+#: and W_o); and sparse attention's ``hvd_dsa_proj`` (the indexer's three
+#: projections), ``hvd_dsa_score`` (the index walk — the kernel's name
+#: too — and a chunk's scores), ``hvd_dsa_select`` and ``hvd_dsa_attend``
+#: (the selected rows' gather and the kernel of that name over them).
 DEVICE_SCOPES = (
     "embed",          # token-embedding lookup
     "layer_scan",     # the scan over layers' own slicing and stacking
@@ -759,16 +827,20 @@ def _rope_one(x, cfg: TransformerConfig, pos_offset, positions):
                  yarn=cfg.rope_yarn)[0]
 
 
-def _mla_q(x, p, cfg: TransformerConfig, pos_offset=0, positions=None):
+def _mla_q(x, p, cfg: TransformerConfig, pos_offset=0, positions=None,
+           with_cq: bool = False):
     """Latent attention's queries: down, norm, up, rope on the rope
-    part -> ``(q_nope (B, S, H, nope), q_rope (B, S, H, rope))``."""
+    part -> ``(q_nope (B, S, H, nope), q_rope (B, S, H, rope))``, and
+    with ``with_cq`` the normed query latent ``cq (B, S, q_lora_rank)``
+    too, which an indexer reads its own queries up from."""
     with jax.named_scope("hvd_mla_q"):
         cq = _rmsnorm(jnp.einsum("bsd,dr->bsr", x,
                                  p["wq_a"].astype(cfg.dtype)),
                       p["q_a_norm"], cfg.norm_eps)
         q = jnp.einsum("bsr,rhk->bshk", cq, p["wq_b"].astype(cfg.dtype))
         q_nope, q_rope = jnp.split(q, [cfg.qk_nope_head_dim], axis=-1)
-        return q_nope, _rope_one(q_rope, cfg, pos_offset, positions)
+        q_rope = _rope_one(q_rope, cfg, pos_offset, positions)
+        return (q_nope, q_rope, cq) if with_cq else (q_nope, q_rope)
 
 
 def _mla_kv(x, p, cfg: TransformerConfig, pos_offset=0, positions=None):
@@ -831,29 +903,172 @@ def _mla_out(o, p, cfg: TransformerConfig, absorbed: bool = False):
 
 
 def _mla_attention(x, p, cfg: TransformerConfig, mesh=None):
-    """Whole-sequence latent attention, expanded: ``(out, latent rows
-    (B, 1, S, latent_row))`` — the rows shaped as the cache block of
-    ONE kv head that a prefill hands back."""
+    """Whole-sequence latent attention: ``(out, latent rows (B, 1, S,
+    latent_row), index keys (B, 1, S, index_head_dim) or None)`` — the
+    rows shaped as the cache block of ONE kv head that a prefill hands
+    back.  Expanded, through the flash forward; with an indexer and a
+    sequence longer than ``index_topk``, each query absorbed over its
+    selected rows (:func:`_dsa_attend`)."""
     from horovod_tpu.ops import attention as attn
 
     if mesh is not None:
         raise UnsupportedModelConfigError(
             "latent attention is not written for a tp mesh")
-    q_nope, q_rope = _mla_q(x, p, cfg)
+    if cfg.attention_impl not in ("reference", "flash"):
+        raise UnsupportedModelConfigError(
+            f"latent attention runs attention_impl 'flash' or "
+            f"'reference', not {cfg.attention_impl!r}")
+    q_nope, q_rope, cq = _mla_q(x, p, cfg, with_cq=True)
     lat = _mla_kv(x, p, cfg)
+    ik = None
+    if cfg.sparse:
+        qi, ik, w = _dsa_proj(x, cq, p, cfg)
+        if x.shape[1] > cfg.index_topk:   # else every query sees it all
+            q = _mla_absorb_q(q_nope, q_rope, p, cfg)
+            pos = jnp.arange(x.shape[1], dtype=jnp.int32)
+            o = lax.map(lambda a: _dsa_attend(*a, pos, cfg), (
+                q, qi, w, lat, ik))
+            return (_mla_out(o, p, cfg, absorbed=True), lat[:, None],
+                    ik[:, None])
+        ik = ik[:, None]
     k, v = _mla_expand(lat, p, cfg)
     qh = _mla_heads(q_nope, q_rope)
     with jax.named_scope("attn"):
         if cfg.attention_impl == "reference":
             oh = attn.reference_attention(qh, k, v, causal=True,
                                           sm_scale=cfg.mla_scale)
-        elif cfg.attention_impl == "flash":
-            oh = attn.flash_attention(qh, k, v, True, cfg.mla_scale)
         else:
-            raise UnsupportedModelConfigError(
-                f"latent attention runs attention_impl 'flash' or "
-                f"'reference', not {cfg.attention_impl!r}")
-    return _mla_out(jnp.moveaxis(oh, 1, 2), p, cfg), lat[:, None]
+            oh = attn.flash_attention(qh, k, v, True, cfg.mla_scale)
+    return _mla_out(jnp.moveaxis(oh, 1, 2), p, cfg), lat[:, None], ik
+
+
+# --- learned sparse attention (an indexer over the latent cache) --------------
+#
+# ``cfg.sparse``: beside the latent row a token leaves ONE index key
+# ``k_I`` (``index_head_dim`` wide, LayerNorm'd, its first
+# ``qk_rope_head_dim`` dims roped) a layer, and a query at ``t`` scores
+# every position ``s <= t``: ``I[t, s] = sum_j w[t, j] relu(q_I[t, j] .
+# k_I[s])`` over ``index_n_heads`` index queries read up from the query
+# latent ``cq``, ``w`` a projection of the layer's input times
+# ``index_n_heads**-0.5 index_head_dim**-0.5``.  It then attends the
+# ``min(index_topk, t + 1)`` best-scored positions only (ties to the
+# lower position) — latent attention as it is, the softmax over that
+# set.  Until a query sees more than ``index_topk`` positions the layer
+# is the dense one.  Scopes: ``hvd_dsa_proj`` (the three projections),
+# ``hvd_dsa_score``, ``hvd_dsa_select``, ``hvd_dsa_attend`` (the
+# selected rows' gather and their absorbed attention).
+
+_INDEX_NORM_EPS = 1e-6
+
+#: Queries whose selection, gathered rows and attention are in flight
+#: together in a chunk or a whole prompt: 128 x 2048 rows x 640 lanes
+#: are 335 MB in bfloat16.
+_DSA_QUERY_BLOCK = 128
+
+
+def _layernorm(x, scale, bias, eps: float):
+    x32 = x.astype(jnp.float32)
+    mu = jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x32 - mu), axis=-1, keepdims=True)
+    return ((x32 - mu) * jax.lax.rsqrt(var + eps) * scale
+            + bias).astype(x.dtype)
+
+
+def _dsa_proj(x, cq, p, cfg: TransformerConfig, pos_offset=0,
+              positions=None):
+    """The indexer's three projections of a layer's input ``x`` (after
+    its norm) and query latent ``cq``: ``(q_I (B, S, Hi, Di), k_I (B,
+    S, Di) — what the cache keeps —, w (B, S, Hi) float32)``."""
+    r = cfg.qk_rope_head_dim
+
+    def rope(a):           # the first r dims, the layer's own tables
+        return jnp.concatenate(
+            [_rope_one(a[..., :r], cfg, pos_offset, positions), a[..., r:]],
+            axis=-1)
+
+    with jax.named_scope("hvd_dsa_proj"):
+        qi = rope(jnp.einsum("bsr,rhk->bshk", cq,
+                             p["wi_q"].astype(cfg.dtype)))
+        ki = _layernorm(jnp.einsum("bsd,dk->bsk", x,
+                                   p["wi_k"].astype(cfg.dtype)),
+                        p["i_k_norm"], p["i_k_bias"], _INDEX_NORM_EPS)
+        ki = rope(ki[:, :, None])[:, :, 0]
+        w = jnp.einsum("bsd,dh->bsh", x, p["wi_w"].astype(cfg.dtype))
+        w = w.astype(jnp.float32) * (cfg.index_n_heads ** -0.5
+                                     * cfg.index_head_dim ** -0.5)
+        return qi, ki, w
+
+
+def _dsa_select_attend(q, scores, n_valid, gather, cfg: TransformerConfig,
+                       kernel: bool):
+    """Queries ``q`` ``(R, H, latent_row)`` (absorbed) with their index
+    ``scores`` ``(R, T)`` of the ``n_valid`` ``(R,)`` positions each
+    sees: the selection (``hvd_dsa_select``), then ``gather(idx)`` ->
+    the picked cache rows ``(R, K, latent_row)`` and their absorbed
+    attention (``hvd_dsa_attend``) -> ``o_lat (R, H, kv_lora_rank)``
+    float32."""
+    from horovod_tpu.ops import paged_attention as _pa
+
+    k = min(cfg.index_topk, scores.shape[1])
+    with jax.named_scope("hvd_dsa_select"):
+        idx, count = _pa.select_topk(scores, n_valid, k, -(-k // 16) * 16)
+    with jax.named_scope("hvd_dsa_attend"):
+        o, _ = _pa.selected_attend(
+            q, gather(idx), count, v_dim=cfg.kv_lora_rank,
+            sm_scale=cfg.mla_scale, kernel=kernel)
+    return o
+
+
+def _dsa_attend(q, qi, w, lat, ik, pos, cfg: TransformerConfig):
+    """ONE sequence's queries against rows that lie in logical order:
+    ``q`` ``(Q, H, latent_row)`` absorbed, ``qi`` ``(Q, Hi, Di)``, ``w``
+    ``(Q, Hi)`` at logical positions ``pos`` ``(Q,)``; ``lat`` ``(T,
+    latent_row)`` and ``ik`` ``(T, Di)`` the cache rows of positions
+    ``0 .. T-1`` (what lies behind a query's position is never picked).
+    Scores for all the queries at once, then selection, gather and
+    attention :data:`_DSA_QUERY_BLOCK` queries at a time -> ``(Q, H,
+    kv_lora_rank)`` float32."""
+    from horovod_tpu.ops import paged_attention as _pa
+
+    kernel = cfg.attention_impl == "flash"
+    with jax.named_scope("hvd_dsa_score"):
+        scores = _pa.index_scores_rows(qi, w, ik, kernel=kernel)
+    Q = q.shape[0]
+    qb = min(_DSA_QUERY_BLOCK, Q)
+    assert Q % qb == 0, (Q, qb)
+
+    def block(a):
+        q_b, s_b, pos_b = a
+        return _dsa_select_attend(q_b, s_b, pos_b + 1, lambda i: lat[i],
+                                  cfg, kernel)
+
+    o = lax.map(block, (q.reshape(Q // qb, qb, *q.shape[1:]),
+                        scores.reshape(Q // qb, qb, -1),
+                        pos.reshape(Q // qb, qb)))
+    return o.reshape(Q, *o.shape[2:])
+
+
+def _dsa_chunk_attend(q, qi, w, lat, ik, prefix_lat, prefix_ik, p0,
+                      cfg: TransformerConfig):
+    """A chunk's ``(K, S0)`` queries (``q`` absorbed) against the
+    landed prefix (``prefix_lat`` ``(P0, latent_row)``, ``prefix_ik``
+    ``(P0, Di)``: positions ``< p0`` of them) and their own rows
+    ``lat`` / ``ik``, SELECTED: the chunk's rows are laid behind the
+    landed ones at ``p0`` (:func:`_mla_chunk_attend`'s layout: row
+    ``j`` of the whole is logical position ``j``), and the query at
+    ``p0 + r`` picks among positions ``<= p0 + r`` (:func:`_dsa_attend`).
+    -> ``(K, S0, H, kv_lora_rank)`` float32."""
+    S0 = lat.shape[1]
+    pos = jnp.asarray(p0, jnp.int32) + jnp.arange(S0, dtype=jnp.int32)
+
+    def behind(prefix, own):
+        with jax.named_scope("chunk_attn"):
+            rows = jnp.pad(prefix.astype(own.dtype), ((0, S0), (0, 0)))
+            return lax.dynamic_update_slice_in_dim(rows, own, p0, 0)
+
+    return lax.map(lambda a: _dsa_attend(
+        a[0], a[1], a[2], behind(prefix_lat, a[3]), behind(prefix_ik, a[4]),
+        pos, cfg), (q, qi, w, lat, ik))
 
 
 #: Rows a chunk expands and attends at a time: ``wkv_b``'s output for
@@ -979,7 +1194,7 @@ def _moe_mlp_dense(x, p, cfg: TransformerConfig, return_aux: bool = False):
     expert, combine with the routing one-hot.  Exact and dropless — the
     oracle for the sparse path, and the right choice for decoding (a
     handful of tokens) and tiny E."""
-    if (cfg.n_experts_per_tok > 1 or cfg.moe_routing
+    if (cfg.n_experts_per_tok > 1 or cfg.moe_routing or cfg.moe_score_bias
             or cfg.held_offset is not None):
         return _moe_mlp_dense_topk(x, p, cfg, return_aux)
     logits = jnp.einsum("bsd,de->bse", x, p["router"].astype(cfg.dtype))
@@ -999,6 +1214,15 @@ def _moe_mlp_dense(x, p, cfg: TransformerConfig, return_aux: bool = False):
     return y, cfg.n_experts * jnp.sum(frac * pbar)
 
 
+def _routing(p, cfg: TransformerConfig) -> dict:
+    """:func:`~horovod_tpu.ops.moe.route_topk`'s keywords for the layer
+    ``p``: what the configuration states (``cfg.moe_routing``) and the
+    layer's own score-correction bias."""
+    if "router_bias" not in p:
+        return cfg.moe_routing
+    return {**cfg.moe_routing, "bias": p["router_bias"]}
+
+
 def _moe_mlp_dense_topk(x, p, cfg: TransformerConfig, return_aux: bool):
     """Top-k MoE by every expert and a mask: the oracle of the dropless
     dispatch for more than one expert a token (router in float32, the
@@ -1015,7 +1239,7 @@ def _moe_mlp_dense_topk(x, p, cfg: TransformerConfig, return_aux: bool):
     B, S, D = x.shape
     top, gate = moe.route_topk(x.reshape(-1, D), p["router"],
                                cfg.n_experts_per_tok, cfg.norm_topk_prob,
-                               **cfg.moe_routing)
+                               **_routing(p, cfg))
     comb = jnp.einsum("tke,tk->te",
                       jax.nn.one_hot(top, cfg.n_experts, dtype=jnp.float32),
                       gate).reshape(B, S, cfg.n_experts)
@@ -1084,17 +1308,18 @@ def _moe_routed(x, p, cfg: TransformerConfig, impl: Optional[str] = None,
                               for k in _EXPERT_LEAVES),
             k=cfg.n_experts_per_tok, norm_topk=cfg.norm_topk_prob,
             token_mask=token_mask, return_counts=return_counts,
-            layer=layer, routing=cfg.moe_routing or None,
+            layer=layer, routing=_routing(p, cfg) or None,
             held_offset=cfg.held_offset)
     if impl != "switch":
         raise ValueError(f"unknown moe_impl {impl!r}; "
                          "expected 'switch', 'dense', or 'dropless'")
-    if (cfg.n_experts_per_tok > 1 or cfg.moe_routing
+    if (cfg.n_experts_per_tok > 1 or cfg.moe_routing or cfg.moe_score_bias
             or cfg.held_offset is not None):
         raise UnsupportedModelConfigError(
             "switch dispatch routes one softmax expert a token over "
             f"every expert; n_experts_per_tok={cfg.n_experts_per_tok}, "
-            f"routing {cfg.moe_routing} or a share of the experts need "
+            f"routing {cfg.moe_routing}, a score-correction bias or a "
+            "share of the experts need "
             "moe_impl='dropless' (serving) or 'dense' (the oracle)")
     return moe.switch_moe(
         x, p["router"], p["w_gate"].astype(cfg.dtype),
@@ -1377,9 +1602,13 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int = 0) -> Dict:
     if cfg.latent:
         # one kv "head" whose key is the cached row and whose value is
         # that row's first kv_lora_rank lanes: ``k`` alone
-        return {"k": jnp.zeros((cfg.n_layers, batch, 1, T,
-                                cfg.latent_row), cfg.dtype),
-                "pos": jnp.zeros((), jnp.int32)}
+        cache = {"k": jnp.zeros((cfg.n_layers, batch, 1, T,
+                                 cfg.latent_row), cfg.dtype),
+                 "pos": jnp.zeros((), jnp.int32)}
+        if cfg.sparse:    # ... and the index keys, one a token
+            cache["ik"] = jnp.zeros((cfg.n_layers, batch, 1, T,
+                                     cfg.index_head_dim), cfg.dtype)
+        return cache
     return {
         "k": jnp.zeros((cfg.n_layers, batch, cfg.kv_heads, T, cfg.head_dim),
                        cfg.dtype),
@@ -1428,14 +1657,32 @@ def _attention_decode(x, p, cfg: TransformerConfig, k_cache, v_cache, pos):
     at ``pos``, attend q over positions <= pos (static-shape mask; the
     attention math itself lives in :func:`_cache_attend`).  Latent
     attention has no ``v_cache``: the row is written to ``k_cache`` and
-    attended absorbed."""
+    attended absorbed; with an indexer ``v_cache`` is the index keys'
+    cache ``(B, 1, T, index_head_dim)``."""
     if cfg.latent:
-        q = _mla_absorb_q(*_mla_q(x, p, cfg, pos), p, cfg)  # (B, 1, H, 640)
+        q_nope, q_rope, cq = _mla_q(x, p, cfg, pos, with_cq=True)
+        q = _mla_absorb_q(q_nope, q_rope, p, cfg)       # (B, 1, H, 640)
         with jax.named_scope("kv_write"):
             k_cache = lax.dynamic_update_slice_in_dim(
                 k_cache, _mla_kv(x, p, cfg, pos)[:, None].astype(
                     k_cache.dtype), pos, axis=2)
         T = k_cache.shape[2]
+        if cfg.sparse:
+            from horovod_tpu.ops import paged_attention as _pa
+
+            qi, ki, w = _dsa_proj(x, cq, p, cfg, pos)
+            with jax.named_scope("kv_write"):
+                v_cache = lax.dynamic_update_slice_in_dim(
+                    v_cache, ki[:, None].astype(v_cache.dtype), pos, axis=2)
+            with jax.named_scope("hvd_dsa_score"):
+                scores = _pa.index_scores_dense(qi[:, 0], w[:, 0],
+                                                v_cache[:, 0])
+            o = _dsa_select_attend(
+                q[:, 0], scores, jnp.full(scores.shape[:1], pos + 1),
+                lambda i: jnp.take_along_axis(k_cache[:, 0], i[..., None],
+                                              axis=1), cfg, kernel=False)
+            return _mla_out(o[:, None], p, cfg,
+                            absorbed=True), k_cache, v_cache
         mask = (lax.broadcasted_iota(jnp.int32, (T,), 0) <= pos)
         o = _cache_attend(jnp.moveaxis(q, 1, 2), k_cache,
                           k_cache[..., :cfg.kv_lora_rank],
@@ -1485,12 +1732,11 @@ def decode_step(params: Dict, tokens_t, cache: Dict, cfg: TransformerConfig):
 
     x, ys = _scan_layer_kinds(
         cfg, layer, x, params["layers"],
-        {"full": (cache["k"], cache.get("v"))}, params.get("dense_layers"))
+        {"full": tuple(cache.get(n) for n in cfg.pool_arrays)},
+        params.get("dense_layers"))
     logits = _lm_head(x, params["ln_f"], params["head"], cfg)
-    k_all, v_all = ys["full"]
-    out = {"k": k_all, "pos": pos + 1}
-    if v_all is not None:
-        out["v"] = v_all
+    out = {n: a for n, a in zip(cfg.pool_arrays, ys["full"]) if n}
+    out["pos"] = pos + 1
     return logits[:, 0], out
 
 
@@ -1650,9 +1896,13 @@ def _attention_decode_paged(x, p, cfg: TransformerConfig, kv, layer, table,
     ps = kv[0].shape[3]
     if cfg.latent:
         # ABSORBED: the row written is the row read, as it lies
-        q = _mla_absorb_q(*_mla_q(x, p, cfg, positions=pos[:, None]), p,
-                          cfg)[:, 0]                     # (S, H, 640)
+        q_nope, q_rope, cq = _mla_q(x, p, cfg, positions=pos[:, None],
+                                    with_cq=True)
+        q = _mla_absorb_q(q_nope, q_rope, p, cfg)[:, 0]  # (S, H, 640)
         rows = (_mla_kv(x, p, cfg, positions=pos[:, None])[:, None],)
+        if cfg.sparse:    # ... and the index key beside it
+            qi, ki, w = _dsa_proj(x, cq, p, cfg, positions=pos[:, None])
+            rows += (ki[:, None],)
     else:
         qh, k_t, v_t = _qkv_proj(x, p, cfg, positions=pos[:, None],
                                  kind=kind)
@@ -1671,6 +1921,25 @@ def _attention_decode_paged(x, p, cfg: TransformerConfig, kv, layer, table,
     if cfg.latent:
         from horovod_tpu.ops import paged_attention as _pa
 
+        if cfg.sparse:
+            # the index walk over the slot's live tokens, the selection,
+            # then the attend over the selected rows alone: the pool is
+            # read BY TOKEN, ``(table[s, t // page], t % page)``
+            with jax.named_scope("hvd_dsa_score"):
+                limit = jnp.where(active, pos + 1, 0)
+                walk = (_pa.index_scores if kernel
+                        else _pa.index_scores_reference)
+                scores = walk(qi[:, 0], w[:, 0], kv[1], table, limit,
+                              layer=layer)
+            lat = kv[0].reshape((-1,) + kv[0].shape[-1:])   # rows, as they lie
+            n_pg = kv[0].shape[1]
+
+            def gather(idx):
+                page = jnp.take_along_axis(table, idx // ps, axis=1)
+                return lat[(layer * n_pg + page) * ps + idx % ps]
+
+            o_lat = _dsa_select_attend(q, scores, limit, gather, cfg, kernel)
+            return _mla_out(o_lat[:, None], p, cfg, absorbed=True), kv
         with jax.named_scope("paged_attend"):
             limit = jnp.where(active, pos + 1, 0)
             attend = _pa.mla_decode if kernel else _pa.mla_decode_reference
@@ -1730,7 +1999,7 @@ def _gather_kv(k_pool, v_pool, k_scale, v_scale, layer, table,
     return kg, vg
 
 
-_POOL_ARRAYS = {"full": ("k", "v", "k_scale", "v_scale"),
+_POOL_ARRAYS = {"full": ("k", "v", "k_scale", "v_scale", "ik"),
                 "sliding": ("wk", "wv")}
 
 
@@ -2126,15 +2395,15 @@ def decode_verify_paged(params: Dict, window, pool: Dict, table,
     return t, mx, acc, out
 
 
-def _by_kind(ys: Dict, pos) -> Dict:
+def _by_kind(ys: Dict, pos, full=("k", "v")) -> Dict:
     """A prefill's per-layer K/V as the cache block it returns:
-    ``k``/``v`` stacked over the full layers and, where the
-    configuration has window layers, ``wk``/``wv`` over those."""
+    ``k``/``v`` stacked over the full layers (``full``:
+    :attr:`TransformerConfig.pool_arrays` — a latent model's rows are
+    ``k`` alone, its index keys ``ik``) and, where the configuration
+    has window layers, ``wk``/``wv`` over those."""
     out = {"pos": pos}
     if "full" in ys:
-        out["k"], out["v"] = ys["full"]
-        if out["v"] is None:       # latent attention: one array, ``k``
-            del out["v"]
+        out.update((n, a) for n, a in zip(full, ys["full"]) if n)
     if "sliding" in ys:
         out["wk"], out["wv"] = ys["sliding"]
     return out
@@ -2179,7 +2448,11 @@ def prefill_with_prefix(params: Dict, suffix, prefix_k, prefix_v,
     With latent attention ``prefix_k`` is the landed latent rows ``(L,
     1, P0, latent_row)`` and ``prefix_v`` None; the chunk attends them
     EXPANDED, in blocks (:func:`_mla_chunk_attend`), and the returned
-    block is ``k`` alone."""
+    block is ``k`` alone.  With an indexer ``prefix_v`` is the landed
+    index keys ``(L, 1, P0, index_head_dim)``, the block carries ``ik``
+    beside ``k``, and once the chunk's last query sees more than
+    ``index_topk`` positions each query attends its selected rows,
+    absorbed (:func:`_dsa_chunk_attend`)."""
     K, S0 = suffix.shape
     P0 = prefix_k.shape[2]
     p0 = jnp.asarray(prefix_len, jnp.int32)
@@ -2191,18 +2464,29 @@ def prefill_with_prefix(params: Dict, suffix, prefix_k, prefix_v,
         # it has no V, and the returned block none either
         def layer(x, p, kind, kv):
             h = _attn_norm(x, p, cfg)
-            q_nope, q_rope = _mla_q(h, p, cfg, positions=positions)
+            q_nope, q_rope, cq = _mla_q(h, p, cfg, positions=positions,
+                                        with_cq=True)
             lat = _mla_kv(h, p, cfg, positions=positions)
-            o = _mla_chunk_attend(q_nope, q_rope, lat, kv[0][0], p0, p, cfg)
-            return (_mlp_block(x + _mla_out(o, p, cfg), p, cfg,
-                               moe_impl=moe_impl), (lat[:, None], None))
+            ik = None
+            if cfg.sparse:
+                qi, ik, w = _dsa_proj(h, cq, p, cfg, positions=positions)
+            if cfg.sparse and P0 + S0 > cfg.index_topk:
+                o = _dsa_chunk_attend(
+                    _mla_absorb_q(q_nope, q_rope, p, cfg), qi, w, lat, ik,
+                    kv[0][0], kv[1][0], p0, cfg)
+                out = _mla_out(o, p, cfg, absorbed=True)
+            else:
+                out = _mla_out(_mla_chunk_attend(
+                    q_nope, q_rope, lat, kv[0][0], p0, p, cfg), p, cfg)
+            return (_mlp_block(x + out, p, cfg, moe_impl=moe_impl),
+                    (lat[:, None], None if ik is None else ik[:, None]))
 
         x, ys = _scan_layer_kinds(cfg, layer, x, params["layers"],
-                                  {"full": (prefix_k, None)},
+                                  {"full": (prefix_k, prefix_v)},
                                   params.get("dense_layers"))
         last = jnp.take_along_axis(x, (true_len - 1)[:, None, None], axis=1)
         logits = _lm_head(last, params["ln_f"], params["head"], cfg)
-        return logits[:, 0], _by_kind(ys, p0 + true_len)
+        return logits[:, 0], _by_kind(ys, p0 + true_len, cfg.pool_arrays)
     H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     G = H // Hkv
     # (S0, P0 + S0) mask: the real prefix is fully visible, page-tail
@@ -2281,8 +2565,8 @@ def _attention_prefill(x, p, cfg: TransformerConfig, mesh=None,
     (blocks wholly behind it skipped)."""
     from horovod_tpu.ops import attention as attn
 
-    if cfg.latent:  # the cache block is the latent rows; there is no V
-        return _mla_attention(x, p, cfg, mesh) + (None,)
+    if cfg.latent:  # the cache block: the latent rows, and no V (with
+        return _mla_attention(x, p, cfg, mesh)  # an indexer, its keys)
     window = cfg.window if kind == "sliding" else 0
     qh, kh, vh = _qkv_proj(x, p, cfg, 0, kind=kind)  # kh/vh: (B,H_kv,S0,Dh)
     if cfg.attention_impl == "reference":
@@ -2387,7 +2671,7 @@ def prefill(params: Dict, prompt, cache: Dict, cfg: TransformerConfig,
         # two kinds of KV state: handed back by kind for the caller's
         # two pools, not landed in a cache of one shape
         return logits[:, 0], _by_kind(ys, new_pos)
-    blocks = dict(zip(("k", "v"), ys[next(iter(ys))]))
+    blocks = dict(zip(cfg.pool_arrays, ys[next(iter(ys))]))
     with jax.named_scope("kv_land"):
         cache = {n: lax.dynamic_update_slice_in_dim(
             cache[n], b.astype(cache[n].dtype), 0, axis=3)

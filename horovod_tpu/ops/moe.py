@@ -342,7 +342,7 @@ def grouped_matmul(xs, w, layer, counts):
 
 def route_topk(xt, router, k: int = 1, norm_topk: bool = False, *,
                score: str = "softmax", n_group: int = 0, topk_group: int = 0,
-               scale: float = 1.0):
+               scale: float = 1.0, bias=None):
     """Routing in float32: ``(experts (T, k) int32, weights (T, k)
     float32)`` — the ``k`` largest scores of ``x @ router``,
     renormalised to sum to one when ``norm_topk``, times ``scale``
@@ -353,9 +353,11 @@ def route_topk(xt, router, k: int = 1, norm_topk: bool = False, *,
     (the experts in ``n_group`` equal runs of the router's outputs): a
     group's score is the sum of its two largest experts' scores, the
     ``topk_group`` best groups stay, and the ``k`` experts are the
-    largest among theirs.  The weights are the chosen experts' OWN
-    scores (no correction bias is written).  Ties go to the lower
-    index, at every step (``lax.top_k``'s rule)."""
+    largest among theirs.  ``bias`` ``(E,)``: a learned
+    score-correction bias (the published ``noaux_tc``) — groups and
+    experts are CHOSEN on ``scores + bias``.  The weights are always the
+    chosen experts' OWN scores, the bias no part of them.  Ties go to
+    the lower index, at every step (``lax.top_k``'s rule)."""
     logits = xt.astype(jnp.float32) @ router.astype(jnp.float32)
     if score == "softmax":
         probs = jax.nn.softmax(logits, axis=-1)
@@ -364,14 +366,14 @@ def route_topk(xt, router, k: int = 1, norm_topk: bool = False, *,
     else:
         raise ValueError(f"unknown router score {score!r}; expected "
                          "'softmax' or 'sigmoid'")
-    choice = probs
+    choice = probs if bias is None else probs + bias.astype(jnp.float32)
     if n_group > 1:
         T, E = probs.shape
         if E % n_group or not 0 < topk_group <= n_group:
             raise ValueError(
                 f"{E} experts do not split into {n_group} groups of "
                 f"which {topk_group} stay")
-        grouped = probs.reshape(T, n_group, E // n_group)
+        grouped = choice.reshape(T, n_group, E // n_group)
         g_score = jnp.sum(lax.top_k(grouped, 2)[0], axis=-1)
         _, g_keep = lax.top_k(g_score, topk_group)
         keep = jnp.zeros((T, n_group), bool).at[

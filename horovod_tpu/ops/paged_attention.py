@@ -76,7 +76,10 @@ from horovod_tpu.ops._pallas_util import (
 
 __all__ = ["DEQUANT_COMPUTE", "UnsupportedPagedLayoutError", "paged_attend",
            "paged_attend_reference", "mla_decode", "mla_decode_reference",
-           "kernel_supported", "walk", "first_block"]
+           "kernel_supported", "walk", "first_block", "index_scores",
+           "index_scores_reference", "index_scores_rows", "index_block_pages",
+           "select_topk",
+           "selected_attend"]
 
 
 # The pinned dequant compute dtype.  ``kv_dequantize`` promotes int8
@@ -351,7 +354,7 @@ def _kernel_body(table_ref, limit_ref, *refs, page_size, n_pages,
 
 def _pallas_paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table,
                          limit, compute_dtype, lower, layer, v_dim=None,
-                         sm_scale=None):
+                         sm_scale=None, name=None):
     S, Hkv, R, Dh = qg.shape
     _, _, _, ps, _ = k_pool.shape
     quantized = k_scale is not None
@@ -433,7 +436,7 @@ def _pallas_paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=use_interpret(),
-        name=KERNEL_NAME if not latent else MLA_KERNEL_NAME,
+        name=KERNEL_NAME if not (latent or name) else name or MLA_KERNEL_NAME,
     )(*scalars, *operands)
     return o[:, :, :R, :], lse[:, :, 0, :R]
 
@@ -537,7 +540,7 @@ def paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table, limit, *,
 
 
 def mla_decode(q, pool, table, limit, *, v_dim: int, sm_scale: float,
-               layer=None):
+               layer=None, name: str = MLA_KERNEL_NAME):
     """Absorbed latent attention of one token a slot, directly against
     a paged LATENT pool: kernel :data:`MLA_KERNEL_NAME`, which is
     :func:`paged_attend`'s walk (table, limits, :func:`walk`,
@@ -559,6 +562,8 @@ def mla_decode(q, pool, table, limit, *, v_dim: int, sm_scale: float,
 
     Returns ``(o_lat (S, H, v_dim) float32, lse (S, H))``: each head's
     weighted sum of latents — ``W_v`` and ``W_o`` are applied outside.
+    ``name``: the call's name on a device trace (:func:`selected_attend`
+    runs this body over gathered rows under a name of its own).
 
     Per cached token the mathematics needs 576 x 2 bytes (the walk
     fetches the stored 640) and ``H x 2 x (576 + v_dim)`` FLOPs: 1 152 B
@@ -568,7 +573,7 @@ def mla_decode(q, pool, table, limit, *, v_dim: int, sm_scale: float,
         layer, pool = 0, pool[None]
     o, lse = _pallas_paged_attend(
         q[:, None], pool, None, None, None, table, limit, pool.dtype, None,
-        layer, v_dim=v_dim, sm_scale=sm_scale)
+        layer, v_dim=v_dim, sm_scale=sm_scale, name=name)
     return o[:, 0], lse[:, 0]
 
 
@@ -589,3 +594,336 @@ def mla_decode_reference(q, pool, table, limit, *, v_dim: int,
         q[:, None], pool, None, None, None, table, limit, v_dim=v_dim,
         sm_scale=sm_scale)
     return o[:, 0], lse[:, 0]
+
+
+# --- learned sparse attention: the index walk, the selection, the attend ------
+#
+# A sparse latent model (``TransformerConfig.sparse``) keeps ONE index
+# key a token and layer beside the latent row — the pool's second array
+# ``ik`` ``(L, P, 1, page, Di)`` under the same page table — and a tick
+# does three things with it: scores every live token of every slot
+# (:func:`index_scores`, the walk above emitting a score a token instead
+# of a softmax), picks each slot's ``k`` best positions
+# (:func:`select_topk`: exact, no sort), and attends those rows alone
+# (:func:`selected_attend`).
+
+#: The index walk's name on a device trace.
+INDEX_KERNEL_NAME = "hvd_dsa_score"
+#: ... and the selected attend's (``mla_decode``'s body over the
+#: gathered rows).
+SELECT_ATTEND_NAME = "hvd_dsa_attend"
+
+# What one step of the index walk holds of the keys (two buffers of
+# this): 1024 tokens of 128 bf16 values.  The scores of a step are
+# (index heads x tokens) float32 — 256 KB at 64 heads.
+_INDEX_BLOCK_BYTES = 256 * 1024
+
+
+def index_block_pages(page_size: int, index_dim: int, storage_dtype,
+                      max_pages: int) -> int:
+    """Pages one step of the index walk fetches and scores: as many as
+    fit :data:`_INDEX_BLOCK_BYTES`, in whole 128-token lane groups (the
+    scores' last dim), never more than a slot's table holds — 64 pages
+    (1024 tokens) for bf16 pages of 16 x 128."""
+    page_bytes = page_size * index_dim * max(
+        jnp.dtype(storage_dtype).itemsize, 2)
+    n = max(1, min(_INDEX_BLOCK_BYTES // page_bytes, max_pages))
+    group = max(128 // page_size, 1)
+    return n - n % group if n > group else n
+
+
+def _index_kernel_body(table_ref, limit_ref, layer_ref, q_ref, w_ref, k_hbm,
+                       o_ref, k_buf, sems, *, page_size, n_pages):
+    """One grid step of the index walk: slot ``s``'s live pages of index
+    keys, block by block (``_kernel_body``'s fetch discipline: one
+    transfer a live page into one of two buffers while the other is
+    scored), each block's ``(Hi, tokens)`` dots through ReLU, times the
+    heads' weights, summed over the heads into one score a token.
+    Positions ``>= limit`` read ``NEG_INF``; blocks past the last live
+    position are never fetched."""
+    s = pl.program_id(0)
+    layer = layer_ref[0]
+    block_tokens = n_pages * page_size
+    limit = jnp.minimum(limit_ref[s], table_ref.shape[1] * page_size)
+    n_blocks, _ = walk(limit, block_tokens)
+    o_ref[...] = jnp.full(o_ref.shape, NEG_INF, o_ref.dtype)
+
+    def fetch(b, buf, wait):
+        for i in range(n_pages):
+            idx = b * n_pages + i
+
+            @pl.when(idx * page_size < limit)
+            def _page():
+                at = (0, 0) if wait else (layer, table_ref[s, idx])
+                dma = pltpu.make_async_copy(
+                    k_hbm.at[at], k_buf.at[buf, :, i], sems.at[buf])
+                dma.wait() if wait else dma.start()
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        fetch(0, 0, wait=False)
+
+    q = q_ref[0].astype(k_buf.dtype)                      # (Hi, Di)
+    w = w_ref[0]                                          # (Hi, 1) f32
+
+    def block(b, carry):
+        buf = b % 2
+
+        @pl.when(b + 1 < n_blocks)
+        def _next():
+            fetch(b + 1, 1 - buf, wait=False)
+
+        fetch(b, buf, wait=True)
+        k = k_buf[buf].reshape(block_tokens, k_buf.shape[-1])
+        dots = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)           # (Hi, tokens)
+        score = jnp.sum(jnp.maximum(dots, 0.0) * w, axis=0, keepdims=True)
+        col = b * block_tokens + jax.lax.broadcasted_iota(
+            jnp.int32, score.shape, 1)
+        o_ref[0, pl.ds(b, 1), :] = jnp.where(col < limit, score, NEG_INF)
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, block, 0)
+
+
+def index_scores(qi, w, ik_pool, table, limit, *, layer=None):
+    """The INDEX WALK: every slot's index score of each of its live
+    tokens, directly against the paged index keys — kernel
+    :data:`INDEX_KERNEL_NAME`, :func:`paged_attend`'s walk (table,
+    limits, :func:`walk`, a block of :func:`index_block_pages` pages,
+    two buffers) with a score a token where that one keeps a softmax.
+
+    Args:
+      qi: ``(S, Hi, Di)`` index queries (roped), one token a slot.
+      w: ``(S, Hi)`` float32 head weights, the two scales folded in.
+      ik_pool: every layer's index keys ``(L, P, 1, page, Di)`` with
+        ``layer``, or one layer's ``(P, 1, page, Di)`` with
+        ``layer=None``.
+      table, limit: as :func:`paged_attend`.
+
+    Returns ``(S, max_pages * page)`` float32: ``sum_j w[s, j] * relu(qi[s, j] . k[t])`` at live
+    positions ``t < limit[s]``, ``NEG_INF`` elsewhere.
+
+    Per live token the mathematics needs ``Di`` x 2 bytes and ``Hi x Di
+    x 2`` FLOPs: 256 B against 16 kFLOP at the published sizes, 64
+    FLOPs a byte — bound by bytes."""
+    if layer is None:
+        layer, ik_pool = 0, ik_pool[None]
+    S, Hi, Di = qi.shape
+    ps = ik_pool.shape[3]
+    max_pages = table.shape[1]
+    n_pages = index_block_pages(ps, Di, ik_pool.dtype, max_pages)
+    n_blocks = -(-max_pages // n_pages)
+    bt = n_pages * ps
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    scalars = [table.astype(jnp.int32), limit.astype(jnp.int32),
+               jnp.asarray(layer, jnp.int32).reshape(1)]
+
+    def of_slot(s, *scalars):
+        return (s, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(scalars), grid=(S,),
+        in_specs=[pl.BlockSpec((1, Hi, Di), of_slot),
+                  pl.BlockSpec((1, Hi, 1), of_slot), hbm],
+        out_specs=pl.BlockSpec((1, n_blocks, bt), of_slot),
+        scratch_shapes=[pltpu.VMEM((2, 1, n_pages, ps, Di), ik_pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,))])
+    out = pl.pallas_call(
+        functools.partial(_index_kernel_body, page_size=ps, n_pages=n_pages),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, n_blocks, bt), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=use_interpret(),
+        name=INDEX_KERNEL_NAME,
+    )(*scalars, qi, w.astype(jnp.float32)[..., None], ik_pool)
+    return out.reshape(S, n_blocks * bt)[:, :max_pages * ps]
+
+
+def index_scores_dense(qi, w, keys):
+    """Index scores against keys that lie in order: ``qi`` ``(R, Hi,
+    Di)``, ``w`` ``(R, Hi)`` float32, ``keys`` ``(R, T, Di)`` (or ``(T,
+    Di)``, every row's) -> ``(R, T)`` float32.  The products take the
+    keys' dtype and accumulate in float32, as the kernel's do."""
+    eq = "rhd,rtd->rht" if keys.ndim == 3 else "rhd,td->rht"
+    dots = jnp.einsum(eq, qi.astype(keys.dtype), keys,
+                      preferred_element_type=jnp.float32)
+    return jnp.sum(jnp.maximum(dots, 0.0)
+                   * w.astype(jnp.float32)[..., None], axis=1)
+
+
+def _rows_kernel_body(q_ref, w_ref, k_ref, o_ref):
+    tq, Hi, Di = q_ref.shape
+    dots = jax.lax.dot_general(
+        q_ref[...].reshape(tq * Hi, Di), k_ref[...],
+        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    dots = jnp.maximum(dots, 0.0) * w_ref[...].reshape(tq * Hi, 1)
+    o_ref[...] = jnp.sum(dots.reshape(tq, Hi, dots.shape[-1]), axis=1)
+
+
+def index_scores_rows(qi, w, keys, *, kernel: bool):
+    """A CHUNK's index scores, every query against every key that lies
+    in order: ``qi`` ``(Q, Hi, Di)``, ``w`` ``(Q, Hi)``, ``keys`` ``(T,
+    Di)`` -> ``(Q, T)`` float32, unmasked (the selection is told how
+    many positions each query sees).  ``kernel``: one Pallas program
+    (:data:`INDEX_KERNEL_NAME`) a tile of 32 queries x 512 keys — the
+    ``(queries, heads, keys)`` dots live in VMEM alone (4.3 GB of them
+    for 512 queries against 32 k keys) — else
+    :func:`index_scores_dense`."""
+    if not kernel:
+        return index_scores_dense(qi, w, keys)
+    Q, Hi, Di = qi.shape
+    T = keys.shape[0]
+    tq, tk = min(32, Q), 512
+    Qp, Tp = -(-Q // tq) * tq, -(-T // tk) * tk
+    qi = jnp.pad(qi.astype(keys.dtype), ((0, Qp - Q), (0, 0), (0, 0)))
+    w = jnp.pad(w.astype(jnp.float32), ((0, Qp - Q), (0, 0)))[..., None]
+    keys = jnp.pad(keys, ((0, Tp - T), (0, 0)))
+    out = pl.pallas_call(
+        _rows_kernel_body,
+        grid=(Qp // tq, Tp // tk),
+        in_specs=[pl.BlockSpec((tq, Hi, Di), lambda i, j: (i, 0, 0)),
+                  pl.BlockSpec((tq, Hi, 1), lambda i, j: (i, 0, 0)),
+                  pl.BlockSpec((tk, Di), lambda i, j: (j, 0))],
+        out_specs=pl.BlockSpec((tq, tk), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((Qp, Tp), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=use_interpret(),
+        name=INDEX_KERNEL_NAME,
+    )(qi, w, keys)
+    return out[:Q, :T]
+
+
+def index_scores_reference(qi, w, ik_pool, table, limit, *, layer=None):
+    """:func:`index_scores`' unfused twin (gather, dots, mask, in XLA):
+    the test oracle, and the tick's index walk where the kernel is not
+    engaged (the CPU).  ``(S, max_pages * page)``."""
+    if layer is None:
+        layer, ik_pool = 0, ik_pool[None]
+    S, max_pages = table.shape
+    g = ik_pool[layer, table]              # (S, max_pages, 1, page, Di)
+    keys = g.reshape(S, max_pages * g.shape[3], g.shape[4])
+    col = jax.lax.broadcasted_iota(jnp.int32, keys.shape[:2], 1)
+    return jnp.where(col < limit[:, None],
+                     index_scores_dense(qi, w, keys), NEG_INF)
+
+
+def _order_keys(x):
+    """float32 -> uint32 whose unsigned order is the floats' order
+    (``-0.0 < +0.0``; NaNs at the ends)."""
+    u = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
+    return jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(1 << 31))
+
+
+_LANES = 128
+
+
+def _block_prefix(mask):
+    """Inclusive running count of a ``(R, nb, 128)`` bool ``mask``
+    inside each 128-wide block, float32: a product with a triangle of
+    ones on the MXU (counts to 128 are exact in bfloat16 operands and
+    float32 sums), not a scan."""
+    tri = (jax.lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 0)
+           <= jax.lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 1))
+    return jnp.einsum("rbl,lm->rbm", mask.astype(jnp.bfloat16),
+                      tri.astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32)
+
+
+def select_topk(scores, n_valid, k: int, width: int = 0):
+    """Each row's ``min(k, n_valid)`` best-scored positions among its
+    first ``n_valid`` — EXACTLY ``lax.top_k``'s set (ties to the lower
+    position), with no sort: the set is what attention needs, not its
+    order.
+
+    ``scores`` ``(R, T)`` float32, ``n_valid`` ``(R,)`` int32 (positions
+    ``>= n_valid[r]`` are never picked, whatever they hold).  Returns
+    ``(idx (R, width) int32, count (R,) int32)``, ``width`` (0 = ``k``)
+    at least ``k``: row ``r``'s picks are ``idx[r, :count[r]]``,
+    ascending; the places behind hold 0.
+
+    Three steps, all of them counting passes and small dense products:
+    (1) the ``count``-th largest score by BISECTION over the floats'
+    bit pattern — 32 passes, each counting the row's scores at or over
+    a candidate; (2) everything over it is in, and of the scores EQUAL
+    to it the lowest positions, as many as are still owed (a running
+    count); (3) COMPACTION of the picked mask into positions, two
+    levels of 128: the block that holds the row's ``j``-th pick from
+    the blocks' running totals, the lane inside it from the block's
+    running count, the block's row fetched by a one-hot product."""
+    R, T = scores.shape
+    width = width or k
+    assert width >= k, (width, k)
+    Tp = -(-T // _LANES) * _LANES
+    n_valid = jnp.minimum(n_valid.astype(jnp.int32), T)
+    count = jnp.minimum(n_valid, k)
+    valid = jax.lax.broadcasted_iota(jnp.int32, (R, Tp), 1) < n_valid[:, None]
+    keys = jnp.pad(_order_keys(scores), ((0, 0), (0, Tp - T)))
+    keys = jnp.where(valid, keys, jnp.uint32(0))
+
+    def bit(i, tau):
+        cand = tau | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        n = jnp.sum((keys >= cand[:, None]) & valid, axis=1,
+                    dtype=jnp.int32)
+        return jnp.where(n >= count, cand, tau)
+
+    # the largest tau with at least ``count`` valid scores at or over it
+    tau = jax.lax.fori_loop(0, 32, bit, jnp.zeros((R,), jnp.uint32))
+    over = valid & (keys > tau[:, None])
+    tie = (valid & (keys == tau[:, None])).reshape(R, -1, _LANES)
+    owed = (count - jnp.sum(over, axis=1, dtype=jnp.int32)
+            ).astype(jnp.float32)
+    tie_in = _block_prefix(tie)
+    tie_before = jnp.cumsum(tie_in[..., -1], axis=1) - tie_in[..., -1]
+    picked = over.reshape(tie.shape) | (
+        tie & (tie_in + tie_before[..., None] <= owed[:, None, None]))
+
+    # compaction: pick j of a row lies in the block whose running total
+    # first passes j, at the lane whose running count is j less the
+    # blocks' before it, plus one
+    rank = _block_prefix(picked)
+    in_block = rank[..., -1]                              # (R, nb)
+    rank = rank * picked                                  # 0 = not picked
+    total = jnp.cumsum(in_block, axis=1)
+    j = jnp.arange(width, dtype=jnp.float32)
+    blk = jnp.sum(total[:, None, :] <= j[None, :, None], axis=-1,
+                  dtype=jnp.int32)                        # (R, k)
+    nb = Tp // _LANES
+    hot = blk[..., None] == jnp.arange(nb, dtype=jnp.int32)   # (R, k, nb)
+    before = total - in_block
+    want = j[None, :] - jnp.sum(jnp.where(hot, before[:, None, :], 0.0),
+                                axis=-1) + 1.0
+    row = jnp.einsum("rkb,rbl->rkl", hot.astype(jnp.bfloat16),
+                     rank.astype(jnp.bfloat16),
+                     preferred_element_type=jnp.float32)
+    lane = jnp.sum(jnp.where(row == want[..., None],
+                             jnp.arange(_LANES, dtype=jnp.int32), 0),
+                   axis=-1)
+    idx = jnp.where(jnp.arange(width)[None, :] < count[:, None],
+                    blk * _LANES + lane, 0)
+    return idx.astype(jnp.int32), count
+
+
+def selected_attend(q, rows, count, *, v_dim: int, sm_scale: float,
+                    kernel: bool):
+    """Absorbed latent attention of each query over ITS OWN gathered
+    rows: ``q`` ``(R, H, W)``, ``rows`` ``(R, K, W)`` (row ``r``'s
+    selected cache rows, the first ``count[r]`` of them real), ``K`` a
+    multiple of 16.  The rows are read as a pool of ``R * K / 16`` pages
+    under an identity table, so the kernel is :func:`mla_decode`'s body
+    (one walk, one online softmax) under the name
+    :data:`SELECT_ATTEND_NAME`; ``kernel=False`` is its unfused twin.
+    -> ``(o_lat (R, H, v_dim) float32, lse (R, H))``."""
+    R, K, W = rows.shape
+    ps = 16
+    assert K % ps == 0, K
+    pool = rows.reshape(R * K // ps, 1, ps, W)
+    table = jnp.arange(R * K // ps, dtype=jnp.int32).reshape(R, K // ps)
+    if kernel:
+        return mla_decode(q, pool, table, count, v_dim=v_dim,
+                          sm_scale=sm_scale, name=SELECT_ATTEND_NAME)
+    return mla_decode_reference(q, pool, table, count, v_dim=v_dim,
+                                sm_scale=sm_scale)

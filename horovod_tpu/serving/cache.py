@@ -3,7 +3,9 @@
 The paged layout (PagedAttention, Kwon et al., SOSP 2023) stores K/V as
 a pool of fixed-size pages, ``(L, P, H_kv, page, Dh)`` (K and V; a
 latent-attention model's ONE array ``(L, P, 1, page, 640)``, the rows
-its heads share: :func:`init_page_pool`); each of the S
+its heads share, and beside it, where the model has an indexer, its
+keys ``(L, P, 1, page, 128)`` under the same page table:
+:func:`init_page_pool`); each of the S
 slots owns an int32 page-table row, resolved INSIDE the compiled decode
 tick (:func:`~horovod_tpu.models.transformer.decode_step_paged`), and a
 per-slot ``(S,)`` write position, because every slot holds a different
@@ -83,9 +85,18 @@ def init_page_pool(cfg: "T.TransformerConfig", n_slots: int, n_pages: int,
             raise T.UnsupportedModelConfigError(
                 "int8 pages (per-vector scales) are not written for a "
                 "latent pool")
-        return {"k": jnp.zeros((L, n_pages, 1, page_size,
+        pool = {"k": jnp.zeros((L, n_pages, 1, page_size,
                                 cfg.latent_row), dt),
                 "pos": jnp.zeros((n_slots,), jnp.int32)}
+        if cfg.sparse:
+            # ... and a SECOND array under the same page table: the
+            # indexer's key, one ``index_head_dim`` vector a token and
+            # layer (128: exactly one lane group, no padding).  A page
+            # of it is granted, landed, copied and released WITH the
+            # latent page of the same id.
+            pool["ik"] = jnp.zeros((L, n_pages, 1, page_size,
+                                    cfg.index_head_dim), dt)
+        return pool
     Hkv, Dh = cfg.kv_heads, cfg.head_dim
     pool = {
         "k": jnp.zeros((L, n_pages, Hkv, page_size, Dh), dt),
@@ -151,7 +162,7 @@ def landing_pages(bucket: int, page_size: int) -> int:
 
 @jax.named_scope("kv_land")  # T.DEVICE_SCOPES
 def paged_insert(pool: Dict, slots, new_pos, pages, first, lens,
-                 prefilled_k, prefilled_v=None) -> Dict:
+                 prefilled_k, prefilled_v=None, prefilled_ik=None) -> Dict:
     """Land a prefilled K/V block ``(L, K, H_kv, Tb, Dh)`` into pages.
     Column ``t`` of row ``i`` is logical position ``start + t``; with
     ``first = start % page`` it goes to offset ``(first + t) % page``
@@ -166,7 +177,9 @@ def paged_insert(pool: Dict, slots, new_pos, pages, first, lens,
     landings — prefix registration).  int8 pools quantize per vector
     on the way in; payload and scale go through the same
     :func:`write_pages`.  A latent pool has ``k`` alone
-    (``prefilled_v`` None): the block is the latent rows."""
+    (``prefilled_v`` None): the block is the latent rows; a sparse
+    model's index keys ``prefilled_ik`` land in ``ik`` at the same
+    pages and offsets."""
     ps = pool["k"].shape[3]
     L, n_pg = pool["k"].shape[0], pages.shape[1]
     first = jnp.asarray(first, jnp.int32)
@@ -187,6 +200,8 @@ def paged_insert(pool: Dict, slots, new_pos, pages, first, lens,
     out["k"] = land("k", k)
     if v is not None:
         out["v"] = land("v", v)
+    if prefilled_ik is not None:
+        out["ik"] = land("ik", prefilled_ik)
     out["pos"] = pool["pos"].at[slots].set(new_pos)
     return out
 
@@ -197,7 +212,7 @@ def copy_page(pool: Dict, src, dst) -> Dict:
     copy-on-write primitive.  ``src``/``dst`` are traced scalars, so
     one compile covers every copy."""
     out = dict(pool)
-    for name in ("k", "v", "k_scale", "v_scale"):
+    for name in ("k", "v", "k_scale", "v_scale", "ik"):
         if name in pool:
             a = pool[name]
             layer = jnp.arange(a.shape[0], dtype=jnp.int32)
@@ -212,12 +227,16 @@ def gather_prefix_pages(pool: Dict, pages):
     ``(k, v)`` of shape ``(L, H_kv, n * page, Dh)`` — the shared-prefix
     K/V handed to :func:`~horovod_tpu.models.transformer.
     prefill_with_prefix`.  int8 pools dequantize here (f32), so the
-    suffix prefill attends real values."""
+    suffix prefill attends real values.  A latent pool gives ``(rows,
+    None)``, or with an indexer ``(rows, index keys)``."""
     k = pool["k"][:, pages]                   # (L, n, H_kv, ps, Dh)
     L, n, Hkv, ps, Dh = k.shape
     k = jnp.moveaxis(k, 1, 2).reshape(L, Hkv, n * ps, Dh)
     if "v" not in pool:                       # a latent pool's rows
-        return k, None
+        if "ik" not in pool:
+            return k, None
+        return k, jnp.moveaxis(pool["ik"][:, pages], 1, 2).reshape(
+            L, 1, n * ps, -1)
     v = jnp.moveaxis(pool["v"][:, pages], 1, 2).reshape(L, Hkv, n * ps, Dh)
     if "k_scale" in pool:
         ks = jnp.moveaxis(pool["k_scale"][:, pages], 1, 2
@@ -398,14 +417,29 @@ class PagedSlotCache:
         """KV bytes one token costs in this pool (the quantization
         lever made legible): payload for k+v across layers, plus the
         per-vector scales for int8."""
+        if self.cfg.latent:    # one stored row a layer, and its index key
+            return self.latent_bytes_per_token + self.index_bytes_per_token
         elem = jnp.dtype(self._storage_dtype).itemsize
-        if self.cfg.latent:    # one stored row a layer (cfg.latent_row)
-            return self.n_layers * self.cfg.latent_row * elem
         n = self.n_layers * self.cfg.kv_heads
         b = 2 * n * self.cfg.head_dim * elem
         if self.quantized:
             b += 2 * n * 4  # f32 scale per (layer, head, token) vector
         return b
+
+    @property
+    def latent_bytes_per_token(self) -> int:
+        """What a token leaves in a latent pool's rows, every layer's
+        (``cfg.latent_row`` as stored; 0 for a pool of K and V)."""
+        return (self.n_layers * self.cfg.latent_row
+                * jnp.dtype(self._storage_dtype).itemsize
+                if self.cfg.latent else 0)
+
+    @property
+    def index_bytes_per_token(self) -> int:
+        """... and in a sparse model's index-key array beside them."""
+        return (self.n_layers * self.cfg.index_head_dim
+                * jnp.dtype(self._storage_dtype).itemsize
+                if self.cfg.sparse else 0)
 
     def pages_for(self, n_tokens: int) -> int:
         return -(-n_tokens // self.page_size) if n_tokens > 0 else 0
@@ -558,7 +592,7 @@ class PagedSlotCache:
             self._land_pages(rows, start, true_lens, bucket),
             np.int32(start % self.page_size),
             np.asarray(true_lens, np.int32), prefilled["k"],
-            prefilled.get("v"))
+            prefilled.get("v"), prefilled.get("ik"))
 
     def land(self, slots: Sequence[int], prefilled: Dict,
              true_lens, start: int = 0) -> None:

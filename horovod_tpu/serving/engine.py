@@ -611,8 +611,9 @@ class InferenceEngine:
                 f"prefill_chunk_tokens must be >= 1 (or 0 to disable), "
                 f"got {engine_cfg.prefill_chunk_tokens}")
         if cfg.latent or cfg.n_dense_layers or cfg.held_offset is not None:
-            # Latent attention, leading dense layers and a chip's share
-            # of the experts are written for the paged single-chip tick
+            # Latent attention (with or without an indexer's sparse
+            # selection), leading dense layers and a chip's share of
+            # the experts are written for the paged single-chip tick
             # and the prefills: every other mode refuses them by name.
             refused = [why for on, why in (
                 (engine_cfg.tp > 1, "tp > 1"),
@@ -622,7 +623,9 @@ class InferenceEngine:
             ) if on]
             if refused:
                 raise T.UnsupportedModelConfigError(
-                    "a configuration with latent attention, leading "
+                    "a configuration with latent attention"
+                    + (" and sparse selection (an indexer)" if cfg.sparse
+                       else "") + ", leading "
                     "dense layers or a share of the experts is not "
                     "served with " + ", ".join(refused))
         if cfg.has_window:
@@ -955,6 +958,12 @@ class InferenceEngine:
                 self.slots.page_size, cfg.kv_heads // engine_cfg.tp,
                 cfg.head_dim, self.slots._storage_dtype,
                 self.slots.max_pages))
+        # ... and one step of a sparse model's index walk
+        self._index_block_tokens = self.slots.page_size * (
+            _pa.index_block_pages(
+                self.slots.page_size, cfg.index_head_dim,
+                self.slots._storage_dtype, self.slots.max_pages)
+            if cfg.sparse else 0)
         # Registered shared prefixes (token tuple -> entry); epoch
         # stamps which cache lifetime the pinned pages belong to.
         self._prefixes: Dict[tuple, _PrefixEntry] = {}
@@ -1383,6 +1392,10 @@ class InferenceEngine:
             raise T.UnsupportedModelConfigError(
                 "prefix sharing is not written for window layers' pages "
                 "(a sharer's window would release a page its peers read)")
+        if self.cfg.sparse:
+            raise T.UnsupportedModelConfigError(
+                "prefix sharing is not written for sparse attention (an "
+                "indexer's keys beside the latent rows)")
         tokens = tuple(int(t) for t in tokens)
         if not tokens:
             raise ServingError("empty prefix")
@@ -2283,6 +2296,18 @@ class InferenceEngine:
         the positions the kernel's walk covers for those limits (the
         kernel's own trip count, ``ops.paged_attention.walk``)."""
         limit = self._page_pos[active] + 1
+        if self.cfg.sparse:
+            # a sparse model's tick walks the INDEX keys of every live
+            # token and reads at most index_topk latent rows a slot:
+            # the latent pool's own walk does not run
+            k = self.cfg.index_topk
+            _, walked = _pa.walk(limit, self._index_block_tokens)
+            self.metrics.dsa_scored_tokens.inc(int(limit.sum()))
+            self.metrics.dsa_walked_tokens.inc(int(walked.sum()))
+            self.metrics.dsa_selected_tokens.inc(
+                int(np.minimum(limit, k).sum()))
+            self.metrics.dsa_full_rows.inc(int((limit <= k).sum()))
+            return
         _, walked = _pa.walk(limit, self._walk_block_tokens)
         self.metrics.paged_live_tokens.inc(int(limit.sum()))
         self.metrics.paged_walked_tokens.inc(int(walked.sum()))
@@ -3931,8 +3956,9 @@ class InferenceEngine:
             "kv_dtype": str(jnp.dtype(self.slots._storage_dtype).name),
             # what a token leaves in a LATENT pool, every layer's row
             # (0: the pool holds every head's K and V, kv_bytes_per_token)
-            "kv_latent_bytes_per_token":
-                self.slots.bytes_per_token if self.cfg.latent else 0,
+            "kv_latent_bytes_per_token": self.slots.latent_bytes_per_token,
+            # ... and in a sparse model's index-key array beside it
+            "kv_index_bytes_per_token": self.slots.index_bytes_per_token,
             "kv_pages_high_water": self.slots.pages_high_water,
             "kv_window_pages_per_slot_bound":
                 self.wslots.window_pages_bound

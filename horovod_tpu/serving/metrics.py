@@ -105,6 +105,14 @@ class ServingMetrics:
       tokens`` / ``window_walked_tokens`` are the window layers' (live:
       the window's span; walked: whole blocks from the one holding the
       window's first position).
+    * ``dsa_scored_tokens`` / ``dsa_walked_tokens`` /
+      ``dsa_selected_tokens`` / ``dsa_full_rows`` — a sparse-attention
+      model's dispatched ticks (a layer counted once): the live tokens
+      the index walk scores, the tokens its blocks fetch for them
+      (``ops.paged_attention.index_block_pages``), the rows the selected
+      attend reads (``min(index_topk, context)`` a slot), and the
+      slot-ticks whose context is no longer than ``index_topk`` (the
+      selection then keeps everything).
     * ``sample_ticks_drawfree`` / ``sample_ticks_sortfree`` — dispatched
       decode ticks whose next-token pick skipped the draw (every row
       greedy) / ran no sort (that, or no row with a top-k or a nucleus):
@@ -255,6 +263,22 @@ class ServingMetrics:
             "serving_window_walked_tokens_total",
             "Per dispatched paged tick, the positions a window layer's "
             "walk covers (whole blocks from the window's first)")
+        self.dsa_scored_tokens = r.counter(
+            "serving_dsa_scored_tokens_total",
+            "Per dispatched paged tick of a sparse-attention model, the "
+            "live tokens its index walk scores (a layer counted once)")
+        self.dsa_walked_tokens = r.counter(
+            "serving_dsa_walked_tokens_total",
+            "Per dispatched paged tick, the tokens the index walk's "
+            "blocks fetch (each slot's limit rounded up to a block)")
+        self.dsa_selected_tokens = r.counter(
+            "serving_dsa_selected_tokens_total",
+            "Per dispatched paged tick, the cache rows the selected "
+            "attend reads: min(index_topk, context) a slot")
+        self.dsa_full_rows = r.counter(
+            "serving_dsa_full_rows_total",
+            "Slot-ticks whose context is at most index_topk: the "
+            "selection keeps every position")
         self.sample_ticks_drawfree = r.counter(
             "serving_sample_ticks_drawfree_total",
             "Dispatched decode ticks whose batch held no sampled row: "
@@ -456,6 +480,10 @@ class ServingMetrics:
             "paged_walked_tokens_total": self.paged_walked_tokens.value,
             "window_live_tokens_total": self.window_live_tokens.value,
             "window_walked_tokens_total": self.window_walked_tokens.value,
+            "dsa_scored_tokens_total": self.dsa_scored_tokens.value,
+            "dsa_walked_tokens_total": self.dsa_walked_tokens.value,
+            "dsa_selected_tokens_total": self.dsa_selected_tokens.value,
+            "dsa_full_rows_total": self.dsa_full_rows.value,
             "sample_ticks_drawfree_total":
                 self.sample_ticks_drawfree.value,
             "sample_ticks_sortfree_total":

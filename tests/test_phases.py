@@ -481,7 +481,7 @@ class TestDeviceScopes:
         for mod in (A, PA):
             src = inspect.getsource(mod)
             calls = len(re.findall(r"= pl\.pallas_call\(", src))
-            assert calls and len(re.findall(r'\bname=("hvd_\w+"|KERNEL_NAME)',
+            assert calls and len(re.findall(r'\bname=("hvd_\w+"|\w*KERNEL_NAME)',
                                             src)) == calls, mod.__name__
 
 

@@ -13,7 +13,9 @@ Keep every such compile in THIS file (one process may hold libtpu), and
 describe the topology only inside the fixture below.
 """
 
+import dataclasses
 import os
+import re
 import sys
 
 import jax
@@ -96,6 +98,12 @@ CASES = {
                    norm_topk_prob=True, routed_scaling_factor=2.5,
                    moe_impl="dropless"),
 }
+# ... and the latent case with a lightning indexer (4 index heads of
+# 128, index_topk 128 under a table of 512): TWO pool arrays under one
+# table, the index walk and the selected attend in the tick
+CASES["sparse"] = dataclasses.replace(
+    CASES["latent"], index_n_heads=4, index_head_dim=128, index_topk=128,
+    moe_score_bias=True)
 
 
 @pytest.mark.parametrize("what", ["tick", "landing"])
@@ -134,7 +142,19 @@ def test_the_tpu_compiler_writes_the_pool_in_place(one_chip, monkeypatch,
                 on_chip(jax.ShapeDtypeStruct((S,), jnp.bool_)), table,
                 table, pool).compile()
         assert "tpu_custom_call" in compiled.as_text()
-        if cfg.latent:      # the latent walk is the kernel in the tick
+        if cfg.sparse:
+            # the index walk and the selected attend went through
+            # Mosaic; the dense latent walk is not in this tick; and the
+            # selection compiled to NO sort (the router's small top-k is
+            # the tick's only one: none lies under hvd_dsa_select)
+            text = compiled.as_text()
+            assert PA.INDEX_KERNEL_NAME in text
+            assert PA.SELECT_ATTEND_NAME in text
+            assert PA.MLA_KERNEL_NAME not in text
+            assert not [l for l in text.splitlines()
+                        if re.search(r"= \S+ sort\(", l)
+                        and "hvd_dsa_select" in l]
+        elif cfg.latent:    # the latent walk is the kernel in the tick
             assert PA.MLA_KERNEL_NAME in compiled.as_text()
     else:
         full = {n: a for n, a in pool.items() if n not in ("wk", "wv")}
@@ -143,9 +163,12 @@ def test_the_tpu_compiler_writes_the_pool_in_place(one_chip, monkeypatch,
             + pool["k"].shape[4:], cfg.dtype))
         i32 = lambda *shape: on_chip(  # noqa: E731
             jax.ShapeDtypeStruct(shape, jnp.int32))
+        ik = on_chip(jax.ShapeDtypeStruct(
+            blk.shape[:-1] + (cfg.index_head_dim,), cfg.dtype))
         compiled = jax.jit(C.paged_insert, donate_argnums=(0,)).lower(
             full, i32(2), i32(2), i32(2, C.landing_pages(128, PS)), i32(),
-            i32(2), blk, *([blk] if "v" in pool else [])).compile()
+            i32(2), blk, blk if "v" in pool else None,
+            ik if "ik" in pool else None).compile()
     offenders, largest = chip_smoke.pool_sized_results(compiled.as_text(),
                                                        layer)
     assert offenders == [], (offenders, largest)
@@ -153,6 +176,131 @@ def test_the_tpu_compiler_writes_the_pool_in_place(one_chip, monkeypatch,
     # program holds less than one layer of it
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < layer * 2, mem
+
+
+@pytest.mark.parametrize("what", ["index_walk", "select", "attend",
+                                  "chunk_scores"])
+def test_sparse_attentions_parts_compile_at_the_published_sizes(
+        one_chip, monkeypatch, what):
+    """DeepSeek-V3.2-Exp's own sizes (64 index heads of 128, 128 heads
+    over rows of 640, ``index_topk`` 2048, 24 slots of 32 768 in pages of
+    16): each part of a tick's sparse attention, and a chunk's scores,
+    through XLA:TPU / Mosaic for the v5e."""
+    monkeypatch.setattr(PA, "use_interpret", lambda: False)
+    S_, ML, K = 24, 32768, 2048
+    bf, f32 = jnp.bfloat16, jnp.float32
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    table, lim = sds((S_, ML // PS), jnp.int32), sds((S_,), jnp.int32)
+    if what == "index_walk":
+        args = (sds((S_, 64, 128), bf), sds((S_, 64), f32),
+                sds((5, S_ * ML // PS + 1, 1, PS, 128), bf), table, lim,
+                sds((), jnp.int32))
+        fn = lambda q, w, pool, t, l, i: PA.index_scores(  # noqa: E731
+            q, w, pool, t, l, layer=i)
+    elif what == "select":
+        args = (sds((S_, ML), f32), lim)
+        fn = lambda sc, l: PA.select_topk(sc, l, K)  # noqa: E731
+    elif what == "attend":
+        args = (sds((S_, 128, 640), bf), sds((S_, K, 640), bf), lim)
+        fn = lambda q, rows, l: PA.selected_attend(  # noqa: E731
+            q, rows, l, v_dim=512, sm_scale=0.135, kernel=True)
+    else:
+        args = (sds((512, 64, 128), bf), sds((512, 64), f32),
+                sds((ML + 512, 128), bf))
+        fn = lambda q, w, keys: PA.index_scores_rows(  # noqa: E731
+            q, w, keys, kernel=True)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    if what == "select":
+        assert not re.search(r"= \S+ sort\(", text)
+        assert "tpu_custom_call" not in text
+    else:
+        assert "tpu_custom_call" in text
+        assert (PA.SELECT_ATTEND_NAME if what == "attend"
+                else PA.INDEX_KERNEL_NAME) in text
+
+
+def test_the_served_sparse_tick_fits_the_chip_and_writes_in_place(
+        one_chip, monkeypatch):
+    """The benchmark's own configuration (`deepseek-v3.2-exp-serve`: 24
+    slots of 32 768, 49 152 pages) as the engine's tick, compiled for
+    the v5e from shapes alone: 3.226 B parameters and 6.04 GB of pool
+    are its arguments, both pool arrays come back aliased, no
+    instruction has a result the size of a layer of the index-key array
+    (the smaller of the two), and the temporaries — the scores, the
+    selection's one-hots, 24 x 2048 gathered rows — stay under 0.2
+    GB."""
+    import json
+
+    from chipbench.drivers import serve_sparse
+
+    monkeypatch.setattr(PA, "use_interpret", lambda: False)
+    monkeypatch.setattr(MOE, "use_interpret", lambda: False)
+    with open(os.path.join(os.path.dirname(chip_smoke.__file__), "chipbench",
+                           "configs", "deepseek-v3.2-exp-serve.json")) as f:
+        dims = json.load(f)
+    cfg, eng = serve_sparse.build_cfg(dims), dims["engine"]
+    params = _params(one_chip, cfg)
+    n_params = sum(a.size for a in jax.tree_util.tree_leaves(params))
+    assert abs(n_params - 3.226e9) < 1e6, n_params
+    pool = _on(one_chip, jax.eval_shape(lambda: C.init_page_pool(
+        cfg, eng["n_slots"], eng["n_pages"] + 1, eng["page_size"])))
+    S_ = eng["n_slots"]
+    compiled = jax.jit(
+        lambda p, tok, act, t, pl: T.decode_step_paged(
+            p, tok, pl, t, cfg, act, kernel=True, return_moe_load=True),
+        donate_argnums=(4,)).lower(
+            params, _on(one_chip, jax.ShapeDtypeStruct((S_,), jnp.int32)),
+            _on(one_chip, jax.ShapeDtypeStruct((S_,), jnp.bool_)),
+            _on(one_chip, jax.ShapeDtypeStruct(
+                (S_, eng["max_len"] // eng["page_size"]), jnp.int32)),
+            pool).compile()
+    text = compiled.as_text()
+    assert PA.INDEX_KERNEL_NAME in text and PA.SELECT_ATTEND_NAME in text
+    layer = pool["ik"].size // pool["ik"].shape[0]
+    offenders, largest = chip_smoke.pool_sized_results(text, layer)
+    assert offenders == [], (offenders, largest)
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * (pool["k"].size + pool["ik"].size)
+    assert abs(pool_bytes - 6.04e9) < 2e7
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 0.2e9, mem
+    assert mem.argument_size_in_bytes < 12.6e9, mem
+
+
+@pytest.mark.parametrize("half", ["attend", "feed"])
+def test_the_sparse_cells_reference_fits_the_chip_in_one_width(one_chip,
+                                                               half):
+    """The benchmark's own float32 reference of `deepseek-v3.2-exp-serve`
+    (``chipbench/reference_sparse.py``), a sequence of any length laid in
+    the engine's 32 768 rows: each half of a layer compiles for the v5e
+    with its temporaries well inside the chip (the engine is gone by
+    then), its length a traced scalar — one executable whatever the seed
+    draws."""
+    import json
+
+    from chipbench import reference_sparse as R
+    from chipbench import weights_sparse as W
+
+    with open(os.path.join(os.path.dirname(chip_smoke.__file__), "chipbench",
+                           "configs", "deepseek-v3.2-exp-serve.json")) as f:
+        dims = json.load(f)
+    att, ffn = R._layer_fns(R._freeze({k: dims[k] for k in R._LAYER_KEYS}),
+                            "f32", True, ())
+    w = _on(one_chip, jax.eval_shape(lambda: W._layer(
+        jax.random.key(0), 1, dims, jnp.bfloat16, False)))
+    wa = {k: w.pop(k) for k in R.ATTENTION_LEAVES}
+    x = _on(one_chip, jax.ShapeDtypeStruct(
+        (dims["engine"]["max_len"], dims["hidden_size"]), jnp.float32))
+    n = _on(one_chip, jax.ShapeDtypeStruct((), jnp.int32))
+    with jax.default_matmul_precision("highest"):
+        compiled = (att.lower(x, wa, n) if half == "attend"
+                    else ffn.lower(x, w, n)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < (9e9 if half == "attend" else 2e9), mem
+    assert "while" in compiled.as_text()    # the rows below n, no more
 
 
 def test_the_tpu_compiler_keeps_the_picks_sorts_under_a_conditional(
