@@ -209,7 +209,10 @@ def _work_items(counts, m_tiles: int, tm: int):
     items its expert and its row tile, plus how many are real.  An
     expert with no row gets no item; a row tile shared by several
     experts is visited once for each.  Items past the real ones repeat
-    the last real one (no new transfer) and are skipped."""
+    the last real one and are skipped; with :func:`_run` in the index
+    maps they name the block already resident, whatever ``k_tiles``: no
+    new transfer.  No real item at all: item 0 names the last expert's
+    matrix, fetched once and not used."""
     E = counts.shape[0]
     ends = jnp.cumsum(counts)
     starts = ends - counts
@@ -234,7 +237,9 @@ def _grouped_kernel(layer_ref, offs_ref, gid_ref, mid_ref, num_ref,
     while consecutive items share it; its first visit clears the rows
     no expert owns.  A matrix too large for one transfer comes in
     ``k_tiles`` runs of whole rows (the grid's inner axis), summed in
-    the float32 scratch ``acc`` and written with the last."""
+    the float32 scratch ``acc`` and written with the last.  An item
+    past the real ones runs no product here and, by :func:`_run`, had
+    nothing fetched for it either."""
     del layer_ref  # read by the weights' index map
     t = pl.program_id(0)
     k = pl.program_id(1) if k_tiles > 1 else 0
@@ -273,7 +278,9 @@ def _grouped_kernel(layer_ref, offs_ref, gid_ref, mid_ref, num_ref,
 #: The most of one expert's matrix a step of the grouped product
 #: fetches (of two such buffers): a whole ``2304 x 896`` bf16 matrix
 #: (4.1 MB) is one step; ``7168 x 2048`` (29 MB, more than the kernel's
-#: VMEM holds twice) goes in 7 runs of 1024 whole rows.
+#: VMEM holds twice) goes in 7 runs of 1024 whole rows.  Only an item
+#: that owns a row walks its runs (:func:`_run`): a call moves the
+#: matrices of the experts touched, once a row tile each, and no other.
 _EXPERT_BLOCK_BYTES = 9 * 512 * 1024
 
 
@@ -289,6 +296,31 @@ def _k_tile(K: int, N: int, itemsize: int) -> int:
     return max(fits) if fits else 128
 
 
+def _run(t, k, num, k_tiles: int):
+    """The run of the matrix (and of the rows' columns) that grid step
+    ``(t, k)`` holds: ``k``, while item ``t`` is real; the LAST run for
+    an item past the real ones, so that every skipped step — and the
+    step from the last real one into the first skipped — names the block
+    the step before it left resident, and the pipeline fetches nothing.
+    (With ``k`` left to walk, each skipped item fetched the last
+    expert's whole matrix again.)  One run a matrix: ``k`` as it is."""
+    if k_tiles == 1:
+        return k
+    return jnp.where(t < num[0], k, k_tiles - 1)
+
+
+def _rows_block(t, k, layer, offsets, gid, mid, num, *, k_tiles: int):
+    """Index map of the rows ``xs``, in blocks of ``(tm, tk)``."""
+    del layer, offsets, gid
+    return mid[t], _run(t, k, num, k_tiles)
+
+
+def _matrix_block(t, k, layer, offsets, gid, mid, num, *, k_tiles: int):
+    """Index map of the weights ``w``, in blocks of ``(1, 1, tk, N)``."""
+    del offsets, mid
+    return layer[0], gid[t], _run(t, k, num, k_tiles), 0
+
+
 def grouped_matmul(xs, w, layer, counts):
     """``xs[rows of expert e] @ w[layer, e]`` for every expert, as one
     Pallas kernel (:data:`EXPERTS_NAME`): ``xs`` ``(M, K)`` rows sorted
@@ -297,11 +329,11 @@ def grouped_matmul(xs, w, layer, counts):
     a (traced) index into it.  The weights stay where they are: each
     expert that owns a row has its ``(K, N)`` matrix fetched straight
     from ``w[layer, e]`` — one contiguous transfer (or :func:`_k_tile`
-    rows of it at a time), overlapped with the previous product — so a
-    layer scan hands the kernel the
-    whole stack and no slice of it is ever copied (``lax.ragged_dot``
-    is a custom call whose operand a scan must first cut out: a copy of
-    every expert, every tick).  Rows past ``sum(counts)`` come back
+    rows of it at a time), overlapped with the previous product; the
+    grid's other steps ask for no transfer (:func:`_run`) — so a layer
+    scan hands the kernel the whole stack and no slice of it is ever
+    copied (``lax.ragged_dot`` is a custom call whose operand a scan
+    must first cut out: a copy of every expert, every tick).  Rows past ``sum(counts)`` come back
     zero where their tile was visited and undefined where not."""
     M, K = xs.shape
     L, E, _, N = w.shape
@@ -317,9 +349,10 @@ def grouped_matmul(xs, w, layer, counts):
         num_scalar_prefetch=5,
         grid=(m_tiles + E - 1, k_tiles),
         in_specs=[
-            pl.BlockSpec((tm, tk), lambda t, k, l, o, g, m, n: (m[t], k)),
+            pl.BlockSpec((tm, tk),
+                         functools.partial(_rows_block, k_tiles=k_tiles)),
             pl.BlockSpec((None, None, tk, N),
-                         lambda t, k, l, o, g, m, n: (l[0], g[t], k, 0)),
+                         functools.partial(_matrix_block, k_tiles=k_tiles)),
         ],
         out_specs=pl.BlockSpec((tm, N),
                                lambda t, k, l, o, g, m, n: (m[t], 0)),

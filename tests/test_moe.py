@@ -2,6 +2,8 @@
 dense oracle, capacity-drop semantics, the ep all_to_all exchange under
 shard_map, and the flat-in-E compute claim."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -490,3 +492,129 @@ class TestModelAuxLoss:
         assert load_aux.min() > 0.10, load_aux
         # Control: measurably less balanced than the aux run.
         assert load_ctrl.max() > load_aux.max() + 0.02, (load_ctrl, load_aux)
+
+
+def _spread(E, touched, rows):
+    """``rows`` rows over ``touched`` of ``E`` experts, spread evenly."""
+    counts = np.zeros(E, np.int32)
+    own = np.linspace(0, E - 1, touched).round().astype(int)
+    counts[own] = rows // touched
+    counts[own[:rows % touched]] += 1
+    return counts
+
+
+# name: (M, tm, K, N, counts, real items)
+GROUPED_WALKS = {
+    # A.X-K1's tick: 32 slots x 8 picks, 16 rows on 9 of the 12 held
+    "axk1_tick": (256, 32, 7168, 2048, _spread(12, 9, 16), 9),
+    # DeepSeek-V3.2-Exp's tick: 24 slots x 8 picks, 3 of the 8 held
+    "dsv32_tick": (192, 32, 7168, 2048, _spread(8, 3, 5), 3),
+    # a chunk of 512 x 8 picks, 258 rows HERE: experts 5 and 11
+    # straddle a row tile of 128 and are visited once in each
+    "chunk": (4096, 128, 7168, 2048, _spread(12, 12, 258), 14),
+    "one_expert": (256, 32, 2048, 7168,
+                   np.eye(12, dtype=np.int32)[4] * 256, 8),
+    "no_row": (256, 32, 7168, 2048, np.zeros(12, np.int32), 0),
+    # Mellum2: a matrix is ONE block, the maps are the parent's
+    "mellum2_tick": (256, 32, 2304, 896, _spread(64, 40, 256), None),
+}
+
+
+class TestGroupedProduct:
+    @pytest.mark.parametrize("case", sorted(GROUPED_WALKS))
+    def test_only_an_item_that_owns_a_row_asks_for_a_transfer(self, case):
+        """Walk ``grouped_matmul``'s grid as the pipeline does and count
+        the steps whose block is not the one the step before left
+        resident: each real item's runs, and nothing for the
+        ``m_tiles + E - 1 - num`` items that run no product (with ``k``
+        left to walk there, each fetched the last expert's whole matrix
+        again: 19 matrices a call in A.X-K1's tick, not 9)."""
+        M, tm, K, N, counts, items = GROUPED_WALKS[case]
+        E, m_tiles = len(counts), -(-M // tm)
+        k_tiles = K // moe._k_tile(K, N, 2)
+        scalars = (np.array([1]),) + tuple(
+            np.asarray(a) for a in
+            moe._work_items(jnp.asarray(counts), m_tiles, tm))
+        num = int(scalars[-1][0])
+        t, k = (a.ravel() for a in np.meshgrid(
+            np.arange(m_tiles + E - 1), np.arange(k_tiles), indexing="ij"))
+
+        def transfers(index_map):
+            blocks = np.stack(np.broadcast_arrays(
+                *(np.asarray(i) for i in index_map(t, k, *scalars))), 1)
+            return 1 + np.count_nonzero(
+                (blocks[1:] != blocks[:-1]).any(axis=1))
+
+        matrix = transfers(functools.partial(moe._matrix_block,
+                                             k_tiles=k_tiles))
+        rows = transfers(functools.partial(moe._rows_block,
+                                           k_tiles=k_tiles))
+        if items is None:
+            # one run a matrix: consecutive items of one expert share
+            # it, as they did before the runs came
+            assert k_tiles == 1
+            assert matrix == np.count_nonzero(counts)
+            assert matrix == transfers(
+                lambda t, k, l, o, g, m, n: (l[0], g[t], k, 0))
+            assert rows == transfers(
+                lambda t, k, l, o, g, m, n: (m[t], k))
+        else:
+            assert k_tiles > 1 and num == items
+            assert matrix == rows == max(items * k_tiles, 1)
+
+    @pytest.mark.parametrize("counts", [
+        (0, 20, 0, 30, 5, 0),        # empty experts, first and last too
+        (0, 0, 0, 0, 0, 0),          # no pick landed here
+        (0, 0, 70, 0, 0, 0),         # one expert over three row tiles
+        (16, 16, 16, 16, 16, 16),    # every row taken, tiles shared
+    ])
+    def test_runs_and_skipped_items_together_give_the_same_products(
+            self, monkeypatch, counts):
+        """A matrix in 4 runs, items skipped: every row of a visited
+        tile is its expert's ``x @ w[layer, e]``, or zero past the
+        groups.  Whole numbers, so that no order of summation shows."""
+        M, K, N, E, tm = 96, 512, 64, 6, 16
+        monkeypatch.setattr(moe, "_EXPERT_BLOCK_BYTES", 128 * N * 2)
+        assert K // moe._k_tile(K, N, 2) == 4
+        rng = np.random.RandomState(sum(counts))
+        x = rng.randint(-3, 4, (M, K)).astype(np.float32)
+        w = rng.randint(-3, 4, (3, E, K, N)).astype(np.float32)
+        got = np.asarray(moe.grouped_matmul(
+            jnp.asarray(x, jnp.bfloat16), jnp.asarray(w, jnp.bfloat16), 1,
+            jnp.asarray(counts, jnp.int32)), np.float32)
+        want = np.zeros((M, N), np.float32)
+        ends = np.cumsum(counts)
+        for e in range(E):
+            rows = slice(ends[e] - counts[e], ends[e])
+            want[rows] = np.asarray(jnp.asarray(x[rows] @ w[1, e],
+                                                jnp.bfloat16), np.float32)
+        visited = -(-ends[-1] // tm) * tm     # the tiles that hold a row
+        np.testing.assert_array_equal(got[:visited], want[:visited])
+
+    @pytest.mark.parametrize("held_offset", [None, 0, 4])
+    def test_a_share_over_the_stack_is_ragged_dots_form(self, monkeypatch,
+                                                        held_offset):
+        """``dropless_moe(layer=)`` over matrices that come in runs, with
+        experts of the share left empty, against the ``lax.ragged_dot``
+        form over that layer's slice."""
+        D, F, T, k = 256, 256, 6, 2
+        E, held = 8, 8 if held_offset is None else 4
+        monkeypatch.setattr(moe, "_EXPERT_BLOCK_BYTES", 128 * 256 * 4)
+        assert D // moe._k_tile(D, F, 4) == 2
+        rng = np.random.RandomState(1)
+        x = jnp.asarray(rng.randn(T, D), jnp.float32)
+        router = jnp.asarray(rng.randn(D, E), jnp.float32)
+        wg, wu = (jnp.asarray(rng.randn(3, held, D, F) / 16, jnp.float32)
+                  for _ in "ab")
+        wd = jnp.asarray(rng.randn(3, held, F, D) / 16, jnp.float32)
+        kw = dict(k=k, norm_topk=True, held_offset=held_offset,
+                  token_mask=jnp.asarray(rng.rand(T) > 0.3),
+                  return_counts=True)
+        got, counts = moe.dropless_moe(x, router, wg, wu, wd, layer=2, **kw)
+        want, counts_r = moe.dropless_moe(x, router, wg[2], wu[2], wd[2],
+                                          **kw)
+        np.testing.assert_array_equal(np.asarray(counts),
+                                      np.asarray(counts_r))
+        assert 0 in np.asarray(counts) and np.asarray(counts).sum() > 2
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)

@@ -222,6 +222,30 @@ def test_sparse_attentions_parts_compile_at_the_published_sizes(
                 else PA.INDEX_KERNEL_NAME) in text
 
 
+@pytest.mark.parametrize("K,N", [(7168, 2048), (2048, 7168)])
+def test_the_grouped_product_compiles_with_its_runs_held_still(
+        one_chip, monkeypatch, K, N):
+    """A.X-K1's tick (256 rows over 12 held experts, a matrix of 29 MB in
+    runs of 4 MB): ``grouped_matmul``'s index maps choose the run by a
+    prefetched scalar (``MOE._run``: a skipped item names the block
+    already resident), and Mosaic takes that inside the 48 MB of VMEM
+    the call asks for."""
+    monkeypatch.setattr(MOE, "use_interpret", lambda: False)
+    assert K // MOE._k_tile(K, N, 2) > 1
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(MOE.grouped_matmul).lower(
+        sds((256, K), jnp.bfloat16), sds((4, 12, K, N), jnp.bfloat16),
+        sds((), jnp.int32), sds((12,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and MOE.EXPERTS_NAME in text
+    # the stack is read where it lies: no copy of it, nor of a layer
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 12 * K * N * 2, mem
+
+
 def test_the_served_sparse_tick_fits_the_chip_and_writes_in_place(
         one_chip, monkeypatch):
     """The benchmark's own configuration (`deepseek-v3.2-exp-serve`: 24
