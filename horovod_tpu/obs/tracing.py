@@ -34,8 +34,11 @@ request retirement one JSONL line.
 trace (``jax.profiler.start_trace`` / ``Timeline.profile``) shows the
 program's own phase names on the host plane of the SAME ``.xplane.pb``
 as the device operations, on the profiler's clock — observes a
-histogram with the duration, and hands the span to the active
-:class:`Tracer`'s tick row.
+histogram with the duration and a second one with the thread's CPU
+seconds, and hands the span to the active :class:`Tracer`'s tick row.
+:data:`gc_watch` is the ONE ``gc.callbacks`` hook of the process: the
+collector's pauses by generation, and an ``hvd:gc`` span for a full
+collection.
 
 All timestamps are ``time.monotonic()`` seconds — the same clock the
 timeline uses (``monotonic_ns / 1e3`` microseconds), so serving spans
@@ -45,6 +48,7 @@ land on the same axis as training spans.
 from __future__ import annotations
 
 import dataclasses
+import gc
 import hashlib
 import json
 import os
@@ -65,7 +69,8 @@ __all__ = [
     "start", "stop", "get", "activate", "deactivate",
     "start_spans", "stop_spans", "spans", "activate_spans",
     "deactivate_spans",
-    "instant", "record_compile", "phase", "PHASE_PREFIX",
+    "instant", "record_compile", "phase", "PHASE_PREFIX", "GcWatch",
+    "gc_watch",
 ]
 
 TRACE_ID_HEADER = "X-Trace-Id"
@@ -282,7 +287,7 @@ class Tracer:
         self._named_tids = set()
         self._tid_lock = threading.Lock()
         # Tick-phase events are the hot emitter (one per engine phase,
-        # seven per steady decode tick):
+        # eight per steady decode tick):
         # buffer them locally and hand the timeline ONE batch per
         # TICK_BATCH events — a per-event queue put wakes the writer
         # thread every time, and those context switches (not the dict
@@ -767,41 +772,122 @@ PHASE_PREFIX = "hvd:"
 
 
 class phase:
-    """One timed phase of a host loop, as a context manager.
+    """One timed phase of a host loop, as a context manager, on TWO
+    clocks.
 
-    ``with phase("admit", hist, k=2): ...`` (a) enters
+    ``with phase("admit", hist, cpu_hist, k=2): ...`` (a) enters
     ``jax.profiler.TraceAnnotation("hvd:admit", k=2)`` — about half a
     microsecond when no profiler session is active; when one is, the
     span lands beside the device operations it caused; (b) observes
-    ``hist`` (anything with ``observe(seconds)``) with the duration;
-    (c) hands ``(name, start, dur)`` to the active :class:`Tracer`'s
-    ``tick_phase`` if there is one.  ``start`` and ``dur`` (seconds on
-    ``time.monotonic()``) stay readable on the object after exit, so a
-    caller that needs a phase boundary's timestamp reads it from the
-    phase instead of taking its own."""
+    ``hist`` (anything with ``observe(seconds)``) with the duration on
+    ``time.monotonic()`` and ``cpu_hist`` with the seconds of
+    ``time.thread_time()`` the calling thread spent inside — so
+    ``wall - cpu`` is the time the thread did not run: waiting for a
+    device, a lock or a sleep where the body blocks, and for the
+    interpreter lock or the OS where it does not; (c) hands ``(name,
+    start, dur)`` to the active :class:`Tracer`'s ``tick_phase`` if
+    there is one.  ``start``, ``dur`` and ``cpu`` stay readable on the
+    object after exit, so a caller that needs a phase boundary's
+    timestamp reads it from the phase instead of taking its own.  The
+    CPU clock is read INSIDE the wall clock's two reads: ``cpu <= dur``
+    up to the clocks' resolution."""
 
-    __slots__ = ("name", "hist", "start", "dur", "_ann")
+    __slots__ = ("name", "hist", "cpu_hist", "start", "dur", "cpu", "_ann")
 
-    def __init__(self, name: str, hist=None, **attrs):
+    def __init__(self, name: str, hist=None, cpu_hist=None, **attrs):
         self.name = name
         self.hist = hist
-        self.start = self.dur = 0.0
+        self.cpu_hist = cpu_hist
+        self.start = self.dur = self.cpu = 0.0
         self._ann = TraceAnnotation(PHASE_PREFIX + name, **attrs)
 
     def __enter__(self) -> "phase":
         self.start = time.monotonic()
+        self.cpu = time.thread_time()
         self._ann.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
+        self.cpu = time.thread_time() - self.cpu
         self.dur = time.monotonic() - self.start
         self._ann.__exit__(*exc)
         if self.hist is not None:
             self.hist.observe(self.dur)
+        if self.cpu_hist is not None:
+            self.cpu_hist.observe(self.cpu)
         tp = _tracer
         if tp is not None:
             tp.tick_phase(self.name, self.start, self.dur)
         return False
+
+
+# -- the collector's pauses ---------------------------------------------------
+
+
+class GcWatch:
+    """The garbage collector's pauses, measured by ONE ``gc.callbacks``
+    hook however many subscribers a process has (each running engine is
+    one): installed with the first :meth:`add`, removed with the last
+    :meth:`remove`.
+
+    Every collection hands ``(generation, seconds on time.monotonic())``
+    to each sink; a collection stops every thread of the process, so it
+    is counted whichever thread it ran on.  A FULL collection
+    (generation 2: the one that takes tens to hundreds of ms over a
+    server's heap) is also an ``hvd:gc`` ``TraceAnnotation`` from its
+    start to its stop, so that on a profiler trace the pause lies
+    beside the device's gap on the same clock.  The path of a young
+    collection allocates two floats.
+
+    A collection starts wherever its thread allocates, under whatever
+    locks that thread holds, and the sinks run there: a sink takes NO
+    lock (it appends to a deque or bumps a field, and its owner folds
+    that into histograms from outside the collector)."""
+
+    def __init__(self) -> None:
+        self._sinks: tuple = ()
+        self._lock = threading.Lock()
+        self._t0 = 0.0
+        self._ann: Optional[TraceAnnotation] = None
+
+    def add(self, sink) -> None:
+        with self._lock:
+            if not self._sinks:
+                gc.callbacks.append(self._on_gc)
+            self._sinks += (sink,)
+
+    def remove(self, sink) -> None:
+        with self._lock:
+            if sink not in self._sinks:
+                return
+            self._sinks = tuple(s for s in self._sinks if s != sink)
+            if not self._sinks:
+                gc.callbacks.remove(self._on_gc)
+
+    def _on_gc(self, when: str, info: Dict) -> None:
+        # collections never nest and the interpreter lock is held
+        # throughout: the two fields are one collection's at a time
+        if when == "start":
+            if info["generation"] == 2:
+                self._ann = TraceAnnotation(PHASE_PREFIX + "gc")
+                self._ann.__enter__()
+            self._t0 = time.monotonic()
+            return
+        t0, self._t0 = self._t0, 0.0
+        if not t0:
+            return  # hooked between a collection's start and its stop
+        seconds = time.monotonic() - t0
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        for sink in self._sinks:
+            sink(info["generation"], seconds)
+
+
+#: The process's one watch (a hook on the interpreter's collector is
+#: process-wide by nature); ``InferenceEngine.start`` / ``stop`` add and
+#: remove their engine's sink.
+gc_watch = GcWatch()
 
 
 # -- cross-cutting event helpers ---------------------------------------------

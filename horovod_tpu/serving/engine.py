@@ -113,7 +113,7 @@ from horovod_tpu.serving.cache import (  # noqa: F401
 )
 from horovod_tpu.serving.faults import FaultInjector
 from horovod_tpu.serving.journal import RequestJournal
-from horovod_tpu.serving.metrics import ServingMetrics
+from horovod_tpu.serving.metrics import SLOW_STEP_SECONDS, ServingMetrics
 from horovod_tpu.serving.sampling import SlotSampling, seed_key
 from horovod_tpu.serving.sampling import validate as validate_sampling
 from horovod_tpu.serving.scheduler import (
@@ -945,11 +945,11 @@ class InferenceEngine:
         self._page_pos = np.zeros(engine_cfg.n_slots, np.int64)
         self._dev_table = None
         self._table_uploaded = -1
-        # What else the CURRENT step ran beside its decode tick
-        # (_observe_step_kind), and the tokens one step of the paged
-        # kernel's walk covers for this pool (_count_paged_walk; the
-        # kernel sees a tp shard's heads).
+        # What else the CURRENT step ran beside its tick, its kind, phases
+        # and retired tick; the collector's seconds; a walk step's tokens.
         self._step_tick = self._step_prefill = self._step_chunk = False
+        self._step_kind = self._retired = None
+        self._step_spans, self._gc_s = [], 0.0
         self._walk_block_tokens = self.slots.page_size * (
             _pa.block_pages(
                 self.slots.page_size, 1, cfg.latent_row,
@@ -2191,6 +2191,7 @@ class InferenceEngine:
         :class:`EngineFailedError` and the engine restarts (fresh slot
         cache, bounded attempts, exponential backoff) — or goes
         terminally ``failed`` when the budget is exhausted."""
+        self._step_spans.clear()  # the phases of THIS step (start's loop)
         if self._health == FAILED:
             return False
         t_step = time.monotonic()
@@ -2200,9 +2201,11 @@ class InferenceEngine:
             faults = self.engine_cfg.faults
             if faults is not None:
                 faults.probe("watchdog")  # a "hang" here stalls the tick
-            with self._lock:
-                # The phases below (and those inside the calls) PARTITION
-                # the step: one after another, none inside another.
+            # The phases below (and those inside the calls) PARTITION
+            # the step: one after another, none inside another.
+            with self._phase("lock_wait"):  # the acquisition, no more
+                self._lock.acquire()
+            try:
                 with self._phase("reclaim"):
                     worked = self._reclaim_cancelled()
                 worked = self._admit_pending() or worked
@@ -2211,9 +2214,16 @@ class InferenceEngine:
                 else:
                     worked = self._decode_tick() or worked
                 with self._phase("bookkeeping"):
+                    # Freeing a device array gives up the interpreter
+                    # lock: after a tick's emissions woke a handler
+                    # thread a stream, the engine thread stands HERE
+                    # until each has had its turn (PERF.md section 5).
+                    self._retired = None
                     self.metrics.queue_depth.set(self.scheduler.depth)
                     self.metrics.slot_occupancy.set(self.slots.occupancy)
                     self._update_page_gauges()
+            finally:
+                self._lock.release()
             self._observe_step_kind(t_step)
         except Exception as exc:  # supervised: ANY tick failure recovers
             self._observe_step_kind(t_step)
@@ -2257,19 +2267,26 @@ class InferenceEngine:
 
     def _phase(self, name: str, **attrs) -> obs_tracing.phase:
         """One phase of the engine loop: an ``hvd:<name>`` span on a
-        profiler trace, its histogram in the CURRENT metrics object
-        (benchmarks swap in a fresh one after warm-up), and the active
-        tracer's tick row."""
-        return obs_tracing.phase(name, self.metrics.phases[name], **attrs)
+        profiler trace, its two histograms (wall and CPU seconds) in
+        the CURRENT metrics object (benchmarks swap in a fresh one
+        after warm-up), the active tracer's tick row, and — kept until
+        the next step begins — the loop's record of a slow step."""
+        metrics = self.metrics
+        ph = obs_tracing.phase(name, metrics.phases[name],
+                               metrics.phases_cpu[name], **attrs)
+        self._step_spans.append(ph)
+        return ph
 
     def _observe_step_kind(self, t_step: float) -> None:
         """Close a step that dispatched a decode tick: its wall time goes
         to ``engine_step{kind=}`` by what else the same step ran — an
         admission prefill (``prefill``, also when a chunk rode along),
         an ingest chunk (``chunk``), or neither (``plain``)."""
+        self._step_kind = None
         if self._step_tick:
-            kind = ("prefill" if self._step_prefill
-                    else "chunk" if self._step_chunk else "plain")
+            self._step_kind = kind = (
+                "prefill" if self._step_prefill
+                else "chunk" if self._step_chunk else "plain")
             self.metrics.engine_step[kind].observe(
                 time.monotonic() - t_step)
         self._step_tick = self._step_prefill = self._step_chunk = False
@@ -3161,6 +3178,7 @@ class InferenceEngine:
             self.metrics.host_syncs.inc()
         with self._phase("tick_host"):
             self._apply_tick(p, nxt, mx, acc, wait.start + wait.dur)
+        self._retired = p  # its device arrays go in `bookkeeping` (step)
 
     def _apply_tick(self, p: Dict, nxt, mx, acc, t1: float) -> None:
         """The host half of :meth:`_retire_pending` (the ``tick_host``
@@ -3377,7 +3395,7 @@ class InferenceEngine:
         (restart/terminal paths — the old device arrays belong to a
         suspect cache lineage); the next dispatch reseeds from host
         slot state."""
-        self._pending = None
+        self._pending = self._retired = None
         self._dev_tokens = None
         self._dev_active = None
         self._dev_active_host = None
@@ -3647,18 +3665,32 @@ class InferenceEngine:
             return
 
         def loop():
-            # engine_loop observes every iteration end to end, so the
-            # phases' sum over it is the share of this thread's time
-            # that lies inside a phase.
-            t_prev = time.monotonic()
+            # engine_loop observes every iteration end to end on both
+            # clocks, so the phases' sums over it are the share of this
+            # thread's time, and of its work, that lies inside a phase.
+            t_start = t_prev = time.monotonic()
+            c_prev = time.thread_time()
+            gc_prev = self._gc_s
             while not self._stop.is_set():
+                compiles = self._decode_traces + self._prefill_traces
                 if not self.step():
                     with self._phase("idle"):
                         time.sleep(idle_sleep)
-                now = time.monotonic()
-                self.metrics.engine_loop.observe(now - t_prev)
-                t_prev = now
+                now, cpu = time.monotonic(), time.thread_time()
+                gc_s = self._gc_s
+                metrics = self.metrics
+                if gc_s != gc_prev:
+                    metrics.fold_gc()
+                metrics.engine_loop.observe(now - t_prev)
+                metrics.engine_loop_cpu.observe(cpu - c_prev)
+                if now - t_prev > SLOW_STEP_SECONDS:
+                    metrics.slow_step(self._slow_step_record(
+                        t_prev - t_start, now - t_prev, cpu - c_prev,
+                        gc_s - gc_prev, self._decode_traces
+                        + self._prefill_traces - compiles))
+                t_prev, c_prev, gc_prev = now, cpu, gc_s
 
+        obs_tracing.gc_watch.add(self._on_gc)
         self._stop.clear()
         self._thread = threading.Thread(target=loop,
                                         name="serving-engine", daemon=True)
@@ -3675,9 +3707,39 @@ class InferenceEngine:
         self._stop.set()
         self._thread.join(timeout)
         self._thread = None
+        obs_tracing.gc_watch.remove(self._on_gc)
         if self._watchdog is not None:
             self._watchdog.join(timeout)
             self._watchdog = None
+
+    def _on_gc(self, generation: int, seconds: float) -> None:
+        """``obs.tracing.gc_watch``'s sink while the loop runs: one
+        collection of the interpreter, on whichever thread and under
+        whatever lock that thread holds, so it takes none
+        (``ServingMetrics.fold_gc`` observes the histogram later)."""
+        self.metrics.gc_pending[generation].append(seconds)
+        self._gc_s += seconds
+
+    def _slow_step_record(self, at_s: float, wall_s: float, cpu_s: float,
+                          gc_s: float, compiles: int) -> Dict:
+        """Where an iteration of the loop that took ``wall_s`` stood:
+        its phases' seconds on both clocks (what lies under none is
+        ``wall_s`` less their sum), the collector's seconds and the
+        executables compiled inside it.  ``at_s``: when it began, on
+        ``time.monotonic()`` since :meth:`start`."""
+        phases: Dict[str, List[float]] = {}
+        for ph in self._step_spans:
+            both = phases.setdefault(ph.name, [0.0, 0.0])
+            both[0] += ph.dur
+            both[1] += ph.cpu
+        return {
+            "at_s": round(at_s, 3), "wall_s": round(wall_s, 6),
+            "cpu_s": round(cpu_s, 6),
+            "phases": {name: [round(w, 6), round(c, 6)]
+                       for name, (w, c) in phases.items()},
+            "gc_s": round(gc_s, 6), "compiles": compiles,
+            "active_slots": self.slots.active_count,
+            "kind": self._step_kind}
 
     def warmup(self, prompt_lens: Sequence[int] = (1,)) -> None:
         """Drive the engine SYNCHRONOUSLY until every compile the given
@@ -3871,10 +3933,12 @@ class InferenceEngine:
         metrics.achieved_flops.set((n1 - n0) / (now - t0) * fpt)
 
     def refresh_windowed_gauges(self) -> None:
-        """Refresh rate-windowed gauges (achieved FLOP/s) without
+        """Refresh rate-windowed gauges (achieved FLOP/s) and fold the
+        collector's pending pauses into their histograms without
         building a /stats snapshot — the cheap hook a /metrics scrape
         wants."""
         self._update_achieved_flops()
+        self.metrics.fold_gc()
 
     def stats(self) -> Dict:
         age = self.heartbeat_age
@@ -3947,10 +4011,6 @@ class InferenceEngine:
             **({
                 "spec_k": self.engine_cfg.spec_k,
                 "spec_draft": "model" if self._spec_model else "ngram",
-                "spec_slots_live": int(self._spec_live.sum()),
-                "draft_pages_free":
-                    self.draft_slots.free_pages
-                    if self.draft_slots is not None else None,
             } if self._spec else {}),
             "page_size": self.slots.page_size,
             "kv_dtype": str(jnp.dtype(self.slots._storage_dtype).name),

@@ -17,8 +17,11 @@ engine updates (``metrics.admitted.inc()`` …), and keeps the original
 
 from __future__ import annotations
 
+import collections
+import logging
 from typing import Dict, Optional
 
+from horovod_tpu.obs import tracing as obs_tracing
 from horovod_tpu.obs.registry import (  # noqa: F401  (back-compat re-export)
     DEFAULT_LATENCY_BUCKETS,
     TICK_PHASE_BUCKETS,
@@ -30,8 +33,32 @@ from horovod_tpu.obs.registry import (  # noqa: F401  (back-compat re-export)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "ServingMetrics",
-    "DEFAULT_LATENCY_BUCKETS", "TICK_PHASE_BUCKETS",
+    "DEFAULT_LATENCY_BUCKETS", "TICK_PHASE_BUCKETS", "PHASES",
+    "SLOW_STEP_SECONDS", "SLOW_STEP_RING", "phase_key",
 ]
+
+#: The engine loop's partition, in the order a step passes through them.
+PHASES = ("lock_wait", "reclaim", "admit", "prefill", "ingest_chunk",
+          "page_prep", "tick_dispatch", "tick_device_wait", "tick_host",
+          "bookkeeping", "idle")
+
+
+def phase_key(name: str, cpu: bool = False) -> str:
+    """The ``/stats`` key of a phase's histogram on either clock (the
+    three ``tick_*`` phases keep the keys they had before the others)."""
+    return (("" if name.startswith("tick_") else "phase_") + name
+            + ("_cpu_seconds" if cpu else "_seconds"))
+
+
+#: An iteration of the engine loop longer than this leaves a record
+#: (:meth:`ServingMetrics.slow_step`).  The longest SOUND step of any
+#: benchmarked cell is a chunk step of 101-118 ms (PERF.md section 5).
+SLOW_STEP_SECONDS = 0.25
+
+#: ... and ``/stats`` keeps this many of the newest.
+SLOW_STEP_RING = 16
+
+_log = logging.getLogger(__name__)
 
 
 class ServingMetrics:
@@ -81,13 +108,30 @@ class ServingMetrics:
       compute.
     * ``phases`` — the engine loop's partition
       (docs/observability.md "Engine phases"): every moment of the
-      engine thread lies in exactly one of ``reclaim``, ``admit``,
-      ``prefill``, ``ingest_chunk``, ``page_prep``, ``tick_dispatch``,
-      ``tick_device_wait``, ``tick_host``, ``bookkeeping``, ``idle``;
-      ``engine_loop`` observes the loop's own wall time per iteration,
-      so ``sum(phases) / engine_loop`` is the share of the loop the
-      phases cover.  The three ``tick_*`` keep their own families; the
-      other seven are the ``{phase=}`` children of one family.
+      engine thread lies in at most one of ``lock_wait``, ``reclaim``,
+      ``admit``, ``prefill``, ``ingest_chunk``, ``page_prep``,
+      ``tick_dispatch``, ``tick_device_wait``, ``tick_host``,
+      ``bookkeeping``, ``idle`` (:data:`PHASES`); ``engine_loop``
+      observes the loop's own wall time per iteration, so
+      ``sum(phases) / engine_loop`` is the share of the loop the phases
+      cover.  The three ``tick_*`` keep their own families; the other
+      eight are the ``{phase=}`` children of one family.
+      ``phases_cpu`` / ``engine_loop_cpu`` are their twins on the engine
+      thread's CPU clock (``time.thread_time()``): a phase's ``wall -
+      cpu`` is the time the thread did not run — the wait, in a phase
+      that blocks (``tick_device_wait``, ``idle``, ``lock_wait``, a
+      prefill's first-token fetch); time it was runnable and the
+      interpreter lock or the OS kept from it, in a host-only one.
+    * ``gc_pause`` — the collector's pauses by generation
+      (``obs.tracing.gc_watch``), on whichever thread they ran: every
+      thread stands still for one.  The collector's callback only
+      appends to ``gc_pending`` (it may run under any lock its thread
+      holds, a histogram's own included); :meth:`fold_gc` observes them
+      from outside the collector.
+    * ``slow_step`` — an iteration of the loop longer than
+      :data:`SLOW_STEP_SECONDS` leaves a record of where it stood
+      (``/stats`` ``slow_steps``, the newest :data:`SLOW_STEP_RING`),
+      counted in ``slow_steps`` / ``slow_step_seconds``.
     * ``engine_step`` — wall time of the ``step()`` calls that
       dispatched a decode tick, by what else the same step ran:
       ``{kind="prefill"}`` an admission prefill, ``{kind="chunk"}`` an
@@ -211,24 +255,57 @@ class ServingMetrics:
             buckets=TICK_PHASE_BUCKETS)
         phase_family = r.histogram(
             "serving_engine_phase_seconds",
-            "Time in one phase of the engine loop (reclaim, admit, "
-            "prefill, ingest_chunk, page_prep, bookkeeping, idle); "
-            "with the three serving_tick_* families the phases "
+            "Time in one phase of the engine loop (lock_wait, reclaim, "
+            "admit, prefill, ingest_chunk, page_prep, bookkeeping, "
+            "idle); with the three serving_tick_* families the phases "
             "partition the engine thread's time",
             buckets=TICK_PHASE_BUCKETS, labels=("phase",))
+        ticks = {"tick_dispatch": self.tick_dispatch,
+                 "tick_device_wait": self.tick_device_wait,
+                 "tick_host": self.tick_host}
         self.phases: Dict[str, Histogram] = {
-            "tick_dispatch": self.tick_dispatch,
-            "tick_device_wait": self.tick_device_wait,
-            "tick_host": self.tick_host,
-            **{name: phase_family.labels(phase=name)
-               for name in ("reclaim", "admit", "prefill", "ingest_chunk",
-                            "page_prep", "bookkeeping", "idle")}}
+            name: ticks.get(name) or phase_family.labels(phase=name)
+            for name in PHASES}
+        cpu_family = r.histogram(
+            "serving_phase_cpu_seconds",
+            "CPU time of the engine thread (time.thread_time) in one "
+            "phase of the engine loop, all eleven: wall less cpu is "
+            "the time the thread did not run",
+            buckets=TICK_PHASE_BUCKETS, labels=("phase",))
+        self.phases_cpu: Dict[str, Histogram] = {
+            name: cpu_family.labels(phase=name) for name in PHASES}
         self.engine_loop = r.histogram(
             "serving_engine_loop_seconds",
             "Wall time of one iteration of the engine thread's loop "
             "(a step and its idle sleep): the denominator of the "
             "phases' coverage",
             buckets=TICK_PHASE_BUCKETS)
+        self.engine_loop_cpu = r.histogram(
+            "serving_engine_loop_cpu_seconds",
+            "CPU time of the engine thread over one iteration of its "
+            "loop, from the same two reads as "
+            "serving_engine_loop_seconds",
+            buckets=TICK_PHASE_BUCKETS)
+        self._gc_family = r.histogram(
+            "serving_gc_pause_seconds",
+            "Garbage collections while the engine ran, by generation "
+            "(wall time from the collector's start to its stop, on "
+            "whichever thread)",
+            buckets=TICK_PHASE_BUCKETS, labels=("generation",))
+        self.gc_pause: Dict[int, Histogram] = {
+            g: self._gc_family.labels(generation=str(g)) for g in (0, 1, 2)}
+        self.gc_pending = tuple(collections.deque() for _ in self.gc_pause)
+        self.slow_steps = r.counter(
+            "serving_slow_steps_total",
+            "Iterations of the engine loop longer than "
+            "SLOW_STEP_SECONDS (each left a record in /stats "
+            "slow_steps)")
+        self.slow_step_seconds = r.counter(
+            "serving_slow_step_seconds_total",
+            "Wall seconds of the iterations counted by "
+            "serving_slow_steps_total")
+        self._slow_ring: collections.deque = collections.deque(
+            maxlen=SLOW_STEP_RING)
         step_family = r.histogram(
             "serving_engine_step_seconds",
             "Wall time of a step() that dispatched a decode tick, by "
@@ -415,6 +492,35 @@ class ServingMetrics:
             "tuning_best_objective",
             "Best constraint-satisfying objective seen this trajectory")
 
+    # -- the collector's pauses --------------------------------------------
+
+    def fold_gc(self) -> None:
+        """Observe the pauses the collector's callback left in
+        ``gc_pending``: every reader does so first (:meth:`snapshot`,
+        a ``/metrics`` scrape), and the engine loop after an iteration
+        that saw a collection.  Never from inside a collection: one may
+        start on a thread that holds a histogram's lock."""
+        for generation, pending in enumerate(self.gc_pending):
+            while pending:
+                try:
+                    seconds = pending.popleft()
+                except IndexError:  # another thread folded it
+                    break
+                self.gc_pause[generation].observe(seconds)
+
+    # -- a slow step's record ----------------------------------------------
+
+    def slow_step(self, record: Dict) -> None:
+        """One iteration of the engine loop that stood still: into the
+        ring ``/stats`` serves, the two counters, one warning line (it
+        reaches the process's stderr, so a stall names its phase in
+        any run) and an instant on whatever trace is recording."""
+        self._slow_ring.append(record)
+        self.slow_steps.inc()
+        self.slow_step_seconds.inc(record["wall_s"])
+        _log.warning("slow engine step: %s", record)
+        obs_tracing.instant("slow_step", record)
+
     # -- per-class observation hooks ---------------------------------------
 
     def observe_ttft(self, priority: str, v: float) -> None:
@@ -425,12 +531,13 @@ class ServingMetrics:
 
     @staticmethod
     def _merged(family) -> Dict:
-        """Class-merged histogram snapshot — the historical /stats
+        """Label-merged histogram snapshot — the historical /stats
         shape (count/sum/mean/p50/p99/buckets over the WHOLE
         population), rebuilt bucket-wise from the labeled children
-        (they all share the default bucket edges)."""
-        h = Histogram()
-        for _, child in family.children():
+        (they all share their family's bucket edges)."""
+        children = [child for _, child in family.children()]
+        h = Histogram(children[0].buckets) if children else Histogram()
+        for child in children:
             st = child.state()
             h._counts = [a + b for a, b in zip(h._counts, st["counts"])]
             h._sum += st["sum"]
@@ -443,6 +550,7 @@ class ServingMetrics:
                 for key, child in family.children()}
 
     def snapshot(self) -> Dict:
+        self.fold_gc()
         ticks = self.decode_ticks.value
         return {
             "ttft_seconds": self._merged(self.ttft),
@@ -465,10 +573,19 @@ class ServingMetrics:
             "tick_device_wait_seconds": self.tick_device_wait.snapshot(),
             "tick_host_seconds": self.tick_host.snapshot(),
             "decode_ticks": ticks,
-            **{f"phase_{name}_seconds": h.snapshot()
+            **{phase_key(name): h.snapshot()
                for name, h in self.phases.items()
                if not name.startswith("tick_")},
+            **{phase_key(name, cpu=True): h.snapshot()
+               for name, h in self.phases_cpu.items()},
             "engine_loop_seconds": self.engine_loop.snapshot(),
+            "engine_loop_cpu_seconds": self.engine_loop_cpu.snapshot(),
+            "gc_pause_seconds": self._merged(self._gc_family),
+            "gc_pause_seconds_gen2": self.gc_pause[2].snapshot(),
+            "slow_steps": list(self._slow_ring),
+            "slow_steps_total": self.slow_steps.value,
+            "slow_step_seconds_total":
+                round(self.slow_step_seconds.value, 6),
             **{f"engine_step_seconds_{kind}": h.snapshot()
                for kind, h in self.engine_step.items()},
             **{f"decode_ticks_{kind}": h.count

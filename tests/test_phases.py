@@ -1,20 +1,29 @@
 """The program's own names on the profiler's trace (ISSUE 24).
 
 * ``obs.tracing.phase`` — the one span primitive: an ``hvd:<name>``
-  ``TraceAnnotation`` on the profiler's clock, a histogram observation,
-  and the active tracer's tick row.
-* The engine loop's phases partition its time (their sums add up to
-  ``engine_loop_seconds``; none nests in another), and the tick-kind,
-  prefill-padding and paged-walk counters count where the work happens.
+  ``TraceAnnotation`` on the profiler's clock, a histogram observation
+  on each of two clocks (wall and the thread's CPU), and the active
+  tracer's tick row.
+* The engine loop's eleven phases partition its time on both clocks
+  (their sums and the time under none add up to ``engine_loop_seconds``
+  / ``engine_loop_cpu_seconds``; none nests in another), and the
+  tick-kind, prefill-padding and paged-walk counters count where the
+  work happens.
+* The waits have a place (ISSUE 36): the step lock's wait is a phase,
+  the collector's pauses a histogram and an ``hvd:gc`` span, and an
+  iteration that stood still leaves a record that names where.
 * ``jax.named_scope`` cuts every compiled body into one flat vocabulary
   (``T.DEVICE_SCOPES``) and every Pallas call carries a ``name=`` — as
   trace-time metadata only: the optimised HLO is unchanged.
 """
 
 import contextlib
+import gc
 import glob
 import json
+import logging
 import re
+import threading
 import time
 
 import jax
@@ -31,11 +40,13 @@ from horovod_tpu.obs.registry import Histogram
 from horovod_tpu.ops import attention as A
 from horovod_tpu.ops import paged_attention as PA
 from horovod_tpu.serving import cache as C
+from horovod_tpu.serving import metrics as M
+from horovod_tpu.serving.faults import FaultInjector, FaultSpec
 
 pytestmark = pytest.mark.serving
 
-PHASES = ("reclaim", "admit", "prefill", "ingest_chunk", "page_prep",
-          "tick_dispatch", "tick_device_wait", "tick_host",
+PHASES = ("lock_wait", "reclaim", "admit", "prefill", "ingest_chunk",
+          "page_prep", "tick_dispatch", "tick_device_wait", "tick_host",
           "bookkeeping", "idle")
 KERNEL_NAMES = ("hvd_paged_attend", "hvd_flash_fwd", "hvd_flash_bwd_dq",
                 "hvd_flash_bwd_dkv")
@@ -63,6 +74,12 @@ def _engine(model, **kw):
     defaults.update(kw)
     return serving.InferenceEngine(
         params, cfg, serving.EngineConfig(**defaults))
+
+
+def _stat_key(name, cpu=False):
+    """The ``/stats`` key of a phase's histogram on either clock."""
+    return (("" if name.startswith("tick_") else "phase_") + name
+            + ("_cpu_seconds" if cpu else "_seconds"))
 
 
 def _host_events(trace_dir, prefix):
@@ -128,6 +145,30 @@ class TestPhasePrimitive:
         with TR.phase("no_histogram"):
             pass
         assert hist.count == 1
+
+    def test_a_sleeping_body_has_wall_time_and_next_to_no_cpu(self):
+        wall, cpu = Histogram(), Histogram()
+        with TR.phase("asleep", wall, cpu) as ph:
+            time.sleep(0.05)
+        assert (wall.count, cpu.count) == (1, 1)
+        assert (wall.sum, cpu.sum) == (ph.dur, ph.cpu)
+        assert ph.dur >= 0.05
+        assert 0 <= ph.cpu <= ph.dur
+        assert ph.cpu < 0.01
+
+    def test_a_busy_body_has_as_much_cpu_as_wall_time(self):
+        wall, cpu = Histogram(), Histogram()
+        c0 = time.thread_time()
+        with TR.phase("busy", wall, cpu) as ph:
+            end = time.thread_time() + 0.05      # 50 ms of WORK,
+            while time.thread_time() < end:      # however loaded the host
+                pass
+        worked = time.thread_time() - c0
+        assert (wall.sum, cpu.sum) == (ph.dur, ph.cpu)
+        # the thread's own clock, read inside the wall clock's reads
+        assert 0.05 <= ph.cpu <= worked
+        assert ph.cpu <= ph.dur + 1e-4
+        assert worked - ph.cpu < 0.005
 
     def test_exception_closes_the_span_and_propagates(self):
         hist = Histogram()
@@ -201,10 +242,11 @@ class TestPhasesPartitionTheLoop:
             finally:
                 engine.stop()
         stats = engine.stats()
-        sums = {n: stats[("" if n.startswith("tick_") else "phase_")
-                         + n + "_seconds"]["sum"] for n in PHASES}
+        sums = {n: stats[_stat_key(n)]["sum"] for n in PHASES}
+        cpus = {n: stats[_stat_key(n, cpu=True)]["sum"] for n in PHASES}
         assert all(v > 0 for v in sums.values()), sums
         loop = stats["engine_loop_seconds"]["sum"]
+        loop_cpu = stats["engine_loop_cpu_seconds"]["sum"]
         assert loop > 0
         assert sum(sums.values()) <= loop
         assert sum(sums.values()) >= 0.98 * loop, (sums, loop)
@@ -214,6 +256,21 @@ class TestPhasesPartitionTheLoop:
         assert {n for n, _, _ in spans} == set(PHASES)
         for (n0, s0, d0), (n1, s1, _) in zip(spans, spans[1:]):
             assert s0 + d0 <= s1 + 1e-9, (n0, n1)
+        # ... and the time under NO phase, read off the spans themselves
+        # (the gaps between one's end and the next one's start), is what
+        # the loop's own clock has beyond the phases' sums
+        gaps = sum(s1 - (s0 + d0)
+                   for (_, s0, d0), (_, s1, _) in zip(spans, spans[1:]))
+        assert sum(sums.values()) + gaps == pytest.approx(loop, rel=0.01)
+        # the CPU clock: a phase cannot have worked longer than it
+        # lasted, nor the phases together longer than the loop; in
+        # `idle` the thread sleeps
+        for n in PHASES:
+            assert 0 <= cpus[n] <= sums[n] + 1e-3, (n, cpus[n], sums[n])
+        assert 0 < sum(cpus.values()) <= loop_cpu <= loop
+        uncovered_cpu = loop_cpu - sum(cpus.values())
+        assert uncovered_cpu <= loop - sum(sums.values()) + 1e-3
+        assert cpus["idle"] < 0.5 * sums["idle"]
 
     def test_prefill_span_carries_its_attributes(self, model, tmp_path):
         engine = _engine(model)
@@ -237,6 +294,204 @@ class TestPhasesPartitionTheLoop:
             (0, 8), (8, 16), (16, 20)]
         assert {c["trace_id"] for c in chunks} == {b.trace_id}
         assert len({c["slot"] for c in chunks}) == 1
+
+
+# -- (2b) the waits, the collector and a slow step -----------------------------
+
+
+@contextlib.contextmanager
+def _running(engine, idle_sleep=0.002):
+    engine.start(idle_sleep=idle_sleep)
+    try:
+        yield engine
+    finally:
+        engine.stop()
+
+
+def _wait_for(pred, timeout=30.0):
+    end = time.monotonic() + timeout
+    while not pred() and time.monotonic() < end:
+        time.sleep(0.002)
+    assert pred()
+
+
+class TestWaitsCollectionsAndSlowSteps:
+    def test_stats_has_the_new_keys_and_not_the_two_removed(self, model):
+        engine = _engine(model, speculative=True, spec_k=3)
+        stats = engine.stats()
+        for name in PHASES:
+            assert stats[_stat_key(name)]["count"] == 0
+            assert stats[_stat_key(name, cpu=True)]["count"] == 0
+        for key in ("engine_loop_cpu_seconds", "gc_pause_seconds",
+                    "gc_pause_seconds_gen2"):
+            assert stats[key]["count"] == 0
+        assert stats["slow_steps"] == []
+        assert stats["slow_steps_total"] == 0
+        assert stats["slow_step_seconds_total"] == 0
+        assert stats["spec_k"] == 3 and stats["spec_draft"] == "ngram"
+        assert "spec_slots_live" not in stats
+        assert "draft_pages_free" not in stats
+        text = engine.metrics.registry.to_prometheus()
+        assert 'serving_phase_cpu_seconds_count{phase="lock_wait"}' in text
+        assert 'serving_engine_phase_seconds_count{phase="lock_wait"}' in text
+        assert 'serving_gc_pause_seconds_count{generation="2"}' in text
+        assert "serving_engine_loop_cpu_seconds_count" in text
+        assert "serving_slow_steps_total" in text
+
+    def test_a_held_step_lock_is_lock_wait_and_costs_no_cpu(self, model):
+        engine = _engine(model)
+        with _running(engine):
+            idle = engine.metrics.phases["idle"]
+            _wait_for(lambda: idle.count >= 3)
+            before = engine.stats()
+            with engine._lock:
+                time.sleep(0.05)
+            seen = idle.count
+            _wait_for(lambda: idle.count >= seen + 2)
+        after = engine.stats()
+        wall = (after["phase_lock_wait_seconds"]["sum"]
+                - before["phase_lock_wait_seconds"]["sum"])
+        cpu = (after["phase_lock_wait_cpu_seconds"]["sum"]
+               - before["phase_lock_wait_cpu_seconds"]["sum"])
+        assert wall >= 0.04
+        assert cpu < 0.005
+        assert after["slow_steps_total"] == 0
+
+    def test_a_full_collection_is_counted_and_is_one_span(self, model,
+                                                          tmp_path):
+        engine = _engine(model)
+        gc.collect()
+        gc.disable()        # no collection but the one forced below
+        try:
+            with _running(engine):
+                jax.profiler.start_trace(str(tmp_path))
+                try:
+                    gc.collect()
+                finally:
+                    jax.profiler.stop_trace()
+        finally:
+            gc.enable()
+        stats = engine.stats()
+        assert stats["gc_pause_seconds_gen2"]["count"] == 1
+        assert stats["gc_pause_seconds"]["count"] == 1
+        assert (stats["gc_pause_seconds"]["sum"]
+                == stats["gc_pause_seconds_gen2"]["sum"] > 0)
+        assert [n for n, _ in _host_events(str(tmp_path), "hvd:gc")] == [
+            "hvd:gc"]
+
+    def test_one_hook_however_many_engines_and_stop_removes_it(self, model):
+        before = list(gc.callbacks)
+        a, b = _engine(model), _engine(model)
+        a.start()
+        b.start()
+        try:
+            assert len(gc.callbacks) == len(before) + 1
+            gc.collect()
+            a.stop()
+            assert len(gc.callbacks) == len(before) + 1
+            gc.collect()
+        finally:
+            a.stop()
+            b.stop()
+        assert gc.callbacks == before
+        assert a.stats()["gc_pause_seconds_gen2"]["count"] == 1
+        assert b.stats()["gc_pause_seconds_gen2"]["count"] == 2
+        gc.collect()        # nobody listens any more
+        assert b.stats()["gc_pause_seconds_gen2"]["count"] == 2
+
+    def test_a_collection_under_a_histograms_lock_does_not_deadlock(
+            self, model):
+        # A collection starts on whichever thread allocates, so also on
+        # one that reads /stats or /metrics and holds a histogram's
+        # lock: the collector's sink takes none.  With a threshold of 1
+        # every read below starts collections of every generation.
+        engine = _engine(model)
+        engine.warmup((3,))
+        done = threading.Event()
+
+        def poll():
+            for _ in range(150):
+                engine.stats()
+                engine.metrics.registry.to_prometheus()
+            done.set()
+
+        threshold = gc.get_threshold()
+        gc.set_threshold(1, 1, 1)
+        try:
+            with _running(engine):
+                poller = threading.Thread(target=poll, daemon=True)
+                poller.start()
+                engine.submit([2, 3, 4], max_new_tokens=4).result(timeout=60)
+                assert done.wait(30), "a /stats reader hangs in the collector"
+        finally:
+            gc.set_threshold(*threshold)
+        stats = engine.stats()
+        assert stats["gc_pause_seconds"]["count"] > 150
+        assert stats["gc_pause_seconds_gen2"]["count"] > 0
+        assert not any(engine.metrics.gc_pending)
+        engine.metrics.gc_pending[2].append(0.5)
+        engine.refresh_windowed_gauges()    # what a /metrics scrape calls
+        assert not any(engine.metrics.gc_pending)
+
+    def test_the_collectors_sink_takes_no_lock(self, model):
+        engine = _engine(model)
+        hist = engine.metrics.gc_pause[2]
+        with hist._lock:        # where a collection may find its thread
+            for pause in ((2, 0.25), (0, 0.001)):
+                sink = threading.Thread(target=engine._on_gc, args=pause,
+                                        daemon=True)
+                sink.start()
+                sink.join(10)
+                assert not sink.is_alive()
+        assert hist.count == 0
+        stats = engine.stats()
+        assert stats["gc_pause_seconds"]["count"] == 2
+        assert stats["gc_pause_seconds_gen2"]["sum"] == 0.25
+        engine.metrics.fold_gc()        # folded once
+        assert engine.stats()["gc_pause_seconds"]["count"] == 2
+
+    def test_a_step_that_slept_leaves_one_record_naming_the_phase(
+            self, model, caplog):
+        faults = FaultInjector()
+        engine = _engine(model, faults=faults)
+        engine.warmup((3,))
+        engine.metrics = serving.ServingMetrics()
+        with _running(engine), caplog.at_level(logging.WARNING):
+            engine.submit([2, 3, 4], max_new_tokens=4).result(timeout=60)
+            assert engine.stats()["slow_steps_total"] == 0    # a clean run
+            faults.add(FaultSpec(site="prefill", kind="hang", delay=0.3,
+                                 skip=faults.visits("prefill")))
+            engine.submit([2, 3, 4], max_new_tokens=4).result(timeout=60)
+        stats = engine.stats()
+        assert stats["slow_steps_total"] == 1
+        (rec,) = stats["slow_steps"]
+        assert stats["slow_step_seconds_total"] == rec["wall_s"] >= 0.3
+        assert rec["wall_s"] > M.SLOW_STEP_SECONDS
+        wall, cpu = rec["phases"]["prefill"]
+        assert wall >= 0.3 and cpu < 0.1
+        assert max(rec["phases"], key=lambda n: rec["phases"][n][0]) \
+            == "prefill"
+        assert set(rec["phases"]) <= set(PHASES)
+        assert sum(w for w, _ in rec["phases"].values()) <= rec["wall_s"]
+        assert rec["cpu_s"] < 0.1 and rec["at_s"] >= 0
+        assert (rec["kind"], rec["compiles"], rec["active_slots"]) == (
+            "prefill", 0, 1)
+        assert rec["gc_s"] >= 0
+        (line,) = [r.getMessage() for r in caplog.records
+                   if "slow engine step" in r.getMessage()]
+        assert "'prefill'" in line
+        json.dumps(stats["slow_steps"])     # /stats serves it as it is
+
+    def test_the_ring_keeps_the_newest_sixteen(self):
+        metrics = serving.ServingMetrics()
+        for i in range(M.SLOW_STEP_RING + 4):
+            metrics.slow_step({"at_s": float(i), "wall_s": 0.5})
+        snap = metrics.snapshot()
+        assert M.SLOW_STEP_RING == 16
+        assert [r["at_s"] for r in snap["slow_steps"]] == [
+            float(i) for i in range(4, 20)]
+        assert snap["slow_steps_total"] == 20
+        assert snap["slow_step_seconds_total"] == 10.0
 
 
 # -- (3) tick kinds, (4) padding and walk counters ----------------------------
