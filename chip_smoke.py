@@ -821,6 +821,13 @@ def phase_serve(smoke: SmokeConfig, host_params, *, tp: int = 1) -> Dict:
              "/stats says the fused paged kernel is NOT in the tick")
     _require(stats["requests_completed"] >= len(prompts),
              f"{stats['requests_completed']} requests completed")
+    # the engine serves from its own tree: the three projection leaves
+    # laid out at load as the product reads them, the caller's as it was
+    relaid = sum(host_params["layers"][n].nbytes for n in ("wq", "wk", "wv"))
+    _require(stats["params_relaid_bytes"] == relaid
+             and np.ndim(params["layers"]["wq"]) == 4,
+             f"/stats params_relaid_bytes {stats['params_relaid_bytes']}, "
+             f"the three leaves hold {relaid}")
     # The decode tick's own executable, at the shapes it was served at.
     text = tick.lower(*seen["tick"]).compile().as_text()
     _require_compiled(smoke, text, 1, f"decode tick (tp={tp})")

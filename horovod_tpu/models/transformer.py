@@ -510,6 +510,34 @@ def param_specs(cfg: TransformerConfig) -> Dict:
     return specs
 
 
+_PROJ_LEAVES = ("wq", "wk", "wv")
+
+
+def lay_out_projections(params: Dict):
+    """``(tree, bytes)``: ``params`` with the standard attention block's
+    ``wq``/``wk``/``wv`` laid out ``(L, D, H * Dh)`` as the product over
+    ``D`` reads them, and the bytes of the leaves so laid out (0: a
+    latent block has none).  A checkpoint stores ``(L, D, H, Dh)``,
+    whose tiles on a TPU lie over ``(H, Dh)``: a program that scans such
+    a stack copies every layer's three leaves into the other layout
+    before it can multiply, each time it runs.  An engine does it ONCE,
+    here, and :func:`_qkv_proj` multiplies by a leaf as it finds it.
+    A reshape, exact; the caller's tree is not touched and every other
+    leaf is shared with it."""
+    out, laid = dict(params), 0
+    for name in ("layers", "dense_layers"):
+        stack = params.get(name)
+        if stack is None:
+            continue
+        out[name] = stack = dict(stack)
+        for leaf in _PROJ_LEAVES:
+            w = stack.get(leaf)
+            if w is not None and w.ndim == 4:
+                stack[leaf] = w.reshape(*w.shape[:2], -1)
+                laid += w.nbytes
+    return out, laid
+
+
 def batch_specs() -> Dict:
     """Activations: batch over dp(+fsdp), sequence over sp."""
     return {"tokens": P(("dp", "fsdp"), "sp"), "targets": P(("dp", "fsdp"), "sp")}
@@ -776,10 +804,21 @@ def _qkv_proj(x, p, cfg: TransformerConfig, pos_offset=0, positions=None,
     attention, prefill, and decode paths so the math cannot drift).
     ``kind`` is the layer's: a full layer takes ``cfg.rope_yarn``, a
     sliding one the plain rope (at ``rope_theta_sliding`` if set)."""
+    def heads(w):
+        # a leaf as a checkpoint stores it, (D, H, Dh), or as an engine
+        # holds it, (D, H * Dh) (lay_out_projections): the same
+        # contraction over D.  There the small product is cut into
+        # heads, not the weight — behind a barrier, or XLA:TPU moves
+        # the reshape back onto the weight (a product by (H, Dh, D))
+        # and copies the layer's leaf into that layout, every time
+        w = w.astype(cfg.dtype)
+        if w.ndim == 3:
+            return jnp.einsum("bsd,dhk->bshk", x, w)
+        y = lax.optimization_barrier(jnp.einsum("bsd,dn->bsn", x, w))
+        return y.reshape(*y.shape[:2], -1, cfg.head_dim)
+
     with jax.named_scope("attn_qkv"):
-        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(cfg.dtype))
-        k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(cfg.dtype))
-        v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(cfg.dtype))
+        q, k, v = heads(p["wq"]), heads(p["wk"]), heads(p["wv"])
         if cfg.qk_norm:
             q = _rmsnorm(q, p["q_norm"], cfg.norm_eps)
             k = _rmsnorm(k, p["k_norm"], cfg.norm_eps)
@@ -1474,31 +1513,49 @@ def expert_load(params: Dict, tokens, cfg: TransformerConfig):
 # --- autoregressive decoding (KV cache) ---------------------------------------
 
 
-def serving_shardings(mesh, cfg: TransformerConfig):
+def serving_shardings(mesh, cfg: TransformerConfig, params=None):
     """``(param_shardings, cache_shardings)`` as ``NamedSharding`` trees
     for a tp serving mesh — the one-call recipe for
     :func:`sample_decode`'s ``cache_shardings`` plus the ``device_put``
-    placement of restored params (see docs/inference.md)."""
+    placement of restored params (see docs/inference.md).  ``params``:
+    the tree to be placed, where it may be an engine's own
+    (:func:`serving_param_specs`)."""
     from jax.sharding import NamedSharding
 
     param_sh = jax.tree_util.tree_map(
-        lambda s: NamedSharding(mesh, s), serving_param_specs(cfg),
+        lambda s: NamedSharding(mesh, s), serving_param_specs(
+            cfg, params=params),
         is_leaf=lambda x: isinstance(x, P))
     cache_sh = {k: NamedSharding(mesh, s) for k, s in cache_specs().items()}
     return param_sh, cache_sh
 
 
-def serving_param_specs(cfg: TransformerConfig, axes=("tp",)) -> Dict:
+def serving_param_specs(cfg: TransformerConfig, axes=("tp",),
+                        params=None) -> Dict:
     """:func:`param_specs` restricted to the mesh axes available at
     SERVING time (default a tp-only mesh): any training-only axis (pp,
     fsdp, ep, ...) is replicated, so a model trained with tp>1 restores
     onto a tp serving mesh without resharding logic — heads/ffn/vocab
-    stay sharded, everything else replicates."""
+    stay sharded, everything else replicates.
+
+    ``params``: the tree the specs are for.  A leaf whose heads and head
+    size are ONE axis there (:func:`lay_out_projections`) takes its spec
+    with the two joined — the head axis' entry, so each shard holds
+    whole, contiguous heads as before."""
     def keep(spec):
         return P(*[a if a in axes else None for a in spec])
 
-    return jax.tree_util.tree_map(
+    def fit(spec, leaf):
+        if len(spec) == leaf.ndim + 1 and spec[-1] is None:
+            return P(*spec[:-1])
+        return spec
+
+    specs = jax.tree_util.tree_map(
         keep, param_specs(cfg), is_leaf=lambda x: isinstance(x, P))
+    if params is None:
+        return specs
+    return jax.tree_util.tree_map(
+        fit, specs, params, is_leaf=lambda x: isinstance(x, P))
 
 
 def cache_specs() -> Dict:
@@ -1575,7 +1632,7 @@ def shard_params(params: Dict, mesh, cfg: TransformerConfig) -> Dict:
     everything else replicated) — the one-call placement for an engine
     or a restored checkpoint.  The sharding tree itself comes from
     :func:`serving_shardings` (the ONE spec→NamedSharding mapping)."""
-    param_sh, _ = serving_shardings(mesh, cfg)
+    param_sh, _ = serving_shardings(mesh, cfg, params)
     return jax.device_put(params, param_sh)
 
 

@@ -574,7 +574,13 @@ class InferenceEngine:
                  detokenize: Optional[Callable[[int], str]] = None,
                  draft_params: Optional[Dict] = None,
                  draft_cfg: Optional["T.TransformerConfig"] = None):
-        self.params = params
+        # The engine serves from a tree of its OWN: the standard
+        # attention block's wq/wk/wv laid out once, here, as the
+        # product reads them (T.lay_out_projections: a tick and a chunk
+        # then cut a layer out of the stack and multiply, with no copy
+        # between); every other leaf is the caller's, whose tree is
+        # neither changed nor donated.
+        self.params, self._relaid_bytes = T.lay_out_projections(params)
         self.cfg = cfg
         self.engine_cfg = engine_cfg
         self.detokenize = detokenize
@@ -606,6 +612,9 @@ class InferenceEngine:
                         f"draft model must share the tokenizer: vocab "
                         f"{draft_cfg.vocab_size} != {cfg.vocab_size}")
             self._spec_model = mode == "model"
+            if self._spec_model:
+                self.draft_params, laid = T.lay_out_projections(draft_params)
+                self._relaid_bytes += laid
         if engine_cfg.prefill_chunk_tokens < 0:
             raise ValueError(
                 f"prefill_chunk_tokens must be >= 1 (or 0 to disable), "
@@ -794,9 +803,10 @@ class InferenceEngine:
         # guard holds under tp unchanged.
         shd = self._shard
         self._sh_R = _R = shd.replicated if shd else None
-        self._sh_params = _psh = shd.param_shardings() if shd else None
+        self._sh_params = _psh = (
+            shd.param_shardings(params=self.params) if shd else None)
         self._sh_draft_params = _dpsh = (
-            shd.param_shardings(draft_cfg)
+            shd.param_shardings(draft_cfg, self.draft_params)
             if shd and self._spec_model else None)
         _poolsh = shd.pool_shardings(self.slots.quantized) if shd else None
         _dpoolsh = (shd.pool_shardings(False)
@@ -4019,6 +4029,9 @@ class InferenceEngine:
             "kv_latent_bytes_per_token": self.slots.latent_bytes_per_token,
             # ... and in a sparse model's index-key array beside it
             "kv_index_bytes_per_token": self.slots.index_bytes_per_token,
+            # the bytes of the projection leaves this engine laid out
+            # at load (T.lay_out_projections; 0: a latent model has none)
+            "params_relaid_bytes": self._relaid_bytes,
             "kv_pages_high_water": self.slots.pages_high_water,
             "kv_window_pages_per_slot_bound":
                 self.wslots.window_pages_bound

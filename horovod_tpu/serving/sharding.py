@@ -152,16 +152,19 @@ class ServingSharding:
     # -- sharding trees ----------------------------------------------------
 
     def param_shardings(self,
-                        cfg: Optional["T.TransformerConfig"] = None):
+                        cfg: Optional["T.TransformerConfig"] = None,
+                        params: Optional[Dict] = None):
         # serving_shardings is the ONE spec->NamedSharding mapping
-        # (T.shard_params routes through it too).
+        # (T.shard_params routes through it too); with ``params`` each
+        # spec is fitted to that tree's leaf (an engine's projections
+        # are stored with heads and head size as one axis).
         param_sh, _ = T.serving_shardings(
-            self.mesh, cfg if cfg is not None else self.cfg)
+            self.mesh, cfg if cfg is not None else self.cfg, params)
         return param_sh
 
     def shard_params(self, params: Dict,
                      cfg: Optional["T.TransformerConfig"] = None) -> Dict:
-        return jax.device_put(params, self.param_shardings(cfg))
+        return jax.device_put(params, self.param_shardings(cfg, params))
 
     def pool_shardings(self, quantized: bool = False) -> Dict:
         return {k: NamedSharding(self.mesh, s)

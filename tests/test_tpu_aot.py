@@ -419,3 +419,69 @@ def test_the_train_step_runs_the_flash_forward_once_under_dots(
     grew = (program.memory_analysis().temp_size_in_bytes
             - before.memory_analysis().temp_size_in_bytes)
     assert 0 < grew <= saved * 1.1, (grew, saved)
+
+
+@pytest.mark.parametrize("tree", ["engine", "checkpoint"])
+@pytest.mark.parametrize("what", ["tick", "chunk"])
+def test_the_layer_scan_copies_no_projection_leaf_of_an_engines_tree(
+        one_chip, monkeypatch, what, tree):
+    """The benchmark's Mistral configuration, its tick and a chunk's
+    program (``prefill_with_prefix``: 512 tokens behind 1024 landed),
+    compiled for the v5e.  With the ENGINE's tree
+    (``T.lay_out_projections``: ``wq``/``wk``/``wv`` stored ``(L, D, H *
+    Dh)``) no operation of the scan's own — under ``layer_scan`` and
+    under no scope of the layer's — has a result the size of one
+    layer's ``wq`` or ``wk``: the products read their leaves where they
+    lie.  With a CHECKPOINT's tree (``(L, D, H, Dh)``: what every tick
+    and chunk ran before ISSUE 37) the same reading finds each of the
+    three leaves cut out AND copied — the control that says the reading
+    would see such a copy."""
+    import json
+
+    from chipbench.drivers import serve
+
+    monkeypatch.setattr(PA, "use_interpret", lambda: False)
+    with open(os.path.join(os.path.dirname(chip_smoke.__file__), "chipbench",
+                           "configs", "mistral-7b-v0.3-serve.json")) as f:
+        dims = json.load(f)
+    cfg, eng = serve.build_cfg(dims), dims["engine"]
+    params = _params(one_chip, cfg)
+    if tree == "engine":
+        params = _on(one_chip, jax.eval_shape(
+            lambda p: T.lay_out_projections(p)[0], params))
+        assert params["layers"]["wq"].shape == (
+            cfg.n_layers, cfg.d_model, cfg.n_heads * cfg.head_dim)
+
+    def sds(shape, dtype):
+        return _on(one_chip, jax.ShapeDtypeStruct(shape, dtype))
+
+    S_, L = eng["n_slots"], cfg.n_layers
+    if what == "tick":
+        pool = _on(one_chip, jax.eval_shape(lambda: C.init_page_pool(
+            cfg, S_, eng["n_pages"] + 1, eng["page_size"])))
+        compiled = jax.jit(
+            lambda p, tok, act, t, pl: T.decode_step_paged(
+                p, tok, pl, t, cfg, act, kernel=True),
+            donate_argnums=(4,)).lower(
+                params, sds((S_,), jnp.int32), sds((S_,), jnp.bool_),
+                sds((S_, eng["max_len"] // eng["page_size"]), jnp.int32),
+                pool).compile()
+    else:
+        landed = sds((L, cfg.kv_heads, 1024, cfg.head_dim), cfg.dtype)
+        compiled = jax.jit(
+            lambda p, tok, lens, pk, pv, p0: T.prefill_with_prefix(
+                p, tok, pk, pv, p0, cfg, true_len=lens)).lower(
+                    params, sds((1, eng["prefill_chunk_tokens"]), jnp.int32),
+                    sds((1,), jnp.int32), landed, landed,
+                    sds((), jnp.int32)).compile()
+    leaf = {cfg.d_model * h * cfg.head_dim
+            for h in (cfg.n_heads, cfg.kv_heads)}
+    found, _ = chip_smoke.pool_sized_results(compiled.as_text(), min(leaf))
+    scans_own = [
+        f for f in found if f[0] in leaf and [
+            c for c in f[3].split("/") if c in T.DEVICE_SCOPES
+        ][-1:] == ["layer_scan"]]
+    if tree == "engine":
+        assert scans_own == [], scans_own
+    else:
+        assert len(scans_own) >= 3, (scans_own, found)
