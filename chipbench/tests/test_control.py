@@ -5,6 +5,7 @@ import json
 import os
 
 import numpy as np
+import pytest
 
 from conftest import TOY
 
@@ -66,16 +67,40 @@ def test_real_configs_keep_published_widths():
         assert d["reduced"] == ["num_hidden_layers"]
 
 
-def test_toy_benchmark_lists_the_real_metrics():
-    """The toy tree the CPU tests run is the real BENCHMARK.json with toy
-    configurations: its cells and metric lists may not drift."""
+TOY_TREES = ("toy", "toy_latent", "toy_patterned", "toy_sparse")
+
+
+@pytest.mark.parametrize("tree", TOY_TREES)
+def test_toy_benchmark_lists_the_real_metrics(tree):
+    """A toy tree the CPU tests run is the real BENCHMARK.json with toy
+    configurations, cut to the tree's cells: its ``per_layer`` is exactly
+    the real entries whose ``workloads`` name one of its cells, each list
+    cut to those cells; its end-to-end metrics, bounds and cells are the
+    real ones.  The next cell cannot let a tree drift."""
     from conftest import ROOT
 
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         real = json.load(f)
-    with open(os.path.join(TOY, "BENCHMARK.json")) as f:
+    with open(os.path.join(os.path.dirname(TOY), tree,
+                           "BENCHMARK.json")) as f:
         toy = json.load(f)
-    for key in ("end_to_end", "per_layer", "command", "paths"):
+    cells = [w["name"] for w in toy["workloads"]]
+
+    def cut(metrics):
+        out = []
+        for m in metrics:
+            if "workloads" not in m:
+                out.append(m)
+                continue
+            here = [c for c in m["workloads"] if c in cells]
+            if here:
+                out.append(dict(m, workloads=here))
+        return out
+
+    assert toy["per_layer"] == cut(real["per_layer"])
+    assert toy["end_to_end"] == cut(real["end_to_end"])
+    for key in ("command", "paths", "run_seconds"):
         assert toy[key] == real[key]
     assert [(w["name"], w["traffic"], w["chips"]) for w in toy["workloads"]] \
-        == [(w["name"], w["traffic"], w["chips"]) for w in real["workloads"]]
+        == [(w["name"], w["traffic"], w["chips"]) for w in real["workloads"]
+            if w["name"] in cells]

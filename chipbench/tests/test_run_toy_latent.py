@@ -91,9 +91,9 @@ def test_the_real_cells_files_are_whole():
         for name, spec in harness.metric_specs(cell, which).items():
             importlib.import_module(f"chipbench.readers.{spec['reader']}")
     per_layer = harness.metric_specs(cell, "per_layer")
-    assert len(per_layer) == 24
-    assert {"mla_decode_roofline_pct.axk1", "moe_experts_roofline_pct.axk1",
-            "device_unscoped_pct.axk1"} <= set(per_layer)
+    assert len(per_layer) >= 28     # a later cell's PR may add, not take
+    assert {"mla_decode_roofline_pct.axk1", "moe_experts_roofline_pct",
+            "device_unscoped_pct.tput"} <= set(per_layer)
     assert "serve_tokens_per_s" in harness.metric_specs(cell, "end_to_end")
     d = cell["dims"]
     assert (d["hidden_size"], d["num_attention_heads"], d["q_lora_rank"],

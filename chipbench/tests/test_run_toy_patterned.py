@@ -86,7 +86,7 @@ def test_the_real_cells_files_are_whole():
         for name, spec in harness.metric_specs(cell, which).items():
             importlib.import_module(f"chipbench.readers.{spec['reader']}")
     per_layer = harness.metric_specs(cell, "per_layer")
-    assert len(per_layer) == 22 and "moe_experts_roofline_pct" in per_layer
+    assert len(per_layer) >= 27 and "moe_experts_roofline_pct" in per_layer
     d = cell["dims"]
     assert (d["hidden_size"], d["num_attention_heads"],
             d["num_key_value_heads"], d["head_dim"]) == (2304, 32, 4, 128)

@@ -233,9 +233,10 @@ def test_every_per_layer_metric_has_its_file_and_reads_nothing_as_none(name):
     reader = importlib.import_module(f"chipbench.readers.{spec['reader']}")
     # a run that observed nothing leaves the metric out
     assert reader.read({}, spec.get("args", {})) is None
-    # ... and so does, for a metric this PR brought (one the toy tree's
-    # list, which is the accepted benchmark's, does not have), a program
-    # without the new counters: a /stats with the old keys alone
+    # ... and so does, for a metric only the expert, latent or sparse
+    # cells read (one the toy tree's list, which is the real entries of
+    # its four Mistral cells, does not have), a program without the newer
+    # counters: a /stats with the old keys alone
     old = {"decode_ticks": 1, "tick_host_seconds": {"sum": 0.5}}
     value = reader.read({"stats0": old, "stats1": {
         "decode_ticks": 9, "tick_host_seconds": {"sum": 0.9}},
@@ -243,8 +244,13 @@ def test_every_per_layer_metric_has_its_file_and_reads_nothing_as_none(name):
     with open(os.path.join(HERE, "toy", "BENCHMARK.json")) as f:
         accepted = {m["name"] for m in json.load(f)["per_layer"]}
     assert value is None or name in accepted
+    assert set(entry) == {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
     cells = {w["name"]: w for w in bench["workloads"]}
     e2e = {m["name"]: m for m in bench["end_to_end"]}
+    assert entry["moves"] in e2e and entry["moves"] != "setup_s"
+    assert entry["workloads"] and len(set(entry["workloads"])) == len(
+        entry["workloads"])
     for cell in entry["workloads"]:
         assert cell in cells
         assert cell in e2e[entry["moves"]].get("workloads", cells)

@@ -43,6 +43,36 @@ def test_summarise_by_hand():
     assert idle_pct == pytest.approx(100 / 6)
 
 
+def test_a_gap_goes_under_the_engines_phase_before_the_benchmarks_span():
+    """Both prefixes the readers take; where both kinds cover a gap, the
+    program's phase names it; a sliver of a phase does not."""
+    from chipbench.readers import trace_scope_per
+
+    assert xplane.SPAN_PREFIXES == trace_scope_per.SPAN_PREFIXES
+    ops = [("fusion.1", 0.0, 1.0), ("fusion.2", 2.0, 3.0),
+           ("fusion.3", 4.0, 5.0), ("fusion.4", 6.0, 7.0)]
+    host = [("chipbench:window", 0.0, 7.0),
+            ("hvd:ingest_chunk", 0.9, 1.8), ("hvd:tick_dispatch", 1.8, 2.1),
+            ("hvd:gc", 1.0, 1.1),                   # nested in the phase
+            ("hvd:bookkeeping", 3.0, 3.1),          # a tenth of gap two
+            ("other", 0.0, 7.0)]
+    planes = {"/device:TPU:0": {"XLA Ops": ops}, "/host:CPU": {"main": host}}
+    assert {n for n, _, _ in xplane.host_spans(planes)} == {
+        "chipbench:window", "hvd:ingest_chunk", "hvd:tick_dispatch",
+        "hvd:gc", "hvd:bookkeeping"}
+    gaps = dict(xplane.summarise(planes)["idle_gaps"])
+    # 1 -> 2: phases cover it whole, ingest_chunk most of it; 3 -> 4: the
+    # phases cover a tenth, the benchmark's span all of it; 5 -> 6 likewise
+    assert gaps == {"ingest_chunk": pytest.approx(1.0),
+                    "window": pytest.approx(2.0)}
+    # no benchmark span at all: the phase that covers most, else nothing
+    planes["/host:CPU"]["main"] = host[1:]
+    gaps = dict(xplane.summarise(planes)["idle_gaps"])
+    assert gaps == {"ingest_chunk": pytest.approx(1.0),
+                    "bookkeeping": pytest.approx(1.0),
+                    "no benchmark span": pytest.approx(1.0)}
+
+
 def test_nested_operations_count_once():
     evs = [("while.1", 0.0, 4.0), ("fusion.2", 0.5, 1.5),
            ("all-reduce.9", 1.5, 2.5), ("fusion.2", 3.0, 4.0)]

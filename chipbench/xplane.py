@@ -14,12 +14,16 @@ chips; operations nest (a ``while`` holds its body), so a sum by name takes
 each event's SELF time; a kernel's time is that sum over the names its
 pattern matches; exposed collective time is the part of the collective
 operations' intervals during which no other operation ran on that chip;
-an idle gap is the space between two busy intervals, labelled by the
-benchmark's own host span (``chipbench:*``) that covers most of it, or
+an idle gap is the space between two busy intervals, labelled by the host
+span that covers most of it: the program's own (``hvd:*``: an engine
+phase) where those cover at least half of the gap, else the benchmark
+driver's (``chipbench:*``) on the same condition — the rule of
+``readers/trace_gap_by_span`` — else whichever span covers most of it, or
 ``no benchmark span``."""
 
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import re
@@ -30,7 +34,9 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 COLLECTIVE = re.compile(
     r"all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all")
-SPAN_PREFIX = "chipbench:"
+# as the readers take them (``readers/trace_scope_per.SPAN_PREFIXES``), the
+# program's before the benchmark's
+SPAN_PREFIXES = ("hvd:", "chipbench:")
 NO_SPAN = "no benchmark span"
 
 Interval = Tuple[float, float]
@@ -132,11 +138,11 @@ def device_ops(planes: dict) -> Dict[int, List[Tuple[str, float, float]]]:
 
 
 def host_spans(planes: dict) -> List[Tuple[str, float, float]]:
-    """The benchmark's own annotations, from any host line."""
+    """The program's and the benchmark's annotations, from any host line."""
     return [ev for name, lines in planes.items()
             if not DEVICE_PLANE.match(name)
             for events in lines.values() for ev in events
-            if ev[0].startswith(SPAN_PREFIX)]
+            if ev[0].startswith(SPAN_PREFIXES)]
 
 
 def summarise(planes: dict) -> Optional[dict]:
@@ -147,7 +153,7 @@ def summarise(planes: dict) -> Optional[dict]:
         return None
     first = min(e[1] for evs in ops.values() for e in evs)
     last = max(e[2] for evs in ops.values() for e in evs)
-    spans = host_spans(planes)
+    spans = _by_start(host_spans(planes))
     busy, exposed, by_name, gaps = [], [], defaultdict(float), \
         defaultdict(float)
     for chip, evs in ops.items():
@@ -171,13 +177,41 @@ def summarise(planes: dict) -> Optional[dict]:
     }
 
 
-def _label(s: float, e: float, spans) -> str:
-    best, cover = NO_SPAN, 0.0
-    for name, a, b in spans:
+def _by_start(spans) -> Tuple[list, List[float], List[float]]:
+    """The spans in order of their start, those starts, and the latest end
+    among the spans up to each: what ``_label`` needs to look at only the
+    spans near a gap (a serving trace holds thousands of both)."""
+    spans = sorted(spans, key=lambda sp: sp[1])
+    ends, latest = [], float("-inf")
+    for _, _, b in spans:
+        latest = max(latest, b)
+        ends.append(latest)
+    return spans, [a for _, a, _ in spans], ends
+
+
+def _label(s: float, e: float, index) -> str:
+    """The name, less its prefix, of the span the gap ``[s, e]`` goes
+    under: the engine's phase wins where both kinds cover the gap."""
+    spans, starts, ends = index
+    best: Dict[str, Tuple[str, float]] = {}
+    covered: Dict[str, float] = defaultdict(float)
+    i = bisect.bisect_left(starts, e) - 1       # the last span begun by e
+    while i >= 0 and ends[i] > s:
+        name, a, b = spans[i]
+        i -= 1
         c = min(e, b) - max(s, a)
-        if c > cover:
-            best, cover = name[len(SPAN_PREFIX):], c
-    return best
+        if c <= 0:
+            continue
+        prefix = next(p for p in SPAN_PREFIXES if name.startswith(p))
+        covered[prefix] += c
+        if c > best.get(prefix, ("", 0.0))[1]:
+            best[prefix] = (name[len(prefix):], c)
+    for prefix in SPAN_PREFIXES:
+        if covered[prefix] >= 0.5 * (e - s):
+            return best[prefix][0]
+    if not best:
+        return NO_SPAN
+    return max(best.values(), key=lambda nc: nc[1])[0]
 
 
 def _top(seconds_by_name: Dict[str, float], n: int = 10) -> list:
