@@ -864,12 +864,15 @@ def phase_serve(smoke: SmokeConfig, host_params, *, tp: int = 1) -> Dict:
     return report
 
 
-def _check_against_oracle(smoke, host_params, prompts, tokens, cfg=None):
+def _check_against_oracle(smoke, host_params, prompts, tokens, cfg=None,
+                          oracle=None):
     """Teacher-forced logit-level check: run the plain XLA forward
     (reference attention, no kernel of ours) over prompt + the engine's
     own tokens; wherever the oracle's top-1/top-2 margin exceeds the
     tolerance the engine's token must BE the oracle's argmax.  ``cfg``:
-    another model than the smoke's own."""
+    another model than the smoke's own.  ``oracle(params, tokens (n,
+    width)) -> logits``: another forward than ``T.forward`` (which
+    computes one kind of layer)."""
     from horovod_tpu.models import transformer as T
 
     cfg = dataclasses.replace(cfg or smoke.model_cfg(),
@@ -881,7 +884,8 @@ def _check_against_oracle(smoke, host_params, prompts, tokens, cfg=None):
     batch = np.zeros((len(prompts), width), np.int32)
     for i, prompt in enumerate(prompts):
         batch[i, :len(prompt) + smoke.max_new_tokens] = prompt + tokens[i]
-    logits = np.asarray(jax.jit(lambda p, t: T.forward(p, t, cfg))(
+    forward = oracle or (lambda p, t: T.forward(p, t, cfg))
+    logits = np.asarray(jax.jit(forward)(
         params, jnp.asarray(batch)))                   # (n, width, V) f32
     checked = 0
     for i, prompt in enumerate(prompts):
@@ -1064,6 +1068,101 @@ def phase_serve_sparse(smoke: SmokeConfig) -> Dict:
     return report
 
 
+def conv_cfg(smoke: SmokeConfig):
+    """The smoke's small model of gated SHORT CONVOLUTIONS between
+    attention layers: conv, conv (dense) then full, conv, conv, conv
+    (2 of 8 sigmoid-routed experts under a bias); 4 query / 2 KV heads
+    of 64 stored two to a 128-lane row; a head tied to the embedding."""
+    from horovod_tpu.models import transformer as T
+
+    return T.TransformerConfig(
+        vocab_size=smoke.vocab_size, d_model=256, n_heads=4, n_kv_heads=2,
+        d_head=64, n_layers=6, n_dense_layers=2, d_ff=512,
+        max_seq=smoke.serve_max_len, dtype=jnp.dtype(smoke.dtype),
+        attention_impl="flash", layer_pattern=("conv", "conv", "full",
+                                               "conv"),
+        conv_kernel=3, tie_embeddings=True, kv_lane_dense=True,
+        qk_norm=True, n_experts=8, n_experts_per_tok=2, d_expert=256,
+        moe_score="sigmoid", moe_score_bias=True, norm_topk_prob=True,
+        norm_topk_eps=1e-6, moe_impl="dropless")
+
+
+def phase_serve_conv(smoke: SmokeConfig) -> Dict:
+    """The small CONV configuration through the engine on one chip:
+    whole and chunked prompts (a state handed from chunk to chunk), the
+    fused paged kernel engaged over rows that two heads of 64 share, the
+    pages AND the per-slot conv state written in place by the compiled
+    tick and landing, the tokens the plain reference's where its margin
+    is clear; and the flash forward at heads of 64 against the XLA
+    form."""
+    from horovod_tpu.models import plain_reference as R
+    from horovod_tpu.ops import attention as attn
+    from horovod_tpu.ops import paged_attention as PA
+
+    cfg = conv_cfg(smoke)
+    engine, params, prompts, futs, seen, tick, land = _serve_small(
+        smoke, cfg, smoke.seed + 5)
+    stats = engine.stats()
+    item = jnp.dtype(cfg.dtype).itemsize
+    _require(stats["paged_kernel_engaged"] is True
+             and stats["decode_compilations"] == 1,
+             f"conv engine: kernel engaged {stats['paged_kernel_engaged']}"
+             f", decode compilations {stats['decode_compilations']}")
+    pool = engine.slots.cache
+    _require(set(pool) == {"k", "v", "conv", "pos"}
+             and pool["k"].shape[2:] == (1, engine.slots.page_size, 128)
+             and pool["conv"].shape == (5, smoke.n_slots, 2, 256)
+             and stats["kv_bytes_per_token"] == 2 * 2 * 64 * item
+             and stats["conv_state_bytes_per_slot"] == 5 * 2 * 256 * item,
+             f"the conv pool's layout: arrays {sorted(pool)}, k "
+             f"{pool['k'].shape}, {stats['kv_bytes_per_token']} B a token, "
+             f"{stats['conv_state_bytes_per_slot']} B of state a slot")
+    text = tick.lower(*seen["tick"]).compile().as_text()
+    _require_compiled(smoke, text, 2, "conv decode tick")
+    _require(not smoke.expect_compiled or PA.KERNEL_NAME in text,
+             "conv decode tick: hvd_paged_attend not compiled")
+    layer = int(np.prod(pool["k"].shape[1:]))
+    _require_pool_in_place(smoke, text, layer, "conv decode tick")
+    _require_pool_in_place(
+        smoke, land.lower(*seen["land"]).compile().as_text(), layer,
+        "conv landing")
+    # the flash forward at heads of 64 (a whole prompt's attention)
+    rng = np.random.RandomState(smoke.seed)
+    q, k, v = (jnp.asarray(rng.randn(1, 4, 512, 64), cfg.dtype)
+               for _ in range(3))
+    fwd = jax.jit(lambda q, k, v: attn.flash_attention(
+        q, k, v, True)).lower(q, k, v).compile()
+    _require_compiled(smoke, fwd.as_text(), 1, "flash forward, heads of 64")
+    err = float(jnp.max(jnp.abs(
+        fwd(q, k, v).astype(jnp.float32) - attn.reference_attention(
+            q, k, v, causal=True).astype(jnp.float32))))
+    tol = 2e-2 if cfg.dtype == jnp.bfloat16 else 2e-4
+    _require(err <= tol, f"flash forward at heads of 64: max|d|={err}")
+    # the tokens are the plain reference's, where its margin is clear
+    dims = dict(
+        hidden_size=cfg.d_model, num_attention_heads=cfg.n_heads,
+        norm_eps=cfg.norm_eps, num_dense_layers=cfg.n_dense_layers,
+        num_experts_per_tok=cfg.n_experts_per_tok, norm_topk_prob=True,
+        routed_scaling_factor=1.0,
+        rope_parameters={"rope_theta": cfg.rope_theta,
+                         "rope_type": "default"},
+        layer_types=["conv" if kind == "conv" else "full_attention"
+                     for kind in cfg.layer_kinds])
+    tokens = {i: f.result() for i, f in enumerate(futs)}
+    checked = _check_against_oracle(
+        smoke, params, prompts, tokens, cfg,
+        oracle=lambda p, t: jax.vmap(
+            lambda row: R.conv_forward(p, row, dims))(t))
+    report = {"requests": len(prompts), "oracle_positions_checked": checked,
+              "flash_fwd_d64_max_abs_err": err,
+              "kv_bytes_per_token": stats["kv_bytes_per_token"],
+              "conv_state_bytes_per_slot":
+                  stats["conv_state_bytes_per_slot"]}
+    _say("conv server: " + json.dumps(report))
+    del engine, params
+    return report
+
+
 def phase_tp(smoke: SmokeConfig, host_params, tp: int, single: Dict) -> Dict:
     """``EngineConfig(tp=n)`` answers the same requests as ``tp=1``."""
     report = phase_serve(smoke, host_params, tp=tp)
@@ -1097,6 +1196,8 @@ def run(smoke: SmokeConfig, *, tp: Optional[int] = None) -> Dict:
     report["serve_latent"] = phase_serve_latent(smoke)
     gc.collect()
     report["serve_sparse"] = phase_serve_sparse(smoke)
+    gc.collect()
+    report["serve_conv"] = phase_serve_conv(smoke)
     gc.collect()
     if tp:
         report["serve_tp"] = phase_tp(smoke, host_params, tp,

@@ -2,7 +2,8 @@
 uniform block: a patterned expert model (below), a LATENT-ATTENTION
 expert model (``latent_*``, with its own description there) and that
 model with a lightning indexer's SPARSE selection and a biased router
-(``sparse_*``, at the end of the file).
+(``sparse_*``), and a model of gated SHORT CONVOLUTIONS between
+attention layers (``conv_*``, at the end of the file).
 
 The plain reference of a patterned expert model: its forward pass in
 straightforward ``jax.numpy``, float32 at
@@ -494,3 +495,125 @@ def sparse_forward(params, tokens, dims: dict, **controls):
             x = sparse_layer(x, w, dims, **controls)
         x = rmsnorm(x, params["ln_f"], dims["rms_norm_eps"])
         return x @ params["head"].astype(F32)
+
+
+# --- gated short convolutions between attention layers (LFM2's block) ---------
+#
+# The forward pass of the block ``LFM2-24B-A2B`` publishes (``model_type:
+# lfm2_moe``), in the same plain style: float32 at
+# ``default_matmul_precision("highest")``, one sequence, every position
+# against every earlier one, every expert computed and masked, no cache,
+# no STATE — the convolution reads the whole sequence — no kernel,
+# nothing imported from the program.  ``tests/test_conv_layers.py`` holds
+# whole prefill, chunked prefill and paged decode to its LOGITS.
+#
+# A layer (no bias; ``h = x + Op(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``),
+# its ``Op`` by ``layer_types``:
+#
+# * ``conv`` (``conv_L_cache`` = K taps, ``conv_bias`` false): ``[B, C, X] =
+#   n W_in`` (split in three in that order); ``u = B * X``; ``v[t] = sum_j
+#   k[:, j] u[t - (K - 1) + j]`` with ``u[s] = 0`` for ``s < 0`` (depthwise
+#   over the hidden size, causal); ``Op = (C * v) W_out``.  No activation.
+# * ``full_attention``: q, k, v projections into heads of ``hidden_size /
+#   num_attention_heads``; q and k each through an RMSNorm over the head
+#   with a learned scale; rotate-half rope (``rope_parameters``); causal
+#   ``softmax(q k^T / sqrt(Dh)) v``; ``W_o``.
+# * FFN: the first ``num_dense_layers`` layers one SwiGLU of width
+#   ``intermediate_size``; the rest ``s = sigmoid(n W_r)`` in float32, the
+#   ``num_experts_per_tok`` experts the largest of ``s + b``
+#   (``use_expert_bias``), their weights the raw ``s`` of the chosen,
+#   divided by ``sum + 1e-6`` (``norm_topk_prob``), times
+#   ``routed_scaling_factor``; each expert a SwiGLU of
+#   ``moe_intermediate_size``; nothing dropped, no shared expert.
+# * final RMSNorm; the logits against the EMBEDDING (tied).
+#
+# Assumptions (the published config has no key for them), the program's
+# too: the tied head, the q/k norms, rotate-half pairing and the order
+# ``B, C, X`` are the family's (LFM2's) convention; the 1e-6.
+#
+# ``zero_taps`` is a CONTROL, not the model: the convolution keeps its
+# current tap alone (``v[t] = k[:, K-1] u[t]``) — what a program serves
+# that loses a request's state at every chunk and tick boundary.
+#
+# ``params``: ``embed (V, D)``, ``ln_f``, ``dense_layers`` and ``layers``,
+# each stacked on a leading axis with the mixer's leaves BY KIND: ``ln1``,
+# ``ln2`` and the FFN's leaves over all the stack's layers; ``wq (D, H,
+# Dh)``, ``wk``/``wv (D, H_kv, Dh)``, ``wo (H, Dh, D)``, ``q_norm``/
+# ``k_norm (Dh)`` over its attention layers; ``conv_in (D, 3D)``,
+# ``conv_k (D, K)``, ``conv_out (D, D)`` over its conv layers.
+
+CONV_NORM_TOPK_EPS = 1e-6
+
+
+def conv_mixer(n, w, zero_taps: bool = False):
+    """The gated short convolution of one sequence ``n`` ``(S, D)``."""
+    S = n.shape[0]
+    b, c, x = jnp.split(n @ w["conv_in"].astype(F32), 3, axis=-1)
+    u = b * x
+    k = w["conv_k"].astype(F32)                       # (D, K)
+    K = k.shape[1]
+    past = jnp.concatenate([jnp.zeros((K - 1, u.shape[1]), F32), u])
+    taps = range(K - 1, K) if zero_taps else range(K)
+    v = sum(past[j:j + S] * k[:, j] for j in taps)
+    return (c * v) @ w["conv_out"].astype(F32)
+
+
+def conv_route(n, router, bias, dims: dict):
+    """``(S, E)`` combination weights: the experts chosen on ``sigmoid +
+    bias``, weighted by the raw sigmoid over ``sum + 1e-6``."""
+    sc = jax.nn.sigmoid(n @ router.astype(F32))
+    _, top_e = jax.lax.top_k(sc + bias.astype(F32),
+                             dims["num_experts_per_tok"])
+    top_g = jnp.take_along_axis(sc, top_e, axis=-1)
+    if dims["norm_topk_prob"]:
+        top_g = top_g / (jnp.sum(top_g, axis=-1, keepdims=True)
+                         + CONV_NORM_TOPK_EPS)
+    top_g = top_g * dims["routed_scaling_factor"]
+    return jnp.zeros_like(sc).at[
+        jnp.arange(n.shape[0])[:, None], top_e].set(top_g)
+
+
+def conv_experts(n, w, dims: dict):
+    weight = conv_route(n, w["router"], w["router_bias"], dims)
+    gate = jnp.einsum("sd,edf->esf", n, w["w_gate"].astype(F32))
+    up = jnp.einsum("sd,edf->esf", n, w["w_up"].astype(F32))
+    out = jnp.einsum("esf,efd->esd", jax.nn.silu(gate) * up,
+                     w["w_down"].astype(F32))
+    return jnp.einsum("esd,se->sd", out, weight)
+
+
+def conv_layer(x, w, dims: dict, kind: str, zero_taps: bool = False):
+    eps = dims["norm_eps"]
+    n = rmsnorm(x, w["ln1"], eps)
+    if kind == "conv":
+        h = x + conv_mixer(n, w, zero_taps)
+    else:
+        h = x + attention(n, w, {
+            "rms_norm_eps": eps,
+            "head_dim": dims["hidden_size"] // dims["num_attention_heads"],
+            "rope_parameters": {kind: dims["rope_parameters"]}}, kind)
+    n = rmsnorm(h, w["ln2"], eps)
+    if "router" in w:
+        return h + conv_experts(n, w, dims)
+    return h + _swiglu(n, w["w_gate"], w["w_up"], w["w_down"])
+
+
+def conv_forward(params, tokens, dims: dict, zero_taps: bool = False):
+    """Logits ``(S, V)`` float32 of one sequence ``tokens`` ``(S,)``."""
+    mixer = {"conv": ("conv_in", "conv_k", "conv_out")}
+    attn = ("wq", "wk", "wv", "wo", "q_norm", "k_norm")
+    with jax.default_matmul_precision("highest"):
+        x = params["embed"].astype(F32)[tokens]
+        nd = dims["num_dense_layers"]
+        seen = {}                       # (stack, kind) -> layers so far
+        for l, kind in enumerate(dims["layer_types"]):
+            stack, i = ("dense_layers", l) if l < nd else ("layers", l - nd)
+            j = seen.get((stack, kind), 0)
+            seen[stack, kind] = j + 1
+            mine = mixer.get(kind, attn)
+            w = {name: a[j if name in mine else i]
+                 for name, a in params[stack].items()
+                 if name in mine or name not in attn + mixer["conv"]}
+            x = conv_layer(x, w, dims, kind, zero_taps)
+        x = rmsnorm(x, params["ln_f"], dims["norm_eps"])
+        return x @ params["embed"].astype(F32).T

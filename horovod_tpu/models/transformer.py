@@ -46,6 +46,7 @@ Design notes (TPU):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional
 
@@ -57,7 +58,7 @@ from jax.sharding import PartitionSpec as P
 
 
 #: The kinds of layer a ``layer_pattern`` may name.
-LAYER_KINDS = ("full", "sliding")
+LAYER_KINDS = ("full", "sliding", "conv")
 
 
 class UnsupportedModelConfigError(ValueError):
@@ -207,6 +208,22 @@ class TransformerConfig:
     index_n_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
+    # A "conv" layer of the pattern: a gated SHORT CONVOLUTION in the
+    # attention's place — ``[B, C, X] = n W_in`` (``conv_in`` (D, 3D)),
+    # ``u = B * X``, ``v[t] = sum_j k[:, j] u[t - (K - 1) + j]`` with a
+    # depthwise kernel ``conv_k`` (D, K) of ``conv_kernel`` = K taps,
+    # ``(C * v) W_out`` — no keys or values; decoding keeps the last
+    # ``K - 1`` gated inputs ``u`` a layer and request (``conv_taps``).
+    conv_kernel: int = 0
+    # The logits are read against the embedding (no ``head`` leaf).
+    tie_embeddings: bool = False
+    # K/V heads narrower than the TPU's 128 lanes stored side by side:
+    # ``128 / head_dim`` KV heads share one 128-lane row of a page
+    # (:attr:`kv_pack`), so a page pool of 64-wide heads holds no
+    # padding and the fused paged kernel reads whole lane groups.
+    kv_lane_dense: bool = False
+    # ``norm_topk_prob`` divides by ``sum + norm_topk_eps``.
+    norm_topk_eps: float = 0.0
 
     def __post_init__(self):
         mla = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
@@ -244,18 +261,33 @@ class TransformerConfig:
                 "written (one latent pool, every layer full)")
         if self.n_dense_layers and self.has_window:
             raise UnsupportedModelConfigError(
-                "leading dense layers together with a layer pattern are "
+                "leading dense layers together with window layers are "
                 "not written")
         bad = [k for k in self.layer_pattern if k not in LAYER_KINDS]
         if bad:
             raise ValueError(f"unknown layer kind(s) {bad}; expected "
                              f"{LAYER_KINDS}")
-        if self.layer_pattern and self.n_layers % len(self.layer_pattern):
+        if self.layer_pattern and (
+                self.n_layers - self.n_dense_layers) % len(self.layer_pattern):
             raise ValueError(
-                f"n_layers={self.n_layers} is not a whole number of "
-                f"periods of {self.layer_pattern}")
+                f"n_layers={self.n_layers} after {self.n_dense_layers} "
+                "leading dense layers is not a whole number of periods of "
+                f"{self.layer_pattern}")
         if self.has_window and self.window < 1:
             raise ValueError("a 'sliding' layer needs window >= 1")
+        if self.has_conv and self.conv_kernel < 2:
+            raise ValueError("a 'conv' layer needs conv_kernel >= 2 taps")
+        if self.has_conv and (self.has_window or self.latent):
+            raise UnsupportedModelConfigError(
+                "conv layers together with window layers or latent "
+                "attention are not written")
+        if self.kv_lane_dense and (
+                self.latent or self.head_dim >= 128 or 128 % self.head_dim
+                or self.kv_heads % (128 // self.head_dim)):
+            raise ValueError(
+                "kv_lane_dense packs 128 / head_dim whole KV heads into a "
+                f"128-lane row; head_dim={self.head_dim} with "
+                f"{self.kv_heads} KV heads (or a latent cache) does not")
 
     @property
     def head_dim(self) -> int:
@@ -330,6 +362,8 @@ class TransformerConfig:
             out.update(n_group=self.n_group, topk_group=self.topk_group)
         if self.routed_scaling_factor != 1.0:
             out["scale"] = self.routed_scaling_factor
+        if self.norm_topk_eps:
+            out["norm_eps"] = self.norm_topk_eps
         return out
 
     @property
@@ -346,10 +380,32 @@ class TransformerConfig:
         """Does any layer attend a window (two kinds of KV state)?"""
         return "sliding" in self.layer_pattern
 
+    @property
+    def has_conv(self) -> bool:
+        """Is any layer a gated short convolution (a per-request state
+        of ``conv_taps`` inputs beside the KV cache)?"""
+        return "conv" in self.layer_pattern
+
+    @property
+    def conv_taps(self) -> int:
+        """Past gated inputs a conv layer keeps a request: ``K - 1``."""
+        return self.conv_kernel - 1
+
+    @property
+    def layer_kinds(self) -> tuple:
+        """Every layer's kind: the pattern repeated from layer 0 on,
+        through the leading dense layers and the rest alike."""
+        period = self.layer_pattern or ("full",)
+        return tuple(period[l % len(period)] for l in range(self.n_layers))
+
     def kind_count(self, kind: str) -> int:
         """How many of the layers are of one kind."""
-        period = self.layer_pattern or ("full",)
-        return period.count(kind) * (self.n_layers // len(period))
+        return self.layer_kinds.count(kind)
+
+    @property
+    def kv_pack(self) -> int:
+        """KV heads that share one stored row (1 = a row a head)."""
+        return 128 // self.head_dim if self.kv_lane_dense else 1
 
     @property
     def kv_heads(self) -> int:
@@ -363,22 +419,40 @@ class TransformerConfig:
 
 def init_params(rng, cfg: TransformerConfig) -> Dict:
     """Seeded parameters as a checkpoint lays them out: ``embed``,
-    ``head``, ``ln_f`` and ``layers`` stacked on a leading axis.  With
-    ``cfg.n_dense_layers`` the leading dense layers are a stack of
-    their own, ``dense_layers``, and ``layers`` holds the rest."""
+    ``head`` (unless tied), ``ln_f`` and ``layers`` stacked on a
+    leading axis.  With ``cfg.n_dense_layers`` the leading dense layers
+    are a stack of their own, ``dense_layers``, and ``layers`` holds
+    the rest.  In a stack with conv layers the mixer's leaves are
+    stacked BY KIND (:data:`_MIXER_LEAVES`): ``wq``/``wk``/``wv``/
+    ``wo`` (and the q/k norms) over its attention layers, ``conv_in
+    (D, 3D)``/``conv_k (D, K)``/``conv_out (D, D)`` over its conv
+    layers; every other leaf over all of them."""
     keys = jax.random.split(rng, 10)
     D, V = cfg.d_model, cfg.vocab_size
 
     def norm_init(k, shape, scale):
         return (jax.random.normal(k, shape) * scale).astype(jnp.float32)
 
-    def stack(keys, L, experts: bool):
+    def stack(keys, kinds, experts: bool):
+        # ``kinds``: this stack's layers' kinds.  Where some are conv
+        # layers the MIXER's leaves are stacked by kind — attention's
+        # over the attention layers (``La`` of them), ``conv_*`` over
+        # the conv layers — and every other leaf over all ``L``.
+        L = len(kinds)
+        La = L - kinds.count("conv")
         H, Dh = cfg.n_heads, cfg.head_dim
         F = cfg.expert_width if experts else cfg.d_ff
         s_d, s_f = 1.0 / np.sqrt(D), 1.0 / np.sqrt(F)
         layers = {"ln1": jnp.ones((L, D), jnp.float32),
                   "ln2": jnp.ones((L, D), jnp.float32)}
-        if cfg.latent:
+        if La < L:
+            ck = jax.random.split(jax.random.fold_in(keys[0], 11), 3)
+            layers.update(
+                conv_in=norm_init(ck[0], (L - La, D, 3 * D), s_d),
+                conv_k=norm_init(ck[1], (L - La, D, cfg.conv_kernel),
+                                 1.0 / np.sqrt(cfg.conv_kernel)),
+                conv_out=norm_init(ck[2], (L - La, D, D), s_d))
+        if cfg.latent:      # (never beside conv layers: __post_init__)
             ak = jax.random.split(keys[0], 4)
             R, C = cfg.q_lora_rank, cfg.kv_lora_rank
             N, Rp, Vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
@@ -400,15 +474,15 @@ def init_params(rng, cfg: TransformerConfig) -> Dict:
                     i_k_norm=jnp.ones((L, Di), jnp.float32),
                     i_k_bias=jnp.zeros((L, Di), jnp.float32),
                     wi_w=norm_init(ik[2], (L, D, Hi), s_d))
-        else:
+        elif La:
             layers.update(
-                wq=norm_init(keys[0], (L, D, H, Dh), s_d),
-                wk=norm_init(keys[1], (L, D, cfg.kv_heads, Dh), s_d),
-                wv=norm_init(keys[2], (L, D, cfg.kv_heads, Dh), s_d),
-                wo=norm_init(keys[3], (L, H, Dh, D), 1.0 / np.sqrt(H * Dh)))
-        if cfg.qk_norm:
-            layers.update(q_norm=jnp.ones((L, Dh), jnp.float32),
-                          k_norm=jnp.ones((L, Dh), jnp.float32))
+                wq=norm_init(keys[0], (La, D, H, Dh), s_d),
+                wk=norm_init(keys[1], (La, D, cfg.kv_heads, Dh), s_d),
+                wv=norm_init(keys[2], (La, D, cfg.kv_heads, Dh), s_d),
+                wo=norm_init(keys[3], (La, H, Dh, D), 1.0 / np.sqrt(H * Dh)))
+        if cfg.qk_norm and La:
+            layers.update(q_norm=jnp.ones((La, Dh), jnp.float32),
+                          k_norm=jnp.ones((La, Dh), jnp.float32))
         if experts:
             E = cfg.experts_held
             layers.update(
@@ -436,17 +510,18 @@ def init_params(rng, cfg: TransformerConfig) -> Dict:
             )
         return layers
 
+    nd = cfg.n_dense_layers
     params = {
         "embed": norm_init(keys[8], (V, D), 1.0),
-        "layers": stack(keys, cfg.n_layers - cfg.n_dense_layers,
-                        cfg.n_experts > 1),
+        "layers": stack(keys, cfg.layer_kinds[nd:], cfg.n_experts > 1),
         "ln_f": jnp.ones((D,), jnp.float32),
-        "head": norm_init(keys[9], (D, V), 1.0 / np.sqrt(D)),
     }
-    if cfg.n_dense_layers:
+    if not cfg.tie_embeddings:
+        params["head"] = norm_init(keys[9], (D, V), 1.0 / np.sqrt(D))
+    if nd:
         params["dense_layers"] = stack(
             jax.random.split(jax.random.fold_in(rng, 1), 10),
-            cfg.n_dense_layers, False)
+            cfg.layer_kinds[:nd], False)
     return params
 
 
@@ -665,7 +740,7 @@ def _require_uniform(cfg: TransformerConfig, what: str) -> None:
     """Refuse a configuration with more than one kind of layer where
     only the uniform block is written (training, the single-request
     and speculative decode bodies, the pipeline schedules)."""
-    if cfg.has_window:
+    if cfg.has_window or cfg.has_conv:
         raise UnsupportedModelConfigError(
             f"{what} computes one kind of layer; this configuration's "
             f"pattern {cfg.layer_pattern} (window {cfg.window}) is served "
@@ -678,6 +753,8 @@ def _require_no_latent(cfg: TransformerConfig, what: str) -> None:
     the pipeline schedules, training's backward)."""
     for on, name in ((cfg.latent, "latent attention"),
                      (cfg.n_dense_layers, "leading dense layers"),
+                     (cfg.tie_embeddings, "a head tied to the embedding"),
+                     (cfg.kv_lane_dense, "KV heads sharing a stored row"),
                      (cfg.held_offset is not None,
                       "a share of the experts (n_experts_held)")):
         if on:
@@ -688,6 +765,13 @@ def _require_no_latent(cfg: TransformerConfig, what: str) -> None:
 
 
 _EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+
+#: A layer's MIXER leaves by its kind.  In a stack that has conv layers
+#: (and there alone) these are stacked over THAT kind's layers; every
+#: other leaf is stacked over all the layers.
+_ATTN_LEAVES = ("wq", "wk", "wv", "wo", "q_norm", "k_norm")
+_MIXER_LEAVES = {"full": _ATTN_LEAVES, "sliding": _ATTN_LEAVES,
+                 "conv": ("conv_in", "conv_k", "conv_out")}
 
 
 def _scan_layer_kinds(cfg: TransformerConfig, layer, init, layers, xs=None,
@@ -715,30 +799,49 @@ def _scan_layer_kinds(cfg: TransformerConfig, layer, init, layers, xs=None,
 
     ``dense``: the stack of the configuration's LEADING dense layers
     (``params["dense_layers"]``), run before ``layers``; ``xs`` is then
-    stacked over both, in order."""
+    stacked over both, in order.  The pattern runs from layer 0
+    through both stacks (:attr:`TransformerConfig.layer_kinds`).
+
+    In a stack with conv layers a layer's MIXER leaves
+    (:data:`_MIXER_LEAVES`) are stacked over its kind's layers alone,
+    and cut out at the layer's index among its kind."""
     xs = xs or {}
     if dense is not None:
         # the leading dense stack, then the rest, ONE layer index
-        # running through both: what is stacked over the layers (a
-        # landed prefix, the pool's layer indices) is cut where the
+        # running through both: what is stacked over a kind's layers
+        # (a landed prefix, the pool's layer indices) is cut where the
         # stacks meet, and the results are joined there
         nd = cfg.n_dense_layers
+        kinds = cfg.layer_kinds
+        # the pattern as each stack meets it: the dense layers' kinds
+        # as one period, the rest's period rotated to start at ``nd``
+        head, tail = (), ()
+        if cfg.layer_pattern:
+            head, tail = kinds[:nd], kinds[nd:nd + len(cfg.layer_pattern)]
 
-        def cut(tree, a, b):
-            return jax.tree_util.tree_map(lambda x: x[a:b], tree)
+        def cut(front: bool):
+            # a kind's xs, stacked over its layers of BOTH stacks
+            def part(k, x):
+                led = kinds[:nd].count(k)
+                return x[:led] if front else x[led:]
+
+            return {k: jax.tree_util.tree_map(
+                functools.partial(part, k), v) for k, v in xs.items()}
 
         carry, ys_d = _scan_layer_kinds(
             dataclasses.replace(cfg, n_layers=nd, n_dense_layers=0,
-                                n_experts=0, n_experts_held=0),
-            layer, init, dense, cut(xs, 0, nd))
+                                n_experts=0, n_experts_held=0,
+                                layer_pattern=head),
+            layer, init, dense, cut(True))
         carry, ys_e = _scan_layer_kinds(
             dataclasses.replace(cfg, n_layers=cfg.n_layers - nd,
-                                n_dense_layers=0),
-            layer, carry, layers, cut(xs, nd, None))
-        return carry, {k: ys_e[k] if ys_d[k] is None else
+                                n_dense_layers=0, layer_pattern=tail),
+            layer, carry, layers, cut(False))
+        return carry, {k: ys_e[k] if ys_d.get(k) is None else
+                       ys_d[k] if ys_e.get(k) is None else
                        jax.tree_util.tree_map(
                            lambda a, b: jnp.concatenate([a, b]),
-                           ys_d[k], ys_e[k]) for k in ys_e}
+                           ys_d[k], ys_e[k]) for k in {**ys_d, **ys_e}}
     stack = None
     if cfg.n_experts > 1:
         stack = {k: layers[k] for k in _EXPERT_LEAVES}
@@ -762,7 +865,16 @@ def _scan_layer_kinds(cfg: TransformerConfig, layer, init, layers, xs=None,
                  jnp.arange(cfg.n_layers, dtype=jnp.int32)))
         return carry, {kind: ys}
     n = cfg.n_layers // len(period)
-    count = {k: period.count(k) for k in set(period)}
+    # (in the pattern's own order: a set's would move the equations, and
+    # with them the compile cache's key, from one process to the next)
+    count = {k: period.count(k) for k in dict.fromkeys(period)}
+    # with conv layers, each kind's mixer leaves are a stack of its own
+    own = {k: {} for k in count}
+    if cfg.has_conv:
+        own = {k: {m: layers[m] for m in _MIXER_LEAVES[k] if m in layers}
+               for k in count}
+        layers = {m: v for m, v in layers.items()
+                  if not any(m in o for o in own.values())}
 
     def at(tree, l):
         return jax.tree_util.tree_map(
@@ -773,8 +885,12 @@ def _scan_layer_kinds(cfg: TransformerConfig, layer, init, layers, xs=None,
         ys = {k: [] for k in count}
         for i, kind in enumerate(period):
             l = q * len(period) + i
-            xs_l = at(xs.get(kind), q * count[kind] + len(ys[kind]))
-            carry, y = layer(carry, with_stack(at(layers, l), l), kind, xs_l)
+            l_kind = q * count[kind] + len(ys[kind])
+            xs_l = at(xs.get(kind), l_kind)
+            p = at(layers, l)
+            if own[kind]:
+                p = {**p, **at(own[kind], l_kind)}
+            carry, y = layer(carry, with_stack(p, l), kind, xs_l)
             ys[kind].append(y)
         return carry, {k: jax.tree_util.tree_map(
             lambda *a: jnp.stack(a), *v) for k, v in ys.items()}
@@ -836,6 +952,110 @@ def _out_proj(oh, p, cfg: TransformerConfig):
     with jax.named_scope("attn_out"):
         o = jnp.moveaxis(oh, 1, 2).astype(cfg.dtype)  # (B, S, H, Dh)
         return jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(cfg.dtype))
+
+
+def _pack_heads(x, n: int):
+    """``(..., H_kv, T, Dh)`` as a pool with ``n`` KV heads a stored
+    row holds it, ``(..., H_kv / n, T, n * Dh)``: heads ``n j .. n j +
+    n - 1`` side by side in row ``j``'s lanes
+    (:attr:`TransformerConfig.kv_pack`; ``n == 1``: as it is)."""
+    if n == 1:
+        return x
+    *lead, hkv, t, dh = x.shape
+    x = jnp.moveaxis(x.reshape(*lead, hkv // n, n, t, dh), -3, -2)
+    return x.reshape(*lead, hkv // n, t, n * dh)
+
+
+def _unpack_heads(x, n: int):
+    """:func:`_pack_heads` undone."""
+    if n == 1:
+        return x
+    *lead, rows, t, w = x.shape
+    x = jnp.moveaxis(x.reshape(*lead, rows, t, n, w // n), -2, -3)
+    return x.reshape(*lead, rows * n, t, w // n)
+
+
+# --- the gated short convolution (a "conv" layer's mixer) --------------------
+#
+# ``[B, C, X] = n W_in``; ``u = B * X``; ``v[t] = sum_j k[:, j] u[t - (K -
+# 1) + j]`` (depthwise, causal, ``u[s] = 0`` before the sequence); the
+# mixer's output ``(C * v) W_out``.  No activation, no bias.  What a
+# request carries from one position to the next is its last ``K - 1``
+# gated inputs ``u`` — a STATE of fixed size a layer, where an attention
+# layer's grows with the context.  Three scopes cut it out of a trace:
+# ``hvd_conv_in`` (the norm and W_in), ``hvd_conv_scan`` (a prompt's or
+# a chunk's convolution) / ``hvd_conv_update`` (a tick's one position),
+# ``hvd_conv_out`` (the gate and W_out).
+
+
+def _conv_in(x, p, cfg: TransformerConfig):
+    """``(C, u)`` of the layer's input ``x`` ``(B, S, D)``, in FLOAT32
+    from the projection's accumulator on: the mixer is a product of
+    three of its outputs, and nothing averages a product's rounding as
+    a softmax averages a score's.  Where a backend rounds ``B``, ``X``,
+    ``u``, ``v`` and ``C * v`` each to bfloat16 (XLA:CPU does; XLA:TPU
+    keeps a fused chain in float32 anyway, so the chip's reading did
+    not move) a served token's logit lay several times as far from the
+    float32 reference's (PERF.md, PR 40).  The state a request keeps is
+    rounded once, where it is stored."""
+    with jax.named_scope("hvd_conv_in"):
+        n = _rmsnorm(x, p["ln1"], cfg.norm_eps)
+        bcx = jnp.einsum("bsd,dn->bsn", n, p["conv_in"].astype(cfg.dtype),
+                         preferred_element_type=jnp.float32)
+        b, c, xx = jnp.split(bcx, 3, axis=-1)
+        return c, b * xx
+
+
+def _conv_taps(ext, p, n: int):
+    """``sum_j k[:, j] ext[:, j : j + n]`` in float32: the ``n``
+    outputs whose ``K`` inputs ``ext`` ``(B, n + K - 1, D)`` holds."""
+    k = p["conv_k"].astype(jnp.float32)
+    return sum(ext[:, j:j + n] * k[:, j] for j in range(k.shape[1]))
+
+
+def _conv_out(c, v, p, cfg: TransformerConfig):
+    with jax.named_scope("hvd_conv_out"):
+        return jnp.einsum("bsd,de->bse", (c * v).astype(cfg.dtype),
+                          p["conv_out"].astype(cfg.dtype))
+
+
+def _conv_prefill(x, p, cfg: TransformerConfig, state, true_len):
+    """A conv layer's mixer over a prompt or a chunk ``x`` ``(B, S,
+    D)``: ``(output, new state)``.  ``state`` ``(B, K - 1, D)`` is the
+    request's ``u`` at the ``K - 1`` positions before ``x`` (None: the
+    zeros a sequence starts from); the new state its ``u`` at the
+    ``K - 1`` positions before ``true_len`` ``(B,)`` — out of ``state``
+    where the row is shorter than that, never from the padding."""
+    c, u = _conv_in(x, p, cfg)
+    B, S, D = u.shape
+    taps = cfg.conv_taps
+    with jax.named_scope("hvd_conv_scan"):
+        if state is None:
+            state = jnp.zeros((B, taps, D), u.dtype)
+        ext = jnp.concatenate([state.astype(u.dtype), u], axis=1)
+        v = _conv_taps(ext, p, S)
+        # ext[i] is position i - taps: the state ends at position len - 1
+        idx = true_len[:, None] + jnp.arange(taps, dtype=jnp.int32)
+        new = jnp.take_along_axis(ext, idx[:, :, None], axis=1)
+    return _conv_out(c, v, p, cfg), new.astype(cfg.dtype)
+
+
+def _conv_decode(x, p, cfg: TransformerConfig, states, layer, active):
+    """A conv layer's mixer for one token a slot, ``x`` ``(S, 1, D)``:
+    ``(output, states)`` with ``states`` ``(L_conv, S, K - 1, D)``, every
+    conv layer's and slot's, read and written IN PLACE at ``layer`` (a
+    layer scan's loop state, like a page pool).  A row that is not
+    ``active`` — idle, or a prompt between two of its chunks — keeps
+    its state."""
+    c, u = _conv_in(x, p, cfg)
+    with jax.named_scope("hvd_conv_update"):
+        old = lax.dynamic_index_in_dim(states, layer, 0, keepdims=False)
+        ext = jnp.concatenate([old.astype(u.dtype), u], axis=1)
+        v = _conv_taps(ext, p, 1)
+        new = jnp.where(active[:, None, None], ext[:, 1:].astype(old.dtype),
+                        old)
+        states = lax.dynamic_update_index_in_dim(states, new, layer, 0)
+    return _conv_out(c, v, p, cfg), states
 
 
 # --- latent attention (MLA) ---------------------------------------------------
@@ -1417,12 +1637,20 @@ def _remat(layer, cfg: TransformerConfig):
                      "expected 'full' or 'dots'")
 
 
+def _head(params: Dict, cfg: TransformerConfig):
+    """The vocabulary projection's weight as :func:`_lm_head` takes it:
+    ``head`` ``(D, V)``, or the embedding ``(V, D)`` of a tied model."""
+    return params["embed"] if cfg.tie_embeddings else params["head"]
+
+
 def _lm_head(y, ln_f, head, cfg: TransformerConfig):
     """Final RMSNorm + vocabulary projection (f32 logits) — the ONE copy
-    shared by forward, decode/prefill, and both pipeline schedules."""
+    shared by forward, decode/prefill, and both pipeline schedules.
+    ``head``: :func:`_head`'s."""
     with jax.named_scope("head"):
         h = _rmsnorm(y, ln_f, cfg.norm_eps)
-        return jnp.einsum("bsd,dv->bsv", h, head.astype(cfg.dtype)).astype(
+        how = "bsd,vd->bsv" if cfg.tie_embeddings else "bsd,dv->bsv"
+        return jnp.einsum(how, h, head.astype(cfg.dtype)).astype(
             jnp.float32)
 
 
@@ -1462,9 +1690,9 @@ def forward(params: Dict, tokens, cfg: TransformerConfig,
         if stack in params:
             carry, _ = _scan_layers(layer, carry, params[stack])
     if return_aux:
-        return _lm_head(carry[0], params["ln_f"], params["head"],
+        return _lm_head(carry[0], params["ln_f"], _head(params, cfg),
                         cfg), carry[1]
-    return _lm_head(carry, params["ln_f"], params["head"], cfg)
+    return _lm_head(carry, params["ln_f"], _head(params, cfg), cfg)
 
 
 def loss_fn(params: Dict, batch: Dict, cfg: TransformerConfig):
@@ -1791,7 +2019,7 @@ def decode_step(params: Dict, tokens_t, cache: Dict, cfg: TransformerConfig):
         cfg, layer, x, params["layers"],
         {"full": tuple(cache.get(n) for n in cfg.pool_arrays)},
         params.get("dense_layers"))
-    logits = _lm_head(x, params["ln_f"], params["head"], cfg)
+    logits = _lm_head(x, params["ln_f"], _head(params, cfg), cfg)
     out = {n: a for n, a in zip(cfg.pool_arrays, ys["full"]) if n}
     out["pos"] = pos + 1
     return logits[:, 0], out
@@ -1878,9 +2106,12 @@ def _paged_kernel_attend(qg, k_pool, v_pool, k_scale, v_scale, layer,
 
     quantized = k_scale is not None
     if mesh is None:
+        # rows that several heads share are scaled by the HEAD's width
+        kw = ({"sm_scale": cfg.head_dim ** -0.5} if cfg.kv_pack > 1
+              else {})
         return _pa.paged_attend(qg, k_pool, v_pool, k_scale, v_scale,
                                 table, limit, compute_dtype=cfg.dtype,
-                                lower=lower, layer=layer)
+                                lower=lower, layer=layer, **kw)
     if lower is not None:
         raise UnsupportedModelConfigError(
             "a window layer's paged kernel is not written for a tp mesh")
@@ -1963,7 +2194,8 @@ def _attention_decode_paged(x, p, cfg: TransformerConfig, kv, layer, table,
     else:
         qh, k_t, v_t = _qkv_proj(x, p, cfg, positions=pos[:, None],
                                  kind=kind)
-        rows = (k_t, v_t)                  # each (S, H_kv, 1, Dh)
+        # each (S, H_kv, 1, Dh), as the pool stores a row
+        rows = (_pack_heads(k_t, cfg.kv_pack), _pack_heads(v_t, cfg.kv_pack))
     lower = (jnp.maximum(pos - cfg.window + 1, 0) if kind == "sliding"
              else None)
     with jax.named_scope("kv_write"):
@@ -2025,14 +2257,27 @@ def _paged_decode_attend(qh, k_pool, v_pool, k_scale, v_scale, layer, table,
         # zeroed for inactive rows so their (NULL-page-routed) writes
         # are never attended.
         limit = jnp.where(active, pos + 1, 0)
-        Hkv = k_pool.shape[2]
+        Hkv, n = k_pool.shape[2], cfg.kv_pack
         qg = qh.reshape(B, Hkv, H // Hkv, Dh)
+        if n > 1:
+            # a stored row is n heads' side by side: head a's queries
+            # lie in ITS lanes, zeros in the others', so the row's
+            # product is that head's alone — and of the output's lanes
+            # each query reads its own head's
+            G = H // (Hkv * n)
+            lane = jnp.eye(n, dtype=qh.dtype)[:, None, :, None]
+            qg = (qg.reshape(B, Hkv, n, G, 1, Dh) * lane).reshape(
+                B, Hkv, n * G, n * Dh)
         o, _ = _paged_kernel_attend(qg, k_pool, v_pool, k_scale, v_scale,
                                     layer, table, limit, cfg, mesh, lower)
+        if n > 1:
+            o = o.reshape(B, Hkv, n, G, n, Dh)
+            o = jnp.stack([o[:, :, a, :, a] for a in range(n)], axis=2)
         o = o.reshape(B, H, 1, Dh)
     else:
         kg, vg = _gather_kv(k_pool, v_pool, k_scale, v_scale, layer, table,
                             cfg)
+        kg, vg = (_unpack_heads(a, cfg.kv_pack) for a in (kg, vg))
         T = max_pages * ps
         col = lax.broadcasted_iota(jnp.int32, (T,), 0)[None, :]
         mask = col <= pos[:, None]
@@ -2057,15 +2302,16 @@ def _gather_kv(k_pool, v_pool, k_scale, v_scale, layer, table,
 
 
 _POOL_ARRAYS = {"full": ("k", "v", "k_scale", "v_scale", "ik"),
-                "sliding": ("wk", "wv")}
+                "sliding": ("wk", "wv"), "conv": ("conv",)}
 
 
 def _kind_pools(pool: Dict, cfg: TransformerConfig):
     """The names of a paged pool's stacked arrays by layer kind — the
-    full layers' ``k``/``v`` (with their scales when quantized) and, for
-    a configuration with window layers, those layers' own ``wk``/``wv``
-    — for the kinds this configuration HAS: a uniform model carries no
-    second stack, a model of window layers alone no first."""
+    full layers' ``k``/``v`` (with their scales when quantized), for
+    a configuration with window layers those layers' own ``wk``/``wv``,
+    for one with conv layers their per-slot state ``conv`` — for the
+    kinds this configuration HAS: a uniform model carries no second
+    stack, a model of window layers alone no first."""
     quantized = "k_scale" in pool
     if cfg.has_window and (quantized or "wk" not in pool):
         raise UnsupportedModelConfigError(
@@ -2117,7 +2363,10 @@ def decode_step_paged(params: Dict, tokens_t, pool: Dict, table,
     kinds of KV state: ``pool["k"]``/``["v"]`` and ``table`` are the
     FULL layers' (stacked over those layers alone), ``pool["wk"]``/
     ``["wv"]`` and ``wtable`` the window layers', whose pages behind
-    ``pos - window`` may be released.  An expert model routes each
+    ``pos - window`` may be released.  One with conv layers
+    (``cfg.has_conv``) holds their per-slot state beside the pages,
+    ``pool["conv"]`` ``(L_conv, S, K - 1, D)``, read and written in
+    place like them (:func:`_conv_decode`).  An expert model routes each
     active row to its ``n_experts_per_tok`` experts through the
     dropless grouped products — ``S * k`` expert rows a tick, idle
     slots none; ``return_moe_load`` adds :func:`moe_load` of the tick
@@ -2142,11 +2391,15 @@ def decode_step_paged(params: Dict, tokens_t, pool: Dict, table,
     # would cut every layer out of the stack and stack it back.
     def layer(carry, p, kind, i):
         x, pools = carry
-        h, kv = _attention_decode_paged(
-            _attn_norm(x, p, cfg), p, cfg,
-            tuple(pools[n] for n in names[kind]), i,
-            wtable if kind == "sliding" else table, pos, active,
-            kernel=kernel, mesh=mesh, kind=kind)
+        if kind == "conv":      # its state: the slots' last inputs
+            h, conv = _conv_decode(x, p, cfg, pools["conv"], i, active)
+            kv = (conv,)
+        else:
+            h, kv = _attention_decode_paged(
+                _attn_norm(x, p, cfg), p, cfg,
+                tuple(pools[n] for n in names[kind]), i,
+                wtable if kind == "sliding" else table, pos, active,
+                kernel=kernel, mesh=mesh, kind=kind)
         pools = {**pools, **dict(zip(names[kind], kv))}
         if not moe or "router" not in p:   # ... or a leading dense layer
             return (_mlp_block(x + h, p, cfg), pools), None
@@ -2160,7 +2413,7 @@ def decode_step_paged(params: Dict, tokens_t, pool: Dict, table,
         params["layers"],
         {kind: jnp.arange(cfg.kind_count(kind), dtype=jnp.int32)
          for kind in names}, params.get("dense_layers"))
-    logits = _lm_head(x, params["ln_f"], params["head"], cfg)
+    logits = _lm_head(x, params["ln_f"], _head(params, cfg), cfg)
     out = {**pools, "pos": pos + active.astype(jnp.int32)}
     if not return_moe_load:
         return logits[:, 0], out
@@ -2463,13 +2716,16 @@ def _by_kind(ys: Dict, pos, full=("k", "v")) -> Dict:
         out.update((n, a) for n, a in zip(full, ys["full"]) if n)
     if "sliding" in ys:
         out["wk"], out["wv"] = ys["sliding"]
+    if "conv" in ys:      # (L_conv, B, K - 1, D): the rows' new state
+        out["conv"] = ys["conv"]
     return out
 
 
 def prefill_with_prefix(params: Dict, suffix, prefix_k, prefix_v,
                         prefix_len, cfg: TransformerConfig, *,
                         true_len, moe_impl: str = "dropless",
-                        win_k=None, win_v=None, win_start=0):
+                        win_k=None, win_v=None, win_start=0,
+                        conv_state=None):
     """Prefill a (K, S0) SUFFIX whose first ``prefix_len`` logical
     positions already exist as cached K/V — the prefix-sharing prefill:
     a registered system prompt is prefilled ONCE, and every request
@@ -2509,7 +2765,15 @@ def prefill_with_prefix(params: Dict, suffix, prefix_k, prefix_v,
     index keys ``(L, 1, P0, index_head_dim)``, the block carries ``ik``
     beside ``k``, and once the chunk's last query sees more than
     ``index_topk`` positions each query attends its selected rows,
-    absorbed (:func:`_dsa_chunk_attend`)."""
+    absorbed (:func:`_dsa_chunk_attend`).
+
+    With conv layers (``cfg.has_conv``) ``prefix_k``/``prefix_v`` hold
+    the attention layers alone and ``conv_state`` ``(L_conv, K, taps,
+    D)`` each row's state at ``prefix_len`` — its last ``taps`` gated
+    inputs, as the chunk before left them; the returned block carries
+    the state at ``prefix_len + true_len`` as ``conv``.  A pool whose
+    rows several KV heads share (``cfg.kv_pack``) hands the prefix over
+    as it stores it."""
     K, S0 = suffix.shape
     P0 = prefix_k.shape[2]
     p0 = jnp.asarray(prefix_len, jnp.int32)
@@ -2542,10 +2806,13 @@ def prefill_with_prefix(params: Dict, suffix, prefix_k, prefix_v,
                                   {"full": (prefix_k, prefix_v)},
                                   params.get("dense_layers"))
         last = jnp.take_along_axis(x, (true_len - 1)[:, None, None], axis=1)
-        logits = _lm_head(last, params["ln_f"], params["head"], cfg)
+        logits = _lm_head(last, params["ln_f"], _head(params, cfg), cfg)
         return logits[:, 0], _by_kind(ys, p0 + true_len, cfg.pool_arrays)
     H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     G = H // Hkv
+    if cfg.kv_pack > 1:
+        prefix_k, prefix_v = (_unpack_heads(a, cfg.kv_pack)
+                              for a in (prefix_k, prefix_v))
     # (S0, P0 + S0) mask: the real prefix is fully visible, page-tail
     # junk (>= p0) never, and the suffix is causal within itself.
     pre_vis = lax.broadcasted_iota(jnp.int32, (P0,), 0)[None, :] < p0
@@ -2569,8 +2836,13 @@ def prefill_with_prefix(params: Dict, suffix, prefix_k, prefix_v,
         masks["sliding"] = jnp.concatenate(
             [wpre, suf_vis & (rows - cols < W)], axis=1)[None, None, None]
         xs["sliding"] = (win_k, win_v)
+    if cfg.has_conv:
+        xs["conv"] = conv_state
 
     def layer(x, p, kind, kv):
+        if kind == "conv":      # kv: the rows' state, (K, taps, D)
+            out, new = _conv_prefill(x, p, cfg, kv, true_len)
+            return _mlp_block(x + out, p, cfg, moe_impl=moe_impl), new
         pk, pv = kv
         P0, mask = pk.shape[1], masks[kind]
         h = _attn_norm(x, p, cfg)
@@ -2599,7 +2871,7 @@ def prefill_with_prefix(params: Dict, suffix, prefix_k, prefix_v,
     x, ys = _scan_layer_kinds(cfg, layer, x, params["layers"], xs,
                               params.get("dense_layers"))
     last = jnp.take_along_axis(x, (true_len - 1)[:, None, None], axis=1)
-    logits = _lm_head(last, params["ln_f"], params["head"], cfg)
+    logits = _lm_head(last, params["ln_f"], _head(params, cfg), cfg)
     return logits[:, 0], _by_kind(ys, p0 + true_len)
 
 
@@ -2684,7 +2956,10 @@ def prefill(params: Dict, prompt, cache: Dict, cfg: TransformerConfig,
     With window layers (``cfg.has_window``) the K/V come back BY KIND —
     ``k``/``v`` stacked over the full layers, ``wk``/``wv`` over the
     window layers, each ``(L_kind, B, H_kv, S0, Dh)`` — for a paged
-    engine's two pools; ``cache`` then only gives ``pos``."""
+    engine's two pools; ``cache`` then only gives ``pos``.  So with
+    conv layers (``cfg.has_conv``): ``k``/``v`` over the attention
+    layers and ``conv`` ``(L_conv, B, taps, D)``, each row's state at
+    its ``true_len``."""
     pos = cache["pos"]
     if not isinstance(pos, jax.core.Tracer) and int(pos) != 0:
         raise ValueError("prefill requires a fresh cache (pos == 0)")
@@ -2695,8 +2970,15 @@ def prefill(params: Dict, prompt, cache: Dict, cfg: TransformerConfig,
             f"prompt ({S0} tokens) exceeds cache capacity ({T_cache}); "
             "init_cache with a larger max_len")
     x = _embed(params, prompt, cfg)
+    if cfg.has_conv:            # each row's real length, for its state
+        lens = jnp.broadcast_to(jnp.asarray(
+            S0 if true_len is None else true_len, jnp.int32),
+            prompt.shape[:1])
 
     def layer(x, p, kind, _):
+        if kind == "conv":      # from the zeros a sequence starts from
+            h, new = _conv_prefill(x, p, cfg, None, lens)
+            return _mlp_block(x + h, p, cfg, moe_impl=moe_impl), new
         h, kh, vh = _attention_prefill(_attn_norm(x, p, cfg), p, cfg, mesh,
                                        kind)
         # Prefill ingests whole prompts: DROPLESS grouped-matmul dispatch
@@ -2723,10 +3005,10 @@ def prefill(params: Dict, prompt, cache: Dict, cfg: TransformerConfig,
         last = jnp.take_along_axis(x, (true_len - 1)[:, None, None],
                                    axis=1)
         new_pos = pos + true_len
-    logits = _lm_head(last, params["ln_f"], params["head"], cfg)
-    if cfg.has_window:
-        # two kinds of KV state: handed back by kind for the caller's
-        # two pools, not landed in a cache of one shape
+    logits = _lm_head(last, params["ln_f"], _head(params, cfg), cfg)
+    if cfg.has_window or cfg.has_conv:
+        # two kinds of state: handed back by kind for the caller's
+        # pools, not landed in a cache of one shape
         return logits[:, 0], _by_kind(ys, new_pos)
     blocks = dict(zip(cfg.pool_arrays, ys[next(iter(ys))]))
     with jax.named_scope("kv_land"):

@@ -375,11 +375,12 @@ def grouped_matmul(xs, w, layer, counts):
 
 def route_topk(xt, router, k: int = 1, norm_topk: bool = False, *,
                score: str = "softmax", n_group: int = 0, topk_group: int = 0,
-               scale: float = 1.0, bias=None):
+               scale: float = 1.0, bias=None, norm_eps: float = 0.0):
     """Routing in float32: ``(experts (T, k) int32, weights (T, k)
     float32)`` — the ``k`` largest scores of ``x @ router``,
-    renormalised to sum to one when ``norm_topk``, times ``scale``
-    (a published ``routed_scaling_factor``).
+    renormalised to sum to one when ``norm_topk`` (divided by ``sum +
+    norm_eps`` where a model publishes one), times ``scale`` (a
+    published ``routed_scaling_factor``).
 
     ``score``: ``"softmax"`` over the experts, or ``"sigmoid"`` of each
     expert's logit alone.  ``n_group`` > 1 limits the choice by GROUPS
@@ -421,7 +422,8 @@ def route_topk(xt, router, k: int = 1, norm_topk: bool = False, *,
         if choice is not probs:
             gate = jnp.take_along_axis(probs, e, axis=-1)
         if norm_topk:
-            gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+            total = jnp.sum(gate, axis=-1, keepdims=True)
+            gate = gate / (total + norm_eps if norm_eps else total)
     if scale != 1.0:
         gate = gate * scale
     return e.astype(jnp.int32), gate

@@ -493,7 +493,7 @@ def paged_attend_reference(qg, k_pool, v_pool, k_scale, v_scale, table,
 
 
 def paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table, limit, *,
-                 compute_dtype=None, lower=None, layer=None):
+                 compute_dtype=None, lower=None, layer=None, sm_scale=None):
     """Fused decode attention directly against a paged KV pool.
 
     Args:
@@ -521,6 +521,13 @@ def paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table, limit, *,
       layer: int32 scalar (traced in a layer scan) — which layer of the
         stacked pool to attend.  The kernel reads ``pool[layer, page]``
         in place; the caller never slices the layer out.
+      sm_scale: the scores' scale where it is not ``Dh ** -0.5`` of the
+        STORED row: a pool whose 128-lane rows hold two 64-wide heads
+        side by side (``TransformerConfig.kv_lane_dense``) is attended
+        as ``H_kv / 2`` heads of 128 — head ``a``'s queries zero outside
+        its lanes, so a row's product is that head's alone, and of the
+        output's lanes each query reads its own head's — at ``64 **
+        -0.5``.  The kernel is the one heads of 128 run.
 
     Returns:
       ``(o, lse)``: ``o`` ``(S, H_kv, R, Dh)`` f32 attention output
@@ -536,7 +543,8 @@ def paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table, limit, *,
         if k_scale is not None:
             k_scale, v_scale = k_scale[None], v_scale[None]
     return _pallas_paged_attend(qg, k_pool, v_pool, k_scale, v_scale,
-                                table, limit, compute_dtype, lower, layer)
+                                table, limit, compute_dtype, lower, layer,
+                                sm_scale=sm_scale)
 
 
 def mla_decode(q, pool, table, limit, *, v_dim: int, sm_scale: float,

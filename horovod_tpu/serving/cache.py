@@ -23,6 +23,16 @@ before first attending it (``tests/test_paged.py`` exercises it).
 Every write into the pool, from the tick, the speculative verify and
 the landing alike, is :func:`write_pages`.
 
+A model with conv layers (gated short convolutions) keeps a SECOND kind
+of per-request state under the same manager: ``conv`` ``(L_conv, S,
+taps, D)``, every conv layer's last ``taps`` gated inputs of every SLOT
+— fixed in size where a slot's pages grow — beside the page arrays in
+the pool dict, so it is donated, carried and written in place with
+them.  It is granted with the slot and ZEROED then (:meth:`PagedSlotCache
+.alloc`), written by the tick for the active rows and by a landing for
+the landed rows (:func:`paged_insert`), and read back for a prompt's
+next chunk (:meth:`PagedSlotCache.slot_state`).
+
 (Until PR 28 a slot-contiguous ``(L, S, H_kv, T, Dh)`` cache stood
 beside this one; no workload ran it.)
 """
@@ -97,12 +107,20 @@ def init_page_pool(cfg: "T.TransformerConfig", n_slots: int, n_pages: int,
             pool["ik"] = jnp.zeros((L, n_pages, 1, page_size,
                                     cfg.index_head_dim), dt)
         return pool
-    Hkv, Dh = cfg.kv_heads, cfg.head_dim
+    # a row a KV head, or ``kv_pack`` narrow heads side by side in one
+    Hkv, Dh = cfg.kv_heads // cfg.kv_pack, cfg.head_dim * cfg.kv_pack
     pool = {
         "k": jnp.zeros((L, n_pages, Hkv, page_size, Dh), dt),
         "v": jnp.zeros((L, n_pages, Hkv, page_size, Dh), dt),
         "pos": jnp.zeros((n_slots,), jnp.int32),
     }
+    if cfg.has_conv:
+        if quant or cfg.kind_count("full") != L:
+            raise T.UnsupportedModelConfigError(
+                "a conv model's pool is its attention layers' pages, "
+                "unquantized, and its conv layers' state")
+        pool["conv"] = jnp.zeros((cfg.kind_count("conv"), n_slots,
+                                  cfg.conv_taps, cfg.d_model), dt)
     if quant:
         pool["k_scale"] = jnp.zeros((L, n_pages, Hkv, page_size),
                                     jnp.float32)
@@ -162,7 +180,8 @@ def landing_pages(bucket: int, page_size: int) -> int:
 
 @jax.named_scope("kv_land")  # T.DEVICE_SCOPES
 def paged_insert(pool: Dict, slots, new_pos, pages, first, lens,
-                 prefilled_k, prefilled_v=None, prefilled_ik=None) -> Dict:
+                 prefilled_k, prefilled_v=None, prefilled_ik=None,
+                 prefilled_conv=None) -> Dict:
     """Land a prefilled K/V block ``(L, K, H_kv, Tb, Dh)`` into pages.
     Column ``t`` of row ``i`` is logical position ``start + t``; with
     ``first = start % page`` it goes to offset ``(first + t) % page``
@@ -179,7 +198,10 @@ def paged_insert(pool: Dict, slots, new_pos, pages, first, lens,
     :func:`write_pages`.  A latent pool has ``k`` alone
     (``prefilled_v`` None): the block is the latent rows; a sparse
     model's index keys ``prefilled_ik`` land in ``ik`` at the same
-    pages and offsets."""
+    pages and offsets.  A pool whose rows several narrow KV heads share
+    takes the block a row a head and lays them side by side.  A conv
+    model's ``prefilled_conv`` ``(L_conv, K, taps, D)`` — each row's
+    state at its new position — replaces its slot's."""
     ps = pool["k"].shape[3]
     L, n_pg = pool["k"].shape[0], pages.shape[1]
     first = jnp.asarray(first, jnp.int32)
@@ -193,6 +215,9 @@ def paged_insert(pool: Dict, slots, new_pos, pages, first, lens,
 
     out = dict(pool)
     k, v = prefilled_k, prefilled_v
+    pack = pool["k"].shape[-1] // k.shape[-1]
+    if pack > 1:
+        k, v = T._pack_heads(k, pack), T._pack_heads(v, pack)
     if "k_scale" in pool:
         k, sk = T.kv_quantize(k)
         v, sv = T.kv_quantize(v)
@@ -202,6 +227,9 @@ def paged_insert(pool: Dict, slots, new_pos, pages, first, lens,
         out["v"] = land("v", v)
     if prefilled_ik is not None:
         out["ik"] = land("ik", prefilled_ik)
+    if prefilled_conv is not None:
+        out["conv"] = pool["conv"].at[:, slots].set(
+            prefilled_conv.astype(pool["conv"].dtype))
     out["pos"] = pool["pos"].at[slots].set(new_pos)
     return out
 
@@ -328,6 +356,13 @@ class PagedSlotCache:
         self._set_pos = jax.jit(
             lambda pool, s, v: {**pool, "pos": pool["pos"].at[s].set(v)},
             donate_argnums=(0,))
+        # a conv model's per-slot state: zeroed with the grant, read
+        # back (L_conv, 1, taps, D) for a prompt's next chunk
+        self._zero_state = jax.jit(
+            lambda pool, s: {**pool, "conv": pool["conv"].at[:, s].set(0)},
+            donate_argnums=(0,))
+        self._slot_state = jax.jit(
+            lambda pool, s: lax.dynamic_slice_in_dim(pool["conv"], s, 1, 1))
 
     # -- slot allocation: lowest free index first, O(log S) an op ------------
 
@@ -337,6 +372,10 @@ class PagedSlotCache:
             return None
         slot = heapq.heappop(self._free)
         self._active[slot] = True
+        if "conv" in self.cache:
+            # a request starts from zeros, whatever the last tenant (or
+            # a tick still in flight for it) left here
+            self.cache = self._zero_state(self.cache, np.int32(slot))
         return slot
 
     def acquire(self, slot: int) -> None:
@@ -425,6 +464,14 @@ class PagedSlotCache:
         if self.quantized:
             b += 2 * n * 4  # f32 scale per (layer, head, token) vector
         return b
+
+    @property
+    def conv_state_bytes_per_slot(self) -> int:
+        """What a slot holds beside its pages, whatever its context:
+        every conv layer's last ``taps`` gated inputs (0: no conv
+        layer)."""
+        a = self.cache.get("conv")
+        return 0 if a is None else a.nbytes // self.n_slots
 
     @property
     def latent_bytes_per_token(self) -> int:
@@ -592,7 +639,7 @@ class PagedSlotCache:
             self._land_pages(rows, start, true_lens, bucket),
             np.int32(start % self.page_size),
             np.asarray(true_lens, np.int32), prefilled["k"],
-            prefilled.get("v"), prefilled.get("ik"))
+            prefilled.get("v"), prefilled.get("ik"), prefilled.get("conv"))
 
     def land(self, slots: Sequence[int], prefilled: Dict,
              true_lens, start: int = 0) -> None:
@@ -623,6 +670,12 @@ class PagedSlotCache:
         self.cache = self._set_pos(
             self.cache, np.asarray(slots, np.int32),
             np.asarray(vals, np.int32))
+
+    def slot_state(self, slot: int):
+        """A conv model's state of one slot, ``(L_conv, 1, taps, D)``,
+        as :func:`~horovod_tpu.models.transformer.prefill_with_prefix`
+        takes it for the slot's next chunk."""
+        return self._slot_state(self.cache, np.int32(slot))
 
     def gather_prefix(self, pages: Sequence[int]):
         """Contiguous ``(k, v)`` for a shared prefix's pages (see

@@ -619,6 +619,25 @@ class InferenceEngine:
             raise ValueError(
                 f"prefill_chunk_tokens must be >= 1 (or 0 to disable), "
                 f"got {engine_cfg.prefill_chunk_tokens}")
+        if cfg.has_conv or cfg.kv_pack > 1:
+            # A conv layer's per-slot state, and rows that narrow KV
+            # heads share, are written for the paged single-chip tick
+            # and the prefills: every other mode refuses them by name.
+            what = ("conv layers (a per-slot state beside the pages)"
+                    if cfg.has_conv else
+                    "KV heads sharing a stored row (kv_lane_dense)")
+            refused = [why for on, why in (
+                (engine_cfg.tp > 1, "tp > 1"),
+                (self._spec, "speculative=True (a rejected draft would "
+                 "have to roll a state back)" if cfg.has_conv
+                 else "speculative=True"),
+                (resolve_kv_dtype(cfg, engine_cfg.kv_dtype)[1],
+                 "kv_dtype='int8'"),
+            ) if on]
+            if refused:
+                raise T.UnsupportedModelConfigError(
+                    f"a configuration with {what} is not served with "
+                    + ", ".join(refused))
         if cfg.latent or cfg.n_dense_layers or cfg.held_offset is not None:
             # Latent attention (with or without an indexer's sparse
             # selection), leading dense layers and a chip's share of
@@ -770,7 +789,7 @@ class InferenceEngine:
         layouts = [(self.slots._storage_dtype, engine_cfg.page_size,
                     cfg.latent_row, cfg.kv_lora_rank) if cfg.latent else
                    (self.slots._storage_dtype, engine_cfg.page_size,
-                    cfg.head_dim)]
+                    cfg.head_dim * cfg.kv_pack)]
         if self._spec_model:
             layouts.append((draft_cfg.dtype, engine_cfg.page_size,
                             draft_cfg.head_dim))
@@ -965,8 +984,9 @@ class InferenceEngine:
                 self.slots.page_size, 1, cfg.latent_row,
                 self.slots._storage_dtype, self.slots.max_pages, True)
             if cfg.latent else _pa.block_pages(
-                self.slots.page_size, cfg.kv_heads // engine_cfg.tp,
-                cfg.head_dim, self.slots._storage_dtype,
+                self.slots.page_size,
+                cfg.kv_heads // cfg.kv_pack // engine_cfg.tp,
+                cfg.head_dim * cfg.kv_pack, self.slots._storage_dtype,
                 self.slots.max_pages))
         # ... and one step of a sparse model's index walk
         self._index_block_tokens = self.slots.page_size * (
@@ -984,7 +1004,8 @@ class InferenceEngine:
             self._prefill_traces += 1
             obs_tracing.record_compile("serving_prefill")
             pk, pv, *win = prefix
-            kw = dict(zip(("win_k", "win_v", "win_start"), win))
+            kw = dict(zip(("conv_state",) if cfg.has_conv else
+                          ("win_k", "win_v", "win_start"), win))
             return T.prefill_with_prefix(
                 params, padded, pk, pv, p0, self.cfg, true_len=lens,
                 **kw)
@@ -1406,6 +1427,11 @@ class InferenceEngine:
             raise T.UnsupportedModelConfigError(
                 "prefix sharing is not written for sparse attention (an "
                 "indexer's keys beside the latent rows)")
+        if self.cfg.has_conv:
+            raise T.UnsupportedModelConfigError(
+                "prefix sharing is not written for conv layers (a sharer "
+                "would need the state as it stood at the prefix's end: a "
+                "snapshot a page boundary)")
         tokens = tuple(int(t) for t in tokens)
         if not tokens:
             raise ServingError("empty prefix")
@@ -2819,6 +2845,9 @@ class InferenceEngine:
                 pages + [NULL_PAGE] * (padded - len(pages)))
 
         prefix = gather(self.slots, 0)
+        if self.cfg.has_conv:
+            # ... and the conv layers' state as the last chunk left it
+            prefix += (self.slots.slot_state(slot),)
         if self.wslots is not None:
             # the window layers' block starts at the first page the
             # chunk's first query (position lo) still sees
@@ -4032,6 +4061,13 @@ class InferenceEngine:
             # the bytes of the projection leaves this engine laid out
             # at load (T.lay_out_projections; 0: a latent model has none)
             "params_relaid_bytes": self._relaid_bytes,
+            # a conv model's second kind of per-request state: bytes a
+            # slot holds beside its pages (fixed, whatever its context),
+            # and the slots that hold a request's now
+            "conv_state_bytes_per_slot":
+                self.slots.conv_state_bytes_per_slot,
+            "conv_state_slots_live": self.slots.active_count
+                if self.cfg.has_conv else 0,
             "kv_pages_high_water": self.slots.pages_high_water,
             "kv_window_pages_per_slot_bound":
                 self.wslots.window_pages_bound

@@ -1,0 +1,109 @@
+"""The jaxprs of the three served programs (the paged tick, a chunk
+against its landed prefix, a whole-prompt prefill) of the FOUR
+architectures the benchmark served before conv layers came, at a small
+size, as digests: ``python tests/served_program_digests.py`` prints them
+as JSON.  ``tests/data/served_program_digests_pr38.json`` holds what the
+tree BEFORE PR 40 printed; ``tests/test_conv_layers.py`` holds this tree
+to it, so every field PR 40 added (``conv_kernel``, ``tie_embeddings``,
+``kv_lane_dense``, ``norm_topk_eps``), left off, leaves those programs
+as they were, equation for equation."""
+
+import hashlib
+import json
+import re
+
+import jax
+import jax.numpy as jnp
+
+from horovod_tpu.models import transformer as T
+from horovod_tpu.serving import cache as C
+
+_LATENT = dict(
+    vocab_size=96, d_model=48, n_heads=4, n_layers=3, d_ff=96, max_seq=128,
+    dtype=jnp.float32, q_lora_rank=24, kv_lora_rank=32, qk_nope_head_dim=16,
+    qk_rope_head_dim=8, v_head_dim=16,
+    rope_yarn=(4.0, 16.0, 32.0, 1.0, 1.0, 1.0), n_dense_layers=1,
+    n_experts=16, n_experts_per_tok=4, d_expert=32, n_shared_experts=1,
+    moe_score="sigmoid", routed_scaling_factor=2.5, n_group=4, topk_group=2,
+    norm_topk_prob=True, moe_impl="dropless", n_experts_held=4,
+    expert_offset=4, attention_impl="flash")
+
+CONFIGS = {
+    # the standard GQA block (mistral-7b-v0.3-serve)
+    "uniform": dict(vocab_size=96, d_model=64, n_heads=4, n_kv_heads=2,
+                    n_layers=3, d_ff=128, max_seq=128, dtype=jnp.float32,
+                    attention_impl="flash"),
+    # window and full layers, top-k experts (mellum2-12b-a2.5b-serve)
+    "patterned": dict(
+        vocab_size=96, d_model=64, n_heads=4, n_kv_heads=2, n_layers=8,
+        d_ff=32, max_seq=128, n_experts=8, n_experts_per_tok=2, d_expert=32,
+        norm_topk_prob=True, d_head=16, qk_norm=True,
+        layer_pattern=("sliding", "sliding", "sliding", "full"), window=8,
+        rope_yarn=(4.0, 16, 32, 1, 1.1386), dtype=jnp.float32,
+        attention_impl="flash", moe_impl="dropless"),
+    # latent attention, a dense layer, a share of the experts (axk1-serve)
+    "latent": _LATENT,
+    # ... with an indexer and a biased router (deepseek-v3.2-exp-serve)
+    "sparse": dict(_LATENT, index_n_heads=4, index_head_dim=16,
+                   index_topk=12, moe_score_bias=True),
+}
+
+SLOTS, PAGE, MAX_LEN, CHUNK = 3, 4, 64, 8
+
+
+def _digest(fn, *args) -> str:
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(fn)(*args)))
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+def programs(cfg):
+    """``{name: digest}`` of the three programs for one configuration."""
+    params = jax.eval_shape(lambda: T.lay_out_projections(
+        T.init_params(jax.random.PRNGKey(0), cfg))[0])
+    n_pg = SLOTS * MAX_LEN // PAGE + 1
+
+    def pool(kind):
+        return jax.eval_shape(lambda: C.init_page_pool(
+            cfg, SLOTS, n_pg, PAGE, None, cfg.kind_count(kind)))
+
+    full = pool("full")
+    table = jnp.zeros((SLOTS, MAX_LEN // PAGE), jnp.int32)
+    tokens = jnp.zeros((SLOTS,), jnp.int32)
+    active = jnp.ones((SLOTS,), bool)
+    kw = {}
+    if cfg.has_window:
+        w = pool("sliding")
+        full = {**full, "wk": w["k"], "wv": w["v"]}
+        kw["wtable"] = table
+    out = {"tick": _digest(
+        lambda p, pl: T.decode_step_paged(
+            p, tokens, pl, table, cfg, active, kernel=True,
+            return_moe_load=cfg.n_experts > 1, **kw), params, full)}
+    chunk = jnp.zeros((1, CHUNK), jnp.int32)
+    lens = jnp.full((1,), CHUNK, jnp.int32)
+    pages = jnp.zeros((2,), jnp.int32)
+
+    def ingest(p, pl):
+        pk, pv = C.gather_prefix_pages(
+            {n: pl[n] for n in ("k", "v", "ik") if n in pl}, pages)
+        win = {}
+        if cfg.has_window:
+            win = dict(zip(("win_k", "win_v"), C.gather_prefix_pages(
+                {"k": pl["wk"], "v": pl["wv"]}, pages)), win_start=0)
+        return T.prefill_with_prefix(p, chunk, pk, pv, jnp.int32(8), cfg,
+                                     true_len=lens, **win)
+
+    out["chunk"] = _digest(ingest, params, full)
+    out["prompt"] = _digest(
+        lambda p: T.prefill(p, chunk, T.init_cache(cfg, 1, CHUNK), cfg,
+                            true_len=lens), params)
+    return out
+
+
+def digests() -> dict:
+    return {name: programs(T.TransformerConfig(**kw))
+            for name, kw in CONFIGS.items()}
+
+
+if __name__ == "__main__":
+    print(json.dumps(digests(), indent=1, sort_keys=True))

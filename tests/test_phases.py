@@ -405,6 +405,13 @@ class TestWaitsCollectionsAndSlowSteps:
         # one that reads /stats or /metrics and holds a histogram's
         # lock: the collector's sink takes none.  With a threshold of 1
         # every read below starts collections of every generation.
+        # (CPython puts the OLDEST generation off while few objects are
+        # new beside the process's long-lived ones — millions in a test
+        # worker that has imported TensorFlow: they are set aside for
+        # the length of this test, so "every generation" holds whatever
+        # ran in the worker before.)
+        gc.freeze()
+        gc.collect()
         engine = _engine(model)
         engine.warmup((3,))
         done = threading.Event()
@@ -425,6 +432,7 @@ class TestWaitsCollectionsAndSlowSteps:
                 assert done.wait(30), "a /stats reader hangs in the collector"
         finally:
             gc.set_threshold(*threshold)
+            gc.unfreeze()
         stats = engine.stats()
         assert stats["gc_pause_seconds"]["count"] > 150
         assert stats["gc_pause_seconds_gen2"]["count"] > 0

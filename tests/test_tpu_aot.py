@@ -485,3 +485,138 @@ def test_the_layer_scan_copies_no_projection_leaf_of_an_engines_tree(
         assert scans_own == [], scans_own
     else:
         assert len(scans_own) >= 3, (scans_own, found)
+
+
+def _lfm2():
+    import json
+
+    from chipbench.drivers import serve_conv
+
+    with open(os.path.join(os.path.dirname(chip_smoke.__file__), "chipbench",
+                           "configs", "lfm2-24b-a2b-serve.json")) as f:
+        dims = json.load(f)
+    return dims, serve_conv.build_cfg(dims)
+
+
+@pytest.mark.parametrize("what", ["tick", "chunk", "prompt"])
+def test_the_served_conv_programs_compile_at_the_published_widths(
+        one_chip, monkeypatch, what):
+    """The benchmark's own configuration (`lfm2-24b-a2b-serve`: 64 slots
+    of 8192, 32 768 pages, heads of 64 two to a stored row) as the
+    engine's three programs, compiled for the v5e from shapes alone.
+    5 267 090 176 parameters = 10.53 GB in bf16 are their argument (the
+    issue's count, from an engine's own tree).  The TICK holds the fused
+    paged kernel — at rows of 128 lanes, the kernel heads of 128 run —
+    and the grouped expert product, takes 2.15 GB of pool and 4 MB of
+    conv state and gives ALL of it back aliased (the state is written in
+    place like the pages), with no result the size of a layer of ``k``
+    and 5 MB of temporaries.  A CHUNK of 512 against 4096 landed tokens
+    (prefix as the pool stores it, state as the chunk before left it)
+    and a PROMPT of 512 — through the flash forward at heads of 64, a
+    Mosaic call and not the XLA form — compile inside 0.7 GB of
+    temporaries."""
+    for mod, name in ((PA, "use_interpret"), (MOE, "use_interpret"),
+                      (ATT, "_use_interpret")):
+        monkeypatch.setattr(mod, name, lambda: False)
+    dims, cfg = _lfm2()
+    eng = dims["engine"]
+    assert (cfg.head_dim, cfg.kv_pack, cfg.n_dense_layers) == (64, 2, 2)
+    params = _on(one_chip, jax.eval_shape(
+        lambda: T.lay_out_projections(jax.tree_util.tree_map(
+            lambda a: a.astype(cfg.dtype),
+            T.init_params(jax.random.PRNGKey(0), cfg)))[0]))
+    n_params = sum(a.size for a in jax.tree_util.tree_leaves(params))
+    assert n_params == 5_267_090_176 and "head" not in params
+    weights = 2 * n_params
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    La, Lc = cfg.kind_count("full"), cfg.kind_count("conv")
+    if what == "tick":
+        S_ = eng["n_slots"]
+        pool = _on(one_chip, jax.eval_shape(lambda: C.init_page_pool(
+            cfg, S_, eng["n_pages"] + 1, eng["page_size"], None, La)))
+        assert pool["k"].shape == (2, 32769, 4, 16, 128)
+        assert pool["conv"].shape == (8, 64, 2, 2048)
+        compiled = jax.jit(
+            lambda p, tok, act, t, pl: T.decode_step_paged(
+                p, tok, pl, t, cfg, act, kernel=True, return_moe_load=True),
+            donate_argnums=(4,)).lower(
+                params, sds((S_,), jnp.int32), sds((S_,), jnp.bool_),
+                sds((S_, eng["max_len"] // eng["page_size"]), jnp.int32),
+                pool).compile()
+        text = compiled.as_text()
+        assert chip_smoke.kernel_calls(text, PA.KERNEL_NAME) == 1
+        assert chip_smoke.kernel_calls(text, MOE.EXPERTS_NAME) >= 1
+        layer = pool["k"].size // pool["k"].shape[0]
+        offenders, largest = chip_smoke.pool_sized_results(text, layer)
+        assert offenders == [], (offenders, largest)
+        mem = compiled.memory_analysis()
+        pool_bytes = sum(a.size * a.dtype.itemsize for a in pool.values())
+        assert abs(pool_bytes - 2.152e9) < 1e6
+        assert mem.alias_size_in_bytes >= pool_bytes     # the state too
+        assert mem.temp_size_in_bytes < 0.05e9, mem
+        assert mem.argument_size_in_bytes < weights + pool_bytes + 1e6
+        return
+    ids, lens = sds((1, 512), jnp.int32), sds((1,), jnp.int32)
+    if what == "chunk":
+        pk = sds((La, cfg.kv_heads // cfg.kv_pack, 4096,
+                  cfg.head_dim * cfg.kv_pack), cfg.dtype)
+        state = sds((Lc, 1, cfg.conv_taps, cfg.d_model), cfg.dtype)
+        compiled = jax.jit(
+            lambda p, suf, k, v, p0, n, st: T.prefill_with_prefix(
+                p, suf, k, v, p0, cfg, true_len=n, conv_state=st)).lower(
+                    params, ids, pk, pk, sds((), jnp.int32), lens,
+                    state).compile()
+    else:
+        compiled = jax.jit(
+            lambda p, pr, n: T.prefill(p, pr, T.init_cache(cfg, 1, 512),
+                                       cfg, true_len=n)).lower(
+                params, ids, lens).compile()
+        assert chip_smoke.kernel_calls(compiled.as_text(),
+                                       "hvd_flash_fwd") == 1
+    text = compiled.as_text()
+    assert chip_smoke.kernel_calls(text, MOE.EXPERTS_NAME) >= 1
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.7e9, mem
+    # a (1, V) row of logits, and the block: K, V of 2 layers, 8 states
+    assert mem.output_size_in_bytes < 3e6, mem
+
+
+@pytest.mark.parametrize("half", ["conv_mix", "attention_mix", "dense_feed",
+                                  "expert_feed", "scores"])
+def test_the_conv_cells_reference_fits_the_chip_in_one_width(one_chip, half):
+    """The benchmark's own float32 reference of `lfm2-24b-a2b-serve`
+    (``chipbench/reference_conv.py``), a sequence of any length laid in
+    the engine's 8192 rows: each half of a layer (and the scores the
+    expert bias is balanced on) compiles for the v5e with its
+    temporaries well inside the chip (the engine is gone by then), its
+    length a traced scalar — five executables whatever the seed
+    draws."""
+    from chipbench import reference_conv as R
+    from chipbench import weights_conv as W
+
+    dims, _ = _lfm2()
+    key = R._layer_dims(dims)
+    kind = "full_attention" if half == "attention_mix" else "conv"
+    w = _on(one_chip, jax.eval_shape(lambda: {
+        n: jnp.zeros(s, jnp.bfloat16) for n, (s, _) in W.layer_shapes(
+            dims, kind, half == "dense_feed").items()}))
+    x = _on(one_chip, jax.ShapeDtypeStruct(
+        (dims["engine"]["max_len"], dims["hidden_size"]), jnp.float32))
+    n = _on(one_chip, jax.ShapeDtypeStruct((), jnp.int32))
+    with jax.default_matmul_precision("highest"):
+        if half.endswith("_mix"):
+            lowered = R._mix_fn(key, kind, "f32", 512, False).lower(
+                x, {k: w[k] for k in R._MIX_LEAVES[kind]}, n)
+        elif half == "scores":
+            lowered = R._scores_fn(key, 512).lower(x, w["ln2"], w["router"],
+                                                   n)
+        else:
+            lowered = R._feed_fn(key, "f32", 512).lower(
+                x, {k: w[k] for k in R._FEED_LEAVES if k in w}, n)
+        compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 3e9, mem
+    assert "while" in compiled.as_text()    # the rows below n, no more
