@@ -216,6 +216,51 @@ def pool_sized_results(text: str, floor: int):
             [f[:4] for f in found[:5]])
 
 
+_HLO_TYPE = re.compile(r"\b([a-z]+\d+)\[([\d,]*)\]")
+_HLO_DTYPES = {"float32": "f32", "bfloat16": "bf16", "float16": "f16"}
+
+
+def product_fusions(text: str) -> Dict[str, str]:
+    """Read a compiled executable's HLO text: ``{name: result type}`` of
+    every fusion whose fused computation holds a ``convolution`` — a
+    matmul, as XLA:TPU writes it."""
+    holds, inside = set(), None
+    for line in text.splitlines():
+        comp = _HLO_COMPUTATION.match(line)
+        if comp:
+            inside = comp.group(1)
+        elif " convolution(" in line:
+            holds.add(inside)
+    found = {}
+    for line in text.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if m and m.group(3) == "fusion" and holds.intersection(
+                re.findall(r"calls=%?([\w.\-]+)", line)):
+            found[m.group(1)] = m.group(2)
+    return found
+
+
+def products_carrying_an_update(text: str, params) -> Dict[str, str]:
+    """The product fusions of a compiled train step that write a
+    parameter's worth of a parameter's dtype MORE THAN ONCE: a weight
+    gradient's matmul with the optimizer's outputs (AdamW: parameter,
+    ``mu``, ``nu``) in its epilogue, which XLA:TPU builds when nothing
+    stands between a gradient and its update and which ran at 41-52 % of
+    the MXU where the product alone reached 83-87 (PERF.md section 6, PR
+    41).  ``optim.distributed_gradients`` hands the optimizer VALUES, so
+    there are none."""
+    leaves = {(_HLO_DTYPES.get(str(x.dtype)), int(np.prod(x.shape)))
+              for x in jax.tree_util.tree_leaves(params)}
+    found = {}
+    for name, result in product_fusions(text).items():
+        written = [(dtype, int(np.prod([int(d) for d in dims.split(",")
+                                        if d])))
+                   for dtype, dims in _HLO_TYPE.findall(result)]
+        if any(written.count(w) > 1 for w in leaves.intersection(written)):
+            found[name] = result
+    return found
+
+
 _HLO_CALLED = re.compile(
     r"\b(?:calls|to_apply|body|condition|true_computation|"
     r"false_computation)=%?([\w.\-]+)")
@@ -663,6 +708,12 @@ def phase_train(smoke: SmokeConfig):
                  f"train step: {n_fwd} hvd_flash_fwd calls in the compiled "
                  "step, expected 1 (the forward loop's; the backward pass "
                  "reads the saved output and log-sum-exp)")
+
+        carrying = products_carrying_an_update(text, params)
+        report["products_carrying_an_update"] = sorted(carrying)
+        _require(not carrying,
+                 "train step: weight-gradient products with the optimizer's "
+                 f"outputs in their epilogue: {carrying}")
 
     losses: List[float] = []
     for _ in range(smoke.train_steps):

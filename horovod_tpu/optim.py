@@ -7,10 +7,22 @@ grads_fn``, ``_DistributedOptimizer``, ``DistributedGradientTape``) and
 ``backward_passes_per_step`` accumulation).
 
 TPU re-design: the optimizer is an **optax gradient transformation** — the
-allreduce is a pure function inside the compiled train step, so XLA overlaps
-it with the backward pass the way the reference's background thread did
-dynamically, but with a static schedule.  There are no hooks, handles, or
-``synchronize()``: data dependencies express completion.
+allreduce is a pure function inside the compiled train step, scheduled
+statically where the reference's background thread scheduled dynamically.
+There are no hooks, handles, or ``synchronize()``: data dependencies
+express completion.
+
+What is MEASURED of that schedule (four v5e chips, Mistral-7B's widths,
+``m7b-train-dp4``: ledger, PR 40): XLA:TPU issues each gradient's
+``all-reduce`` right behind the product that makes the gradient, but as a
+BLOCKING operation — 34.13 of the 34.35 ms a step under ``grad_allreduce``
+are exposed, the core waits them out in seven pieces, and nothing of the
+backward pass runs meanwhile (ROADMAP.md S11).  The reference's overlap
+is not had for free.
+
+Gradients enter the reduction as VALUES (``_as_values`` says why: one
+``lax.optimization_barrier`` a leaf, in traced code), so that the compiler
+cannot fuse the wrapped optimizer into the matmul that makes its gradient.
 """
 
 from __future__ import annotations
@@ -24,6 +36,24 @@ import optax
 from horovod_tpu.ops import collectives as C
 from horovod_tpu.ops import fusion as F
 from horovod_tpu.ops.compression import Compression
+
+
+def _as_values(grads):
+    """Traced gradients as MATERIALISED values, leaf by leaf.
+
+    With nothing between a gradient and its update — a world of one, where
+    the reduction emits no operation — XLA:TPU puts the optimizer's outputs
+    (AdamW: parameter, ``mu``, ``nu``) into the epilogue of the matmul that
+    makes the gradient, and that product then runs at 41-52 % of the MXU
+    where it reaches 83-87 % alone (``m7b-train-1chip`` against
+    ``m7b-train-dp4``: ledger, PR 40; PERF.md section 6, PR 41).  On
+    several chips the allreduce already stands there, and the program is
+    the same with the barrier.  One barrier a LEAF: no leaf's reduction or
+    update waits for another leaf's gradient.  Eager arrays are values
+    already."""
+    return jax.tree_util.tree_map(
+        lambda g: jax.lax.optimization_barrier(g)
+        if isinstance(g, jax.core.Tracer) else g, grads)
 
 
 def distributed_gradients(
@@ -74,7 +104,7 @@ def distributed_gradients(
     # "grad_allreduce": the device scope a profile attributes the
     # compiled collectives (and their casts and packing) to.
     with jax.named_scope("grad_allreduce"):
-        grads, ctx = compression.compress(grads)
+        grads, ctx = compression.compress(_as_values(grads))
         if fuse and op in (C.Average, C.Sum):
             out = F.fused_allreduce_tree(
                 grads, op, axis_name=axis_name, threshold=fusion_threshold

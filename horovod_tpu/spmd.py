@@ -4,9 +4,12 @@ entire runtime hot path.
 Where the reference enqueues each gradient to a background thread that
 negotiates, fuses and launches NCCL (call stack SURVEY.md §3.2), here the
 whole train step — forward, backward, allreduce, optimizer — is ONE jitted
-SPMD program over the horovod mesh.  XLA overlaps the gradient collectives
-with remaining backward computation (latency hiding, same effect as the
-reference's async background thread) and schedules them on ICI.
+SPMD program over the horovod mesh, with the gradient collectives scheduled
+statically on ICI.  That schedule does NOT hide them today: on four v5e
+chips at Mistral-7B's widths each ``all-reduce`` follows the product that
+makes its gradient but blocks the core, 34.13 of 34.35 ms a step exposed
+(``m7b-train-dp4``: ledger, PR 40; ROADMAP.md S11) — the reference's
+async background thread overlapped, this compiled step does not yet.
 """
 
 from __future__ import annotations

@@ -344,3 +344,51 @@ def test_sorts_by_conditional_reads_a_compiled_program():
         0, [], ["sort.6", "sort.9"])
     with pytest.raises(chip_smoke.SmokeFailure, match="not gated"):
         chip_smoke._require_sorts_gated(smoke, flat, "probe")
+
+
+_HLO_TRAIN = """HloModule jit__step, is_scheduled=true
+
+%fused_computation.29 (a: bf16[24576,4096], b: bf16[24576,32768]) -> (f32[4096,32768], f32[4096,32768], f32[4096,32768]) {
+  %convolution.7 = f32[4096,32768]{1,0:T(8,128)} convolution(%a, %b), dim_labels=fb_io->bf
+  ROOT %tuple.3 = (f32[4096,32768]{1,0:T(8,128)}, f32[4096,32768]{1,0:T(8,128)}, f32[4096,32768]{1,0:T(8,128)}) tuple(%sub.1, %add.2, %add.3)
+}
+
+%fused_computation.52 (a: bf16[6,4096,4096]) -> (f32[6,4096], bf16[6,4096,4096], bf16[6,4096,4096]) {
+  %convolution.9 = f32[6,4096,4096]{2,1,0:T(8,128)} convolution(%a, %w), dim_labels=b0f_0io->b0f
+  ROOT %tuple.4 = (f32[6,4096]{1,0:T(8,128)}, bf16[6,4096,4096]{2,1,0:T(8,128)(2,1)}, bf16[6,4096,4096]{2,1,0:T(8,128)(2,1)}) tuple(%r, %c, %d)
+}
+
+%fused_computation.60 (g: f32[4096,32768]) -> (f32[4096,32768], f32[4096,32768], f32[4096,32768]) {
+  ROOT %tuple.5 = (f32[4096,32768]{1,0:T(8,128)}, f32[4096,32768]{1,0:T(8,128)}, f32[4096,32768]{1,0:T(8,128)}) tuple(%p, %m, %n)
+}
+
+ENTRY %main (p: f32[4096,32768]) -> f32[4096,32768] {
+  %fusion.52 = (f32[6,4096]{1,0:T(8,128)}, bf16[6,4096,4096]{2,1,0:T(8,128)(2,1)}, bf16[6,4096,4096]{2,1,0:T(8,128)(2,1)}) fusion(%x), kind=kOutput, calls=%fused_computation.52
+  %fusion.29 = (f32[4096,32768]{1,0:T(8,128)}, f32[4096,32768]{1,0:T(8,128)}, f32[4096,32768]{1,0:T(8,128)}) fusion(%h, %dlogits), kind=kOutput, calls=%fused_computation.29
+  %multiply_add_fusion = (f32[4096,32768]{1,0:T(8,128)}, f32[4096,32768]{1,0:T(8,128)}, f32[4096,32768]{1,0:T(8,128)}) fusion(%g), kind=kLoop, calls=%fused_computation.60
+}
+"""
+
+
+def test_products_carrying_an_update_reads_a_compiled_program():
+    """The compiled-program check of the train step: a fusion that holds
+    a matmul and writes a parameter's worth of float32 three times is a
+    weight gradient's product with AdamW in its epilogue; an activation
+    written twice is not, nor is an update with no product."""
+    import jax
+    import jax.numpy as jnp
+
+    params = {"head": jax.ShapeDtypeStruct((4096, 32768), jnp.float32),
+              "norm": jax.ShapeDtypeStruct((4096,), jnp.float32)}
+    assert sorted(chip_smoke.product_fusions(_HLO_TRAIN)) == [
+        "fusion.29", "fusion.52"]
+    assert list(chip_smoke.products_carrying_an_update(
+        _HLO_TRAIN, params)) == ["fusion.29"]
+    alone = _HLO_TRAIN.replace(
+        "ROOT %tuple.3 = (f32[4096,32768]{1,0:T(8,128)}, "
+        "f32[4096,32768]{1,0:T(8,128)}, f32[4096,32768]{1,0:T(8,128)})",
+        "ROOT %tuple.3 = (f32[4096,32768]{1,0:T(8,128)})").replace(
+        "%fusion.29 = (f32[4096,32768]{1,0:T(8,128)}, "
+        "f32[4096,32768]{1,0:T(8,128)}, f32[4096,32768]{1,0:T(8,128)})",
+        "%fusion.29 = f32[4096,32768]{1,0:T(8,128)}")
+    assert chip_smoke.products_carrying_an_update(alone, params) == {}

@@ -277,8 +277,13 @@ class TestPhasesPartitionTheLoop:
         engine.warmup((3, 20))
         jax.profiler.start_trace(str(tmp_path))
         try:
-            a = engine.submit([2, 3, 4], max_new_tokens=2)
-            b = engine.submit(list(range(1, 21)), max_new_tokens=2)
+            # named: a drawn id of sixteen hex digits now and then reads
+            # as a number ("262e047069604807" came back from the trace as
+            # inf, once in a whole run of PR 41)
+            a = engine.submit([2, 3, 4], max_new_tokens=2,
+                              trace_id="request-a")
+            b = engine.submit(list(range(1, 21)), max_new_tokens=2,
+                              trace_id="request-b")
             for _ in range(40):
                 if a.done() and b.done():
                     break

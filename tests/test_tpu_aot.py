@@ -6,11 +6,14 @@ assignment is the TPU compiler's, so the CPU tests of
 ``tests/test_paged.py`` cannot see it, and ``chip_smoke.py`` sees it
 only on the chip; the tick's next-token pick keeps its conditionals
 (a compiler that ran both branches and selected would sort every tick);
-and the training step under remat ``dots`` runs the flash forward kernel
-once, for the two arrays it saves.
+the training step under remat ``dots`` runs the flash forward kernel
+once, for the two arrays it saves; and the step the benchmark's training
+cells build hands its optimizer VALUES: no weight-gradient product of
+the one-chip program carries AdamW in its epilogue, and the four-chip
+program is the one it was.
 
 Keep every such compile in THIS file (one process may hold libtpu), and
-describe the topology only inside the fixture below.
+describe the topology only inside the fixture ``v5e`` below.
 """
 
 import dataclasses
@@ -41,7 +44,8 @@ S, PS, PAGES, MAX_LEN = 8, 16, 2048, 512
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e():
+    """The described ``v5e:2x2``: its four devices, none attached."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -55,9 +59,14 @@ def one_chip():
     # read back without a chip: keep it out
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", True)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    return SingleDeviceSharding(v5e.devices[0])
 
 
 def _cfg(**kw):
@@ -419,6 +428,140 @@ def test_the_train_step_runs_the_flash_forward_once_under_dots(
     grew = (program.memory_analysis().temp_size_in_bytes
             - before.memory_analysis().temp_size_in_bytes)
     assert 0 < grew <= saved * 1.1, (grew, saved)
+
+
+# Mistral-7B-v0.3's widths at one layer and 6 rows of 4096 a chip (the
+# training cells'), and the smallest widths heads of 128 allow: the
+# compiler fuses an update into its product at both.
+TRAIN_WIDTHS = {
+    "published": dict(d_model=4096, n_heads=32, n_kv_heads=8, d_ff=14336,
+                      vocab_size=32768, max_seq=4096, rows=6),
+    "smallest": dict(d_model=256, n_heads=2, n_kv_heads=1, d_ff=512,
+                     vocab_size=1024, max_seq=1024, rows=2),
+}
+
+
+def _cell_train_step(v5e, n_devices, widths):
+    """The step ``chipbench/drivers/train.py`` builds —
+    ``value_and_grad(T.loss_fn)``, then
+    ``hvd.DistributedOptimizer(optax.adamw(..)).update``, under
+    ``shard_map`` — compiled for ``n_devices`` of the described v5e:
+    ``(HLO text, parameter shapes)``.  Traced anew at every call."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import horovod_tpu as hvd
+    from horovod_tpu import spmd
+
+    widths = dict(widths)
+    rows = widths.pop("rows") * n_devices
+    cfg = T.TransformerConfig(
+        n_layers=1, rope_theta=1e6, dtype=jnp.bfloat16,
+        attention_impl="flash", remat=True, remat_policy="dots", **widths)
+    mesh = Mesh(np.array(v5e.devices[:n_devices]), (hvd.AXIS,))
+    repl = NamedSharding(mesh, P())
+    params = _on(repl, jax.eval_shape(
+        lambda: T.init_params(jax.random.PRNGKey(0), cfg)))
+    opt = hvd.DistributedOptimizer(optax.adamw(3e-4, weight_decay=1e-4))
+    opt_state = _on(repl, jax.eval_shape(opt.init, params))
+    batch = _on(NamedSharding(mesh, P(hvd.AXIS)), {
+        k: jax.ShapeDtypeStruct((rows, cfg.max_seq), jnp.int32)
+        for k in ("tokens", "targets")})
+
+    def _step(params, opt_state, batch):
+        loss, grads = jax.value_and_grad(
+            lambda p: T.loss_fn(p, batch, cfg))(params)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return (optax.apply_updates(params, updates), opt_state,
+                jax.lax.pmean(loss, hvd.AXIS))
+
+    step = jax.jit(spmd.shard(
+        _step, in_specs=(P(), P(), P(hvd.AXIS)),
+        out_specs=(P(), P(), P()), mesh=mesh), donate_argnums=(0, 1))
+    return step.lower(params, opt_state, batch).compile().as_text(), params
+
+
+def _gradients_as_they_come(monkeypatch):
+    """The parent's form: nothing between a gradient and its reduction."""
+    from horovod_tpu import optim
+
+    monkeypatch.setattr(optim, "_as_values", lambda grads: grads)
+
+
+@pytest.mark.parametrize("widths", sorted(TRAIN_WIDTHS))
+def test_no_weight_gradient_product_carries_the_update_on_one_chip(
+        v5e, monkeypatch, widths):
+    """On ONE device the reduction emits no operation, and XLA:TPU then
+    writes AdamW's three outputs from the epilogue of the matmul that
+    makes the gradient — ``fusion.29`` / ``.85`` / ``.87`` / ``.89`` of
+    ``m7b-train-1chip``, at 41-52 % of the MXU (ledger, PR 40).  The
+    optimizer receives values (``optim._as_values``): no fusion that
+    holds a ``convolution`` writes a parameter's worth of float32 more
+    than once."""
+    monkeypatch.setattr(ATT, "_use_interpret", lambda: False)
+    text, params = _cell_train_step(v5e, 1, TRAIN_WIDTHS[widths])
+    assert chip_smoke.kernel_calls(text, "hvd_flash_fwd") == 1
+    carrying = chip_smoke.products_carrying_an_update(text, params)
+    assert carrying == {}, carrying
+
+
+def test_the_compiler_still_fuses_an_update_into_a_bare_gradients_product(
+        v5e, monkeypatch):
+    """What the test above guards against is still what this compiler
+    does: with the gradients handed on as they come, all eight matrices
+    (head, three of the MLP, ``wq`` / ``wk`` / ``wv`` / ``wo``) are
+    written three times over by their gradient's product.  When this
+    fails the compiler has changed its mind, and ``_as_values`` can be
+    measured again."""
+    monkeypatch.setattr(ATT, "_use_interpret", lambda: False)
+    _gradients_as_they_come(monkeypatch)
+    text, params = _cell_train_step(v5e, 1, TRAIN_WIDTHS["smallest"])
+    carrying = chip_smoke.products_carrying_an_update(text, params)
+    assert len(carrying) == 8, carrying
+
+
+def _entry_schedule(text):
+    """The entry computation's collectives, products and custom calls in
+    the order the compiler scheduled them."""
+    products = chip_smoke.product_fusions(text)
+    lines = text.splitlines()
+    start = next(i for i, l in enumerate(lines) if l.startswith("ENTRY "))
+    kinds = []
+    for line in lines[start + 1:]:
+        if line.startswith("}"):
+            break
+        m = chip_smoke._HLO_INSTRUCTION.match(line)
+        if not m:
+            continue
+        name, _, opcode = m.groups()
+        if name in products:
+            kinds.append("product")
+        elif opcode == "custom-call":
+            kinds.append(re.search(
+                r'custom_call_target="([^"]+)"', line).group(1))
+        elif opcode.startswith(chip_smoke._HLO_COLLECTIVES):
+            kinds.append(opcode)
+    return kinds
+
+
+def test_the_four_chip_step_is_the_program_it_was(v5e, monkeypatch):
+    """On the four devices the allreduce already stood between a gradient
+    and its update, and a barrier BEFORE it changes nothing: at the
+    published widths (a schedule read at toy widths says nothing about
+    the cell: ROADMAP S11) the entry computation's collectives, products
+    and custom calls are the parent's in kind and order — seven
+    ``all-reduce``s, each right behind the product that makes its
+    gradient — and no product carries an update on either side."""
+    monkeypatch.setattr(ATT, "_use_interpret", lambda: False)
+    text, params = _cell_train_step(v5e, 4, TRAIN_WIDTHS["published"])
+    schedule = _entry_schedule(text)
+    assert schedule.count("all-reduce") == 7, schedule
+    assert schedule.count("tpu_custom_call") == 3, schedule
+    assert chip_smoke.products_carrying_an_update(text, params) == {}
+    _gradients_as_they_come(monkeypatch)
+    parent, _ = _cell_train_step(v5e, 4, TRAIN_WIDTHS["published"])
+    assert chip_smoke.products_carrying_an_update(parent, params) == {}
+    assert schedule == _entry_schedule(parent)
 
 
 @pytest.mark.parametrize("tree", ["engine", "checkpoint"])
