@@ -1214,6 +1214,120 @@ def phase_serve_conv(smoke: SmokeConfig) -> Dict:
     return report
 
 
+def hybrid_cfg(smoke: SmokeConfig):
+    """The smoke's small model of TWO-MIXER layers: 10 query / 2 KV
+    heads of 128 (a GQA group of five) beside a state-space mixer of 4
+    heads of 128 with a state of 128 columns in 2 groups and 4 taps,
+    every published multiplier set."""
+    from horovod_tpu.models import transformer as T
+
+    return T.TransformerConfig(
+        vocab_size=smoke.vocab_size, d_model=256, n_heads=10, n_kv_heads=2,
+        d_head=128, n_layers=3, d_ff=512, max_seq=smoke.serve_max_len,
+        dtype=jnp.dtype(smoke.dtype), attention_impl="flash",
+        layer_pattern=("hybrid",), conv_kernel=4, ssm_heads=4,
+        ssm_head_dim=128, ssm_state=128, ssm_groups=2, ssm_chunk=32,
+        embed_multiplier=2.0, head_multiplier=0.5, attn_out_multiplier=0.5,
+        key_multiplier=0.5, ssm_in_multiplier=0.5, ssm_out_multiplier=0.7,
+        ssm_multipliers=(0.7, 0.5, 0.7, 1.0, 0.7),
+        mlp_multipliers=(0.5, 0.5))
+
+
+def phase_serve_hybrid(smoke: SmokeConfig) -> Dict:
+    """The small HYBRID configuration through the engine on one chip:
+    whole and chunked prompts (both states handed from chunk to chunk),
+    the fused paged kernel at a group of five AND the state update's
+    kernel compiled into the tick, the pages and both per-slot states
+    written in place by the compiled tick and landing, a slot's states
+    zero at its next grant, the tokens the plain reference's where its
+    margin is clear."""
+    from horovod_tpu.models import plain_reference as R
+    from horovod_tpu.ops import paged_attention as PA
+    from horovod_tpu.ops import ssm as SSM
+
+    cfg = hybrid_cfg(smoke)
+    engine, params, prompts, futs, seen, tick, land = _serve_small(
+        smoke, cfg, smoke.seed + 7)
+    stats = engine.stats()
+    item = jnp.dtype(cfg.dtype).itemsize
+    _require(stats["paged_kernel_engaged"] is True
+             and stats["decode_compilations"] == 1,
+             f"hybrid engine: kernel engaged {stats['paged_kernel_engaged']}"
+             f", decode compilations {stats['decode_compilations']}")
+    pool = engine.slots.cache
+    _require(set(pool) == {"k", "v", "conv", "ssm", "pos"}
+             and pool["k"].shape[0] == 3
+             and pool["conv"].shape == (3, smoke.n_slots, 3, 1024)
+             and pool["ssm"].shape == (3, smoke.n_slots, 4, 128, 128)
+             and stats["kv_bytes_per_token"] == 3 * 2 * 2 * 128 * item
+             and stats["ssm_state_bytes_per_slot"] == 3 * 4 * 128 * 128 * item
+             and stats["conv_state_bytes_per_slot"] == 3 * 3 * 1024 * item,
+             f"the hybrid pool's layout: arrays {sorted(pool)}, "
+             f"{stats['kv_bytes_per_token']} B a token, "
+             f"{stats['ssm_state_bytes_per_slot']} + "
+             f"{stats['conv_state_bytes_per_slot']} B of state a slot")
+    n_prompt = sum(map(len, prompts))
+    # (an overlapped engine dispatches up to one tick past a request's
+    # last token: the counter counts the rows DISPATCHED)
+    rows = stats["ssm_updated_slots_total"] / (3 * len(prompts))
+    _require(stats["ssm_scanned_tokens_total"] == 3 * n_prompt
+             and smoke.max_new_tokens - 1 <= rows <= smoke.max_new_tokens,
+             f"state-space counters: scanned "
+             f"{stats['ssm_scanned_tokens_total']} (prompts {n_prompt} x 3 "
+             f"layers), updated {stats['ssm_updated_slots_total']}")
+    text = tick.lower(*seen["tick"]).compile().as_text()
+    _require_compiled(smoke, text, 2, "hybrid decode tick")
+    _require(not smoke.expect_compiled or (
+        PA.KERNEL_NAME in text and SSM.UPDATE_NAME in text),
+        "hybrid decode tick: hvd_paged_attend / hvd_ssm_update not compiled")
+    # (a layer of the pages; at four slots a layer of the states is
+    # smaller than the head's weights, so THAT array's turn is
+    # tests/test_tpu_aot.py's, at 64 slots of 2 MiB)
+    layer = int(np.prod(pool["k"].shape[1:]))
+    _require_pool_in_place(smoke, text, layer, "hybrid decode tick")
+    _require_pool_in_place(
+        smoke, land.lower(*seen["land"]).compile().as_text(), layer,
+        "hybrid landing")
+    # a released slot keeps what its tenant left until its NEXT grant
+    left = float(jnp.abs(pool["ssm"][:, 0]).max())
+    slot = engine.slots.alloc()
+    fresh = max(float(jnp.abs(engine.slots.cache[n][:, slot]).max())
+                for n in ("conv", "ssm"))
+    engine.slots.free(slot)
+    _require(slot == 0 and left > 0 and fresh == 0.0,
+             f"slot {slot}'s states at its next grant: {fresh} (its last "
+             f"tenant left {left})")
+    dims = dict(
+        hidden_size=cfg.d_model, head_dim=cfg.head_dim,
+        rms_norm_eps=cfg.norm_eps, rope_theta=cfg.rope_theta,
+        num_hidden_layers=cfg.n_layers, mamba_d_ssm=cfg.ssm_inner,
+        mamba_n_heads=cfg.ssm_heads, mamba_d_head=cfg.ssm_head_dim,
+        mamba_d_state=cfg.ssm_state, mamba_n_groups=cfg.ssm_groups,
+        mamba_d_conv=cfg.conv_kernel,
+        embedding_multiplier=cfg.embed_multiplier,
+        lm_head_multiplier=cfg.head_multiplier,
+        attention_in_multiplier=cfg.attn_in_multiplier,
+        attention_out_multiplier=cfg.attn_out_multiplier,
+        key_multiplier=cfg.key_multiplier,
+        ssm_in_multiplier=cfg.ssm_in_multiplier,
+        ssm_out_multiplier=cfg.ssm_out_multiplier,
+        ssm_multipliers=cfg.ssm_multipliers,
+        mlp_multipliers=cfg.mlp_multipliers)
+    tokens = {i: f.result() for i, f in enumerate(futs)}
+    checked = _check_against_oracle(
+        smoke, params, prompts, tokens, cfg,
+        oracle=lambda p, t: jax.vmap(
+            lambda row: R.hybrid_forward(p, row, dims))(t))
+    report = {"requests": len(prompts), "oracle_positions_checked": checked,
+              "kv_bytes_per_token": stats["kv_bytes_per_token"],
+              "ssm_state_bytes_per_slot": stats["ssm_state_bytes_per_slot"],
+              "ssm_updated_slots": stats["ssm_updated_slots_total"],
+              "ssm_scanned_tokens": stats["ssm_scanned_tokens_total"]}
+    _say("hybrid server: " + json.dumps(report))
+    del engine, params
+    return report
+
+
 def phase_tp(smoke: SmokeConfig, host_params, tp: int, single: Dict) -> Dict:
     """``EngineConfig(tp=n)`` answers the same requests as ``tp=1``."""
     report = phase_serve(smoke, host_params, tp=tp)
@@ -1249,6 +1363,8 @@ def run(smoke: SmokeConfig, *, tp: Optional[int] = None) -> Dict:
     report["serve_sparse"] = phase_serve_sparse(smoke)
     gc.collect()
     report["serve_conv"] = phase_serve_conv(smoke)
+    gc.collect()
+    report["serve_hybrid"] = phase_serve_hybrid(smoke)
     gc.collect()
     if tp:
         report["serve_tp"] = phase_tp(smoke, host_params, tp,

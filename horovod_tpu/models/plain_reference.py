@@ -2,8 +2,9 @@
 uniform block: a patterned expert model (below), a LATENT-ATTENTION
 expert model (``latent_*``, with its own description there) and that
 model with a lightning indexer's SPARSE selection and a biased router
-(``sparse_*``), and a model of gated SHORT CONVOLUTIONS between
-attention layers (``conv_*``, at the end of the file).
+(``sparse_*``), a model of gated SHORT CONVOLUTIONS between attention
+layers (``conv_*``), and a model whose every layer runs attention AND a
+STATE-SPACE mixer side by side (``hybrid_*``, at the end of the file).
 
 The plain reference of a patterned expert model: its forward pass in
 straightforward ``jax.numpy``, float32 at
@@ -617,3 +618,137 @@ def conv_forward(params, tokens, dims: dict, zero_taps: bool = False):
             x = conv_layer(x, w, dims, kind, zero_taps)
         x = rmsnorm(x, params["ln_f"], dims["norm_eps"])
         return x @ params["embed"].astype(F32).T
+
+
+# --- attention and a state-space mixer side by side (Falcon-H1's block) -------
+#
+# The forward pass of the block ``Falcon-H1-34B-Instruct`` publishes
+# (``model_type: falcon_h1``), in the same plain style: float32 at
+# ``default_matmul_precision("highest")``, one sequence, every position
+# against every earlier one, no cache, no kernel, nothing imported from the
+# program — and the recurrence as a SEQUENTIAL scan over the tokens, never
+# the chunked dual form the program runs.  ``tests/test_hybrid_layers.py``
+# holds whole prefill, chunked prefill and paged decode to its LOGITS.
+#
+# With ``m_*`` the published multipliers (their PLACES are the family's
+# modeling code's; the config gives values): ``e = m_emb Embed[id]``; a
+# layer ``n = RMSNorm(x)``, ``x' = x + m_ao Attn(m_ai n) + m_so SSM(m_si
+# n)``, ``y = x' + MLP(RMSNorm(x'))``; logits ``m_head RMSNorm(y) W_head``
+# (untied); no bias but the convolution's.
+#
+# * Attn: q, k, v into heads of ``head_dim``; ``k <- m_k k`` before the
+#   rope; rotate-half rope (``rope_theta``, no scaling); causal ``softmax(q
+#   k^T / sqrt(Dh)) v``; ``W_o``; no q/k norm.
+# * SSM (Mamba-2): ``[z | xBC | dt] = (u W_in) * mup`` with ``mup`` the
+#   vector that multiplies the ``z``, ``x``, ``B``, ``C``, ``dt`` columns by
+#   ``ssm_multipliers[0..4]``; ``xBC <- silu(conv(xBC) + b)`` (depthwise over
+#   the ``mamba_d_ssm + 2 groups d_state`` columns, ``mamba_d_conv`` taps,
+#   causal, zeros before the sequence); ``x`` into ``mamba_n_heads`` heads of
+#   ``mamba_d_head``, ``B``/``C`` into ``mamba_n_groups`` groups of
+#   ``mamba_d_state`` (head ``h`` reads group ``h // (heads / groups)``);
+#   ``dt = softplus(dt + dt_bias)``; ``a_t = exp(-exp(A_log) dt_t)``; ``h_t =
+#   a_t h_{t-1} + dt_t x_t B_t^T`` (``h_{-1} = 0``); ``y_t = h_t C_t + D x_t``;
+#   ``g = y * silu(z)`` (``mamba_norm_before_gate`` false), an RMSNorm over
+#   EACH GROUP's ``mamba_d_ssm / groups`` columns with a learned scale;
+#   ``g W_out``.
+# * MLP: ``down(silu(m_g gate(v)) * up(v)) * m_d`` (``mlp_multipliers``).
+#
+# ``reset`` is a CONTROL, not the model: a boolean ``(S,)``; where it is
+# true the state ``h`` and the convolution's past taps are ZERO before that
+# token — what a program serves that loses a request's state there.
+#
+# ``params``: ``embed (V, D)``, ``head (D, V)``, ``ln_f`` and ``layers``
+# stacked on a leading axis: ``ln1``, ``ln2``, ``wq (D, H, Dh)``, ``wk``/
+# ``wv (D, H_kv, Dh)``, ``wo (H, Dh, D)``, ``ssm_in (D, 2 d_ssm + 2 G N +
+# heads)``, ``ssm_conv_k (C, K)``, ``ssm_conv_b (C)``, ``ssm_dt_bias``/
+# ``ssm_A_log``/``ssm_D (heads)``, ``ssm_norm (d_ssm)``, ``ssm_out (d_ssm,
+# D)``, ``w_gate``/``w_up (D, F)``, ``w_down (F, D)``.
+
+
+def hybrid_attention(n, w, dims: dict):
+    S, dh = n.shape[0], dims["head_dim"]
+    q = jnp.einsum("sd,dhk->shk", n, w["wq"].astype(F32))
+    k = jnp.einsum("sd,dhk->shk", n, w["wk"].astype(F32))
+    v = jnp.einsum("sd,dhk->shk", n, w["wv"].astype(F32))
+    k = k * dims["key_multiplier"]
+    cos, sin = rope_tables(jnp.arange(S), dh,
+                           {"rope_theta": dims["rope_theta"]})
+    q, k = rotate(q, cos, sin), rotate(k, cos, sin)
+    g = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, g, axis=1), jnp.repeat(v, g, axis=1)
+    s = jnp.einsum("qhd,khd->hqk", q, k) / math.sqrt(dh)
+    vis = jnp.arange(S)[None, :] <= jnp.arange(S)[:, None]
+    p = jax.nn.softmax(jnp.where(vis[None], s, -jnp.inf), axis=-1)
+    o = jnp.einsum("hqk,khd->qhd", p, v)
+    return jnp.einsum("shk,hkd->sd", o, w["wo"].astype(F32))
+
+
+def hybrid_ssm(n, w, dims: dict, reset=None):
+    """The state-space mixer of one sequence ``n`` ``(S, D)``, token by
+    token."""
+    S = n.shape[0]
+    I, H, P = dims["mamba_d_ssm"], dims["mamba_n_heads"], dims["mamba_d_head"]
+    G, N, K = (dims["mamba_n_groups"], dims["mamba_d_state"],
+               dims["mamba_d_conv"])
+    gn = G * N
+    m = dims["ssm_multipliers"]
+    mup = jnp.concatenate([jnp.full((I,), m[0], F32), jnp.full((I,), m[1]),
+                           jnp.full((gn,), m[2]), jnp.full((gn,), m[3]),
+                           jnp.full((H,), m[4])])
+    zxd = (n @ w["ssm_in"].astype(F32)) * mup
+    z, xbc, dt = zxd[:, :I], zxd[:, I:I + I + 2 * gn], zxd[:, 2 * I + 2 * gn:]
+    dt = jax.nn.softplus(dt + w["ssm_dt_bias"].astype(F32))      # (S, H)
+    a_neg = -jnp.exp(w["ssm_A_log"].astype(F32))
+    kern, bias = w["ssm_conv_k"].astype(F32), w["ssm_conv_b"].astype(F32)
+    if reset is None:
+        reset = jnp.zeros((S,), bool)
+
+    def token(carry, inp):
+        h, taps = carry                 # (H, P, N), (K - 1, C)
+        u, dt_t, lost = inp
+        h = jnp.where(lost, 0.0, h)
+        taps = jnp.where(lost, 0.0, taps)
+        window = jnp.concatenate([taps, u[None]])               # (K, C)
+        act = jax.nn.silu(jnp.sum(window * kern.T, axis=0) + bias)
+        x = act[:I].reshape(H, P)
+        b = jnp.repeat(act[I:I + gn].reshape(G, N), H // G, axis=0)
+        c = jnp.repeat(act[I + gn:].reshape(G, N), H // G, axis=0)
+        a = jnp.exp(a_neg * dt_t)
+        h = a[:, None, None] * h + (dt_t[:, None] * x)[:, :, None] \
+            * b[:, None, :]
+        y = jnp.einsum("hpn,hn->hp", h, c) + w["ssm_D"].astype(F32)[
+            :, None] * x
+        return (h, window[1:]), y.reshape(I)
+
+    init = (jnp.zeros((H, P, N), F32), jnp.zeros((K - 1, xbc.shape[1]), F32))
+    _, y = jax.lax.scan(token, init, (xbc, dt, reset))
+    g = (y * jax.nn.silu(z)).reshape(S, G, I // G)
+    g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), axis=-1, keepdims=True)
+                          + dims["rms_norm_eps"])
+    return (g.reshape(S, I) * w["ssm_norm"].astype(F32)) @ w[
+        "ssm_out"].astype(F32)
+
+
+def hybrid_layer(x, w, dims: dict, reset=None):
+    eps = dims["rms_norm_eps"]
+    n = rmsnorm(x, w["ln1"], eps)
+    h = (x + dims["attention_out_multiplier"] * hybrid_attention(
+        n * dims["attention_in_multiplier"], w, dims)
+        + dims["ssm_out_multiplier"] * hybrid_ssm(
+            n * dims["ssm_in_multiplier"], w, dims, reset))
+    v = rmsnorm(h, w["ln2"], eps)
+    m_g, m_d = dims["mlp_multipliers"]
+    gate = jax.nn.silu((v @ w["w_gate"].astype(F32)) * m_g)
+    return h + ((gate * (v @ w["w_up"].astype(F32)))
+                @ w["w_down"].astype(F32)) * m_d
+
+
+def hybrid_forward(params, tokens, dims: dict, reset=None):
+    """Logits ``(S, V)`` float32 of one sequence ``tokens`` ``(S,)``."""
+    with jax.default_matmul_precision("highest"):
+        x = params["embed"].astype(F32)[tokens] * dims["embedding_multiplier"]
+        for l in range(dims["num_hidden_layers"]):
+            w = jax.tree_util.tree_map(lambda a: a[l], params["layers"])
+            x = hybrid_layer(x, w, dims, reset)
+        x = rmsnorm(x, params["ln_f"], dims["rms_norm_eps"])
+        return (x @ params["head"].astype(F32)) * dims["lm_head_multiplier"]

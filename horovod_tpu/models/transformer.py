@@ -41,6 +41,16 @@ Design notes (TPU):
   (``hvd_dsa_attend``) in the tick, in a chunk and in a whole prompt —
   and a router that chooses under a score-correction bias
   (``moe_score_bias``).
+* Serving only: layers of TWO mixers (``layer_pattern=("hybrid",)``):
+  attention and a state-space mixer (Mamba-2: ``ssm_heads`` and its
+  sizes) side by side on one normed input, summed before the residual,
+  under a width-transfer parametrisation's published multipliers; every
+  layer keeps pages AND a per-slot state (the mixer's matrix a head and
+  its convolution's taps).  The mixer's two bodies are
+  :mod:`horovod_tpu.ops.ssm` (a tick's update in place,
+  ``hvd_ssm_update``; a prompt's chunked scan, ``hvd_ssm_scan``), its
+  projections, convolution and gate under ``hvd_ssm_in`` /
+  ``hvd_ssm_conv`` / ``hvd_ssm_out``.
 """
 
 from __future__ import annotations
@@ -58,7 +68,17 @@ from jax.sharding import PartitionSpec as P
 
 
 #: The kinds of layer a ``layer_pattern`` may name.
-LAYER_KINDS = ("full", "sliding", "conv")
+LAYER_KINDS = ("full", "sliding", "conv", "hybrid")
+
+#: What a layer of each kind keeps for a request, by the page pool's
+#: names: pages of keys and values (``k``/``v``, their scales when
+#: quantized, an indexer's keys ``ik``; a window layer's are a pool of
+#: their own, ``wk``/``wv``), a short convolution's last inputs
+#: (``conv``, a slot), a state-space mixer's matrix state (``ssm``, a
+#: slot).  A hybrid layer owns pages AND both states.
+_POOL_ARRAYS = {"full": ("k", "v", "k_scale", "v_scale", "ik"),
+                "sliding": ("wk", "wv"), "conv": ("conv",),
+                "hybrid": ("k", "v", "conv", "ssm")}
 
 
 class UnsupportedModelConfigError(ValueError):
@@ -224,6 +244,39 @@ class TransformerConfig:
     kv_lane_dense: bool = False
     # ``norm_topk_prob`` divides by ``sum + norm_topk_eps``.
     norm_topk_eps: float = 0.0
+    # A "hybrid" layer of the pattern: attention AND a STATE-SPACE mixer
+    # (Mamba-2) side by side on ONE normed input ``n``, summed before
+    # the residual — ``x + m_ao Attn(m_ai n) + m_so SSM(m_si n)``.  The
+    # mixer has ``ssm_heads`` heads of ``ssm_head_dim`` (``ssm_inner`` =
+    # their product), a state of ``ssm_state`` columns a head row
+    # (``d_state``), ``ssm_groups`` groups that share ``B``/``C``, a
+    # depthwise causal convolution of ``conv_kernel`` taps (with a bias)
+    # over ``[x | B | C]`` (:attr:`ssm_conv_width`), a gated RMSNorm
+    # over each group and the dual form's block ``ssm_chunk`` for
+    # prompts: :func:`_ssm_in` .. :func:`_ssm_out`.  Decoding keeps the
+    # ``(ssm_head_dim, ssm_state)`` matrix a head and the last
+    # ``conv_kernel - 1`` pre-activation ``[x | B | C]`` a layer and
+    # request.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_chunk: int = 128
+    # The published multipliers of a model parametrised for width
+    # transfer (1 / () = none): on the embedding's rows, on the logits,
+    # on the attention's input, output and keys (before the rope), on
+    # the state-space branch's input and output, on the five parts ``(z,
+    # x, B, C, dt)`` of its projection's output, and on the MLP's gate
+    # (before its activation) and output.
+    embed_multiplier: float = 1.0
+    head_multiplier: float = 1.0
+    attn_in_multiplier: float = 1.0
+    attn_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: tuple = ()
+    mlp_multipliers: tuple = ()
 
     def __post_init__(self):
         mla = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
@@ -281,6 +334,24 @@ class TransformerConfig:
             raise UnsupportedModelConfigError(
                 "conv layers together with window layers or latent "
                 "attention are not written")
+        if self.has_ssm and (set(self.layer_pattern) != {"hybrid"}
+                             or self.latent or self.kv_lane_dense):
+            raise UnsupportedModelConfigError(
+                "a two-mixer ('hybrid') layer together with window, "
+                "latent or conv layers, or KV heads sharing a stored "
+                "row, is not written")
+        if self.has_ssm and not (
+                self.ssm_heads > 0 and self.ssm_head_dim > 0
+                and self.ssm_state > 0 and self.conv_kernel >= 2
+                and self.ssm_groups > 0
+                and self.ssm_heads % self.ssm_groups == 0
+                and len(self.ssm_multipliers) in (0, 5)):
+            raise ValueError(
+                "a 'hybrid' layer needs ssm_heads (a multiple of "
+                "ssm_groups), ssm_head_dim, ssm_state, conv_kernel >= 2 "
+                "and none or five ssm_multipliers")
+        if len(self.mlp_multipliers) not in (0, 2):
+            raise ValueError("mlp_multipliers is (gate, down) or ()")
         if self.kv_lane_dense and (
                 self.latent or self.head_dim >= 128 or 128 % self.head_dim
                 or self.kv_heads % (128 // self.head_dim)):
@@ -392,6 +463,40 @@ class TransformerConfig:
         return self.conv_kernel - 1
 
     @property
+    def has_ssm(self) -> bool:
+        """Is any layer a hybrid one (a state-space mixer beside its
+        attention: a matrix state and a convolution's taps a slot)?"""
+        return "hybrid" in self.layer_pattern
+
+    @property
+    def has_state(self) -> bool:
+        """Does a request keep a state of fixed size beside its pages
+        (``pool["conv"]``, ``pool["ssm"]``)?"""
+        return self.has_conv or self.has_ssm
+
+    @property
+    def ssm_inner(self) -> int:
+        """The state-space mixer's width (``d_ssm``)."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_width(self) -> int:
+        """What its short convolution runs over: ``[x | B | C]``."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def conv_width(self) -> int:
+        """The width of the taps ``pool["conv"]`` keeps: a conv layer's
+        gated inputs are ``d_model`` wide, a hybrid layer's ``[x | B |
+        C]`` :attr:`ssm_conv_width`."""
+        return self.ssm_conv_width if self.has_ssm else self.d_model
+
+    def layers_with(self, array: str) -> int:
+        """How many layers keep the pool array ``array`` for a request
+        (:data:`_POOL_ARRAYS`): by what a kind carries, not its name."""
+        return sum(array in _POOL_ARRAYS[k] for k in self.layer_kinds)
+
+    @property
     def layer_kinds(self) -> tuple:
         """Every layer's kind: the pattern repeated from layer 0 on,
         through the leading dense layers and the rest alike."""
@@ -445,6 +550,26 @@ def init_params(rng, cfg: TransformerConfig) -> Dict:
         s_d, s_f = 1.0 / np.sqrt(D), 1.0 / np.sqrt(F)
         layers = {"ln1": jnp.ones((L, D), jnp.float32),
                   "ln2": jnp.ones((L, D), jnp.float32)}
+        if cfg.has_ssm:     # beside the attention's leaves, every layer
+            sk = jax.random.split(jax.random.fold_in(keys[0], 13), 5)
+            Hs, I, C = cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_conv_width
+            # Mamba-2's published initialisation: A uniform in 1-16, dt
+            # log-uniform in 1e-3..1e-1 through the inverse softplus
+            dt0 = jnp.exp(jax.random.uniform(sk[3], (L, Hs)) * (
+                math.log(1e-1) - math.log(1e-3)) + math.log(1e-3))
+            layers.update(
+                ssm_in=norm_init(sk[0], (L, D, I + C + Hs), s_d),
+                ssm_conv_k=norm_init(sk[1], (L, C, cfg.conv_kernel),
+                                     1.0 / np.sqrt(cfg.conv_kernel)),
+                ssm_conv_b=jnp.zeros((L, C), jnp.float32),
+                ssm_dt_bias=(dt0 + jnp.log(-jnp.expm1(-dt0))).astype(
+                    jnp.float32),
+                ssm_A_log=jnp.log(jax.random.uniform(
+                    sk[4], (L, Hs), minval=1.0, maxval=16.0)).astype(
+                        jnp.float32),
+                ssm_D=jnp.ones((L, Hs), jnp.float32),
+                ssm_norm=jnp.ones((L, I), jnp.float32),
+                ssm_out=norm_init(sk[2], (L, I, D), 1.0 / np.sqrt(I)))
         if La < L:
             ck = jax.random.split(jax.random.fold_in(keys[0], 11), 3)
             layers.update(
@@ -740,7 +865,7 @@ def _require_uniform(cfg: TransformerConfig, what: str) -> None:
     """Refuse a configuration with more than one kind of layer where
     only the uniform block is written (training, the single-request
     and speculative decode bodies, the pipeline schedules)."""
-    if cfg.has_window or cfg.has_conv:
+    if cfg.has_window or cfg.has_state:
         raise UnsupportedModelConfigError(
             f"{what} computes one kind of layer; this configuration's "
             f"pattern {cfg.layer_pattern} (window {cfg.window}) is served "
@@ -772,6 +897,10 @@ _EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
 _ATTN_LEAVES = ("wq", "wk", "wv", "wo", "q_norm", "k_norm")
 _MIXER_LEAVES = {"full": _ATTN_LEAVES, "sliding": _ATTN_LEAVES,
                  "conv": ("conv_in", "conv_k", "conv_out")}
+#: (A hybrid layer's two mixers' leaves — the attention's beside
+#: ``ssm_in``, ``ssm_conv_k``/``ssm_conv_b``, ``ssm_dt_bias``,
+#: ``ssm_A_log``, ``ssm_D``, ``ssm_norm``, ``ssm_out`` — are stacked
+#: over all the layers: a stack of them is uniform.)
 
 
 def _scan_layer_kinds(cfg: TransformerConfig, layer, init, layers, xs=None,
@@ -903,7 +1032,10 @@ def _scan_layer_kinds(cfg: TransformerConfig, layer, init, layers, xs=None,
 
 def _embed(params, tokens, cfg: TransformerConfig):
     with jax.named_scope("embed"):
-        return params["embed"].astype(cfg.dtype)[tokens]
+        e = params["embed"].astype(cfg.dtype)[tokens]
+        if cfg.embed_multiplier != 1.0:
+            e = e * jnp.asarray(cfg.embed_multiplier, e.dtype)
+        return e
 
 
 def _attn_norm(x, p, cfg: TransformerConfig):
@@ -935,6 +1067,8 @@ def _qkv_proj(x, p, cfg: TransformerConfig, pos_offset=0, positions=None,
 
     with jax.named_scope("attn_qkv"):
         q, k, v = heads(p["wq"]), heads(p["wk"]), heads(p["wv"])
+        if cfg.key_multiplier != 1.0:
+            k = k * jnp.asarray(cfg.key_multiplier, k.dtype)
         if cfg.qk_norm:
             q = _rmsnorm(q, p["q_norm"], cfg.norm_eps)
             k = _rmsnorm(k, p["k_norm"], cfg.norm_eps)
@@ -1006,11 +1140,43 @@ def _conv_in(x, p, cfg: TransformerConfig):
         return c, b * xx
 
 
-def _conv_taps(ext, p, n: int):
+def _conv_taps(ext, p, n: int, leaf: str = "conv_k"):
     """``sum_j k[:, j] ext[:, j : j + n]`` in float32: the ``n``
-    outputs whose ``K`` inputs ``ext`` ``(B, n + K - 1, D)`` holds."""
-    k = p["conv_k"].astype(jnp.float32)
+    outputs whose ``K`` inputs ``ext`` ``(B, n + K - 1, D)`` holds
+    (``leaf``: the depthwise kernel's name in ``p``)."""
+    k = p[leaf].astype(jnp.float32)
     return sum(ext[:, j:j + n] * k[:, j] for j in range(k.shape[1]))
+
+
+def _taps_over(state, u, p, true_len, leaf: str = "conv_k"):
+    """A prompt's or a chunk's depthwise causal convolution of ``u``
+    ``(B, S, C)`` from the taps before it: ``(outputs, new taps)``.
+    ``state`` ``(B, K - 1, C)`` holds the ``K - 1`` inputs before ``u``
+    (None: the zeros a sequence starts from); the new taps are the
+    inputs at the ``K - 1`` positions before ``true_len`` ``(B,)`` —
+    out of ``state`` where the row is shorter than that, never from
+    the padding."""
+    B, S, C = u.shape
+    taps = p[leaf].shape[1] - 1
+    if state is None:
+        state = jnp.zeros((B, taps, C), u.dtype)
+    ext = jnp.concatenate([state.astype(u.dtype), u], axis=1)
+    v = _conv_taps(ext, p, S, leaf)
+    # ext[i] is position i - taps: the state ends at position len - 1
+    idx = true_len[:, None] + jnp.arange(taps, dtype=jnp.int32)
+    return v, jnp.take_along_axis(ext, idx[:, :, None], axis=1)
+
+
+def _taps_step(states, layer, u, p, active, leaf: str = "conv_k"):
+    """A tick's one position of that convolution, ``u`` ``(S, 1, C)``:
+    ``(output, states)`` with ``states`` ``(L, S, K - 1, C)`` read and
+    written IN PLACE at ``layer``; a row that is not ``active`` keeps
+    its taps."""
+    old = lax.dynamic_index_in_dim(states, layer, 0, keepdims=False)
+    ext = jnp.concatenate([old.astype(u.dtype), u], axis=1)
+    v = _conv_taps(ext, p, 1, leaf)
+    new = jnp.where(active[:, None, None], ext[:, 1:].astype(old.dtype), old)
+    return v, lax.dynamic_update_index_in_dim(states, new, layer, 0)
 
 
 def _conv_out(c, v, p, cfg: TransformerConfig):
@@ -1027,16 +1193,8 @@ def _conv_prefill(x, p, cfg: TransformerConfig, state, true_len):
     ``K - 1`` positions before ``true_len`` ``(B,)`` — out of ``state``
     where the row is shorter than that, never from the padding."""
     c, u = _conv_in(x, p, cfg)
-    B, S, D = u.shape
-    taps = cfg.conv_taps
     with jax.named_scope("hvd_conv_scan"):
-        if state is None:
-            state = jnp.zeros((B, taps, D), u.dtype)
-        ext = jnp.concatenate([state.astype(u.dtype), u], axis=1)
-        v = _conv_taps(ext, p, S)
-        # ext[i] is position i - taps: the state ends at position len - 1
-        idx = true_len[:, None] + jnp.arange(taps, dtype=jnp.int32)
-        new = jnp.take_along_axis(ext, idx[:, :, None], axis=1)
+        v, new = _taps_over(state, u, p, true_len)
     return _conv_out(c, v, p, cfg), new.astype(cfg.dtype)
 
 
@@ -1049,13 +1207,136 @@ def _conv_decode(x, p, cfg: TransformerConfig, states, layer, active):
     its state."""
     c, u = _conv_in(x, p, cfg)
     with jax.named_scope("hvd_conv_update"):
-        old = lax.dynamic_index_in_dim(states, layer, 0, keepdims=False)
-        ext = jnp.concatenate([old.astype(u.dtype), u], axis=1)
-        v = _conv_taps(ext, p, 1)
-        new = jnp.where(active[:, None, None], ext[:, 1:].astype(old.dtype),
-                        old)
-        states = lax.dynamic_update_index_in_dim(states, new, layer, 0)
+        v, states = _taps_step(states, layer, u, p, active)
     return _conv_out(c, v, p, cfg), states
+
+
+# --- the state-space mixer (a "hybrid" layer's second mixer) -----------------
+#
+# Mamba-2 beside the attention, on the same normed input ``n``:
+# ``[z | xBC | dt] = (m_si n) W_in * mup`` (``mup``: the five published
+# multipliers laid over the parts' columns); ``xBC <- silu(conv(xBC) +
+# b)`` (depthwise, causal, zeros before the sequence); ``x`` into heads,
+# ``B``/``C`` into groups; ``dt = softplus(dt + dt_bias)`` a head; the
+# recurrence ``S_t = exp(-exp(A_log) dt_t) S_{t-1} + (dt_t x_t) B_t^T``,
+# ``y_t = S_t C_t + D x_t`` (:mod:`horovod_tpu.ops.ssm`); ``g = y *
+# silu(z)``, an RMSNorm over EACH GROUP's columns with a learned scale;
+# ``g W_out``.  In float32 from the projection's accumulator to the
+# operand of ``W_out`` (as a conv layer's gates: :func:`_conv_in`).
+# What a request carries: the matrix ``S`` a head and the last ``K - 1``
+# PRE-activation ``xBC``.  Five scopes cut it out of a trace:
+# ``hvd_ssm_in``, ``hvd_ssm_conv``, ``hvd_ssm_scan`` (a prompt, a chunk)
+# / ``hvd_ssm_update`` (a tick; the kernel's name too), ``hvd_ssm_out``.
+
+
+def _ssm_in(n, p, cfg: TransformerConfig):
+    """``(z, xBC, dt)`` float32 of the layer's NORMED input ``n``."""
+    with jax.named_scope("hvd_ssm_in"):
+        if cfg.ssm_in_multiplier != 1.0:
+            n = n * jnp.asarray(cfg.ssm_in_multiplier, n.dtype)
+        out = jnp.einsum("bsd,dn->bsn", n, p["ssm_in"].astype(cfg.dtype),
+                         preferred_element_type=jnp.float32)
+        I, C = cfg.ssm_inner, cfg.ssm_conv_width
+        if cfg.ssm_multipliers:
+            gn = cfg.ssm_groups * cfg.ssm_state
+            out = out * np.repeat(
+                np.asarray(cfg.ssm_multipliers, np.float32),
+                (I, I, gn, gn, cfg.ssm_heads))
+        return out[..., :I], out[..., I:I + C], out[..., I + C:]
+
+
+def _ssm_split(xbc, dt, p, cfg: TransformerConfig):
+    """The activated ``xBC`` and the raw ``dt`` as the recurrence takes
+    them: ``x (.., H, P)``, ``B``/``C`` ``(.., G, N)``, ``dt (.., H)``
+    after its bias and softplus, ``A (H,)`` negative."""
+    I, gn = cfg.ssm_inner, cfg.ssm_groups * cfg.ssm_state
+    lead = xbc.shape[:-1]
+    x = xbc[..., :I].reshape(*lead, cfg.ssm_heads, cfg.ssm_head_dim)
+    b = xbc[..., I:I + gn].reshape(*lead, cfg.ssm_groups, cfg.ssm_state)
+    c = xbc[..., I + gn:].reshape(*lead, cfg.ssm_groups, cfg.ssm_state)
+    dt = jax.nn.softplus(dt + p["ssm_dt_bias"].astype(jnp.float32))
+    return x, b, c, dt, -jnp.exp(p["ssm_A_log"].astype(jnp.float32))
+
+
+def _ssm_out(y, x, z, p, cfg: TransformerConfig):
+    """The skip ``D x``, the gate, the norm over each group, ``W_out``:
+    ``y``/``x`` ``(.., H, P)``, ``z (.., I)`` float32."""
+    with jax.named_scope("hvd_ssm_out"):
+        y = y + p["ssm_D"].astype(jnp.float32)[:, None] * x
+        g = y.reshape(z.shape) * jax.nn.silu(z)
+        grp = g.reshape(*g.shape[:-1], cfg.ssm_groups, -1)
+        grp = grp * lax.rsqrt(jnp.mean(jnp.square(grp), axis=-1,
+                                       keepdims=True) + cfg.norm_eps)
+        g = grp.reshape(g.shape) * p["ssm_norm"].astype(jnp.float32)
+        return jnp.einsum("bsi,id->bsd", g.astype(cfg.dtype),
+                          p["ssm_out"].astype(cfg.dtype))
+
+
+def _ssm_prefill(n, p, cfg: TransformerConfig, conv_state, ssm_state,
+                 true_len):
+    """A hybrid layer's state-space mixer over a prompt or a chunk,
+    ``n`` ``(B, S, D)`` normed: ``(output, new taps, new state)``.
+    ``conv_state`` ``(B, K - 1, C)`` / ``ssm_state`` ``(B, H, P, N)``
+    are the request's before ``n`` (None: the zeros a sequence starts
+    from); the new ones stand at ``true_len`` ``(B,)`` — the padding
+    behind it changes neither."""
+    from horovod_tpu.ops import ssm
+
+    z, xbc, dt = _ssm_in(n, p, cfg)
+    B, S, _ = xbc.shape
+    with jax.named_scope("hvd_ssm_conv"):
+        v, new_conv = _taps_over(conv_state, xbc, p, true_len, "ssm_conv_k")
+        act = jax.nn.silu(v + p["ssm_conv_b"].astype(jnp.float32))
+
+    with jax.named_scope("hvd_ssm_scan"):
+        x, b, c, dt, a_neg = _ssm_split(act, dt, p, cfg)
+        real = jnp.arange(S, dtype=jnp.int32)[None, :] < true_len[:, None]
+        if ssm_state is None:
+            ssm_state = jnp.zeros((B, cfg.ssm_heads, cfg.ssm_head_dim,
+                                   cfg.ssm_state), jnp.float32)
+        y, new = ssm.ssm_scan(x, jnp.where(real[..., None], dt, 0.0), a_neg,
+                              b, c, ssm_state, chunk=cfg.ssm_chunk,
+                              dtype=cfg.dtype)
+    return (_ssm_out(y, x, z, p, cfg), new_conv.astype(cfg.dtype),
+            new.astype(cfg.dtype))
+
+
+def _ssm_decode(n, p, cfg: TransformerConfig, taps, states, layer, active,
+                kernel: bool):
+    """A hybrid layer's state-space mixer for one token a slot, ``n``
+    ``(S, 1, D)`` normed: ``(output, taps, states)`` with ``taps`` ``(L,
+    S, K - 1, C)`` and ``states`` ``(L, S, H, P, N)`` every layer's and
+    slot's, both read and written IN PLACE at ``layer`` (a layer scan's
+    loop state, like a page pool).  A row that is not ``active`` keeps
+    both."""
+    from horovod_tpu.ops import ssm
+
+    z, xbc, dt = _ssm_in(n, p, cfg)
+    with jax.named_scope("hvd_ssm_conv"):
+        v, taps = _taps_step(taps, layer, xbc, p, active, "ssm_conv_k")
+        act = jax.nn.silu(v + p["ssm_conv_b"].astype(jnp.float32))
+    with jax.named_scope("hvd_ssm_update"):
+        x, b, c, dt, a_neg = _ssm_split(act[:, 0], dt[:, 0], p, cfg)
+        y, states = ssm.ssm_update(states, layer, x, dt, a_neg, b, c,
+                                   active, kernel=kernel)
+    return _ssm_out(y[:, None], x[:, None], z, p, cfg), taps, states
+
+
+def _mix(h_attn, h_ssm, cfg: TransformerConfig):
+    """A hybrid layer's two branches summed, each under its output
+    multiplier."""
+    if cfg.attn_out_multiplier != 1.0:
+        h_attn = h_attn * jnp.asarray(cfg.attn_out_multiplier, h_attn.dtype)
+    if cfg.ssm_out_multiplier != 1.0:
+        h_ssm = h_ssm * jnp.asarray(cfg.ssm_out_multiplier, h_ssm.dtype)
+    return h_attn + h_ssm
+
+
+def _attn_in(n, cfg: TransformerConfig):
+    """The attention's input of a hybrid layer's normed ``n``."""
+    if cfg.attn_in_multiplier == 1.0:
+        return n
+    return n * jnp.asarray(cfg.attn_in_multiplier, n.dtype)
 
 
 # --- latent attention (MLA) ---------------------------------------------------
@@ -1445,6 +1726,10 @@ def _attention_core(qh, kh, vh, cfg: TransformerConfig, attn):
 def _dense_mlp(x, p, cfg: TransformerConfig):
     g = jnp.einsum("bsd,df->bsf", x, p["w_gate"].astype(cfg.dtype))
     u = jnp.einsum("bsd,df->bsf", x, p["w_up"].astype(cfg.dtype))
+    if cfg.mlp_multipliers:     # (gate, down)
+        m_g, m_d = (jnp.asarray(m, g.dtype) for m in cfg.mlp_multipliers)
+        return jnp.einsum("bsf,fd->bsd", jax.nn.silu(g * m_g) * u,
+                          p["w_down"].astype(cfg.dtype)) * m_d
     return jnp.einsum("bsf,fd->bsd", jax.nn.silu(g) * u, p["w_down"].astype(cfg.dtype))
 
 
@@ -1650,8 +1935,11 @@ def _lm_head(y, ln_f, head, cfg: TransformerConfig):
     with jax.named_scope("head"):
         h = _rmsnorm(y, ln_f, cfg.norm_eps)
         how = "bsd,vd->bsv" if cfg.tie_embeddings else "bsd,dv->bsv"
-        return jnp.einsum(how, h, head.astype(cfg.dtype)).astype(
+        logits = jnp.einsum(how, h, head.astype(cfg.dtype)).astype(
             jnp.float32)
+        if cfg.head_multiplier != 1.0:
+            logits = logits * cfg.head_multiplier
+        return logits
 
 
 def _xent_sum(logits, targets):
@@ -2301,17 +2589,15 @@ def _gather_kv(k_pool, v_pool, k_scale, v_scale, layer, table,
     return kg, vg
 
 
-_POOL_ARRAYS = {"full": ("k", "v", "k_scale", "v_scale", "ik"),
-                "sliding": ("wk", "wv"), "conv": ("conv",)}
-
-
 def _kind_pools(pool: Dict, cfg: TransformerConfig):
     """The names of a paged pool's stacked arrays by layer kind — the
     full layers' ``k``/``v`` (with their scales when quantized), for
     a configuration with window layers those layers' own ``wk``/``wv``,
-    for one with conv layers their per-slot state ``conv`` — for the
-    kinds this configuration HAS: a uniform model carries no second
-    stack, a model of window layers alone no first."""
+    for one with conv layers their per-slot state ``conv``, for one of
+    hybrid layers pages AND ``conv`` AND ``ssm`` of every layer — for
+    the kinds this configuration HAS (:data:`_POOL_ARRAYS`): a uniform
+    model carries no second stack, a model of window layers alone no
+    first."""
     quantized = "k_scale" in pool
     if cfg.has_window and (quantized or "wk" not in pool):
         raise UnsupportedModelConfigError(
@@ -2366,7 +2652,11 @@ def decode_step_paged(params: Dict, tokens_t, pool: Dict, table,
     ``pos - window`` may be released.  One with conv layers
     (``cfg.has_conv``) holds their per-slot state beside the pages,
     ``pool["conv"]`` ``(L_conv, S, K - 1, D)``, read and written in
-    place like them (:func:`_conv_decode`).  An expert model routes each
+    place like them (:func:`_conv_decode`).  One of hybrid layers
+    (``cfg.has_ssm``) holds all three in EVERY layer: ``k``/``v`` pages,
+    the mixer's taps ``pool["conv"]`` ``(L, S, K - 1, C)`` and its
+    matrix states ``pool["ssm"]`` ``(L, S, H, P, N)``
+    (:func:`_ssm_decode`).  An expert model routes each
     active row to its ``n_experts_per_tok`` experts through the
     dropless grouped products — ``S * k`` expert rows a tick, idle
     slots none; ``return_moe_load`` adds :func:`moe_load` of the tick
@@ -2394,6 +2684,14 @@ def decode_step_paged(params: Dict, tokens_t, pool: Dict, table,
         if kind == "conv":      # its state: the slots' last inputs
             h, conv = _conv_decode(x, p, cfg, pools["conv"], i, active)
             kv = (conv,)
+        elif kind == "hybrid":  # pages AND two states, one normed input
+            n = _attn_norm(x, p, cfg)
+            h, kv = _attention_decode_paged(
+                _attn_in(n, cfg), p, cfg, (pools["k"], pools["v"]), i,
+                table, pos, active, kernel=kernel, mesh=mesh, kind=kind)
+            hs, conv, ssm = _ssm_decode(n, p, cfg, pools["conv"],
+                                        pools["ssm"], i, active, kernel)
+            h, kv = _mix(h, hs, cfg), kv + (conv, ssm)
         else:
             h, kv = _attention_decode_paged(
                 _attn_norm(x, p, cfg), p, cfg,
@@ -2718,6 +3016,8 @@ def _by_kind(ys: Dict, pos, full=("k", "v")) -> Dict:
         out["wk"], out["wv"] = ys["sliding"]
     if "conv" in ys:      # (L_conv, B, K - 1, D): the rows' new state
         out["conv"] = ys["conv"]
+    if "hybrid" in ys:    # pages' rows, taps and matrix states
+        out.update(zip(_POOL_ARRAYS["hybrid"], ys["hybrid"]))
     return out
 
 
@@ -2725,7 +3025,7 @@ def prefill_with_prefix(params: Dict, suffix, prefix_k, prefix_v,
                         prefix_len, cfg: TransformerConfig, *,
                         true_len, moe_impl: str = "dropless",
                         win_k=None, win_v=None, win_start=0,
-                        conv_state=None):
+                        conv_state=None, ssm_state=None):
     """Prefill a (K, S0) SUFFIX whose first ``prefix_len`` logical
     positions already exist as cached K/V — the prefix-sharing prefill:
     a registered system prompt is prefilled ONCE, and every request
@@ -2773,7 +3073,10 @@ def prefill_with_prefix(params: Dict, suffix, prefix_k, prefix_v,
     inputs, as the chunk before left them; the returned block carries
     the state at ``prefix_len + true_len`` as ``conv``.  A pool whose
     rows several KV heads share (``cfg.kv_pack``) hands the prefix over
-    as it stores it."""
+    as it stores it.  With hybrid layers (``cfg.has_ssm``) every layer
+    takes its landed K/V AND ``conv_state`` ``(L, K, taps, C)`` AND
+    ``ssm_state`` ``(L, K, H, P, N)``, and the block carries ``k``,
+    ``v``, ``conv`` and ``ssm``."""
     K, S0 = suffix.shape
     P0 = prefix_k.shape[2]
     p0 = jnp.asarray(prefix_len, jnp.int32)
@@ -2822,6 +3125,9 @@ def prefill_with_prefix(params: Dict, suffix, prefix_k, prefix_v,
     masks = {"full": jnp.concatenate([pre_vis, suf_vis],
                                      axis=1)[None, None, None]}
     xs = {"full": (prefix_k, prefix_v)}
+    if cfg.has_ssm:
+        masks["hybrid"] = masks["full"]
+        xs = {"hybrid": (prefix_k, prefix_v, conv_state, ssm_state)}
     if cfg.has_window:
         # The window block's column j is logical position win_start + j:
         # landed (< p0; the gather's padding lies past it) and within
@@ -2843,9 +3149,11 @@ def prefill_with_prefix(params: Dict, suffix, prefix_k, prefix_v,
         if kind == "conv":      # kv: the rows' state, (K, taps, D)
             out, new = _conv_prefill(x, p, cfg, kv, true_len)
             return _mlp_block(x + out, p, cfg, moe_impl=moe_impl), new
-        pk, pv = kv
+        pk, pv, *state = kv
         P0, mask = pk.shape[1], masks[kind]
-        h = _attn_norm(x, p, cfg)
+        h = n = _attn_norm(x, p, cfg)
+        if kind == "hybrid":
+            h = _attn_in(n, cfg)
         qh, kh, vh = _qkv_proj(h, p, cfg, positions=positions, kind=kind)
         with jax.named_scope("chunk_attn"):
             k_full = jnp.concatenate(
@@ -2866,6 +3174,10 @@ def prefill_with_prefix(params: Dict, suffix, prefix_k, prefix_v,
                            v_full, preferred_element_type=jnp.float32)
             oh = o.reshape(K, H, S0, Dh)
         out = _out_proj(oh.astype(cfg.dtype), p, cfg)
+        if kind == "hybrid":    # from the taps and the state before it
+            hs, *new = _ssm_prefill(n, p, cfg, *state, true_len)
+            return (_mlp_block(x + _mix(out, hs, cfg), p, cfg,
+                               moe_impl=moe_impl), (kh, vh, *new))
         return _mlp_block(x + out, p, cfg, moe_impl=moe_impl), (kh, vh)
 
     x, ys = _scan_layer_kinds(cfg, layer, x, params["layers"], xs,
@@ -2959,7 +3271,8 @@ def prefill(params: Dict, prompt, cache: Dict, cfg: TransformerConfig,
     engine's two pools; ``cache`` then only gives ``pos``.  So with
     conv layers (``cfg.has_conv``): ``k``/``v`` over the attention
     layers and ``conv`` ``(L_conv, B, taps, D)``, each row's state at
-    its ``true_len``."""
+    its ``true_len``; and with hybrid layers (``cfg.has_ssm``) ``k``/
+    ``v``, ``conv`` and ``ssm`` ``(L, B, H, P, N)`` over every layer."""
     pos = cache["pos"]
     if not isinstance(pos, jax.core.Tracer) and int(pos) != 0:
         raise ValueError("prefill requires a fresh cache (pos == 0)")
@@ -2970,7 +3283,7 @@ def prefill(params: Dict, prompt, cache: Dict, cfg: TransformerConfig,
             f"prompt ({S0} tokens) exceeds cache capacity ({T_cache}); "
             "init_cache with a larger max_len")
     x = _embed(params, prompt, cfg)
-    if cfg.has_conv:            # each row's real length, for its state
+    if cfg.has_state:           # each row's real length, for its state
         lens = jnp.broadcast_to(jnp.asarray(
             S0 if true_len is None else true_len, jnp.int32),
             prompt.shape[:1])
@@ -2979,6 +3292,13 @@ def prefill(params: Dict, prompt, cache: Dict, cfg: TransformerConfig,
         if kind == "conv":      # from the zeros a sequence starts from
             h, new = _conv_prefill(x, p, cfg, None, lens)
             return _mlp_block(x + h, p, cfg, moe_impl=moe_impl), new
+        if kind == "hybrid":    # both mixers from zeros, one normed input
+            n = _attn_norm(x, p, cfg)
+            h, kh, vh = _attention_prefill(_attn_in(n, cfg), p, cfg, mesh,
+                                           kind)
+            hs, *new = _ssm_prefill(n, p, cfg, None, None, lens)
+            return (_mlp_block(x + _mix(h, hs, cfg), p, cfg,
+                               moe_impl=moe_impl), (kh, vh, *new))
         h, kh, vh = _attention_prefill(_attn_norm(x, p, cfg), p, cfg, mesh,
                                        kind)
         # Prefill ingests whole prompts: DROPLESS grouped-matmul dispatch
@@ -3006,7 +3326,7 @@ def prefill(params: Dict, prompt, cache: Dict, cfg: TransformerConfig,
                                    axis=1)
         new_pos = pos + true_len
     logits = _lm_head(last, params["ln_f"], _head(params, cfg), cfg)
-    if cfg.has_window or cfg.has_conv:
+    if cfg.has_window or cfg.has_state:
         # two kinds of state: handed back by kind for the caller's
         # pools, not landed in a cache of one shape
         return logits[:, 0], _by_kind(ys, new_pos)

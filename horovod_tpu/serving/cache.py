@@ -31,7 +31,12 @@ the pool dict, so it is donated, carried and written in place with
 them.  It is granted with the slot and ZEROED then (:meth:`PagedSlotCache
 .alloc`), written by the tick for the active rows and by a landing for
 the landed rows (:func:`paged_insert`), and read back for a prompt's
-next chunk (:meth:`PagedSlotCache.slot_state`).
+next chunk (:meth:`PagedSlotCache.slot_state`).  A model of hybrid
+layers (attention and a state-space mixer side by side) keeps BOTH in
+every layer: pages, the mixer's short convolution's taps in ``conv``
+(``[x | B | C]`` wide, not ``D``) and a THIRD array ``ssm`` ``(L, S, H,
+P, N)``, the mixer's matrix state a head — MBs a slot and layer where
+the taps are KBs — under the same grant, zeroing, landing and read-back.
 
 (Until PR 28 a slot-contiguous ``(L, S, H_kv, T, Dh)`` cache stood
 beside this one; no workload ran it.)
@@ -114,13 +119,18 @@ def init_page_pool(cfg: "T.TransformerConfig", n_slots: int, n_pages: int,
         "v": jnp.zeros((L, n_pages, Hkv, page_size, Dh), dt),
         "pos": jnp.zeros((n_slots,), jnp.int32),
     }
-    if cfg.has_conv:
-        if quant or cfg.kind_count("full") != L:
+    if cfg.has_state:
+        if quant or cfg.layers_with("k") != L:
             raise T.UnsupportedModelConfigError(
-                "a conv model's pool is its attention layers' pages, "
-                "unquantized, and its conv layers' state")
-        pool["conv"] = jnp.zeros((cfg.kind_count("conv"), n_slots,
-                                  cfg.conv_taps, cfg.d_model), dt)
+                "the pool of a model with a per-slot state (conv or "
+                "hybrid layers) is its attention layers' pages, "
+                "unquantized, and that state")
+        pool["conv"] = jnp.zeros((cfg.layers_with("conv"), n_slots,
+                                  cfg.conv_taps, cfg.conv_width), dt)
+    if cfg.has_ssm:
+        pool["ssm"] = jnp.zeros(
+            (cfg.layers_with("ssm"), n_slots, cfg.ssm_heads,
+             cfg.ssm_head_dim, cfg.ssm_state), dt)
     if quant:
         pool["k_scale"] = jnp.zeros((L, n_pages, Hkv, page_size),
                                     jnp.float32)
@@ -181,7 +191,7 @@ def landing_pages(bucket: int, page_size: int) -> int:
 @jax.named_scope("kv_land")  # T.DEVICE_SCOPES
 def paged_insert(pool: Dict, slots, new_pos, pages, first, lens,
                  prefilled_k, prefilled_v=None, prefilled_ik=None,
-                 prefilled_conv=None) -> Dict:
+                 prefilled_conv=None, prefilled_ssm=None) -> Dict:
     """Land a prefilled K/V block ``(L, K, H_kv, Tb, Dh)`` into pages.
     Column ``t`` of row ``i`` is logical position ``start + t``; with
     ``first = start % page`` it goes to offset ``(first + t) % page``
@@ -201,7 +211,8 @@ def paged_insert(pool: Dict, slots, new_pos, pages, first, lens,
     pages and offsets.  A pool whose rows several narrow KV heads share
     takes the block a row a head and lays them side by side.  A conv
     model's ``prefilled_conv`` ``(L_conv, K, taps, D)`` — each row's
-    state at its new position — replaces its slot's."""
+    state at its new position — replaces its slot's; so a hybrid
+    model's ``prefilled_ssm`` ``(L, K, H, P, N)``."""
     ps = pool["k"].shape[3]
     L, n_pg = pool["k"].shape[0], pages.shape[1]
     first = jnp.asarray(first, jnp.int32)
@@ -230,6 +241,9 @@ def paged_insert(pool: Dict, slots, new_pos, pages, first, lens,
     if prefilled_conv is not None:
         out["conv"] = pool["conv"].at[:, slots].set(
             prefilled_conv.astype(pool["conv"].dtype))
+    if prefilled_ssm is not None:
+        out["ssm"] = pool["ssm"].at[:, slots].set(
+            prefilled_ssm.astype(pool["ssm"].dtype))
     out["pos"] = pool["pos"].at[slots].set(new_pos)
     return out
 
@@ -356,13 +370,16 @@ class PagedSlotCache:
         self._set_pos = jax.jit(
             lambda pool, s, v: {**pool, "pos": pool["pos"].at[s].set(v)},
             donate_argnums=(0,))
-        # a conv model's per-slot state: zeroed with the grant, read
-        # back (L_conv, 1, taps, D) for a prompt's next chunk
+        # the per-slot state arrays (conv layers' taps; a hybrid
+        # model's taps and matrix states): zeroed with the grant, read
+        # back (L, 1, ...) for a prompt's next chunk
+        state = tuple(n for n in ("conv", "ssm") if n in self.cache)
         self._zero_state = jax.jit(
-            lambda pool, s: {**pool, "conv": pool["conv"].at[:, s].set(0)},
+            lambda pool, s: {**pool, **{n: pool[n].at[:, s].set(0)
+                                        for n in state}},
             donate_argnums=(0,))
         self._slot_state = jax.jit(
-            lambda pool, s: lax.dynamic_slice_in_dim(pool["conv"], s, 1, 1))
+            lambda array, s: lax.dynamic_slice_in_dim(array, s, 1, 1))
 
     # -- slot allocation: lowest free index first, O(log S) an op ------------
 
@@ -468,9 +485,16 @@ class PagedSlotCache:
     @property
     def conv_state_bytes_per_slot(self) -> int:
         """What a slot holds beside its pages, whatever its context:
-        every conv layer's last ``taps`` gated inputs (0: no conv
-        layer)."""
+        the last ``taps`` inputs of every layer that keeps a short
+        convolution's (0: none does)."""
         a = self.cache.get("conv")
+        return 0 if a is None else a.nbytes // self.n_slots
+
+    @property
+    def ssm_state_bytes_per_slot(self) -> int:
+        """... and every state-space mixer's matrix state (0: the
+        model has none)."""
+        a = self.cache.get("ssm")
         return 0 if a is None else a.nbytes // self.n_slots
 
     @property
@@ -639,7 +663,8 @@ class PagedSlotCache:
             self._land_pages(rows, start, true_lens, bucket),
             np.int32(start % self.page_size),
             np.asarray(true_lens, np.int32), prefilled["k"],
-            prefilled.get("v"), prefilled.get("ik"), prefilled.get("conv"))
+            prefilled.get("v"), prefilled.get("ik"), prefilled.get("conv"),
+            prefilled.get("ssm"))
 
     def land(self, slots: Sequence[int], prefilled: Dict,
              true_lens, start: int = 0) -> None:
@@ -671,11 +696,13 @@ class PagedSlotCache:
             self.cache, np.asarray(slots, np.int32),
             np.asarray(vals, np.int32))
 
-    def slot_state(self, slot: int):
-        """A conv model's state of one slot, ``(L_conv, 1, taps, D)``,
-        as :func:`~horovod_tpu.models.transformer.prefill_with_prefix`
-        takes it for the slot's next chunk."""
-        return self._slot_state(self.cache, np.int32(slot))
+    def slot_state(self, slot: int, name: str = "conv"):
+        """One slot's state in the per-slot array ``name`` — the taps
+        ``conv`` ``(L, 1, taps, C)``, a hybrid model's matrix states
+        ``ssm`` ``(L, 1, H, P, N)`` — as :func:`~horovod_tpu.models.
+        transformer.prefill_with_prefix` takes it for the slot's next
+        chunk."""
+        return self._slot_state(self.cache[name], np.int32(slot))
 
     def gather_prefix(self, pages: Sequence[int]):
         """Contiguous ``(k, v)`` for a shared prefix's pages (see

@@ -619,17 +619,20 @@ class InferenceEngine:
             raise ValueError(
                 f"prefill_chunk_tokens must be >= 1 (or 0 to disable), "
                 f"got {engine_cfg.prefill_chunk_tokens}")
-        if cfg.has_conv or cfg.kv_pack > 1:
-            # A conv layer's per-slot state, and rows that narrow KV
-            # heads share, are written for the paged single-chip tick
-            # and the prefills: every other mode refuses them by name.
-            what = ("conv layers (a per-slot state beside the pages)"
+        if cfg.has_state or cfg.kv_pack > 1:
+            # A per-slot state (a conv layer's taps, a hybrid layer's
+            # taps and matrix state), and rows that narrow KV heads
+            # share, are written for the paged single-chip tick and the
+            # prefills: every other mode refuses them by name.
+            what = ("hybrid layers (a state-space mixer's per-slot "
+                    "state beside the pages)" if cfg.has_ssm else
+                    "conv layers (a per-slot state beside the pages)"
                     if cfg.has_conv else
                     "KV heads sharing a stored row (kv_lane_dense)")
             refused = [why for on, why in (
                 (engine_cfg.tp > 1, "tp > 1"),
                 (self._spec, "speculative=True (a rejected draft would "
-                 "have to roll a state back)" if cfg.has_conv
+                 "have to roll a state back)" if cfg.has_state
                  else "speculative=True"),
                 (resolve_kv_dtype(cfg, engine_cfg.kv_dtype)[1],
                  "kv_dtype='int8'"),
@@ -962,6 +965,9 @@ class InferenceEngine:
                     out_s=shd and (_R, _R, _R, _R, _poolsh, _R))
         self._prefill_fns: Dict[tuple, Callable] = {}
         self._prefill_traces = 0
+        # layers with a state-space mixer (0: none): what the two
+        # ssm_* counters count rows and tokens by
+        self._ssm_layers = cfg.layers_with("ssm")
         self._prefill_calls = 0  # prefill FORWARD PASSES (sharing hook)
 
         # Paged-cache host state: _page_pos mirrors each slot's device
@@ -1004,7 +1010,7 @@ class InferenceEngine:
             self._prefill_traces += 1
             obs_tracing.record_compile("serving_prefill")
             pk, pv, *win = prefix
-            kw = dict(zip(("conv_state",) if cfg.has_conv else
+            kw = dict(zip(("conv_state", "ssm_state") if cfg.has_state else
                           ("win_k", "win_v", "win_start"), win))
             return T.prefill_with_prefix(
                 params, padded, pk, pv, p0, self.cfg, true_len=lens,
@@ -1358,7 +1364,7 @@ class InferenceEngine:
         return PagedSlotCache(
             self.cfg, ec.n_slots, ec.max_len, page_size=ec.page_size,
             n_pages=ec.n_pages, kv_dtype=ec.kv_dtype, mesh=self.mesh,
-            n_layers=self.cfg.kind_count("full"))
+            n_layers=self.cfg.layers_with("k"))
 
     def _make_window_slots(self) -> Optional[PagedSlotCache]:
         """The WINDOW layers' page pool of a configuration that has
@@ -1427,11 +1433,12 @@ class InferenceEngine:
             raise T.UnsupportedModelConfigError(
                 "prefix sharing is not written for sparse attention (an "
                 "indexer's keys beside the latent rows)")
-        if self.cfg.has_conv:
+        if self.cfg.has_state:
             raise T.UnsupportedModelConfigError(
-                "prefix sharing is not written for conv layers (a sharer "
-                "would need the state as it stood at the prefix's end: a "
-                "snapshot a page boundary)")
+                "prefix sharing is not written for "
+                + ("hybrid" if self.cfg.has_ssm else "conv")
+                + " layers (a sharer would need the state as it stood at "
+                "the prefix's end: a snapshot a page boundary)")
         tokens = tuple(int(t) for t in tokens)
         if not tokens:
             raise ServingError("empty prefix")
@@ -2341,6 +2348,7 @@ class InferenceEngine:
         self._prefill_calls += 1
         self.metrics.prefill_tokens.inc(tokens)
         self.metrics.prefill_padded_tokens.inc(rows * bucket)
+        self.metrics.ssm_scanned_tokens.inc(tokens * self._ssm_layers)
 
     def _count_paged_walk(self, active: np.ndarray) -> None:
         """One dispatched paged tick: the positions its active slots may
@@ -2349,6 +2357,7 @@ class InferenceEngine:
         the positions the kernel's walk covers for those limits (the
         kernel's own trip count, ``ops.paged_attention.walk``)."""
         limit = self._page_pos[active] + 1
+        self.metrics.ssm_updated_slots.inc(len(limit) * self._ssm_layers)
         if self.cfg.sparse:
             # a sparse model's tick walks the INDEX keys of every live
             # token and reads at most index_topk latent rows a slot:
@@ -2845,9 +2854,11 @@ class InferenceEngine:
                 pages + [NULL_PAGE] * (padded - len(pages)))
 
         prefix = gather(self.slots, 0)
-        if self.cfg.has_conv:
-            # ... and the conv layers' state as the last chunk left it
+        if self.cfg.has_state:
+            # ... and the per-slot state as the last chunk left it
             prefix += (self.slots.slot_state(slot),)
+            if self.cfg.has_ssm:
+                prefix += (self.slots.slot_state(slot, "ssm"),)
         if self.wslots is not None:
             # the window layers' block starts at the first page the
             # chunk's first query (position lo) still sees
@@ -4067,7 +4078,17 @@ class InferenceEngine:
             "conv_state_bytes_per_slot":
                 self.slots.conv_state_bytes_per_slot,
             "conv_state_slots_live": self.slots.active_count
-                if self.cfg.has_conv else 0,
+                if self.cfg.has_state else 0,
+            # ... and a third: a state-space mixer's matrix state, its
+            # bytes a slot, the slots holding one now, and what the two
+            # bodies that touch it have done (rows x layers a tick
+            # updated in place; true tokens x layers a prompt's or a
+            # chunk's scan carried it over: ssm_*_total, with the
+            # metrics' counters)
+            "ssm_state_bytes_per_slot":
+                self.slots.ssm_state_bytes_per_slot,
+            "ssm_state_slots_live": self.slots.active_count
+                if self.cfg.has_ssm else 0,
             "kv_pages_high_water": self.slots.pages_high_water,
             "kv_window_pages_per_slot_bound":
                 self.wslots.window_pages_bound

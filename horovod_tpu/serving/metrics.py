@@ -141,6 +141,11 @@ class ServingMetrics:
     * ``prefill_tokens`` / ``prefill_padded_tokens`` — prompt tokens
       the prefill executables were asked to ingest, and the
       bucket-/chunk-padded tokens they actually ran.
+    * ``ssm_updated_slots`` / ``ssm_scanned_tokens`` — what the two
+      bodies of a state-space mixer were asked for: active rows x
+      layers a dispatched tick (each a matrix state read and written
+      once), true prompt tokens x layers a prefill executable (0 for a
+      model with none).
     * ``paged_live_tokens`` / ``paged_walked_tokens`` — per dispatched
       paged tick, the positions the active slots may attend, and the
       positions the paged kernel's walk covers for them: each slot's
@@ -332,6 +337,15 @@ class ServingMetrics:
             "Per dispatched paged tick, the positions the paged "
             "attention's walk covers (each active slot's limit rounded "
             "up to a block of pages)")
+        self.ssm_updated_slots = r.counter(
+            "serving_ssm_updated_slots_total",
+            "Per dispatched paged tick of a model with state-space "
+            "mixers, active rows x layers: the matrix states its "
+            "update read and wrote in place")
+        self.ssm_scanned_tokens = r.counter(
+            "serving_ssm_scanned_tokens_total",
+            "Prompt tokens x layers a state-space mixer's chunked scan "
+            "carried a state over (admission groups and ingest chunks)")
         self.window_live_tokens = r.counter(
             "serving_window_live_tokens_total",
             "Per dispatched paged tick, the positions its active slots "
@@ -595,6 +609,8 @@ class ServingMetrics:
                 self.prefill_padded_tokens.value,
             "paged_live_tokens_total": self.paged_live_tokens.value,
             "paged_walked_tokens_total": self.paged_walked_tokens.value,
+            "ssm_updated_slots_total": self.ssm_updated_slots.value,
+            "ssm_scanned_tokens_total": self.ssm_scanned_tokens.value,
             "window_live_tokens_total": self.window_live_tokens.value,
             "window_walked_tokens_total": self.window_walked_tokens.value,
             "dsa_scored_tokens_total": self.dsa_scored_tokens.value,

@@ -1,12 +1,16 @@
 """The jaxprs of the three served programs (the paged tick, a chunk
-against its landed prefix, a whole-prompt prefill) of the FOUR
-architectures the benchmark served before conv layers came, at a small
-size, as digests: ``python tests/served_program_digests.py`` prints them
-as JSON.  ``tests/data/served_program_digests_pr38.json`` holds what the
-tree BEFORE PR 40 printed; ``tests/test_conv_layers.py`` holds this tree
-to it, so every field PR 40 added (``conv_kernel``, ``tie_embeddings``,
-``kv_lane_dense``, ``norm_topk_eps``), left off, leaves those programs
-as they were, equation for equation."""
+against its landed prefix, a whole-prompt prefill) of the architectures
+the benchmark serves, at a small size, as digests: ``python
+tests/served_program_digests.py`` prints them as JSON.
+``tests/data/served_program_digests_pr38.json`` holds what the tree
+BEFORE PR 40 printed for the FOUR served before conv layers came;
+``tests/test_conv_layers.py`` holds this tree to it, so every field PR
+40 added (``conv_kernel``, ``tie_embeddings``, ``kv_lane_dense``,
+``norm_topk_eps``), left off, leaves those programs as they were,
+equation for equation.  ``tests/data/served_program_digests_pr41.json``
+holds what the tree before PR 42 (a layer of two mixers) printed for
+the FIVE, the conv architecture among them; ``tests/test_hybrid_layers
+.py`` holds this tree to that."""
 
 import hashlib
 import json
@@ -46,6 +50,18 @@ CONFIGS = {
     # ... with an indexer and a biased router (deepseek-v3.2-exp-serve)
     "sparse": dict(_LATENT, index_n_heads=4, index_head_dim=16,
                    index_topk=12, moe_score_bias=True),
+    # conv layers between attention layers whose narrow heads share a
+    # stored row, a dense layer, a biased router, a tied head
+    # (lfm2-24b-a2b-serve)
+    "conv": dict(
+        vocab_size=96, d_model=128, n_heads=4, n_kv_heads=2, d_head=64,
+        n_layers=5, n_dense_layers=1, d_ff=96, d_expert=48, n_experts=8,
+        n_experts_per_tok=2, norm_topk_prob=True, norm_topk_eps=1e-6,
+        moe_impl="dropless", moe_score="sigmoid", moe_score_bias=True,
+        qk_norm=True, norm_eps=1e-5,
+        layer_pattern=("conv", "conv", "full", "conv"), conv_kernel=3,
+        tie_embeddings=True, kv_lane_dense=True, max_seq=128,
+        dtype=jnp.float32, attention_impl="flash"),
 }
 
 SLOTS, PAGE, MAX_LEN, CHUNK = 3, 4, 64, 8
@@ -90,6 +106,8 @@ def programs(cfg):
         if cfg.has_window:
             win = dict(zip(("win_k", "win_v"), C.gather_prefix_pages(
                 {"k": pl["wk"], "v": pl["wv"]}, pages)), win_start=0)
+        if "conv" in pl:        # the one row's state, as a slot holds it
+            win["conv_state"] = pl["conv"][:, :1]
         return T.prefill_with_prefix(p, chunk, pk, pv, jnp.int32(8), cfg,
                                      true_len=lens, **win)
 

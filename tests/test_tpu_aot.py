@@ -763,3 +763,143 @@ def test_the_conv_cells_reference_fits_the_chip_in_one_width(one_chip, half):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 3e9, mem
     assert "while" in compiled.as_text()    # the rows below n, no more
+
+
+def _falcon_h1():
+    import json
+
+    from chipbench.drivers import serve_hybrid
+
+    with open(os.path.join(os.path.dirname(chip_smoke.__file__), "chipbench",
+                           "configs", "falcon-h1-34b-serve.json")) as f:
+        dims = json.load(f)
+    return dims, serve_hybrid.build_cfg(dims)
+
+
+@pytest.mark.parametrize("what", ["tick", "chunk", "prompt"])
+def test_the_served_hybrid_programs_compile_at_the_published_widths(
+        one_chip, monkeypatch, what):
+    """The benchmark's own configuration (`falcon-h1-34b-serve`: 64 slots
+    of 4096, 8192 pages, a GQA group of FIVE, a matrix state of 2 MiB a
+    slot and layer) as the engine's three programs, compiled for the
+    v5e from shapes alone.  4 205 319 008 parameters = 8.41 GB in bf16
+    are their argument (the issue's count, from an engine's own tree).
+    The TICK holds the fused paged kernel once (five query rows padded
+    to eight) and the state update's kernel once, takes 2.42 GB of
+    pages, 1.21 GB of matrix states and 18 MB of taps and gives ALL of
+    it back aliased, with no result the size of a layer of the states
+    (134 MB: 64 x 32 x 128 x 256 values) or of ``k`` but the arrays
+    passing through, and under 50 MB of temporaries.  A CHUNK of 512
+    against 2048 landed tokens (both states as the chunk before left
+    them) and a PROMPT of two rows of 512 — through the flash forward at
+    20 heads, a Mosaic call and not the XLA form — compile inside 0.7
+    GB of temporaries."""
+    from horovod_tpu.ops import ssm as SSM
+
+    for mod, name in ((PA, "use_interpret"), (SSM, "use_interpret"),
+                      (ATT, "_use_interpret")):
+        monkeypatch.setattr(mod, name, lambda: False)
+    dims, cfg = _falcon_h1()
+    eng = dims["engine"]
+    assert (cfg.head_dim, cfg.n_heads // cfg.kv_heads, cfg.has_ssm) == (
+        128, 5, True)
+    params = _on(one_chip, jax.eval_shape(
+        lambda: T.lay_out_projections(jax.tree_util.tree_map(
+            lambda a: a.astype(cfg.dtype),
+            T.init_params(jax.random.PRNGKey(0), cfg)))[0]))
+    n_params = sum(a.size for a in jax.tree_util.tree_leaves(params))
+    assert n_params == 4_205_319_008 and "head" in params
+    weights = 2 * n_params
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    L = cfg.n_layers
+    if what == "tick":
+        S_ = eng["n_slots"]
+        pool = _on(one_chip, jax.eval_shape(lambda: C.init_page_pool(
+            cfg, S_, eng["n_pages"] + 1, eng["page_size"], None, L)))
+        assert pool["k"].shape == (9, 8193, 4, 16, 128)
+        assert pool["conv"].shape == (9, 64, 3, 5120)
+        assert pool["ssm"].shape == (9, 64, 32, 128, 256)
+        compiled = jax.jit(
+            lambda p, tok, act, t, pl: T.decode_step_paged(
+                p, tok, pl, t, cfg, act, kernel=True),
+            donate_argnums=(4,)).lower(
+                params, sds((S_,), jnp.int32), sds((S_,), jnp.bool_),
+                sds((S_, eng["max_len"] // eng["page_size"]), jnp.int32),
+                pool).compile()
+        text = compiled.as_text()
+        assert chip_smoke.kernel_calls(text, PA.KERNEL_NAME) == 1
+        assert chip_smoke.kernel_calls(text, SSM.UPDATE_NAME) == 1
+        for name in ("ssm", "k"):   # no copy the size of a layer of either
+            layer = pool[name].size // pool[name].shape[0]
+            offenders, largest = chip_smoke.pool_sized_results(text, layer)
+            assert offenders == [], (name, offenders, largest)
+        mem = compiled.memory_analysis()
+        pool_bytes = sum(a.size * a.dtype.itemsize for a in pool.values())
+        assert abs(pool_bytes - 3.642e9) < 1e6
+        assert mem.alias_size_in_bytes >= pool_bytes     # the states too
+        assert mem.temp_size_in_bytes < 0.05e9, mem
+        assert mem.argument_size_in_bytes < weights + pool_bytes + 1e6
+        return
+    if what == "chunk":
+        ids, lens = sds((1, 512), jnp.int32), sds((1,), jnp.int32)
+        pk = sds((L, cfg.kv_heads, 2048, cfg.head_dim), cfg.dtype)
+        taps = sds((L, 1, cfg.conv_taps, cfg.conv_width), cfg.dtype)
+        state = sds((L, 1, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
+                    cfg.dtype)
+        compiled = jax.jit(
+            lambda p, suf, k, v, p0, n, a, b: T.prefill_with_prefix(
+                p, suf, k, v, p0, cfg, true_len=n, conv_state=a,
+                ssm_state=b)).lower(
+                    params, ids, pk, pk, sds((), jnp.int32), lens, taps,
+                    state).compile()
+    else:
+        ids, lens = sds((2, 512), jnp.int32), sds((2,), jnp.int32)
+        compiled = jax.jit(
+            lambda p, pr, n: T.prefill(p, pr, T.init_cache(cfg, 2, 512),
+                                       cfg, true_len=n)).lower(
+                params, ids, lens).compile()
+        assert chip_smoke.kernel_calls(compiled.as_text(),
+                                       "hvd_flash_fwd") == 1
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.7e9, mem
+    # a row of logits a request, and the block: K, V of 9 layers' 512
+    # rows, 9 layers' taps and matrix states (2 MiB each) a request
+    assert mem.output_size_in_bytes < 0.07e9, mem
+
+
+@pytest.mark.parametrize("half", ["mix", "feed"])
+def test_the_hybrid_cells_reference_fits_the_chip_in_one_width(one_chip,
+                                                               half):
+    """The benchmark's own float32 reference of `falcon-h1-34b-serve`
+    (``chipbench/reference_hybrid.py``), a sequence of any length laid
+    in the engine's 4096 rows: each half of a layer compiles for the
+    v5e with its temporaries well inside the chip (the engine is gone by
+    then), its length a traced scalar — two executables a mode whatever
+    the seed draws; the recurrence is a loop over the tokens."""
+    from chipbench import reference_hybrid as R
+    from chipbench import weights_hybrid as W
+
+    dims, _ = _falcon_h1()
+    key = R._layer_dims(dims)
+    w = _on(one_chip, jax.eval_shape(lambda: {
+        n: jnp.zeros(s, jnp.bfloat16)
+        for n, (s, _) in W.layer_shapes(dims).items()}))
+    S_ = dims["engine"]["max_len"]
+    x = _on(one_chip, jax.ShapeDtypeStruct((S_, dims["hidden_size"]),
+                                           jnp.float32))
+    n = _on(one_chip, jax.ShapeDtypeStruct((), jnp.int32))
+    with jax.default_matmul_precision("highest"):
+        if half == "mix":
+            lowered = R._mix_fn(key, "f32", 512).lower(
+                x, {k: w[k] for k in R._MIX_LEAVES}, n,
+                _on(one_chip, jax.ShapeDtypeStruct((S_,), jnp.bool_)))
+        else:
+            lowered = R._feed_fn(key, "f32", 512).lower(
+                x, {k: w[k] for k in R._FEED_LEAVES}, n)
+        compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 3e9, mem
+    assert "while" in compiled.as_text()    # the rows below n, no more
