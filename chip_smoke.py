@@ -174,6 +174,11 @@ _HLO_ARRAY = re.compile(r"\b[a-z]+\d+\[([\d,]*)\]")
 _HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
 
 
+def _elements(dims: str) -> int:
+    """``"6,4096,128"`` (an HLO array type's dimensions) -> 3145728."""
+    return int(np.prod([int(d) for d in dims.split(",") if d]))
+
+
 def pool_sized_results(text: str, floor: int):
     """Read a compiled executable's HLO text: ``(offenders, largest)``.
 
@@ -204,8 +209,7 @@ def pool_sized_results(text: str, floor: int):
         name, result, opcode = m.groups()
         if opcode in _POOL_PASS_THROUGH:
             continue
-        size = max((int(np.prod([int(d) for d in dims.split(",") if d]))
-                    for dims in _HLO_ARRAY.findall(result)), default=0)
+        size = max(map(_elements, _HLO_ARRAY.findall(result)), default=0)
         in_place = ('"aliasing_operands":{"lists":[{' in line
                     or "output_to_operand_aliasing={" in line)
         op_name = re.search(r'op_name="([^"]*)"', line)
@@ -253,12 +257,61 @@ def products_carrying_an_update(text: str, params) -> Dict[str, str]:
               for x in jax.tree_util.tree_leaves(params)}
     found = {}
     for name, result in product_fusions(text).items():
-        written = [(dtype, int(np.prod([int(d) for d in dims.split(",")
-                                        if d])))
+        written = [(dtype, _elements(dims))
                    for dtype, dims in _HLO_TYPE.findall(result)]
         if any(written.count(w) > 1 for w in leaves.intersection(written)):
             found[name] = result
     return found
+
+
+def entry_instructions(text: str):
+    """Read a compiled executable's HLO text: the entry computation's
+    instructions in the order the compiler scheduled them, each ``(name,
+    result type, opcode, line)``."""
+    lines = text.splitlines()
+    start = next(i for i, l in enumerate(lines) if l.startswith("ENTRY "))
+    for line in lines[start + 1:]:
+        if line.startswith("}"):
+            return
+        m = _HLO_INSTRUCTION.match(line)
+        if m:
+            yield (*m.groups(), line)
+
+
+def leaves_relaid_for_a_bucket(text: str, params, floor: int):
+    """What a compiled train step pays for reducing a parameter leaf of
+    ``floor`` elements or more as a FLAT array: ``(relayouts, passes)``
+    out of the entry computation, ``{name: (opcode, result type)}`` and
+    ``{name: result type}``.
+
+    ``relayouts`` are the ``copy`` and ``reshape`` instructions whose
+    result is such a leaf whole, in its own dimensions, the compiler's or
+    flat: on a TPU the leaf lies in tiles of ``(8, 128)`` and the flat
+    array in tiles of 1024, so each is a pass over the whole leaf.
+    ``passes`` are the fusions under ``opt_update`` with two or more FLAT
+    outputs of a leaf's size: AdamW's moments computed on the flat
+    buffer, a pass of their own in front of the one that writes
+    parameter, ``mu`` and ``nu`` in the leaf's shape.  Five copies, ten
+    reshapes and five such passes for the five leaves of 58.7 M and 134 M
+    elements of ``m7b-train-dp4`` while a leaf alone in its bucket was
+    raveled too (PERF.md section 6, PR 43); ``ops/fusion.py`` reduces it
+    in its own shape, so there are none."""
+    big = {(_HLO_DTYPES.get(str(x.dtype)), int(np.prod(x.shape)))
+           for x in jax.tree_util.tree_leaves(params)
+           if int(np.prod(x.shape)) >= floor}
+
+    relayouts, passes = {}, {}
+    for name, result, opcode, line in entry_instructions(text):
+        # (is it flat, is it a big leaf's dtype and size) of each array
+        found = [("," not in dims, (dtype, _elements(dims)) in big)
+                 for dtype, dims in _HLO_TYPE.findall(result)]
+        if opcode in ("copy", "reshape"):
+            if any(leaf for _, leaf in found):
+                relayouts[name] = (opcode, result)
+        elif opcode == "fusion" and "/opt_update/" in line:
+            if sum(flat and leaf for flat, leaf in found) > 1:
+                passes[name] = result
+    return relayouts, passes
 
 
 _HLO_CALLED = re.compile(
@@ -714,6 +767,16 @@ def phase_train(smoke: SmokeConfig):
         _require(not carrying,
                  "train step: weight-gradient products with the optimizer's "
                  f"outputs in their epilogue: {carrying}")
+
+        # a matrix of 50 M elements fills a 64 MiB bucket alone and is
+        # reduced as it lies (this model's embedding, head and MLP
+        # leaves: 65.5 M and 67.1 M)
+        relaid, passes = leaves_relaid_for_a_bucket(text, params, 50_000_000)
+        report["leaves_relaid_for_a_bucket"] = sorted(relaid) + sorted(passes)
+        _require(not relaid and not passes,
+                 "train step: leaves reduced alone are relaid to a flat "
+                 f"buffer and back: {relaid}, with a pass of the optimizer "
+                 f"over the flat form: {passes}")
 
     losses: List[float] = []
     for _ in range(smoke.train_steps):

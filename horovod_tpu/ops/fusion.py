@@ -8,15 +8,28 @@ Reference: the fusion buffer + response fusion
 
 TPU re-design: there is no persistent byte buffer or memcpy in/out.  Fusion
 is a *functional transform*: leaves are grouped by dtype into buckets of at
-most ``threshold`` bytes, each bucket is flattened and concatenated, ONE
-collective runs per bucket, and results are split and reshaped back.  Under
-``jit`` the concat and split are the compiler's to place; they are NOT
-free: on four v5e chips the ``grad_allreduce`` scope (collectives, packing
-and unpacking) is 34.35 ms of a 494 ms step at Mistral-7B's widths, 34.13 of
-it exposed (``m7b-train-dp4``: ledger, PR 40).  What the static form saves
-over the reference is the runtime machinery (negotiation, a persistent
-buffer), not the bytes moved.  The bucket size is the main autotuning knob
-(:mod:`horovod_tpu.autotune`).
+most ``threshold`` bytes, and ONE collective runs per bucket.  A bucket of
+several leaves is PACKED: each leaf raveled, the lot concatenated, reduced,
+split and reshaped back.  A leaf alone in its bucket — one the threshold
+leaves alone, or the only one of its dtype — is reduced in its own shape.
+
+The packing is NOT free, and under ``jit`` it does not "disappear": on a
+TPU an array lies in tiles (a ``(4096, 14336)`` float32 leaf as
+``T(8,128)``, the flat array as ``T(1024)``), so a ravel is a relayout
+``copy`` of the whole leaf and the reshape back is another.  Read out of
+the four-chip step compiled for the v5e at Mistral-7B's widths (ISSUE 43;
+``tests/test_tpu_aot.py``): while a big leaf was raveled too, each weight
+gradient was copied to the flat form in front of its ``all-reduce``, AdamW
+ran in two passes (the moments' increments on the flat array, then
+parameter, ``mu`` and ``nu`` in the leaf's shape) with two ``reshape``s a
+leaf between them: 33.3 GB moved a step around the update where one chip
+moves 13.6.  With the leaf kept in its shape there is no copy, no reshape
+and one pass.  A packed bucket still pays its packing (there ``wv`` with
+the final norm, and the layer's two norms: 17 MB), which is what tensor
+fusion buys its fewer collectives with.  What the static form
+saves over the reference is the runtime machinery (negotiation, a
+persistent buffer), not the bytes moved.  The bucket size is the main
+autotuning knob (:mod:`horovod_tpu.autotune`).
 """
 
 from __future__ import annotations
@@ -79,11 +92,17 @@ def make_buckets(
 
 
 def _flatten_bucket(leaves: Sequence[Any]):
-    flats = [jnp.ravel(jnp.asarray(l)) for l in leaves]
-    return jnp.concatenate(flats) if len(flats) > 1 else flats[0]
+    """Several leaves packed into one flat array.  A leaf alone stays as
+    it lies: raveling a tiled array is a relayout on a TPU (module
+    docstring)."""
+    if len(leaves) == 1:
+        return jnp.asarray(leaves[0])
+    return jnp.concatenate([jnp.ravel(jnp.asarray(l)) for l in leaves])
 
 
 def _split_bucket(buf, leaves: Sequence[Any]):
+    if len(leaves) == 1:
+        return [buf]
     out = []
     off = 0
     for l in leaves:
@@ -95,9 +114,10 @@ def _split_bucket(buf, leaves: Sequence[Any]):
 
 
 def fused_allreduce_tree(tree, op=None, *, axis_name=None, threshold: int = None):
-    """In-graph fused allreduce of a pytree: bucket → concat → one
-    ``psum`` per bucket → split.  The JAX-transform equivalent of the
-    reference's fusion buffer cycle
+    """In-graph fused allreduce of a pytree: bucket → one ``psum`` per
+    bucket; a bucket of several leaves is concatenated in front of it and
+    split behind it, a leaf alone keeps its shape.  The JAX-transform
+    equivalent of the reference's fusion buffer cycle
     (``MemcpyInFusionBuffer → ncclAllReduce → MemcpyOutFusionBuffer``,
     ``ops/nccl_operations.cc:122-156``)."""
     from horovod_tpu.ops import collectives as C

@@ -348,3 +348,31 @@ class PoolMirror:
                 f"pool[{n!r}] differs from the (page, offset) writer at "
                 f"{len(bad)} places, first (layer, page-1, ...) = "
                 f"{bad[0].tolist()}")
+
+
+# --- tensor fusion before PR 43 (ops/fusion.py) ------------------------------
+#
+# Shared by tests/test_optim.py (the numbers and the traced program) and
+# tests/test_tpu_aot.py (what the TPU compiler makes of it).
+
+
+def every_bucket_packed(monkeypatch):
+    """``ops/fusion.py`` as it was before PR 43: a leaf ALONE in its
+    bucket is raveled and reshaped back like a packed one's leaves."""
+    from horovod_tpu.ops import fusion
+
+    def flatten(leaves):
+        flats = [jnp.ravel(jnp.asarray(l)) for l in leaves]
+        return jnp.concatenate(flats) if len(flats) > 1 else flats[0]
+
+    def split(buf, leaves):
+        out, off = [], 0
+        for l in leaves:
+            a = jnp.asarray(l)
+            n = int(np.prod(a.shape)) if a.ndim else 1
+            out.append(jnp.reshape(buf[off:off + n], a.shape))
+            off += n
+        return out
+
+    monkeypatch.setattr(fusion, "_flatten_bucket", flatten)
+    monkeypatch.setattr(fusion, "_split_bucket", split)

@@ -8,6 +8,7 @@ kernel bodies.
 """
 
 import os
+import re
 import subprocess
 import sys
 
@@ -392,3 +393,54 @@ def test_products_carrying_an_update_reads_a_compiled_program():
         "f32[4096,32768]{1,0:T(8,128)}, f32[4096,32768]{1,0:T(8,128)})",
         "%fusion.29 = f32[4096,32768]{1,0:T(8,128)}")
     assert chip_smoke.products_carrying_an_update(alone, params) == {}
+
+
+_HLO_PACKED = """HloModule jit__step, is_scheduled=true
+
+%fused_computation.1 (g: f32[58720256]) -> (f32[58720256], f32[58720256]) {
+  ROOT %tuple.1 = (f32[58720256]{0:T(1024)}, f32[58720256]{0:T(1024)}) tuple(%m, %n)
+}
+
+ENTRY %main (p: f32[1,4096,14336]) -> f32[1,4096,14336] {
+  %copy.243 = f32[512,112,8,128]{3,1,2,0:T(8,128)} copy(%fusion.87)
+  %copy.185 = f32[6,32,4096,128]{2,3,1,0:T(8,128)} copy(%dq), metadata={op_name="jit(_step)/shard_map/attn_qkv/convert_element_type"}
+  %psum.82 = f32[58720256]{0:T(1024)} all-reduce(%bitcast.9), metadata={op_name="jit(_step)/shard_map/grad_allreduce/psum"}
+  %broadcast_multiply_fusion.2 = (f32[58720256]{0:T(1024)}, f32[58720256]{0:T(1024)}) fusion(%psum.82), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(_step)/shard_map/opt_update/mul"}
+  %slice_multiply_fusion = (f32[4096]{0:T(1024)}, f32[4096]{0:T(1024)}) fusion(%psum.9), kind=kLoop, calls=%fused_computation.2, metadata={op_name="jit(_step)/shard_map/opt_update/mul"}
+  %mul.1406 = f32[1,4096,14336]{2,1,0:T(8,128)} reshape(%get-tuple-element.1), metadata={op_name="jit(_step)/shard_map/opt_update/mul"}
+  %mul.1409 = f32[1,4096,14336]{2,1,0:T(8,128)} reshape(%get-tuple-element.2), metadata={op_name="jit(_step)/shard_map/opt_update/mul"}
+  %copy.244 = f32[512,32,8,128]{3,1,2,0:T(8,128)} copy(%fusion.91)
+  %multiply_add_fusion.6 = (f32[1,4096,14336]{2,1,0:T(8,128)}, f32[1,4096,14336]{2,1,0:T(8,128)}, f32[1,4096,14336]{2,1,0:T(8,128)}) fusion(%p, %mul.1406, %mul.1409), kind=kLoop, calls=%fused_computation.3, metadata={op_name="jit(_step)/shard_map/add"}
+}
+"""
+
+
+def test_leaves_relaid_for_a_bucket_reads_a_compiled_program():
+    """The compiled-program check of a leaf reduced alone: a ``copy`` or
+    ``reshape`` of as many elements as a big leaf is its relayout to the
+    flat buffer or back, whatever dimensions the compiler gave it, and a
+    fusion under ``opt_update`` with two flat outputs of its size the
+    moments' own pass; an activation's copy, a small leaf's packing and
+    the one pass that writes parameter, ``mu`` and ``nu`` in the leaf's
+    shape are not."""
+    import jax
+    import jax.numpy as jnp
+
+    params = {"w_up": jax.ShapeDtypeStruct((1, 4096, 14336), jnp.float32),
+              "wo": jax.ShapeDtypeStruct((1, 32, 128, 4096), jnp.float32),
+              "norm": jax.ShapeDtypeStruct((4096,), jnp.float32)}
+    names = [n for n, *_ in chip_smoke.entry_instructions(_HLO_PACKED)]
+    assert names[0] == "copy.243" and names[-1] == "multiply_add_fusion.6"
+    relaid, passes = chip_smoke.leaves_relaid_for_a_bucket(
+        _HLO_PACKED, params, 50_000_000)
+    assert {n: op for n, (op, _) in relaid.items()} == {
+        "copy.243": "copy", "mul.1406": "reshape", "mul.1409": "reshape"}
+    assert list(passes) == ["broadcast_multiply_fusion.2"]
+    relaid, _ = chip_smoke.leaves_relaid_for_a_bucket(
+        _HLO_PACKED, params, 16_000_000)
+    assert "copy.244" in relaid and "copy.185" not in relaid
+    alone = "\n".join(
+        line for line in _HLO_PACKED.splitlines()
+        if not re.match(r"\s*%(copy\.24|mul\.|broadcast_multiply)", line))
+    assert chip_smoke.leaves_relaid_for_a_bucket(
+        alone, params, 16_000_000) == ({}, {})

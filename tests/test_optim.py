@@ -329,6 +329,96 @@ class TestFusion:
         assert len(buckets) == 5
 
 
+class TestALeafAloneKeepsItsShape:
+    """A bucket of ONE leaf — one the threshold leaves alone, or the only
+    one of its dtype — goes into its ``psum`` in its own shape and comes
+    back as it is; only a bucket of several leaves is raveled,
+    concatenated and split (on a TPU a ravel of a tiled array is a
+    relayout of the whole leaf: PERF.md section 6, PR 43;
+    ``tests/test_tpu_aot.py`` holds the compiled program).  The numbers
+    are the packed form's to the bit."""
+
+    THRESHOLD = 1024    # bytes: 256 float32 elements fill a bucket
+
+    # per device: {name: (shape, dtype)}
+    CASES = {
+        "one_big": {"w": ((16, 32), np.float32)},
+        "big_and_small": {"a": ((3,), np.float32), "w": ((16, 32), np.float32),
+                          "b": ((5,), np.float32), "c": ((2, 2), np.float32),
+                          "v": ((4, 8, 8), np.float32)},
+        "mixed_dtypes": {"a": ((3,), np.float32), "h": ((4, 6), jnp.bfloat16),
+                         "w": ((16, 32), np.float32), "b": ((7,), np.float32),
+                         "k": ((2, 3), np.float16), "j": ((5,), np.float16)},
+        "scalar": {"s": ((), np.float32), "h": ((4, 4), jnp.bfloat16),
+                   "g": ((), jnp.bfloat16)},
+    }
+
+    @classmethod
+    def _tree(cls, case):
+        """One draw a device: the leaves with a leading axis of ``N``."""
+        rng = np.random.RandomState(sorted(cls.CASES).index(case))
+        return {k: jnp.asarray(rng.randn(N, *shape), dtype)
+                for k, (shape, dtype) in cls.CASES[case].items()}
+
+    @classmethod
+    def _reduce(cls, op):
+        def inner(tree):
+            out = fusion.fused_allreduce_tree(
+                jax.tree_util.tree_map(lambda x: x[0], tree), op,
+                threshold=cls.THRESHOLD)
+            return jax.tree_util.tree_map(lambda x: x[None], out)
+
+        return spmd.shard(inner, in_specs=(P(hvd.AXIS),),
+                          out_specs=P(hvd.AXIS))
+
+    @staticmethod
+    def _psum_shapes(jaxpr):
+        """The shape of every ``psum``'s operand, in program order."""
+        return [tuple(v.aval.shape) for eqn in _equations(jaxpr)
+                if "psum" in eqn.primitive.name for v in eqn.invars]
+
+    @pytest.mark.parametrize("op", [hvd.Sum, hvd.Average])
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_the_result_is_the_packed_forms_and_the_psum_has_the_leafs_shape(
+            self, monkeypatch, case, op):
+        from conftest import every_bucket_packed
+
+        tree = self._tree(case)
+        names = sorted(self.CASES[case])     # a dict flattens by key
+        shapes = [self.CASES[case][k][0] for k in names]
+        buckets = fusion.make_buckets(
+            [tree[k][0] for k in names], self.THRESHOLD)
+        alone = [shapes[b[0]] for b in buckets if len(b) == 1]
+        packed = [(sum(int(np.prod(shapes[i])) for i in b),)
+                  for b in buckets if len(b) > 1]
+        assert alone, buckets    # every case has a leaf alone
+
+        seen = self._psum_shapes(jax.make_jaxpr(self._reduce(op))(tree).jaxpr)
+        assert sorted(seen) == sorted(alone + packed), (seen, buckets)
+        out = jax.jit(self._reduce(op))(tree)
+
+        every_bucket_packed(monkeypatch)
+        before = self._psum_shapes(
+            jax.make_jaxpr(self._reduce(op))(tree).jaxpr)
+        assert sorted(before) == sorted(
+            [(int(np.prod(s)),) for s in alone] + packed), before
+        want = jax.jit(self._reduce(op))(tree)
+
+        for k in names:
+            assert out[k].dtype == want[k].dtype == tree[k].dtype
+            assert out[k].shape == want[k].shape == tree[k].shape
+            np.testing.assert_array_equal(
+                np.asarray(out[k].astype(jnp.float32)),
+                np.asarray(want[k].astype(jnp.float32)), err_msg=k)
+            # every device holds the reduction of all N draws
+            ref = np.asarray(tree[k].astype(jnp.float32)).sum(axis=0)
+            if op == hvd.Average:
+                ref = ref / N
+            np.testing.assert_allclose(
+                np.asarray(out[k][0].astype(jnp.float32)), ref,
+                rtol=0.05, atol=0.05)
+
+
 class TestSparseGradients:
     """Row-sparse embedding-gradient reduction — the IndexedSlices
     allgather analogue (reference tensorflow/__init__.py:74-89)."""
@@ -416,18 +506,21 @@ class TestSparseGradients:
             SP.sparse_allreduce(np.ones((4, 2), np.float32), hvd.Adasum)
 
 
-def _primitives(jaxpr, out=None):
+def _equations(jaxpr):
     """Every equation of ``jaxpr`` and of the jaxprs under it (a
-    ``shard_map``'s body, a ``cond``'s branches), in program order:
-    ``[(primitive's name, number of operands)]``."""
+    ``shard_map``'s body, a ``cond``'s branches), in program order."""
     from conftest import _sub_jaxprs
 
-    out = [] if out is None else out
     for eqn in jaxpr.eqns:
-        out.append((eqn.primitive.name, len(eqn.invars)))
+        yield eqn
         for sub in _sub_jaxprs(eqn):
-            _primitives(sub, out)
-    return out
+            yield from _equations(sub)
+
+
+def _primitives(jaxpr):
+    """``[(primitive's name, number of operands)]`` of ``_equations``."""
+    return [(eqn.primitive.name, len(eqn.invars))
+            for eqn in _equations(jaxpr)]
 
 
 class TestGradientsEnterTheReductionAsValues:

@@ -524,16 +524,8 @@ def _entry_schedule(text):
     """The entry computation's collectives, products and custom calls in
     the order the compiler scheduled them."""
     products = chip_smoke.product_fusions(text)
-    lines = text.splitlines()
-    start = next(i for i, l in enumerate(lines) if l.startswith("ENTRY "))
     kinds = []
-    for line in lines[start + 1:]:
-        if line.startswith("}"):
-            break
-        m = chip_smoke._HLO_INSTRUCTION.match(line)
-        if not m:
-            continue
-        name, _, opcode = m.groups()
+    for name, _, opcode, line in chip_smoke.entry_instructions(text):
         if name in products:
             kinds.append("product")
         elif opcode == "custom-call":
@@ -562,6 +554,45 @@ def test_the_four_chip_step_is_the_program_it_was(v5e, monkeypatch):
     parent, _ = _cell_train_step(v5e, 4, TRAIN_WIDTHS["published"])
     assert chip_smoke.products_carrying_an_update(parent, params) == {}
     assert schedule == _entry_schedule(parent)
+
+
+def test_the_four_chip_step_relays_no_leaf_that_is_reduced_alone(
+        v5e, monkeypatch):
+    """A leaf that fills a bucket alone goes into its ``all-reduce`` as it
+    lies (``ops/fusion.py``): at the published widths the four-device
+    step's entry computation holds no ``copy`` and no ``reshape`` of a
+    whole leaf of 50 M float32 elements or more (the embedding, the
+    head, the MLP's three), and AdamW no pass of its own over their flat
+    form — it runs in the ONE pass it takes on one chip — under the same
+    seven ``all-reduce``s.  (The attention leaves, 4-17 M elements, keep
+    copies of their own on both sides and are left out.)  With every
+    bucket packed, as before PR 43, the same reader finds a copy in
+    front of each of the five reductions, two reshapes behind each and
+    the moments' pass between (twelve reshapes counting ``wo``'s): when
+    THAT fails the compiler has changed its mind about a ravel."""
+    from conftest import every_bucket_packed
+
+    monkeypatch.setattr(ATT, "_use_interpret", lambda: False)
+    floor = 50_000_000
+    text, params = _cell_train_step(v5e, 4, TRAIN_WIDTHS["published"])
+    assert chip_smoke.leaves_relaid_for_a_bucket(
+        text, params, floor) == ({}, {})
+    assert _entry_schedule(text).count("all-reduce") == 7
+
+    every_bucket_packed(monkeypatch)
+    before, _ = _cell_train_step(v5e, 4, TRAIN_WIDTHS["published"])
+    assert _entry_schedule(before).count("all-reduce") == 7
+    relayouts, passes = chip_smoke.leaves_relaid_for_a_bucket(
+        before, params, floor)
+    opcodes = [opcode for opcode, _ in relayouts.values()]
+    assert (opcodes.count("copy"), opcodes.count("reshape"),
+            len(passes)) == (5, 10, 5), (relayouts, passes)
+    # ... and wo's (16.8 M elements: a bucket of its own at 64 MiB)
+    relayouts, passes = chip_smoke.leaves_relaid_for_a_bucket(
+        before, params, 16_000_000)
+    assert [opcode for opcode, _ in relayouts.values()].count(
+        "reshape") == 12, relayouts
+    assert len(passes) == 7, passes
 
 
 @pytest.mark.parametrize("tree", ["engine", "checkpoint"])
