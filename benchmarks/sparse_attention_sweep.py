@@ -87,7 +87,7 @@ def main() -> int:
     lat = pool.reshape(-1, 640)
 
     def rows_of(idx):
-        page = jnp.take_along_axis(table, idx // ps, axis=1)
+        page = PA.pages_of(table, idx, ps)
         return lat[page * ps + idx % ps]
 
     gather = jax.jit(rows_of)

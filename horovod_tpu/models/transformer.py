@@ -2512,7 +2512,7 @@ def _attention_decode_paged(x, p, cfg: TransformerConfig, kv, layer, table,
             n_pg = kv[0].shape[1]
 
             def gather(idx):
-                page = jnp.take_along_axis(table, idx // ps, axis=1)
+                page = _pa.pages_of(table, idx, ps)
                 return lat[(layer * n_pg + page) * ps + idx % ps]
 
             o_lat = _dsa_select_attend(q, scores, limit, gather, cfg, kernel)

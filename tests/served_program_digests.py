@@ -10,7 +10,11 @@ BEFORE PR 40 printed for the FOUR served before conv layers came;
 equation for equation.  ``tests/data/served_program_digests_pr41.json``
 holds what the tree before PR 42 (a layer of two mixers) printed for
 the FIVE, the conv architecture among them; ``tests/test_hybrid_layers
-.py`` holds this tree to that."""
+.py`` holds this tree to that.  ONE entry of both files is PR 44's:
+``sparse`` / ``tick``, whose selected attend reads each pick's page out
+of a product (``ops.paged_attention.pages_of``) where it called
+``jnp.take_along_axis``; its chunk and prompt, and every other
+architecture's three programs, are the digests those trees printed."""
 
 import hashlib
 import json

@@ -249,6 +249,41 @@ class TestTheSelectedSets:
             assert got[t] == set(want.tolist())
 
 
+class TestThePicksPages:
+    @pytest.mark.parametrize("mp", [24, 21], ids=["whole_groups",
+                                                  "a_ragged_group"])
+    @pytest.mark.parametrize("n_pages", [4096, 49152, 2 ** 20])
+    @pytest.mark.parametrize("ps", [16, 32])
+    def test_are_take_along_axis_exactly(self, ps, n_pages, mp):
+        """The tick's lookup of each pick's physical page
+        (``pages_of``: a one-hot product over the table row in groups
+        of ``128 // page`` pages, the ids a byte at a time) against
+        ``jnp.take_along_axis(table, idx // page, axis=1)``, element for
+        element: ids past bfloat16's 2^8 and past 2^16, a table whose
+        width is not whole groups (21 pages: groups of 8 or of 4), rows
+        that picked nothing, fewer than ``k`` and ``k`` — the places
+        behind ``count`` name the row's page 0, as ``idx`` 0 does."""
+        rng = np.random.default_rng(ps + n_pages + mp)
+        k, T_ = 48, mp * ps
+        n_valid = np.array([0, 5, k, T_ - 3, T_, 1], np.int32)
+        table = rng.integers(0, n_pages, (n_valid.size, mp)).astype(np.int32)
+        table[2, :3] = n_pages - 1, 0, 255        # the ends, a byte's edge
+        scores = jnp.asarray(rng.standard_normal((n_valid.size, T_)),
+                             jnp.float32)
+        idx, count = PA.select_topk(scores, jnp.asarray(n_valid), k)
+        assert np.asarray(count).tolist() == np.minimum(n_valid, k).tolist()
+        got = jax.jit(PA.pages_of, static_argnums=2)(
+            jnp.asarray(table), idx, ps)
+        want = jnp.take_along_axis(jnp.asarray(table), idx // ps, axis=1)
+        assert got.dtype == jnp.int32 and got.shape == idx.shape
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+        # ... and at the LAST position of the table too, picked or not
+        last = jnp.full((n_valid.size, 1), T_ - 1, jnp.int32)
+        assert np.array_equal(
+            np.asarray(PA.pages_of(jnp.asarray(table), last, ps))[:, 0],
+            table[:, -1])
+
+
 def _pool_and_table(rng, L=2, P=40, ps=4, S=3, mp=9, width=128,
                     dtype=jnp.float32):
     pool = jnp.asarray(rng.standard_normal((L, P, 1, ps, width)), dtype)

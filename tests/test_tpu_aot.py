@@ -255,8 +255,9 @@ def test_the_grouped_product_compiles_with_its_runs_held_still(
     assert mem.temp_size_in_bytes < 12 * K * N * 2, mem
 
 
+@pytest.mark.parametrize("lookup", ["product", "scalar_gather"])
 def test_the_served_sparse_tick_fits_the_chip_and_writes_in_place(
-        one_chip, monkeypatch):
+        one_chip, monkeypatch, lookup):
     """The benchmark's own configuration (`deepseek-v3.2-exp-serve`: 24
     slots of 32 768, 49 152 pages) as the engine's tick, compiled for
     the v5e from shapes alone: 3.226 B parameters and 6.04 GB of pool
@@ -264,13 +265,20 @@ def test_the_served_sparse_tick_fits_the_chip_and_writes_in_place(
     instruction has a result the size of a layer of the index-key array
     (the smaller of the two), and the temporaries — the scores, the
     selection's one-hots, 24 x 2048 gathered rows — stay under 0.2
-    GB."""
+    GB.  Each pick's PAGE comes out of a product (``PA.pages_of``):
+    with ``jnp.take_along_axis`` patched back in (``scalar_gather``:
+    the form until PR 44) each layer program holds a gather of 49 152
+    single int32s."""
     import json
 
     from chipbench.drivers import serve_sparse
 
     monkeypatch.setattr(PA, "use_interpret", lambda: False)
     monkeypatch.setattr(MOE, "use_interpret", lambda: False)
+    if lookup == "scalar_gather":
+        monkeypatch.setattr(
+            PA, "pages_of", lambda table, idx, ps: jnp.take_along_axis(
+                table, idx // ps, axis=1))
     with open(os.path.join(os.path.dirname(chip_smoke.__file__), "chipbench",
                            "configs", "deepseek-v3.2-exp-serve.json")) as f:
         dims = json.load(f)
@@ -301,6 +309,19 @@ def test_the_served_sparse_tick_fits_the_chip_and_writes_in_place(
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < 0.2e9, mem
     assert mem.argument_size_in_bytes < 12.6e9, mem
+    # two layer programs (the dense stack's scan, the expert stack's):
+    # each gathers its 24 x 2048 rows once, and none of them an int32 a
+    # pick (a transfer an ELEMENT) unless the old lookup is patched in
+    lines = text.splitlines()
+    rows = [l for l in lines if re.search(
+        r"= bf16\[49152,640\]\S* fusion\(.*kind=kCustom", l)]
+    assert len(rows) == 2 and all("gather" in l for l in rows), rows
+    ids = [l for l in lines if re.search(
+        r"= s32\[49152\]\S* fusion\(.*kind=kCustom", l)]
+    assert len(ids) == (2 if lookup == "scalar_gather" else 0), ids
+    assert all("take_along_axis" in l and "gather" in l for l in ids), ids
+    assert (lookup == "scalar_gather") == bool(re.search(
+        r"= s32\[24,2048\]\S* gather\(", text))
 
 
 @pytest.mark.parametrize("half", ["attend", "feed"])
