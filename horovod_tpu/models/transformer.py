@@ -55,6 +55,7 @@ Design notes (TPU):
 
 from __future__ import annotations
 
+import copy
 import dataclasses
 import functools
 import math
@@ -67,18 +68,42 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 
-#: The kinds of layer a ``layer_pattern`` may name.
-LAYER_KINDS = ("full", "sliding", "conv", "hybrid")
+#: The kinds of layer a ``layer_pattern`` may name.  What each one keeps
+#: for a request, by the page pool's names, and its mixer are declared
+#: ONCE, in :data:`LAYER_KINDS` (below the mixers it names).
+PATTERN_KINDS = ("full", "sliding", "conv", "hybrid")
 
-#: What a layer of each kind keeps for a request, by the page pool's
-#: names: pages of keys and values (``k``/``v``, their scales when
-#: quantized, an indexer's keys ``ik``; a window layer's are a pool of
-#: their own, ``wk``/``wv``), a short convolution's last inputs
-#: (``conv``, a slot), a state-space mixer's matrix state (``ssm``, a
-#: slot).  A hybrid layer owns pages AND both states.
-_POOL_ARRAYS = {"full": ("k", "v", "k_scale", "v_scale", "ik"),
-                "sliding": ("wk", "wv"), "conv": ("conv",),
-                "hybrid": ("k", "v", "conv", "ssm")}
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class LayerKind:
+    """One kind of layer, as :data:`LAYER_KINDS` declares it.
+
+    ``mixer(x, p, cfg, kind, reach) -> (h, new)``: what the layer adds
+    to its input ``x`` before the MLP and what it leaves in the cache,
+    written once; ``reach`` is how the calling body reaches the cached
+    state (:class:`_Prompt`, :class:`_Chunk`, :class:`_Tick`).
+    ``paged``: the pool arrays a page table indexes, ``{name: cfg ->
+    (heads, width)}`` of ``(L, P, heads, page, width)``; ``scales``: an
+    int8 pool's per-vector scales beside them, in their order;
+    ``state``: what a SLOT holds whatever its context, ``{name: cfg ->
+    shape}`` of ``(L, S) + shape``; ``window``: the pages lie under a
+    table of their own and are released behind the window."""
+    mixer: Any
+    paged: Any = dataclasses.field(default_factory=dict)
+    scales: tuple = ()
+    state: Any = dataclasses.field(default_factory=dict)
+    window: bool = False
+
+    @property
+    def block(self) -> tuple:
+        """The arrays a prefill hands back for a layer, and a chunk's
+        landed prefix carries."""
+        return (*self.paged, *self.state)
+
+    @property
+    def arrays(self) -> tuple:
+        """... and those of a (quantized) pool, in a tick's order."""
+        return (*self.paged, *self.scales, *self.state)
 
 
 class UnsupportedModelConfigError(ValueError):
@@ -316,10 +341,10 @@ class TransformerConfig:
             raise UnsupportedModelConfigError(
                 "leading dense layers together with window layers are "
                 "not written")
-        bad = [k for k in self.layer_pattern if k not in LAYER_KINDS]
+        bad = [k for k in self.layer_pattern if k not in PATTERN_KINDS]
         if bad:
             raise ValueError(f"unknown layer kind(s) {bad}; expected "
-                             f"{LAYER_KINDS}")
+                             f"{PATTERN_KINDS}")
         if self.layer_pattern and (
                 self.n_layers - self.n_dense_layers) % len(self.layer_pattern):
             raise ValueError(
@@ -438,15 +463,6 @@ class TransformerConfig:
         return out
 
     @property
-    def pool_arrays(self) -> tuple:
-        """What a prefill hands back for the full layers' cache, by the
-        page pool's names: K and V; a latent model's rows alone; with an
-        indexer, its keys beside them."""
-        if not self.latent:
-            return ("k", "v")
-        return ("k", "ik") if self.sparse else ("k", None)
-
-    @property
     def has_window(self) -> bool:
         """Does any layer attend a window (two kinds of KV state)?"""
         return "sliding" in self.layer_pattern
@@ -491,10 +507,26 @@ class TransformerConfig:
         C]`` :attr:`ssm_conv_width`."""
         return self.ssm_conv_width if self.has_ssm else self.d_model
 
+    def kind(self, name: str) -> LayerKind:
+        """The table's entry for a layer the pattern calls ``name``: a
+        full layer of a latent model is a ``latent`` one, with an
+        indexer a ``sparse`` one."""
+        if name == "full" and self.latent:
+            name = "sparse" if self.sparse else "latent"
+        return LAYER_KINDS[name]
+
+    @property
+    def kinds(self) -> Dict[str, LayerKind]:
+        """The entries of the kinds this configuration HAS, by the
+        pattern's names, in the table's order (never a set's: the
+        order of a program's equations hangs on it)."""
+        return {k: self.kind(k) for k in PATTERN_KINDS
+                if k in self.layer_kinds}
+
     def layers_with(self, array: str) -> int:
         """How many layers keep the pool array ``array`` for a request
-        (:data:`_POOL_ARRAYS`): by what a kind carries, not its name."""
-        return sum(array in _POOL_ARRAYS[k] for k in self.layer_kinds)
+        (:data:`LAYER_KINDS`): by what a kind carries, not its name."""
+        return sum(array in self.kind(k).arrays for k in self.layer_kinds)
 
     @property
     def layer_kinds(self) -> tuple:
@@ -861,6 +893,11 @@ def _scan_layers(layer, init, xs):
         return lax.scan(layer, init, xs)
 
 
+#: The bodies that compute EVERY kind of the table (:data:`LAYER_KINDS`);
+#: every other body refuses what it does not, through the two below.
+_KIND_BODIES = ("prefill", "prefill_with_prefix", "decode_step_paged")
+
+
 def _require_uniform(cfg: TransformerConfig, what: str) -> None:
     """Refuse a configuration with more than one kind of layer where
     only the uniform block is written (training, the single-request
@@ -869,7 +906,7 @@ def _require_uniform(cfg: TransformerConfig, what: str) -> None:
         raise UnsupportedModelConfigError(
             f"{what} computes one kind of layer; this configuration's "
             f"pattern {cfg.layer_pattern} (window {cfg.window}) is served "
-            "by prefill, prefill_with_prefix and decode_step_paged only")
+            f"by {', '.join(_KIND_BODIES)} only")
 
 
 def _require_no_latent(cfg: TransformerConfig, what: str) -> None:
@@ -884,9 +921,9 @@ def _require_no_latent(cfg: TransformerConfig, what: str) -> None:
                       "a share of the experts (n_experts_held)")):
         if on:
             raise UnsupportedModelConfigError(
-                f"{what} is not written for {name}: forward, prefill, "
-                "prefill_with_prefix, decode_step and decode_step_paged "
-                "compute it")
+                f"{what} is not written for {name}: "
+                + ", ".join(("forward", "decode_step") + _KIND_BODIES)
+                + " compute it")
 
 
 _EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
@@ -1442,46 +1479,6 @@ def _mla_out(o, p, cfg: TransformerConfig, absorbed: bool = False):
         return jnp.einsum("bshv,hvd->bsd", o, p["wo"].astype(cfg.dtype))
 
 
-def _mla_attention(x, p, cfg: TransformerConfig, mesh=None):
-    """Whole-sequence latent attention: ``(out, latent rows (B, 1, S,
-    latent_row), index keys (B, 1, S, index_head_dim) or None)`` — the
-    rows shaped as the cache block of ONE kv head that a prefill hands
-    back.  Expanded, through the flash forward; with an indexer and a
-    sequence longer than ``index_topk``, each query absorbed over its
-    selected rows (:func:`_dsa_attend`)."""
-    from horovod_tpu.ops import attention as attn
-
-    if mesh is not None:
-        raise UnsupportedModelConfigError(
-            "latent attention is not written for a tp mesh")
-    if cfg.attention_impl not in ("reference", "flash"):
-        raise UnsupportedModelConfigError(
-            f"latent attention runs attention_impl 'flash' or "
-            f"'reference', not {cfg.attention_impl!r}")
-    q_nope, q_rope, cq = _mla_q(x, p, cfg, with_cq=True)
-    lat = _mla_kv(x, p, cfg)
-    ik = None
-    if cfg.sparse:
-        qi, ik, w = _dsa_proj(x, cq, p, cfg)
-        if x.shape[1] > cfg.index_topk:   # else every query sees it all
-            q = _mla_absorb_q(q_nope, q_rope, p, cfg)
-            pos = jnp.arange(x.shape[1], dtype=jnp.int32)
-            o = lax.map(lambda a: _dsa_attend(*a, pos, cfg), (
-                q, qi, w, lat, ik))
-            return (_mla_out(o, p, cfg, absorbed=True), lat[:, None],
-                    ik[:, None])
-        ik = ik[:, None]
-    k, v = _mla_expand(lat, p, cfg)
-    qh = _mla_heads(q_nope, q_rope)
-    with jax.named_scope("attn"):
-        if cfg.attention_impl == "reference":
-            oh = attn.reference_attention(qh, k, v, causal=True,
-                                          sm_scale=cfg.mla_scale)
-        else:
-            oh = attn.flash_attention(qh, k, v, True, cfg.mla_scale)
-    return _mla_out(jnp.moveaxis(oh, 1, 2), p, cfg), lat[:, None], ik
-
-
 # --- learned sparse attention (an indexer over the latent cache) --------------
 #
 # ``cfg.sparse``: beside the latent row a token leaves ONE index key
@@ -1676,7 +1673,8 @@ def _mla_chunk_attend(q_nope, q_rope, lat, prefix_lat, p0, p,
 
 def _attention(x, p, cfg: TransformerConfig):
     if cfg.latent:
-        return _mla_attention(x, p, cfg)[0]
+        return _latent_attention(x, p, cfg, cfg.kind("full"),
+                                 _Prompt(cfg))[0]
     B, S, D = x.shape
     from horovod_tpu.ops import attention as attn
 
@@ -2172,23 +2170,13 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int = 0) -> Dict:
     growing arrays).  GQA (``n_kv_heads``) shrinks the cache by
     ``n_heads / kv_heads`` — the serving-memory lever."""
     T = max_len or cfg.max_seq
-    if cfg.latent:
-        # one kv "head" whose key is the cached row and whose value is
-        # that row's first kv_lora_rank lanes: ``k`` alone
-        cache = {"k": jnp.zeros((cfg.n_layers, batch, 1, T,
-                                 cfg.latent_row), cfg.dtype),
-                 "pos": jnp.zeros((), jnp.int32)}
-        if cfg.sparse:    # ... and the index keys, one a token
-            cache["ik"] = jnp.zeros((cfg.n_layers, batch, 1, T,
-                                     cfg.index_head_dim), cfg.dtype)
-        return cache
-    return {
-        "k": jnp.zeros((cfg.n_layers, batch, cfg.kv_heads, T, cfg.head_dim),
-                       cfg.dtype),
-        "v": jnp.zeros((cfg.n_layers, batch, cfg.kv_heads, T, cfg.head_dim),
-                       cfg.dtype),
-        "pos": jnp.zeros((), jnp.int32),
-    }
+    # what a full layer pages (:data:`LAYER_KINDS`: K and V; a latent
+    # model's rows alone, and its index keys), a row a head here
+    flat = dataclasses.replace(cfg, kv_lane_dense=False)
+    cache = {n: jnp.zeros((cfg.n_layers, batch, row(flat)[0], T,
+                           row(flat)[1]), cfg.dtype)
+             for n, row in cfg.kind("full").paged.items()}
+    return {**cache, "pos": jnp.zeros((), jnp.int32)}
 
 
 def _cache_attend(qh, k_cache, v_cache, mask, scale=None):
@@ -2298,19 +2286,19 @@ def decode_step(params: Dict, tokens_t, cache: Dict, cfg: TransformerConfig):
             f"{T_cache}); init_cache with a larger max_len")
     x = _embed(params, tokens_t, cfg)[:, None]  # (B, 1, D)
 
+    names = tuple(cfg.kind("full").paged)   # a latent cache has no V
+
     def layer(x, p, kind, kv):
         h, k_new, v_new = _attention_decode(
-            _attn_norm(x, p, cfg), p, cfg, kv[0], kv[1], pos)
+            _attn_norm(x, p, cfg), p, cfg, *(*kv, None)[:2], pos)
         return _mlp_block(x + h, p, cfg, moe_impl="dense"), (k_new, v_new)
 
     x, ys = _scan_layer_kinds(
         cfg, layer, x, params["layers"],
-        {"full": tuple(cache.get(n) for n in cfg.pool_arrays)},
+        {"full": tuple(cache[n] for n in names)},
         params.get("dense_layers"))
     logits = _lm_head(x, params["ln_f"], _head(params, cfg), cfg)
-    out = {n: a for n, a in zip(cfg.pool_arrays, ys["full"]) if n}
-    out["pos"] = pos + 1
-    return logits[:, 0], out
+    return logits[:, 0], {**dict(zip(names, ys["full"])), "pos": pos + 1}
 
 
 # --- paged KV cache (block tables resolved inside the tick) -------------------
@@ -2407,135 +2395,440 @@ def _paged_kernel_attend(qg, k_pool, v_pool, k_scale, v_scale, layer,
     from horovod_tpu import spmd
 
     in_specs, out_specs = paged_kernel_specs(quantized)
-    if quantized:
-        fn = spmd.shard(
-            lambda q_, k_, v_, ks_, vs_, t_, l_, i_: _pa.paged_attend(
-                q_, k_, v_, ks_, vs_, t_, l_, compute_dtype=cfg.dtype,
-                layer=i_),
-            in_specs=in_specs, out_specs=out_specs, mesh=mesh)
-        return fn(qg, k_pool, v_pool, k_scale, v_scale, table, limit, layer)
+    scales = (k_scale, v_scale) if quantized else ()
     fn = spmd.shard(
-        lambda q_, k_, v_, t_, l_, i_: _pa.paged_attend(
-            q_, k_, v_, None, None, t_, l_, compute_dtype=cfg.dtype,
-            layer=i_),
+        lambda q_, k_, v_, *rest: _pa.paged_attend(
+            q_, k_, v_, *(rest[:-3] or (None, None)), *rest[-3:-1],
+            compute_dtype=cfg.dtype, layer=rest[-1]),
         in_specs=in_specs, out_specs=out_specs, mesh=mesh)
-    return fn(qg, k_pool, v_pool, table, limit, layer)
+    return fn(qg, k_pool, v_pool, *scales, table, limit, layer)
 
 
-def _attention_decode_paged(x, p, cfg: TransformerConfig, kv, layer, table,
-                            pos, active, kernel=False, mesh=None,
-                            kind: str = "full"):
-    """Per-slot one-token attention against a PAGED cache: row ``s``
-    writes its K/V at logical position ``pos[s]`` — resolved through
-    the page table to ``(page table[s, pos//page], offset pos%page)`` —
-    then gathers its pages back into logical order and attends
-    positions ``<= pos[s]`` (the shared :func:`_cache_attend` math).
+# --- how a body reaches a request's cached state ------------------------------
+#
+# A layer's mixer (:data:`LAYER_KINDS`, below) is written ONCE; the three
+# served bodies differ only in how the cached state is reached.  A reach
+# answers a mixer's four questions — ``attend`` (K/V pages), ``latent``
+# (latent rows, with or without an indexer), ``conv`` and ``ssm`` (a
+# per-slot state) — each with ``(output, *what the layer leaves)``.
 
-    ``kv`` holds the STACKED pools of this layer's kind, ``(k, v)`` each
-    ``(L, P, H_kv, page, Dh)`` (``(k, v, k_scale, v_scale)`` for int8
-    storage), and comes back written; ``layer`` is this layer's
-    (traced) index into them: the write is
-    :func:`~horovod_tpu.serving.cache.write_pages` at ``[layer, page]``
-    and the attend reads ``[layer, table]``, so no operation cuts a
-    layer out of the stack or has a result of its size, and the caller
-    carries the stacks through its layer scan as loop state.
+
+class _Prompt:
+    """The WHOLE prompt (:func:`prefill`): nothing is cached before it.
+    Attention runs over the prompt itself — the flash forward, under
+    ``shard_map`` on a tp ``mesh`` (GSPMD cannot partition a Mosaic
+    kernel; attention is per head, and a contiguous tp split keeps every
+    query head on the device that holds its KV head) — and a state
+    starts from zeros and stands at each row's real length ``lens``."""
+
+    positions = None    # 0 .. S0 - 1
+    landed: Dict = {}   # the layer's arrays before these tokens, by name
+
+    def __init__(self, cfg: TransformerConfig, lens=None, mesh=None):
+        self.cfg, self.lens, self.mesh = cfg, lens, mesh
+
+    def at(self, **layer):
+        """This reach at one layer of the scan."""
+        new = copy.copy(self)
+        new.__dict__.update(layer)
+        return new
+
+    def conv(self, x, p, kind: LayerKind):
+        return _conv_prefill(x, p, self.cfg,
+                             *(self.landed.get(n) for n in kind.state),
+                             self.lens)
+
+    def ssm(self, n, p, kind: LayerKind):
+        return _ssm_prefill(n, p, self.cfg,
+                            *(self.landed.get(n) for n in kind.state),
+                            self.lens)
+
+    def attend(self, qh, kh, vh, kind: LayerKind):
+        """Causal attention over the prompt's own (unexpanded, post-RoPE)
+        K/V, which are also what it leaves: a window layer through the
+        same kernel with the window's lower bound (blocks wholly behind
+        it skipped).  The sequence-parallel impls need a bound mesh
+        axis, so they prefill through the flash kernel (which takes the
+        XLA form for untileable prompts)."""
+        from horovod_tpu.ops import attention as attn
+
+        reference = self.cfg.attention_impl == "reference"
+        window = self.cfg.window if kind.window else 0
+
+        def core(q, k, v):
+            k, v = attn.expand_kv(k, q.shape[1]), attn.expand_kv(v, q.shape[1])
+            if reference:
+                return attn.reference_attention(q, k, v, causal=True,
+                                                window=window)
+            if window:
+                return attn.flash_attention_windowed(q, k, v, window)
+            return attn.flash_attention(q, k, v, True)
+
+        if self.mesh is not None and not reference:
+            from horovod_tpu import spmd
+
+            head = P(None, "tp", None, None)
+            core = spmd.shard(core, in_specs=(head, head, head),
+                              out_specs=head, mesh=self.mesh)
+        with jax.named_scope("attn"):
+            return core(qh, kh, vh), kh, vh
+
+    def latent(self, q_nope, q_rope, lat, index, p, kind: LayerKind):
+        """Expanded, through the flash forward; with an indexer and a
+        sequence longer than ``index_topk``, each query absorbed over
+        its selected rows (:func:`_dsa_attend`)."""
+        from horovod_tpu.ops import attention as attn
+
+        cfg = self.cfg
+        if self.mesh is not None:
+            raise UnsupportedModelConfigError(
+                "latent attention is not written for a tp mesh")
+        if cfg.attention_impl not in ("reference", "flash"):
+            raise UnsupportedModelConfigError(
+                f"latent attention runs attention_impl 'flash' or "
+                f"'reference', not {cfg.attention_impl!r}")
+        S0 = lat.shape[1]
+        if index is not None and S0 > cfg.index_topk:
+            qi, ik, w = index     # else every query sees it all
+            q = _mla_absorb_q(q_nope, q_rope, p, cfg)
+            pos = jnp.arange(S0, dtype=jnp.int32)
+            o = lax.map(lambda a: _dsa_attend(*a, pos, cfg), (
+                q, qi, w, lat, ik))
+            return (_mla_out(o, p, cfg, absorbed=True),
+                    *_latent_rows(lat, index))
+        k, v = _mla_expand(lat, p, cfg)
+        qh = _mla_heads(q_nope, q_rope)
+        with jax.named_scope("attn"):
+            if cfg.attention_impl == "reference":
+                oh = attn.reference_attention(qh, k, v, causal=True,
+                                              sm_scale=cfg.mla_scale)
+            else:
+                oh = attn.flash_attention(qh, k, v, True, cfg.mla_scale)
+        return (_mla_out(jnp.moveaxis(oh, 1, 2), p, cfg),
+                *_latent_rows(lat, index))
+
+
+class _Chunk(_Prompt):
+    """A CHUNK of ``S0`` tokens behind ``prefix_len`` landed ones
+    (:func:`prefill_with_prefix`): ``prefix`` holds what every layer
+    kept for them, by the pool's names, ``landed`` one layer's share.
+    Pages come as the pool stores them, gathered to ``P0 >=
+    prefix_len`` positions (page-granular gathers round up: what lies
+    at or past ``prefix_len`` is masked out, so page-tail junk is
+    inert); a window layer's from logical position ``win_start`` on (a
+    traced scalar: what lies behind the first query's window was never
+    gathered), its query at ``i`` seeing key ``j`` iff ``j <= i`` and
+    ``i - j < cfg.window``.  A state is each row's as the chunk before
+    left it."""
+
+    def __init__(self, cfg: TransformerConfig, lens, positions, p0,
+                 prefix: Dict, win_start):
+        super().__init__(cfg, lens)
+        self.positions, self.p0, self.win_start = positions, p0, win_start
+        kv = [k for k in cfg.kinds.values() if _kv_row in k.paged.values()]
+        if cfg.kv_pack > 1:   # rows that several heads share, a row a head
+            prefix = {n: _unpack_heads(a, cfg.kv_pack) if any(
+                n in k.paged for k in kv) else a for n, a in prefix.items()}
+        self.prefix, self._causal = prefix, None
+        self.masks = {k: self._mask(k) for k in kv}
+
+    def _mask(self, kind: LayerKind):
+        """``(S0, P0 + S0)``: the real prefix visible (to a window layer,
+        what of it lies within the row's window), the gather's padding
+        (``>= p0``) never, and the chunk causal within itself."""
+        p0, positions = self.p0, self.positions
+        S0 = positions.shape[0]
+        P0 = self.prefix[next(iter(kind.paged))].shape[2]
+        if not kind.window:
+            pre = lax.broadcasted_iota(jnp.int32, (P0,), 0)[None, :] < p0
+            pre = jnp.broadcast_to(pre, (S0, P0))
+        if self._causal is None:
+            self._causal = (lax.broadcasted_iota(jnp.int32, (S0, S0), 1)
+                            <= lax.broadcasted_iota(jnp.int32, (S0, S0), 0))
+        own = self._causal
+        if kind.window:
+            # the block's column j is logical position win_start + j
+            W = self.cfg.window
+            kpos = (jnp.asarray(self.win_start, jnp.int32)
+                    + lax.broadcasted_iota(jnp.int32, (P0,), 0))
+            pre = ((kpos[None, :] < p0)
+                   & (positions[:, None] - kpos[None, :] < W))
+            rows = lax.broadcasted_iota(jnp.int32, (S0, S0), 0)
+            cols = lax.broadcasted_iota(jnp.int32, (S0, S0), 1)
+            own = own & (rows - cols < W)
+        return jnp.concatenate([pre, own], axis=1)[None, None, None]
+
+    def attend(self, qh, kh, vh, kind: LayerKind):
+        """The chunk's queries against the landed K/V and their own,
+        under the kind's mask — grouped-query attention with the same
+        bandwidth discipline as :func:`_cache_attend`, S0 queries wide."""
+        pk, pv = (self.landed[n] for n in kind.paged)
+        (K, H, S0, Dh), (Hkv, P0) = qh.shape, pk.shape[:2]
+        with jax.named_scope("chunk_attn"):
+            k_full = jnp.concatenate(
+                [jnp.broadcast_to(pk[None].astype(kh.dtype),
+                                  (K, Hkv, P0, Dh)), kh], axis=2)
+            v_full = jnp.concatenate(
+                [jnp.broadcast_to(pv[None].astype(vh.dtype),
+                                  (K, Hkv, P0, Dh)), vh], axis=2)
+            qg = qh.reshape(K, Hkv, H // Hkv, S0, Dh)
+            s = jnp.einsum("bkgsd,bktd->bkgst", qg.astype(k_full.dtype),
+                           k_full, preferred_element_type=jnp.float32
+                           ) / np.sqrt(Dh)
+            s = jnp.where(self.masks[kind], s, -1e30)
+            w = jax.nn.softmax(s, axis=-1)
+            o = jnp.einsum("bkgst,bktd->bkgsd", w.astype(v_full.dtype),
+                           v_full, preferred_element_type=jnp.float32)
+            oh = o.reshape(K, H, S0, Dh)
+        return oh.astype(self.cfg.dtype), kh, vh
+
+    def latent(self, q_nope, q_rope, lat, index, p, kind: LayerKind):
+        """The landed rows ``(1, P0, latent_row)`` attended EXPANDED, in
+        blocks (:func:`_mla_chunk_attend`); with an indexer, once the
+        chunk's last query sees more than ``index_topk`` positions, each
+        query its selected rows, absorbed (:func:`_dsa_chunk_attend`)."""
+        cfg = self.cfg
+        rows = [self.landed[n][0] for n in kind.paged]
+        if (index is not None and rows[0].shape[0] + lat.shape[1]
+                > cfg.index_topk):
+            qi, ik, w = index
+            o = _dsa_chunk_attend(
+                _mla_absorb_q(q_nope, q_rope, p, cfg), qi, w, lat, ik,
+                *rows, self.p0, cfg)
+            out = _mla_out(o, p, cfg, absorbed=True)
+        else:
+            out = _mla_out(_mla_chunk_attend(
+                q_nope, q_rope, lat, rows[0], self.p0, p, cfg), p, cfg)
+        return (out, *_latent_rows(lat, index))
+
+
+class _Tick(_Prompt):
+    """One token a slot (:func:`decode_step_paged`): ``pools`` are the
+    STACKED arrays of the layer scan's carry and ``layer`` this layer's
+    (traced) index among its kind's — every write lands at ``[layer,
+    page]`` or ``[layer, slot]`` and every read takes ``[layer,
+    table]``, so no operation cuts a layer out of a stack or has a
+    result of its size.  Row ``s`` writes its K/V (or latent row) at
+    logical position ``pos[s]`` — through the page table to ``(page
+    table[s, pos // page], offset pos % page)`` — BEFORE it attends
+    positions ``<= pos[s]``, so the attend sees exactly the pool's
+    state, under the fused kernels (``kernel``; under ``shard_map`` on a
+    tp ``mesh``) and the gathers alike.
 
     Inactive rows are routed to physical page 0, the reserved NULL/
     trash page no live slot's table ever maps below its own position:
     a stale write could land in a page that has since been re-granted
     or shared, so the inactive scribble is not merely harmless-by-
-    overwrite — it must be (and is) aimed somewhere no one attends.  Active rows never collide: the
-    host allocator guarantees every active slot's write page is
-    PRIVATE (refcount 1; copy-on-write splits a shared page before any
-    write targets it).
+    overwrite — it must be (and is) aimed somewhere no one attends.
+    Active rows never collide: the host allocator guarantees every
+    active slot's write page is PRIVATE (refcount 1; copy-on-write
+    splits a shared page before any write targets it).  A window
+    layer's pages lie under ``wtable``, and its row attends positions
+    ``pos[s] - window < t <= pos[s]`` only — the entries behind that
+    may already be released."""
 
-    The scales are the per-(head, position) f32 scales of int8 pools
-    (absent for bf16/f32 storage): the payload is dequantized
-    AFTER the gather, so only the logical view — not the whole pool —
-    is ever materialized at compute dtype.
+    def __init__(self, cfg: TransformerConfig, table, wtable, pos, active,
+                 kernel, mesh):
+        super().__init__(cfg, mesh=mesh)
+        self.table, self.wtable, self.pos = table, wtable, pos
+        self.active, self.kernel = active, kernel
 
-    ``kernel=True`` replaces the gather -> dequant -> attend tail with
-    the fused Pallas flash-decoding kernel (:mod:`horovod_tpu.ops.
-    paged_attention`): the pages stream through VMEM with int8 dequant
-    in the load and NOTHING materialized at logical shape.  The write
-    (write-before-attend) is identical under both paths, so the fused
-    tick attends exactly the same pool state; ``mesh`` routes the
-    kernel through ``shard_map`` for tp head-sharded pools.
+    @property
+    def positions(self):
+        return self.pos[:, None]
 
-    ``kind="sliding"``: the pool and table are the window layers' own,
-    and row ``s`` attends positions ``pos[s] - window < t <= pos[s]``
-    only — the table's entries behind that may already be released."""
-    from horovod_tpu.serving.cache import write_pages
+    def conv(self, x, p, kind: LayerKind):
+        return _conv_decode(x, p, self.cfg,
+                            *(self.pools[n] for n in kind.state),
+                            self.layer, self.active)
 
-    S = x.shape[0]
-    max_pages = table.shape[1]
-    ps = kv[0].shape[3]
-    if cfg.latent:
-        # ABSORBED: the row written is the row read, as it lies
-        q_nope, q_rope, cq = _mla_q(x, p, cfg, positions=pos[:, None],
-                                    with_cq=True)
-        q = _mla_absorb_q(q_nope, q_rope, p, cfg)[:, 0]  # (S, H, 640)
-        rows = (_mla_kv(x, p, cfg, positions=pos[:, None])[:, None],)
-        if cfg.sparse:    # ... and the index key beside it
-            qi, ki, w = _dsa_proj(x, cq, p, cfg, positions=pos[:, None])
-            rows += (ki[:, None],)
-    else:
-        qh, k_t, v_t = _qkv_proj(x, p, cfg, positions=pos[:, None],
-                                 kind=kind)
-        # each (S, H_kv, 1, Dh), as the pool stores a row
-        rows = (_pack_heads(k_t, cfg.kv_pack), _pack_heads(v_t, cfg.kv_pack))
-    lower = (jnp.maximum(pos - cfg.window + 1, 0) if kind == "sliding"
-             else None)
-    with jax.named_scope("kv_write"):
-        idx = jnp.clip(pos // ps, 0, max_pages - 1)
-        phys = jnp.where(active, table[jnp.arange(S), idx], 0)
-        take = jnp.arange(ps, dtype=jnp.int32) == (pos % ps)[:, None]
-        if len(kv) == 4:                   # ... and the (S, H_kv, 1) scales
-            (qk, sk), (qv, sv) = kv_quantize(k_t), kv_quantize(v_t)
-            rows = (qk, qv, sk, sv)
-        kv = tuple(write_pages(stack, layer, phys, row, take)
-                   for stack, row in zip(kv, rows))
-    if cfg.latent:
+    def ssm(self, n, p, kind: LayerKind):
+        return _ssm_decode(n, p, self.cfg,
+                           *(self.pools[n] for n in kind.state),
+                           self.layer, self.active, self.kernel)
+
+    def _target(self, table, ps: int):
+        """Where each row's one position goes: ``(physical page, the
+        page's offsets that take it)``."""
+        S, max_pages = table.shape
+        idx = jnp.clip(self.pos // ps, 0, max_pages - 1)
+        phys = jnp.where(self.active, table[jnp.arange(S), idx], 0)
+        take = jnp.arange(ps, dtype=jnp.int32) == (self.pos % ps)[:, None]
+        return phys, take
+
+    def attend(self, qh, k_t, v_t, kind: LayerKind):
+        """An int8 pool's per-(head, position) f32 scales are written
+        with the payload, which is dequantized AFTER the gather (or in
+        the kernel's load): only the logical view, never the whole pool,
+        exists at compute dtype."""
         from horovod_tpu.ops import paged_attention as _pa
 
-        if cfg.sparse:
-            # the index walk over the slot's live tokens, the selection,
-            # then the attend over the selected rows alone: the pool is
-            # read BY TOKEN, ``(table[s, t // page], t % page)``
-            with jax.named_scope("hvd_dsa_score"):
-                limit = jnp.where(active, pos + 1, 0)
-                walk = (_pa.index_scores if kernel
-                        else _pa.index_scores_reference)
-                scores = walk(qi[:, 0], w[:, 0], kv[1], table, limit,
-                              layer=layer)
-            lat = kv[0].reshape((-1,) + kv[0].shape[-1:])   # rows, as they lie
-            n_pg = kv[0].shape[1]
+        cfg, pos = self.cfg, self.pos
+        stacks = [self.pools[n] for n in (*kind.paged, *kind.scales)
+                  if n in self.pools]
+        table = self.wtable if kind.window else self.table
+        rows = (_pack_heads(k_t, cfg.kv_pack), _pack_heads(v_t, cfg.kv_pack))
+        lower = (jnp.maximum(pos - cfg.window + 1, 0) if kind.window
+                 else None)
 
-            def gather(idx):
-                page = _pa.pages_of(table, idx, ps)
-                return lat[(layer * n_pg + page) * ps + idx % ps]
-
-            o_lat = _dsa_select_attend(q, scores, limit, gather, cfg, kernel)
-            return _mla_out(o_lat[:, None], p, cfg, absorbed=True), kv
+        with jax.named_scope("kv_write"):
+            phys, take = self._target(table, stacks[0].shape[3])
+            if len(stacks) == 4:           # ... and the (S, H_kv, 1) scales
+                (qk, sk), (qv, sv) = kv_quantize(k_t), kv_quantize(v_t)
+                rows = (qk, qv, sk, sv)
+            stacks = tuple(
+                _pa.write_pages(stack, self.layer, phys, row, take)
+                for stack, row in zip(stacks, rows))
         with jax.named_scope("paged_attend"):
-            limit = jnp.where(active, pos + 1, 0)
-            attend = _pa.mla_decode if kernel else _pa.mla_decode_reference
-            o_lat, _ = attend(q, kv[0], table, limit, layer=layer,
-                              v_dim=cfg.kv_lora_rank, sm_scale=cfg.mla_scale)
-        return _mla_out(o_lat[:, None], p, cfg, absorbed=True), kv
-    with jax.named_scope("paged_attend"):
-        o = _paged_decode_attend(qh, *kv, *(None,) * (4 - len(kv)), layer,
-                                 table, pos, active, cfg, kernel, mesh,
-                                 lower)
-    return _out_proj(o.astype(cfg.dtype), p, cfg), kv
+            o = _paged_decode_attend(qh, *stacks, *(None,) * (4 - len(stacks)),
+                                     self.layer, table, pos, self.active,
+                                     cfg, self.kernel, self.mesh, lower)
+        return (o.astype(cfg.dtype), *stacks)
+
+    def latent(self, q_nope, q_rope, lat, index, p, kind: LayerKind):
+        """ABSORBED: the row written is the row read, as it lies — by
+        the latent walk; with an indexer, by the index walk over the
+        slot's live tokens, the selection, then the attend over the
+        selected rows alone: the pool read BY TOKEN, ``(table[s, t //
+        page], t % page)``."""
+        from horovod_tpu.ops import paged_attention as _pa
+
+        cfg, table, layer = self.cfg, self.table, self.layer
+        q = _mla_absorb_q(q_nope, q_rope, p, cfg)[:, 0]  # (S, H, 640)
+        stacks = [self.pools[n] for n in kind.paged]
+        rows = _latent_rows(lat, index)
+        with jax.named_scope("kv_write"):
+            phys, take = self._target(table, stacks[0].shape[3])
+            stacks = tuple(_pa.write_pages(stack, layer, phys, row, take)
+                           for stack, row in zip(stacks, rows))
+        if index is None:
+            with jax.named_scope("paged_attend"):
+                limit = jnp.where(self.active, self.pos + 1, 0)
+                attend = (_pa.mla_decode if self.kernel
+                          else _pa.mla_decode_reference)
+                o_lat, _ = attend(q, stacks[0], table, limit, layer=layer,
+                                  v_dim=cfg.kv_lora_rank,
+                                  sm_scale=cfg.mla_scale)
+        else:
+            qi, _, w = index
+            with jax.named_scope("hvd_dsa_score"):
+                limit = jnp.where(self.active, self.pos + 1, 0)
+                walk = (_pa.index_scores if self.kernel
+                        else _pa.index_scores_reference)
+                scores = walk(qi[:, 0], w[:, 0], stacks[1], table, limit,
+                              layer=layer)
+            flat = stacks[0].reshape((-1,) + stacks[0].shape[-1:])
+            n_pg, ps = stacks[0].shape[1], stacks[0].shape[3]
+
+            def gather(idx):            # the rows, as they lie
+                page = _pa.pages_of(table, idx, ps)
+                return flat[(layer * n_pg + page) * ps + idx % ps]
+
+            o_lat = _dsa_select_attend(q, scores, limit, gather, cfg,
+                                       self.kernel)
+        return (_mla_out(o_lat[:, None], p, cfg, absorbed=True), *stacks)
+
+
+# --- the mixers, one a kind ----------------------------------------------------
+
+
+def _kv_attention(n, p, cfg: TransformerConfig, kind: LayerKind, reach):
+    """Attention over K/V pages, of a layer's NORMED input ``n``."""
+    qh, kh, vh = _qkv_proj(n, p, cfg, positions=reach.positions,
+                           kind="sliding" if kind.window else "full")
+    oh, *new = reach.attend(qh, kh, vh, kind)
+    return _out_proj(oh, p, cfg), tuple(new)
+
+
+def _kv_mixer(x, p, cfg: TransformerConfig, kind: LayerKind, reach):
+    return _kv_attention(_attn_norm(x, p, cfg), p, cfg, kind, reach)
+
+
+def _hybrid_mixer(x, p, cfg: TransformerConfig, kind: LayerKind, reach):
+    """Attention AND the state-space mixer side by side on ONE normed
+    input, summed under their multipliers (:func:`_mix`)."""
+    n = _attn_norm(x, p, cfg)
+    h, rows = _kv_attention(_attn_in(n, cfg), p, cfg, kind, reach)
+    hs, *state = reach.ssm(n, p, kind)
+    return _mix(h, hs, cfg), rows + tuple(state)
+
+
+def _conv_mixer(x, p, cfg: TransformerConfig, kind: LayerKind, reach):
+    h, *state = reach.conv(x, p, kind)
+    return h, tuple(state)
+
+
+def _latent_rows(lat, index):
+    """What a token leaves in a latent cache, shaped as the block of ONE
+    kv head: its row, and with an indexer its index key."""
+    return (lat[:, None],) + (() if index is None else (index[1][:, None],))
+
+
+def _latent_attention(n, p, cfg: TransformerConfig, kind: LayerKind, reach):
+    """Latent attention of a layer's NORMED input ``n``; the sparse kind
+    projects the indexer's ``(q_I, k_I, w)`` beside."""
+    q_nope, q_rope, cq = _mla_q(n, p, cfg, positions=reach.positions,
+                                with_cq=True)
+    lat = _mla_kv(n, p, cfg, positions=reach.positions)
+    index = (_dsa_proj(n, cq, p, cfg, positions=reach.positions)
+             if kind is LAYER_KINDS["sparse"] else None)
+    h, *new = reach.latent(q_nope, q_rope, lat, index, p, kind)
+    return h, tuple(new)
+
+
+def _latent_mixer(x, p, cfg: TransformerConfig, kind: LayerKind, reach):
+    return _latent_attention(_attn_norm(x, p, cfg), p, cfg, kind, reach)
+
+
+def _kv_row(cfg: TransformerConfig):
+    """A page's rows of K or V: one a KV head, or ``kv_pack`` narrow
+    heads side by side in one."""
+    return cfg.kv_heads // cfg.kv_pack, cfg.head_dim * cfg.kv_pack
+
+
+def _taps(cfg: TransformerConfig):
+    return cfg.conv_taps, cfg.conv_width
+
+
+#: THE table of layer kinds: its mixer, and what a layer of each kind
+#: keeps for a request by the page pool's names — pages of keys and
+#: values (``k``/``v``, their scales when quantized; a window layer's a
+#: pool of their own, ``wk``/``wv``), a latent layer's one row a token
+#: (``k``) and an indexer's keys beside it (``ik``), a short
+#: convolution's last inputs (``conv``, a slot), a state-space mixer's
+#: matrix state (``ssm``, a slot).  The pattern's names first, in the
+#: order programs take them; then what a ``full`` layer of a latent
+#: model resolves to (:meth:`TransformerConfig.kind`).  A new kind is an
+#: entry here and a reference in ``plain_reference.py``: the bodies and
+#: the pool's allocation, landing and gather read this table.
+LAYER_KINDS = {
+    "full": LayerKind(_kv_mixer, {"k": _kv_row, "v": _kv_row},
+                      ("k_scale", "v_scale")),
+    "sliding": LayerKind(_kv_mixer, {"wk": _kv_row, "wv": _kv_row},
+                         window=True),
+    "conv": LayerKind(_conv_mixer, state={"conv": _taps}),
+    "hybrid": LayerKind(
+        _hybrid_mixer, {"k": _kv_row, "v": _kv_row},
+        state={"conv": _taps, "ssm": lambda c: (
+            c.ssm_heads, c.ssm_head_dim, c.ssm_state)}),
+    "latent": LayerKind(_latent_mixer, {"k": lambda c: (1, c.latent_row)}),
+    "sparse": LayerKind(_latent_mixer, {
+        "k": lambda c: (1, c.latent_row),
+        "ik": lambda c: (1, c.index_head_dim)}),
+}
+
+#: A window layer's arrays by the names they take in the pool of their
+#: own (a full layer's: one allocator class serves both).
+WINDOW_ARRAYS = dict(zip(LAYER_KINDS["sliding"].paged,
+                         LAYER_KINDS["full"].paged))
 
 
 def _paged_decode_attend(qh, k_pool, v_pool, k_scale, v_scale, layer, table,
                          pos, active, cfg: TransformerConfig, kernel, mesh,
                          lower=None):
-    """The attend tail of :func:`_attention_decode_paged` (after the
-    write): the fused kernel, or gather -> dequant -> ``_cache_attend``;
-    ``lower`` is a window layer's first visible position."""
+    """The attend tail of :meth:`_Tick.attend` (after the write): the
+    fused kernel, or gather -> dequant -> ``_cache_attend``; ``lower``
+    is a window layer's first visible position."""
     max_pages = table.shape[1]
     ps = k_pool.shape[3]
     quantized = k_scale is not None
@@ -2589,22 +2882,19 @@ def _gather_kv(k_pool, v_pool, k_scale, v_scale, layer, table,
     return kg, vg
 
 
-def _kind_pools(pool: Dict, cfg: TransformerConfig):
-    """The names of a paged pool's stacked arrays by layer kind — the
-    full layers' ``k``/``v`` (with their scales when quantized), for
-    a configuration with window layers those layers' own ``wk``/``wv``,
-    for one with conv layers their per-slot state ``conv``, for one of
-    hybrid layers pages AND ``conv`` AND ``ssm`` of every layer — for
-    the kinds this configuration HAS (:data:`_POOL_ARRAYS`): a uniform
-    model carries no second stack, a model of window layers alone no
-    first."""
-    quantized = "k_scale" in pool
-    if cfg.has_window and (quantized or "wk" not in pool):
-        raise UnsupportedModelConfigError(
-            "window layers keep their own unquantized pool "
-            "('wk'/'wv' beside 'k'/'v')")
-    return {kind: tuple(n for n in names if n in pool)
-            for kind, names in _POOL_ARRAYS.items() if cfg.kind_count(kind)}
+def _pool_kinds(pool: Dict, cfg: TransformerConfig) -> Dict[str, LayerKind]:
+    """``cfg.kinds``, once ``pool`` is seen to hold every array each
+    declares, and scales only beside a kind that declares them (window
+    layers keep their own unquantized pool)."""
+    scaled = any(n in pool for k in LAYER_KINDS.values() for n in k.scales)
+    for name, kind in cfg.kinds.items():
+        lacks = [n for n in kind.block if n not in pool]
+        if lacks or (scaled and kind.paged and not kind.scales):
+            raise UnsupportedModelConfigError(
+                f"a {name!r} layer keeps {kind.block} in the pool, "
+                f"unquantized unless it declares scales; this pool lacks "
+                f"{lacks}" + (" and is quantized" if scaled else ""))
+    return cfg.kinds
 
 
 def moe_load(counts):
@@ -2639,24 +2929,17 @@ def decode_step_paged(params: Dict, tokens_t, pool: Dict, table,
     host-owned and passed back unchanged.
 
     ``kernel=True`` routes every layer's attention through the fused
-    Pallas flash-decoding kernel (gather/dequant/attend in one VMEM
-    pass — :mod:`horovod_tpu.ops.paged_attention`); logits stay greedy-
-    token-identical to the unfused path.  ``kernel``/``mesh`` are
-    trace-time Python values, so flipping them selects a DIFFERENT
+    Pallas kernels (:mod:`horovod_tpu.ops.paged_attention`); logits stay
+    greedy-token-identical to the unfused path.  ``kernel``/``mesh`` are
+    trace-time Python values: flipping them selects a DIFFERENT
     executable rather than recompiling an existing one.
 
-    A configuration with window layers (``cfg.has_window``) holds two
-    kinds of KV state: ``pool["k"]``/``["v"]`` and ``table`` are the
-    FULL layers' (stacked over those layers alone), ``pool["wk"]``/
-    ``["wv"]`` and ``wtable`` the window layers', whose pages behind
-    ``pos - window`` may be released.  One with conv layers
-    (``cfg.has_conv``) holds their per-slot state beside the pages,
-    ``pool["conv"]`` ``(L_conv, S, K - 1, D)``, read and written in
-    place like them (:func:`_conv_decode`).  One of hybrid layers
-    (``cfg.has_ssm``) holds all three in EVERY layer: ``k``/``v`` pages,
-    the mixer's taps ``pool["conv"]`` ``(L, S, K - 1, C)`` and its
-    matrix states ``pool["ssm"]`` ``(L, S, H, P, N)``
-    (:func:`_ssm_decode`).  An expert model routes each
+    ``pool`` holds every array the configuration's kinds of layer
+    declare (:data:`LAYER_KINDS`), each stacked over ITS kind's layers
+    alone: pages under ``table`` — a window layer's under ``wtable``,
+    whose pages behind ``pos - window`` may be released — and per-slot
+    states ``(L_kind, S, ...)``, all read and written in place
+    (:class:`_Tick`).  An expert model routes each
     active row to its ``n_experts_per_tok`` experts through the
     dropless grouped products — ``S * k`` expert rows a tick, idle
     slots none; ``return_moe_load`` adds :func:`moe_load` of the tick
@@ -2674,31 +2957,18 @@ def decode_step_paged(params: Dict, tokens_t, pool: Dict, table,
     x = _embed(params, tokens_t, cfg)[:, None]  # (S, 1, D)
     x = jnp.where(active[:, None, None], x, jnp.zeros_like(x))
     moe = cfg.n_experts > 1
-    names = _kind_pools(pool, cfg)
+    kinds = _pool_kinds(pool, cfg)
+    reach = _Tick(cfg, table, wtable, pos, active, kernel, mesh)
 
     # The stacked pools are the scan's CARRY, beside x: loop state that
     # each layer writes in place at its own index.  As xs -> ys the scan
     # would cut every layer out of the stack and stack it back.
     def layer(carry, p, kind, i):
         x, pools = carry
-        if kind == "conv":      # its state: the slots' last inputs
-            h, conv = _conv_decode(x, p, cfg, pools["conv"], i, active)
-            kv = (conv,)
-        elif kind == "hybrid":  # pages AND two states, one normed input
-            n = _attn_norm(x, p, cfg)
-            h, kv = _attention_decode_paged(
-                _attn_in(n, cfg), p, cfg, (pools["k"], pools["v"]), i,
-                table, pos, active, kernel=kernel, mesh=mesh, kind=kind)
-            hs, conv, ssm = _ssm_decode(n, p, cfg, pools["conv"],
-                                        pools["ssm"], i, active, kernel)
-            h, kv = _mix(h, hs, cfg), kv + (conv, ssm)
-        else:
-            h, kv = _attention_decode_paged(
-                _attn_norm(x, p, cfg), p, cfg,
-                tuple(pools[n] for n in names[kind]), i,
-                wtable if kind == "sliding" else table, pos, active,
-                kernel=kernel, mesh=mesh, kind=kind)
-        pools = {**pools, **dict(zip(names[kind], kv))}
+        h, new = kinds[kind].mixer(x, p, cfg, kinds[kind],
+                                   reach.at(pools=pools, layer=i))
+        # (an unquantized pool has no scales: the zip stops short)
+        pools = {**pools, **dict(zip(kinds[kind].arrays, new))}
         if not moe or "router" not in p:   # ... or a leading dense layer
             return (_mlp_block(x + h, p, cfg), pools), None
         # only the active rows' k picks are computed: S * k expert rows
@@ -2707,10 +2977,11 @@ def decode_step_paged(params: Dict, tokens_t, pool: Dict, table,
         return (y, pools), counts
 
     (x, pools), ys = _scan_layer_kinds(
-        cfg, layer, (x, {n: pool[n] for ns in names.values() for n in ns}),
+        cfg, layer, (x, {n: pool[n] for k in kinds.values()
+                         for n in k.arrays if n in pool}),
         params["layers"],
         {kind: jnp.arange(cfg.kind_count(kind), dtype=jnp.int32)
-         for kind in names}, params.get("dense_layers"))
+         for kind in kinds}, params.get("dense_layers"))
     logits = _lm_head(x, params["ln_f"], _head(params, cfg), cfg)
     out = {**pools, "pos": pos + active.astype(jnp.int32)}
     if not return_moe_load:
@@ -2974,7 +3245,7 @@ def decode_verify_paged(params: Dict, window, pool: Dict, table,
     # of a slot span at most ``C`` pages: they are merged into whole
     # pages first (a page is written once), and a page that takes none
     # of them is the NULL page (physical 0).
-    from horovod_tpu.serving.cache import write_pages
+    from horovod_tpu.ops.paged_attention import write_pages
 
     with jax.named_scope("kv_write"):
         C = -(-(W - 1) // ps) + 1
@@ -3003,236 +3274,67 @@ def decode_verify_paged(params: Dict, window, pool: Dict, table,
     return t, mx, acc, out
 
 
-def _by_kind(ys: Dict, pos, full=("k", "v")) -> Dict:
-    """A prefill's per-layer K/V as the cache block it returns:
-    ``k``/``v`` stacked over the full layers (``full``:
-    :attr:`TransformerConfig.pool_arrays` — a latent model's rows are
-    ``k`` alone, its index keys ``ik``) and, where the configuration
-    has window layers, ``wk``/``wv`` over those."""
+def _block(cfg: TransformerConfig, ys: Dict, pos) -> Dict:
+    """A prefill's results, stacked by kind, as the block it returns:
+    each array ``(L_kind, B, ...)`` under the pool's name, and ``pos``."""
     out = {"pos": pos}
-    if "full" in ys:
-        out.update((n, a) for n, a in zip(full, ys["full"]) if n)
-    if "sliding" in ys:
-        out["wk"], out["wv"] = ys["sliding"]
-    if "conv" in ys:      # (L_conv, B, K - 1, D): the rows' new state
-        out["conv"] = ys["conv"]
-    if "hybrid" in ys:    # pages' rows, taps and matrix states
-        out.update(zip(_POOL_ARRAYS["hybrid"], ys["hybrid"]))
+    for name, y in ys.items():
+        out.update(zip(cfg.kind(name).block, y))
     return out
 
 
-def prefill_with_prefix(params: Dict, suffix, prefix_k, prefix_v,
-                        prefix_len, cfg: TransformerConfig, *,
-                        true_len, moe_impl: str = "dropless",
-                        win_k=None, win_v=None, win_start=0,
-                        conv_state=None, ssm_state=None):
+def prefill_with_prefix(params: Dict, suffix, prefix: Dict, prefix_len,
+                        cfg: TransformerConfig, *, true_len, win_start=0):
     """Prefill a (K, S0) SUFFIX whose first ``prefix_len`` logical
-    positions already exist as cached K/V — the prefix-sharing prefill:
-    a registered system prompt is prefilled ONCE, and every request
-    that starts with it runs only its suffix through the model,
-    attending the shared prefix K/V read back from its (refcounted)
-    pages.
+    positions already exist in the cache — a prompt's next CHUNK, or the
+    prefix-sharing prefill: a registered system prompt is prefilled
+    ONCE, and every request that starts with it runs only its suffix
+    through the model, attending the shared prefix read back from its
+    (refcounted) pages.
 
-    ``prefix_k``/``prefix_v``: ``(L, H_kv, P0, Dh)`` with ``P0 >=
-    prefix_len`` (page-granular gathers round up; positions ``>=
-    prefix_len`` are masked out, so page-tail junk is inert), shared by
-    every row.  ``true_len``: ``(K,)`` per-row REAL suffix token counts
-    (rows are right-padded to the bucket S0).  Suffix queries sit at
-    global positions ``prefix_len + i`` (RoPE) and attend the full
-    prefix plus their causal suffix span.  Returns ``(last-real-
-    position logits (K, V), {"k": (L, K, H_kv, S0, Dh), "v": ...,
-    "pos": prefix_len + true_len})`` — the suffix K/V for page landing,
-    exactly :func:`prefill`'s contract shifted by the prefix.
+    ``prefix``: what the layers kept for those positions, ONE dict under
+    the pool's names — exactly the arrays the configuration's kinds
+    declare (:attr:`LayerKind.block`; anything else is refused, typed):
+    pages as :func:`~horovod_tpu.serving.cache.gather_prefix_pages`
+    hands them over, ``(L_kind, heads, P0, width)``, shared by every
+    row, and per-slot states ``(L_kind, K, ...)`` (:class:`_Chunk`).
+    ``true_len``: ``(K,)`` per-row REAL suffix token counts (rows are
+    right-padded to the bucket S0).  Suffix queries sit at global
+    positions ``prefix_len + i`` (RoPE) and attend the full prefix plus
+    their causal suffix span.  Returns ``(last-real-position logits (K,
+    V), block)`` — the block for page landing under the same names,
+    ``(L_kind, K, ...)``, with ``pos = prefix_len + true_len``: exactly
+    :func:`prefill`'s contract shifted by the prefix.
 
-    Position-wise the suffix K/V (and logits) match a full-prompt
-    :func:`prefill` bit-for-bit at f32: K/V at a position depend only
-    on the tokens at and before it, and the shared math
-    (``_qkv_proj`` / ``_cache_attend``-style grouped attention /
-    ``_mlp_block`` / ``_lm_head``) is the same code.
-
-    With window layers (``cfg.has_window``) ``prefix_k``/``prefix_v``
-    hold the FULL layers alone and ``win_k``/``win_v`` ``(L_win, H_kv,
-    P0w, Dh)`` the window layers' landed K/V from logical position
-    ``win_start`` on (a traced scalar: whatever lies behind the first
-    query's window was never gathered).  A window layer's query at
-    ``i`` sees key ``j`` iff ``j <= i`` and ``i - j < cfg.window``.
-    The returned block then carries ``wk``/``wv`` beside ``k``/``v``.
-
-    With latent attention ``prefix_k`` is the landed latent rows ``(L,
-    1, P0, latent_row)`` and ``prefix_v`` None; the chunk attends them
-    EXPANDED, in blocks (:func:`_mla_chunk_attend`), and the returned
-    block is ``k`` alone.  With an indexer ``prefix_v`` is the landed
-    index keys ``(L, 1, P0, index_head_dim)``, the block carries ``ik``
-    beside ``k``, and once the chunk's last query sees more than
-    ``index_topk`` positions each query attends its selected rows,
-    absorbed (:func:`_dsa_chunk_attend`).
-
-    With conv layers (``cfg.has_conv``) ``prefix_k``/``prefix_v`` hold
-    the attention layers alone and ``conv_state`` ``(L_conv, K, taps,
-    D)`` each row's state at ``prefix_len`` — its last ``taps`` gated
-    inputs, as the chunk before left them; the returned block carries
-    the state at ``prefix_len + true_len`` as ``conv``.  A pool whose
-    rows several KV heads share (``cfg.kv_pack``) hands the prefix over
-    as it stores it.  With hybrid layers (``cfg.has_ssm``) every layer
-    takes its landed K/V AND ``conv_state`` ``(L, K, taps, C)`` AND
-    ``ssm_state`` ``(L, K, H, P, N)``, and the block carries ``k``,
-    ``v``, ``conv`` and ``ssm``."""
+    Position-wise the suffix's block (and logits) match a full-prompt
+    :func:`prefill` bit-for-bit at f32: what a position leaves depends
+    only on the tokens at and before it, and the layer is the same code
+    (:data:`LAYER_KINDS`)."""
     K, S0 = suffix.shape
-    P0 = prefix_k.shape[2]
+    kinds = cfg.kinds
+    declared = {n for k in kinds.values() for n in k.block}
+    if set(prefix) != declared:
+        raise UnsupportedModelConfigError(
+            f"this configuration's layers keep {sorted(declared)} for a "
+            f"request; the prefix holds {sorted(prefix)}")
     p0 = jnp.asarray(prefix_len, jnp.int32)
     true_len = jnp.asarray(true_len, jnp.int32)
     positions = p0 + jnp.arange(S0, dtype=jnp.int32)
     x = _embed(params, suffix, cfg)
-    if cfg.latent:
-        # the prefix is the landed latent rows (L, 1, P0, latent_row);
-        # it has no V, and the returned block none either
-        def layer(x, p, kind, kv):
-            h = _attn_norm(x, p, cfg)
-            q_nope, q_rope, cq = _mla_q(h, p, cfg, positions=positions,
-                                        with_cq=True)
-            lat = _mla_kv(h, p, cfg, positions=positions)
-            ik = None
-            if cfg.sparse:
-                qi, ik, w = _dsa_proj(h, cq, p, cfg, positions=positions)
-            if cfg.sparse and P0 + S0 > cfg.index_topk:
-                o = _dsa_chunk_attend(
-                    _mla_absorb_q(q_nope, q_rope, p, cfg), qi, w, lat, ik,
-                    kv[0][0], kv[1][0], p0, cfg)
-                out = _mla_out(o, p, cfg, absorbed=True)
-            else:
-                out = _mla_out(_mla_chunk_attend(
-                    q_nope, q_rope, lat, kv[0][0], p0, p, cfg), p, cfg)
-            return (_mlp_block(x + out, p, cfg, moe_impl=moe_impl),
-                    (lat[:, None], None if ik is None else ik[:, None]))
+    reach = _Chunk(cfg, true_len, positions, p0, prefix, win_start)
 
-        x, ys = _scan_layer_kinds(cfg, layer, x, params["layers"],
-                                  {"full": (prefix_k, prefix_v)},
-                                  params.get("dense_layers"))
-        last = jnp.take_along_axis(x, (true_len - 1)[:, None, None], axis=1)
-        logits = _lm_head(last, params["ln_f"], _head(params, cfg), cfg)
-        return logits[:, 0], _by_kind(ys, p0 + true_len, cfg.pool_arrays)
-    H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
-    G = H // Hkv
-    if cfg.kv_pack > 1:
-        prefix_k, prefix_v = (_unpack_heads(a, cfg.kv_pack)
-                              for a in (prefix_k, prefix_v))
-    # (S0, P0 + S0) mask: the real prefix is fully visible, page-tail
-    # junk (>= p0) never, and the suffix is causal within itself.
-    pre_vis = lax.broadcasted_iota(jnp.int32, (P0,), 0)[None, :] < p0
-    pre_vis = jnp.broadcast_to(pre_vis, (S0, P0))
-    suf_vis = (lax.broadcasted_iota(jnp.int32, (S0, S0), 1)
-               <= lax.broadcasted_iota(jnp.int32, (S0, S0), 0))
-    masks = {"full": jnp.concatenate([pre_vis, suf_vis],
-                                     axis=1)[None, None, None]}
-    xs = {"full": (prefix_k, prefix_v)}
-    if cfg.has_ssm:
-        masks["hybrid"] = masks["full"]
-        xs = {"hybrid": (prefix_k, prefix_v, conv_state, ssm_state)}
-    if cfg.has_window:
-        # The window block's column j is logical position win_start + j:
-        # landed (< p0; the gather's padding lies past it) and within
-        # the window of the row; the suffix is causal within the window.
-        W = cfg.window
-        kpos = (jnp.asarray(win_start, jnp.int32)
-                + lax.broadcasted_iota(jnp.int32, (win_k.shape[2],), 0))
-        wpre = ((kpos[None, :] < p0)
-                & (positions[:, None] - kpos[None, :] < W))
-        rows = lax.broadcasted_iota(jnp.int32, (S0, S0), 0)
-        cols = lax.broadcasted_iota(jnp.int32, (S0, S0), 1)
-        masks["sliding"] = jnp.concatenate(
-            [wpre, suf_vis & (rows - cols < W)], axis=1)[None, None, None]
-        xs["sliding"] = (win_k, win_v)
-    if cfg.has_conv:
-        xs["conv"] = conv_state
+    def layer(x, p, kind, landed):
+        h, new = kinds[kind].mixer(x, p, cfg, kinds[kind], reach.at(
+            landed=dict(zip(kinds[kind].block, landed))))
+        return _mlp_block(x + h, p, cfg, moe_impl="dropless"), new
 
-    def layer(x, p, kind, kv):
-        if kind == "conv":      # kv: the rows' state, (K, taps, D)
-            out, new = _conv_prefill(x, p, cfg, kv, true_len)
-            return _mlp_block(x + out, p, cfg, moe_impl=moe_impl), new
-        pk, pv, *state = kv
-        P0, mask = pk.shape[1], masks[kind]
-        h = n = _attn_norm(x, p, cfg)
-        if kind == "hybrid":
-            h = _attn_in(n, cfg)
-        qh, kh, vh = _qkv_proj(h, p, cfg, positions=positions, kind=kind)
-        with jax.named_scope("chunk_attn"):
-            k_full = jnp.concatenate(
-                [jnp.broadcast_to(pk[None].astype(kh.dtype),
-                                  (K, Hkv, P0, Dh)), kh], axis=2)
-            v_full = jnp.concatenate(
-                [jnp.broadcast_to(pv[None].astype(vh.dtype),
-                                  (K, Hkv, P0, Dh)), vh], axis=2)
-            # Grouped-query attention with the prefix mask — the same
-            # bandwidth discipline as _cache_attend, S0 queries wide.
-            qg = qh.reshape(K, Hkv, G, S0, Dh)
-            s = jnp.einsum("bkgsd,bktd->bkgst", qg.astype(k_full.dtype),
-                           k_full, preferred_element_type=jnp.float32
-                           ) / np.sqrt(Dh)
-            s = jnp.where(mask, s, -1e30)
-            w = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("bkgst,bktd->bkgsd", w.astype(v_full.dtype),
-                           v_full, preferred_element_type=jnp.float32)
-            oh = o.reshape(K, H, S0, Dh)
-        out = _out_proj(oh.astype(cfg.dtype), p, cfg)
-        if kind == "hybrid":    # from the taps and the state before it
-            hs, *new = _ssm_prefill(n, p, cfg, *state, true_len)
-            return (_mlp_block(x + _mix(out, hs, cfg), p, cfg,
-                               moe_impl=moe_impl), (kh, vh, *new))
-        return _mlp_block(x + out, p, cfg, moe_impl=moe_impl), (kh, vh)
-
-    x, ys = _scan_layer_kinds(cfg, layer, x, params["layers"], xs,
-                              params.get("dense_layers"))
+    x, ys = _scan_layer_kinds(
+        cfg, layer, x, params["layers"],
+        {name: tuple(reach.prefix[n] for n in k.block)
+         for name, k in kinds.items()}, params.get("dense_layers"))
     last = jnp.take_along_axis(x, (true_len - 1)[:, None, None], axis=1)
     logits = _lm_head(last, params["ln_f"], _head(params, cfg), cfg)
-    return logits[:, 0], _by_kind(ys, p0 + true_len)
-
-
-def _attention_prefill(x, p, cfg: TransformerConfig, mesh=None,
-                       kind: str = "full"):
-    """Full-sequence attention that ALSO returns the (unexpanded,
-    post-RoPE) per-layer K/V for cache filling.  Shares the projection
-    math with :func:`_attention` via ``_qkv_proj``/``_out_proj`` and
-    honors ``attention_impl='reference'``; the sequence-parallel impls
-    need a bound mesh axis, so they prefill through the flash kernel
-    (which takes the XLA form for untileable prompts).
-
-    ``mesh``: a tp serving mesh.  GSPMD cannot partition a Mosaic
-    kernel ("wrap the call in a shard_map"), so under tp the flash
-    kernel runs per head shard through ``shard_map`` — attention is
-    per-head, and a contiguous tp split keeps every query head on the
-    device that holds its KV head, so the GQA expansion is local.
-
-    ``kind="sliding"``: the same kernel with the window's lower bound
-    (blocks wholly behind it skipped)."""
-    from horovod_tpu.ops import attention as attn
-
-    if cfg.latent:  # the cache block: the latent rows, and no V (with
-        return _mla_attention(x, p, cfg, mesh)  # an indexer, its keys)
-    window = cfg.window if kind == "sliding" else 0
-    qh, kh, vh = _qkv_proj(x, p, cfg, 0, kind=kind)  # kh/vh: (B,H_kv,S0,Dh)
-    if cfg.attention_impl == "reference":
-        with jax.named_scope("attn"):
-            oh = attn.reference_attention(
-                qh, attn.expand_kv(kh, cfg.n_heads),
-                attn.expand_kv(vh, cfg.n_heads), causal=True,
-                window=window)
-        return _out_proj(oh, p, cfg), kh, vh
-
-    def flash(q, k, v):
-        k, v = attn.expand_kv(k, q.shape[1]), attn.expand_kv(v, q.shape[1])
-        if window:
-            return attn.flash_attention_windowed(q, k, v, window)
-        return attn.flash_attention(q, k, v, True)
-
-    if mesh is not None:
-        from horovod_tpu import spmd
-
-        head = P(None, "tp", None, None)
-        flash = spmd.shard(flash, in_specs=(head, head, head),
-                           out_specs=head, mesh=mesh)
-    with jax.named_scope("attn"):
-        oh = flash(qh, kh, vh)
-    return _out_proj(oh, p, cfg), kh, vh
+    return logits[:, 0], _block(cfg, ys, p0 + true_len)
 
 
 def prefill(params: Dict, prompt, cache: Dict, cfg: TransformerConfig,
@@ -3243,8 +3345,9 @@ def prefill(params: Dict, prompt, cache: Dict, cfg: TransformerConfig,
     with ``pos = S0``.  Continue with :func:`decode_step`.
 
     ``moe_impl`` selects the MoE dispatch for MoE configs: "dropless"
-    (grouped ragged matmuls — exact at 1/E of dense FLOPs, the default)
-    or "dense" (the every-expert oracle; benchmarking/fallback).
+    (grouped ragged matmuls — exact like dense but k/E of its FFN FLOPs,
+    the default: prefill ingests whole prompts) or "dense" (the
+    every-expert oracle; benchmarking/fallback).
 
     ``true_len`` supports BUCKETED prefill (the serving engine's
     compile-stability lever): the prompt is RIGHT-padded to a bucket
@@ -3263,16 +3366,13 @@ def prefill(params: Dict, prompt, cache: Dict, cfg: TransformerConfig,
     position ``p`` in the same step that first attends it.
 
     ``mesh``: the tp serving mesh when params are head-sharded under
-    GSPMD (see :func:`_attention_prefill`).
+    GSPMD (:class:`_Prompt`).
 
-    With window layers (``cfg.has_window``) the K/V come back BY KIND —
-    ``k``/``v`` stacked over the full layers, ``wk``/``wv`` over the
-    window layers, each ``(L_kind, B, H_kv, S0, Dh)`` — for a paged
-    engine's two pools; ``cache`` then only gives ``pos``.  So with
-    conv layers (``cfg.has_conv``): ``k``/``v`` over the attention
-    layers and ``conv`` ``(L_conv, B, taps, D)``, each row's state at
-    its ``true_len``; and with hybrid layers (``cfg.has_ssm``) ``k``/
-    ``v``, ``conv`` and ``ssm`` ``(L, B, H, P, N)`` over every layer."""
+    Where the layers keep arrays ``cache`` has no place for (two kinds
+    of pages, a per-slot state: :data:`LAYER_KINDS`) the results come
+    back BY KIND for a paged engine's pools — each array ``(L_kind, B,
+    ...)`` under its name, a state each row's at its ``true_len`` — and
+    ``cache`` only gives ``pos``."""
     pos = cache["pos"]
     if not isinstance(pos, jax.core.Tracer) and int(pos) != 0:
         raise ValueError("prefill requires a fresh cache (pos == 0)")
@@ -3283,28 +3383,16 @@ def prefill(params: Dict, prompt, cache: Dict, cfg: TransformerConfig,
             f"prompt ({S0} tokens) exceeds cache capacity ({T_cache}); "
             "init_cache with a larger max_len")
     x = _embed(params, prompt, cfg)
-    if cfg.has_state:           # each row's real length, for its state
+    kinds, lens = cfg.kinds, None
+    if any(k.state for k in kinds.values()):   # each row's real length
         lens = jnp.broadcast_to(jnp.asarray(
             S0 if true_len is None else true_len, jnp.int32),
             prompt.shape[:1])
+    reach = _Prompt(cfg, lens, mesh)
 
     def layer(x, p, kind, _):
-        if kind == "conv":      # from the zeros a sequence starts from
-            h, new = _conv_prefill(x, p, cfg, None, lens)
-            return _mlp_block(x + h, p, cfg, moe_impl=moe_impl), new
-        if kind == "hybrid":    # both mixers from zeros, one normed input
-            n = _attn_norm(x, p, cfg)
-            h, kh, vh = _attention_prefill(_attn_in(n, cfg), p, cfg, mesh,
-                                           kind)
-            hs, *new = _ssm_prefill(n, p, cfg, None, None, lens)
-            return (_mlp_block(x + _mix(h, hs, cfg), p, cfg,
-                               moe_impl=moe_impl), (kh, vh, *new))
-        h, kh, vh = _attention_prefill(_attn_norm(x, p, cfg), p, cfg, mesh,
-                                       kind)
-        # Prefill ingests whole prompts: DROPLESS grouped-matmul dispatch
-        # by default — exact like dense but k/E of its FFN FLOPs
-        # (ops/moe.py dropless_moe).
-        return _mlp_block(x + h, p, cfg, moe_impl=moe_impl), (kh, vh)
+        h, new = kinds[kind].mixer(x, p, cfg, kinds[kind], reach)
+        return _mlp_block(x + h, p, cfg, moe_impl=moe_impl), new
 
     x, ys = _scan_layer_kinds(cfg, layer, x, params["layers"],
                               dense=params.get("dense_layers"))
@@ -3326,16 +3414,13 @@ def prefill(params: Dict, prompt, cache: Dict, cfg: TransformerConfig,
                                    axis=1)
         new_pos = pos + true_len
     logits = _lm_head(last, params["ln_f"], _head(params, cfg), cfg)
-    if cfg.has_window or cfg.has_state:
-        # two kinds of state: handed back by kind for the caller's
-        # pools, not landed in a cache of one shape
-        return logits[:, 0], _by_kind(ys, new_pos)
-    blocks = dict(zip(cfg.pool_arrays, ys[next(iter(ys))]))
+    block = _block(cfg, ys, new_pos)
+    if not set(block) <= set(cache):
+        return logits[:, 0], block   # for the caller's pools, by kind
     with jax.named_scope("kv_land"):
-        cache = {n: lax.dynamic_update_slice_in_dim(
+        cache = {n: b if n == "pos" else lax.dynamic_update_slice_in_dim(
             cache[n], b.astype(cache[n].dtype), 0, axis=3)
-            for n, b in blocks.items() if b is not None}
-        cache["pos"] = new_pos
+            for n, b in block.items()}
     return logits[:, 0], cache
 
 

@@ -968,3 +968,32 @@ def selected_attend(q, rows, count, *, v_dim: int, sm_scale: float,
                           sm_scale=sm_scale, name=SELECT_ATTEND_NAME)
     return mla_decode_reference(q, pool, table, count, v_dim=v_dim,
                                 sm_scale=sm_scale)
+
+
+def write_pages(stack, layer, phys, new, take):
+    """THE write into a page pool: whole pages, addressed by the pool's
+    two leading dims and nothing else.
+
+    ``stack`` is one pool array, ``(L, P, H_kv, page, ...)`` (payload
+    with its trailing ``Dh``, or an int8 pool's scales without one);
+    ``layer`` and ``phys`` are int32 arrays that broadcast to one batch
+    shape ``B``; ``new`` broadcasts to ``B + (H_kv, page, ...)`` and
+    ``take`` ``B + (page,)`` says which offsets of each page take it.
+    The ``B`` target pages are read, the taken offsets replaced, and the
+    pages written back at ``[layer, phys]``: the scatter's indices are
+    the leading dims and its window the whole page, which is the pool's
+    own layout, so the compiler updates a donated (or loop-carried) pool
+    in place and no operation has a result the size of a layer of it.
+    What is not taken keeps its contents — the positions before a
+    suffix's ``start``, a page's tail.
+
+    A page may appear ONCE among the targets: of two whole-page updates
+    of one page the later would undo the earlier, so callers merge the
+    rows that share a page first.  The NULL page alone is exempt:
+    inactive rows, padding and rejected drafts all go there, and what
+    it holds is never attended."""
+    idx = (jnp.asarray(layer, jnp.int32), jnp.asarray(phys, jnp.int32))
+    take = take.reshape(take.shape[:-1] + (1, take.shape[-1])
+                        + (1,) * (stack.ndim - 4))
+    pages = jnp.where(take, new.astype(stack.dtype), stack[idx])
+    return stack.at[idx].set(pages)

@@ -1,11 +1,9 @@
 """The serving engine's KV cache: a pool of fixed-size pages.
 
 The paged layout (PagedAttention, Kwon et al., SOSP 2023) stores K/V as
-a pool of fixed-size pages, ``(L, P, H_kv, page, Dh)`` (K and V; a
-latent-attention model's ONE array ``(L, P, 1, page, 640)``, the rows
-its heads share, and beside it, where the model has an indexer, its
-keys ``(L, P, 1, page, 128)`` under the same page table:
-:func:`init_page_pool`); each of the S
+a pool of fixed-size pages, ``(L, P, H_kv, page, Dh)`` (K and V, or
+whatever the model's kinds of layer page: :func:`init_page_pool`); each
+of the S
 slots owns an int32 page-table row, resolved INSIDE the compiled decode
 tick (:func:`~horovod_tpu.models.transformer.decode_step_paged`), and a
 per-slot ``(S,)`` write position, because every slot holds a different
@@ -21,22 +19,20 @@ granted on demand, refcounted for prefix sharing and copied on write.
 Nothing freed is scrubbed: a page's next owner writes every position
 before first attending it (``tests/test_paged.py`` exercises it).
 Every write into the pool, from the tick, the speculative verify and
-the landing alike, is :func:`write_pages`.
+the landing alike, is :func:`~horovod_tpu.ops.paged_attention.write_pages`.
+WHICH arrays a pool holds is the model's table of layer kinds
+(:data:`~horovod_tpu.models.transformer.LAYER_KINDS`).
 
-A model with conv layers (gated short convolutions) keeps a SECOND kind
-of per-request state under the same manager: ``conv`` ``(L_conv, S,
-taps, D)``, every conv layer's last ``taps`` gated inputs of every SLOT
+A kind of layer may keep a per-SLOT state under the same manager (a
+conv layer's last ``taps`` gated inputs ``conv`` ``(L_conv, S, taps,
+D)``; a hybrid layer's taps and, MBs a slot and layer where those are
+KBs, its state-space mixer's matrix state ``ssm`` ``(L, S, H, P, N)``)
 — fixed in size where a slot's pages grow — beside the page arrays in
 the pool dict, so it is donated, carried and written in place with
 them.  It is granted with the slot and ZEROED then (:meth:`PagedSlotCache
 .alloc`), written by the tick for the active rows and by a landing for
 the landed rows (:func:`paged_insert`), and read back for a prompt's
-next chunk (:meth:`PagedSlotCache.slot_state`).  A model of hybrid
-layers (attention and a state-space mixer side by side) keeps BOTH in
-every layer: pages, the mixer's short convolution's taps in ``conv``
-(``[x | B | C]`` wide, not ``D``) and a THIRD array ``ssm`` ``(L, S, H,
-P, N)``, the mixer's matrix state a head — MBs a slot and layer where
-the taps are KBs — under the same grant, zeroing, landing and read-back.
+next chunk (:meth:`PagedSlotCache.slot_state`).
 
 (Until PR 28 a slot-contiguous ``(L, S, H_kv, T, Dh)`` cache stood
 beside this one; no workload ran it.)
@@ -53,6 +49,7 @@ import numpy as np
 from jax import lax
 
 from horovod_tpu.models import transformer as T
+from horovod_tpu.ops.paged_attention import write_pages
 from horovod_tpu.serving.scheduler import CacheOutOfPagesError
 
 NULL_PAGE = 0
@@ -77,95 +74,55 @@ def resolve_kv_dtype(cfg: "T.TransformerConfig", kv_dtype):
     return kv_dtype, jnp.dtype(kv_dtype) == jnp.int8
 
 
+def _arrays(pool: Dict, role: str) -> List[str]:
+    """The names of ``pool``'s arrays that the table declares ``role``
+    (``paged`` | ``scales`` | ``state``), in the table's order."""
+    return [n for n in dict.fromkeys(
+        n for k in T.LAYER_KINDS.values() for n in getattr(k, role))
+        if n in pool]
+
+
 def init_page_pool(cfg: "T.TransformerConfig", n_slots: int, n_pages: int,
                    page_size: int, kv_dtype=None, n_layers=None) -> Dict:
-    """The paged device cache: ``k``/``v`` are ``(L, P, H_kv, page,
-    Dh)`` page pools (``P`` counts the NULL page), ``pos`` is the
-    per-slot ``(S,)`` logical write position, and int8 storage adds
-    ``k_scale``/``v_scale`` ``(L, P, H_kv, page)`` per-vector f32
-    scales.  The page table itself is HOST state
+    """The paged device cache: what the configuration's kinds of layer
+    declare (:data:`~horovod_tpu.models.transformer.LAYER_KINDS`) —
+    every paged array a page pool ``(L, P, heads, page, width)`` (``P``
+    counts the NULL page; ``k``/``v``, or a latent model's ONE array of
+    rows and an indexer's keys under the same page table), every
+    per-slot state ``(L_kind, S, ...)`` — and ``pos``, the per-slot
+    ``(S,)`` logical write position.
+    int8 storage adds ``k_scale``/``v_scale`` ``(L, P, H_kv, page)``
+    per-vector f32 scales.  The page table itself is HOST state
     (:attr:`PagedSlotCache.table`), uploaded as data each tick.
-    ``n_layers`` overrides the depth: the pool of ONE kind of layer."""
+    ``n_layers`` overrides the pages' depth: the pool of ONE kind of
+    layer (a window layer's own is laid out as a full layer's)."""
     dt, quant = resolve_kv_dtype(cfg, kv_dtype)
     L = cfg.n_layers if n_layers is None else n_layers
-    if cfg.latent:
-        # latent attention: ONE array.  A token leaves one row a layer,
-        # ``[ckv | k_rope | 0]`` (cfg.latent_row: 576 values in 640
-        # lanes) — the key of one kv "head" every query head shares,
-        # whose first kv_lora_rank lanes are the value too — so
-        # the pool is ``k`` with H_kv = 1 and no ``v``; everything that
-        # addresses pages (write_pages, the landing, COW, the gather)
-        # reads its layout from the array, as before.
-        if quant:
+    kinds = [k for k in {"full": cfg.kind("full"), **cfg.kinds}.values()
+             if not k.window]   # a window layer's pool: a full layer's
+    stateful = any(k.state for k in kinds)
+    pool = {"pos": jnp.zeros((n_slots,), jnp.int32)}
+    for kind in kinds:
+        scale = dict(zip(kind.paged, kind.scales))
+        if (quant and not scale) or (stateful and any(
+                cfg.layers_with(n) != L for n in kind.paged)):
             raise T.UnsupportedModelConfigError(
-                "int8 pages (per-vector scales) are not written for a "
-                "latent pool")
-        pool = {"k": jnp.zeros((L, n_pages, 1, page_size,
-                                cfg.latent_row), dt),
-                "pos": jnp.zeros((n_slots,), jnp.int32)}
-        if cfg.sparse:
-            # ... and a SECOND array under the same page table: the
-            # indexer's key, one ``index_head_dim`` vector a token and
-            # layer (128: exactly one lane group, no padding).  A page
-            # of it is granted, landed, copied and released WITH the
-            # latent page of the same id.
-            pool["ik"] = jnp.zeros((L, n_pages, 1, page_size,
-                                    cfg.index_head_dim), dt)
-        return pool
-    # a row a KV head, or ``kv_pack`` narrow heads side by side in one
-    Hkv, Dh = cfg.kv_heads // cfg.kv_pack, cfg.head_dim * cfg.kv_pack
-    pool = {
-        "k": jnp.zeros((L, n_pages, Hkv, page_size, Dh), dt),
-        "v": jnp.zeros((L, n_pages, Hkv, page_size, Dh), dt),
-        "pos": jnp.zeros((n_slots,), jnp.int32),
-    }
-    if cfg.has_state:
-        if quant or cfg.layers_with("k") != L:
-            raise T.UnsupportedModelConfigError(
-                "the pool of a model with a per-slot state (conv or "
-                "hybrid layers) is its attention layers' pages, "
-                "unquantized, and that state")
-        pool["conv"] = jnp.zeros((cfg.layers_with("conv"), n_slots,
-                                  cfg.conv_taps, cfg.conv_width), dt)
-    if cfg.has_ssm:
-        pool["ssm"] = jnp.zeros(
-            (cfg.layers_with("ssm"), n_slots, cfg.ssm_heads,
-             cfg.ssm_head_dim, cfg.ssm_state), dt)
-    if quant:
-        pool["k_scale"] = jnp.zeros((L, n_pages, Hkv, page_size),
-                                    jnp.float32)
-        pool["v_scale"] = jnp.zeros((L, n_pages, Hkv, page_size),
-                                    jnp.float32)
+                "int8 pages (per-vector scales) are written for pages of "
+                "K and V alone: not for a latent pool, nor beside a "
+                "per-slot state (conv or hybrid layers), whose pool is "
+                "its attention layers' pages and that state")
+        for name, row in kind.paged.items():
+            if name not in pool:    # (a hybrid layer's are a full layer's:
+                heads, width = row(cfg)  # never a pool's array twice)
+                pool[name] = jnp.zeros(
+                    (L, n_pages, heads, page_size, width), dt)
+                if quant:
+                    pool[scale[name]] = jnp.zeros(
+                        (L, n_pages, heads, page_size), jnp.float32)
+        for name, shape in kind.state.items():
+            pool[name] = jnp.zeros(
+                (cfg.layers_with(name), n_slots) + shape(cfg), dt)
     return pool
-
-
-def write_pages(stack, layer, phys, new, take):
-    """THE write into a page pool: whole pages, addressed by the pool's
-    two leading dims and nothing else.
-
-    ``stack`` is one pool array, ``(L, P, H_kv, page, ...)`` (payload
-    with its trailing ``Dh``, or an int8 pool's scales without one);
-    ``layer`` and ``phys`` are int32 arrays that broadcast to one batch
-    shape ``B``; ``new`` broadcasts to ``B + (H_kv, page, ...)`` and
-    ``take`` ``B + (page,)`` says which offsets of each page take it.
-    The ``B`` target pages are read, the taken offsets replaced, and the
-    pages written back at ``[layer, phys]``: the scatter's indices are
-    the leading dims and its window the whole page, which is the pool's
-    own layout, so the compiler updates a donated (or loop-carried) pool
-    in place and no operation has a result the size of a layer of it.
-    What is not taken keeps its contents — the positions before a
-    suffix's ``start``, a page's tail.
-
-    A page may appear ONCE among the targets: of two whole-page updates
-    of one page the later would undo the earlier, so callers merge the
-    rows that share a page first.  The NULL page alone is exempt:
-    inactive rows, padding and rejected drafts all go there, and what
-    it holds is never attended."""
-    idx = (jnp.asarray(layer, jnp.int32), jnp.asarray(phys, jnp.int32))
-    take = take.reshape(take.shape[:-1] + (1, take.shape[-1])
-                        + (1,) * (stack.ndim - 4))
-    pages = jnp.where(take, new.astype(stack.dtype), stack[idx])
-    return stack.at[idx].set(pages)
 
 
 def _bucket_pages(x, first, n_pg: int, ps: int):
@@ -190,60 +147,47 @@ def landing_pages(bucket: int, page_size: int) -> int:
 
 @jax.named_scope("kv_land")  # T.DEVICE_SCOPES
 def paged_insert(pool: Dict, slots, new_pos, pages, first, lens,
-                 prefilled_k, prefilled_v=None, prefilled_ik=None,
-                 prefilled_conv=None, prefilled_ssm=None) -> Dict:
-    """Land a prefilled K/V block ``(L, K, H_kv, Tb, Dh)`` into pages.
-    Column ``t`` of row ``i`` is logical position ``start + t``; with
-    ``first = start % page`` it goes to offset ``(first + t) % page``
-    of ``pages[i, (first + t) // page]`` if ``t < lens[i]``, and
-    nowhere otherwise (bucket padding).  ``pages`` ``(K,
-    landing_pages(Tb, page))``, ``first`` and ``lens`` are host-built
-    DATA, so one executable per ``(K, bucket)`` shape serves every page
-    assignment and every bucket alignment (suffix landings start
-    mid-page after a COW: the positions before ``start`` stay), and a
-    page that takes no column is the NULL page.  ``slots`` /
-    ``new_pos`` adopt the per-row positions (empty for slotless
-    landings — prefix registration).  int8 pools quantize per vector
-    on the way in; payload and scale go through the same
-    :func:`write_pages`.  A latent pool has ``k`` alone
-    (``prefilled_v`` None): the block is the latent rows; a sparse
-    model's index keys ``prefilled_ik`` land in ``ik`` at the same
-    pages and offsets.  A pool whose rows several narrow KV heads share
-    takes the block a row a head and lays them side by side.  A conv
-    model's ``prefilled_conv`` ``(L_conv, K, taps, D)`` — each row's
-    state at its new position — replaces its slot's; so a hybrid
-    model's ``prefilled_ssm`` ``(L, K, H, P, N)``."""
-    ps = pool["k"].shape[3]
-    L, n_pg = pool["k"].shape[0], pages.shape[1]
+                 block: Dict) -> Dict:
+    """Land a prefilled ``block`` — what :func:`~horovod_tpu.models.
+    transformer.prefill` / ``prefill_with_prefix`` hand back, under the
+    pool's names (``pos`` apart: ``new_pos`` is it) — into the pool.
+
+    A PAGED array ``(L, K, heads, Tb, width)`` goes into pages: column
+    ``t`` of row ``i`` is logical position ``start + t``; with ``first
+    = start % page`` it goes to offset ``(first + t) % page`` of
+    ``pages[i, (first + t) // page]`` if ``t < lens[i]``, and nowhere
+    otherwise (bucket padding).  ``pages`` ``(K, landing_pages(Tb,
+    page))``, ``first`` and ``lens`` are host-built DATA, so one
+    executable per ``(K, bucket)`` shape serves every page assignment
+    and every bucket alignment (suffix landings start mid-page after a
+    COW: the positions before ``start`` stay), and a page that takes no
+    column is the NULL page.  int8 pools quantize per vector on the way
+    in; payload and scale go through the same :func:`write_pages`.  A
+    pool whose rows several narrow KV heads share takes the block a row
+    a head and lays them side by side.  A per-slot STATE ``(L_kind, K,
+    ...)`` — each row's at its new position — replaces its slot's.
+    ``slots`` / ``new_pos`` adopt the per-row positions (empty for
+    slotless landings — prefix registration)."""
+    paged, scales = _arrays(pool, "paged"), _arrays(pool, "scales")
+    ps, n_pg = pool[paged[0]].shape[3], pages.shape[1]
     first = jnp.asarray(first, jnp.int32)
     col = (jnp.arange(n_pg * ps, dtype=jnp.int32) - first).reshape(n_pg, ps)
     take = (col >= 0) & (col < jnp.asarray(lens, jnp.int32)[:, None, None])
-    layer = jnp.arange(L, dtype=jnp.int32)[:, None, None]
+    layer = jnp.arange(pool[paged[0]].shape[0], dtype=jnp.int32)[:, None, None]
 
     def land(name, x):
         return write_pages(pool[name], layer, pages[None],
                            _bucket_pages(x, first, n_pg, ps), take[None])
 
-    out = dict(pool)
-    k, v = prefilled_k, prefilled_v
-    pack = pool["k"].shape[-1] // k.shape[-1]
-    if pack > 1:
-        k, v = T._pack_heads(k, pack), T._pack_heads(v, pack)
-    if "k_scale" in pool:
-        k, sk = T.kv_quantize(k)
-        v, sv = T.kv_quantize(v)
-        out["k_scale"], out["v_scale"] = land("k_scale", sk), land("v_scale", sv)
-    out["k"] = land("k", k)
-    if v is not None:
-        out["v"] = land("v", v)
-    if prefilled_ik is not None:
-        out["ik"] = land("ik", prefilled_ik)
-    if prefilled_conv is not None:
-        out["conv"] = pool["conv"].at[:, slots].set(
-            prefilled_conv.astype(pool["conv"].dtype))
-    if prefilled_ssm is not None:
-        out["ssm"] = pool["ssm"].at[:, slots].set(
-            prefilled_ssm.astype(pool["ssm"].dtype))
+    rows = {n: T._pack_heads(block[n], pool[n].shape[-1]
+                             // block[n].shape[-1]) for n in paged}
+    if scales:
+        quant = {n: T.kv_quantize(rows[n]) for n in paged}
+        rows = {n: q for n, (q, _) in quant.items()}
+        rows.update(zip(scales, (s for _, s in quant.values())))
+    out = {**pool, **{n: land(n, x) for n, x in rows.items()}}
+    for n in _arrays(pool, "state"):
+        out[n] = pool[n].at[:, slots].set(block[n].astype(pool[n].dtype))
     out["pos"] = pool["pos"].at[slots].set(new_pos)
     return out
 
@@ -254,40 +198,34 @@ def copy_page(pool: Dict, src, dst) -> Dict:
     copy-on-write primitive.  ``src``/``dst`` are traced scalars, so
     one compile covers every copy."""
     out = dict(pool)
-    for name in ("k", "v", "k_scale", "v_scale", "ik"):
-        if name in pool:
-            a = pool[name]
-            layer = jnp.arange(a.shape[0], dtype=jnp.int32)
-            out[name] = write_pages(a, layer, dst, a[layer, src],
-                                    jnp.ones((1, a.shape[3]), bool))
+    for name in _arrays(pool, "paged") + _arrays(pool, "scales"):
+        a = pool[name]
+        layer = jnp.arange(a.shape[0], dtype=jnp.int32)
+        out[name] = write_pages(a, layer, dst, a[layer, src],
+                                jnp.ones((1, a.shape[3]), bool))
     return out
 
 
 @jax.named_scope("landed_gather")  # T.DEVICE_SCOPES
-def gather_prefix_pages(pool: Dict, pages):
-    """Materialize ``pages`` (a ``(n,)`` id vector) as contiguous
-    ``(k, v)`` of shape ``(L, H_kv, n * page, Dh)`` — the shared-prefix
-    K/V handed to :func:`~horovod_tpu.models.transformer.
-    prefill_with_prefix`.  int8 pools dequantize here (f32), so the
-    suffix prefill attends real values.  A latent pool gives ``(rows,
-    None)``, or with an indexer ``(rows, index keys)``."""
-    k = pool["k"][:, pages]                   # (L, n, H_kv, ps, Dh)
-    L, n, Hkv, ps, Dh = k.shape
-    k = jnp.moveaxis(k, 1, 2).reshape(L, Hkv, n * ps, Dh)
-    if "v" not in pool:                       # a latent pool's rows
-        if "ik" not in pool:
-            return k, None
-        return k, jnp.moveaxis(pool["ik"][:, pages], 1, 2).reshape(
-            L, 1, n * ps, -1)
-    v = jnp.moveaxis(pool["v"][:, pages], 1, 2).reshape(L, Hkv, n * ps, Dh)
-    if "k_scale" in pool:
-        ks = jnp.moveaxis(pool["k_scale"][:, pages], 1, 2
-                          ).reshape(L, Hkv, n * ps)
-        vs = jnp.moveaxis(pool["v_scale"][:, pages], 1, 2
-                          ).reshape(L, Hkv, n * ps)
-        k = T.kv_dequantize(k, ks, jnp.float32)
-        v = T.kv_dequantize(v, vs, jnp.float32)
-    return k, v
+def gather_prefix_pages(pool: Dict, pages) -> Dict:
+    """Materialize ``pages`` (a ``(n,)`` id vector) of every paged array
+    as contiguous ``(L, heads, n * page, width)``, under the pool's
+    names — the landed prefix handed to :func:`~horovod_tpu.models.
+    transformer.prefill_with_prefix` (``k`` and ``v``; a latent pool's
+    ``k`` alone, with an indexer ``ik`` beside it).  int8 pools
+    dequantize here (f32), so the suffix prefill attends real values."""
+    paged = _arrays(pool, "paged")
+    scale = dict(zip(paged, _arrays(pool, "scales")))
+    out = {}
+    for name in paged:
+        a = pool[name][:, pages]                  # (L, n, heads, ps, width)
+        L, n, H, ps, D = a.shape
+        out[name] = jnp.moveaxis(a, 1, 2).reshape(L, H, n * ps, D)
+        if name in scale:
+            s = jnp.moveaxis(pool[scale[name]][:, pages], 1, 2)
+            out[name] = T.kv_dequantize(out[name], s.reshape(L, H, n * ps),
+                                        jnp.float32)
+    return out
 
 
 class PagedSlotCache:
@@ -373,7 +311,7 @@ class PagedSlotCache:
         # the per-slot state arrays (conv layers' taps; a hybrid
         # model's taps and matrix states): zeroed with the grant, read
         # back (L, 1, ...) for a prompt's next chunk
-        state = tuple(n for n in ("conv", "ssm") if n in self.cache)
+        self.state_arrays = state = _arrays(self.cache, "state")
         self._zero_state = jax.jit(
             lambda pool, s: {**pool, **{n: pool[n].at[:, s].set(0)
                                         for n in state}},
@@ -389,7 +327,7 @@ class PagedSlotCache:
             return None
         slot = heapq.heappop(self._free)
         self._active[slot] = True
-        if "conv" in self.cache:
+        if self.state_arrays:
             # a request starts from zeros, whatever the last tenant (or
             # a tick still in flight for it) left here
             self.cache = self._zero_state(self.cache, np.int32(slot))
@@ -468,49 +406,46 @@ class PagedSlotCache:
         """Most pages ever simultaneously allocated."""
         return self.n_pages - self._min_free
 
+    def _bytes(self, names, *per) -> int:
+        """Bytes of the pool's arrays among ``names`` a unit of dims ``per``."""
+        return sum(a.nbytes // int(np.prod([a.shape[d] for d in per]))
+                   for a in map(self.cache.get, names) if a is not None)
+
     @property
     def bytes_per_token(self) -> int:
         """KV bytes one token costs in this pool (the quantization
-        lever made legible): payload for k+v across layers, plus the
-        per-vector scales for int8."""
-        if self.cfg.latent:    # one stored row a layer, and its index key
-            return self.latent_bytes_per_token + self.index_bytes_per_token
-        elem = jnp.dtype(self._storage_dtype).itemsize
-        n = self.n_layers * self.cfg.kv_heads
-        b = 2 * n * self.cfg.head_dim * elem
-        if self.quantized:
-            b += 2 * n * 4  # f32 scale per (layer, head, token) vector
-        return b
+        lever made legible): every paged array's payload across layers
+        (k + v; a latent pool's one stored row and its index key), plus
+        the per-vector scales for int8."""
+        return self._bytes(_arrays(self.cache, "paged")
+                           + _arrays(self.cache, "scales"), 1, 3)
 
     @property
     def conv_state_bytes_per_slot(self) -> int:
         """What a slot holds beside its pages, whatever its context:
         the last ``taps`` inputs of every layer that keeps a short
         convolution's (0: none does)."""
-        a = self.cache.get("conv")
-        return 0 if a is None else a.nbytes // self.n_slots
+        return self._bytes(T.LAYER_KINDS["conv"].state, 1)
 
     @property
     def ssm_state_bytes_per_slot(self) -> int:
         """... and every state-space mixer's matrix state (0: the
         model has none)."""
-        a = self.cache.get("ssm")
-        return 0 if a is None else a.nbytes // self.n_slots
+        return self._bytes(set(T.LAYER_KINDS["hybrid"].state)
+                           - set(T.LAYER_KINDS["conv"].state), 1)
 
     @property
     def latent_bytes_per_token(self) -> int:
         """What a token leaves in a latent pool's rows, every layer's
         (``cfg.latent_row`` as stored; 0 for a pool of K and V)."""
-        return (self.n_layers * self.cfg.latent_row
-                * jnp.dtype(self._storage_dtype).itemsize
+        return (self._bytes(T.LAYER_KINDS["latent"].paged, 1, 3)
                 if self.cfg.latent else 0)
 
     @property
     def index_bytes_per_token(self) -> int:
         """... and in a sparse model's index-key array beside them."""
-        return (self.n_layers * self.cfg.index_head_dim
-                * jnp.dtype(self._storage_dtype).itemsize
-                if self.cfg.sparse else 0)
+        return self._bytes(set(T.LAYER_KINDS["sparse"].paged)
+                           - set(T.LAYER_KINDS["latent"].paged), 1, 3)
 
     def pages_for(self, n_tokens: int) -> int:
         return -(-n_tokens // self.page_size) if n_tokens > 0 else 0
@@ -657,14 +592,15 @@ class PagedSlotCache:
 
     def _land(self, slots, new_pos, rows, prefilled: Dict, true_lens,
               start: int) -> None:
-        bucket = prefilled["k"].shape[3]
+        # what THIS pool holds of it (``pos`` travels as ``new_pos``)
+        block = {n: a for n, a in prefilled.items()
+                 if n in self.cache and n != "pos"}
+        bucket = block[_arrays(block, "paged")[0]].shape[3]
         self.cache = self._insert(
             self.cache, np.asarray(slots, np.int32), new_pos,
             self._land_pages(rows, start, true_lens, bucket),
             np.int32(start % self.page_size),
-            np.asarray(true_lens, np.int32), prefilled["k"],
-            prefilled.get("v"), prefilled.get("ik"), prefilled.get("conv"),
-            prefilled.get("ssm"))
+            np.asarray(true_lens, np.int32), block)
 
     def land(self, slots: Sequence[int], prefilled: Dict,
              true_lens, start: int = 0) -> None:
@@ -705,6 +641,6 @@ class PagedSlotCache:
         return self._slot_state(self.cache[name], np.int32(slot))
 
     def gather_prefix(self, pages: Sequence[int]):
-        """Contiguous ``(k, v)`` for a shared prefix's pages (see
-        :func:`gather_prefix_pages`)."""
+        """A shared prefix's pages, contiguous, by the pool's names
+        (:func:`gather_prefix_pages`)."""
         return self._gather(self.cache, np.asarray(pages, np.int32))

@@ -619,61 +619,37 @@ class InferenceEngine:
             raise ValueError(
                 f"prefill_chunk_tokens must be >= 1 (or 0 to disable), "
                 f"got {engine_cfg.prefill_chunk_tokens}")
-        if cfg.has_state or cfg.kv_pack > 1:
-            # A per-slot state (a conv layer's taps, a hybrid layer's
-            # taps and matrix state), and rows that narrow KV heads
-            # share, are written for the paged single-chip tick and the
-            # prefills: every other mode refuses them by name.
-            what = ("hybrid layers (a state-space mixer's per-slot "
-                    "state beside the pages)" if cfg.has_ssm else
-                    "conv layers (a per-slot state beside the pages)"
-                    if cfg.has_conv else
-                    "KV heads sharing a stored row (kv_lane_dense)")
-            refused = [why for on, why in (
-                (engine_cfg.tp > 1, "tp > 1"),
-                (self._spec, "speculative=True (a rejected draft would "
-                 "have to roll a state back)" if cfg.has_state
-                 else "speculative=True"),
-                (resolve_kv_dtype(cfg, engine_cfg.kv_dtype)[1],
-                 "kv_dtype='int8'"),
-            ) if on]
-            if refused:
+        # Every architecture beyond the uniform block (a per-slot state,
+        # rows that narrow KV heads share; latent attention, leading
+        # dense layers, a share of the experts; window layers' second
+        # kind of pages) is written for the paged single-chip tick and
+        # the prefills.  Every other MODE refuses it here, typed and by
+        # name: none may run it as another model.
+        int8 = resolve_kv_dtype(cfg, engine_cfg.kv_dtype)[1]
+        for on, what, int8_too, spec in (
+            (cfg.has_state or cfg.kv_pack > 1,
+             "hybrid layers (a state-space mixer's per-slot state beside "
+             "the pages)" if cfg.has_ssm else
+             "conv layers (a per-slot state beside the pages)"
+             if cfg.has_conv else
+             "KV heads sharing a stored row (kv_lane_dense)", True,
+             "speculative=True (a rejected draft would have to roll a "
+             "state back)" if cfg.has_state else "speculative=True"),
+            (cfg.latent or cfg.n_dense_layers
+             or cfg.held_offset is not None,
+             "latent attention"
+             + (" and sparse selection (an indexer)" if cfg.sparse else "")
+             + ", leading dense layers or a share of the experts",
+             cfg.latent, "speculative=True"),
+            (cfg.has_window,
+             f"window layers (pattern {cfg.layer_pattern})", True,
+             "speculative=True")):
+            refused = [why for hit, why in (
+                (engine_cfg.tp > 1, "tp > 1"), (self._spec, spec),
+                (int8 and int8_too, "kv_dtype='int8'")) if hit]
+            if on and refused:
                 raise T.UnsupportedModelConfigError(
                     f"a configuration with {what} is not served with "
-                    + ", ".join(refused))
-        if cfg.latent or cfg.n_dense_layers or cfg.held_offset is not None:
-            # Latent attention (with or without an indexer's sparse
-            # selection), leading dense layers and a chip's share of
-            # the experts are written for the paged single-chip tick
-            # and the prefills: every other mode refuses them by name.
-            refused = [why for on, why in (
-                (engine_cfg.tp > 1, "tp > 1"),
-                (self._spec, "speculative=True"),
-                (cfg.latent and resolve_kv_dtype(
-                    cfg, engine_cfg.kv_dtype)[1], "kv_dtype='int8'"),
-            ) if on]
-            if refused:
-                raise T.UnsupportedModelConfigError(
-                    "a configuration with latent attention"
-                    + (" and sparse selection (an indexer)" if cfg.sparse
-                       else "") + ", leading "
-                    "dense layers or a share of the experts is not "
-                    "served with " + ", ".join(refused))
-        if cfg.has_window:
-            # Two kinds of KV state live side by side only where they
-            # are written: the paged single-chip tick.  Every other
-            # mode refuses the configuration here, typed — none may
-            # run it as another model.
-            refused = [why for on, why in (
-                (engine_cfg.tp > 1, "tp > 1"),
-                (self._spec, "speculative=True"),
-                (resolve_kv_dtype(cfg, engine_cfg.kv_dtype)[1],
-                 "kv_dtype='int8'"),
-            ) if on]
-            if refused:
-                raise T.UnsupportedModelConfigError(
-                    f"a configuration with window layers (pattern "
-                    f"{cfg.layer_pattern}) is not served with "
                     + ", ".join(refused))
         # Tensor-parallel mesh (EngineConfig.tp): the engine OWNS the
         # mesh — built once here, params and the page pool placed on
@@ -967,7 +943,7 @@ class InferenceEngine:
         self._prefill_traces = 0
         # layers with a state-space mixer (0: none): what the two
         # ssm_* counters count rows and tokens by
-        self._ssm_layers = cfg.layers_with("ssm")
+        self._ssm_layers = cfg.kind_count("hybrid")
         self._prefill_calls = 0  # prefill FORWARD PASSES (sharing hook)
 
         # Paged-cache host state: _page_pos mirrors each slot's device
@@ -1006,22 +982,20 @@ class InferenceEngine:
         self._prefix_version = 0  # bumps on (un)register: match cache
         self._cache_epoch = 0
 
-        def _suffix_prefill(params, padded, lens, prefix, p0):
+        def _suffix_prefill(params, padded, lens, prefix, p0, win_start=0):
             self._prefill_traces += 1
             obs_tracing.record_compile("serving_prefill")
-            pk, pv, *win = prefix
-            kw = dict(zip(("conv_state", "ssm_state") if cfg.has_state else
-                          ("win_k", "win_v", "win_start"), win))
             return T.prefill_with_prefix(
-                params, padded, pk, pv, p0, self.cfg, true_len=lens,
-                **kw)
+                params, padded, prefix, p0, self.cfg, true_len=lens,
+                win_start=win_start)
 
         # jax.jit caches per (n_prefix_pages, bucket, k) shape; the
         # prefix length p0 is a traced scalar, so prefixes of any
         # length share the page-granular compile set.
         self._suffix_prefill = self._jit(
             _suffix_prefill,
-            in_s=shd and (_psh, _R, _R, (_presh, _presh), _R),
+            in_s=shd and (_psh, _R, _R,
+                          dict.fromkeys(cfg.kind("full").block, _presh), _R),
             out_s=shd and (_R, _kvsh))
         self._update_page_gauges()
 
@@ -1425,20 +1399,18 @@ class InferenceEngine:
         prefill at all (the first greedy token is cached here).  Pages
         stay pinned across slot churn; a supervised restart invalidates
         the entry, which lazily re-prefills on next use."""
-        if self.wslots is not None:
-            raise T.UnsupportedModelConfigError(
-                "prefix sharing is not written for window layers' pages "
-                "(a sharer's window would release a page its peers read)")
-        if self.cfg.sparse:
-            raise T.UnsupportedModelConfigError(
-                "prefix sharing is not written for sparse attention (an "
-                "indexer's keys beside the latent rows)")
-        if self.cfg.has_state:
-            raise T.UnsupportedModelConfigError(
-                "prefix sharing is not written for "
-                + ("hybrid" if self.cfg.has_ssm else "conv")
-                + " layers (a sharer would need the state as it stood at "
-                "the prefix's end: a snapshot a page boundary)")
+        for on, why in (
+            (self.wslots is not None, "window layers' pages (a sharer's "
+             "window would release a page its peers read)"),
+            (self.cfg.sparse, "sparse attention (an indexer's keys beside "
+             "the latent rows)"),
+            (self.cfg.has_state,
+             ("hybrid" if self.cfg.has_ssm else "conv") + " layers (a "
+             "sharer would need the state as it stood at the prefix's "
+             "end: a snapshot a page boundary)")):
+            if on:
+                raise T.UnsupportedModelConfigError(
+                    "prefix sharing is not written for " + why)
         tokens = tuple(int(t) for t in tokens)
         if not tokens:
             raise ServingError("empty prefix")
@@ -2195,16 +2167,15 @@ class InferenceEngine:
             # slots it skips — marked for rebuild at re-probe.
             self._spec_stale |= self.slots.active_mask()
         pool = self.slots.cache
-        if self.wslots is not None:
-            pool = {**pool, "wk": self.wslots.cache["k"],
-                    "wv": self.wslots.cache["v"]}
+        if self.wslots is not None:   # the window layers' arrays beside
+            pool = {**pool, **{w: self.wslots.cache[n]
+                               for w, n in T.WINDOW_ARRAYS.items()}}
         nxt, mx, cache, *load = self._tick_fn(
             self.params, tokens_dev, active_dev, self._dev_table,
             pool, s_t, s_k, s_p, s_key)
         if self.wslots is not None:
-            self.wslots.cache = {**self.wslots.cache,
-                                 "k": cache.pop("wk"),
-                                 "v": cache.pop("wv")}
+            self.wslots.cache = {**self.wslots.cache, **{
+                n: cache.pop(w) for w, n in T.WINDOW_ARRAYS.items()}}
         self.slots.cache = cache
         return nxt, {"nxt": nxt, "mx": mx,
                      **({"moe": load[0]} if load else {})}
@@ -2661,8 +2632,9 @@ class InferenceEngine:
         layer's K/V in its own pool."""
         self.slots.land(slots, pre, lens, start=start)
         if self.wslots is not None:
-            self.wslots.land(slots, {"k": pre["wk"], "v": pre["wv"],
-                                     "pos": pre["pos"]}, lens, start=start)
+            self.wslots.land(slots, {"pos": pre["pos"], **{
+                n: pre[w] for w, n in T.WINDOW_ARRAYS.items()}},
+                lens, start=start)
 
     def _map_pages(self, slot: int, req: Request,
                    entry: Optional[_PrefixEntry]) -> None:
@@ -2837,12 +2809,15 @@ class InferenceEngine:
         return True
 
     def _gather_landed(self, slot: int, lo: int):
-        """The slot's already-landed K/V as a PREFIX block for its
-        next chunk: the first ``pages_for(lo)`` table pages, padded to
-        a power-of-two page count with NULL pages (their junk is
-        masked out by the traced prefix length ``lo``), so the gather
-        + suffix-prefill compile set is bounded by page-count buckets
-        — chunk boundaries stay pure data."""
+        """What the slot's layers keep for its first ``lo`` positions,
+        as ``T.prefill_with_prefix`` takes it for the next chunk: ONE
+        dict under the pool's names, and where a window layer's block
+        starts (none: ``()``).  The pages are the first
+        ``pages_for(lo)`` of the table, padded to a power-of-two page
+        count with NULL pages (their junk is masked out by the traced
+        prefix length ``lo``), so the gather + suffix-prefill compile
+        set is bounded by page-count buckets — chunk boundaries stay
+        pure data."""
         n_pg = self.slots.pages_for(lo)
 
         def gather(cache, first):
@@ -2854,18 +2829,21 @@ class InferenceEngine:
                 pages + [NULL_PAGE] * (padded - len(pages)))
 
         prefix = gather(self.slots, 0)
-        if self.cfg.has_state:
-            # ... and the per-slot state as the last chunk left it
-            prefix += (self.slots.slot_state(slot),)
-            if self.cfg.has_ssm:
-                prefix += (self.slots.slot_state(slot, "ssm"),)
-        if self.wslots is not None:
-            # the window layers' block starts at the first page the
-            # chunk's first query (position lo) still sees
-            first = self.wslots.first_live(lo)
-            prefix += gather(self.wslots, first) + (
-                jnp.int32(first * self.wslots.page_size),)
-        return prefix
+        # ... and the per-slot state as the last chunk left it (the
+        # first array by slot_state's default name: the benchmark's
+        # controls patch that method in its one-argument form)
+        first, *rest = self.slots.state_arrays or (None,)
+        if first:
+            prefix[first] = self.slots.slot_state(slot)
+        prefix.update((n, self.slots.slot_state(slot, n)) for n in rest)
+        if self.wslots is None:
+            return prefix, ()
+        # the window layers' block starts at the first page the
+        # chunk's first query (position lo) still sees
+        first = self.wslots.first_live(lo)
+        landed = gather(self.wslots, first)
+        prefix.update((w, landed[n]) for w, n in T.WINDOW_ARRAYS.items())
+        return prefix, (jnp.int32(first * self.wslots.page_size),)
 
     def _ingest_step(self, slot: int) -> bool:
         """Land ONE chunk of ``slot``'s prompt: grant/COW the chunk's
@@ -2914,7 +2892,7 @@ class InferenceEngine:
         # (its gather is dispatched now); only then do the window
         # layers give back what the NEXT chunk's window no longer
         # reaches and claim the pages this chunk's tail lands in.
-        prefix = self._gather_landed(slot, lo) if lo else None
+        prefix, win_start = self._gather_landed(slot, lo) if lo else ({}, ())
         if not self._ensure_window_pages(
                 slot, lo + n, lo + n - 1, lambda: slot in self._ingest):
             return True
@@ -2934,7 +2912,7 @@ class InferenceEngine:
         else:
             logits, suf = self._suffix_prefill(
                 self.params, jnp.asarray(padded), lens, prefix,
-                jnp.int32(lo))
+                jnp.int32(lo), *win_start)
             self._count_prefill(n, 1, bucket, chunk=True)
             self._land([slot], suf, np.asarray([n]), start=lo)
         self._tick_prefill_spent += n

@@ -14,7 +14,16 @@ the FIVE, the conv architecture among them; ``tests/test_hybrid_layers
 ``sparse`` / ``tick``, whose selected attend reads each pick's page out
 of a product (``ops.paged_attention.pages_of``) where it called
 ``jnp.take_along_axis``; its chunk and prompt, and every other
-architecture's three programs, are the digests those trees printed."""
+architecture's three programs, are the digests those trees printed.
+FIVE entries of both files are PR 45's (one mixer a kind): ``latent`` /
+``tick``, ``chunk`` and ``sparse`` / ``tick``, ``chunk``, ``prompt``
+hold the SAME equations in another order — a tick absorbs its queries
+after it has projected the row it writes, and every body lays a layer's
+rows out as the block of one kv head (a reshape) where the attention
+ends, not after the MLP or before the attention — and the TPU compiler
+makes the same program of both (PERF.md section 6, PR 45: the optimised
+HLO of all eighteen served programs at the published widths, equal but
+for names); the other entries are what the parent printed."""
 
 import hashlib
 import json
@@ -104,16 +113,16 @@ def programs(cfg):
     pages = jnp.zeros((2,), jnp.int32)
 
     def ingest(p, pl):
-        pk, pv = C.gather_prefix_pages(
-            {n: pl[n] for n in ("k", "v", "ik") if n in pl}, pages)
-        win = {}
+        prefix = C.gather_prefix_pages(
+            {n: a for n, a in pl.items() if n not in T.WINDOW_ARRAYS}, pages)
         if cfg.has_window:
-            win = dict(zip(("win_k", "win_v"), C.gather_prefix_pages(
-                {"k": pl["wk"], "v": pl["wv"]}, pages)), win_start=0)
-        if "conv" in pl:        # the one row's state, as a slot holds it
-            win["conv_state"] = pl["conv"][:, :1]
-        return T.prefill_with_prefix(p, chunk, pk, pv, jnp.int32(8), cfg,
-                                     true_len=lens, **win)
+            landed = C.gather_prefix_pages(
+                {n: pl[w] for w, n in T.WINDOW_ARRAYS.items()}, pages)
+            prefix.update((w, landed[n]) for w, n in T.WINDOW_ARRAYS.items())
+        for name in C._arrays(pl, "state"):   # the one row's, as a slot holds it
+            prefix[name] = pl[name][:, :1]
+        return T.prefill_with_prefix(p, chunk, prefix, jnp.int32(8), cfg,
+                                     true_len=lens)
 
     out["chunk"] = _digest(ingest, params, full)
     out["prompt"] = _digest(

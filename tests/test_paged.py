@@ -1092,7 +1092,7 @@ class TestPoolWrittenInPlace:
             jaxpr = jax.make_jaxpr(
                 lambda pl, pages, first, lens: serving.cache.paged_insert(
                     pl, jnp.asarray([0, 1]), blk["pos"], pages, first, lens,
-                    blk["k"], blk["v"]))(
+                    blk))(
                 pool, jnp.zeros((2, serving.cache.landing_pages(8, self.PS)),
                                 jnp.int32), jnp.int32(0),
                 jnp.asarray([5, 8], jnp.int32))

@@ -685,13 +685,12 @@ def _chunk_lowering(model):
     pool = C.init_page_pool(cfg, 2, n_pages, ps)
 
     def chunk(params, pool, suffix, pages, land, first):
-        pk, pv = C.gather_prefix_pages(pool, pages)
         logits, suf = T.prefill_with_prefix(
-            params, suffix, pk, pv, jnp.int32(6), cfg,
-            true_len=jnp.asarray([8]))
+            params, suffix, C.gather_prefix_pages(pool, pages), jnp.int32(6),
+            cfg, true_len=jnp.asarray([8]))
         return logits, C.paged_insert(
             pool, jnp.asarray([0]), suf["pos"], land, first,
-            jnp.asarray([8]), suf["k"], suf["v"])
+            jnp.asarray([8]), suf)
 
     return jax.jit(chunk).lower(
         params, pool, jnp.zeros((1, 8), jnp.int32),

@@ -176,8 +176,8 @@ def test_the_tpu_compiler_writes_the_pool_in_place(one_chip, monkeypatch,
             blk.shape[:-1] + (cfg.index_head_dim,), cfg.dtype))
         compiled = jax.jit(C.paged_insert, donate_argnums=(0,)).lower(
             full, i32(2), i32(2), i32(2, C.landing_pages(128, PS)), i32(),
-            i32(2), blk, blk if "v" in pool else None,
-            ik if "ik" in pool else None).compile()
+            i32(2), {n: ik if n == "ik" else blk
+                     for n in full if n != "pos"}).compile()
     offenders, largest = chip_smoke.pool_sized_results(compiled.as_text(),
                                                        layer)
     assert offenders == [], (offenders, largest)
@@ -665,7 +665,7 @@ def test_the_layer_scan_copies_no_projection_leaf_of_an_engines_tree(
         landed = sds((L, cfg.kv_heads, 1024, cfg.head_dim), cfg.dtype)
         compiled = jax.jit(
             lambda p, tok, lens, pk, pv, p0: T.prefill_with_prefix(
-                p, tok, pk, pv, p0, cfg, true_len=lens)).lower(
+                p, tok, {"k": pk, "v": pv}, p0, cfg, true_len=lens)).lower(
                     params, sds((1, eng["prefill_chunk_tokens"]), jnp.int32),
                     sds((1,), jnp.int32), landed, landed,
                     sds((), jnp.int32)).compile()
@@ -761,7 +761,8 @@ def test_the_served_conv_programs_compile_at_the_published_widths(
         state = sds((Lc, 1, cfg.conv_taps, cfg.d_model), cfg.dtype)
         compiled = jax.jit(
             lambda p, suf, k, v, p0, n, st: T.prefill_with_prefix(
-                p, suf, k, v, p0, cfg, true_len=n, conv_state=st)).lower(
+                p, suf, {"k": k, "v": v, "conv": st}, p0, cfg,
+                true_len=n)).lower(
                     params, ids, pk, pk, sds((), jnp.int32), lens,
                     state).compile()
     else:
@@ -903,8 +904,8 @@ def test_the_served_hybrid_programs_compile_at_the_published_widths(
                     cfg.dtype)
         compiled = jax.jit(
             lambda p, suf, k, v, p0, n, a, b: T.prefill_with_prefix(
-                p, suf, k, v, p0, cfg, true_len=n, conv_state=a,
-                ssm_state=b)).lower(
+                p, suf, {"k": k, "v": v, "conv": a, "ssm": b}, p0, cfg,
+                true_len=n)).lower(
                     params, ids, pk, pk, sds((), jnp.int32), lens, taps,
                     state).compile()
     else:
