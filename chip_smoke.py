@@ -1391,6 +1391,116 @@ def phase_serve_hybrid(smoke: SmokeConfig) -> Dict:
     return report
 
 
+def linear_sparse_cfg(smoke: SmokeConfig):
+    """The smoke's small model of LINEAR-ATTENTION layers between
+    BLOCK-SPARSE attention layers: 4 query / 2 KV heads of 128, sparse,
+    linear, linear, sparse; windows of 32 keys every
+    16 (the page), blocks of 32, the first block, a window of one and
+    the best of the rest, the switch at 32 tokens — the longest prompts
+    cross it in chunks and tick behind it; the muP scales set."""
+    from horovod_tpu.models import transformer as T
+
+    return T.TransformerConfig(
+        vocab_size=smoke.vocab_size, d_model=256, n_heads=4, n_kv_heads=2,
+        d_head=128, n_layers=4, d_ff=512, max_seq=smoke.serve_max_len,
+        dtype=jnp.dtype(smoke.dtype), attention_impl="flash", qk_norm=True,
+        layer_pattern=("block_sparse", "linear", "linear", "block_sparse"),
+        bsa_kernel=32, bsa_stride=16,
+        bsa_block=32, bsa_topk=1, bsa_window=32, bsa_init_blocks=1,
+        bsa_dense_len=32, ssm_chunk=32, embed_multiplier=2.0,
+        head_multiplier=0.5, attn_out_multiplier=0.5,
+        mlp_multipliers=(1.0, 0.5))
+
+
+def phase_serve_linear_sparse(smoke: SmokeConfig) -> Dict:
+    """The small LINEAR + BLOCK-SPARSE configuration through the engine
+    on one chip: whole and chunked prompts (the float32 state handed
+    from chunk to chunk), the paged kernel over a table a slot and KV
+    head AND the state update's kernel at a group a head compiled into
+    the tick, the pages, the compressed keys and the state written in
+    place by the compiled tick and landing, the tokens the plain
+    reference's where its margin is clear."""
+    from horovod_tpu.models import plain_reference as R
+    from horovod_tpu.ops import paged_attention as PA
+    from horovod_tpu.ops import ssm as SSM
+
+    cfg = linear_sparse_cfg(smoke)
+    engine, params, prompts, futs, seen, tick, land = _serve_small(
+        smoke, cfg, smoke.seed + 9)
+    stats = engine.stats()
+    item = jnp.dtype(cfg.dtype).itemsize
+    _require(stats["paged_kernel_engaged"] is True
+             and stats["decode_compilations"] == 1,
+             f"linear-sparse engine: kernel engaged "
+             f"{stats['paged_kernel_engaged']}, decode compilations "
+             f"{stats['decode_compilations']}")
+    pool = engine.slots.cache
+    _require(set(pool) == {"k", "v", "ck", "lin", "pos"}
+             and pool["k"].shape[0] == 2
+             and pool["ck"].shape[::2] == (2, 256)
+             and pool["lin"].shape == (2, smoke.n_slots, 4, 128, 128)
+             and pool["lin"].dtype == jnp.float32
+             and stats["kv_bytes_per_token"] == 2 * 2 * 2 * 128 * item
+             and stats["kv_compressed_bytes_per_page"] == 2 * 2 * 128 * item
+             and stats["lin_state_bytes_per_slot"] == 2 * 4 * 128 * 128 * 4,
+             f"the linear-sparse pool's layout: arrays {sorted(pool)}, "
+             f"{stats['kv_bytes_per_token']} B a token, "
+             f"{stats['kv_compressed_bytes_per_page']} B a page, "
+             f"{stats['lin_state_bytes_per_slot']} B of state a slot")
+    n_prompt = sum(map(len, prompts))
+    rows = stats["lin_updated_slots_total"] / (2 * len(prompts))
+    _require(stats["lin_scanned_tokens_total"] == 2 * n_prompt
+             and smoke.max_new_tokens - 1 <= rows <= smoke.max_new_tokens
+             # (less than it holds once a context passes three blocks)
+             and 0 < stats["bsa_attended_tokens_total"] < (
+                 stats["bsa_live_tokens_total"]
+                 + (max(map(len, prompts)) <= 3 * cfg.bsa_block)),
+             f"linear-sparse counters: scanned "
+             f"{stats['lin_scanned_tokens_total']} (prompts {n_prompt} x 2 "
+             f"layers), updated {stats['lin_updated_slots_total']}, attended "
+             f"{stats['bsa_attended_tokens_total']} of "
+             f"{stats['bsa_live_tokens_total']}")
+    text = tick.lower(*seen["tick"]).compile().as_text()
+    _require_compiled(smoke, text, 4, "linear-sparse decode tick")
+    _require(not smoke.expect_compiled or (
+        kernel_calls(text, PA.BSA_KERNEL_NAME) == 2
+        and kernel_calls(text, SSM.UPDATE_NAME) == 2),
+        "linear-sparse decode tick: hvd_bsa_attend / hvd_ssm_update not "
+        "compiled twice each")
+    layer = int(np.prod(pool["k"].shape[1:]))
+    _require_pool_in_place(smoke, text, layer, "linear-sparse decode tick")
+    _require_pool_in_place(
+        smoke, land.lower(*seen["land"]).compile().as_text(), layer,
+        "linear-sparse landing")
+    dims = dict(
+        hidden_size=cfg.d_model, head_dim=cfg.head_dim,
+        lightning_nh=cfg.n_heads, lightning_head_dim=cfg.head_dim,
+        qk_norm=True, lightning_use_rope=True, attn_use_rope=False,
+        attn_use_output_gate=True, rms_norm_eps=cfg.norm_eps,
+        rope_theta=cfg.rope_theta, num_hidden_layers=cfg.n_layers,
+        scale_emb=cfg.embed_multiplier, dim_model_base=128,
+        scale_depth=cfg.attn_out_multiplier * 2.0,   # r = 0.5 at 4 layers
+        mixer_types=["minicpm4", "lightning-attn", "lightning-attn",
+                     "minicpm4"],
+        sparse_config=dict(kernel_size=32, kernel_stride=16, block_size=32,
+                           topk=1, window_size=32, init_blocks=1,
+                           dense_len=32))
+    tokens = {i: f.result() for i, f in enumerate(futs)}
+    checked = _check_against_oracle(
+        smoke, params, prompts, tokens, cfg,
+        oracle=lambda p, t: jax.vmap(
+            lambda row: R.sala_forward(p, row, dims))(t))
+    report = {"requests": len(prompts), "oracle_positions_checked": checked,
+              "kv_bytes_per_token": stats["kv_bytes_per_token"],
+              "lin_state_bytes_per_slot": stats["lin_state_bytes_per_slot"],
+              "lin_updated_slots": stats["lin_updated_slots_total"],
+              "bsa_attended_tokens": stats["bsa_attended_tokens_total"],
+              "bsa_live_tokens": stats["bsa_live_tokens_total"]}
+    _say("linear-sparse server: " + json.dumps(report))
+    del engine, params
+    return report
+
+
 def phase_tp(smoke: SmokeConfig, host_params, tp: int, single: Dict) -> Dict:
     """``EngineConfig(tp=n)`` answers the same requests as ``tp=1``."""
     report = phase_serve(smoke, host_params, tp=tp)
@@ -1428,6 +1538,8 @@ def run(smoke: SmokeConfig, *, tp: Optional[int] = None) -> Dict:
     report["serve_conv"] = phase_serve_conv(smoke)
     gc.collect()
     report["serve_hybrid"] = phase_serve_hybrid(smoke)
+    gc.collect()
+    report["serve_linear_sparse"] = phase_serve_linear_sparse(smoke)
     gc.collect()
     if tp:
         report["serve_tp"] = phase_tp(smoke, host_params, tp,

@@ -3,8 +3,10 @@ uniform block: a patterned expert model (below), a LATENT-ATTENTION
 expert model (``latent_*``, with its own description there) and that
 model with a lightning indexer's SPARSE selection and a biased router
 (``sparse_*``), a model of gated SHORT CONVOLUTIONS between attention
-layers (``conv_*``), and a model whose every layer runs attention AND a
-STATE-SPACE mixer side by side (``hybrid_*``, at the end of the file).
+layers (``conv_*``), a model whose every layer runs attention AND a
+STATE-SPACE mixer side by side (``hybrid_*``), and a model of LINEAR-
+ATTENTION layers between BLOCK-SPARSE attention layers (``sala_*``, at
+the end of the file).
 
 The plain reference of a patterned expert model: its forward pass in
 straightforward ``jax.numpy``, float32 at
@@ -752,3 +754,209 @@ def hybrid_forward(params, tokens, dims: dict, reset=None):
             x = hybrid_layer(x, w, dims, reset)
         x = rmsnorm(x, params["ln_f"], dims["rms_norm_eps"])
         return (x @ params["head"].astype(F32)) * dims["lm_head_multiplier"]
+
+
+# --- linear attention between block-sparse attention (MiniCPM-SALA's blocks) ---
+#
+# The forward pass of the two layers ``MiniCPM-SALA`` publishes
+# (``model_type: minicpm_sala``, ``mixer_types`` a layer), in the same plain
+# style: float32 at ``default_matmul_precision("highest")``, one sequence,
+# no cache, no kernel, nothing imported from the program — the recurrence a
+# SEQUENTIAL scan over the tokens (never the chunked dual form), the
+# selection by BRUTE FORCE from its definition (every window's mean, every
+# block's score, ``lax.top_k``).  ``tests/test_linear_sparse_layers.py``
+# holds whole prefill, chunked prefill and paged decode to its LOGITS.
+#
+# With ``r = scale_depth / sqrt(published layers)``: ``e = scale_emb
+# Embed[id]``; a layer ``n = RMSNorm(x)``, ``x' = x + r Mix(n)``, ``y = x' +
+# r SwiGLU(RMSNorm(x'))``; ``logits = Head(RMSNorm(y) / (hidden_size /
+# dim_model_base))`` (untied); no bias.
+#
+# * ``lightning-attn``: ``q = RMSNorm(n W_q)``, ``k = RMSNorm(n W_k)`` a head
+#   of ``lightning_head_dim`` with one learned scale (``qk_norm``), ``v = n
+#   W_v``; rotate-half rope on q and k (``lightning_use_rope``); a head keeps
+#   ``S (Dh, Dh)``: ``S_t = lambda_h S_{t-1} + k_t^T v_t`` (``S_{-1} = 0``),
+#   ``o_t = (q_t / sqrt(Dh)) S_t``; ``(RMSNorm(o) * sigmoid(n W_g)) W_o``
+#   (``use_output_norm`` a head with one learned scale, ``use_output_gate``).
+#   ``lambda_h = exp(lin_decay[h])``: the leaf holds ``log lambda``.
+# * ``minicpm4`` (InfLLM-V2): q, k normed as above, NO rope
+#   (``attn_use_rope`` false); K/V of ``num_key_value_heads`` heads.  With
+#   ``sparse_config`` ``(kernel_size, kernel_stride, block_size, topk,
+#   window_size, init_blocks, dense_len)``: a compressed key ``c_j = mean(k[
+#   stride j : stride j + kernel])`` a KV head for every window that lies
+#   WHOLE in the query's context; a query at ``t`` with ``t + 1 >
+#   dense_len``: ``p_h = softmax_j(q_h . c_j / sqrt(Dh))``, summed over the
+#   query heads of the KV head; a block's score the largest over the windows
+#   that overlap its ``block_size`` tokens; it attends the blocks ``<
+#   init_blocks``, the ``window_size / block_size`` blocks up to its own, and
+#   the ``topk`` best-scored of the rest (ties to the lower block), causally,
+#   ``softmax(q k^T / sqrt(Dh)) v``; a shorter context attends everything.
+#   ``(o * sigmoid(n W_g)) W_o`` (``attn_use_output_gate``).
+#
+# Departures from the published description, each an ``assumed`` of the
+# configuration's file: the window as BLOCKS (the query's own and the 31
+# before it: 1985-2048 tokens; InfLLM-V2's kernels count it so), the switch
+# on the query's own context, the decay as a leaf.
+#
+# ``reset`` and ``select`` are CONTROLS, not the model: ``reset`` a boolean
+# ``(S,)``, the state ``S`` ZERO before that token (a program that lost a
+# request's state there); ``select=False`` attends everything everywhere.
+#
+# ``params``: ``embed (V, D)``, ``head (D, V)``, ``ln_f`` and ``layers``:
+# ``ln1``, ``ln2``, ``w_gate``/``w_up (D, F)``, ``w_down (F, D)`` stacked over
+# all the layers; ``lin_q``/``lin_k``/``lin_v``/``lin_g (D, H Dh)``, ``lin_o
+# (H Dh, D)``, ``lin_norm``/``lin_q_norm``/``lin_k_norm (Dh)``, ``lin_decay
+# (H)`` over the lightning layers; ``wq (D, H, Dh)``, ``wk``/``wv (D, H_kv,
+# Dh)``, ``wo (H, Dh, D)``, ``q_norm``/``k_norm (Dh)``, ``wg (D, H Dh)`` over
+# the ``minicpm4`` layers.
+
+SALA_KINDS = {"lightning-attn": "linear", "minicpm4": "block_sparse"}
+_SALA_LEAVES = {
+    "linear": ("lin_q", "lin_k", "lin_v", "lin_g", "lin_o", "lin_norm",
+               "lin_decay", "lin_q_norm", "lin_k_norm"),
+    "block_sparse": ("wq", "wk", "wv", "wo", "q_norm", "k_norm", "wg")}
+
+
+def sala_residual_scale(dims: dict) -> float:
+    """``scale_depth / sqrt(layers)`` at the PUBLISHED depth (a cut in
+    depth keeps it)."""
+    layers = dims.get("published", {}).get("num_hidden_layers",
+                                           dims["num_hidden_layers"])
+    return dims["scale_depth"] / math.sqrt(layers)
+
+
+def sala_linear(n, w, dims: dict, reset=None):
+    """The lightning layer's mixer of one sequence ``n`` ``(S, D)``,
+    token by token."""
+    S = n.shape[0]
+    H, dh = dims["lightning_nh"], dims["lightning_head_dim"]
+    eps = dims["rms_norm_eps"]
+    q = (n @ w["lin_q"].astype(F32)).reshape(S, H, dh)
+    k = (n @ w["lin_k"].astype(F32)).reshape(S, H, dh)
+    v = (n @ w["lin_v"].astype(F32)).reshape(S, H, dh)
+    if dims["qk_norm"]:
+        q, k = rmsnorm(q, w["lin_q_norm"], eps), rmsnorm(k, w["lin_k_norm"],
+                                                         eps)
+    if dims["lightning_use_rope"]:
+        cos, sin = rope_tables(jnp.arange(S), dh,
+                               {"rope_theta": dims["rope_theta"]})
+        q, k = rotate(q, cos, sin), rotate(k, cos, sin)
+    lam = jnp.exp(w["lin_decay"].astype(F32))
+    if reset is None:
+        reset = jnp.zeros((S,), bool)
+
+    def token(state, inp):
+        q_t, k_t, v_t, lost = inp
+        state = jnp.where(lost, 0.0, state)
+        state = lam[:, None, None] * state + k_t[:, :, None] * v_t[:, None, :]
+        return state, jnp.einsum("hk,hkv->hv", q_t / math.sqrt(dh), state)
+
+    _, o = jax.lax.scan(token, jnp.zeros((H, dh, dh), F32), (q, k, v, reset))
+    o = rmsnorm(o, w["lin_norm"], eps).reshape(S, H * dh)
+    return (o * jax.nn.sigmoid(n @ w["lin_g"].astype(F32))) @ w[
+        "lin_o"].astype(F32)
+
+
+def sala_selected(q, k, sc: dict):
+    """``(S, H_kv, S)`` bool: may the query at ``t`` (rows) attend key
+    ``s`` under the selection — by brute force.  ``q`` ``(S, H, Dh)``,
+    ``k`` ``(S, H_kv, Dh)`` as the scores take them."""
+    S, H, dh = q.shape
+    hkv = k.shape[1]
+    ker, stride, blk = sc["kernel_size"], sc["kernel_stride"], sc["block_size"]
+    n_w = max((S - ker) // stride + 1, 0)
+    n_b = -(-S // blk)
+    t = jnp.arange(S)
+    causal = t[None, :] <= t[:, None]
+    everything = jnp.broadcast_to(causal[:, None, :], (S, hkv, S))
+    if n_w == 0:
+        return everything
+    c = jnp.stack([jnp.mean(k[stride * j:stride * j + ker], axis=0)
+                   for j in range(n_w)])                     # (nW, Hkv, Dh)
+    j = jnp.arange(n_w)
+    whole = stride * j[None, :] + ker <= t[:, None] + 1      # (S, nW)
+    s = jnp.einsum("thd,jhd->thj", q, jnp.repeat(c, H // hkv, axis=1)
+                   ) / math.sqrt(dh)
+    p = jax.nn.softmax(jnp.where(whole[:, None, :], s, -jnp.inf), axis=-1)
+    p = jnp.where(whole[:, None, :], p, 0.0)
+    p = p.reshape(S, hkv, H // hkv, n_w).sum(axis=2)         # (S, Hkv, nW)
+    b = jnp.arange(n_b)
+    overlap = ((stride * j[None, :] <= blk * b[:, None] + blk - 1)
+               & (stride * j[None, :] + ker - 1 >= blk * b[:, None]))
+    score = jnp.max(jnp.where(
+        (overlap[None, :, :] & whole[:, None, :])[:, None], p[:, :, None, :],
+        -jnp.inf), axis=-1)                                  # (S, Hkv, nB)
+    own = t // blk
+    w_blocks, init = sc["window_size"] // blk, sc["init_blocks"]
+    forced = ((b[None, :] < init) | (b[None, :] > own[:, None] - w_blocks)
+              ) & (b[None, :] <= own[:, None])               # (S, nB)
+    rest = (b[None, :] >= init) & (b[None, :] <= own[:, None] - w_blocks)
+    topk = min(sc["topk"], n_b)
+    _, best = jax.lax.top_k(jnp.where(rest[:, None, :], score, -jnp.inf), topk)
+    rank_ok = jnp.arange(topk)[None, :] < jnp.minimum(
+        sc["topk"], rest.sum(axis=-1))[:, None]              # (S, topk)
+    picked = jnp.any((best[..., None] == b) & rank_ok[:, None, :, None],
+                     axis=2)                                 # (S, Hkv, nB)
+    blocks = picked | forced[:, None, :]
+    tokens = jnp.repeat(blocks, blk, axis=-1)[..., :S] & causal[:, None, :]
+    sparse = (t + 1 > sc["dense_len"])[:, None, None]
+    return jnp.where(sparse, tokens, everything)
+
+
+def sala_sparse_attention(n, w, dims: dict, select: bool = True):
+    """The ``minicpm4`` layer's mixer of one sequence ``n`` ``(S, D)``."""
+    S, dh, eps = n.shape[0], dims["head_dim"], dims["rms_norm_eps"]
+    q = jnp.einsum("sd,dhk->shk", n, w["wq"].astype(F32))
+    k = jnp.einsum("sd,dhk->shk", n, w["wk"].astype(F32))
+    v = jnp.einsum("sd,dhk->shk", n, w["wv"].astype(F32))
+    if dims["qk_norm"]:
+        q, k = rmsnorm(q, w["q_norm"], eps), rmsnorm(k, w["k_norm"], eps)
+    if dims["attn_use_rope"]:
+        cos, sin = rope_tables(jnp.arange(S), dh,
+                               {"rope_theta": dims["rope_theta"]})
+        q, k = rotate(q, cos, sin), rotate(k, cos, sin)
+    H, hkv = q.shape[1], k.shape[1]
+    if select:
+        vis = sala_selected(q, k, dims["sparse_config"])     # (S, Hkv, S)
+    else:
+        t = jnp.arange(S)
+        vis = jnp.broadcast_to((t[None, :] <= t[:, None])[:, None, :],
+                               (S, hkv, S))
+    s = jnp.einsum("qhd,khd->qhk", q, jnp.repeat(k, H // hkv, axis=1)
+                   ) / math.sqrt(dh)
+    p = jax.nn.softmax(jnp.where(jnp.repeat(vis, H // hkv, axis=1), s,
+                                 -jnp.inf), axis=-1)
+    o = jnp.einsum("qhk,khd->qhd", p, jnp.repeat(v, H // hkv, axis=1))
+    if dims["attn_use_output_gate"]:
+        o = o * jax.nn.sigmoid(n @ w["wg"].astype(F32)).reshape(o.shape)
+    return jnp.einsum("shk,hkd->sd", o, w["wo"].astype(F32))
+
+
+def sala_layer(x, w, dims: dict, kind: str, reset=None, select: bool = True):
+    eps, r = dims["rms_norm_eps"], sala_residual_scale(dims)
+    n = rmsnorm(x, w["ln1"], eps)
+    mix = (sala_linear(n, w, dims, reset) if kind == "linear"
+           else sala_sparse_attention(n, w, dims, select))
+    h = x + r * mix
+    v = rmsnorm(h, w["ln2"], eps)
+    gate = jax.nn.silu(v @ w["w_gate"].astype(F32))
+    return h + r * ((gate * (v @ w["w_up"].astype(F32)))
+                    @ w["w_down"].astype(F32))
+
+
+def sala_forward(params, tokens, dims: dict, reset=None, select: bool = True):
+    """Logits ``(S, V)`` float32 of one sequence ``tokens`` ``(S,)``."""
+    with jax.default_matmul_precision("highest"):
+        x = params["embed"].astype(F32)[tokens] * dims["scale_emb"]
+        seen = {kind: 0 for kind in _SALA_LEAVES}
+        mixers = sum(_SALA_LEAVES.values(), ())
+        for l, name in enumerate(dims["mixer_types"]):
+            kind = SALA_KINDS[name]
+            w = {leaf: a[seen[kind] if leaf in mixers else l]
+                 for leaf, a in params["layers"].items()
+                 if leaf in _SALA_LEAVES[kind] or leaf not in mixers}
+            seen[kind] += 1
+            x = sala_layer(x, w, dims, kind, reset, select)
+        x = rmsnorm(x, params["ln_f"], dims["rms_norm_eps"])
+        x = x / (dims["hidden_size"] / dims["dim_model_base"])
+        return x @ params["head"].astype(F32)

@@ -51,6 +51,14 @@ Design notes (TPU):
   ``hvd_ssm_update``; a prompt's chunked scan, ``hvd_ssm_scan``), its
   projections, convolution and gate under ``hvd_ssm_in`` /
   ``hvd_ssm_conv`` / ``hvd_ssm_out``.
+* Serving only: ``"linear"`` and ``"block_sparse"`` layers in one
+  pattern: LINEAR ATTENTION with a fixed decay a head (a float32 matrix
+  state a head and slot, no pages; the recurrence is
+  :mod:`horovod_tpu.ops.ssm`'s at a group a head) and softmax attention
+  that SELECTS BLOCKS by scores over compressed keys (``bsa_*``: pages
+  of K and V and a third pool array of one row a page; a tick attends
+  the chosen blocks through the fused paged kernel over a table a slot
+  and KV head), mixer leaves stacked by kind, under muP scales.
 """
 
 from __future__ import annotations
@@ -71,7 +79,8 @@ from jax.sharding import PartitionSpec as P
 #: The kinds of layer a ``layer_pattern`` may name.  What each one keeps
 #: for a request, by the page pool's names, and its mixer are declared
 #: ONCE, in :data:`LAYER_KINDS` (below the mixers it names).
-PATTERN_KINDS = ("full", "sliding", "conv", "hybrid")
+PATTERN_KINDS = ("full", "sliding", "conv", "hybrid", "linear",
+                 "block_sparse")
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -85,25 +94,36 @@ class LayerKind:
     ``paged``: the pool arrays a page table indexes, ``{name: cfg ->
     (heads, width)}`` of ``(L, P, heads, page, width)``; ``scales``: an
     int8 pool's per-vector scales beside them, in their order;
+    ``page_rows``: pool arrays of one row A PAGE under the same page
+    table, ``{name: cfg -> width}`` of ``(L, P, width)`` (a summary of
+    the page's tokens that a landing and the tick write with them);
     ``state``: what a SLOT holds whatever its context, ``{name: cfg ->
-    shape}`` of ``(L, S) + shape``; ``window``: the pages lie under a
-    table of their own and are released behind the window."""
+    shape}`` of ``(L, S) + shape``, in the pool's dtype unless named in
+    ``f32`` (a state that every token multiplies on: kept in float32);
+    ``window``: the pages lie under a table of their own and are
+    released behind the window."""
     mixer: Any
     paged: Any = dataclasses.field(default_factory=dict)
     scales: tuple = ()
     state: Any = dataclasses.field(default_factory=dict)
     window: bool = False
+    page_rows: Any = dataclasses.field(default_factory=dict)
+    f32: tuple = ()
+
+    @property
+    def landed(self) -> tuple:
+        """The arrays a chunk's landed prefix carries for a layer."""
+        return (*self.paged, *self.state)
 
     @property
     def block(self) -> tuple:
-        """The arrays a prefill hands back for a layer, and a chunk's
-        landed prefix carries."""
-        return (*self.paged, *self.state)
+        """The arrays a prefill hands back for a layer."""
+        return (*self.paged, *self.page_rows, *self.state)
 
     @property
     def arrays(self) -> tuple:
         """... and those of a (quantized) pool, in a tick's order."""
-        return (*self.paged, *self.scales, *self.state)
+        return (*self.paged, *self.scales, *self.page_rows, *self.state)
 
 
 class UnsupportedModelConfigError(ValueError):
@@ -302,6 +322,36 @@ class TransformerConfig:
     ssm_out_multiplier: float = 1.0
     ssm_multipliers: tuple = ()
     mlp_multipliers: tuple = ()
+    # A "linear" layer of the pattern: LINEAR ATTENTION (lightning
+    # attention) in the softmax's place — ``q``, ``k`` normed a head
+    # (``qk_norm``) and roped, a head keeps ONE matrix
+    # ``S`` ``(head_dim, head_dim)`` a request in FLOAT32, ``S_t =
+    # lambda_h S_{t-1} + k_t^T v_t`` with a fixed decay a head and layer
+    # (the leaf ``lin_decay`` holds ``log lambda``), ``o_t = (q_t /
+    # sqrt(head_dim)) S_t``; an RMSNorm over each head of ``o`` and a
+    # sigmoid gate of the layer's input before ``W_o``: :func:`_lin_in`
+    # .. :func:`_lin_out`, the recurrence :mod:`horovod_tpu.ops.ssm`'s
+    # (``ssm_chunk`` its dual form's block).  ``n_heads`` heads of
+    # ``head_dim``, a key and value head each; no keys or values kept.
+    # A "block_sparse" layer of the pattern: softmax attention that
+    # SELECTS BLOCKS of keys by scores over COMPRESSED keys (InfLLM-V2).
+    # A compressed key a KV head is the mean of ``bsa_kernel`` keys, one
+    # every ``bsa_stride`` (= a page; a window spans two); a query whose
+    # context is longer than ``bsa_dense_len`` scores them — softmax over
+    # the whole windows it sees, summed over the query heads of its KV
+    # head, a block of ``bsa_block`` tokens the largest over the windows
+    # that overlap it — and attends its first ``bsa_init_blocks`` blocks,
+    # the ``bsa_window / bsa_block`` up to its own and the ``bsa_topk``
+    # best-scored of the rest; a shorter context attends everything.
+    # Such a layer ropes neither q nor k, and its output is gated by
+    # ``sigmoid(n W_g)`` before ``W_o``.  Set = the first five > 0.
+    bsa_kernel: int = 0
+    bsa_stride: int = 0
+    bsa_block: int = 0
+    bsa_topk: int = 0
+    bsa_window: int = 0
+    bsa_init_blocks: int = 1
+    bsa_dense_len: int = 0
 
     def __post_init__(self):
         mla = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
@@ -377,6 +427,27 @@ class TransformerConfig:
                 "and none or five ssm_multipliers")
         if len(self.mlp_multipliers) not in (0, 2):
             raise ValueError("mlp_multipliers is (gate, down) or ()")
+        if self.has_bsa and not (
+                self.bsa_stride > 0 and self.bsa_kernel == 2 * self.bsa_stride
+                and self.bsa_block > 0
+                and self.bsa_block % self.bsa_stride == 0
+                and self.bsa_topk > 0 and self.bsa_window > 0
+                and self.bsa_window % self.bsa_block == 0
+                and self.bsa_init_blocks >= 0 and self.bsa_dense_len >= 0):
+            raise ValueError(
+                "a 'block_sparse' layer needs bsa_stride, bsa_kernel = 2 "
+                "bsa_stride (a window ends one page after it starts), "
+                "bsa_block a multiple of bsa_stride, bsa_topk and "
+                "bsa_window a multiple of bsa_block")
+        if (self.has_linear or self.has_bsa) and (
+                set(self.layer_pattern) - {"linear", "block_sparse"}
+                or self.latent or self.kv_lane_dense or self.n_dense_layers
+                or self.n_experts > 1 or self.tie_embeddings):
+            raise UnsupportedModelConfigError(
+                "'linear' and 'block_sparse' layers together with full, "
+                "window, conv or hybrid layers, latent attention, KV "
+                "heads sharing a stored row, leading dense layers, "
+                "experts or a tied head are not written")
         if self.kv_lane_dense and (
                 self.latent or self.head_dim >= 128 or 128 % self.head_dim
                 or self.kv_heads % (128 // self.head_dim)):
@@ -485,10 +556,31 @@ class TransformerConfig:
         return "hybrid" in self.layer_pattern
 
     @property
+    def has_linear(self) -> bool:
+        """Is any layer linear attention (a float32 matrix state a head
+        and slot, ``pool["lin"]``, and no pages)?"""
+        return "linear" in self.layer_pattern
+
+    @property
+    def has_bsa(self) -> bool:
+        """Does any layer select blocks by compressed keys (a row a
+        page, ``pool["ck"]``, beside its pages)?"""
+        return "block_sparse" in self.layer_pattern
+
+    @property
+    def bsa_blocks_max(self) -> int:
+        """The most blocks a query of a block-sparse layer attends: the
+        forced and the picked, or every block of a context that is not
+        longer than ``bsa_dense_len``."""
+        return max(self.bsa_init_blocks + self.bsa_topk
+                   + self.bsa_window // self.bsa_block,
+                   -(-self.bsa_dense_len // self.bsa_block))
+
+    @property
     def has_state(self) -> bool:
         """Does a request keep a state of fixed size beside its pages
-        (``pool["conv"]``, ``pool["ssm"]``)?"""
-        return self.has_conv or self.has_ssm
+        (``pool["conv"]``, ``pool["ssm"]``, ``pool["lin"]``)?"""
+        return self.has_conv or self.has_ssm or self.has_linear
 
     @property
     def ssm_inner(self) -> int:
@@ -559,11 +651,15 @@ def init_params(rng, cfg: TransformerConfig) -> Dict:
     ``head`` (unless tied), ``ln_f`` and ``layers`` stacked on a
     leading axis.  With ``cfg.n_dense_layers`` the leading dense layers
     are a stack of their own, ``dense_layers``, and ``layers`` holds
-    the rest.  In a stack with conv layers the mixer's leaves are
-    stacked BY KIND (:data:`_MIXER_LEAVES`): ``wq``/``wk``/``wv``/
-    ``wo`` (and the q/k norms) over its attention layers, ``conv_in
-    (D, 3D)``/``conv_k (D, K)``/``conv_out (D, D)`` over its conv
-    layers; every other leaf over all of them."""
+    the rest.  In a stack with conv or linear layers the mixer's leaves
+    are stacked BY KIND (:data:`_MIXER_LEAVES`): ``wq``/``wk``/``wv``/
+    ``wo`` (the q/k norms, a block-sparse layer's gate ``wg (D, H
+    Dh)``) over its attention layers, ``conv_in (D, 3D)``/``conv_k (D,
+    K)``/``conv_out (D, D)`` over its conv layers, ``lin_q``/``lin_k``/
+    ``lin_v``/``lin_g (D, H Dh)``, ``lin_o (H Dh, D)``, ``lin_norm``/
+    ``lin_q_norm``/``lin_k_norm (Dh)`` and ``lin_decay (H)`` = ``log
+    lambda`` over its linear layers; every other leaf over all of
+    them."""
     keys = jax.random.split(rng, 10)
     D, V = cfg.d_model, cfg.vocab_size
 
@@ -576,7 +672,8 @@ def init_params(rng, cfg: TransformerConfig) -> Dict:
         # over the attention layers (``La`` of them), ``conv_*`` over
         # the conv layers — and every other leaf over all ``L``.
         L = len(kinds)
-        La = L - kinds.count("conv")
+        Lc, Ll = kinds.count("conv"), kinds.count("linear")
+        La = L - Lc - Ll
         H, Dh = cfg.n_heads, cfg.head_dim
         F = cfg.expert_width if experts else cfg.d_ff
         s_d, s_f = 1.0 / np.sqrt(D), 1.0 / np.sqrt(F)
@@ -602,13 +699,30 @@ def init_params(rng, cfg: TransformerConfig) -> Dict:
                 ssm_D=jnp.ones((L, Hs), jnp.float32),
                 ssm_norm=jnp.ones((L, I), jnp.float32),
                 ssm_out=norm_init(sk[2], (L, I, D), 1.0 / np.sqrt(I)))
-        if La < L:
+        if Lc:
             ck = jax.random.split(jax.random.fold_in(keys[0], 11), 3)
             layers.update(
-                conv_in=norm_init(ck[0], (L - La, D, 3 * D), s_d),
-                conv_k=norm_init(ck[1], (L - La, D, cfg.conv_kernel),
+                conv_in=norm_init(ck[0], (Lc, D, 3 * D), s_d),
+                conv_k=norm_init(ck[1], (Lc, D, cfg.conv_kernel),
                                  1.0 / np.sqrt(cfg.conv_kernel)),
-                conv_out=norm_init(ck[2], (L - La, D, D), s_d))
+                conv_out=norm_init(ck[2], (Lc, D, D), s_d))
+        if Ll:
+            # a linear layer's leaves, as the products read them; log
+            # lambda drawn a layer and head (a model's weights bring
+            # their own: no schedule is the program's)
+            lk = jax.random.split(jax.random.fold_in(keys[0], 17), 6)
+            layers.update(
+                lin_q=norm_init(lk[0], (Ll, D, H * Dh), s_d),
+                lin_k=norm_init(lk[1], (Ll, D, H * Dh), s_d),
+                lin_v=norm_init(lk[2], (Ll, D, H * Dh), s_d),
+                lin_g=norm_init(lk[3], (Ll, D, H * Dh), s_d),
+                lin_o=norm_init(lk[4], (Ll, H * Dh, D), 1.0 / np.sqrt(H * Dh)),
+                lin_norm=jnp.ones((Ll, Dh), jnp.float32),
+                lin_decay=-jax.random.uniform(lk[5], (Ll, H), jnp.float32,
+                                              1e-3, 0.5))
+            if cfg.qk_norm:
+                layers.update(lin_q_norm=jnp.ones((Ll, Dh), jnp.float32),
+                              lin_k_norm=jnp.ones((Ll, Dh), jnp.float32))
         if cfg.latent:      # (never beside conv layers: __post_init__)
             ak = jax.random.split(keys[0], 4)
             R, C = cfg.q_lora_rank, cfg.kv_lora_rank
@@ -640,6 +754,9 @@ def init_params(rng, cfg: TransformerConfig) -> Dict:
         if cfg.qk_norm and La:
             layers.update(q_norm=jnp.ones((La, Dh), jnp.float32),
                           k_norm=jnp.ones((La, Dh), jnp.float32))
+        if cfg.has_bsa:
+            layers["wg"] = norm_init(jax.random.fold_in(keys[3], 19),
+                                     (La, D, H * Dh), s_d)
         if experts:
             E = cfg.experts_held
             layers.update(
@@ -797,7 +914,14 @@ def batch_specs() -> Dict:
 #: and W_o); and sparse attention's ``hvd_dsa_proj`` (the indexer's three
 #: projections), ``hvd_dsa_score`` (the index walk — the kernel's name
 #: too — and a chunk's scores), ``hvd_dsa_select`` and ``hvd_dsa_attend``
-#: (the selected rows' gather and the kernel of that name over them).
+#: (the selected rows' gather and the kernel of that name over them);
+#: linear attention's ``hvd_lin_in`` (projections, q/k norms, rope) and
+#: ``hvd_lin_out`` (the output norm, the gate, W_o) around the
+#: recurrence's ``hvd_ssm_scan`` / ``hvd_ssm_update``; and block-sparse
+#: attention's ``hvd_bsa_score`` (the compressed rows' scores pooled into
+#: blocks), ``hvd_bsa_select`` and ``hvd_bsa_attend`` (the chosen
+#: blocks' pages compacted into a table a slot and KV head, and the
+#: paged kernel over it).
 DEVICE_SCOPES = (
     "embed",          # token-embedding lookup
     "layer_scan",     # the scan over layers' own slicing and stacking
@@ -928,12 +1052,16 @@ def _require_no_latent(cfg: TransformerConfig, what: str) -> None:
 
 _EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
 
-#: A layer's MIXER leaves by its kind.  In a stack that has conv layers
-#: (and there alone) these are stacked over THAT kind's layers; every
-#: other leaf is stacked over all the layers.
+#: A layer's MIXER leaves by its kind.  In a stack that has conv or
+#: linear layers (and there alone) these are stacked over THAT kind's
+#: layers; every other leaf is stacked over all the layers.
 _ATTN_LEAVES = ("wq", "wk", "wv", "wo", "q_norm", "k_norm")
 _MIXER_LEAVES = {"full": _ATTN_LEAVES, "sliding": _ATTN_LEAVES,
-                 "conv": ("conv_in", "conv_k", "conv_out")}
+                 "conv": ("conv_in", "conv_k", "conv_out"),
+                 "block_sparse": _ATTN_LEAVES + ("wg",),
+                 "linear": ("lin_q", "lin_k", "lin_v", "lin_g", "lin_o",
+                            "lin_norm", "lin_decay", "lin_q_norm",
+                            "lin_k_norm")}
 #: (A hybrid layer's two mixers' leaves — the attention's beside
 #: ``ssm_in``, ``ssm_conv_k``/``ssm_conv_b``, ``ssm_dt_bias``,
 #: ``ssm_A_log``, ``ssm_D``, ``ssm_norm``, ``ssm_out`` — are stacked
@@ -968,7 +1096,7 @@ def _scan_layer_kinds(cfg: TransformerConfig, layer, init, layers, xs=None,
     stacked over both, in order.  The pattern runs from layer 0
     through both stacks (:attr:`TransformerConfig.layer_kinds`).
 
-    In a stack with conv layers a layer's MIXER leaves
+    In a stack with conv or linear layers a layer's MIXER leaves
     (:data:`_MIXER_LEAVES`) are stacked over its kind's layers alone,
     and cut out at the layer's index among its kind."""
     xs = xs or {}
@@ -1036,7 +1164,7 @@ def _scan_layer_kinds(cfg: TransformerConfig, layer, init, layers, xs=None,
     count = {k: period.count(k) for k in dict.fromkeys(period)}
     # with conv layers, each kind's mixer leaves are a stack of its own
     own = {k: {} for k in count}
-    if cfg.has_conv:
+    if cfg.has_conv or cfg.has_linear:
         own = {k: {m: layers[m] for m in _MIXER_LEAVES[k] if m in layers}
                for k in count}
         layers = {m: v for m, v in layers.items()
@@ -1088,7 +1216,8 @@ def _qkv_proj(x, p, cfg: TransformerConfig, pos_offset=0, positions=None,
     ``(B, H, S, Dh)`` / ``(B, H_kv, S, Dh)`` (shared by the training
     attention, prefill, and decode paths so the math cannot drift).
     ``kind`` is the layer's: a full layer takes ``cfg.rope_yarn``, a
-    sliding one the plain rope (at ``rope_theta_sliding`` if set)."""
+    sliding one the plain rope (at ``rope_theta_sliding`` if set), a
+    block-sparse one none."""
     def heads(w):
         # a leaf as a checkpoint stores it, (D, H, Dh), or as an engine
         # holds it, (D, H * Dh) (lay_out_projections): the same
@@ -1109,7 +1238,9 @@ def _qkv_proj(x, p, cfg: TransformerConfig, pos_offset=0, positions=None,
         if cfg.qk_norm:
             q = _rmsnorm(q, p["q_norm"], cfg.norm_eps)
             k = _rmsnorm(k, p["k_norm"], cfg.norm_eps)
-        if kind == "sliding":
+        if kind == "block_sparse":      # no rope
+            pass
+        elif kind == "sliding":
             q, k = _rope(q, k, cfg.rope_theta_sliding or cfg.rope_theta,
                          pos_offset, positions=positions)
         else:
@@ -1374,6 +1505,268 @@ def _attn_in(n, cfg: TransformerConfig):
     if cfg.attn_in_multiplier == 1.0:
         return n
     return n * jnp.asarray(cfg.attn_in_multiplier, n.dtype)
+
+
+# --- linear attention (a "linear" layer's mixer) ------------------------------
+#
+# Lightning attention: ``q = RMSNorm(n W_q)``, ``k = RMSNorm(n W_k)`` a
+# head (``qk_norm``), ``v = n W_v``, rope on q and k; a
+# head keeps ``S`` ``(Dh, Dh)`` a request, ``S_t = lambda_h S_{t-1} +
+# k_t^T v_t``, ``o_t = (q_t / sqrt(Dh)) S_t``; ``(RMSNorm(o) *
+# sigmoid(n W_g)) W_o``.  The recurrence IS :mod:`horovod_tpu.ops.ssm`'s
+# with ``x = v``, ``B = k``, ``C = q / sqrt(Dh)``, ``dt = 1`` (0 on
+# padding), ``A = log lambda`` (the leaf ``lin_decay``) and a group a
+# head: the state ``(H, P = Dh of v, N = Dh of k)`` in FLOAT32 — with
+# ``lambda`` up to 0.9995 a state rounded to bfloat16 at each of
+# thousands of ticks drifts.  In float32 from the projections'
+# accumulators to the operand of ``W_o``.  Scopes: ``hvd_lin_in``, the
+# recurrence under its bodies' names (``hvd_ssm_scan``,
+# ``hvd_ssm_update``), ``hvd_lin_out``.
+
+
+def _lin_in(n, p, cfg: TransformerConfig, positions):
+    """``(q / sqrt(Dh), k, v (B, S, H, Dh), g (B, S, H * Dh))`` float32
+    of the layer's NORMED input ``n``."""
+    with jax.named_scope("hvd_lin_in"):
+        def proj(leaf):
+            return jnp.einsum("bsd,dn->bsn", n, p[leaf].astype(cfg.dtype),
+                              preferred_element_type=jnp.float32)
+
+        heads = n.shape[:2] + (cfg.n_heads, cfg.head_dim)
+        q, k, v = (proj(w).reshape(heads) for w in ("lin_q", "lin_k",
+                                                    "lin_v"))
+        if cfg.qk_norm:
+            q = _rmsnorm(q, p["lin_q_norm"], cfg.norm_eps)
+            k = _rmsnorm(k, p["lin_k_norm"], cfg.norm_eps)
+        q, k = _rope(q, k, cfg.rope_theta, positions=positions)
+        return q * cfg.head_dim ** -0.5, k, v, proj("lin_g")
+
+
+def _lin_out(y, g, p, cfg: TransformerConfig):
+    """The norm over each head of ``y`` ``(B, S, H, Dh)``, the gate and
+    ``W_o``, under the layer's output multiplier."""
+    with jax.named_scope("hvd_lin_out"):
+        y = y * lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True)
+                          + cfg.norm_eps) * p["lin_norm"].astype(jnp.float32)
+        o = y.reshape(g.shape) * jax.nn.sigmoid(g)
+        h = jnp.einsum("bsn,nd->bsd", o.astype(cfg.dtype),
+                       p["lin_o"].astype(cfg.dtype))
+        return _out_scaled(h, cfg)
+
+
+def _out_scaled(h, cfg: TransformerConfig):
+    if cfg.attn_out_multiplier == 1.0:
+        return h
+    return h * jnp.asarray(cfg.attn_out_multiplier, h.dtype)
+
+
+def _lin_prefill(q, k, v, p, cfg: TransformerConfig, state, true_len):
+    """A linear layer's recurrence over a prompt or a chunk from
+    ``state`` ``(B, H, Dh, Dh)`` (None: zeros) to the state at
+    ``true_len`` ``(B,)``: ``(y (B, S, H, Dh), new state)`` float32."""
+    from horovod_tpu.ops import ssm
+
+    B, S, H, Dh = q.shape
+    with jax.named_scope("hvd_ssm_scan"):
+        real = jnp.arange(S, dtype=jnp.int32)[None, :] < true_len[:, None]
+        if state is None:
+            state = jnp.zeros((B, H, Dh, Dh), jnp.float32)
+        return ssm.ssm_scan(
+            v, jnp.broadcast_to(real[..., None].astype(jnp.float32),
+                                (B, S, H)),
+            p["lin_decay"].astype(jnp.float32), k, q, state,
+            chunk=cfg.ssm_chunk, dtype=cfg.dtype)
+
+
+def _lin_decode(q, k, v, p, states, layer, active, kernel: bool):
+    """... for one token a slot, ``q``/``k``/``v`` ``(S, H, Dh)``: ``(y
+    (S, H, Dh), states)`` with ``states`` ``(L, S, H, Dh, Dh)`` float32
+    read and written IN PLACE at ``layer``."""
+    from horovod_tpu.ops import ssm
+
+    with jax.named_scope("hvd_ssm_update"):
+        return ssm.ssm_update(
+            states, layer, v, jnp.ones(q.shape[:2], jnp.float32),
+            p["lin_decay"].astype(jnp.float32), k, q, active, kernel=kernel)
+
+
+# --- block-sparse attention (a "block_sparse" layer's mixer) ------------------
+#
+# InfLLM-V2's selection over a paged K/V cache.  Beside its keys a KV
+# head keeps a COMPRESSED key for every whole window of ``bsa_kernel``
+# tokens, one every ``bsa_stride`` — the mean of the window's keys; the
+# page is the stride, so window ``j`` (tokens ``16 j .. 16 j + 31`` at
+# the published sizes) ends with page ``j + 1`` and its row lies THERE:
+# ``ck[page r]`` is the mean over pages ``r - 1`` and ``r``, written
+# when page ``r`` fills, and page 0's row is never read.  A query at
+# position ``t`` whose context ``t + 1`` is longer than ``bsa_dense_len``
+# scores the rows it sees whole (``(r + 1) stride <= t + 1``): ``softmax_r
+# (q_h . c_r / sqrt(Dh))`` a head, summed over the query heads of the KV
+# head; a block of ``bsa_block`` tokens takes the largest over the rows
+# ``m b .. m b + m`` (``m`` pages a block: the windows that overlap it);
+# the query attends blocks ``< bsa_init_blocks``, the ``bsa_window /
+# bsa_block`` blocks up to its own, and the ``bsa_topk`` best of the rest
+# (:func:`~horovod_tpu.ops.paged_attention.select_topk`: ties to the
+# lower block), causally, at ``1 / sqrt(Dh)``.  A shorter context attends
+# everything.  Scopes: ``hvd_bsa_score`` (the compressed rows' scores and
+# their pooling into blocks), ``hvd_bsa_select``, ``hvd_bsa_attend`` (a
+# tick: the chosen blocks' pages compacted into a table a slot and KV
+# head, and the fused paged kernel over it).
+
+#: Queries whose ``(heads, queries, keys)`` scores are in flight together
+#: in a chunk or a whole prompt: 32 x 64 x 33 280 float32 are 273 MB.
+_BSA_QUERY_BLOCK = 64
+
+
+def _bsa_window_mean(prev, this, cfg: TransformerConfig):
+    """The compressed key of the window that ends with a page: the mean
+    over the page before (``prev``) and the page (``this``), ``(...,
+    page, Dh)`` each -> ``(..., Dh)`` float32."""
+    total = (jnp.sum(prev.astype(jnp.float32), axis=-2)
+             + jnp.sum(this.astype(jnp.float32), axis=-2))
+    return total / cfg.bsa_kernel
+
+
+def _bsa_compress(k_log, cfg: TransformerConfig):
+    """Every page's row of keys that lie in logical order: ``k_log``
+    ``(K, Hkv, T, Dh)`` -> ``(K, Hkv, ceil(T / stride), Dh)`` in the
+    keys' dtype (what the pool stores); row 0 is page 0's alone and is
+    never read."""
+    K, Hkv, T, Dh = k_log.shape
+    ps = cfg.bsa_stride
+    pad = -T % ps
+    pages = jnp.pad(k_log, ((0, 0), (0, 0), (0, pad), (0, 0))).reshape(
+        K, Hkv, (T + pad) // ps, ps, Dh)
+    prev = jnp.pad(pages, ((0, 0), (0, 0), (1, 0), (0, 0), (0, 0)))[:, :, :-1]
+    return _bsa_window_mean(prev, pages, cfg).astype(k_log.dtype)
+
+
+def _bsa_block_scores(qg, rows, pos, n_blocks: int, cfg: TransformerConfig):
+    """Each block's score for each query: ``qg`` ``(B, Hkv, G, Q, Dh)``
+    queries at positions ``pos`` ``(B | 1, Q)``, ``rows`` ``(B, Hkv, nP,
+    Dh)`` the compressed rows by page -> ``(B, Hkv, Q, n_blocks)``
+    float32, -1 where a block overlaps no window the query sees whole."""
+    nP, m = rows.shape[2], cfg.bsa_block // cfg.bsa_stride
+    s = jnp.einsum("bkgqd,bkrd->bkgqr", qg.astype(rows.dtype), rows,
+                   preferred_element_type=jnp.float32) * cfg.head_dim ** -0.5
+    r = jnp.arange(nP, dtype=jnp.int32)
+    seen = (r >= 1) & ((r + 1) * cfg.bsa_stride <= pos[..., None] + 1)
+    seen = seen[:, None, None]                       # (B | 1, 1, 1, Q, nP)
+    prob = jnp.where(seen, jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1),
+                     0.0)
+    score = jnp.where(seen[:, :, 0], jnp.sum(prob, axis=2), -1.0)
+    # block b: the rows m b .. m b + m (the windows that overlap it)
+    score = jnp.pad(score, ((0, 0),) * 3 + ((0, (n_blocks + 1) * m - nP),),
+                    constant_values=-1.0)[..., :(n_blocks + 1) * m]
+    score = score.reshape(score.shape[:-1] + (n_blocks + 1, m))
+    return jnp.maximum(jnp.max(score[..., :-1, :], axis=-1),
+                       score[..., 1:, 0])
+
+
+def _bsa_chosen(score, pos, cfg: TransformerConfig):
+    """The blocks each row attends, IN ORDER, as a tick's table takes
+    them, from its block scores ``score`` ``(R, nB)`` and its position
+    ``pos`` ``(R,)``: ``(chosen (R, bsa_blocks_max) int32 — the first
+    ``n`` real, ascending, 0 behind —, n (R,))``: the first blocks, the
+    picked, the window's — or, of a context no longer than
+    ``bsa_dense_len``, every one up to the row's own (the LAST of the
+    list either way).  The forced blocks are taken out before the
+    selection: it runs over ``bsa_init_blocks <= b < own - W + 1``
+    alone."""
+    from horovod_tpu.ops import paged_attention as _pa
+
+    n_max = cfg.bsa_blocks_max
+    init, W = cfg.bsa_init_blocks, cfg.bsa_window // cfg.bsa_block
+    own = pos // cfg.bsa_block
+    dense = pos + 1 <= cfg.bsa_dense_len
+    rest = score[:, init:]
+    if rest.shape[1] == 0:
+        rest = jnp.full((score.shape[0], 1), -1.0, score.dtype)
+    picks, count = _pa.select_topk(
+        rest, jnp.clip(own - W + 1 - init, 0, None),
+        min(cfg.bsa_topk, rest.shape[1]))
+    j = jnp.arange(n_max, dtype=jnp.int32)[None, :]
+    n_init = jnp.minimum(init, own + 1)[:, None]
+    lo = jnp.maximum(own - W + 1, init)[:, None]
+    n_pick = n_init + count[:, None]
+    # (no pick where a row's own block is among the first: count is 0)
+    picks = jnp.pad(picks + init, ((0, 0), (init, max(
+        n_max - init - picks.shape[1], 0))))[:, :n_max]
+    chosen = jnp.where(j < n_init, j, jnp.where(
+        j < n_pick, picks, lo + j - n_pick))
+    n = n_pick[:, 0] + jnp.maximum(own + 1 - lo[:, 0], 0)
+    chosen = jnp.where(dense[:, None], j, chosen)
+    n = jnp.where(dense, own + 1, n)
+    return jnp.where(j < n[:, None], chosen, 0), n
+
+
+def _bsa_block_mask(score, pos, cfg: TransformerConfig):
+    """``(R, nB)`` bool: :func:`_bsa_chosen`'s blocks as a mask, for the
+    attention that is dense under it (a prompt's, a chunk's)."""
+    chosen, n = _bsa_chosen(score, pos, cfg)
+    real = jnp.arange(chosen.shape[1], dtype=jnp.int32)[None, :] < n[:, None]
+    b = jnp.arange(score.shape[1], dtype=jnp.int32)
+    return jnp.any((chosen[:, :, None] == b) & real[:, :, None], axis=1)
+
+
+def _bsa_rows_attend(qh, k_log, v_log, pos, cfg: TransformerConfig):
+    """Queries ``qh`` ``(K, H, S0, Dh)`` at logical positions ``pos``
+    ``(S0,)`` against keys and values that lie in logical order,
+    ``(K, Hkv, T, Dh)`` (a whole prompt's own; a chunk's landed prefix
+    with the chunk behind it): each query its selected blocks, causally
+    -> ``(oh (K, H, S0, Dh), the compressed rows (K, Hkv, nP, Dh))``.
+    The selection becomes a MASK a block and the attention is dense
+    under it, :data:`_BSA_QUERY_BLOCK` queries at a time."""
+    K, H, S0, Dh = qh.shape
+    Hkv, T = k_log.shape[1:3]
+    G, blk = H // Hkv, cfg.bsa_block
+    rows = _bsa_compress(k_log, cfg)
+    nB = -(-T // blk)
+    qb_n = min(_BSA_QUERY_BLOCK, S0)
+    assert S0 % qb_n == 0, (S0, qb_n)
+    qg = qh.reshape(K, Hkv, G, S0, Dh)
+    sparse = T > cfg.bsa_dense_len      # else no query's context is longer
+
+    def block(a):
+        q_b, pos_b = a                           # (K, Hkv, G, qb_n, Dh)
+        if sparse:
+            with jax.named_scope("hvd_bsa_score"):
+                score = _bsa_block_scores(q_b, rows, pos_b[None], nB, cfg)
+            with jax.named_scope("hvd_bsa_select"):
+                sel = _bsa_block_mask(
+                    score.reshape(-1, nB), jnp.broadcast_to(
+                        pos_b, (K, Hkv, qb_n)).reshape(-1), cfg
+                ).reshape(K, Hkv, qb_n, nB)
+        else:
+            sel = jnp.broadcast_to(
+                jnp.arange(nB, dtype=jnp.int32) <= pos_b[:, None] // blk,
+                (K, Hkv, qb_n, nB))
+        with jax.named_scope("chunk_attn"):
+            s = jnp.einsum("bkgqd,bktd->bkgqt", q_b.astype(k_log.dtype),
+                           k_log, preferred_element_type=jnp.float32
+                           ) * Dh ** -0.5
+            t = jnp.arange(T, dtype=jnp.int32)
+            vis = (jnp.repeat(sel, blk, axis=-1)[..., :T]
+                   & (t <= pos_b[:, None]))
+            w = jax.nn.softmax(jnp.where(vis[:, :, None], s, -1e30), axis=-1)
+            return jnp.einsum("bkgqt,bktd->bkgqd", w.astype(v_log.dtype),
+                              v_log, preferred_element_type=jnp.float32)
+
+    o = lax.map(block, (
+        jnp.moveaxis(qg.reshape(K, Hkv, G, S0 // qb_n, qb_n, Dh), 3, 0),
+        pos.reshape(S0 // qb_n, qb_n)))           # (nq, K, Hkv, G, qb_n, Dh)
+    o = jnp.moveaxis(o, 0, 3).reshape(K, H, S0, Dh)
+    return o.astype(cfg.dtype), rows
+
+
+def _bsa_landing_rows(rows, p0, S0: int, cfg: TransformerConfig):
+    """Of the compressed rows by page ``(K, Hkv, nP, Dh)``, those of the
+    pages a block of ``S0`` tokens from position ``p0`` lands in, as the
+    pool stores a row: ``(K, landing pages, Hkv * Dh)``."""
+    n_pg = -(-(S0 + cfg.bsa_stride - 1) // cfg.bsa_stride)
+    rows = jnp.pad(rows, ((0, 0), (0, 0), (0, n_pg), (0, 0)))
+    rows = lax.dynamic_slice_in_dim(rows, p0 // cfg.bsa_stride, n_pg, 2)
+    return jnp.moveaxis(rows, 1, 2).reshape(rows.shape[0], n_pg, -1)
 
 
 # --- latent attention (MLA) ---------------------------------------------------
@@ -2443,6 +2836,20 @@ class _Prompt:
                             *(self.landed.get(n) for n in kind.state),
                             self.lens)
 
+    def lin(self, q, k, v, p, kind: LayerKind):
+        return _lin_prefill(q, k, v, p, self.cfg,
+                            *(self.landed.get(n) for n in kind.state),
+                            self.lens)
+
+    def select_attend(self, qh, kh, vh, kind: LayerKind):
+        """Block-sparse attention over the prompt's own K/V, each query
+        its selected blocks; what it leaves: K, V and the compressed
+        rows of the pages it lands in."""
+        S0 = qh.shape[2]
+        oh, rows = _bsa_rows_attend(qh, kh, vh,
+                                    jnp.arange(S0, dtype=jnp.int32), self.cfg)
+        return oh, kh, vh, _bsa_landing_rows(rows, 0, S0, self.cfg)
+
     def attend(self, qh, kh, vh, kind: LayerKind):
         """Causal attention over the prompt's own (unexpanded, post-RoPE)
         K/V, which are also what it leaves: a window layer through the
@@ -2530,7 +2937,8 @@ class _Chunk(_Prompt):
             prefix = {n: _unpack_heads(a, cfg.kv_pack) if any(
                 n in k.paged for k in kv) else a for n, a in prefix.items()}
         self.prefix, self._causal = prefix, None
-        self.masks = {k: self._mask(k) for k in kv}
+        # (a block-sparse layer masks by its own selection)
+        self.masks = {k: self._mask(k) for k in kv if not k.page_rows}
 
     def _mask(self, kind: LayerKind):
         """``(S0, P0 + S0)``: the real prefix visible (to a window layer,
@@ -2581,6 +2989,25 @@ class _Chunk(_Prompt):
                            v_full, preferred_element_type=jnp.float32)
             oh = o.reshape(K, H, S0, Dh)
         return oh.astype(self.cfg.dtype), kh, vh
+
+    def select_attend(self, qh, kh, vh, kind: LayerKind):
+        """The chunk's rows laid behind the landed ones AT ``p0`` (row
+        ``j`` of the whole is logical position ``j``), each query its
+        selected blocks of prefix + chunk; the compressed rows are read
+        off the keys so laid, the landed ones' with them."""
+        K, S0 = qh.shape[0], qh.shape[2]
+
+        def behind(prefix, own):
+            with jax.named_scope("chunk_attn"):
+                rows = jnp.broadcast_to(prefix[None].astype(own.dtype),
+                                        (K,) + prefix.shape)
+                rows = jnp.pad(rows, ((0, 0), (0, 0), (0, S0), (0, 0)))
+                return lax.dynamic_update_slice_in_dim(rows, own, self.p0, 2)
+
+        pk, pv = (self.landed[n] for n in kind.paged)
+        oh, rows = _bsa_rows_attend(qh, behind(pk, kh), behind(pv, vh),
+                                    self.positions, self.cfg)
+        return oh, kh, vh, _bsa_landing_rows(rows, self.p0, S0, self.cfg)
 
     def latent(self, q_nope, q_rope, lat, index, p, kind: LayerKind):
         """The landed rows ``(1, P0, latent_row)`` attended EXPANDED, in
@@ -2646,6 +3073,69 @@ class _Tick(_Prompt):
         return _ssm_decode(n, p, self.cfg,
                            *(self.pools[n] for n in kind.state),
                            self.layer, self.active, self.kernel)
+
+    def lin(self, q, k, v, p, kind: LayerKind):
+        y, states = _lin_decode(q[:, 0], k[:, 0], v[:, 0], p,
+                                *(self.pools[n] for n in kind.state),
+                                self.layer, self.active, self.kernel)
+        return y[:, None], states
+
+    def select_attend(self, qh, k_t, v_t, kind: LayerKind):
+        """K and V written as :meth:`attend` writes them, and the row of
+        the page that the write FILLS (from the pool's own two pages);
+        then the compressed rows scored through the table, the blocks
+        selected, their pages compacted into a table a slot and KV head
+        and attended by the fused kernel — the pool read by BLOCK."""
+        from horovod_tpu.ops import paged_attention as _pa
+
+        cfg, pos, table, layer = self.cfg, self.pos, self.table, self.layer
+        k_pool, v_pool = (self.pools[n] for n in kind.paged)
+        (ck,) = (self.pools[n] for n in kind.page_rows)
+        S, H, _, Dh = qh.shape
+        Hkv, ps = k_pool.shape[2:4]
+        blk, m = cfg.bsa_block, cfg.bsa_block // cfg.bsa_stride
+        max_pages = table.shape[1]
+        if ps != cfg.bsa_stride:
+            raise UnsupportedModelConfigError(
+                f"a block-sparse layer's page is its compressed keys' "
+                f"stride ({cfg.bsa_stride}), not {ps}")
+        with jax.named_scope("kv_write"):
+            phys, take = self._target(table, ps)
+            k_pool = _pa.write_pages(k_pool, layer, phys, k_t, take)
+            v_pool = _pa.write_pages(v_pool, layer, phys, v_t, take)
+            # the window that ends with this page, once the page is full
+            at = jnp.clip(pos // ps, 0, max_pages - 1)
+            before = table[jnp.arange(S), jnp.maximum(at - 1, 0)]
+            row = _bsa_window_mean(k_pool[layer, before], k_pool[layer, phys],
+                                   cfg).reshape(S, Hkv * Dh)
+            full = self.active & (pos % ps == ps - 1) & (at >= 1)
+            ck = ck.at[layer, jnp.where(full, phys, 0)].set(
+                row.astype(ck.dtype))
+        qg = qh.reshape(S, Hkv, H // Hkv, 1, Dh)
+        live = jnp.where(self.active, pos, -1)
+        with jax.named_scope("hvd_bsa_score"):
+            rows = ck[layer, table].reshape(S, max_pages, Hkv, Dh)
+            score = _bsa_block_scores(qg, jnp.moveaxis(rows, 1, 2),
+                                      live[:, None], -(-max_pages // m), cfg)
+        R = S * Hkv
+        with jax.named_scope("hvd_bsa_select"):
+            chosen, n_sel = _bsa_chosen(
+                score.reshape(R, -1), jnp.repeat(jnp.maximum(live, 0), Hkv),
+                cfg)
+        with jax.named_scope("hvd_bsa_attend"):
+            page = (chosen[:, :, None] * m + jnp.arange(m, dtype=jnp.int32)
+                    ).reshape(R, -1)
+            compact = _pa.pages_of(jnp.repeat(table, Hkv, axis=0),
+                                   jnp.minimum(page, max_pages - 1) * ps, ps)
+            limit = jnp.where(jnp.repeat(self.active, Hkv),
+                              (n_sel - 1) * blk + jnp.repeat(pos, Hkv) % blk
+                              + 1, 0)
+            attend = (_pa.paged_attend if self.kernel
+                      else _pa.paged_attend_reference)
+            o, _ = attend(qg[:, :, :, 0], k_pool, v_pool, None, None,
+                          compact.reshape(S, Hkv, -1), limit.reshape(S, Hkv),
+                          layer=layer)
+        return (o.reshape(S, H, 1, Dh).astype(cfg.dtype), k_pool, v_pool, ck)
 
     def _target(self, table, ps: int):
         """Where each row's one position goes: ``(physical page, the
@@ -2758,6 +3248,30 @@ def _conv_mixer(x, p, cfg: TransformerConfig, kind: LayerKind, reach):
     return h, tuple(state)
 
 
+def _linear_mixer(x, p, cfg: TransformerConfig, kind: LayerKind, reach):
+    n = _attn_norm(x, p, cfg)
+    q, k, v, g = _lin_in(n, p, cfg, reach.positions)
+    y, state = reach.lin(q, k, v, p, kind)
+    return _lin_out(y, g, p, cfg), (state,)
+
+
+def _bsa_mixer(x, p, cfg: TransformerConfig, kind: LayerKind, reach):
+    """Block-sparse attention with its output gate: ``(o * sigmoid(n
+    W_g)) W_o`` under the layer's output multiplier."""
+    n = _attn_norm(x, p, cfg)
+    qh, kh, vh = _qkv_proj(n, p, cfg, positions=reach.positions,
+                           kind="block_sparse")
+    oh, *new = reach.select_attend(qh, kh, vh, kind)
+    with jax.named_scope("attn_out"):
+        o = jnp.moveaxis(oh, 1, 2)                        # (B, S, H, Dh)
+        g = jnp.einsum("bsd,dn->bsn", n, p["wg"].astype(cfg.dtype),
+                       preferred_element_type=jnp.float32)
+        o = o * jax.nn.sigmoid(g).reshape(o.shape)
+        h = jnp.einsum("bshk,hkd->bsd", o.astype(cfg.dtype),
+                       p["wo"].astype(cfg.dtype))
+    return _out_scaled(h, cfg), tuple(new)
+
+
 def _latent_rows(lat, index):
     """What a token leaves in a latent cache, shaped as the block of ONE
     kv head: its row, and with an indexer its index key."""
@@ -2811,6 +3325,12 @@ LAYER_KINDS = {
         _hybrid_mixer, {"k": _kv_row, "v": _kv_row},
         state={"conv": _taps, "ssm": lambda c: (
             c.ssm_heads, c.ssm_head_dim, c.ssm_state)}),
+    "linear": LayerKind(
+        _linear_mixer, f32=("lin",),
+        state={"lin": lambda c: (c.n_heads, c.head_dim, c.head_dim)}),
+    "block_sparse": LayerKind(
+        _bsa_mixer, {"k": _kv_row, "v": _kv_row},
+        page_rows={"ck": lambda c: c.kv_heads * c.head_dim}),
     "latent": LayerKind(_latent_mixer, {"k": lambda c: (1, c.latent_row)}),
     "sparse": LayerKind(_latent_mixer, {
         "k": lambda c: (1, c.latent_row),
@@ -3294,7 +3814,7 @@ def prefill_with_prefix(params: Dict, suffix, prefix: Dict, prefix_len,
 
     ``prefix``: what the layers kept for those positions, ONE dict under
     the pool's names — exactly the arrays the configuration's kinds
-    declare (:attr:`LayerKind.block`; anything else is refused, typed):
+    declare (:attr:`LayerKind.landed`; anything else is refused, typed):
     pages as :func:`~horovod_tpu.serving.cache.gather_prefix_pages`
     hands them over, ``(L_kind, heads, P0, width)``, shared by every
     row, and per-slot states ``(L_kind, K, ...)`` (:class:`_Chunk`).
@@ -3312,7 +3832,7 @@ def prefill_with_prefix(params: Dict, suffix, prefix: Dict, prefix_len,
     (:data:`LAYER_KINDS`)."""
     K, S0 = suffix.shape
     kinds = cfg.kinds
-    declared = {n for k in kinds.values() for n in k.block}
+    declared = {n for k in kinds.values() for n in k.landed}
     if set(prefix) != declared:
         raise UnsupportedModelConfigError(
             f"this configuration's layers keep {sorted(declared)} for a "
@@ -3325,12 +3845,12 @@ def prefill_with_prefix(params: Dict, suffix, prefix: Dict, prefix_len,
 
     def layer(x, p, kind, landed):
         h, new = kinds[kind].mixer(x, p, cfg, kinds[kind], reach.at(
-            landed=dict(zip(kinds[kind].block, landed))))
+            landed=dict(zip(kinds[kind].landed, landed))))
         return _mlp_block(x + h, p, cfg, moe_impl="dropless"), new
 
     x, ys = _scan_layer_kinds(
         cfg, layer, x, params["layers"],
-        {name: tuple(reach.prefix[n] for n in k.block)
+        {name: tuple(reach.prefix[n] for n in k.landed)
          for name, k in kinds.items()}, params.get("dense_layers"))
     last = jnp.take_along_axis(x, (true_len - 1)[:, None, None], axis=1)
     logits = _lm_head(last, params["ln_f"], _head(params, cfg), cfg)

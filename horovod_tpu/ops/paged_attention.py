@@ -52,8 +52,9 @@ program per SLOT, walking only the pages the slot can attend:
 Conventions shared with :mod:`~horovod_tpu.ops.attention` via
 :mod:`~horovod_tpu.ops._pallas_util`: compiled on TPU, interpreted on
 CPU (tier-1 CPU tests exercise the REAL kernel body).  The pure-JAX
-:func:`paged_attend_reference` is the test oracle only —
-:func:`paged_attend` never substitutes it; a caller asks
+:func:`paged_attend_reference` is the test oracle of a table a slot
+(of a table a slot and KV head it is the unfused tick's attend: its
+docstring) — :func:`paged_attend` never substitutes it; a caller asks
 :func:`kernel_supported` first (the engine does, once, at
 construction) and takes the unfused XLA tick for layouts the compiler
 cannot tile.
@@ -141,6 +142,9 @@ KERNEL_NAME = "hvd_paged_attend"
 #: ... and the name the same walk carries over a LATENT pool
 #: (:func:`mla_decode`).
 MLA_KERNEL_NAME = "hvd_mla_decode"
+#: ... and over a table a slot AND KV head (block-sparse attention: the
+#: chosen blocks' pages, compacted).
+BSA_KERNEL_NAME = "hvd_bsa_attend"
 
 # What one step of a slot's walk holds of K (and as much of V) in VMEM,
 # counted at the width it is computed at: an int8 page is widened to the
@@ -208,8 +212,13 @@ def first_block(lower, block_tokens: int):
 
 def _kernel_body(table_ref, limit_ref, *refs, page_size, n_pages,
                  compute_dtype, quantized, windowed, v_dim=None,
-                 sm_scale=None):
+                 sm_scale=None, heads=0):
     """One grid step: slot ``s``, every KV head, the slot's live pages.
+
+    ``heads`` (a table a slot AND KV head: block-sparse attention): the
+    grid's row ``s`` is slot ``s // heads``, KV head ``s % heads``,
+    whose own table row it walks; a page's transfer is that head's
+    ``(page, Dh)`` share alone.
 
     ``v_dim`` (a latent pool): there is no V pool — a fetched row is the
     key, and its first ``v_dim`` lanes the value.
@@ -276,9 +285,12 @@ def _kernel_body(table_ref, limit_ref, *refs, page_size, n_pages,
             def _page():
                 # a wait needs the descriptor's shape, not its source
                 at = (0, 0) if wait else (layer, table_ref[s, idx])
-                pairs = [(k_hbm.at[at], k_buf.at[buf, :, i])]
+                to = slice(None)
+                if heads:         # this row's head of the page alone
+                    at, to = at + ((0,) if wait else (s % heads,)), 0
+                pairs = [(k_hbm.at[at], k_buf.at[buf, to, i])]
                 if not latent:
-                    pairs.append((v_hbm.at[at], v_buf.at[buf, :, i]))
+                    pairs.append((v_hbm.at[at], v_buf.at[buf, to, i]))
                 if quantized:         # one layer's scales: (P, H_kv, lanes)
                     pairs += [(ks_hbm.at[at[1]], ks_buf.at[buf, i]),
                               (vs_hbm.at[at[1]], vs_buf.at[buf, i])]
@@ -355,6 +367,15 @@ def _kernel_body(table_ref, limit_ref, *refs, page_size, n_pages,
 def _pallas_paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table,
                          limit, compute_dtype, lower, layer, v_dim=None,
                          sm_scale=None, name=None):
+    heads = 0
+    if table.ndim == 3:     # a table a slot AND KV head: rows (slot, head)
+        assert k_scale is None and lower is None and v_dim is None
+        heads, name = qg.shape[1], name or BSA_KERNEL_NAME
+        qg = qg.reshape((-1, 1) + qg.shape[2:])
+        table = table.reshape(-1, table.shape[2])
+        limit = jnp.broadcast_to(limit.reshape(-1, 1) if limit.ndim == 1
+                                 else limit, (qg.shape[0] // heads, heads)
+                                 ).reshape(-1)
     S, Hkv, R, Dh = qg.shape
     _, _, _, ps, _ = k_pool.shape
     quantized = k_scale is not None
@@ -429,7 +450,8 @@ def _pallas_paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table,
     o, lse = pl.pallas_call(
         functools.partial(_kernel_body, page_size=ps, n_pages=n_pages,
                           compute_dtype=compute_dtype, quantized=quantized,
-                          windowed=windowed, v_dim=v_dim, sm_scale=sm_scale),
+                          windowed=windowed, v_dim=v_dim, sm_scale=sm_scale,
+                          heads=heads),
         grid_spec=grid_spec,
         out_shape=[o_shape, lse_shape],
         # the buffers' zeroing at slot 0 must come first
@@ -438,19 +460,45 @@ def _pallas_paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table,
         interpret=use_interpret(),
         name=KERNEL_NAME if not (latent or name) else name or MLA_KERNEL_NAME,
     )(*scalars, *operands)
-    return o[:, :, :R, :], lse[:, :, 0, :R]
+    o, lse = o[:, :, :R, :], lse[:, :, 0, :R]
+    if heads:
+        o = o.reshape(S // heads, heads, R, Dv)
+        lse = lse.reshape(S // heads, heads, R)
+    return o, lse
 
 
 def paged_attend_reference(qg, k_pool, v_pool, k_scale, v_scale, table,
                            limit, *, compute_dtype=None, lower=None,
-                           v_dim=None, sm_scale=None):
+                           v_dim=None, sm_scale=None, layer=None):
     """Pure-JAX reference for :func:`paged_attend` — gather, dequant,
     masked softmax — mirroring the unfused decode path's op-for-op
     rounding (``kv_dequantize``'s f32 contract, ``_cache_attend``'s
     stored-dtype dots with f32 accumulation, normalize-then-cast
-    weights).  The oracle in tests and in ``chip_smoke.py``; never a
-    silent substitute for the kernel."""
+    weights).  For a table a slot (``(S, n)``) the oracle in tests and
+    in ``chip_smoke.py``, never a substitute for the kernel.  For a
+    table a slot AND KV head (``(S, H_kv, n)``, ``limit`` ``(S, H_kv)``:
+    block-sparse attention, with ``layer`` the stacked pool's) it is NOT
+    an oracle but the tick's own attend wherever the kernel is not
+    engaged (the CPU), as :func:`mla_decode_reference` is: the ``heads``
+    kernel is held to ``plain_reference.sala_forward`` instead, which
+    shares nothing with either (``tests/test_linear_sparse_layers.py``,
+    ``chip_smoke.py``'s linear-sparse phase)."""
     S, Hkv, R, Dh = qg.shape
+    if table.ndim == 3:
+        kg, vg = (pool[layer, table, jnp.arange(Hkv)[None, :, None]].reshape(
+            S, Hkv, -1, Dh) for pool in (k_pool, v_pool))
+        s = jnp.einsum("skrd,sktd->skrt", qg.astype(kg.dtype), kg,
+                       preferred_element_type=jnp.float32) / np.sqrt(Dh)
+        col = jax.lax.broadcasted_iota(jnp.int32, (kg.shape[2],), 0)
+        vis = (col < limit[..., None])[:, :, None]        # (S, Hkv, 1, T)
+        m = jnp.max(jnp.where(vis, s, NEG_INF), axis=-1, keepdims=True)
+        p = jnp.where(vis, jnp.exp(s - m), 0.0)
+        l = jnp.sum(p, axis=-1, keepdims=True)
+        o = jnp.einsum("skrt,sktd->skrd",
+                       (p / jnp.where(l > 0, l, 1.0)).astype(vg.dtype), vg,
+                       preferred_element_type=jnp.float32)
+        return o, jnp.where(l[..., 0] > 0, m[..., 0] + jnp.log(
+            jnp.where(l > 0, l, 1.0))[..., 0], NEG_INF)
     max_pages = table.shape[1]
     ps = k_pool.shape[2]
     if compute_dtype is None:
@@ -507,7 +555,12 @@ def paged_attend(qg, k_pool, v_pool, k_scale, v_scale, table, limit, *,
       k_scale / v_scale: f32 per-vector scales for int8 pools, shaped as
         the pool less its last dim, else ``None``.
       table: ``(S, max_pages)`` int32 physical page ids (host data —
-        any allocation pattern, one executable).
+        any allocation pattern, one executable); or ``(S, H_kv, n)``, a
+        table a slot AND KV head (block-sparse attention: the pages of
+        the blocks that head's queries selected, compacted) — the grid
+        is then a (slot, head) a step, a page's transfer that head's
+        ``(page, Dh)`` alone, ``limit`` ``(S, H_kv)`` counts positions
+        of the COMPACTED order; unquantized, no window.
       limit: ``(S,)`` int32 — attend logical positions ``< limit[s]``
         (``pos + 1`` for decode-at-``pos``, ``pos`` for VERIFY over
         committed pages; ``0`` masks a slot entirely).

@@ -26,7 +26,9 @@ WHICH arrays a pool holds is the model's table of layer kinds
 A kind of layer may keep a per-SLOT state under the same manager (a
 conv layer's last ``taps`` gated inputs ``conv`` ``(L_conv, S, taps,
 D)``; a hybrid layer's taps and, MBs a slot and layer where those are
-KBs, its state-space mixer's matrix state ``ssm`` ``(L, S, H, P, N)``)
+KBs, its state-space mixer's matrix state ``ssm`` ``(L, S, H, P, N)``; a
+linear-attention layer's matrix state ``lin`` ``(L_lin, S, H, Dh, Dh)``,
+in FLOAT32 whatever the pool's dtype)
 — fixed in size where a slot's pages grow — beside the page arrays in
 the pool dict, so it is donated, carried and written in place with
 them.  It is granted with the slot and ZEROED then (:meth:`PagedSlotCache
@@ -76,7 +78,8 @@ def resolve_kv_dtype(cfg: "T.TransformerConfig", kv_dtype):
 
 def _arrays(pool: Dict, role: str) -> List[str]:
     """The names of ``pool``'s arrays that the table declares ``role``
-    (``paged`` | ``scales`` | ``state``), in the table's order."""
+    (``paged`` | ``scales`` | ``page_rows`` | ``state``), in the table's
+    order."""
     return [n for n in dict.fromkeys(
         n for k in T.LAYER_KINDS.values() for n in getattr(k, role))
         if n in pool]
@@ -88,9 +91,11 @@ def init_page_pool(cfg: "T.TransformerConfig", n_slots: int, n_pages: int,
     declare (:data:`~horovod_tpu.models.transformer.LAYER_KINDS`) —
     every paged array a page pool ``(L, P, heads, page, width)`` (``P``
     counts the NULL page; ``k``/``v``, or a latent model's ONE array of
-    rows and an indexer's keys under the same page table), every
-    per-slot state ``(L_kind, S, ...)`` — and ``pos``, the per-slot
-    ``(S,)`` logical write position.
+    rows and an indexer's keys under the same page table), every array
+    of a row a page ``(L_kind, P, width)`` (a block-sparse layer's
+    compressed keys), every per-slot state ``(L_kind, S, ...)`` (in the
+    pool's dtype, or float32 where the kind says so) — and ``pos``, the
+    per-slot ``(S,)`` logical write position.
     int8 storage adds ``k_scale``/``v_scale`` ``(L, P, H_kv, page)``
     per-vector f32 scales.  The page table itself is HOST state
     (:attr:`PagedSlotCache.table`), uploaded as data each tick.
@@ -119,9 +124,13 @@ def init_page_pool(cfg: "T.TransformerConfig", n_slots: int, n_pages: int,
                 if quant:
                     pool[scale[name]] = jnp.zeros(
                         (L, n_pages, heads, page_size), jnp.float32)
+        for name, width in kind.page_rows.items():   # a row A PAGE
+            pool[name] = jnp.zeros(
+                (cfg.layers_with(name), n_pages, width(cfg)), dt)
         for name, shape in kind.state.items():
             pool[name] = jnp.zeros(
-                (cfg.layers_with(name), n_slots) + shape(cfg), dt)
+                (cfg.layers_with(name), n_slots) + shape(cfg),
+                jnp.float32 if name in kind.f32 else dt)
     return pool
 
 
@@ -164,7 +173,9 @@ def paged_insert(pool: Dict, slots, new_pos, pages, first, lens,
     column is the NULL page.  int8 pools quantize per vector on the way
     in; payload and scale go through the same :func:`write_pages`.  A
     pool whose rows several narrow KV heads share takes the block a row
-    a head and lays them side by side.  A per-slot STATE ``(L_kind, K,
+    a head and lays them side by side.  An array of a row A PAGE takes
+    ``(L, K, landing pages, width)``, the row of each page the landing
+    touches, and keeps those of the pages it fills.  A per-slot STATE ``(L_kind, K,
     ...)`` — each row's at its new position — replaces its slot's.
     ``slots`` / ``new_pos`` adopt the per-row positions (empty for
     slotless landings — prefix registration)."""
@@ -186,6 +197,16 @@ def paged_insert(pool: Dict, slots, new_pos, pages, first, lens,
         rows = {n: q for n, (q, _) in quant.items()}
         rows.update(zip(scales, (s for _, s in quant.values())))
     out = {**pool, **{n: land(n, x) for n, x in rows.items()}}
+    for n in _arrays(pool, "page_rows"):
+        # ``(L, K, n_pg, width)``: the row of each page this landing
+        # FILLS (its last offset is a landed column); the others' to
+        # the NULL page
+        done = ((jnp.arange(n_pg, dtype=jnp.int32) + 1) * ps
+                <= first + jnp.asarray(lens, jnp.int32)[:, None])
+        out[n] = pool[n].at[
+            jnp.arange(pool[n].shape[0], dtype=jnp.int32)[:, None, None],
+            jnp.where(done, pages, NULL_PAGE)[None]].set(
+                block[n].astype(pool[n].dtype))
     for n in _arrays(pool, "state"):
         out[n] = pool[n].at[:, slots].set(block[n].astype(pool[n].dtype))
     out["pos"] = pool["pos"].at[slots].set(new_pos)
@@ -203,6 +224,8 @@ def copy_page(pool: Dict, src, dst) -> Dict:
         layer = jnp.arange(a.shape[0], dtype=jnp.int32)
         out[name] = write_pages(a, layer, dst, a[layer, src],
                                 jnp.ones((1, a.shape[3]), bool))
+    for name in _arrays(pool, "page_rows"):
+        out[name] = pool[name].at[:, dst].set(pool[name][:, src])
     return out
 
 
@@ -435,6 +458,18 @@ class PagedSlotCache:
                            - set(T.LAYER_KINDS["conv"].state), 1)
 
     @property
+    def lin_state_bytes_per_slot(self) -> int:
+        """... and every linear-attention layer's float32 matrix state
+        (0: the model has none)."""
+        return self._bytes(T.LAYER_KINDS["linear"].state, 1)
+
+    @property
+    def compressed_bytes_per_page(self) -> int:
+        """What a page holds beside its tokens' rows: every
+        block-sparse layer's compressed key a KV head (0: none)."""
+        return self._bytes(T.LAYER_KINDS["block_sparse"].page_rows, 1)
+
+    @property
     def latent_bytes_per_token(self) -> int:
         """What a token leaves in a latent pool's rows, every layer's
         (``cfg.latent_row`` as stored; 0 for a pool of K and V)."""
@@ -632,13 +667,15 @@ class PagedSlotCache:
             self.cache, np.asarray(slots, np.int32),
             np.asarray(vals, np.int32))
 
-    def slot_state(self, slot: int, name: str = "conv"):
-        """One slot's state in the per-slot array ``name`` — the taps
-        ``conv`` ``(L, 1, taps, C)``, a hybrid model's matrix states
-        ``ssm`` ``(L, 1, H, P, N)`` — as :func:`~horovod_tpu.models.
-        transformer.prefill_with_prefix` takes it for the slot's next
-        chunk."""
-        return self._slot_state(self.cache[name], np.int32(slot))
+    def slot_state(self, slot: int, name: Optional[str] = None):
+        """One slot's state in the per-slot array ``name`` (None: the
+        pool's first) — the taps ``conv`` ``(L, 1, taps, C)``, a hybrid
+        model's matrix states ``ssm`` ``(L, 1, H, P, N)``, a linear
+        layer's ``lin`` ``(L, 1, H, Dh, Dh)`` — as :func:`~horovod_tpu.
+        models.transformer.prefill_with_prefix` takes it for the slot's
+        next chunk."""
+        return self._slot_state(self.cache[name or self.state_arrays[0]],
+                                np.int32(slot))
 
     def gather_prefix(self, pages: Sequence[int]):
         """A shared prefix's pages, contiguous, by the pool's names

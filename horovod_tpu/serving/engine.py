@@ -628,6 +628,9 @@ class InferenceEngine:
         int8 = resolve_kv_dtype(cfg, engine_cfg.kv_dtype)[1]
         for on, what, int8_too, spec in (
             (cfg.has_state or cfg.kv_pack > 1,
+             "linear-attention layers (a float32 matrix state a slot) "
+             "and block-sparse layers (a compressed key a page)"
+             if cfg.has_linear else
              "hybrid layers (a state-space mixer's per-slot state beside "
              "the pages)" if cfg.has_ssm else
              "conv layers (a per-slot state beside the pages)"
@@ -641,6 +644,10 @@ class InferenceEngine:
              + (" and sparse selection (an indexer)" if cfg.sparse else "")
              + ", leading dense layers or a share of the experts",
              cfg.latent, "speculative=True"),
+            (cfg.has_bsa,
+             "block-sparse layers (a compressed key a page beside the "
+             "pages, a table a slot and KV head)", True,
+             "speculative=True"),
             (cfg.has_window,
              f"window layers (pattern {cfg.layer_pattern})", True,
              "speculative=True")):
@@ -672,6 +679,11 @@ class InferenceEngine:
             if self._spec_model:
                 self.draft_params = self._shard.shard_params(
                     self.draft_params, self.draft_cfg)
+        if cfg.has_bsa and engine_cfg.page_size != cfg.bsa_stride:
+            raise T.UnsupportedModelConfigError(
+                f"a block-sparse layer keeps one compressed key a page: "
+                f"page_size must be its stride ({cfg.bsa_stride}), not "
+                f"{engine_cfg.page_size}")
         self.slots = self._make_slots()
         self.wslots = self._make_window_slots()
         self.metrics = ServingMetrics()
@@ -944,6 +956,9 @@ class InferenceEngine:
         # layers with a state-space mixer (0: none): what the two
         # ssm_* counters count rows and tokens by
         self._ssm_layers = cfg.kind_count("hybrid")
+        # ... and the lin_* and bsa_* counters theirs
+        self._lin_layers = cfg.kind_count("linear")
+        self._bsa_layers = cfg.kind_count("block_sparse")
         self._prefill_calls = 0  # prefill FORWARD PASSES (sharing hook)
 
         # Paged-cache host state: _page_pos mirrors each slot's device
@@ -1404,8 +1419,11 @@ class InferenceEngine:
              "window would release a page its peers read)"),
             (self.cfg.sparse, "sparse attention (an indexer's keys beside "
              "the latent rows)"),
+            (self.cfg.has_bsa, "block-sparse layers (a sharer's compressed "
+             "keys would span the prefix's last page and its own first)"),
             (self.cfg.has_state,
-             ("hybrid" if self.cfg.has_ssm else "conv") + " layers (a "
+             ("linear-attention" if self.cfg.has_linear else
+              "hybrid" if self.cfg.has_ssm else "conv") + " layers (a "
              "sharer would need the state as it stood at the prefix's "
              "end: a snapshot a page boundary)")):
             if on:
@@ -2320,6 +2338,7 @@ class InferenceEngine:
         self.metrics.prefill_tokens.inc(tokens)
         self.metrics.prefill_padded_tokens.inc(rows * bucket)
         self.metrics.ssm_scanned_tokens.inc(tokens * self._ssm_layers)
+        self.metrics.lin_scanned_tokens.inc(tokens * self._lin_layers)
 
     def _count_paged_walk(self, active: np.ndarray) -> None:
         """One dispatched paged tick: the positions its active slots may
@@ -2329,6 +2348,26 @@ class InferenceEngine:
         kernel's own trip count, ``ops.paged_attention.walk``)."""
         limit = self._page_pos[active] + 1
         self.metrics.ssm_updated_slots.inc(len(limit) * self._ssm_layers)
+        self.metrics.lin_updated_slots.inc(len(limit) * self._lin_layers)
+        if self.cfg.has_bsa:
+            # a block-sparse tick scores the compressed row of every
+            # whole window a KV head and attends the chosen blocks
+            # alone (every block of a context within bsa_dense_len):
+            # the whole pool's walk does not run
+            cfg = self.cfg
+            rows = np.maximum(limit // cfg.bsa_stride - 1, 0)
+            chosen = (cfg.bsa_init_blocks + cfg.bsa_topk
+                      + cfg.bsa_window // cfg.bsa_block - 1) * cfg.bsa_block \
+                + (limit - 1) % cfg.bsa_block + 1
+            attended = np.where(limit <= cfg.bsa_dense_len, limit,
+                                np.minimum(limit, chosen))
+            self.metrics.bsa_scored_rows.inc(
+                int(rows.sum()) * cfg.kv_heads * self._bsa_layers)
+            self.metrics.bsa_attended_tokens.inc(
+                int(attended.sum()) * self._bsa_layers)
+            self.metrics.bsa_live_tokens.inc(
+                int(limit.sum()) * self._bsa_layers)
+            return
         if self.cfg.sparse:
             # a sparse model's tick walks the INDEX keys of every live
             # token and reads at most index_topk latent rows a slot:
@@ -4067,6 +4106,13 @@ class InferenceEngine:
                 self.slots.ssm_state_bytes_per_slot,
             "ssm_state_slots_live": self.slots.active_count
                 if self.cfg.has_ssm else 0,
+            # ... a fourth: a linear-attention layer's float32 matrix
+            # state (lin_*_total as ssm_*_total); and what a page of a
+            # block-sparse model holds beside its tokens' rows
+            "lin_state_bytes_per_slot":
+                self.slots.lin_state_bytes_per_slot,
+            "kv_compressed_bytes_per_page":
+                self.slots.compressed_bytes_per_page,
             "kv_pages_high_water": self.slots.pages_high_water,
             "kv_window_pages_per_slot_bound":
                 self.wslots.window_pages_bound

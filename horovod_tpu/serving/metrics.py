@@ -146,6 +146,16 @@ class ServingMetrics:
       layers a dispatched tick (each a matrix state read and written
       once), true prompt tokens x layers a prefill executable (0 for a
       model with none).
+    * ``lin_updated_slots`` / ``lin_scanned_tokens`` — the same two for
+      linear-attention layers (a float32 matrix state a head): active
+      rows x layers a dispatched tick, true prompt tokens x layers a
+      prefill executable.
+    * ``bsa_scored_rows`` / ``bsa_attended_tokens`` / ``bsa_live_tokens``
+      — a block-sparse model's dispatched ticks, summed over its
+      block-sparse layers: the compressed rows a (slot, KV head) scores
+      (the whole windows of its context), the tokens a slot's KV head
+      attends (the chosen blocks', or every one of a context no longer
+      than ``bsa_dense_len``) and the tokens it holds.
     * ``paged_live_tokens`` / ``paged_walked_tokens`` — per dispatched
       paged tick, the positions the active slots may attend, and the
       positions the paged kernel's walk covers for them: each slot's
@@ -346,6 +356,29 @@ class ServingMetrics:
             "serving_ssm_scanned_tokens_total",
             "Prompt tokens x layers a state-space mixer's chunked scan "
             "carried a state over (admission groups and ingest chunks)")
+        self.lin_updated_slots = r.counter(
+            "serving_lin_updated_slots_total",
+            "Per dispatched paged tick of a model with linear-attention "
+            "layers, active rows x layers: the float32 matrix states "
+            "its update read and wrote in place")
+        self.lin_scanned_tokens = r.counter(
+            "serving_lin_scanned_tokens_total",
+            "Prompt tokens x layers a linear-attention layer's chunked "
+            "scan carried a state over (admission groups and ingest "
+            "chunks)")
+        self.bsa_scored_rows = r.counter(
+            "serving_bsa_scored_rows_total",
+            "Per dispatched paged tick of a block-sparse model, the "
+            "compressed rows scored: whole windows x KV heads x layers")
+        self.bsa_attended_tokens = r.counter(
+            "serving_bsa_attended_tokens_total",
+            "Per dispatched paged tick, the tokens a slot's KV head "
+            "attends in a block-sparse layer (the chosen blocks'), "
+            "x layers")
+        self.bsa_live_tokens = r.counter(
+            "serving_bsa_live_tokens_total",
+            "Per dispatched paged tick, the tokens the active slots "
+            "hold, x block-sparse layers")
         self.window_live_tokens = r.counter(
             "serving_window_live_tokens_total",
             "Per dispatched paged tick, the positions its active slots "
@@ -611,6 +644,11 @@ class ServingMetrics:
             "paged_walked_tokens_total": self.paged_walked_tokens.value,
             "ssm_updated_slots_total": self.ssm_updated_slots.value,
             "ssm_scanned_tokens_total": self.ssm_scanned_tokens.value,
+            "lin_updated_slots_total": self.lin_updated_slots.value,
+            "lin_scanned_tokens_total": self.lin_scanned_tokens.value,
+            "bsa_scored_rows_total": self.bsa_scored_rows.value,
+            "bsa_attended_tokens_total": self.bsa_attended_tokens.value,
+            "bsa_live_tokens_total": self.bsa_live_tokens.value,
             "window_live_tokens_total": self.window_live_tokens.value,
             "window_walked_tokens_total": self.window_walked_tokens.value,
             "dsa_scored_tokens_total": self.dsa_scored_tokens.value,

@@ -23,7 +23,11 @@ rows out as the block of one kv head (a reshape) where the attention
 ends, not after the MLP or before the attention — and the TPU compiler
 makes the same program of both (PERF.md section 6, PR 45: the optimised
 HLO of all eighteen served programs at the published widths, equal but
-for names); the other entries are what the parent printed."""
+for names); the other entries are what the parent printed.
+``tests/data/served_program_digests_pr45.json`` holds what the tree
+before PR 46 (linear-attention and block-sparse layers) printed for the
+SIX, the hybrid architecture among them; ``tests/test_linear_sparse_
+layers.py`` holds this tree to that."""
 
 import hashlib
 import json
@@ -75,6 +79,25 @@ CONFIGS = {
         layer_pattern=("conv", "conv", "full", "conv"), conv_kernel=3,
         tie_embeddings=True, kv_lane_dense=True, max_seq=128,
         dtype=jnp.float32, attention_impl="flash"),
+    # attention and a state-space mixer in every layer
+    # (falcon-h1-34b-serve)
+    "hybrid": dict(
+        vocab_size=96, d_model=64, n_heads=4, n_kv_heads=2, n_layers=3,
+        d_ff=128, max_seq=128, dtype=jnp.float32, attention_impl="flash",
+        layer_pattern=("hybrid",), conv_kernel=4, ssm_heads=4,
+        ssm_head_dim=8, ssm_state=16, ssm_groups=2, ssm_chunk=4),
+    # linear-attention layers between block-sparse attention layers
+    # (minicpm-sala-serve)
+    "linear_sparse": dict(
+        vocab_size=96, d_model=64, n_heads=4, n_kv_heads=2, d_head=16,
+        n_layers=4, d_ff=128, max_seq=128, dtype=jnp.float32,
+        attention_impl="flash", qk_norm=True,
+        layer_pattern=("block_sparse", "linear", "linear", "block_sparse"),
+        bsa_kernel=8, bsa_stride=4,
+        bsa_block=8, bsa_topk=2, bsa_window=16, bsa_init_blocks=1,
+        bsa_dense_len=24, ssm_chunk=4, embed_multiplier=3.0,
+        head_multiplier=0.5, attn_out_multiplier=0.5,
+        mlp_multipliers=(1.0, 0.5)),
 }
 
 SLOTS, PAGE, MAX_LEN, CHUNK = 3, 4, 64, 8
@@ -91,17 +114,17 @@ def programs(cfg):
         T.init_params(jax.random.PRNGKey(0), cfg))[0])
     n_pg = SLOTS * MAX_LEN // PAGE + 1
 
-    def pool(kind):
+    def pool(layers):
         return jax.eval_shape(lambda: C.init_page_pool(
-            cfg, SLOTS, n_pg, PAGE, None, cfg.kind_count(kind)))
+            cfg, SLOTS, n_pg, PAGE, None, layers))
 
-    full = pool("full")
+    full = pool(cfg.layers_with("k"))
     table = jnp.zeros((SLOTS, MAX_LEN // PAGE), jnp.int32)
     tokens = jnp.zeros((SLOTS,), jnp.int32)
     active = jnp.ones((SLOTS,), bool)
     kw = {}
     if cfg.has_window:
-        w = pool("sliding")
+        w = pool(cfg.kind_count("sliding"))
         full = {**full, "wk": w["k"], "wv": w["v"]}
         kw["wtable"] = table
     out = {"tick": _digest(

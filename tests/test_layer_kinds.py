@@ -1,5 +1,5 @@
 """ONE table of layer kinds (``models/transformer.py LAYER_KINDS``): for
-each of the six served architectures' toy configurations, the arrays
+each of the eight served architectures' toy configurations, the arrays
 the table declares are the arrays the page pool allocates, a whole-prompt
 prefill hands back, and a chunk's prefill accepts and hands back — under
 the same names — and a prefix that lacks one of them, or holds another,
@@ -15,13 +15,8 @@ from horovod_tpu.serving import cache as C
 
 pytestmark = [pytest.mark.serving, pytest.mark.paged]
 
-# the digests' toy builders (tests/served_program_digests.py), and the
-# hybrid one beside them
-CONFIGS = dict(D.CONFIGS, hybrid=dict(
-    vocab_size=96, d_model=64, n_heads=4, n_kv_heads=2, n_layers=3,
-    d_ff=128, max_seq=128, dtype=jnp.float32, attention_impl="flash",
-    layer_pattern=("hybrid",), conv_kernel=4, ssm_heads=4, ssm_head_dim=8,
-    ssm_state=16, ssm_groups=2, ssm_chunk=4))
+# the digests' toy builders (tests/served_program_digests.py)
+CONFIGS = D.CONFIGS
 PAGE, CHUNK = 4, 8
 
 
@@ -30,7 +25,12 @@ def test_the_tables_arrays_are_the_pools_the_blocks_and_the_prefixs(name):
     cfg = T.TransformerConfig(**CONFIGS[name])
     declared = {n for k in cfg.kinds.values() for n in k.block}
     assert declared == {n for n in ("k", "v", "wk", "wv", "ik", "conv",
-                                    "ssm") if cfg.layers_with(n)}
+                                    "ssm", "ck", "lin")
+                        if cfg.layers_with(n)}
+    # (a chunk's prefix carries no array of a row a page: the rows are
+    # read off the landed keys)
+    landed = {n for k in cfg.kinds.values() for n in k.landed}
+    assert landed == declared - {"ck"}
     # the pool(s): the main one, and a window layer's own under its names
     made, zeros = [], jnp.zeros
     with pytest.MonkeyPatch.context() as patch:   # each array made ONCE
@@ -59,7 +59,7 @@ def test_the_tables_arrays_are_the_pools_the_blocks_and_the_prefixs(name):
             {n: pool[w] for w, n in T.WINDOW_ARRAYS.items()}, pages)
         prefix.update((w, own[n]) for w, n in T.WINDOW_ARRAYS.items())
     prefix.update((n, pool[n][:, :1]) for n in C._arrays(pool, "state"))
-    assert set(prefix) == declared
+    assert set(prefix) == landed
 
     def chunk(p, prefix):
         return T.prefill_with_prefix(p, ids, prefix, jnp.int32(PAGE), cfg,
@@ -69,8 +69,8 @@ def test_the_tables_arrays_are_the_pools_the_blocks_and_the_prefixs(name):
     assert set(got) - {"pos"} == declared
     assert {n: a.shape for n, a in got.items()} == {
         n: a.shape for n, a in block.items()}
-    lacking = {n: a for n, a in prefix.items() if n != sorted(declared)[0]}
-    foreign = {**prefix, "zz": prefix[sorted(declared)[0]]}
+    lacking = {n: a for n, a in prefix.items() if n != sorted(landed)[0]}
+    foreign = {**prefix, "zz": prefix[sorted(landed)[0]]}
     for bad in (lacking, foreign):
         with pytest.raises(T.UnsupportedModelConfigError, match="prefix"):
             jax.eval_shape(chunk, params, bad)

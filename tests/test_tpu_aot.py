@@ -956,3 +956,152 @@ def test_the_hybrid_cells_reference_fits_the_chip_in_one_width(one_chip,
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 3e9, mem
     assert "while" in compiled.as_text()    # the rows below n, no more
+
+
+def _minicpm_sala():
+    import json
+
+    from chipbench.drivers import serve_linear_sparse
+
+    with open(os.path.join(os.path.dirname(chip_smoke.__file__), "chipbench",
+                           "configs", "minicpm-sala-serve.json")) as f:
+        dims = json.load(f)
+    return dims, serve_linear_sparse.build_cfg(dims)
+
+
+@pytest.mark.parametrize("what", ["tick", "chunk", "prompt"])
+def test_the_served_linear_sparse_programs_compile_at_the_published_widths(
+        one_chip, monkeypatch, what):
+    """The benchmark's own configuration (`minicpm-sala-serve`: 48 slots
+    of 28 672, 81 920 pages, published layers 16-27 — three block-sparse
+    layers of 32 query / 2 KV heads, nine linear-attention layers of 32
+    heads — a float32 matrix state of 2 MiB a slot and layer) as the
+    engine's three programs, compiled for the v5e from shapes alone.
+    3 929 973 152 parameters = 7.86 GB in bf16 are their argument: the
+    issue's 3 929 866 240 of matrices, and 106 912 of norms and decays
+    (two norms of 4096 a layer and the last, 3 x 128 + 32 a linear
+    layer, 2 x 128 a sparse one).  The TICK holds the paged kernel over
+    a table a slot and KV head three times (a layer unrolled each: the
+    pattern is one period of twelve) and the state update's kernel nine
+    times, takes 3.87 GB of pages, 126 MB of compressed keys and 0.91 GB
+    of float32 states and gives ALL of it back aliased, with no result
+    the size of a layer of the states (201 MB) or of ``k`` but the
+    arrays passing through, under 0.5 GB of temporaries: 13.4 GB with
+    the weights.  A CHUNK of 512 against 32 768 landed positions (the
+    largest bucket: a query a row its own blocks, 64 queries' scores in
+    flight) and a PROMPT of two rows of 512 compile inside 1.3 GB."""
+    from horovod_tpu.ops import ssm as SSM
+
+    for mod, name in ((PA, "use_interpret"), (SSM, "use_interpret"),
+                      (ATT, "_use_interpret")):
+        monkeypatch.setattr(mod, name, lambda: False)
+    dims, cfg = _minicpm_sala()
+    eng = dims["engine"]
+    assert cfg.layer_kinds == ("block_sparse",) * 2 + ("linear",) * 4 + (
+        "block_sparse",) + ("linear",) * 5
+    assert (cfg.head_dim, cfg.n_heads // cfg.kv_heads,
+            cfg.bsa_blocks_max) == (128, 16, 128)
+    params = _on(one_chip, jax.eval_shape(
+        lambda: T.lay_out_projections(jax.tree_util.tree_map(
+            lambda a: a.astype(cfg.dtype),
+            T.init_params(jax.random.PRNGKey(0), cfg)))[0]))
+    n_params = sum(a.size for a in jax.tree_util.tree_leaves(params))
+    assert n_params == 3_929_973_152 == 3_929_866_240 + 106_912
+    weights = 2 * n_params
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    Lk, Ll = cfg.layers_with("k"), cfg.layers_with("lin")
+    assert (Lk, Ll, cfg.layers_with("ck")) == (3, 9, 3)
+    if what == "tick":
+        S_ = eng["n_slots"]
+        pool = _on(one_chip, jax.eval_shape(lambda: C.init_page_pool(
+            cfg, S_, eng["n_pages"] + 1, eng["page_size"], None, Lk)))
+        assert pool["k"].shape == (3, 81921, 2, 16, 128)
+        assert pool["ck"].shape == (3, 81921, 256)
+        assert (pool["lin"].shape, pool["lin"].dtype) == (
+            (9, 48, 32, 128, 128), jnp.float32)
+        compiled = jax.jit(
+            lambda p, tok, act, t, pl: T.decode_step_paged(
+                p, tok, pl, t, cfg, act, kernel=True),
+            donate_argnums=(4,)).lower(
+                params, sds((S_,), jnp.int32), sds((S_,), jnp.bool_),
+                sds((S_, eng["max_len"] // eng["page_size"]), jnp.int32),
+                pool).compile()
+        text = compiled.as_text()
+        assert chip_smoke.kernel_calls(text, PA.BSA_KERNEL_NAME) == 3
+        assert chip_smoke.kernel_calls(text, SSM.UPDATE_NAME) == 9
+        for name in ("lin", "k"):   # no copy the size of a layer of either
+            layer = pool[name].size // pool[name].shape[0]
+            offenders, largest = chip_smoke.pool_sized_results(text, layer)
+            assert offenders == [], (name, offenders, largest)
+        mem = compiled.memory_analysis()
+        pool_bytes = sum(a.size * a.dtype.itemsize for a in pool.values())
+        assert abs(pool_bytes - 5.058e9) < 1e6
+        assert mem.alias_size_in_bytes >= pool_bytes   # ck and lin too
+        assert mem.temp_size_in_bytes < 0.5e9, mem
+        assert mem.argument_size_in_bytes < weights + pool_bytes + 1e6
+        return
+    if what == "chunk":
+        ids, lens = sds((1, 512), jnp.int32), sds((1,), jnp.int32)
+        pk = sds((Lk, cfg.kv_heads, 32768, cfg.head_dim), cfg.dtype)
+        state = sds((Ll, 1, cfg.n_heads, cfg.head_dim, cfg.head_dim),
+                    jnp.float32)
+        compiled = jax.jit(
+            lambda p, suf, k, v, p0, n, a: T.prefill_with_prefix(
+                p, suf, {"k": k, "v": v, "lin": a}, p0, cfg,
+                true_len=n)).lower(
+                    params, ids, pk, pk, sds((), jnp.int32), lens,
+                    state).compile()
+    else:
+        ids, lens = sds((2, 512), jnp.int32), sds((2,), jnp.int32)
+        compiled = jax.jit(
+            lambda p, pr, n: T.prefill(p, pr, T.init_cache(cfg, 2, 512),
+                                       cfg, true_len=n)).lower(
+                params, ids, lens).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.3e9, mem
+    # a row of logits a request, and the block: K, V of 3 layers' 512
+    # rows, their pages' compressed keys, 9 float32 states a request
+    assert mem.output_size_in_bytes < 0.05e9, mem
+
+
+@pytest.mark.parametrize("half", ["linear", "block_sparse", "feed"])
+def test_the_linear_sparse_cells_reference_fits_the_chip_in_one_width(
+        one_chip, half):
+    """The benchmark's own float32 reference of `minicpm-sala-serve`
+    (``chipbench/reference_linear_sparse.py``), a sequence of any length
+    laid in the engine's 28 672 rows: each kind's first half of a layer
+    and the MLP compile for the v5e with their temporaries inside the
+    chip (the engine is gone by then), the length a traced scalar —
+    three executables a mode whatever the seed draws; the recurrence and
+    the rows' blocks are loops."""
+    from chipbench import reference_linear_sparse as R
+    from chipbench import weights_linear_sparse as W
+
+    dims, _ = _minicpm_sala()
+    key = R._layer_dims(dims)
+    S_ = dims["engine"]["max_len"]
+    x = _on(one_chip, jax.ShapeDtypeStruct((S_, dims["hidden_size"]),
+                                           jnp.float32))
+    n = _on(one_chip, jax.ShapeDtypeStruct((), jnp.int32))
+
+    def leaves(kind, names=None):
+        return _on(one_chip, jax.eval_shape(lambda: {
+            k: jnp.zeros(s, jnp.float32 if how == "decay" else jnp.bfloat16)
+            for k, (s, how) in W.layer_shapes(dims, kind).items()
+            if (k in R._FEED_LEAVES) == (names is R._FEED_LEAVES)}))
+
+    with jax.default_matmul_precision("highest"):
+        if half == "feed":
+            lowered = R._feed_fn(key, "f32", 512).lower(
+                x, leaves("linear", R._FEED_LEAVES), n)
+        else:
+            lowered = R._mix_fn(key, half, "f32", 512, True).lower(
+                x, leaves(half), n,
+                _on(one_chip, jax.ShapeDtypeStruct((S_,), jnp.bool_)))
+        compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 6e9, mem
+    assert "while" in compiled.as_text()    # the rows below n, no more

@@ -699,7 +699,7 @@ class TestRefusals:
 
     def test_bad_patterns_are_refused_at_construction(self):
         with pytest.raises(ValueError, match="layer kind"):
-            _cfg(layer_pattern=("sliding", "linear"))
+            _cfg(layer_pattern=("sliding", "ring"))
         with pytest.raises(ValueError, match="periods"):
             _cfg(n_layers=6)
         with pytest.raises(ValueError, match="window"):
