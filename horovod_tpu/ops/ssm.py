@@ -15,8 +15,10 @@ by the heads of one GROUP (head ``h`` reads group ``h // (H / G)``)::
   scan carries; the update reads and writes layer ``layer`` of it IN
   PLACE and nothing has a result the size of a layer's states.  Its
   cost is the state's bytes, read once and written once.  ``kernel=
-  True`` is the Pallas kernel ``hvd_ssm_update`` (a block a slot and
-  group, aliased onto its operand; the read-out ``S C`` on the MXU),
+  True`` is the Pallas kernel ``hvd_ssm_update`` (a grid step takes
+  as many whole groups of one slot as ``_BLOCK_BYTES`` of stored state
+  hold — the shape alone decides — aliased onto its operand; the
+  read-out ``S C`` on the MXU, each group against its own ``C``),
   ``False`` the same arithmetic as XLA operations.
 * :func:`ssm_scan` — a prompt or a chunk of one, FROM a given state TO
   the state after its last token, in the chunked dual form: inside a
@@ -44,40 +46,60 @@ __all__ = ["UPDATE_NAME", "ssm_update", "ssm_scan"]
 #: The tick kernel's name on a device trace (``pl.pallas_call(name=)``).
 UPDATE_NAME = "hvd_ssm_update"
 
+# What one grid step of the tick kernel takes of a slot's STORED states:
+# as many whole groups as fit.  A step costs ~0.3 us of its own beside
+# its bytes, so on a v5e the kernel alone moves, of MiniCPM-SALA's
+# float32 heads of 128 x 128 (64 KiB a group), 283 GB/s at a group a
+# step, 488 at 256 KiB, 580 at 512, 639 at 1 MiB, 642 at 2 MiB (a whole
+# slot), and of Falcon-H1's groups of 16 bfloat16 heads of 128 x 256
+# (1 MiB) 648 at one a step, 650 at both (PERF.md, PR 47): past 1 MiB
+# nothing is gained, and twice the VMEM is held.
+_BLOCK_BYTES = 1024 * 1024
+
+
+def _groups_a_step(G: int, group_bytes: int) -> int:
+    """The largest divisor of ``G`` whose groups' stored states stay at
+    or under ``_BLOCK_BYTES`` (one group where one alone is over)."""
+    fit = max(1, _BLOCK_BYTES // group_bytes)
+    return max(d for d in range(1, G + 1) if G % d == 0 and d <= fit)
+
 
 def _update_kernel(layer_ref, h_ref, da_ref, dtx_ref, b_ref, c_ref,
                    h_out, y_out):
     del layer_ref                      # (the index maps read it)
-    hg, p, n = h_ref.shape
-    new = (da_ref[...][:, :, None] * h_ref[...].astype(jnp.float32)
-           + dtx_ref[...][:, :, None] * b_ref[...][None])
-    h_out[...] = new.astype(h_out.dtype)
-    # y[h, p] = sum_n new[h, p, n] c[n]: the reduction over the lanes is
-    # the MXU's, (1, N) x (Hg P, N)^T, and comes back lane-dense
-    y_out[...] = lax.dot_general(
-        c_ref[...], new.reshape(hg * p, n), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    gb, hg, p = da_ref.shape
+    n = h_ref.shape[-1]
+    for g in range(gb):                # each group its OWN b and c
+        heads = pl.ds(g * hg, hg)
+        new = (da_ref[g][:, :, None] * h_ref[heads].astype(jnp.float32)
+               + dtx_ref[g][:, :, None] * b_ref[g][None])
+        h_out[heads] = new.astype(h_out.dtype)
+        # y[h, p] = sum_n new[h, p, n] c[n]: the reduction over the
+        # lanes is the MXU's, (1, N) x (Hg P, N)^T, and comes back
+        # lane-dense
+        y_out[g] = lax.dot_general(
+            c_ref[g], new.reshape(hg * p, n), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
 
 def _update_pallas(states, layer, da, dtx, b, c):
     L, S, H, P, N = states.shape
     G = b.shape[1]
     hg = H // G
+    gb = _groups_a_step(G, hg * P * N * states.dtype.itemsize)
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
-    row = pl.BlockSpec((None, None, hg, P), lambda s, g, l: (s, g, 0, 0))
-    vec = pl.BlockSpec((None, None, 1, N), lambda s, g, l: (s, g, 0, 0))
+    held = pl.BlockSpec((None, None, gb * hg, P, N),
+                        lambda s, j, l: (l[0], s, j, 0, 0))
+    row = pl.BlockSpec((None, gb, hg, P), lambda s, j, l: (s, j, 0, 0))
+    vec = pl.BlockSpec((None, gb, 1, N), lambda s, j, l: (s, j, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(S, G),
-        in_specs=[
-            pl.BlockSpec((None, None, hg, P, N),
-                         lambda s, g, l: (l[0], s, g, 0, 0)),
-            row, row, vec, vec],
+        grid=(S, G // gb),
+        in_specs=[held, row, row, vec, vec],
         out_specs=[
-            pl.BlockSpec((None, None, hg, P, N),
-                         lambda s, g, l: (l[0], s, g, 0, 0)),
-            pl.BlockSpec((None, None, 1, hg * P),
-                         lambda s, g, l: (s, g, 0, 0))],
+            held,
+            pl.BlockSpec((None, gb, 1, hg * P),
+                         lambda s, j, l: (s, j, 0, 0))],
     )
     bcast = jnp.broadcast_to(da[..., None], dtx.shape)
     new, y = pl.pallas_call(
@@ -90,8 +112,12 @@ def _update_pallas(states, layer, da, dtx, b, c):
         input_output_aliases={1: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
-            # a block of states in and out, twice each, and the float32
-            # forms of one: 1 MiB a block of 16 heads of 128 x 256
+            # a step's states in and out, two buffers each, and the
+            # float32 forms of ONE group (a bfloat16 group's are twice
+            # its stored bytes): 4.5 MB at 1 MiB a step of 64 KiB
+            # groups, 5.5 MB at one bfloat16 group of 1 MiB, by the
+            # compiler's count; the rest is room for a group that is
+            # over _BLOCK_BYTES alone
             vmem_limit_bytes=48 * 1024 * 1024),
         interpret=use_interpret(),
         name=UPDATE_NAME,

@@ -27,7 +27,13 @@ for names); the other entries are what the parent printed.
 ``tests/data/served_program_digests_pr45.json`` holds what the tree
 before PR 46 (linear-attention and block-sparse layers) printed for the
 SIX, the hybrid architecture among them; ``tests/test_linear_sparse_
-layers.py`` holds this tree to that."""
+layers.py`` holds this tree to that.  ONE entry of that file is PR
+47's: ``hybrid`` / ``tick``, whose state update (``ops/ssm.py``'s
+kernel) takes as many groups of a slot a grid step as 1 MiB of stored
+state holds — at this size both groups, grid ``(S, 1)`` for ``(S, 2)``,
+a loop over the groups in the kernel's body — and is otherwise the
+equations the parent traced; its chunk and prompt and the other five
+architectures' programs are what the parent printed."""
 
 import hashlib
 import json

@@ -264,6 +264,74 @@ class TestTheTwoBodies:
                 np.asarray(new[1, s], np.float32), hs,
                 atol=2e-5 if dtype == jnp.float32 else 3e-2, rtol=1e-5)
 
+    # (H, G, _BLOCK_BYTES, groups a step): a group of float32 states is
+    # H / G x 8 x 16 x 4 bytes, of bfloat16 half that
+    BLOCKS = {
+        "a_group_a_head_several_a_step": (8, 8, 2048, {4: 4, 2: 8}),
+        "groups_of_heads_one_a_step": (4, 2, 1024, {4: 1, 2: 2}),
+        "a_count_the_bytes_do_not_divide": (6, 6, 2560, {4: 3, 2: 6}),
+        "the_whole_slot_a_step": (8, 8, 1 << 20, {4: 8, 2: 8}),
+        "a_group_over_the_block_alone": (4, 2, 256, {4: 1, 2: 1}),
+    }
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("case", list(BLOCKS))
+    def test_the_tick_kernel_takes_a_block_by_its_bytes(
+            self, highest, monkeypatch, case, dtype):
+        """A grid step of the kernel takes as many whole GROUPS of a
+        slot as ``_BLOCK_BYTES`` of stored state hold (the largest
+        divisor of ``G``): whatever that count, the active rows step the
+        recurrence — every group with its OWN ``b`` and ``c`` — the
+        idle rows and every other layer keep their states to the bit,
+        and the stored states are the XLA body's."""
+        H, G, block, groups = self.BLOCKS[case]
+        monkeypatch.setattr(SSM, "_BLOCK_BYTES", block)
+        size = jnp.dtype(dtype).itemsize
+        gb = SSM._groups_a_step(G, H // G * 8 * 16 * size)
+        assert gb == groups[size] and G % gb == 0
+        z = self._case(B=1, S=5, H=H, G=G)
+        b, c = z["b"][0], z["c"][0]
+        assert all(np.abs(np.asarray(v[:, i] - v[:, j])).max() > 0.1
+                   for v in (b, c) for i in range(G) for j in range(i))
+        states = jax.random.normal(jax.random.PRNGKey(9), (3, 5, H, 8, 16)
+                                   ).astype(dtype)
+        active = jnp.asarray([True, False, True, True, False])
+        args = (states, jnp.int32(1), z["x"][0], z["dt"][0], z["a"], b, c,
+                active)
+        y, new = SSM.ssm_update(*args, kernel=True)
+        y_xla, new_xla = SSM.ssm_update(*args, kernel=False)
+        assert new.dtype == dtype and y.dtype == jnp.float32
+        np.testing.assert_array_equal(new[0], states[0])
+        np.testing.assert_array_equal(new[2], states[2])
+        np.testing.assert_array_equal(new[1][~active], states[1][~active])
+        # the state's arithmetic an element is one multiply-add and one
+        # store on either side (a compiler may fuse the two: an ulp)
+        np.testing.assert_allclose(np.asarray(new, np.float32),
+                                   np.asarray(new_xla, np.float32),
+                                   atol=1e-6 if size == 4 else 3e-2,
+                                   rtol=1e-6)
+        for s in np.nonzero(np.asarray(active))[0]:
+            hs, ys = self._step(states[1, s].astype(jnp.float32), z["x"][0, s],
+                                z["dt"][0, s], z["a"], b[s], c[s])
+            np.testing.assert_allclose(y[s], ys, atol=2e-5, rtol=1e-5)
+            np.testing.assert_allclose(y[s], y_xla[s], atol=2e-5, rtol=1e-5)
+            np.testing.assert_allclose(
+                np.asarray(new[1, s], np.float32), hs,
+                atol=2e-5 if size == 4 else 3e-2, rtol=1e-5)
+
+    @pytest.mark.parametrize("heads, dtype, G, groups", [
+        ((32, 128, 128), jnp.float32, 32, 16),     # minicpm-sala-serve
+        ((32, 128, 256), jnp.bfloat16, 2, 1),      # falcon-h1-34b-serve
+    ])
+    def test_the_block_at_the_served_shapes(self, heads, dtype, G, groups):
+        """1 MiB of stored state a step: sixteen of MiniCPM-SALA's
+        float32 heads (64 KiB each, a group a head), ONE of Falcon-H1's
+        two groups of sixteen bfloat16 heads."""
+        H, P, N = heads
+        group = H // G * P * N * jnp.dtype(dtype).itemsize
+        assert SSM._groups_a_step(G, group) == groups
+        assert groups * group == SSM._BLOCK_BYTES == 1 << 20
+
 
 class TestTheStateUnderTheCacheManager:
     def test_pool_holds_both_states_beside_the_pages(self, model):

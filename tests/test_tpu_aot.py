@@ -818,6 +818,36 @@ def test_the_conv_cells_reference_fits_the_chip_in_one_width(one_chip, half):
     assert "while" in compiled.as_text()    # the rows below n, no more
 
 
+def _kernel_windows(text, name):
+    """``[(grid, block of the first array operand)]`` of every Mosaic
+    call named ``name`` in a compiled program's text: the serialised
+    kernel's ``iteration_bounds`` and first ``window_bounds`` (the
+    squeezed dimensions read 1)."""
+    import base64
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    def ints(attr, asm):
+        found = re.search(attr + r" = array<i64: ([\d, ]+)>", asm)
+        return tuple(int(d) for d in found.group(1).split(","))
+
+    out = []
+    for line in text.splitlines():
+        if ('custom_call_target="tpu_custom_call"' not in line
+                or f"/{name}/" not in line):
+            continue
+        body = base64.b64decode(re.search(r'"body":"([^"]*)"', line).group(1))
+        ctx = mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            asm = ir.Module.parse(body).operation.get_asm(
+                enable_debug_info=False)
+        out.append((ints("iteration_bounds", asm),
+                    ints("window_bounds", asm)))
+    return out
+
+
 def _falcon_h1():
     import json
 
@@ -885,6 +915,10 @@ def test_the_served_hybrid_programs_compile_at_the_published_widths(
         text = compiled.as_text()
         assert chip_smoke.kernel_calls(text, PA.KERNEL_NAME) == 1
         assert chip_smoke.kernel_calls(text, SSM.UPDATE_NAME) == 1
+        # ONE group of sixteen bfloat16 heads a step: 1 MiB, the grid
+        # and block the kernel had when a step took a group
+        assert _kernel_windows(text, SSM.UPDATE_NAME) == [
+            ((64, 2), (1, 1, 16, 128, 256))]
         for name in ("ssm", "k"):   # no copy the size of a layer of either
             layer = pool[name].size // pool[name].shape[0]
             offenders, largest = chip_smoke.pool_sized_results(text, layer)
@@ -1032,6 +1066,10 @@ def test_the_served_linear_sparse_programs_compile_at_the_published_widths(
         text = compiled.as_text()
         assert chip_smoke.kernel_calls(text, PA.BSA_KERNEL_NAME) == 3
         assert chip_smoke.kernel_calls(text, SSM.UPDATE_NAME) == 9
+        # SIXTEEN groups of one float32 head a step: 1 MiB, 96 steps a
+        # layer (a group a step was 1536 of 64 KiB)
+        assert _kernel_windows(text, SSM.UPDATE_NAME) == 9 * [
+            ((48, 2), (1, 1, 16, 128, 128))]
         for name in ("lin", "k"):   # no copy the size of a layer of either
             layer = pool[name].size // pool[name].shape[0]
             offenders, largest = chip_smoke.pool_sized_results(text, layer)
