@@ -94,9 +94,13 @@ class LayerKind:
     ``paged``: the pool arrays a page table indexes, ``{name: cfg ->
     (heads, width)}`` of ``(L, P, heads, page, width)``; ``scales``: an
     int8 pool's per-vector scales beside them, in their order;
-    ``page_rows``: pool arrays of one row A PAGE under the same page
-    table, ``{name: cfg -> width}`` of ``(L, P, width)`` (a summary of
-    the page's tokens that a landing and the tick write with them);
+    ``page_rows``: pool arrays of one row a page of a SLOT's table,
+    ``{name: cfg -> (heads, width)}`` of ``(L, S, heads, max_pages,
+    width)`` — a summary of the page's tokens that a landing and the
+    tick write with them at the page's LOGICAL index, so that the tick
+    reads a slot's rows as they lie; a page of such a kind has one
+    owner (no prefix is shared), and a slot's row 0 is never read and
+    takes what the NULL page takes of the pages;
     ``state``: what a SLOT holds whatever its context, ``{name: cfg ->
     shape}`` of ``(L, S) + shape``, in the pool's dtype unless named in
     ``f32`` (a state that every token multiplies on: kept in float32);
@@ -564,7 +568,7 @@ class TransformerConfig:
     @property
     def has_bsa(self) -> bool:
         """Does any layer select blocks by compressed keys (a row a
-        page, ``pool["ck"]``, beside its pages)?"""
+        page of a slot's table, ``pool["ck"]``, beside its pages)?"""
         return "block_sparse" in self.layer_pattern
 
     @property
@@ -1596,9 +1600,11 @@ def _lin_decode(q, k, v, p, states, layer, active, kernel: bool):
 # head keeps a COMPRESSED key for every whole window of ``bsa_kernel``
 # tokens, one every ``bsa_stride`` — the mean of the window's keys; the
 # page is the stride, so window ``j`` (tokens ``16 j .. 16 j + 31`` at
-# the published sizes) ends with page ``j + 1`` and its row lies THERE:
-# ``ck[page r]`` is the mean over pages ``r - 1`` and ``r``, written
-# when page ``r`` fills, and page 0's row is never read.  A query at
+# the published sizes) ends with page ``j + 1`` and its row lies THERE,
+# by the SLOT and the page's logical index (no page of this kind is
+# shared, and the tick is the rows' one reader): ``ck[slot, head, r]``
+# is the mean over pages ``r - 1`` and ``r``, written when page ``r``
+# fills, and row 0 is never read.  A query at
 # position ``t`` whose context ``t + 1`` is longer than ``bsa_dense_len``
 # scores the rows it sees whole (``(r + 1) stride <= t + 1``): ``softmax_r
 # (q_h . c_r / sqrt(Dh))`` a head, summed over the query heads of the KV
@@ -1762,11 +1768,10 @@ def _bsa_rows_attend(qh, k_log, v_log, pos, cfg: TransformerConfig):
 def _bsa_landing_rows(rows, p0, S0: int, cfg: TransformerConfig):
     """Of the compressed rows by page ``(K, Hkv, nP, Dh)``, those of the
     pages a block of ``S0`` tokens from position ``p0`` lands in, as the
-    pool stores a row: ``(K, landing pages, Hkv * Dh)``."""
+    pool lays a slot's: ``(K, Hkv, landing pages, Dh)``."""
     n_pg = -(-(S0 + cfg.bsa_stride - 1) // cfg.bsa_stride)
     rows = jnp.pad(rows, ((0, 0), (0, 0), (0, n_pg), (0, 0)))
-    rows = lax.dynamic_slice_in_dim(rows, p0 // cfg.bsa_stride, n_pg, 2)
-    return jnp.moveaxis(rows, 1, 2).reshape(rows.shape[0], n_pg, -1)
+    return lax.dynamic_slice_in_dim(rows, p0 // cfg.bsa_stride, n_pg, 2)
 
 
 # --- latent attention (MLA) ---------------------------------------------------
@@ -3034,8 +3039,9 @@ class _Tick(_Prompt):
     STACKED arrays of the layer scan's carry and ``layer`` this layer's
     (traced) index among its kind's — every write lands at ``[layer,
     page]`` or ``[layer, slot]`` and every read takes ``[layer,
-    table]``, so no operation cuts a layer out of a stack or has a
-    result of its size.  Row ``s`` writes its K/V (or latent row) at
+    table]`` (or, of what lies by slot, a layer as the operand of the
+    product that reads it), so no operation cuts a layer out of a stack
+    or has a result of its size.  Row ``s`` writes its K/V (or latent row) at
     logical position ``pos[s]`` — through the page table to ``(page
     table[s, pos // page], offset pos % page)`` — BEFORE it attends
     positions ``<= pos[s]``, so the attend sees exactly the pool's
@@ -3082,10 +3088,11 @@ class _Tick(_Prompt):
 
     def select_attend(self, qh, k_t, v_t, kind: LayerKind):
         """K and V written as :meth:`attend` writes them, and the row of
-        the page that the write FILLS (from the pool's own two pages);
-        then the compressed rows scored through the table, the blocks
-        selected, their pages compacted into a table a slot and KV head
-        and attended by the fused kernel — the pool read by BLOCK."""
+        the page that the write FILLS (from the pool's own two pages) at
+        the page's logical index of the slot's rows; then the slot's
+        compressed rows scored AS THEY LIE, the blocks selected, their
+        pages compacted into a table a slot and KV head and attended by
+        the fused kernel — the pool read by BLOCK."""
         from horovod_tpu.ops import paged_attention as _pa
 
         cfg, pos, table, layer = self.cfg, self.pos, self.table, self.layer
@@ -3099,24 +3106,31 @@ class _Tick(_Prompt):
             raise UnsupportedModelConfigError(
                 f"a block-sparse layer's page is its compressed keys' "
                 f"stride ({cfg.bsa_stride}), not {ps}")
+        if ck.shape[1:4] != (S, Hkv, max_pages):
+            raise ValueError(
+                f"the compressed keys lie by slot, {ck.shape[1:4]}, under a "
+                f"table of {(S, Hkv, max_pages)}: init_page_pool is told "
+                f"the table's width")
         with jax.named_scope("kv_write"):
             phys, take = self._target(table, ps)
             k_pool = _pa.write_pages(k_pool, layer, phys, k_t, take)
             v_pool = _pa.write_pages(v_pool, layer, phys, v_t, take)
-            # the window that ends with this page, once the page is full
+            # the window that ends with this page, once the page is
+            # full; an idle row's, or one whose page is not, goes to the
+            # slot's own row 0, which no query reads (the NULL page's part)
             at = jnp.clip(pos // ps, 0, max_pages - 1)
             before = table[jnp.arange(S), jnp.maximum(at - 1, 0)]
             row = _bsa_window_mean(k_pool[layer, before], k_pool[layer, phys],
-                                   cfg).reshape(S, Hkv * Dh)
-            full = self.active & (pos % ps == ps - 1) & (at >= 1)
-            ck = ck.at[layer, jnp.where(full, phys, 0)].set(
+                                   cfg)                       # (S, Hkv, Dh)
+            full = self.active & (pos % ps == ps - 1)
+            ck = ck.at[layer, jnp.arange(S)[:, None], jnp.arange(Hkv),
+                       jnp.where(full, at, 0)[:, None]].set(
                 row.astype(ck.dtype))
         qg = qh.reshape(S, Hkv, H // Hkv, 1, Dh)
         live = jnp.where(self.active, pos, -1)
         with jax.named_scope("hvd_bsa_score"):
-            rows = ck[layer, table].reshape(S, max_pages, Hkv, Dh)
-            score = _bsa_block_scores(qg, jnp.moveaxis(rows, 1, 2),
-                                      live[:, None], -(-max_pages // m), cfg)
+            score = _bsa_block_scores(qg, ck[layer], live[:, None],
+                                      -(-max_pages // m), cfg)
         R = S * Hkv
         with jax.named_scope("hvd_bsa_select"):
             chosen, n_sel = _bsa_chosen(
@@ -3330,7 +3344,7 @@ LAYER_KINDS = {
         state={"lin": lambda c: (c.n_heads, c.head_dim, c.head_dim)}),
     "block_sparse": LayerKind(
         _bsa_mixer, {"k": _kv_row, "v": _kv_row},
-        page_rows={"ck": lambda c: c.kv_heads * c.head_dim}),
+        page_rows={"ck": lambda c: (c.kv_heads, c.head_dim)}),
     "latent": LayerKind(_latent_mixer, {"k": lambda c: (1, c.latent_row)}),
     "sparse": LayerKind(_latent_mixer, {
         "k": lambda c: (1, c.latent_row),

@@ -86,16 +86,19 @@ def _arrays(pool: Dict, role: str) -> List[str]:
 
 
 def init_page_pool(cfg: "T.TransformerConfig", n_slots: int, n_pages: int,
-                   page_size: int, kv_dtype=None, n_layers=None) -> Dict:
+                   page_size: int, kv_dtype=None, n_layers=None,
+                   max_pages: int = 0) -> Dict:
     """The paged device cache: what the configuration's kinds of layer
     declare (:data:`~horovod_tpu.models.transformer.LAYER_KINDS`) —
     every paged array a page pool ``(L, P, heads, page, width)`` (``P``
     counts the NULL page; ``k``/``v``, or a latent model's ONE array of
     rows and an indexer's keys under the same page table), every array
-    of a row a page ``(L_kind, P, width)`` (a block-sparse layer's
-    compressed keys), every per-slot state ``(L_kind, S, ...)`` (in the
-    pool's dtype, or float32 where the kind says so) — and ``pos``, the
-    per-slot ``(S,)`` logical write position.
+    of a row a page of a SLOT's table ``(L_kind, S, heads, max_pages,
+    width)`` (a block-sparse layer's compressed keys, by the page's
+    logical index; ``max_pages`` is the table's width, 0 = what
+    ``cfg.max_seq`` takes), every per-slot state ``(L_kind, S, ...)``
+    (in the pool's dtype, or float32 where the kind says so) — and
+    ``pos``, the per-slot ``(S,)`` logical write position.
     int8 storage adds ``k_scale``/``v_scale`` ``(L, P, H_kv, page)``
     per-vector f32 scales.  The page table itself is HOST state
     (:attr:`PagedSlotCache.table`), uploaded as data each tick.
@@ -124,9 +127,11 @@ def init_page_pool(cfg: "T.TransformerConfig", n_slots: int, n_pages: int,
                 if quant:
                     pool[scale[name]] = jnp.zeros(
                         (L, n_pages, heads, page_size), jnp.float32)
-        for name, width in kind.page_rows.items():   # a row A PAGE
+        for name, row in kind.page_rows.items():   # a row a page of a SLOT
+            heads, width = row(cfg)
             pool[name] = jnp.zeros(
-                (cfg.layers_with(name), n_pages, width(cfg)), dt)
+                (cfg.layers_with(name), n_slots, heads,
+                 max_pages or -(-cfg.max_seq // page_size), width), dt)
         for name, shape in kind.state.items():
             pool[name] = jnp.zeros(
                 (cfg.layers_with(name), n_slots) + shape(cfg),
@@ -173,10 +178,13 @@ def paged_insert(pool: Dict, slots, new_pos, pages, first, lens,
     column is the NULL page.  int8 pools quantize per vector on the way
     in; payload and scale go through the same :func:`write_pages`.  A
     pool whose rows several narrow KV heads share takes the block a row
-    a head and lays them side by side.  An array of a row A PAGE takes
-    ``(L, K, landing pages, width)``, the row of each page the landing
-    touches, and keeps those of the pages it fills.  A per-slot STATE ``(L_kind, K,
-    ...)`` — each row's at its new position — replaces its slot's.
+    a head and lays them side by side.  An array of a row a page of a
+    SLOT's table takes ``(L, K, heads, landing pages, width)``, the row
+    of each page the landing touches, and keeps those of the pages it
+    FILLS, at the page's logical index ``(new_pos - lens) // page + j``
+    of its slot's rows; the others' go to the slot's row 0, which is
+    never read.  A per-slot STATE ``(L_kind, K, ...)`` — each row's at
+    its new position — replaces its slot's.
     ``slots`` / ``new_pos`` adopt the per-row positions (empty for
     slotless landings — prefix registration)."""
     paged, scales = _arrays(pool, "paged"), _arrays(pool, "scales")
@@ -198,15 +206,24 @@ def paged_insert(pool: Dict, slots, new_pos, pages, first, lens,
         rows.update(zip(scales, (s for _, s in quant.values())))
     out = {**pool, **{n: land(n, x) for n, x in rows.items()}}
     for n in _arrays(pool, "page_rows"):
-        # ``(L, K, n_pg, width)``: the row of each page this landing
-        # FILLS (its last offset is a landed column); the others' to
-        # the NULL page
-        done = ((jnp.arange(n_pg, dtype=jnp.int32) + 1) * ps
-                <= first + jnp.asarray(lens, jnp.int32)[:, None])
+        if len(slots) != len(lens):
+            raise T.UnsupportedModelConfigError(
+                f"a slotless landing (prefix registration) has no slot "
+                f"whose rows would take {n!r}: no page of such a pool is "
+                f"shared")
+        # ``(L, K, heads, n_pg, width)``: the row of each page this
+        # landing FILLS (its last offset is a landed column) at the
+        # page's logical index; the others' to the slot's row 0
+        end = jnp.asarray(new_pos, jnp.int32)[:, None]
+        page = ((end - jnp.asarray(lens, jnp.int32)[:, None]) // ps
+                + jnp.arange(n_pg, dtype=jnp.int32))
+        at = jnp.where((page + 1) * ps <= end, page, 0)
+        L, _, heads = pool[n].shape[:3]
         out[n] = pool[n].at[
-            jnp.arange(pool[n].shape[0], dtype=jnp.int32)[:, None, None],
-            jnp.where(done, pages, NULL_PAGE)[None]].set(
-                block[n].astype(pool[n].dtype))
+            jnp.arange(L, dtype=jnp.int32)[:, None, None, None],
+            jnp.asarray(slots, jnp.int32)[:, None, None],
+            jnp.arange(heads, dtype=jnp.int32)[:, None],
+            at[:, None]].set(block[n].astype(pool[n].dtype))
     for n in _arrays(pool, "state"):
         out[n] = pool[n].at[:, slots].set(block[n].astype(pool[n].dtype))
     out["pos"] = pool["pos"].at[slots].set(new_pos)
@@ -224,8 +241,8 @@ def copy_page(pool: Dict, src, dst) -> Dict:
         layer = jnp.arange(a.shape[0], dtype=jnp.int32)
         out[name] = write_pages(a, layer, dst, a[layer, src],
                                 jnp.ones((1, a.shape[3]), bool))
-    for name in _arrays(pool, "page_rows"):
-        out[name] = pool[name].at[:, dst].set(pool[name][:, src])
+    # (an array of a row a page lies by slot: no page of a pool that
+    # holds one is shared, so none is ever copied)
     return out
 
 
@@ -310,7 +327,8 @@ class PagedSlotCache:
         self._storage_dtype, self.quantized = resolve_kv_dtype(
             cfg, kv_dtype)
         self.cache = init_page_pool(cfg, n_slots, self.n_pages + 1,
-                                    page_size, kv_dtype, self.n_layers)
+                                    page_size, kv_dtype, self.n_layers,
+                                    self.max_pages)
         self.slot_pages_max = 0  # most pages one slot ever held at once
         if mesh is not None:
             self.cache = T.shard_kv_pool(self.cache, mesh)
@@ -466,8 +484,9 @@ class PagedSlotCache:
     @property
     def compressed_bytes_per_page(self) -> int:
         """What a page holds beside its tokens' rows: every
-        block-sparse layer's compressed key a KV head (0: none)."""
-        return self._bytes(T.LAYER_KINDS["block_sparse"].page_rows, 1)
+        block-sparse layer's compressed key a KV head (0: none), in
+        its slot's rows."""
+        return self._bytes(T.LAYER_KINDS["block_sparse"].page_rows, 1, 3)
 
     @property
     def latent_bytes_per_token(self) -> int:

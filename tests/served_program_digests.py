@@ -122,7 +122,7 @@ def programs(cfg):
 
     def pool(layers):
         return jax.eval_shape(lambda: C.init_page_pool(
-            cfg, SLOTS, n_pg, PAGE, None, layers))
+            cfg, SLOTS, n_pg, PAGE, None, layers, MAX_LEN // PAGE))
 
     full = pool(cfg.layers_with("k"))
     table = jnp.zeros((SLOTS, MAX_LEN // PAGE), jnp.int32)

@@ -297,6 +297,35 @@ def test_pool_sized_results_reads_a_compiled_program():
         layer, "probe")
 
 
+_HLO_PREFETCH = """HloModule jit__tick, is_scheduled=true
+
+ENTRY %main (w: bf16[3,32,128,4096]) -> bf16[3,32,128,4096] {
+  %w = bf16[3,32,128,4096]{3,2,1,0:T(8,128)(2,1)} parameter(0)
+  %slice-start.1 = ((bf16[3,32,128,4096]{3,2,1,0:T(8,128)(2,1)}), bf16[1,32,128,4096]{3,2,1,0:T(8,128)(2,1)S(1)}, s32[]{:S(2)}) slice-start(%w), slice={[0:1], [0:32], [0:128], [0:4096]}
+  %slice-done.1 = bf16[1,32,128,4096]{3,2,1,0:T(8,128)(2,1)S(1)} slice-done(%slice-start.1)
+  ROOT %custom-call.7 = bf16[3,32,128,4096]{3,2,1,0:T(8,128)(2,1)S(1)} custom-call(%slice-done.1, %slice-done.1, %slice-done.1), custom_call_target="ConcatBitcast", backend_config={"aliasing_operands":{"lists":[]}}
+}
+"""
+
+
+@pytest.mark.parametrize("sliced,offends", [(1, False), (2, True)])
+def test_pool_sized_results_reads_an_asynchronous_slice_by_its_result(
+        sliced, offends):
+    """A weight stack prefetched a layer at a time (the sala tick since
+    PR 48: the fast memory the gathered rows held went to ``wo``): an
+    asynchronous slice's type restates its OPERAND — the whole stack —
+    before its result, and the slices' results are seen as one array
+    through a ``ConcatBitcast``; neither is a result of the stack's
+    size.  A slice whose own result is that large still is."""
+    text = _HLO_PREFETCH.replace("bf16[1,32", f"bf16[{sliced},32")
+    offenders, largest = chip_smoke.pool_sized_results(
+        text, 2 * 32 * 128 * 4096)
+    assert [o[2] for o in offenders] == (
+        ["slice-start.1", "slice-done.1"] if offends else [])
+    assert largest[0][:3] == (sliced * 32 * 128 * 4096, "slice-start",
+                              "slice-start.1")
+
+
 _HLO_PICK = """HloModule jit__tick, is_scheduled=true
 
 %compare (a: f32[], b: f32[]) -> pred[] {

@@ -43,6 +43,10 @@ def test_the_tables_arrays_are_the_pools_the_blocks_and_the_prefixs(name):
                                cfg.kind_count("sliding"))
         pool.update((w, own[n]) for w, n in T.WINDOW_ARRAYS.items())
     assert set(pool) - {"pos"} == declared
+    for kind in cfg.kinds.values():   # a row a page of a SLOT's table
+        for n, row in kind.page_rows.items():
+            assert pool[n].shape == (cfg.layers_with(n), 2, row(cfg)[0],
+                                     cfg.max_seq // PAGE, row(cfg)[1])
     params = jax.eval_shape(lambda: T.init_params(jax.random.PRNGKey(0), cfg))
     ids, lens = jnp.zeros((1, CHUNK), jnp.int32), jnp.full((1,), CHUNK)
     # a whole prompt's block (a cache of one kind of pages takes it whole)

@@ -1,9 +1,10 @@
 """LINEAR-ATTENTION layers between BLOCK-SPARSE attention layers, served
 (MiniCPM-SALA's two blocks): a fifth and a sixth layer kind — a float32
 matrix state a head and slot beside a pool of another dtype and no pages;
-pages of K and V with a third pool array of ONE ROW A PAGE, the
-compressed keys whose scores choose the blocks a query attends — under
-the published muP scales.
+pages of K and V with a third pool array of ONE ROW A PAGE of a SLOT's
+table (by the page's logical index: the tick reads a slot's rows as they
+lie), the compressed keys whose scores choose the blocks a query attends
+— under the published muP scales.
 
 The program's LOGITS are held to ``horovod_tpu.models.plain_reference``
 (``sala_forward``: straightforward float32 ``jax.numpy``, the recurrence
@@ -34,6 +35,7 @@ from horovod_tpu import serving
 from horovod_tpu.models import plain_reference as R
 from horovod_tpu.models import transformer as T
 from horovod_tpu.ops import ssm as SSM
+from horovod_tpu.serving import cache as C
 
 from test_window_layers import _LogitTap
 
@@ -192,9 +194,10 @@ class TestCompressedKeys:
     def test_every_full_pages_row_is_the_mean_of_its_windows_keys(
             self, model, highest):
         """After RAGGED landings (chunks of 7 over pages of 4) and some
-        ticks: ``ck[page r]`` of every full page ``r >= 1`` of a live
-        slot is the mean over pages ``r - 1`` and ``r`` of the keys the
-        pool holds, a layer and KV head."""
+        ticks: ``ck[slot, head, r]`` of every full page ``r >= 1`` of a
+        live slot — the page's LOGICAL index, whatever physical page the
+        table names there — is the mean over pages ``r - 1`` and ``r``
+        of the keys the pool holds, a layer and KV head."""
         params, cfg = model
         engine = _engine(params, cfg, prefill_chunk_tokens=7)
         futs = [engine.submit(p, max_new_tokens=30)
@@ -203,17 +206,178 @@ class TestCompressedKeys:
             engine.step()
         pool, table = engine.slots.cache, engine.slots.table
         k, ck = np.asarray(pool["k"]), np.asarray(pool["ck"])
+        assert ck.shape == (2, 3, 2, engine.slots.max_pages, 16)
         pos = np.asarray(pool["pos"])
         checked = 0
         for s in range(2):
             for r in range(1, int(pos[s]) // 4):
                 two = k[:, [table[s, r - 1], table[s, r]]]  # (L,2,Hkv,4,Dh)
-                want = two.mean(axis=(1, 3)).reshape(2, -1)
-                np.testing.assert_allclose(ck[:, table[s, r]], want,
-                                           atol=1e-6)
+                np.testing.assert_allclose(
+                    ck[:, s, :, r], two.mean(axis=(1, 3)), atol=1e-6)
                 checked += 1
         assert checked > 20
+        assert not ck[:, 2].any()       # the slot no request was granted
         _run(engine, futs)
+
+    def test_the_tick_scores_a_slots_rows_as_they_lie(self, monkeypatch,
+                                                      highest):
+        """One tick of four slots over rows laid by slot — a context
+        within ``dense_len`` whose page fills in THIS tick, one beyond
+        it whose page fills in this tick, one beyond it mid-page, an
+        idle slot — under a page table that scatters the physical
+        pages: the block scores and the chosen blocks are those of
+        ``_bsa_compress`` of the keys in logical order, the two filled
+        pages' rows are written at their logical index, and nothing else
+        of the array moves but the slots' rows 0.  Every row that no
+        landing or tick has written yet holds NaN: none is scored."""
+        cfg = _cfg()
+        kind, ps, mp, layer = cfg.kind("block_sparse"), 4, 24, 1
+        rng = np.random.default_rng(4)
+        pos = np.asarray([19, 43, 57, 30], np.int32)
+        active = np.asarray([True, True, True, False])
+        k_log, v_log = (rng.normal(size=(4, 2, mp * ps, 16)).astype(
+            np.float32) for _ in range(2))
+        q = jnp.asarray(rng.normal(size=(4, 4, 1, 16)), jnp.float32)
+        table = 1 + rng.permutation(4 * mp).reshape(4, mp).astype(np.int32)
+        pool = C.init_page_pool(cfg, 4, 4 * mp + 1, ps, None, 2, mp)
+        rows = np.asarray(T._bsa_compress(jnp.asarray(k_log), cfg))
+        k, v = np.zeros(pool["k"].shape, np.float32), np.zeros(
+            pool["v"].shape, np.float32)
+        ck = np.full(pool["ck"].shape, np.nan, np.float32)
+        for s in range(4):      # what the landings and ticks before left
+            held = np.arange(mp * ps) < pos[s]
+            for log, arr in ((k_log, k), (v_log, v)):
+                arr[layer, table[s]] = np.moveaxis(
+                    np.where(held[:, None], log[s], 0).reshape(2, mp, ps, 16),
+                    0, 1)
+            full = np.arange(1, pos[s] // ps)
+            ck[layer, s, :, full] = np.moveaxis(rows[s][:, full], 1, 0)
+        seen = {}
+        for name in ("_bsa_block_scores", "_bsa_chosen"):
+            def tapped(*a, _f=getattr(T, name), _n=name):
+                seen[_n] = _f(*a)
+                return seen[_n]
+
+            monkeypatch.setattr(T, name, tapped)
+        tick = T._Tick(cfg, jnp.asarray(table), None, jnp.asarray(pos),
+                       jnp.asarray(active), False, None).at(
+            pools={"k": jnp.asarray(k), "v": jnp.asarray(v),
+                   "ck": jnp.asarray(ck)}, layer=jnp.int32(layer))
+        at = np.arange(4), slice(None), pos
+        o, _, _, new = tick.select_attend(
+            q, jnp.asarray(k_log[at])[:, :, None],
+            jnp.asarray(v_log[at])[:, :, None], kind)
+        monkeypatch.undo()
+        live = jnp.asarray(np.where(active, pos, -1))
+        want = T._bsa_block_scores(q.reshape(4, 2, 2, 1, 16),
+                                   jnp.asarray(rows), live[:, None], mp // 2,
+                                   cfg)
+        got = np.asarray(seen["_bsa_block_scores"])
+        np.testing.assert_allclose(got, want, atol=1e-6)
+        assert (got[3] == -1).all() and (got[:3, :, :, 0] >= 0).all()
+        chosen, n = T._bsa_chosen(want.reshape(8, -1), jnp.repeat(
+            jnp.maximum(live, 0), 2), cfg)
+        np.testing.assert_array_equal(seen["_bsa_chosen"][0], chosen)
+        np.testing.assert_array_equal(seen["_bsa_chosen"][1], n)
+        assert n.tolist() == [3, 3, 5, 5, 5, 5, 1, 1]   # dense; 1 + 2 + 2
+        assert np.isfinite(np.asarray(o)[:3]).all()
+        for s in (0, 1):        # the page this token fills: 4 and 10
+            ck[layer, s, :, pos[s] // ps] = rows[s][:, pos[s] // ps]
+        np.testing.assert_array_equal(np.asarray(new)[..., 1:, :],
+                                      ck[..., 1:, :])
+
+    @pytest.mark.parametrize("case", ["mid_page", "several_pages",
+                                      "two_rows"])
+    def test_a_landing_lays_each_filled_pages_row_at_its_logical_index(
+            self, case):
+        """``paged_insert`` alone: a landing that starts mid-page
+        (positions 6-12: it fills pages 1 and 2, not 3), one that spans
+        several pages from 0 (14 tokens in a bucket of 16: pages 0-2),
+        and two rows of one landing into two slots — each filled page's
+        row at ``[layer, slot, head, start // page + j]``, and nothing
+        else of the array moved but the landed slots' rows 0."""
+        cfg = _cfg()
+        start, lens, bucket, slots = {
+            "mid_page": (6, [7], 8, [1]),
+            "several_pages": (0, [14], 16, [2]),
+            "two_rows": (8, [8, 5], 8, [2, 0])}[case]
+        K, n_pg = len(slots), C.landing_pages(bucket, 4)
+        pool = C.init_page_pool(cfg, 3, 3 * 24 + 1, 4, None, 2, 24)
+        before = np.asarray(pool["ck"]) + 7.0
+        rng = np.random.default_rng(1)
+        block = {"k": rng.normal(size=(2, K, 2, bucket, 16)),
+                 "v": rng.normal(size=(2, K, 2, bucket, 16)),
+                 "ck": rng.normal(size=(2, K, 2, n_pg, 16)),
+                 "lin": np.zeros((2, K, 4, 16, 16))}
+        pages = 1 + np.arange(K * n_pg, dtype=np.int32).reshape(K, n_pg)
+        out = C.paged_insert(
+            {**pool, "ck": jnp.asarray(before)}, np.asarray(slots, np.int32),
+            jnp.asarray(lens, jnp.int32) + start, pages, np.int32(start % 4),
+            np.asarray(lens, np.int32),
+            {n: jnp.asarray(a, jnp.float32) for n, a in block.items()})
+        want, filled = before.copy(), 0
+        for i, s in enumerate(slots):
+            for j in range(n_pg):
+                if (start // 4 + j + 1) * 4 <= start + lens[i]:
+                    want[:, s, :, start // 4 + j] = block["ck"][:, i, :, j]
+                    filled += 1
+        assert filled == {"mid_page": 2, "several_pages": 3,
+                          "two_rows": 3}[case]
+        got = np.asarray(out["ck"])
+        np.testing.assert_array_equal(got[..., 1:, :], want[..., 1:, :])
+        idle = [s for s in range(3) if s not in slots]
+        np.testing.assert_array_equal(got[:, idle], before[:, idle])
+        assert out["pos"].tolist() == [
+            dict(zip(slots, np.add(lens, start))).get(s, 0) for s in range(3)]
+
+    def test_a_slotless_landing_is_refused(self):
+        """Prefix registration lands into pages no slot holds: a pool
+        with an array of a row a page of a SLOT's table has nowhere to
+        put its rows (and no page of it is ever shared)."""
+        cfg = _cfg()
+        pool = C.init_page_pool(cfg, 3, 9, 4, None, 2, 24)
+        block = {"k": jnp.zeros((2, 1, 2, 8, 16)),
+                 "v": jnp.zeros((2, 1, 2, 8, 16)),
+                 "ck": jnp.zeros((2, 1, 2, 3, 16)),
+                 "lin": jnp.zeros((2, 1, 4, 16, 16))}
+        with pytest.raises(T.UnsupportedModelConfigError,
+                           match="slotless landing"):
+            C.paged_insert(pool, np.zeros((0,), np.int32),
+                           jnp.zeros((0,), jnp.int32),
+                           np.ones((1, 3), np.int32), np.int32(0),
+                           np.asarray([8], np.int32), block)
+
+    @pytest.mark.parametrize("chunk", [0, 7])
+    def test_no_row_of_the_last_tenant_is_scored(self, model, highest,
+                                                 chunk):
+        """ONE slot, granted to a request of 41 + 30 tokens and then to
+        one of 30 + 12: nothing is scrubbed between them (the first
+        tenant's rows lie there still), and with EVERY row of the array
+        then turned to NaN the second tenant's logits are the
+        reference's all the same — a query scores row ``r`` only once
+        ``(r + 1) stride <= pos + 1``, and each such page was filled by
+        this tenant's own landings or ticks first (the pages'
+        write-before-attend argument, a row a page)."""
+        params, cfg = model
+        long, short = _prompts((41, 30), seed=7)
+        engine = _engine(params, cfg, n_slots=1, prefill_chunk_tokens=chunk)
+        tap = _LogitTap(engine)
+        first = engine.submit(long, max_new_tokens=30)
+        _run(engine, [first])
+        ck = np.asarray(engine.slots.cache["ck"])
+        assert ck[:, 0, :, 10:17].all()   # pages 10-16: the first tenant's
+        second = engine.submit(short, max_new_tokens=12)
+        _run(engine, [second])
+        assert _worst(params, tap, [short], [second]) < LOGIT_TOL
+        ck = np.asarray(engine.slots.cache["ck"])
+        assert ck[:, 0, :, 11:17].all()   # ... still: 42 tokens fill 0-9
+        cache = engine.slots.cache   # a row scored unwritten would show
+        engine.slots.cache = {**cache,
+                              "ck": jnp.full_like(cache["ck"], jnp.nan)}
+        third = engine.submit(short, max_new_tokens=12)
+        _run(engine, [third])
+        assert third.result() == second.result()
+        assert _worst(params, tap, [short], [third]) < LOGIT_TOL
 
 
 def _brute_blocks(score, pos, sc):
@@ -370,17 +534,19 @@ class TestRefusalsByName:
 
     def test_a_pool_of_one_kind_only(self, model):
         """``lin`` is float32 beside a bfloat16 pool; ``ck`` a row a
-        page; neither kind's layers count the other's arrays."""
-        from horovod_tpu.serving import cache as C
-
+        page of a slot's table, as wide as the pool is told the table
+        is (``max_seq`` / page if it is not); neither kind's layers
+        count the other's arrays."""
         cfg = _cfg(dtype=jnp.bfloat16)
-        pool = C.init_page_pool(cfg, 3, 9, 4, None, cfg.layers_with("k"))
+        pool = C.init_page_pool(cfg, 3, 9, 4, None, cfg.layers_with("k"), 5)
         assert {n: (a.shape, a.dtype.name) for n, a in pool.items()} == {
             "pos": ((3,), "int32"),
             "k": ((2, 9, 2, 4, 16), "bfloat16"),
             "v": ((2, 9, 2, 4, 16), "bfloat16"),
-            "ck": ((2, 9, 32), "bfloat16"),
+            "ck": ((2, 3, 2, 5, 16), "bfloat16"),
             "lin": ((2, 3, 4, 16, 16), "float32")}
+        assert C.init_page_pool(cfg, 3, 9, 4, None, 2)["ck"].shape == (
+            2, 3, 2, 24, 16)
 
 
 with open(os.path.join(os.path.dirname(__file__), "data",

@@ -930,6 +930,29 @@ def test_the_served_hybrid_programs_compile_at_the_published_widths(
         assert mem.temp_size_in_bytes < 0.05e9, mem
         assert mem.argument_size_in_bytes < weights + pool_bytes + 1e6
         return
+    if what == "landing":
+        pool = _on(one_chip, jax.eval_shape(lambda: C.init_page_pool(
+            cfg, eng["n_slots"], eng["n_pages"] + 1, eng["page_size"], None,
+            Lk, eng["max_len"] // eng["page_size"])))
+        n_pg = C.landing_pages(512, eng["page_size"])
+        kv = sds((Lk, 1, cfg.kv_heads, 512, cfg.head_dim), cfg.dtype)
+        compiled = jax.jit(C.paged_insert, donate_argnums=(0,)).lower(
+            pool, sds((1,), jnp.int32), sds((1,), jnp.int32),
+            sds((1, n_pg), jnp.int32), sds((), jnp.int32),
+            sds((1,), jnp.int32), {
+                "k": kv, "v": kv,
+                "ck": sds((Lk, 1, cfg.kv_heads, n_pg, cfg.head_dim),
+                          cfg.dtype),
+                "lin": sds((Ll, 1) + pool["lin"].shape[2:], jnp.float32),
+            }).compile()
+        mem = compiled.memory_analysis()
+        pool_bytes = sum(a.size * a.dtype.itemsize for a in pool.values())
+        assert mem.alias_size_in_bytes >= pool_bytes > 5.06e9, mem
+        assert mem.temp_size_in_bytes < 1e6, mem
+        offenders, largest = chip_smoke.pool_sized_results(
+            compiled.as_text(), pool["ck"].size // Lk)
+        assert offenders == [], (offenders, largest)
+        return
     if what == "chunk":
         ids, lens = sds((1, 512), jnp.int32), sds((1,), jnp.int32)
         pk = sds((L, cfg.kv_heads, 2048, cfg.head_dim), cfg.dtype)
@@ -1003,7 +1026,53 @@ def _minicpm_sala():
     return dims, serve_linear_sparse.build_cfg(dims)
 
 
-@pytest.mark.parametrize("what", ["tick", "chunk", "prompt"])
+def _select_attend_by_page(self, qh, k_t, v_t, kind):
+    """``transformer._Tick.select_attend`` as it stood until PR 48: the
+    compressed keys ``(L, P, Hkv * Dh)`` a row a PHYSICAL page, written
+    at the page the token fills and read back through the page table —
+    ``ck[layer, table]``, a row of 512 B a table entry."""
+    cfg, pos, table, layer = self.cfg, self.pos, self.table, self.layer
+    k_pool, v_pool = (self.pools[n] for n in kind.paged)
+    (ck,) = (self.pools[n] for n in kind.page_rows)
+    S, H, _, Dh = qh.shape
+    Hkv, ps = k_pool.shape[2:4]
+    blk, m = cfg.bsa_block, cfg.bsa_block // cfg.bsa_stride
+    max_pages = table.shape[1]
+    with jax.named_scope("kv_write"):
+        phys, take = self._target(table, ps)
+        k_pool = PA.write_pages(k_pool, layer, phys, k_t, take)
+        v_pool = PA.write_pages(v_pool, layer, phys, v_t, take)
+        at = jnp.clip(pos // ps, 0, max_pages - 1)
+        before = table[jnp.arange(S), jnp.maximum(at - 1, 0)]
+        row = T._bsa_window_mean(k_pool[layer, before], k_pool[layer, phys],
+                                 cfg).reshape(S, Hkv * Dh)
+        full = self.active & (pos % ps == ps - 1) & (at >= 1)
+        ck = ck.at[layer, jnp.where(full, phys, 0)].set(row.astype(ck.dtype))
+    qg = qh.reshape(S, Hkv, H // Hkv, 1, Dh)
+    live = jnp.where(self.active, pos, -1)
+    with jax.named_scope("hvd_bsa_score"):
+        rows = ck[layer, table].reshape(S, max_pages, Hkv, Dh)
+        score = T._bsa_block_scores(qg, jnp.moveaxis(rows, 1, 2),
+                                    live[:, None], -(-max_pages // m), cfg)
+    R = S * Hkv
+    with jax.named_scope("hvd_bsa_select"):
+        chosen, n_sel = T._bsa_chosen(
+            score.reshape(R, -1), jnp.repeat(jnp.maximum(live, 0), Hkv), cfg)
+    with jax.named_scope("hvd_bsa_attend"):
+        page = (chosen[:, :, None] * m + jnp.arange(m, dtype=jnp.int32)
+                ).reshape(R, -1)
+        compact = PA.pages_of(jnp.repeat(table, Hkv, axis=0),
+                              jnp.minimum(page, max_pages - 1) * ps, ps)
+        limit = jnp.where(jnp.repeat(self.active, Hkv), (n_sel - 1) * blk
+                          + jnp.repeat(pos, Hkv) % blk + 1, 0)
+        o, _ = PA.paged_attend(qg[:, :, :, 0], k_pool, v_pool, None, None,
+                               compact.reshape(S, Hkv, -1),
+                               limit.reshape(S, Hkv), layer=layer)
+    return o.reshape(S, H, 1, Dh).astype(cfg.dtype), k_pool, v_pool, ck
+
+
+@pytest.mark.parametrize("what", ["tick", "tick-by_page", "landing", "chunk",
+                                  "prompt"])
 def test_the_served_linear_sparse_programs_compile_at_the_published_widths(
         one_chip, monkeypatch, what):
     """The benchmark's own configuration (`minicpm-sala-serve`: 48 slots
@@ -1017,13 +1086,23 @@ def test_the_served_linear_sparse_programs_compile_at_the_published_widths(
     layer, 2 x 128 a sparse one).  The TICK holds the paged kernel over
     a table a slot and KV head three times (a layer unrolled each: the
     pattern is one period of twelve) and the state update's kernel nine
-    times, takes 3.87 GB of pages, 126 MB of compressed keys and 0.91 GB
+    times, takes 3.87 GB of pages, 132 MB of compressed keys and 0.91 GB
     of float32 states and gives ALL of it back aliased, with no result
     the size of a layer of the states (201 MB) or of ``k`` but the
     arrays passing through, under 0.5 GB of temporaries: 13.4 GB with
-    the weights.  A CHUNK of 512 against 32 768 landed positions (the
-    largest bucket: a query a row its own blocks, 64 queries' scores in
-    flight) and a PROMPT of two rows of 512 compile inside 1.3 GB."""
+    the weights.  The compressed keys lie BY SLOT, ``(3, 48, 2, 1792,
+    128)``, and the product that scores them reads a layer of them
+    where it lies (an operand of its fusion): no instruction has a
+    result of a slot table's worth of rows (44 MB).  ``tick-by_page`` is
+    the read until PR 48 patched back in over an array a row a physical
+    page: each sparse layer then GATHERS its 48 x 1792 = 86 016 rows of
+    512 B through the page table under ``hvd_bsa_score``.  The LANDING
+    of a chunk of 512 (33 pages' rows a layer and KV head, scattered to
+    the slot's logical indices) gives the whole pool back aliased too,
+    under a megabyte of temporaries.  A CHUNK of 512 against 32 768
+    landed positions (the largest bucket: a query a row its own blocks,
+    64 queries' scores in flight) and a PROMPT of two rows of 512
+    compile inside 1.3 GB."""
     from horovod_tpu.ops import ssm as SSM
 
     for mod, name in ((PA, "use_interpret"), (SSM, "use_interpret"),
@@ -1048,21 +1127,25 @@ def test_the_served_linear_sparse_programs_compile_at_the_published_widths(
 
     Lk, Ll = cfg.layers_with("k"), cfg.layers_with("lin")
     assert (Lk, Ll, cfg.layers_with("ck")) == (3, 9, 3)
-    if what == "tick":
-        S_ = eng["n_slots"]
+    if what.startswith("tick"):
+        S_, width = eng["n_slots"], eng["max_len"] // eng["page_size"]
         pool = _on(one_chip, jax.eval_shape(lambda: C.init_page_pool(
-            cfg, S_, eng["n_pages"] + 1, eng["page_size"], None, Lk)))
+            cfg, S_, eng["n_pages"] + 1, eng["page_size"], None, Lk, width)))
         assert pool["k"].shape == (3, 81921, 2, 16, 128)
-        assert pool["ck"].shape == (3, 81921, 256)
+        assert pool["ck"].shape == (3, 48, 2, 1792, 128)
         assert (pool["lin"].shape, pool["lin"].dtype) == (
             (9, 48, 32, 128, 128), jnp.float32)
+        by_page = what == "tick-by_page"
+        if by_page:
+            pool["ck"] = sds((3, 81921, 256), cfg.dtype)
+            monkeypatch.setattr(T._Tick, "select_attend",
+                                _select_attend_by_page)
         compiled = jax.jit(
             lambda p, tok, act, t, pl: T.decode_step_paged(
                 p, tok, pl, t, cfg, act, kernel=True),
             donate_argnums=(4,)).lower(
                 params, sds((S_,), jnp.int32), sds((S_,), jnp.bool_),
-                sds((S_, eng["max_len"] // eng["page_size"]), jnp.int32),
-                pool).compile()
+                sds((S_, width), jnp.int32), pool).compile()
         text = compiled.as_text()
         assert chip_smoke.kernel_calls(text, PA.BSA_KERNEL_NAME) == 3
         assert chip_smoke.kernel_calls(text, SSM.UPDATE_NAME) == 9
@@ -1074,12 +1157,46 @@ def test_the_served_linear_sparse_programs_compile_at_the_published_widths(
             layer = pool[name].size // pool[name].shape[0]
             offenders, largest = chip_smoke.pool_sized_results(text, layer)
             assert offenders == [], (name, offenders, largest)
+        # a slot table's worth of compressed rows (86 016 of 512 B): a
+        # result of that size under the scores' scope is the gather
+        # through the page table
+        gathers = [g for g in chip_smoke.pool_sized_results(
+            text, S_ * width * cfg.kv_heads * cfg.head_dim)[0]
+            if "hvd_bsa_score" in g[3]]
+        rows = re.findall(r"= bf16\[86016,256\]\S* fusion\(", text)
+        assert by_page or not gathers, gathers
+        assert len(rows) == (3 if by_page else 0) == sum(
+            g[1] == "fusion" and g[3].endswith("hvd_bsa_score/gather")
+            for g in gathers), gathers
         mem = compiled.memory_analysis()
         pool_bytes = sum(a.size * a.dtype.itemsize for a in pool.values())
-        assert abs(pool_bytes - 5.058e9) < 1e6
+        assert abs(pool_bytes - (5.058e9 if by_page else 5.064e9)) < 1e6
         assert mem.alias_size_in_bytes >= pool_bytes   # ck and lin too
         assert mem.temp_size_in_bytes < 0.5e9, mem
         assert mem.argument_size_in_bytes < weights + pool_bytes + 1e6
+        return
+    if what == "landing":
+        pool = _on(one_chip, jax.eval_shape(lambda: C.init_page_pool(
+            cfg, eng["n_slots"], eng["n_pages"] + 1, eng["page_size"], None,
+            Lk, eng["max_len"] // eng["page_size"])))
+        n_pg = C.landing_pages(512, eng["page_size"])
+        kv = sds((Lk, 1, cfg.kv_heads, 512, cfg.head_dim), cfg.dtype)
+        compiled = jax.jit(C.paged_insert, donate_argnums=(0,)).lower(
+            pool, sds((1,), jnp.int32), sds((1,), jnp.int32),
+            sds((1, n_pg), jnp.int32), sds((), jnp.int32),
+            sds((1,), jnp.int32), {
+                "k": kv, "v": kv,
+                "ck": sds((Lk, 1, cfg.kv_heads, n_pg, cfg.head_dim),
+                          cfg.dtype),
+                "lin": sds((Ll, 1) + pool["lin"].shape[2:], jnp.float32),
+            }).compile()
+        mem = compiled.memory_analysis()
+        pool_bytes = sum(a.size * a.dtype.itemsize for a in pool.values())
+        assert mem.alias_size_in_bytes >= pool_bytes > 5.06e9, mem
+        assert mem.temp_size_in_bytes < 1e6, mem
+        offenders, largest = chip_smoke.pool_sized_results(
+            compiled.as_text(), pool["ck"].size // Lk)
+        assert offenders == [], (offenders, largest)
         return
     if what == "chunk":
         ids, lens = sds((1, 512), jnp.int32), sds((1,), jnp.int32)
